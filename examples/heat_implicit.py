@@ -21,10 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-# honor JAX_PLATFORMS=cpu even when a platform plugin tries to override
-# it (same workaround as examples/benchmark.py:70-75)
-if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)  # stiff Newton wants f64
 
 from sparse_tpu import csr_array  # noqa: E402

@@ -13,9 +13,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from sparse_tpu import csr_array
 
 

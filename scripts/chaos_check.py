@@ -396,7 +396,6 @@ def _elastic_remesh(report: dict) -> list:
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
     env["SPARSE_TPU_VAULT"] = vdir
-    env["SPARSE_TPU_COMPILE_CACHE"] = os.path.join(vdir, "_xla_cache")
     env["SPARSE_TPU_FLEET"] = "auto"
     env["SPARSE_TPU_FLEET_MIN_B"] = "2"
     env.pop("SPARSE_TPU_FAULTS", None)
@@ -1568,7 +1567,6 @@ def _ingest_kill_restart(report: dict) -> list:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["SPARSE_TPU_VAULT"] = vdir
-    env["SPARSE_TPU_COMPILE_CACHE"] = os.path.join(vdir, "_xla_cache")
     env.pop("SPARSE_TPU_FAULTS", None)
 
     def child(mode):
@@ -1660,7 +1658,6 @@ def _vault_kill_restart(report: dict) -> list:
     env["SPARSE_TPU_VAULT"] = vdir
     # the XLA-executable tier rides along (ISSUE 9 satellite): both
     # children share one persistent compilation cache dir
-    env["SPARSE_TPU_COMPILE_CACHE"] = os.path.join(vdir, "_xla_cache")
     env.pop("SPARSE_TPU_FAULTS", None)
 
     def child(mode):
@@ -1734,7 +1731,6 @@ def _fleet_kill_restart(report: dict) -> list:
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
     env["SPARSE_TPU_VAULT"] = vdir
-    env["SPARSE_TPU_COMPILE_CACHE"] = os.path.join(vdir, "_xla_cache")
     env["SPARSE_TPU_FLEET"] = "auto"
     # VAULT_B=4 real lanes must clear the batch-sharding threshold (the
     # bucket then rounds 4 -> 8, one lane per virtual device)
@@ -1821,7 +1817,6 @@ def _pipeline_restart_admission(report: dict) -> list:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["SPARSE_TPU_VAULT"] = vdir
-    env["SPARSE_TPU_COMPILE_CACHE"] = os.path.join(vdir, "_xla_cache")
     env["SPARSE_TPU_INFLIGHT"] = "4"
     env.pop("SPARSE_TPU_FAULTS", None)
 

@@ -776,12 +776,12 @@ class SolveSession:
         # byte-identical (pinned by tests/test_history.py)
         _history.maybe_start()
         # serving-path persistent XLA compile cache (ISSUE 9 satellite):
-        # env-gated so bucket-program executables survive restarts
-        # alongside the vault's packed artifacts
-        if settings.compile_cache:
-            from ..utils import enable_compilation_cache
+        # bucket-program executables survive restarts alongside the
+        # vault's packed artifacts (JAX_COMPILATION_CACHE_DIR if set,
+        # else <repo>/.jax_cache — utils.enable_compilation_cache)
+        from ..utils import enable_compilation_cache
 
-            enable_compilation_cache(settings.compile_cache)
+        enable_compilation_cache()
         self._warm: _WarmReplay | None = None
         self._warm_replayed = 0
         # background ingest onboarder (ISSUE 18): created lazily on the

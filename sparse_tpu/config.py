@@ -10,7 +10,10 @@ Flags (all env-overridable):
                                 per-shard column windows for the SpMV x-gather instead
                                 of cheap min/max bounds.
   SPARSE_TPU_SPMV_MODE        - 'auto' | 'segment' | 'ell' | 'sell' | 'pallas': SpMV
-                                kernel choice (docs/performance.md).
+                                kernel choice (docs/performance.md). On a TPU 'pallas'
+                                speeds up banded matrices only (packed-DIA kernel); the
+                                SELL Pallas kernels run in interpret mode off the TPU
+                                and are not selected on it (Mosaic refuses them).
   SPARSE_TPU_PLAN_CACHE       - library-wide operator plan cache (sparse_tpu.plan_cache):
                                 packed SELL/DIA operators and compiled distributed SpMV
                                 programs are prepared once per operator and reused.
@@ -37,10 +40,11 @@ Flags (all env-overridable):
                                 (default) = disk tier off, in-process cache only.
   SPARSE_TPU_VAULT_CAP_MB     - vault size budget in MB (default 512); the mtime-LRU GC
                                 sweep (vault.gc / scripts/vault_gc.py) evicts past it.
-  SPARSE_TPU_COMPILE_CACHE    - directory for jax's persistent XLA compilation cache on
-                                the serving path: SolveSession construction (and bench)
-                                call utils.enable_compilation_cache(dir) when set, so
-                                bucket-program executables persist across restarts too.
+  JAX_COMPILATION_CACHE_DIR   - (JAX's own variable, the only knob) where the persistent
+                                XLA compilation cache lives. SolveSession, bench.py and
+                                chip_smoke.py call utils.enable_compilation_cache(): set,
+                                no code touches the directory; unset, it is
+                                <repo>/.jax_cache.
   SPARSE_TPU_FLEET            - mesh-sharded serving tier (sparse_tpu.fleet): 'auto'
                                 enables both sharding strategies, 'batch' / 'row'
                                 restrict to one; empty (default) = single-device
@@ -218,8 +222,8 @@ class Settings:
     # Max |col - row| band at which the fused Pallas CG iteration
     # (kernels/cg_dia.py) applies — wider bands exceed the per-tile VMEM
     # window budget. (spmv_mode == 'pallas' accelerates DIA-profiled
-    # matrices only; general ELL matrices always take the XLA gather —
-    # Mosaic has no windowed-gather lowering, VERDICT r2 #8.)
+    # matrices only; general ELL/SELL matrices always take the XLA gather
+    # on a TPU — Mosaic has no in-VMEM gather lowering.)
     pallas_max_band: int = 8192
     # Runtime row-tile autotune for the packed-DIA Pallas SpMV: one ~1 s
     # chained probe per matrix geometry per session on real TPUs picks the
@@ -292,12 +296,6 @@ class Settings:
     vault: str = field(default_factory=lambda: _env_str("SPARSE_TPU_VAULT", ""))
     vault_cap_mb: int = field(
         default_factory=lambda: max(_env_int("SPARSE_TPU_VAULT_CAP_MB", 512), 1)
-    )
-    # Serving-path persistent XLA compilation cache dir: when set,
-    # SolveSession/bench call utils.enable_compilation_cache(dir) so the
-    # compiled-executable tier survives restarts alongside the vault.
-    compile_cache: str = field(
-        default_factory=lambda: _env_str("SPARSE_TPU_COMPILE_CACHE", "")
     )
     # Mesh-sharded serving tier (sparse_tpu.fleet): '' = off (the
     # single-device SolveSession path, byte-identical programs);

@@ -1,8 +1,8 @@
 """SELL-C-sigma packing + Pallas row-block SpMV for general (non-banded) CSR.
 
-The bench kernel sweep shows a ~1000x gap between the banded fast path and
-the general one: packed-DIA reaches 57.6 GFLOP/s while the segment path
-sits at 0.01-0.04 (BENCH_NOTES.md). DIA only covers banded matrices, so
+Older records put a ~1000x gap between the banded fast path and the
+general one (not measured on the current chip; ROADMAP S3). DIA only covers
+banded matrices, so
 every non-banded workload (eigsh, integrate Jacobians, csgraph, AMG
 hierarchies) paid the slow path per matvec. SELL-C-sigma (Kreutzer et al.,
 SISC 2014) is the standard SIMD-friendly packing for skewed row profiles
@@ -392,6 +392,11 @@ class PreparedCSR:
     def _pallas_viable(self, x) -> bool:
         from ..resilience import failover
 
+        if jax.default_backend() == "tpu":
+            # Mosaic refuses the in-VMEM gather ("Cannot do int indexing
+            # on TPU", pinned by tests/test_chip_compile.py): on a TPU
+            # the XLA slab form is the choice, not a failover
+            return False
         if failover.failed(self.KERNEL, self) or not self.slabs:
             return False
         if x.shape[0] > PALLAS_MAX_X:

@@ -4,7 +4,9 @@ Reference analog: ``sparse/config.py:21-58`` (``LegateSparseLib`` loading
 ``liblegate_sparse.so`` and exposing its C ABI through CFFI). Here the native
 surface is small — host-side work outside the XLA compute path (bitset BFS
 expansion, MatrixMarket tokenizing) — and is bound with ctypes. The library
-is compiled on first use with g++ -O3 into the package directory; every
+is compiled on first use with g++ -O3 (no -march=native: the binary must
+load on whatever machine a copy of this tree lands on) into the package
+directory; every
 caller must handle ``lib() is None`` (pure-numpy fallback), so missing
 toolchains degrade gracefully.
 """
@@ -34,7 +36,7 @@ def _build() -> str | None:
         return _SO
     try:
         subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-o", _SO, _SRC],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
             check=True,
             capture_output=True,
             timeout=120,

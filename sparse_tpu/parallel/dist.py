@@ -161,13 +161,24 @@ class DistCSR:
             if not in_trace():
                 led.commit(1, self.S)
 
-    def spmv_padded(self, xp: jax.Array) -> jax.Array:
+    def _blocks(self) -> tuple:
+        """The mesh-sharded matrix blocks the compiled SpMV takes as
+        ARGUMENTS (per layout)."""
+        if self.layout == "ell":
+            return (self.ell_idx, self.ell_val)
+        return (self.nz_rows, self.nz_cols, self.nz_vals)
+
+    def spmv_padded(self, xp: jax.Array, blocks: tuple | None = None) -> jax.Array:
         """y = A @ x entirely in padded layout ([n_pad] -> [m_pad]).
 
         This is the jit-safe inner-loop primitive; solvers call it inside
         ``lax.while_loop`` without any host sync. Telemetry counts eager
         dispatches and their structural comm volume (traced inner-loop
         calls are accounted at the solver level instead — ``comm.cg``).
+
+        ``blocks`` (default: this matrix's own, :meth:`_blocks`) lets a
+        compiled solver thread the blocks through as its own jit
+        arguments instead of capturing them as constants.
         """
         from .. import telemetry
 
@@ -178,14 +189,7 @@ class DistCSR:
                 telemetry.count("comm.spmv.calls")
                 telemetry.add_bytes("comm.spmv.total", self._spmv_comm_bytes())
         fn = self._plan_fn("_spmv_fn", "dist.spmv", lambda: _build_spmv(self))
-        out = fn(
-            xp,
-            *(
-                (self.ell_idx, self.ell_val)
-                if self.layout == "ell"
-                else (self.nz_rows, self.nz_cols, self.nz_vals)
-            ),
-        )
+        out = fn(xp, *(self._blocks() if blocks is None else blocks))
         # measured accounting: the trace populated the ledger by the time
         # the dispatch returns, so an eager call commits exactly one
         # program execution's collective volume
@@ -856,9 +860,16 @@ def make_dist_cg(
     else:
         precond = M
 
+    # The matrix blocks are ARGUMENTS of the compiled loop, never closure
+    # constants: captured, they are baked into the executable (2.68 GB at
+    # 8192^2 over four chips — too large to serialize, and on the TPU its
+    # outputs then came back without a sharding; chip_smoke.py, PR 22).
     @jax.jit
-    def run(bp, xp):
-        r = bp - A.spmv_padded(xp)
+    def solve(bp, xp, *blocks):
+        def spmv(v):
+            return A.spmv_padded(v, blocks)
+
+        r = bp - spmv(xp)
         bnorm2 = jnp.real(jnp.vdot(bp, bp))
         tol2 = jnp.maximum(
             jnp.asarray(tol, dtype=bnorm2.dtype) ** 2 * bnorm2,
@@ -871,7 +882,7 @@ def make_dist_cg(
             rho_new = jnp.vdot(r, z)
             beta = rho_new / jnp.where(rho == 0, 1, rho)
             p = jnp.where(iters == 0, z, z + beta * p)
-            q = A.spmv_padded(p)
+            q = spmv(p)
             pq = jnp.vdot(p, q)
             alpha = rho_new / jnp.where(pq == 0, 1, pq)
             return x + alpha * p, r - alpha * q, p, rho_new, iters + 1
@@ -887,6 +898,9 @@ def make_dist_cg(
         x, r, _, _, iters = jax.lax.while_loop(cond, body, state)
         rnorm2 = jnp.real(jnp.vdot(r, r))
         return x, iters, rnorm2 < tol2
+
+    def run(bp, xp):
+        return solve(bp, xp, *A._blocks())
 
     return run
 

@@ -6,8 +6,8 @@ One JSON file (``<vault>/manifest.json``) listing the
 replays it on session construction (``SolveSession(warm_start=...)``):
 each entry's pattern structure loads from its ``pattern`` artifact, the
 SELL pack loads from the disk tier, and the bucket program re-builds /
-re-compiles ahead of traffic (hitting jax's persistent compilation cache
-when ``SPARSE_TPU_COMPILE_CACHE`` is set) — so a killed server comes
+re-compiles ahead of traffic (hitting jax's persistent compilation
+cache, ``utils.enable_compilation_cache``) — so a killed server comes
 back warm instead of paying its whole cold start on the first request.
 
 Same trust model as artifacts: writes are atomic (tmp + fsync +

@@ -246,11 +246,9 @@ def _hermitian_stack(n=32, seed=10):
     return H, zb
 
 
-def test_b1_cg_complex_via_stacked_shim(monkeypatch):
-    """c64/c128 batch-of-1 parity with the TRANSFER-RESTRICTED path
-    forced: complex host inputs ride utils.asjnp's stacked-real shim
-    into the batched solver, exactly like the unbatched solvers."""
-    monkeypatch.setattr(utils, "_TRANSFER_RESTRICTED", True)
+def test_b1_cg_complex():
+    """c64/c128 batch-of-1 parity: complex host inputs enter the batched
+    solver through utils.asjnp exactly like the unbatched solvers."""
     H, zb = _hermitian_stack()
     Xb, info = batched_cg(
         BatchedCSR.from_stack([H]), zb[None, :], tol=1e-10, maxiter=400

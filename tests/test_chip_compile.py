@@ -1,0 +1,247 @@
+"""Compile rehearsal for the chip: the main path's programs, at the sizes
+``chip_smoke.py`` runs, handed to the TPU's own compiler for a *described*
+``v5e:2x2`` (no chip attached; nothing executes).
+
+A compile that passes is not a chip run — it only says the chip's compiler
+accepts the program and how many bytes it plans per device. What runs and
+how fast is ``chip_smoke.py``'s business.
+
+Discipline (one process may hold libtpu): the topology is described inside
+a module-scoped fixture, never at import; every case compiles in this
+process from ``ShapeDtypeStruct``s carrying a described device's sharding,
+``interpret=False``, with the persistent compile cache off (an entry
+written for a described chip cannot be read back without one).
+"""
+
+import os
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+# the sizes chip_smoke.py runs (kept in step with its SERVED_GRID/DIST_GRID)
+PDE_N = 6000  # BASELINE.md: 5-pt Laplacian, 6000^2 unknowns per chip
+SERVED_GRID = 2048
+SERVED_BUCKET = 16
+DIST_GRID = 4096  # per-chip grid of the 4-chip weak-scaled dist_cg
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def chip(topo):
+    """The described topology under the chip process's defaults: x64 off
+    (tests/conftest.py turns it on for the scipy oracles; ``chip_smoke.py``
+    runs f32, and with x64 on Mosaic's lowering of the DIA kernels' int32
+    ``program_id % 2`` recurses without end)."""
+    with jax.enable_x64(False):
+        yield topo
+
+
+@pytest.fixture
+def one_chip(chip):
+    return SingleDeviceSharding(chip.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return int(
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        + ma.temp_size_in_bytes - ma.alias_size_in_bytes
+    )
+
+
+def _five_point(g: int) -> sp.csr_matrix:
+    """5-point Laplacian pattern on a g x g grid (host, scipy)."""
+    T = sp.diags([-1.0, 2.0, -1.0], [-1, 0, 1], shape=(g, g))
+    I = sp.identity(g)
+    return (sp.kron(I, T) + sp.kron(T, I)).tocsr().astype(np.float32)
+
+
+# ---------------------------------------------------------------------------
+# DIA SpMV (A @ x under spmv_mode='pallas', and the SpMV microbenchmark row)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize(
+    "rows, offsets",
+    [
+        (10_000_000, tuple(range(-5, 6))),  # BASELINE SpMV row: 10M x 11
+        (PDE_N * PDE_N, (-PDE_N, -1, 0, 1, PDE_N)),  # PDE operator: 36M x 5
+    ],
+    ids=["10Mx11", "36Mx5"],
+)
+def test_dia_spmv_packed_compiles(one_chip, rows, offsets):
+    from sparse_tpu.kernels.dia_spmv import dia_plan, dia_spmv_packed
+
+    plan = dia_plan(offsets, (rows, rows), tile=65536)
+    m_pad = plan.G * plan.TM
+    planes = _sds((plan.D * m_pad,), jnp.float32, one_chip)
+    xpad = _sds((m_pad + 2 * plan.B,), jnp.float32, one_chip)
+    c = dia_spmv_packed.lower(planes, xpad, plan, interpret=False).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# fused CG: what linalg.cg takes on a TPU for a banded f32 operator
+# ---------------------------------------------------------------------------
+def _linalg_cg_tile(D: int) -> int:
+    """The tile ``linalg._try_fused_cg`` picks for a D-diagonal operator."""
+    from sparse_tpu.config import settings
+
+    return max(16384, min(int(settings.fused_cg_tile),
+                          (6 << 20) // (max(2 * D + 10, 1) * 4)))
+
+
+def test_cg_dia_fused_compiles_at_pde_size(one_chip):
+    from sparse_tpu.kernels.cg_dia import cg_dia_fused
+
+    n = PDE_N * PDE_N
+    offsets = (-PDE_N, -1, 0, 1, PDE_N)
+    tile = _linalg_cg_tile(len(offsets))
+    planes = _sds((len(offsets), n), jnp.float32, one_chip)
+    b = _sds((n,), jnp.float32, one_chip)
+    # the first conv-test chunk exactly as linalg.cg issues it
+    c = cg_dia_fused.lower(
+        planes, offsets, b, None, n, iters=25, tile=tile,
+        state=None, return_state=True, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+def test_cg_dia_fused_onepass_compiles_at_pde_size(one_chip):
+    from sparse_tpu.kernels.cg_dia import cg_dia_fused_onepass
+
+    n = PDE_N * PDE_N
+    offsets = (-PDE_N, -1, 0, 1, PDE_N)
+    planes = _sds((len(offsets), n), jnp.float32, one_chip)
+    b = _sds((n,), jnp.float32, one_chip)
+    c = cg_dia_fused_onepass.lower(
+        planes, offsets, b, None, n, iters=25, tile=65536, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the served bucket program (SolveSession._build_program), from shapes
+# ---------------------------------------------------------------------------
+def test_served_bucket_program_compiles(one_chip, monkeypatch):
+    from sparse_tpu.batch import service
+
+    # the chip donates the staged value stack / rhs / x0; jax.default_backend()
+    # is the CPU here, so steer the one backend question the builder asks
+    monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    A = _five_point(SERVED_GRID)
+    n = A.shape[0]
+    ses = service.SolveSession("cg", batch_max=SERVED_BUCKET, warm_start=False)
+    pattern = ses.pattern_of(A)
+    run = ses._build_program(pattern, SERVED_BUCKET, np.dtype(np.float32))
+    B = SERVED_BUCKET
+    c = run.lower(
+        _sds((B, pattern.nnz), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile()
+    used = _device_bytes(c)
+    print(f"served g={SERVED_GRID} B={B}: {used / 2**30:.2f} GiB planned")
+    # a real share of HBM, and it fits
+    assert 0.25 * HBM_BYTES < used < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# four chips: make_dist_cg's program over a Mesh of described devices
+# ---------------------------------------------------------------------------
+def test_dist_cg_program_compiles_on_four_chips(chip):
+    from sparse_tpu.parallel import dist
+
+    mesh = Mesh(np.array(chip.devices[:4]), ("shards",))
+    S = 4
+    R = DIST_GRID * DIST_GRID  # rows per shard (weak scaling: g^2 per chip)
+    H = 2 * DIST_GRID  # 5-point halo: one grid row each side
+    k = 5
+    A = dist.DistCSR(
+        mesh=mesh, axis="shards", shape=(S * R, S * R),
+        row_splits=np.arange(S + 1) * R, col_splits=np.arange(S + 1) * R,
+        R=R, C=R, HL=H // 2, HR=H // 2, mode="halo", layout="ell",
+        dtype=np.dtype(np.float32),
+    )
+    s3 = NamedSharding(mesh, P("shards", None, None))
+    s1 = NamedSharding(mesh, P("shards"))
+    idx = _sds((S, R, k), jnp.int32, s3)
+    val = _sds((S, R, k), jnp.float32, s3)
+    vec = _sds((S * R,), jnp.float32, s1)
+
+    @jax.jit
+    def run(bp, xp, ell_idx, ell_val):
+        # a described device holds no arrays: hand the DistCSR the traced
+        # blocks, which make_dist_cg passes to its loop as arguments
+        A.ell_idx, A.ell_val = ell_idx, ell_val
+        return dist.make_dist_cg(A, tol=0.0, maxiter=300)(bp, xp)
+
+    c = run.lower(vec, vec, idx, val).compile()
+    hlo = c.as_text()
+    assert "collective-permute" in hlo  # the halo exchange
+    assert "all-reduce" in hlo  # the CG dot products
+    used = _device_bytes(c)
+    print(f"dist_cg g={DIST_GRID}/chip: {used / 2**30:.2f} GiB per device")
+    assert used < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the two opt-in SELL Pallas kernels: Mosaic refuses both today. These pin
+# the refusal, so the PR that makes them lower (or deletes them, ROADMAP S3)
+# has a test to flip.
+# ---------------------------------------------------------------------------
+def test_sell_slab_pallas_is_refused(one_chip):
+    from sparse_tpu.kernels.sell_spmv import _sell_slab_pallas
+
+    K, R, n, TM = 16, 4096, 65536, 1024
+    with pytest.raises(ValueError, match="int indexing"):
+        _sell_slab_pallas.lower(
+            _sds((K, R), jnp.int32, one_chip),
+            _sds((K, R), jnp.float32, one_chip),
+            _sds((n,), jnp.float32, one_chip),
+            K, TM, interpret=False,
+        ).compile()
+
+
+def test_sell_slab_pallas_batched_is_refused(one_chip):
+    from sparse_tpu.kernels.sell_spmv import _sell_slab_pallas_batched
+
+    B, K, R, n, TM = 8, 16, 4096, 65536, 1024
+    with pytest.raises(ValueError, match="divisible"):
+        _sell_slab_pallas_batched.lower(
+            _sds((K, R), jnp.int32, one_chip),
+            _sds((B, K, R), jnp.float32, one_chip),
+            _sds((B, n), jnp.float32, one_chip),
+            K, TM, interpret=False,
+        ).compile()

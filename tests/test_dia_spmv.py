@@ -241,7 +241,7 @@ def test_autotune_off_tpu_returns_default_without_caching():
 def test_autotune_probe_failure_returns_default_without_crash(monkeypatch):
     """On a backend where the chain/kernel cannot run, every candidate
     drops out of the race and the default tile comes back — no exception
-    escapes (the wedge-safety contract of the one-attempt design)."""
+    escapes (the contract of the one-attempt design)."""
     from sparse_tpu.kernels import dia_spmv as K
 
     K._TILE_CACHE.clear()

@@ -6,7 +6,7 @@ The load-bearing contracts:
   sweeps under the f64 iterative-refinement outer loop) reaches the
   f64 answer at its absolute tolerance; ``scripts/f64_oracle.py``'s
   per-size table is pinned HERE (the oracle-fixture satellite), not
-  just pasted into BENCH_NOTES.md. The divergence safeguard returns
+  just pasted into a notes file. The divergence safeguard returns
   the best iterate, reported unconverged, when refinement cannot
   contract.
 * **Kernels** — the SELL/DIA formulations accept a storage dtype

@@ -503,6 +503,58 @@ def test_injected_pallas_failure_batched(monkeypatch):
     assert failover.failed(sib.KERNEL, sib.pattern)
 
 
+@pytest.mark.parametrize(
+    "kernel, vocab",
+    [("sell_spmv", False), ("sell_spmv_batched", False), ("dia_spmv", True)],
+)
+def test_tpu_backend_pallas_error_raises_at_every_site(monkeypatch, kernel, vocab):
+    """On the TPU backend a Pallas error that was not injected is an error
+    at each of the three failover sites — no quiet XLA substitute."""
+    import jax
+
+    monkeypatch.delenv("SPARSE_TPU_STRICT_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    class Obj:
+        pass
+
+    o = Obj()
+    for err in (ValueError("Mosaic lowering failed"),
+                NotImplementedError("unimplemented lowering"),
+                ValueError("only supported in interpret mode")):
+        with pytest.raises(type(err)):
+            failover.handle(kernel, o, err, vocab=vocab)
+    assert not failover.failed(kernel, o)
+    # the injected failure still rides the production failover path
+    with pytest.warns(UserWarning, match="failing over"):
+        failover.handle(
+            kernel, o, failover.InjectedPallasFailure("injected"), vocab=vocab
+        )
+    assert failover.failed(kernel, o)
+
+
+def test_tpu_backend_does_not_select_the_sell_pallas_kernels(monkeypatch):
+    """While Mosaic refuses both SELL kernels (tests/test_chip_compile.py),
+    spmv_mode='pallas' picks the XLA slab form on a TPU by choice."""
+    import jax
+
+    from sparse_tpu.batch import BatchedCSR
+    from sparse_tpu.kernels.sell_spmv import PreparedCSR
+
+    monkeypatch.setattr(settings, "spmv_mode", "pallas")
+    G = _spd(32).astype(np.float32)
+    prep = PreparedCSR(G.indptr, G.indices, G.data, G.shape)
+    x = np.ones(32, np.float32)
+    mats, _ = _stack(n=32, B=2)
+    bc = BatchedCSR.from_stack([m.astype(np.float32) for m in mats])
+    pack, _vals = bc._packed()
+    X = np.ones((2, 32), np.float32)
+    assert prep._pallas_viable(x) and bc._pallas_viable(pack, X)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not prep._pallas_viable(x)
+    assert not bc._pallas_viable(pack, X)
+
+
 # ---------------------------------------------------------------------------
 # resilient SolveSession
 # ---------------------------------------------------------------------------

@@ -422,13 +422,24 @@ class TestWarmRestart:
                 for m, x, b in zip(mats, X, rhs))
         assert r <= 1e-4
 
-    def test_compile_cache_env_gate(self, tmp_path, monkeypatch):
-        target = str(tmp_path / "xla_cache")
+    @pytest.mark.parametrize("env_set", [True, False])
+    def test_compile_cache_rule(self, tmp_path, monkeypatch, env_set):
+        """One rule: JAX_COMPILATION_CACHE_DIR set -> the session sets no
+        directory; unset -> the fixed <repo>/.jax_cache."""
+        from sparse_tpu import utils
+
         old = jax.config.jax_compilation_cache_dir
-        monkeypatch.setattr(settings, "compile_cache", target)
+        placed = str(tmp_path / "placed_from_outside")
         try:
+            jax.config.update("jax_compilation_cache_dir", placed)
+            if env_set:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+            else:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
             SolveSession("cg", warm_start=False)
-            assert jax.config.jax_compilation_cache_dir == target
+            want = placed if env_set else utils._REPO_CACHE
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.basename(utils._REPO_CACHE) == ".jax_cache"
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
 
