@@ -69,8 +69,10 @@ class BatchedSolveInfo:
 
 def _bdot(a, b):
     """Per-lane inner product with the first argument conjugated — the
-    batched form of ``linalg._vdot`` (scipy's ``np.vdot`` choice)."""
-    return jnp.sum(jnp.conj(a) * b, axis=-1)
+    batched form of ``linalg._vdot`` (scipy's ``np.vdot`` choice).
+    The scope names the reduction's ops in a device trace (``op_name``)."""
+    with jax.named_scope("bucket.dots"):
+        return jnp.sum(jnp.conj(a) * b, axis=-1)
 
 
 def _prep(A, b, x0, tol, maxiter):
@@ -183,9 +185,10 @@ def _cg_loop(matvec, b, X0, tol, maxiter, conv_test_iters, Mvec=None,
         pq = _bdot(Pn, Q)
         alpha = rho_new / jnp.where(pq == 0, 1, pq)  # 0/0 guard: b=0/exact x0
         am = active[:, None]
-        X = jnp.where(am, X + alpha[:, None] * Pn, X)
-        R = jnp.where(am, R - alpha[:, None] * Q, R)
-        P = jnp.where(am, Pn, P)
+        with jax.named_scope("bucket.axpy"):
+            X = jnp.where(am, X + alpha[:, None] * Pn, X)
+            R = jnp.where(am, R - alpha[:, None] * Q, R)
+            P = jnp.where(am, Pn, P)
         rho = jnp.where(active, rho_new, rho)
         iters = iters + active.astype(jnp.int32)
         k = k + 1

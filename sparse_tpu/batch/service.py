@@ -240,7 +240,7 @@ class SolveTicket:
     def __init__(self, session, deadline_s=None, tenant=None):
         self._session = session
         self._out = None
-        self.t_submit = time.monotonic()
+        self.t_submit = telemetry.clock()
         self.state = TicketState.PENDING
         self.error = None
         self.deadline_s = None if deadline_s is None else float(deadline_s)
@@ -311,7 +311,9 @@ class SolveTicket:
         return self._session._ticket_ready(self)
 
     def result(self, timeout: float | None = None):
-        if self.state is TicketState.PENDING:
+        # not finalized: pending, or holding a first (unconverged) result
+        # while its fallback bucket is still in flight
+        if self.t_done is None:
             self._session._resolve_ticket(self, timeout)
         if self.state is TicketState.PENDING:
             if self._session._holds(self):
@@ -338,7 +340,7 @@ class SolveTicket:
 
     @property
     def converged(self) -> bool:
-        if self.state is TicketState.PENDING:
+        if self.t_done is None:
             self._session._resolve_ticket(self, None)
         if self._out is None:
             return False
@@ -456,6 +458,12 @@ def _build_ir_program(pack, mixed: dict, solver: str, cti: int, mfac,
     return run
 
 
+def _at(t):
+    """A live span's own reading of the clock — or, telemetry off, when
+    the span is the shared no-op and holds none, a reading taken now."""
+    return telemetry.clock() if t is None else t
+
+
 class _InFlight:
     """One dispatched-but-not-retired bucket program: everything
     ``_retire`` needs to scatter results, account phases and decide
@@ -463,18 +471,22 @@ class _InFlight:
     donated) input arrays — only the program outputs."""
 
     __slots__ = ("reqs", "dt", "solver", "allow_requeue", "plan", "key",
-                 "bkt", "nb", "out", "built", "snap", "t0", "t_packed",
-                 "t_solve0", "t_dispatched", "sampled", "policy", "auto",
-                 "_ready")
+                 "bkt", "nb", "out", "built", "snap", "seq", "t0",
+                 "t_packed", "t_solve0", "t_dispatched", "sampled",
+                 "policy", "auto", "_ready")
 
     def __init__(self, reqs, dt, solver, allow_requeue, plan, key, bkt,
-                 nb, out, built, snap, t0, t_packed, t_solve0,
+                 nb, out, built, snap, seq, t0, t_packed, t_solve0,
                  t_dispatched, sampled, policy=mixed_mod.EXACT, auto=None):
         self.reqs, self.dt, self.solver = reqs, dt, solver
         self.allow_requeue, self.plan, self.key = allow_requeue, plan, key
         self.bkt, self.nb, self.out = bkt, nb, out
         self.built, self.snap = built, snap
-        self.t0, self.t_packed, self.t_solve0 = t0, t_packed, t_solve0
+        # `t0` is where the launch began; the rest are readings of its
+        # spans (docs/telemetry.md): `t_packed` is where session.plan
+        # began, `t_solve0`/`t_dispatched` are session.call's two ends
+        self.seq, self.t0, self.t_packed = seq, t0, t_packed
+        self.t_solve0 = t_solve0
         self.t_dispatched, self.sampled = t_dispatched, sampled
         # the resolved dtype policy this bucket ran under (ISSUE 15):
         # 'exact' or a reduced policy — the promote rung keys off it
@@ -1392,14 +1404,16 @@ class SolveSession:
         once the budget lapses."""
         deadline = (
             None if timeout is None
-            else time.monotonic() + max(float(timeout), 0.0)
+            else telemetry.clock() + max(float(timeout), 0.0)
         )
         if t.state is TicketState.PENDING and self._pending:
             # the legacy result() contract: a pending ticket flushes the
             # session (every queued pattern), just without blocking —
             # the retire loop below does exactly the waiting needed
             self._flush_pending()
-        while t.state is TicketState.PENDING:
+        # to the ticket's finalisation, not its first result: a requeued
+        # lane is DONE with what it has while its fallback is in flight
+        while t.t_done is None:
             fl = self._find_inflight(t)
             if fl is None:
                 return  # unresolved/failed: result() raises
@@ -1407,7 +1421,7 @@ class SolveSession:
                 self._retire_through(fl)
             elif fl.is_ready():
                 self._retire_through(fl)
-            elif time.monotonic() >= deadline:
+            elif telemetry.clock() >= deadline:
                 return
             else:
                 time.sleep(2e-4)
@@ -1427,7 +1441,7 @@ class SolveSession:
         is the assertion)."""
         if t.t_done is not None:
             return  # already finalized (a requeue resolves in-flush)
-        t.t_done = time.monotonic()
+        t.t_done = telemetry.clock()
         _QUEUE_DEPTH.dec()
         self._unfinalized -= 1
         latency_s = t.t_done - t.t_submit
@@ -1616,8 +1630,14 @@ class SolveSession:
         replay of the same program instead of rebuilding it), and call
         it WITHOUT blocking. Returns the :class:`_InFlight` record, or
         ``None`` when the compiled path was unavailable and the lanes
-        were already resolved on the eager degraded path."""
-        t0 = time.monotonic()
+        were already resolved on the eager degraded path.
+
+        The stack, the upload, the program's resolution and its call are
+        ``telemetry.span``s (``session.pack`` / ``.upload`` / ``.plan`` /
+        ``.call``, docs/telemetry.md); the instants the retire needs are
+        read off them, but for ``t0``: the tickets' queue ends where the
+        launch begins, and the decisions before the stack are no span."""
+        t0 = telemetry.clock()
         if _faults.ACTIVE:
             # elastic detection, forged-world trigger (ISSUE 20): a live
             # ``mesh`` fault clause changes what the world offers —
@@ -1687,21 +1707,28 @@ class SolveSession:
                 nb, policy=self.bucket_policy, batch_max=self.batch_max,
                 multiple_of=(plan.S if plan.strategy == "batch" else 1),
             )
-        values = np.stack([r.values.astype(dt) for r in reqs])
-        rhs = np.stack([r.b.astype(dt) for r in reqs])
-        tols = np.asarray([r.tol for r in reqs])
-        x0 = None
-        if any(r.x0 is not None for r in reqs):
-            x0 = np.stack([
-                np.zeros(pattern.shape[0], dt) if r.x0 is None
-                else np.asarray(r.x0, dtype=dt)
-                for r in reqs
-            ])
+        seq = self._dispatch_seq + 1  # this dispatch's, once it is called
+        tag = (
+            {"seq": seq, "bucket": bkt, "lanes": nb}
+            if telemetry.enabled() else {}
+        )
+        with telemetry.span("session.pack", **tag):
+            values = np.stack([r.values.astype(dt) for r in reqs])
+            rhs = np.stack([r.b.astype(dt) for r in reqs])
+            tols = np.asarray([r.tol for r in reqs])
+            x0 = None
+            if any(r.x0 is not None for r in reqs):
+                x0 = np.stack([
+                    np.zeros(pattern.shape[0], dt) if r.x0 is None
+                    else np.asarray(r.x0, dtype=dt)
+                    for r in reqs
+                ])
         # pad + eager host->device upload: the transfers overlap the
         # solve of whatever bucket is currently in flight
-        values, rhs, tols, x0, _ = bucketing.stage_lanes(
-            values, rhs, tols, bkt, x0=x0
-        )
+        with telemetry.span("session.upload", **tag):
+            values, rhs, tols, x0, _ = bucketing.stage_lanes(
+                values, rhs, tols, bkt, x0=x0
+            )
         maxiter = max(
             (r.maxiter if r.maxiter is not None else pattern.shape[0] * 10)
             for r in reqs
@@ -1768,7 +1795,6 @@ class SolveSession:
             # their trace: never share cache entries with clean ones
             key += ".faults"
         args = (values, rhs, x0, tols, maxiter)
-        t_packed = time.monotonic()
         built: dict = {}
 
         def build():
@@ -1776,14 +1802,14 @@ class SolveSession:
             # AOT compile duration, XLA cost/memory analysis — one
             # plan_cache.compile event per program, ever (same cadence
             # as the miss itself)
-            tb = time.perf_counter()
+            tb = telemetry.clock()
             fn = self._build_program(pattern, bkt, np.dtype(dt),
                                      solver=solver, plan=plan,
                                      precond=mkind, dtype_policy=pol,
                                      precond_dtype=pdt)
             prog, info = _cost.attribute(
                 key, fn, args,
-                pack_s=time.perf_counter() - tb,
+                pack_s=telemetry.clock() - tb,
                 solver=solver, bucket=bkt, dtype=np.dtype(dt).str,
                 n=pattern.shape[0], nnz=pattern.nnz,
                 **({"precond": mkind}
@@ -1797,64 +1823,67 @@ class SolveSession:
             return prog
 
         try:
-            if self._warm is not None and self._warm.active:
-                # the async replay may already be compiling this very
-                # program: wait for it rather than building twice — the
-                # zero-serving-miss warm restart contract
-                self._warm.wait_for(key)
-            prog = plan_cache.get(pattern, key, build)
-            if built:
-                self._serving_builds += 1
-            if built and not faulty:
-                # a freshly built bucket program is warm-start state:
-                # note it (and its pattern artifact) in the vault
-                # manifest so a restarted process replays it. Fault-
-                # wrapped programs are never noted — their traces carry
-                # the injection callback.
-                from .. import vault
+            with telemetry.span("session.plan", **tag) as sp:
+                # where `pack_ms` ends, as before the spans: after the
+                # policy decisions and the key, before the program
+                t_packed = _at(sp.t0)
+                if self._warm is not None and self._warm.active:
+                    # the async replay may already be compiling this very
+                    # program: wait for it rather than building twice — the
+                    # zero-serving-miss warm restart contract
+                    self._warm.wait_for(key)
+                prog = plan_cache.get(pattern, key, build)
+                if built:
+                    self._serving_builds += 1
+                if built and not faulty:
+                    # a freshly built bucket program is warm-start state:
+                    # note it (and its pattern artifact) in the vault
+                    # manifest so a restarted process replays it. Fault-
+                    # wrapped programs are never noted — their traces carry
+                    # the injection callback.
+                    from .. import vault
 
-                if vault.enabled() and plan.strategy != "row":
-                    # row programs are rebuilt per dispatch (no compiled
-                    # artifact worth replaying); batch-sharded programs
-                    # note the mesh fingerprint so only a same-topology
-                    # restart replays them; preconditioned programs note
-                    # their resolved kind (ISSUE 14) so the replay
-                    # rebuilds the SAME keyed program, symbolic maps
-                    # loading from their vault artifacts
-                    vault.note_program(
-                        pattern, solver=solver, bucket=bkt,
-                        dtype=np.dtype(dt).str,
-                        mesh=(plan.fingerprint if plan.sharded else None),
-                        strategy=(plan.strategy if plan.sharded else None),
-                        precond=(mkind if mkind != precond_mod.NONE
-                                 else None),
-                        dtype_policy=(pol if pol != mixed_mod.EXACT
-                                      else None),
-                        precond_dtype=(pdt if pdt != "compute"
-                                       else None),
+                    if vault.enabled() and plan.strategy != "row":
+                        # row programs are rebuilt per dispatch (no compiled
+                        # artifact worth replaying); batch-sharded programs
+                        # note the mesh fingerprint so only a same-topology
+                        # restart replays them; preconditioned programs note
+                        # their resolved kind (ISSUE 14) so the replay
+                        # rebuilds the SAME keyed program, symbolic maps
+                        # loading from their vault artifacts
+                        vault.note_program(
+                            pattern, solver=solver, bucket=bkt,
+                            dtype=np.dtype(dt).str,
+                            mesh=(plan.fingerprint if plan.sharded else None),
+                            strategy=(plan.strategy if plan.sharded else None),
+                            precond=(mkind if mkind != precond_mod.NONE
+                                     else None),
+                            dtype_policy=(pol if pol != mixed_mod.EXACT
+                                          else None),
+                            precond_dtype=(pdt if pdt != "compute"
+                                           else None),
+                        )
+                if mkind != precond_mod.NONE and telemetry.enabled():
+                    # the host-side record that this dispatch's program
+                    # factorizes/applies M in-trace (the numeric build is
+                    # compiled into the bucket program)
+                    telemetry.record(
+                        "precond.apply", precond=mkind, lanes=nb,
+                        solver=solver, bucket=bkt,
                     )
-            # sampled timed dispatch (ISSUE 12): every Nth dispatch
-            # takes ONE extra timestamp at the dispatch-return boundary
-            # so the solve wall clock splits into host (async dispatch)
-            # vs device (results-ready wait) time. Off (the default)
-            # takes no timestamp at all; the program and its plan-cache
-            # key are identical either way.
-            if mkind != precond_mod.NONE and telemetry.enabled():
-                # the host-side record that this dispatch's program
-                # factorizes/applies M in-trace (the numeric build is
-                # compiled into the bucket program)
-                telemetry.record(
-                    "precond.apply", precond=mkind, lanes=nb,
-                    solver=solver, bucket=bkt,
-                )
-            self._dispatch_seq += 1
+            # sampled timed dispatch (ISSUE 12): every Nth dispatch reads
+            # the dispatch-return boundary too, so the solve wall clock
+            # splits into host (async dispatch) vs device (results-ready
+            # wait) time. The program and its plan-cache key are
+            # identical either way.
+            self._dispatch_seq = seq
             sampled = (
-                self.profile_every > 0
-                and self._dispatch_seq % self.profile_every == 0
+                self.profile_every > 0 and seq % self.profile_every == 0
             )
-            t_solve0 = time.monotonic()
-            out = prog(*args)
-            t_dispatched = time.monotonic() if sampled else None
+            with telemetry.span("session.call", **tag) as sp:
+                t_solve0 = _at(sp.t0)
+                out = prog(*args)
+            t_dispatched = _at(sp.t1) if sampled else None
         except Exception as e:  # noqa: BLE001 - degrade, don't strand
             # elastic detection, dispatch-failure trigger (ISSUE 20): a
             # classified topology error revalidates the mesh — when the
@@ -1874,8 +1903,8 @@ class SolveSession:
             return None
         return _InFlight(
             reqs, dt, solver, allow_requeue, plan, key, bkt, nb, out,
-            built, snap, t0, t_packed, t_solve0, t_dispatched, sampled,
-            policy=pol, auto=auto,
+            built, snap, seq, t0, t_packed, t_solve0, t_dispatched,
+            sampled, policy=pol, auto=auto,
         )
 
     def _degrade(self, reqs, dt, solver, nb, e) -> None:
@@ -1917,37 +1946,55 @@ class SolveSession:
                     self._finalize_ticket(r.ticket)
 
     def _retire_scoped(self, fl: _InFlight) -> None:
+        tag = (
+            {"seq": fl.seq, "bucket": fl.bkt, "lanes": fl.nb}
+            if telemetry.enabled() else {}
+        )
+        try:
+            # the wait for the device and nothing else: the one number
+            # that tells a host-bound pipeline from a device-bound one
+            with telemetry.span("session.device_wait", **tag) as sp:
+                try:
+                    jax.block_until_ready(fl.out)
+                except Exception:
+                    pass  # non-jax leaves (ints): np.asarray blocks below
+            t_solved = _at(sp.t1)
+            with telemetry.span("session.readback", **tag) as sp:
+                # IR bucket programs (ISSUE 15) return a 5th output: the
+                # shared refinement-sweep count
+                if len(fl.out) == 5:
+                    X, iters, resid2, conv, ir_outer = fl.out
+                    ir_outer = int(np.asarray(ir_outer))
+                else:
+                    X, iters, resid2, conv = fl.out
+                    ir_outer = None
+                X = np.asarray(X)
+                iters = np.asarray(iters)
+                resid2 = np.asarray(resid2)
+                conv = np.asarray(conv)
+        except Exception as e:  # noqa: BLE001 - degrade, don't strand
+            self._degrade(fl.reqs, fl.dt, fl.solver, fl.nb, e)
+            return
+        t_read = _at(sp.t1)
+        fl.out = None  # release device buffers promptly
+        with telemetry.span("session.scatter", **tag):
+            self._scatter(fl, X, iters, resid2, conv, ir_outer,
+                          t_solved, t_read)
+
+    def _scatter(self, fl: _InFlight, X, iters, resid2, conv, ir_outer,
+                 t_solved, t_read) -> None:
+        """Results to tickets, requeue decisions, accounting, events and
+        finalisation of one retired bucket. ``t_solved`` is where
+        ``session.device_wait`` ended, ``t_read`` where
+        ``session.readback`` did."""
         reqs, dt, solver, plan = fl.reqs, fl.dt, fl.solver, fl.plan
         nb, bkt, key = fl.nb, fl.bkt, fl.key
-        try:
-            try:
-                jax.block_until_ready(fl.out)
-            except Exception:
-                pass  # non-jax leaves (ints) — np.asarray blocks below
-            t_solved = time.monotonic()
-            # IR bucket programs (ISSUE 15) return a 5th output: the
-            # shared refinement-sweep count
-            if len(fl.out) == 5:
-                X, iters, resid2, conv, ir_outer = fl.out
-                ir_outer = int(np.asarray(ir_outer))
-            else:
-                X, iters, resid2, conv = fl.out
-                ir_outer = None
-            X = np.asarray(X)
-            iters = np.asarray(iters)
-            resid2 = np.asarray(resid2)
-            conv = np.asarray(conv)
-        except Exception as e:  # noqa: BLE001 - degrade, don't strand
-            self._degrade(reqs, dt, solver, nb, e)
-            return
-        fl.out = None  # release device buffers promptly
         if ir_outer is not None:
             _metrics.counter(
                 "mixed.ir_outer_iters",
                 help="iterative-refinement outer sweeps across all IR "
                 "solves",
             ).inc(ir_outer)
-        t_read = time.monotonic()
         profile_ms = None
         if fl.sampled:
             profile_ms = (
@@ -2089,11 +2136,12 @@ class SolveSession:
             ]
             cache_d = plan_cache.delta(fl.snap)
             telemetry.record(
-                "batch.dispatch", solver=solver, batch=nb,
+                "batch.dispatch", seq=fl.seq, solver=solver, batch=nb,
                 bucket=bkt, pad_waste=bkt - nb,
                 queue_ms_max=round(max(q_ms), 3),
                 queue_ms_mean=round(sum(q_ms) / len(q_ms), 3),
-                dispatch_ms=round((time.monotonic() - fl.t0) * 1e3, 3),
+                # to this event, inside session.scatter; not a span's end
+                dispatch_ms=round((telemetry.clock() - fl.t0) * 1e3, 3),
                 solve_ms=round(solve_ms, 3),
                 compile_ms=round(compile_ms, 3),
                 program=key,
@@ -2508,9 +2556,12 @@ class SolveSession:
             vals = pack.pack_values(values)
 
             def mv(X):
-                return spmv_ops.csr_spmv_sell_batched(
-                    idx_slabs, vals, pos, X, zero_rows
-                )
+                # names the matvec's ops in the device trace (`op_name`);
+                # krylov scopes the loop's `bucket.dots`/`bucket.axpy`
+                with jax.named_scope("bucket.matvec"):
+                    return spmv_ops.csr_spmv_sell_batched(
+                        idx_slabs, vals, pos, X, zero_rows
+                    )
 
             fmv = krylov._maybe_faulty_mv(mv)
             # batched numeric factorization from THIS dispatch's value

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .base import SparseArray
 from .coverage import track_provenance
 from .config import settings
@@ -330,7 +331,11 @@ class csr_array(SparseArray):
             return None
         # the cache is written only by a detection that ran to its end: a
         # failed fetch raises and leaves _dia unchecked, never "not banded"
-        with host_scope():  # one-time eager analysis: on the host
+        # one-time eager analysis, on the host. The span is the host's time
+        # in it and does not wait for the planes' last ops: they overlap
+        # what the caller does next (the first solve's compile), and a wait
+        # here would put them in its way (6 s at 3200^2, PERF.md section 5)
+        with telemetry.span("layout.dia_build"), host_scope():
             self._dia = self._maybe_dia_detect(m, n, nnz)
         return self._dia
 

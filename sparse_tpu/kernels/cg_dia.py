@@ -373,6 +373,7 @@ def cg_dia_fused_onepass(
 
     kern = pl.pallas_call(
         _kernel_cgcg(offsets, TM, B, win, D, m_pad),
+        name="cg_dia_onepass",
         grid=(G + 2,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * 6,
@@ -490,16 +491,20 @@ def cg_dia_fused(
     D = len(offsets)
 
     pdt = _resolve_plane_dtype(plane_dtype, dt, TM)
-    planes_row = _row_planes(data.astype(pdt), offsets, TM, B, G, m)
-    bp = _pad_vec(b.astype(dt), TM, G)
-    xp = (
-        jnp.zeros(((G + 2) * TM,), dt)
-        if x0 is None
-        else _pad_vec(x0.astype(dt), TM, G)
-    )
+    # every call re-packs the planes into the kernels' row layout and pads
+    # the vectors: the scope names that work in each op's `op_name`
+    with jax.named_scope("cg_dia.repack"):
+        planes_row = _row_planes(data.astype(pdt), offsets, TM, B, G, m)
+        bp = _pad_vec(b.astype(dt), TM, G)
+        xp = (
+            jnp.zeros(((G + 2) * TM,), dt)
+            if x0 is None
+            else _pad_vec(x0.astype(dt), TM, G)
+        )
 
     kA = pl.pallas_call(
         _kernel_a(offsets, TM, B, win, D, m_pad),
+        name="cg_dia_a",
         grid=(G + 2,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -535,6 +540,7 @@ def cg_dia_fused(
 
     kB = pl.pallas_call(
         _kernel_b(),
+        name="cg_dia_b",
         grid=(G + 2,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

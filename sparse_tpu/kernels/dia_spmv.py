@@ -206,6 +206,7 @@ def dia_spmv_packed(planes_flat, x_padded, plan: DiaPlan, interpret: bool = Fals
 
     return pl.pallas_call(
         kernel,
+        name="dia_spmv_packed",
         grid=(G,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -633,6 +634,7 @@ def _dia_spmv_pallas(
 
     y = pl.pallas_call(
         kernel,
+        name="dia_spmv_pallas",
         grid=(G,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),

@@ -225,6 +225,7 @@ def _sell_slab_pallas(idx_t, val_t, x, K: int, TM: int, interpret: bool = False,
 
     return pl.pallas_call(
         kernel,
+        name="sell_slab_pallas",
         grid=(R // TM,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),  # x resident whole
@@ -292,6 +293,7 @@ def _sell_slab_pallas_batched(idx_t, val_bt, X, K: int, TM: int,
 
     return pl.pallas_call(
         kernel,
+        name="sell_slab_pallas_batched",
         grid=(B, R // TM),
         in_specs=[
             # one lane of x resident per grid step

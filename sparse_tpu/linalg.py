@@ -602,44 +602,64 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
     tol2 = float(tol) ** 2
     chunk = max(int(conv_test_iters), 1)
     state = None
-    iters = 0
+    iters = chunks = 0
+    dispatch_s = fetch_s = 0.0
     x = None
     rho_f = None
-    while iters < maxiter:
-        if _faults.ACTIVE:
-            # chunk boundaries are the preemption points this loop
-            # survives (the carry state is host-visible here)
-            _faults.check_preempt("cg.fused.chunk")
-        # mirror _cg_device_loop's test points exactly: every conv_test
-        # iterations AND at iters == maxiter - 1 (so a solve converging at
-        # the last test reports maxiter-1, not maxiter). The off-size last
-        # chunks add at most two extra trace shapes, only for solves that
-        # actually reach maxiter.
-        k = min(chunk, max(maxiter - 1 - iters, 1))
-        k = min(k, maxiter - iters)
-        x, _r, rho, state = cg_dia_fused(
-            planes, offsets, b, x0, m, iters=k, tile=tile,
-            state=state, return_state=True, interpret=interpret,
-        )
-        iters += k
-        rho_f = float(rho)
-        if telemetry.enabled():
-            # one event per conv-test chunk, reusing the rho scalar this
-            # loop already fetches — per-chunk granularity, zero extra
-            # syncs on the fused fast path
-            telemetry.record(
-                "solver.iter", solver="cg", path="fused", iter=iters,
-                resid2=rho_f, chunk=k,
-            )
-            telemetry.health.observe("cg", iters, rho_f, path="fused")
-        if not np.isfinite(rho_f):
-            # a nonfinite rho is a BREAKDOWN exit, not convergence: flag
-            # it so callers (and the recovery policy via the health
-            # report) can tell the two apart (ISSUE 5 satellite)
-            return x, iters, rho_f, -1
-        if rho_f < tol2:
-            return x, iters, rho_f, 0
-    info = 0 if (rho_f is not None and rho_f < tol2) else iters
+    info = None
+    # One `cg.solve` span a call. Per chunk, `cg.chunk` is the kernel
+    # program's call until it returns (asynchronous: the host's part of
+    # dispatching it) and `cg.rho_fetch` the wait for its rho on the host;
+    # both are trace annotations and aggregates only, and their sums go
+    # onto the solve's event as `dispatch_s` and `fetch_s`.
+    with telemetry.span("cg.solve", path="fused") as solve:
+        while iters < maxiter:
+            if _faults.ACTIVE:
+                # chunk boundaries are the preemption points this loop
+                # survives (the carry state is host-visible here)
+                _faults.check_preempt("cg.fused.chunk")
+            # mirror _cg_device_loop's test points exactly: every
+            # conv_test iterations AND at iters == maxiter - 1 (so a solve
+            # converging at the last test reports maxiter-1, not maxiter).
+            # The off-size last chunks add at most two extra trace shapes,
+            # only for solves that actually reach maxiter.
+            k = min(chunk, max(maxiter - 1 - iters, 1))
+            k = min(k, maxiter - iters)
+            with telemetry.span("cg.chunk", emit=False) as sp:
+                x, _r, rho, state = cg_dia_fused(
+                    planes, offsets, b, x0, m, iters=k, tile=tile,
+                    state=state, return_state=True, interpret=interpret,
+                )
+            dispatch_s += sp.dur_s or 0.0
+            iters += k
+            chunks += 1
+            with telemetry.span("cg.rho_fetch", emit=False) as sp:
+                rho_f = float(rho)
+            fetch_s += sp.dur_s or 0.0
+            if telemetry.enabled():
+                # one event per conv-test chunk, reusing the rho scalar
+                # this loop already fetches — per-chunk granularity, zero
+                # extra syncs on the fused fast path
+                telemetry.record(
+                    "solver.iter", solver="cg", path="fused", iter=iters,
+                    resid2=rho_f, chunk=k,
+                )
+                telemetry.health.observe("cg", iters, rho_f, path="fused")
+            if not np.isfinite(rho_f):
+                # a nonfinite rho is a BREAKDOWN exit, not convergence:
+                # flag it so callers (and the recovery policy via the
+                # health report) can tell the two apart (ISSUE 5 satellite)
+                info = -1
+                break
+            if rho_f < tol2:
+                info = 0
+                break
+        if solve.t0 is not None:  # live: telemetry on, not under a trace
+            solve.annotate(chunks=chunks, iters=iters,
+                           dispatch_s=round(dispatch_s, 9),
+                           fetch_s=round(fetch_s, 9))
+    if info is None:  # maxiter exhausted
+        info = 0 if (rho_f is not None and rho_f < tol2) else iters
     return x, iters, rho_f, info
 
 

@@ -54,13 +54,12 @@ tol`` for CG/BiCGStab, ``max(tol * ||b||, atol)`` for GMRES).
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from ..config import settings
-from ..telemetry import _metrics
+from ..telemetry import _metrics, clock
 from . import faults
 
 __all__ = [
@@ -81,7 +80,8 @@ ESCALATION = {"cg": "bicgstab", "bicgstab": "gmres", "gmres": "gmres"}
 def deadline_remaining_s(t_start: float, deadline_s,
                          now: float | None = None) -> float:
     """Seconds left in a wall-clock budget measured from ``t_start``
-    (``time.monotonic`` base); ``inf`` when ``deadline_s`` is ``None``.
+    (a reading of ``telemetry.clock``, the program's one clock); ``inf``
+    when ``deadline_s`` is ``None``.
 
     The shared deadline arithmetic of the resilience surfaces: the
     recovery ladder's between-attempt gate here, and the batch
@@ -92,7 +92,7 @@ def deadline_remaining_s(t_start: float, deadline_s,
     deadline."""
     if deadline_s is None:
         return math.inf
-    now = time.monotonic() if now is None else now
+    now = clock() if now is None else now
     return float(deadline_s) - (now - float(t_start))
 
 
@@ -270,7 +270,7 @@ def solve_with_recovery(
     target = float(tol) * max(bnorm, 1.0) if solver == "gmres" else float(tol)
 
     verify_target = target * max(float(pol.verify_factor), 1.0)
-    t0 = time.monotonic()
+    t0 = clock()
     cur_solver = solver
     cur_x0 = x0
     cur_M = M  # dropped (set None) by the drop-preconditioner rung

@@ -114,6 +114,7 @@ from ._recorder import (  # noqa: F401
     add_bytes,
     add_span,
     bytes_by_kind,
+    clock,
     configure,
     count,
     counters,
@@ -181,6 +182,7 @@ __all__ = [
     "stop_history",
     "bytes_by_kind",
     "capture_now",
+    "clock",
     "configure",
     "cost",
     "count",

@@ -1,5 +1,14 @@
 """Scoped wall-clock + device-sync timers (``span("cg.iter")``).
 
+A live span is three things at once, under one name: a
+``jax.profiler.TraceAnnotation`` for its extent (with a profile running it
+is an event on the ``python3`` line of ``/host:CPU`` in the same
+``.xplane.pb`` as the device's ops, on the same axis — the shared clock,
+with nothing to align afterwards; with no profile running a level test), a
+duration in the p50/p95 aggregates, and (``emit=True``) a ``span`` event
+that carries its start ``t0`` on the events' ``tm`` axis next to ``dur_s``.
+The trace carries the bare name; the fields live on the event.
+
 Trace safety is the defining constraint: library code wraps hot paths
 that are routinely re-entered under ``jit``/``vmap``/``scan`` tracing,
 where (a) wall-clock around tracer ops measures trace construction, not
@@ -15,10 +24,10 @@ the middle of user computations.
 
 from __future__ import annotations
 
-import time
+from jax.profiler import TraceAnnotation
 
 from ..config import settings
-from . import _metrics, _recorder
+from . import _context, _metrics, _recorder
 
 # Failed best-effort device syncs used to vanish silently (ISSUE 12
 # satellite): a backend erroring inside block_until_ready is exactly the
@@ -32,9 +41,11 @@ _SYNC_ERRORS = _metrics.counter(
 
 
 class _NullSpan:
-    """Shared disabled/traced span: every method is a no-op."""
+    """Shared disabled/traced span: every method is a no-op, and it holds
+    no time (``t0``/``dur_s``/``t1`` are ``None``)."""
 
     __slots__ = ()
+    t0 = dur_s = t1 = None
 
     def __enter__(self):
         return self
@@ -53,16 +64,27 @@ _NULL = _NullSpan()
 
 
 class Span:
-    """One timed scope. Use via :func:`span`; not constructed directly."""
+    """One timed scope. Use via :func:`span`; not constructed directly.
 
-    __slots__ = ("name", "fields", "_t0", "_sync", "emit")
+    ``t0`` (a reading of ``telemetry.clock``, set at entry) and ``dur_s``
+    (set at exit) stay readable afterwards, so a caller that needs the
+    instants for its own arithmetic reads them here and takes no
+    timestamp of its own."""
+
+    __slots__ = ("name", "fields", "t0", "dur_s", "_sync", "emit", "_ann")
 
     def __init__(self, name: str, fields: dict, sync, emit: bool):
         self.name = name
         self.fields = fields
         self._sync = sync
         self.emit = emit
-        self._t0 = None
+        self.t0 = self.dur_s = None
+        self._ann = TraceAnnotation(name)
+
+    @property
+    def t1(self):
+        """The span's end on ``telemetry.clock`` (``None`` until exit)."""
+        return None if self.dur_s is None else self.t0 + self.dur_s
 
     def annotate(self, **fields):
         """Attach fields to the span's event after entry (e.g. results
@@ -77,7 +99,9 @@ class Span:
         return value
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        _recorder.session_info()  # the tm base precedes the first start
+        self._ann.__enter__()
+        self.t0 = _recorder.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -93,16 +117,24 @@ class Span:
                 # sync stays best-effort (the wall clock still stands),
                 # but the failure is counted — see _SYNC_ERRORS
                 _SYNC_ERRORS.inc()
-        dur = time.perf_counter() - self._t0
+        self.dur_s = dur = _recorder.clock() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
         _recorder.add_span(self.name, dur)
         if self.emit:
-            _recorder.record(
-                "span",
-                name=self.name,
-                dur_s=round(dur, 9),
-                **({"error": exc_type.__name__} if exc_type else {}),
-                **self.fields,
-            )
+            # a span times a scope of the program (a bucket, a solve, a
+            # build), which its own fields identify: it is recorded
+            # outside the ticket scope, so that a bucket's seven spans do
+            # not each repeat its lanes' ids. ``batch.dispatch`` carries
+            # the same ``seq`` and the tickets.
+            with _context.ticket_scope():
+                _recorder.record(
+                    "span",
+                    name=self.name,
+                    t0=_recorder.tm_of(self.t0),
+                    dur_s=round(dur, 9),
+                    **({"error": exc_type.__name__} if exc_type else {}),
+                    **self.fields,
+                )
         return False
 
 
@@ -110,11 +142,12 @@ def span(name: str, sync=None, emit: bool = True, **fields):
     """Scoped timer: ``with span("cg.iter"): ...``.
 
     Returns a shared no-op context when telemetry is disabled or a jax
-    trace is active (see module docstring). When live, records the
-    duration into the p50/p95 aggregates and (``emit=True``) emits a
-    ``span`` event. ``sync`` is an optional array/pytree blocked on at
-    exit so device work attributes to the span rather than a later
-    fence; pass ``emit=False`` for hot scopes that should aggregate
+    trace is active (see module docstring). When live, annotates the
+    profiler's trace with ``name``, records the duration into the
+    p50/p95 aggregates and (``emit=True``) emits a ``span`` event.
+    ``sync`` is an optional array/pytree blocked on at exit so device
+    work attributes to the span rather than a later fence; pass
+    ``emit=False`` for hot scopes that should aggregate (and annotate)
     without flooding the event log.
     """
     if not settings.telemetry:

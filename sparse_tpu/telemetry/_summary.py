@@ -43,14 +43,14 @@ def summary() -> dict:
         k = e.get("kind", "?")
         by_kind[k] = by_kind.get(k, 0) + 1
     spans = {}
-    for name, durs in _recorder.span_durations().items():
-        ds = sorted(durs)
+    for name, (n, total, mx, durs) in _recorder.span_aggregates().items():
+        ds = sorted(durs)  # the last `telemetry_ring` spans of this name
         spans[name] = {
-            "n": len(ds),
-            "total_s": round(sum(ds), 6),
+            "n": n,
+            "total_s": round(total, 6),
             "p50_s": round(_percentile(ds, 0.50), 6),
             "p95_s": round(_percentile(ds, 0.95), 6),
-            "max_s": round(ds[-1], 6) if ds else 0.0,
+            "max_s": round(mx, 6),
         }
     return {
         "enabled": _recorder.enabled(),

@@ -10,11 +10,12 @@ plan_cache, batch, bench, spans, resilience, tickets) with named
 *thread* tracks inside it (per solver, per event kind, per span family,
 per ticket). Mapping:
 
-* ``span`` events become complete (``"X"``) slices — the recorder stamps
-  a span at *exit* with its duration, so the slice start is
-  ``ts - dur_s`` and nesting falls out of containment (an inner span
-  both starts later and ends earlier than its parent on the same
-  track).
+* ``span`` events become complete (``"X"``) slices from their recorded
+  start: the event carries the span's ``t0`` on the same ``tm`` axis as
+  its own stamp, so the slice starts at ``ts - (tm - t0)`` and nesting
+  falls out of containment (an inner span both starts later and ends
+  earlier than its parent on the same track). A ``span`` event without
+  a recorded start is a mark like any other event.
 * ``solver.iter`` events additionally feed a per-solver ``resid2``
   counter track (``"C"``), so convergence plots right under the
   iteration marks.
@@ -149,13 +150,15 @@ def to_chrome_trace(events) -> dict:
         args = {
             k: v for k, v in ev.items() if k not in ("kind", "ts")
         }
-        if kind == "span":
+        t0, tm = _num(ev.get("t0")), _num(ev.get("tm"))
+        if kind == "span" and t0 is not None and tm is not None:
             dur = _num(ev.get("dur_s"))
-            dur_us = max(dur * 1e6, 0.0) if dur is not None else 0.0
             trace_events.append({
                 "ph": "X", "name": str(ev.get("name", "span")),
                 "cat": "span", "pid": pid, "tid": tid,
-                "ts": ts_us - dur_us, "dur": dur_us, "args": args,
+                "ts": ts_us - (tm - t0) * 1e6,
+                "dur": max(dur * 1e6, 0.0) if dur is not None else 0.0,
+                "args": args,
             })
             continue
         if kind == "batch.ticket":

@@ -178,6 +178,51 @@ def test_served_bucket_program_compiles(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# names on the device (PR 25): what a profile of the chip can print. The
+# kernels' `name=` becomes the HLO instruction's name (it was
+# `closed_call.N`), the scopes go into each op's `op_name` metadata.
+# ---------------------------------------------------------------------------
+def test_cg_dia_fused_kernels_and_repack_are_named(one_chip):
+    from sparse_tpu.kernels.cg_dia import cg_dia_fused
+
+    g = 512
+    n, offsets = g * g, (-g, -1, 0, 1, g)
+    lowered = cg_dia_fused.lower(
+        _sds((len(offsets), n), jnp.float32, one_chip), offsets,
+        _sds((n,), jnp.float32, one_chip), None, n, iters=25,
+        tile=_linalg_cg_tile(len(offsets)), state=None, return_state=True,
+        interpret=False,
+    )
+    text = lowered.as_text()
+    assert "cg_dia_a" in text and "cg_dia_b" in text
+    hlo = lowered.compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert any(ln.lstrip().startswith("%cg_dia_a") for ln in calls)
+    assert any(ln.lstrip().startswith("%cg_dia_b") for ln in calls)
+    assert "jit(cg_dia_fused)/cg_dia.repack/" in hlo
+
+
+def test_bucket_program_ops_carry_their_scope(one_chip, monkeypatch):
+    from sparse_tpu.batch import service
+
+    monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    A = _five_point(64)
+    n, B = A.shape[0], 4
+    ses = service.SolveSession("cg", batch_max=B, warm_start=False)
+    pattern = ses.pattern_of(A)
+    run = ses._build_program(pattern, B, np.dtype(np.float32))
+    hlo = run.lower(
+        _sds((B, pattern.nnz), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile().as_text()
+    for scope in ("bucket.matvec", "bucket.dots", "bucket.axpy"):
+        assert f"/{scope}/" in hlo, scope
+
+
+# ---------------------------------------------------------------------------
 # four chips: make_dist_cg's program over a Mesh of described devices
 # ---------------------------------------------------------------------------
 def test_dist_cg_program_compiles_on_four_chips(chip):

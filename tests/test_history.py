@@ -309,22 +309,24 @@ def test_tenant_attribution_solves(tel):
     A = _tridiag()
     b = np.ones(A.shape[0])
     ses = SolveSession("cg", slo_ms=10_000.0)
-    ses.submit(A, b, tol=1e-8, tenant="acme")
-    ses.submit(A, b, tol=1e-8, tenant="acme")
-    ses.submit(A, b, tol=1e-8, tenant="zeta")
+    # the usage families are always-on and outlive a test: tenants of this
+    # test's own, so that its counts do not depend on what ran before it
+    ses.submit(A, b, tol=1e-8, tenant="hist-acme")
+    ses.submit(A, b, tol=1e-8, tenant="hist-acme")
+    ses.submit(A, b, tol=1e-8, tenant="hist-zeta")
     ses.submit(A, b, tol=1e-8)  # untagged -> the '-' bucket
     ses.drain()
     usage = _budget.usage_stats()
-    assert usage["acme"]["tickets"] == 2
-    assert usage["zeta"]["tickets"] == 1
+    assert usage["hist-acme"]["tickets"] == 2
+    assert usage["hist-zeta"]["tickets"] == 1
     assert usage["-"]["tickets"] >= 1
-    assert usage["acme"].get("device_ms", 0.0) >= 0.0
+    assert usage["hist-acme"].get("device_ms", 0.0) >= 0.0
     stats = ses.session_stats()
-    assert stats["usage"]["acme"]["tickets"] == 2
+    assert stats["usage"]["hist-acme"]["tickets"] == 2
     # tenant-labeled latency series exist only for tagged tickets
     fam = _metrics.family("batch.ticket_latency")
     tenants = {m.labels.get("tenant") for m in fam}
-    assert "acme" in tenants and "zeta" in tenants
+    assert "hist-acme" in tenants and "hist-zeta" in tenants
 
 
 def test_ingest_ticket_event_and_metering(tel):

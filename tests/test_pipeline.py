@@ -229,6 +229,23 @@ def test_unexpired_unconverged_lane_still_requeues():
     assert np.linalg.norm(mats[0] @ x - rhs[0]) < 1e-6
 
 
+def test_result_follows_a_requeue_still_in_flight(monkeypatch):
+    """The first bucket is not ready when ``flush(wait=False)`` polls, so
+    ``result()`` retires it, which requeues the lane into a fallback
+    bucket that stays in the window: ``result()`` goes on to that one."""
+    mats, rhs = _systems(B=1)
+    ses = SolveSession("cg", inflight=4, requeue=True, warm_start=False)
+    t = ses.submit(mats[0], rhs[0], tol=1e-10, maxiter=1)
+    with monkeypatch.context() as m:
+        m.setattr(_InFlight, "is_ready", lambda self: False)
+        ses.flush(wait=False)
+    assert t.t_done is None and len(ses._inflight) == 1
+    x, _iters, _r2 = t.result()
+    assert t.requeued and t.converged and t.solver == "gmres"
+    assert np.linalg.norm(mats[0] @ x - rhs[0]) < 1e-6
+    assert not ses._inflight
+
+
 # ---------------------------------------------------------------------------
 # (d) admission control
 # ---------------------------------------------------------------------------

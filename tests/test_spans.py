@@ -1,0 +1,380 @@
+"""The span primitive and its sites (PR 25).
+
+One primitive, ``telemetry.span``: a live span is a
+``jax.profiler.TraceAnnotation`` (so it lies on the device trace's own
+axis), a bounded aggregate, and an event that carries its recorded start.
+Its sites: the session's seven phases of a dispatch, the fused CG's solve
+with its per-chunk dispatch and ``rho`` fetch, and the library entry's
+DIA plane build. With telemetry off every site gets the shared no-op.
+"""
+
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+import jax
+
+import sparse_tpu
+from sparse_tpu import linalg, telemetry
+from sparse_tpu.batch import SolveSession
+from sparse_tpu.config import settings
+from sparse_tpu.telemetry import _recorder
+from sparse_tpu.telemetry._spans import _NULL, Span
+
+SESSION_SPANS = (
+    "session.pack", "session.upload", "session.plan", "session.call",
+    "session.device_wait", "session.readback", "session.scatter",
+)
+
+
+@pytest.fixture
+def tel(tmp_path, monkeypatch):
+    telemetry.reset()
+    monkeypatch.setattr(settings, "telemetry", True)
+    telemetry.configure(str(tmp_path / "records.jsonl"))
+    yield tmp_path / "records.jsonl"
+    telemetry.configure(None)
+    telemetry.reset()
+
+
+@pytest.fixture
+def off(monkeypatch):
+    telemetry.reset()
+    monkeypatch.setattr(settings, "telemetry", False)
+    yield
+    telemetry.reset()
+
+
+def _tridiag(n):
+    e = np.ones(n)
+    return sp.diags([-e[:-1], 2.5 * e, -e[:-1]], [-1, 0, 1]).tocsr()
+
+
+def _two_buckets(ses, n=300, lanes=4, buckets=2, seed=0):
+    S = _tridiag(n)
+    pat = ses.pattern_of(sparse_tpu.csr_array(S))
+    rng = np.random.default_rng(seed)
+    for _ in range(buckets):
+        ts = [
+            ses.submit(S.data * (1 + 0.1 * i), rng.standard_normal(n),
+                       tol=1e-8, pattern=pat)
+            for i in range(lanes)
+        ]
+        ses.flush()
+        for t in ts:
+            assert t.result()[0].shape == (n,)
+
+
+def _pde(g=24):
+    n = g * g
+    a = np.full(n - 1, -1.0, np.float32)
+    a[g - 1::g] = 0.0
+    far = np.full(n - g, -1.0, np.float32)
+    c = np.full(n, 4.0, np.float32)
+    A = sparse_tpu.diags([far, a, c, a, far], [-g, -1, 0, 1, g],
+                         shape=(n, n))
+    return A, np.ones(n, np.float32)
+
+
+# -- the primitive -----------------------------------------------------------
+def test_one_clock_for_spans_tickets_and_deadlines(tel):
+    assert telemetry.clock is time.monotonic
+    before = telemetry.clock()
+    with telemetry.span("t.clock") as sp_:
+        pass
+    ses = SolveSession("cg", warm_start=False)
+    S = _tridiag(16)
+    t = ses.submit(S, np.ones(16), tol=1e-8)
+    after = telemetry.clock()
+    assert before <= sp_.t0 <= sp_.t1 <= t.t_submit <= after
+    ses.flush()
+    assert before <= t.t_submit <= t.t_done
+
+
+def test_span_holds_its_start_and_duration_after_exit(tel):
+    with telemetry.span("t.held", n=3) as sp_:
+        assert isinstance(sp_, Span)
+        assert sp_.t0 is not None and sp_.dur_s is None and sp_.t1 is None
+        time.sleep(0.01)
+    assert sp_.dur_s >= 0.01
+    assert sp_.t1 == pytest.approx(sp_.t0 + sp_.dur_s)
+    assert _NULL.t0 is None and _NULL.dur_s is None and _NULL.t1 is None
+
+
+def test_span_event_carries_its_recorded_start_on_the_tm_axis(tel):
+    with telemetry.span("t.start", n=3) as sp_:
+        time.sleep(0.01)
+    (ev,) = telemetry.events("span")
+    base = telemetry.session_info()["mono"]
+    assert ev["t0"] == pytest.approx(sp_.t0 - base, abs=2e-6)
+    assert ev["dur_s"] == pytest.approx(sp_.dur_s, abs=1e-8)
+    # recorded at exit: the event's own stamp lies at or after the end
+    assert ev["t0"] + ev["dur_s"] <= ev["tm"] + 2e-6
+    assert ev["n"] == 3 and not telemetry.schema.validate(ev)
+
+
+def test_trace_export_places_a_span_at_its_recorded_start(tel):
+    with telemetry.span("t.outer"):
+        time.sleep(0.005)
+        # the sync at exit belongs to the span, the record after it does not
+    (ev,) = telemetry.events("span")
+    made = {"kind": "span", "ts": 50.0, "tm": 7.0, "name": "old", "dur_s": 1.0}
+    tr = telemetry.to_chrome_trace([ev, made])["traceEvents"]
+    (sl,) = [e for e in tr if e["ph"] == "X"]
+    assert sl["name"] == "t.outer"
+    assert sl["ts"] == pytest.approx(
+        ev["ts"] * 1e6 - (ev["tm"] - ev["t0"]) * 1e6)
+    assert sl["dur"] == pytest.approx(ev["dur_s"] * 1e6)
+    # no recorded start, no inferred one: a mark like any other event
+    (mark,) = [e for e in tr if e["ph"] == "i"]
+    assert mark["name"] == "span" and mark["ts"] == 50.0 * 1e6
+
+
+def test_span_aggregates_stay_bounded_with_exact_count_and_total(
+        tel, monkeypatch):
+    monkeypatch.setattr(settings, "telemetry_ring", 16)
+    for i in range(100):
+        telemetry.add_span("t.loop", 0.5 + i)
+    n, total, mx, sample = _recorder.span_aggregates()["t.loop"]
+    assert (n, total, mx) == (100, sum(0.5 + i for i in range(100)), 99.5)
+    assert sample == [0.5 + i for i in range(84, 100)]
+    assert len(_recorder._SPANS["t.loop"][3]) == 16
+    s = telemetry.summary()["spans"]["t.loop"]
+    assert s["n"] == 100 and s["total_s"] == pytest.approx(total)
+    assert s["max_s"] == 99.5 and 84.5 <= s["p50_s"] <= 99.5
+
+
+def test_span_events_are_not_stamped_with_the_ticket_scope(tel):
+    with telemetry.ticket_scope("tk-x"):
+        with telemetry.span("t.scoped"):
+            telemetry.record("solver.iter", solver="cg", iter=1)
+    (ev,) = telemetry.events("span")
+    assert "tickets" not in ev
+    assert telemetry.events("solver.iter")[0]["tickets"] == ["tk-x"]
+
+
+# -- a span is a TraceAnnotation ---------------------------------------------
+def _host_annotations(trace_dir, prefix):
+    from jax.profiler import ProfileData
+
+    (pb,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(e.name, float(e.start_ns), float(e.duration_ns))
+                    for e in line.events if e.name.startswith(prefix)]
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_session_spans_lie_on_the_profile_in_order(tel, tmp_path):
+    ses = SolveSession("cg", inflight=1, warm_start=False)
+    _two_buckets(ses, buckets=1, seed=1)  # compile outside the profile
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        _two_buckets(ses, buckets=2, seed=2)
+    finally:
+        jax.profiler.stop_trace()
+    anns = _host_annotations(str(tmp_path / "trace"), "session.")
+    assert [a[0] for a in anns] == list(SESSION_SPANS) * 2
+    for (_n0, s0, d0), (_n1, s1, _d1) in zip(anns, anns[1:]):
+        assert s0 + d0 <= s1  # in order and not overlapping
+    # the same extents as the events hold, on the profile's own axis
+    evs = [e for e in telemetry.events("span")
+           if e["name"] in SESSION_SPANS][-14:]
+    for (name, _s, d), ev in zip(anns, evs):
+        assert name == ev["name"]
+        assert d * 1e-9 >= ev["dur_s"]  # the annotation encloses the span
+        assert d * 1e-9 == pytest.approx(ev["dur_s"], abs=2e-3)
+
+
+# -- the session's sites -----------------------------------------------------
+def test_seven_session_spans_sum_to_the_dispatch(tel):
+    ses = SolveSession("cg", inflight=1, warm_start=False)
+    _two_buckets(ses, n=20000, lanes=8, buckets=3)
+    disp = {e["seq"]: e for e in telemetry.events("batch.dispatch")}
+    assert sorted(disp) == [1, 2, 3]
+    by_seq = {}
+    for e in telemetry.events("span"):
+        if e["name"].startswith("session."):
+            assert (e["bucket"], e["lanes"]) == (8, 8)
+            by_seq.setdefault(e["seq"], {})[e["name"]] = e
+    for seq, spans in by_seq.items():
+        assert tuple(spans) == SESSION_SPANS  # recorded in this order
+        total_ms = 1e3 * sum(e["dur_s"] for e in spans.values())
+        assert total_ms == pytest.approx(disp[seq]["dispatch_ms"], rel=0.05)
+        # the dispatch's tag and the span's own extent, nothing computed
+        # for the event's sake
+        for e in spans.values():
+            assert set(e) - {"kind", "ts", "tm", "pi", "pid", "name", "t0",
+                             "dur_s"} == {"seq", "bucket", "lanes"}
+
+
+def test_dispatch_and_phase_arithmetic_is_read_off_the_spans(tel):
+    ses = SolveSession("cg", inflight=1, warm_start=False)
+    S = _tridiag(5000)
+    pat = ses.pattern_of(sparse_tpu.csr_array(S))
+    ts = [ses.submit(S.data, np.ones(5000), tol=1e-8, pattern=pat)
+          for _ in range(4)]
+    ses.flush()
+    (d,) = telemetry.events("batch.dispatch")
+    sp_ = {e["name"]: e for e in telemetry.events("span")}
+    call, wait = sp_["session.call"], sp_["session.device_wait"]
+    plan = sp_["session.plan"]
+    read = sp_["session.readback"]
+    # solve_ms keeps its meaning: call start -> results ready
+    assert d["solve_ms"] == pytest.approx(
+        (wait["t0"] + wait["dur_s"] - call["t0"]) * 1e3, abs=0.01)
+    ph = ts[0].phase_ms
+    assert set(ph) == {"queue_ms", "pack_ms", "compile_ms", "solve_ms",
+                       "readback_ms"}
+    assert ph["solve_ms"] == pytest.approx(d["solve_ms"], abs=0.01)
+    assert ph["readback_ms"] == pytest.approx(
+        (read["t0"] + read["dur_s"] - wait["t0"] - wait["dur_s"]) * 1e3,
+        abs=0.01)
+    # queue and pack run from the submit to where session.plan begins
+    base = telemetry.session_info()["mono"]
+    assert ph["queue_ms"] + ph["pack_ms"] == pytest.approx(
+        (plan["t0"] - (ts[0].t_submit - base)) * 1e3, abs=0.01)
+    latency_ms = (ts[0].t_done - ts[0].t_submit) * 1e3
+    assert sum(ph.values()) <= latency_ms * 1.05
+
+
+def test_queue_ends_where_the_launch_begins(tel, monkeypatch):
+    """``phase_ms``' boundaries are those from before the spans: the queue
+    ends at ``_launch``'s entry, and ``pack_ms`` runs from there over the
+    fleet and bucket decisions, the stack, the upload, and the policy
+    decisions and the key, to where ``session.plan`` begins."""
+    from sparse_tpu.batch import service
+
+    ses = SolveSession("cg", inflight=1, warm_start=False)
+    decide, snapshot = ses.fleet.decide, service.plan_cache.snapshot
+
+    def slow_decide(*a, **kw):  # before session.pack
+        time.sleep(0.02)
+        return decide(*a, **kw)
+
+    def slow_snapshot():  # between session.upload and session.plan
+        time.sleep(0.02)
+        return snapshot()
+
+    monkeypatch.setattr(ses.fleet, "decide", slow_decide)
+    monkeypatch.setattr(service.plan_cache, "snapshot", slow_snapshot)
+    S = _tridiag(2000)
+    pat = ses.pattern_of(sparse_tpu.csr_array(S))
+    t = ses.submit(S.data, np.ones(2000), tol=1e-8, pattern=pat)
+    ses.flush()
+    (d,) = telemetry.events("batch.dispatch")
+    sp_ = {e["name"]: e for e in telemetry.events("span")}
+    pack, upload = sp_["session.pack"], sp_["session.upload"]
+    ph = t.phase_ms
+    submit = t.t_submit - telemetry.session_info()["mono"]
+    in_spans_ms = (pack["dur_s"] + upload["dur_s"]) * 1e3
+    assert ph["pack_ms"] >= 40.0 + in_spans_ms
+    assert ph["queue_ms"] <= (pack["t0"] - submit) * 1e3 - 20.0
+    assert d["queue_ms_max"] == pytest.approx(ph["queue_ms"], abs=0.01)
+    assert ph["compile_ms"] <= sp_["session.plan"]["dur_s"] * 1e3
+
+
+def test_sampled_dispatch_splits_at_the_call_spans_end(tel):
+    ses = SolveSession("cg", inflight=1, warm_start=False, profile_every=1)
+    _two_buckets(ses, buckets=1)
+    (d,) = telemetry.events("batch.dispatch")
+    call = next(e for e in telemetry.events("span")
+                if e["name"] == "session.call")
+    assert d["host_ms"] == pytest.approx(call["dur_s"] * 1e3, abs=0.01)
+    assert d["host_ms"] + d["device_ms"] == pytest.approx(
+        d["solve_ms"], abs=0.01)
+
+
+# -- the solver's and the library entry's sites ------------------------------
+def test_fused_cg_solve_is_one_span_event_with_its_chunks(tel, monkeypatch):
+    monkeypatch.setattr(settings, "fused_cg", "force")
+    A, b = _pde()
+    A = A.tocsr()
+    linalg.cg(A, b, maxiter=60)  # builds the layout, compiles
+    n0 = len(telemetry.events("span"))
+    agg0 = telemetry.summary()["spans"]
+    _x, iters = linalg.cg(A, b, maxiter=60)
+    evs = telemetry.events("span")[n0:]
+    assert [e["name"] for e in evs] == ["cg.solve"]  # one event a call
+    (ev,) = evs
+    assert ev["path"] == "fused" and ev["iters"] == int(iters)
+    assert ev["chunks"] == len(telemetry.events("solver.iter")) // 2
+    assert ev["chunks"] >= 2
+    assert 0 < ev["dispatch_s"] and 0 < ev["fetch_s"]
+    assert ev["dispatch_s"] + ev["fetch_s"] <= ev["dur_s"]
+    # the inner spans are annotations and aggregates, not events
+    agg = telemetry.summary()["spans"]
+    for name in ("cg.chunk", "cg.rho_fetch"):
+        assert agg[name]["n"] - agg0[name]["n"] == ev["chunks"]
+    assert agg["cg.chunk"]["total_s"] - agg0["cg.chunk"]["total_s"] == \
+        pytest.approx(ev["dispatch_s"], abs=1e-5)
+
+
+def test_layout_span_fires_once_an_operator(tel, monkeypatch):
+    monkeypatch.setattr(settings, "fused_cg", "force")
+    D, b = _pde()
+    A = D.tocsr()
+    assert telemetry.events("span") == []
+    linalg.cg(A, b, maxiter=30)
+    linalg.cg(A, b, maxiter=30)  # cached: no second build
+    A @ b
+    evs = [e for e in telemetry.events("span")
+           if e["name"] == "layout.dia_build"]
+    assert len(evs) == 1
+    assert telemetry.summary()["spans"]["layout.dia_build"]["n"] == 1
+    # a matrix that is not banded: the detection is the span
+    R = sparse_tpu.csr_array(sp.random(200, 200, density=0.2, random_state=0,
+                                       format="csr"))
+    R @ np.ones(200)
+    R @ np.ones(200)
+    assert telemetry.summary()["spans"]["layout.dia_build"]["n"] == 2
+
+
+# -- telemetry off -----------------------------------------------------------
+def test_off_every_site_gets_the_null_span_and_nothing_is_recorded(
+        off, monkeypatch):
+    monkeypatch.setattr(settings, "fused_cg", "force")
+    got = []
+    real = telemetry.span
+
+    def spy(name, *a, **kw):
+        s = real(name, *a, **kw)
+        got.append((name, s))
+        return s
+
+    monkeypatch.setattr(telemetry, "span", spy)
+    ses = SolveSession("cg", warm_start=False)
+    _two_buckets(ses)
+    D, b = _pde()
+    A = D.tocsr()
+    linalg.cg(A, b, maxiter=30)
+    assert {n for n, _ in got} >= set(SESSION_SPANS) | {
+        "cg.solve", "cg.chunk", "cg.rho_fetch", "layout.dia_build"}
+    assert all(s is _NULL for _, s in got)
+    assert telemetry.events() == []
+    assert telemetry.summary()["spans"] == {}
+    assert _recorder._SPANS == {}
+
+
+def test_off_the_session_still_accounts_its_solve_time(off):
+    seen = []
+    ses = SolveSession("cg", warm_start=False)
+    real = ses._fleet_account
+
+    def account(plan, solver, dt, nb, bkt, iters, solve_s, **kw):
+        seen.append(solve_s)
+        return real(plan, solver, dt, nb, bkt, iters, solve_s, **kw)
+
+    ses._fleet_account = account
+    _two_buckets(ses)
+    assert len(seen) == 2 and all(0 < s < 60 for s in seen)
