@@ -31,6 +31,8 @@ instrument that shows exactly one compile/pack per bucket.
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..config import settings
@@ -110,28 +112,101 @@ def pad_lanes(values, rhs, tols, bucket: int, x0=None, big_tol=1e30):
     return values, rhs, tols, x0, b
 
 
+#: Lanes of at least this many bytes (``values`` + right-hand side of
+#: one lane, as staged) go to the device one by one and are stacked
+#: there; smaller lanes are stacked on the host and go up as four
+#: arrays. A host stack costs one memcpy of the bucket; the device form
+#: costs one transfer a lane array instead, whose bookkeeping is what a
+#: memcpy of about this much costs (the crossing measured on the v5e:
+#: PERF.md section 6, PR 26).
+DEVICE_STACK_LANE_BYTES = 1 << 20
+
+
+def lanes_stack_on(values, rhs) -> str:
+    """Where a bucket of these lanes is stacked, ``"device"`` or
+    ``"host"``: by the bytes of one lane's values and right-hand side
+    against :data:`DEVICE_STACK_LANE_BYTES`."""
+    lane_nbytes = values[0].nbytes + rhs[0].nbytes
+    return "device" if lane_nbytes >= DEVICE_STACK_LANE_BYTES else "host"
+
+
+@jax.jit
+def _assemble(values, rhs, x0):
+    """The three lane stacks of one bucket from its per-lane device
+    arrays: one trace and one executable per (bucket, lane shapes,
+    dtype), kept by ``jax.jit``'s own cache."""
+    return jnp.stack(values), jnp.stack(rhs), jnp.stack(x0)
+
+
 def stage_lanes(values, rhs, tols, bucket: int, x0=None, big_tol=1e30):
-    """:func:`pad_lanes` + eager host->device upload of the padded
-    stacks (the streaming-dispatch entry, ISSUE 13).
+    """The padded lane stacks of one bucket, on the device (the
+    streaming-dispatch entry, ISSUE 13; assembled there, ISSUE 26).
 
-    ``jax.device_put`` starts the transfers as soon as the pads exist,
-    so by the time the session's pipeline actually *dispatches* the
-    bucket program — possibly while an earlier bucket is still solving
-    on the device — the value stack / rhs / x0 / tolerances are already
-    on (or on their way to) the device. Returns
-    ``(values, rhs, tols, x0, nreal)`` with the first four as device
-    arrays; numerically identical to ``pad_lanes`` + ``jnp.asarray`` at
-    the dispatch site (pinned by the pipeline parity tests).
+    ``values`` and ``rhs`` are sequences of ``b`` lanes of one dtype
+    (a 2-D array passes as its rows), ``x0`` is ``None`` or a sequence
+    whose entries may be ``None`` (that lane starts from zero). Returns
+    ``(values, rhs, tols, x0, nreal)``, the first four as device arrays
+    equal bit for bit to :func:`pad_lanes`' output of the stacked lanes
+    (pinned by ``tests/test_pipeline.py``), uncommitted on the default
+    device whichever way they were made:
+
+    * lanes under :data:`DEVICE_STACK_LANE_BYTES` are stacked and
+      padded on the host and uploaded as four arrays;
+    * larger lanes are uploaded as they are, in one ``jax.device_put``
+      of the list, and one jitted program stacks them on the device: no
+      lane-sized host array is made. Pad lanes name lane 0's device
+      array again as an operand, and they and the real lanes without an
+      ``x0`` take one zero vector made on the device, so nothing is
+      uploaded for them and the program's signature depends on the
+      bucket alone. The per-lane arrays are dropped once it is
+      dispatched.
+
+    Either way the transfers start here, so they overlap the solve of
+    whatever bucket is in flight. The transfer reads the caller's own
+    arrays, possibly after this returns: they must not change until the
+    tickets are terminal (docs/batching.md, "Streaming dispatch").
     """
-    import jax
+    nreal = len(values)
+    x0 = [None] * nreal if x0 is None else list(x0)
+    if len(rhs) != nreal or len(tols) != nreal or len(x0) != nreal:
+        raise ValueError("values/rhs/tols lane counts disagree")
+    if bucket < nreal:
+        raise ValueError(f"bucket {bucket} smaller than batch {nreal}")
+    if lanes_stack_on(values, rhs) == "host":
+        values, rhs = _stack(values), _stack(rhs)
+        if all(x is None for x in x0):
+            x0 = None
+        else:
+            x0 = np.stack(
+                [np.zeros_like(rhs[0]) if x is None else x for x in x0]
+            )
+        values, rhs, tols, x0, _ = pad_lanes(
+            values, rhs, tols, bucket, x0=x0, big_tol=big_tol
+        )
+        return (
+            jax.device_put(values), jax.device_put(rhs),
+            jax.device_put(tols), jax.device_put(x0), nreal,
+        )
+    pad = bucket - nreal
+    tols = np.concatenate(
+        [np.asarray(tols, dtype=np.float64), np.full(pad, big_tol)]
+    )
+    v, r, given = jax.device_put(
+        (list(values), list(rhs), [x for x in x0 if x is not None])
+    )
+    zero = jnp.zeros_like(r[0]) if pad or len(given) < nreal else None
+    given = iter(given)
+    values, rhs, x0 = _assemble(
+        (*v, *[v[0]] * pad),
+        (*r, *[zero] * pad),
+        (*(zero if x is None else next(given) for x in x0), *[zero] * pad),
+    )
+    del v, r, given
+    return values, rhs, jax.device_put(tols), x0, nreal
 
-    values, rhs, tols, x0, nreal = pad_lanes(
-        values, rhs, tols, bucket, x0=x0, big_tol=big_tol
-    )
-    return (
-        jax.device_put(values), jax.device_put(rhs),
-        jax.device_put(tols), jax.device_put(x0), nreal,
-    )
+
+def _stack(lanes):
+    return lanes if isinstance(lanes, np.ndarray) else np.stack(lanes)
 
 
 def pattern_bucket(n: int, nnz: int) -> tuple:

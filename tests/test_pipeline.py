@@ -12,8 +12,14 @@ admission control blocks or rejects at `max_queue_depth` with
 `batch.admission` evidence; (e) the async `_prebuild` warm replay races
 a first `submit` to a zero-serving-build window; (f) the
 `batch.queue_depth` gauge decrements per ticket at finalize — no drift
-through failures, deadlines or requeues (`queue_depth_drift == 0`).
+through failures, deadlines or requeues (`queue_depth_drift == 0`); (g) the lane stacks of a bucket are the same
+four arguments, bit for bit and aval for aval, whether they were stacked
+on the host (small lanes) or uploaded lane by lane and assembled on the
+device (lanes of `bucket.DEVICE_STACK_LANE_BYTES` or more, ISSUE 26).
 """
+
+import contextlib
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -31,6 +37,7 @@ from sparse_tpu.batch import (
     pad_lanes,
     stage_lanes,
 )
+from sparse_tpu.batch import bucket as bucketing
 from sparse_tpu.batch.service import _InFlight
 from sparse_tpu.config import settings
 from sparse_tpu.resilience import faults
@@ -389,3 +396,265 @@ def test_bucket_batch_unchanged_by_pipeline():
     # the pipeline must not perturb bucketing: same pow2 quantization
     assert bucket_batch(5, policy="pow2", batch_max=64) == 8
     assert bucket_batch(5, policy="exact", batch_max=64) == 5
+
+
+# ---------------------------------------------------------------------------
+# (g) staging: host stack or device assemble, the same four arguments
+# ---------------------------------------------------------------------------
+_FORMS = {"device": 0, "host": 1 << 62}  # DEVICE_STACK_LANE_BYTES of each
+
+
+@pytest.fixture(params=sorted(_FORMS))
+def form(request, monkeypatch):
+    monkeypatch.setattr(
+        bucketing, "DEVICE_STACK_LANE_BYTES", _FORMS[request.param]
+    )
+    return request.param
+
+
+def _x0_of(kind, rng, nb, n):
+    if kind == "none":
+        return None
+    x0 = list(rng.standard_normal((nb, n)))
+    if kind == "some":
+        x0[1] = None
+    return x0
+
+
+@pytest.mark.parametrize(
+    "nb,bkt,x0_kind,lanes_as",
+    [
+        (4, 4, "all", "list"),       # a full bucket
+        (3, 4, "all", "list"),       # a pad lane: lane 0 again, zero rhs/x0
+        (3, 8, "some", "list"),      # a real lane without x0
+        (3, 4, "none", "list"),      # no lane brings one
+        (3, 4, "all", "f32_lane"),   # one lane of another dtype
+        (3, 4, "none", "rows_2d"),   # lanes as rows of one 2-D array
+    ],
+)
+def test_stage_lanes_equals_pad_lanes_either_form(
+    form, nb, bkt, x0_kind, lanes_as
+):
+    rng = np.random.default_rng(5)
+    n, nnz, dt = 5, 10, np.dtype(np.float64)
+    values = rng.standard_normal((nb, nnz))
+    rhs = rng.standard_normal((nb, n))
+    tols = np.array([1e-8, 1e-6, 1e-4, 1e-2][:nb])
+    x0 = _x0_of(x0_kind, rng, nb, n)
+    if lanes_as != "rows_2d":
+        values, rhs = list(values), list(rhs)
+    if lanes_as == "f32_lane":
+        # what _launch does with a lane that is not of the group's dtype
+        values[1] = values[1].astype(np.float32).astype(dt, copy=False)
+    # the parent's form: stacks of copies, zeros for an absent x0
+    ref = pad_lanes(
+        np.stack([np.asarray(v).astype(dt) for v in values]),
+        np.stack([np.asarray(b).astype(dt) for b in rhs]), tols, bkt,
+        x0=None if x0 is None else np.stack(
+            [np.zeros(n, dt) if x is None else x for x in x0]
+        ),
+    )
+    dev = stage_lanes(values, rhs, tols, bkt, x0=x0)
+    assert ref[4] == dev[4] == nb
+    for want, got in zip(ref[:4], dev[:4]):
+        put = jax.device_put(want)  # what the parent handed the program
+        assert isinstance(got, jax.Array)
+        assert got.aval == put.aval and not got.aval.weak_type
+        assert got.sharding == put.sharding
+        assert got.committed == put.committed is False
+        assert np.array_equal(np.asarray(got), want)
+    if bkt > nb:
+        assert np.array_equal(np.asarray(dev[0])[nb], np.asarray(values[0]))
+        assert not np.asarray(dev[1])[nb:].any()
+        assert not np.asarray(dev[3])[nb:].any()
+        assert (np.asarray(dev[2])[nb:] == 1e30).all()
+
+
+def _serve(solver, inflight, maxiter=None, x0=False, B=5):
+    mats, rhs = _systems(B=B)
+    ses = SolveSession(solver, inflight=inflight, batch_max=4,
+                       requeue=True, warm_start=False)
+    ts = [
+        ses.submit(A, b, tol=1e-10, maxiter=maxiter,
+                   x0=(0.1 * b if x0 and i != 2 else None))
+        for i, (A, b) in enumerate(zip(mats, rhs))
+    ]
+    ses.flush(wait=False)
+    outs = [t.result() for t in ts]
+    return ts, outs
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+@pytest.mark.parametrize("solver", ["cg", "bicgstab", "gmres"])
+def test_session_answers_identical_under_either_staging(
+    monkeypatch, solver, inflight
+):
+    runs = {}
+    for name, lane_bytes in _FORMS.items():
+        monkeypatch.setattr(bucketing, "DEVICE_STACK_LANE_BYTES", lane_bytes)
+        runs[name] = _serve(solver, inflight, x0=True)
+    for (td, od), (th, oh) in zip(zip(*runs["device"]), zip(*runs["host"])):
+        assert np.array_equal(od[0], oh[0])  # bit for bit
+        assert od[1] == oh[1] and od[2] == oh[2]
+        assert sorted(td.phase_ms) == sorted(th.phase_ms)
+        assert td.converged and th.converged
+
+
+def test_requeue_rereads_host_arrays_under_either_staging(monkeypatch):
+    """Every lane stops at one iteration and requeues: the fallback
+    buckets are staged from the requests' host arrays again, under either
+    form."""
+    runs = {}
+    for name, lane_bytes in _FORMS.items():
+        monkeypatch.setattr(bucketing, "DEVICE_STACK_LANE_BYTES", lane_bytes)
+        runs[name] = _serve("cg", 2, maxiter=1)
+    for (td, od), (th, oh) in zip(zip(*runs["device"]), zip(*runs["host"])):
+        assert td.requeued == th.requeued
+        assert np.array_equal(od[0], oh[0]) and od[1] == oh[1]
+    assert all(t.requeued and t.solver == "gmres" and t.converged
+               for t in runs["device"][0])
+
+
+def test_mixed_dtype_lane_through_a_session(form):
+    """A float32 lane among float64 ones whose right-hand sides are all
+    float64 joins their group: its values are the one copy the pack makes."""
+    mats, rhs = _systems(B=3)
+    ses = SolveSession("cg", warm_start=False)
+    pat = ses.pattern_of(mats[0])
+    vals = [A.data for A in mats]
+    vals[1] = vals[1].astype(np.float32)
+    ts = [ses.submit(v, b, tol=1e-6, pattern=pat)
+          for v, b in zip(vals, rhs)]
+    assert ses.flush() == 1  # one group, one bucket
+    for v, b, t in zip(vals, rhs, ts):
+        A = sp.csr_matrix((v.astype(np.float64), mats[0].indices,
+                           mats[0].indptr), shape=mats[0].shape)
+        assert np.linalg.norm(A @ t.result()[0] - b) < 1e-5
+
+
+def _big_systems(B=4, n=60000):
+    """Lanes over the shipped constant: 180k float64 values and a
+    right-hand side, 1.9 MB a lane."""
+    mats, rhs = _systems(B=B, n=n)
+    assert mats[0].data.nbytes + rhs[0].nbytes >= (
+        bucketing.DEVICE_STACK_LANE_BYTES
+    )
+    return mats, rhs
+
+
+def test_launch_allocates_no_lane_sized_host_array():
+    mats, rhs = _big_systems()
+    ses = SolveSession("cg", inflight=2, warm_start=False)
+    pat = ses.pattern_of(mats[0])
+    vals = [A.data for A in mats]
+    ses.solve_many(mats, rhs, tol=1e-8)  # builds the program, then drains
+    ts = [ses.submit(v, b, tol=1e-8, x0=b, pattern=pat)
+          for v, b in zip(vals, rhs)]
+    tracemalloc.start()
+    try:
+        ses.flush(wait=False)  # the launch alone: the window holds it
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert len(ses._inflight) == 1
+    # numpy reports its buffers to tracemalloc: a stack of the four lanes
+    # (the parent made three) would show as four lanes at least
+    assert peak < vals[0].nbytes + rhs[0].nbytes
+    ses.drain()
+    assert all(t.converged for t in ts)
+
+
+@pytest.mark.parametrize("size", ["large", "small"])
+def test_lanes_staged_counter_and_dispatch_event(tel, size):
+    where = {"large": "device", "small": "host"}[size]
+    mats, rhs = _big_systems(B=3) if size == "large" else _systems(B=3)
+    counters = {
+        w: _metrics.counter("batch.lanes_staged", where=w)
+        for w in ("device", "host")
+    }
+    base = {w: c.value for w, c in counters.items()}
+    SolveSession("cg", warm_start=False).solve_many(mats, rhs, tol=1e-8)
+    other = "host" if where == "device" else "device"
+    assert counters[where].value == base[where] + 3  # real lanes, not 4
+    assert counters[other].value == base[other]
+    evs = [e for e in telemetry.events() if e["kind"] == "batch.dispatch"]
+    assert [e["staged"] for e in evs] == [where]
+
+
+@contextlib.contextmanager
+def _compiles():
+    """The programs compiled inside the block, as the benchmark counts
+    them (``compiles_in_window``)."""
+    from jax import monitoring
+
+    seen = []
+
+    def on_duration(name, *_a, **_k):
+        if name == "/jax/core/compile/backend_compile_duration":
+            seen.append(name)
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield seen
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+
+
+def test_assemble_built_once_second_step_compiles_nothing(monkeypatch):
+    monkeypatch.setattr(bucketing, "DEVICE_STACK_LANE_BYTES", 0)
+    mats, rhs = _systems(B=3)
+    ses = SolveSession("cg", inflight=2, warm_start=False)
+    X, _, _ = ses.solve_many(mats, rhs, tol=1e-10)  # step 1: builds
+    traces = bucketing._assemble._cache_size()
+    snap = plan_cache.snapshot()
+    with _compiles() as compiled:
+        # step 2: x0 carried over, other right-hand sides, the same bucket
+        ts = [ses.submit(A, b + 1.0, tol=1e-10, x0=x)
+              for A, b, x in zip(mats, rhs, X)]
+        ses.flush()
+    assert all(t.converged for t in ts)
+    assert bucketing._assemble._cache_size() == traces
+    assert plan_cache.delta(snap)["misses"] == 0
+    assert compiled == []
+
+
+def test_program_key_and_avals_same_under_either_staging(monkeypatch, tel):
+    mats, rhs = _systems(B=3)
+    seen = {}
+    real = bucketing.stage_lanes
+
+    def spy(*a, **k):
+        out = real(*a, **k)
+        seen.setdefault(name, []).append(
+            [(x.aval, x.sharding, x.committed) for x in out[:4]]
+        )
+        return out
+
+    monkeypatch.setattr(bucketing, "stage_lanes", spy)
+    for name, lane_bytes in _FORMS.items():
+        monkeypatch.setattr(bucketing, "DEVICE_STACK_LANE_BYTES", lane_bytes)
+        SolveSession("cg", warm_start=False).solve_many(mats, rhs, tol=1e-10)
+    assert seen["device"] == seen["host"] and len(seen["host"]) == 1
+    keys = [e["program"] for e in telemetry.events()
+            if e["kind"] == "batch.dispatch"]
+    assert len(keys) == 2 and keys[0] == keys[1]
+
+
+def test_warm_replayed_program_takes_device_assembled_arguments(monkeypatch):
+    """The replay compiles a bucket program ahead of time against zero
+    stacks; an executable compiled so refuses other avals, so the first
+    real dispatch, assembled on the device, must bring the same ones."""
+    monkeypatch.setattr(bucketing, "DEVICE_STACK_LANE_BYTES", 0)
+    mats, rhs = _systems(B=3)
+    plan_cache.clear()
+    ses = SolveSession("cg", warm_start=False)
+    pat = ses.pattern_of(mats[0])
+    ses._prebuild(pat, "cg", 4, np.dtype(np.float64))
+    degraded = _metrics.counter("batch.degraded").value
+    snap = plan_cache.snapshot()
+    X, _, _ = ses.solve_many(mats, rhs, tol=1e-10)
+    assert plan_cache.delta(snap)["misses"] == 0
+    assert ses.session_stats()["pipeline"]["serving_builds"] == 0
+    assert _metrics.counter("batch.degraded").value == degraded
+    for A, b, x in zip(mats, rhs, X):
+        assert np.linalg.norm(A @ x - b) < 1e-8
