@@ -61,7 +61,8 @@ def main():
 
         st = comm_stats(D, conv_test_iters=args.iters)
         results.append(
-            {"shards": S, "rows": A.shape[0], "iters_per_s": round(best, 2),
+            {"shards": S, "rows": A.shape[0], "layout": D.layout,
+             "iters_per_s": round(best, 2),
              "efficiency": round(eff, 3),
              "halo_entries": st["halo_entries_per_spmv"],
              "collective_bytes_per_iter":
@@ -70,7 +71,8 @@ def main():
         )
         print(
             f"S={S:3d}  rows={A.shape[0]:>10,}  {best:8.2f} iters/s  "
-            f"efficiency {eff:6.1%}  halo {st['halo_entries_per_spmv']}  "
+            f"efficiency {eff:6.1%}  {D.layout}  "
+            f"halo {st['halo_entries_per_spmv']}  "
             f"{st['cg_iter_collective_bytes_per_shard']} B/iter"
         )
     print(json.dumps({"weak_scaling": results}))

@@ -360,10 +360,11 @@ class csr_array(SparseArray):
         # gather path in silence (tests/test_sell_spmv.py)
         offs = self._fetch_offsets(offs_dev)
         offs = offs[offs != np.iinfo(np.int32).max]
-        D = len(offs)
-        if D > settings.dia_max_diags or D * n > settings.dia_max_fill * nnz:
+        from .dia import _coo_to_dia, few_diagonals
+
+        if not few_diagonals(len(offs), n, nnz):
             return None
-        from .dia import _coo_to_dia  # duplicate-summing plane build
+        # duplicate-summing plane build
 
         planes, offsets, _ = _coo_to_dia(self.tocoo())
         return (planes, tuple(int(o) for o in offsets))

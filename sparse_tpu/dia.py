@@ -272,6 +272,18 @@ class dia_array(SparseArray):
     __repr__ = __str__
 
 
+def few_diagonals(n_diags: int, n: int, nnz: int) -> bool:
+    """The banded rule, in one place: an operator with ``nnz`` entries on
+    ``n_diags`` distinct diagonals of length ``n`` is laid out as planes by
+    ``csr_array._maybe_dia`` (one chip) and ``parallel.dist.shard_csr`` (a
+    mesh) when the diagonals are few and their planes are not mostly fill.
+    Each caller counts the diagonals where its arrays live."""
+    from .config import settings
+
+    return (n_diags <= settings.dia_max_diags
+            and n_diags * n <= settings.dia_max_fill * nnz)
+
+
 def _coo_to_dia(c):
     """COO -> (data, offsets, shape). Host-syncs the distinct-offset set."""
     m, n = c.shape

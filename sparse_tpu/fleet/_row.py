@@ -14,8 +14,9 @@ if the mesh solve comes back unconverged — the standard requeue into the
 single-device fallback bucket.
 
 Cost shape: the row-block *layout* is rebuilt per dispatch (values
-change per request and ``DistCSR`` bakes them into its shard planes) and
-``dist_cg`` retraces per call — acceptable because row-sharded traffic
+change per request and ``DistCSR`` bakes them into its shard planes), and
+with it the compiled CG that ``dist_cg`` keeps on a layout: a trace per
+dispatch, a compile when the shapes are new — acceptable because row-sharded traffic
 is by definition rare and enormous (the solve dominates), and honest:
 under streaming dispatch (ISSUE 13) the closure is host-driven, so a
 row "dispatch" completes its solve before returning — the pipeline

@@ -1190,7 +1190,7 @@ def _make_gmres_cycle(A, M, restart: int, dt):
     with no breakdown means converged on entry. (The compiled cycle is
     built once per gmres() call and reused across all outer restarts; it
     is not cached across calls — the jitted closure captures the
-    operator's buffers, see make_dist_cg's same convention.)"""
+    operator's buffers.)"""
     rdt = jnp.zeros((), dt).real.dtype
 
     @jax.jit

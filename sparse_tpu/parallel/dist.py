@@ -14,9 +14,12 @@ Layout (S = mesh size):
     ``P('shards')``, entries beyond a block's real rows are zero;
   * column ids are remapped into the same padded coordinate space at
     construction, so x-gathers are direct indexed loads;
-  * per-shard nonzeros are stored either as stacked ELL planes
-    ``[S, R, k]`` (banded/bounded-degree: pure gather + VPU reduce — the shape
-    TPUs like) or stacked padded CSR ``[S, K]`` + row ids (general profile);
+  * per-shard nonzeros are stored as D diagonal planes ``[S*R]``
+    (banded operators: D vectors in the padded row layout, the local
+    product is D shifted multiply-adds over the halo slab, no index loads at
+    all), as stacked ELL planes ``[S, R, k]``
+    (bounded-degree: gather + VPU reduce) or as stacked padded CSR
+    ``[S, K]`` + row ids (general profile);
   * the x-window each shard needs (the MinMaxImagePartition analog,
     partition.py:139-214) becomes a **static halo width H**: SpMV fetches the
     H-wide tails of its mesh neighbors with ``lax.ppermute`` over ICI and runs
@@ -37,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry import _metrics
 from ..utils import asjnp
 from . import comm
 from .mesh import get_mesh
@@ -64,7 +68,7 @@ class DistCSR:
     HL: int  # left halo width (cols), 0 when mode == "gather"
     HR: int  # right halo width; == HL unless settings.precise_windows
     mode: str  # "halo" | "gather"
-    layout: str  # "ell" | "csr"
+    layout: str  # "dia" | "ell" | "csr"
     dtype: np.dtype
     # device arrays, all sharded P(axis) on their leading dim:
     ell_idx: jax.Array | None = None  # [S, R, k] padded-space col ids (rel. to window)
@@ -72,9 +76,17 @@ class DistCSR:
     nz_rows: jax.Array | None = None  # [S, K] local row ids (csr layout)
     nz_cols: jax.Array | None = None  # [S, K] padded-space col ids (rel. to window)
     nz_vals: jax.Array | None = None  # [S, K]
+    # D vectors [S*R] in the padded row layout (not one [S, D, R] stack: the
+    # chip's compiler re-tiles and slices a stack inside every CG iteration);
+    # plane d holds A[row, row + dia_offsets[d]] at the row's padded position
+    dia_planes: tuple | None = None
+    dia_offsets: tuple = ()  # static (col - row) of each plane, ascending
     _spmv_fn: object = field(default=None, repr=False, compare=False)
     _spmm_fn: object = field(default=None, repr=False, compare=False)
     _rspmm_fn: object = field(default=None, repr=False, compare=False)
+    # compiled CG programs of this layout, by what changes the program
+    # (_cg_program): dist_cg's second and later calls neither trace nor compile
+    _cg_fns: dict = field(default_factory=dict, repr=False, compare=False)
 
     @property
     def S(self) -> int:
@@ -164,6 +176,8 @@ class DistCSR:
     def _blocks(self) -> tuple:
         """The mesh-sharded matrix blocks the compiled SpMV takes as
         ARGUMENTS (per layout)."""
+        if self.layout == "dia":
+            return self.dia_planes
         if self.layout == "ell":
             return (self.ell_idx, self.ell_val)
         return (self.nz_rows, self.nz_cols, self.nz_vals)
@@ -248,13 +262,6 @@ class DistCSR:
         out = fn(Bp)
         self._commit_comm("_comm_ledger_rspmm")
         return out
-
-    def _blocks(self):
-        return (
-            (self.ell_idx, self.ell_val)
-            if self.layout == "ell"
-            else (self.nz_rows, self.nz_cols, self.nz_vals)
-        )
 
     def dot(self, x) -> np.ndarray:
         """Convenience global SpMV/SpMM (pads, multiplies, unpads)."""
@@ -380,33 +387,83 @@ def _build_spmv(A: DistCSR, matrix: bool = False):
             )
         return jnp.concatenate(parts)  # [HL + C + HR, ...]
 
-    if layout == "ell":
+    if layout == "dia":
+        offsets = A.dia_offsets
+        n_own = np.diff(np.asarray(A.row_splits))  # real rows of each shard
+        uneven = bool(np.any(n_own != C))
+        # the slab a shard multiplies is contiguous in GLOBAL coordinates
+        # around its real rows: [WL | its block | WR], the block padded with
+        # zeros and the halos written into the margins (one shard has no
+        # neighbour: its margins stay zero). A pad and two small updates,
+        # not a concatenate: the chip copies a concatenate's operands
+        # through HBM, 46 % of an iteration at 1.6M rows (PERF.md, PR 27).
+        WL, WR = (HL, HR) if S > 1 else (
+            max(-min(offsets), 0), max(max(offsets), 0))
+
+        def dia_slab(x_l):
+            slab = jnp.pad(x_l, ((WL, WR),) + ((0, 0),) * (x_l.ndim - 1))
+            if S == 1:
+                return slab
+            # uneven row blocks: a block's real rows end before its padding
+            # does, so the sender cuts its real tail and the receiver puts
+            # its right neighbour's head where its own real rows end
+            n_s = (jnp.asarray(n_own, jnp.int32)[jax.lax.axis_index(axis)]
+                   if uneven else C)
+            if WL:
+                tail = jax.lax.dynamic_slice_in_dim(x_l, n_s - WL, WL)
+                slab = jax.lax.dynamic_update_slice_in_dim(
+                    slab, comm.ppermute(tail, axis, perm_right, ledger=led,
+                                        tag="halo_l"), 0, axis=0)
+            if WR:
+                head = comm.ppermute(
+                    x_l[:WR], axis, perm_left, ledger=led, tag="halo_r")
+                slab = jax.lax.dynamic_update_slice_in_dim(
+                    slab, head, WL + n_s, axis=0)
+            return slab
+
+        def shard_fn(x_l, *planes):  # D planes of [R]
+            with jax.named_scope("dist.halo"):
+                slab = dia_slab(x_l)
+            with jax.named_scope("dist.local_spmv"):
+                out = None
+                for plane, off in zip(planes, offsets):
+                    seg = jax.lax.slice_in_dim(slab, WL + off, WL + off + R)
+                    term = (plane[:, None] if is_mat else plane) * seg
+                    out = term if out is None else out + term
+            return out if is_mat else out[None]
+
+        in_specs = (P(axis),) * (1 + len(offsets))
+    elif layout == "ell":
 
         from ..ops.spmv import csr_spmm_ell, csr_spmv_ell
 
         def shard_fn(x_l, ell_idx_l, ell_val_l):
-            slab = gather_x(x_l)
+            with jax.named_scope("dist.halo"):
+                slab = gather_x(x_l)
             idx, val = ell_idx_l.squeeze(0), ell_val_l.squeeze(0)
-            if is_mat:
-                return csr_spmm_ell(idx, val, slab)  # [R, nB]
-            return csr_spmv_ell(idx, val, slab)[None]
+            with jax.named_scope("dist.local_spmv"):
+                if is_mat:
+                    return csr_spmm_ell(idx, val, slab)  # [R, nB]
+                return csr_spmv_ell(idx, val, slab)[None]
 
         in_specs = (P(axis), P(axis, None, None), P(axis, None, None))
     else:
 
         def shard_fn(x_l, rows_l, cols_l, vals_l):
-            slab = gather_x(x_l)
+            with jax.named_scope("dist.halo"):
+                slab = gather_x(x_l)
             rows, cols, vals = (
                 rows_l.squeeze(0),
                 cols_l.squeeze(0),
                 vals_l.squeeze(0),
             )
-            prod = (
-                vals[:, None] * slab[cols] if is_mat else vals * slab[cols]
-            )
-            out = jax.ops.segment_sum(
-                prod, rows, num_segments=R, indices_are_sorted=True
-            )
+            with jax.named_scope("dist.local_spmv"):
+                prod = (
+                    vals[:, None] * slab[cols] if is_mat else vals * slab[cols]
+                )
+                out = jax.ops.segment_sum(
+                    prod, rows, num_segments=R, indices_are_sorted=True
+                )
             return out if is_mat else out[None]
 
         in_specs = (P(axis), P(axis, None), P(axis, None), P(axis, None))
@@ -442,7 +499,24 @@ def _build_rspmm(A: DistCSR):
 
     def shard_fn(B_l, *blocks):
         s = jax.lax.axis_index(axis)
-        if layout == "ell":
+        if layout == "dia":
+            # the planes as (row, column, value) triples: plane d, local row
+            # l is at slab position HL + l + off_d (_build_spmv's slab,
+            # contiguous in global coordinates), which lies in the left
+            # neighbour's real tail, in this block, or in the right
+            # neighbour's head
+            n_own = jnp.asarray(np.diff(np.asarray(A.row_splits)), jnp.int32)
+            n_s = n_own[s]
+            n_left = n_own[jnp.maximum(s - 1, 0)]
+            lrow = jnp.arange(R, dtype=jnp.int32)
+            rows = jnp.tile(lrow, len(A.dia_offsets))
+            pos = (lrow[None, :] + jnp.asarray(
+                A.dia_offsets, jnp.int32)[:, None]).reshape(-1)
+            cols = jnp.where(
+                pos < 0, (s - 1) * C + n_left + pos,
+                jnp.where(pos < n_s, s * C + pos, (s + 1) * C + pos - n_s))
+            vals = jnp.concatenate(blocks)
+        elif layout == "ell":
             ell_idx, ell_val = (b.squeeze(0) for b in blocks)
             k = ell_idx.shape[1]
             rows = jnp.repeat(jnp.arange(R, dtype=jnp.int32), k)
@@ -451,7 +525,7 @@ def _build_rspmm(A: DistCSR):
         else:
             rows, cols, vals = (b.squeeze(0) for b in blocks)
         # window-local col ids -> padded global col ids
-        if mode != "gather":
+        if mode != "gather" and layout != "dia":
             cols = cols.astype(jnp.int32) + s * C - HL
         cols = jnp.clip(cols, 0, n_pad - 1)  # padding entries carry val 0
         contrib = B_l[:, rows] * vals  # [p, Kf]
@@ -459,7 +533,9 @@ def _build_rspmm(A: DistCSR):
         # [p, n_pad] replicated (ADD-reduction into a broadcast C)
         return comm.psum(out.T, axis, ledger=led, tag="reduce")
 
-    if layout == "ell":
+    if layout == "dia":
+        block_specs = (P(axis),) * len(A.dia_offsets)
+    elif layout == "ell":
         block_specs = (P(axis, None, None), P(axis, None, None))
     else:
         block_specs = (P(axis, None), P(axis, None), P(axis, None))
@@ -680,6 +756,63 @@ def shard_csr_cols(
     )
 
 
+def _banded_offsets(off: np.ndarray, n: int):
+    """``(offsets, plane)``: the distinct diagonals (column - row, ascending)
+    of a host CSR whose per-entry ``off`` is given, and each entry's index
+    into them, when the operator is banded by the one-chip rule
+    (``dia.few_diagonals``); else None. The diagonals are counted here on
+    the host, where ``shard_csr`` holds the arrays. ``off`` is the caller's
+    scratch: it is overwritten."""
+    from ..config import settings
+    from ..dia import few_diagonals
+
+    nnz = off.shape[0]
+    if nnz == 0:
+        return None
+    # a general matrix is turned away by a strided sample of its entries
+    sample = off[:: max(nnz // 8192, 1)]
+    if len(np.unique(sample)) > settings.dia_max_diags:
+        return None
+    lo, hi = int(off.min()), int(off.max())
+    table = hi - lo < (1 << 22)  # a table over the band: two passes, no sort
+    if table:
+        off -= lo
+        seen = np.bincount(off, minlength=hi - lo + 1) > 0
+        offs = np.flatnonzero(seen) + lo
+    else:
+        offs, plane = np.unique(off, return_inverse=True)
+    if not few_diagonals(len(offs), n, nnz):
+        return None
+    if table:
+        plane = (np.cumsum(seen) - 1)[off]
+    return offs, plane
+
+
+def _dia_fit(indices, nnz_row, m, n, row_splits, col_splits, S, C,
+             halo_max_ratio):
+    """Whether the 'dia' layout can hold the operator: ``(offsets, each
+    entry's plane, HL, HR)``, or the reason why not as a string."""
+    from ..config import settings
+
+    if m != n or not np.array_equal(row_splits, col_splits):
+        return "its rows and columns do not share one split"
+    banded = _banded_offsets(indices.astype(np.int64) - nnz_row, n)
+    if banded is None:
+        return ("it is not banded by settings.dia_max_diags and "
+                "settings.dia_max_fill")
+    offs, plane = banded
+    if S == 1:
+        return offs, plane, 0, 0
+    HL, HR = max(-int(offs[0]), 0), max(int(offs[-1]), 0)
+    if not settings.precise_windows:
+        HL = HR = max(HL, HR)
+    # the halo exchange reaches one neighbour: its real rows must hold the band
+    if (max(HL, HR) > int(np.min(np.diff(row_splits)))
+            or HL + HR > 2 * halo_max_ratio * C):
+        return "its band reaches past a mesh neighbour's row block"
+    return offs, plane, HL, HR
+
+
 def shard_csr(
     A,
     mesh: Mesh | None = None,
@@ -693,13 +826,45 @@ def shard_csr(
     """Lay a ``csr_array`` out over a mesh.
 
     ``balanced`` selects nnz-balanced row splits (the balance() analog);
-    ``layout`` is 'ell' | 'csr' | 'auto' (ELL when max row degree is within
+    ``layout`` is 'dia' | 'ell' | 'csr' | 'auto'. 'auto' takes 'dia' for a
+    square operator that is banded by the one-chip rule (few distinct
+    diagonals: ``settings.dia_max_diags``/``dia_max_fill``) and whose band
+    every mesh neighbour's row block covers, so that the halo exchange
+    brings all of it; else ELL when max row degree is within
     ``settings.ell_max_ratio`` of the mean, mirroring the single-chip
-    heuristic); a shard's column window overhang beyond ``halo_max_ratio * C``
-    forces the all_gather fallback. Explicit ``row_splits``/``col_splits``
-    pin the layout so chains of rectangular operators (AMG's R/A/P) share
-    vector spaces without repacking.
+    heuristic, else padded CSR. A shard's column window overhang beyond
+    ``halo_max_ratio * C`` forces the all_gather fallback. Explicit
+    ``row_splits``/``col_splits`` pin the layout so chains of rectangular
+    operators (AMG's R/A/P) share vector spaces without repacking.
     """
+    from .. import telemetry
+
+    # the host build, whole: splits, layout choice, block construction and
+    # the device_put calls (which return before the transfers end)
+    with telemetry.span("dist.shard_csr") as sp:
+        dist = _shard_csr(A, mesh, axis, balanced, layout, halo_max_ratio,
+                          row_splits, col_splits)
+        if telemetry.enabled():
+            sp.annotate(layout=dist.layout, mode=dist.mode, S=dist.S,
+                        R=dist.R, HL=dist.HL, HR=dist.HR, rows=dist.shape[0],
+                        nnz=int(np.asarray(A.indptr[-1])))
+    if telemetry.enabled():
+        # one event per sharded operator: the structural per-SpMV comm
+        # model (the introspection the reference gets from Legion's
+        # partition analysis) — eager SpMVs then accumulate against it
+        cs = comm_stats(dist)
+        telemetry.record(
+            "comm.spmv", model=True, shape=list(dist.shape), S=dist.S,
+            mode=dist.mode, layout=dist.layout,
+            halo_entries_per_spmv=cs["halo_entries_per_spmv"],
+            bytes=int(cs["spmv_collective_bytes_per_shard"]) * dist.S,
+        )
+    return dist
+
+
+def _shard_csr(A, mesh, axis, balanced, layout, halo_max_ratio, row_splits,
+               col_splits) -> DistCSR:
+    """``shard_csr`` without its span and its event."""
     from ..config import settings
 
     if mesh is None:
@@ -728,6 +893,44 @@ def shard_csr(
     R = max(int(np.max(np.diff(row_splits))), 1)
     C = max(int(np.max(np.diff(col_splits))), 1)
 
+    counts = np.diff(indptr)
+    nnz_row = np.repeat(np.arange(m, dtype=np.int64), counts)  # global row/nnz
+
+    if layout in ("auto", "dia"):
+        fit = _dia_fit(indices, nnz_row, m, n, row_splits, col_splits, S, C,
+                       halo_max_ratio)
+        if isinstance(fit, str):
+            if layout == "dia":
+                raise ValueError(f"layout='dia' cannot hold this operator: {fit}")
+        else:
+            offs, flat, HL, HR = fit
+            D = len(offs)
+            # a row's position in the padded layout, then an entry's, in
+            # place: a fresh array of nnz entries is a pass of page faults
+            pad_row = np.arange(m, dtype=np.int64) + np.repeat(
+                np.arange(S, dtype=np.int64) * R - row_splits[:-1],
+                np.diff(row_splits))
+            flat *= S * R
+            flat += np.repeat(pad_row, counts)
+
+            # one weighted bincount places every entry in its plane and sums
+            # duplicates as the other layouts' products do
+            def place(w):
+                return np.bincount(flat, weights=w, minlength=D * S * R)
+
+            planes = (place(data.real) + 1j * place(data.imag)
+                      if np.iscomplexobj(data) else place(data))
+            planes = planes.astype(data.dtype).reshape(D, S * R)
+            return DistCSR(
+                mesh=mesh, axis=axis, shape=(int(m), int(n)),
+                row_splits=row_splits, col_splits=col_splits, R=R, C=C,
+                HL=HL, HR=HR, mode="halo", layout="dia",
+                dtype=np.dtype(data.dtype),
+                dia_planes=tuple(jax.device_put(
+                    list(planes), [NamedSharding(mesh, P(axis))] * D)),
+                dia_offsets=tuple(int(o) for o in offs),
+            )
+
     # Remap global column ids -> padded coordinate space.
     col_shard = np.clip(
         np.searchsorted(col_splits, indices, side="right") - 1, 0, S - 1
@@ -742,7 +945,6 @@ def shard_csr(
     HL, HR, mode = windows_to_halo(windows, C, S, halo_max_ratio)
 
     # Row degree stats for layout choice.
-    counts = np.diff(indptr)
     kmax = int(counts.max()) if m else 0
     mean = max(nnz / max(m, 1), 1.0)
     if layout == "auto":
@@ -777,8 +979,6 @@ def shard_csr(
     # Vectorized layout construction: one pass of repeat/searchsorted/scatter
     # over the nnz (no per-row Python loops — a 36M-row matrix lays out in
     # seconds of host time, like ops/conv.csr_to_ell).
-    counts = np.diff(indptr)
-    nnz_row = np.repeat(np.arange(m, dtype=np.int64), counts)  # global row/nnz
     nnz_shard = np.clip(
         np.searchsorted(row_splits, nnz_row, side="right") - 1, 0, S - 1
     )
@@ -814,25 +1014,99 @@ def shard_csr(
         dist.nz_rows = jax.device_put(nz_rows, sharding2)
         dist.nz_cols = jax.device_put(nz_cols, sharding2)
         dist.nz_vals = jax.device_put(nz_vals, sharding2)
-    from .. import telemetry
-
-    if telemetry.enabled():
-        # one event per sharded operator: the structural per-SpMV comm
-        # model (the introspection the reference gets from Legion's
-        # partition analysis) — eager SpMVs then accumulate against it
-        cs = comm_stats(dist)
-        telemetry.record(
-            "comm.spmv", model=True, shape=[int(m), int(n)], S=S,
-            mode=mode, layout=layout,
-            halo_entries_per_spmv=cs["halo_entries_per_spmv"],
-            bytes=int(cs["spmv_collective_bytes_per_shard"]) * S,
-        )
     return dist
 
 
 # ---------------------------------------------------------------------------
 # Distributed CG — the full "training step" over the mesh (solver north star).
 # ---------------------------------------------------------------------------
+_CG_TRACES = _metrics.counter(
+    "dist.cg.traces",
+    help="traces of the compiled mesh-CG program (dist_cg/make_dist_cg): "
+    "one per program built, none for a call that reuses one",
+)
+#: compiled CG programs one DistCSR keeps (a fresh ``M`` each call would
+#: otherwise grow the table without bound)
+_CG_PROGRAMS_KEPT = 8
+
+
+def _cg_program(A: DistCSR, maxiter: int, conv_test_iters: int, M):
+    """The compiled mesh-CG loop ``solve(bp, xp, tol, atol, *blocks)`` of this
+    layout, kept on the ``DistCSR`` by what changes the program: the
+    iteration limit, the test cadence and the preconditioner (by identity).
+    ``tol``/``atol`` are traced scalars, dtypes and shapes are jit's own key.
+    The trace names it ``jit_dist_cg_<layout>``."""
+    key = (int(maxiter), int(conv_test_iters), id(M))
+    hit = A._cg_fns.get(key)
+    if hit is not None:
+        return hit[0]
+    # M may be a padded-vector callable (the historic contract) or a
+    # LinearOperator-shaped object (ISSUE 14: e.g. a multigrid V-cycle
+    # promoted via parallel.multigrid.vcycle_operator) — resolve to the
+    # traceable apply either way
+    precond = M.matvec if hasattr(M, "matvec") else M
+    # the loop captures the layout's compiled product, not the layout: kept
+    # on A, a closure over A would be a cycle that holds the device planes
+    # until a cyclic collection (which device-memory pressure never starts)
+    product = A._plan_fn("_spmv_fn", "dist.spmv", lambda: _build_spmv(A))
+    layout = A.layout
+
+    # The matrix blocks are ARGUMENTS of the compiled loop, never closure
+    # constants: captured, they are baked into the executable (2.68 GB at
+    # 8192^2 over four chips — too large to serialize, and on the TPU its
+    # outputs then came back without a sharding; chip_smoke.py, PR 22).
+    def solve(bp, xp, tol, atol, *blocks):
+        _CG_TRACES.inc()
+
+        def spmv(v):
+            return product(v, *blocks)
+
+        def rdot(u, v):
+            return jnp.real(jnp.vdot(u, v))
+
+        r = bp - spmv(xp)
+        bnorm2 = rdot(bp, bp)
+        tol2 = jnp.maximum(
+            jnp.asarray(tol, dtype=bnorm2.dtype) ** 2 * bnorm2,
+            jnp.asarray(atol, dtype=bnorm2.dtype) ** 2,
+        )
+
+        # rr = ||r||^2 rides in the state: the test reads it, and without a
+        # preconditioner it is rho too, so an iteration reduces twice
+        def body(state):
+            x, r, p, rho, rr, iters = state
+            if precond is None:
+                z, rho_new = r, rr.astype(r.dtype)
+            else:
+                z = precond(r)
+                rho_new = jnp.vdot(r, z)
+            beta = rho_new / jnp.where(rho == 0, 1, rho)
+            p = jnp.where(iters == 0, z, z + beta * p)
+            q = spmv(p)
+            pq = jnp.vdot(p, q)
+            alpha = rho_new / jnp.where(pq == 0, 1, pq)
+            r = r - alpha * q
+            return x + alpha * p, r, p, rho_new, rdot(r, r), iters + 1
+
+        def cond(state):
+            *_, rr, iters = state
+            tested = (iters % conv_test_iters == 0) | (iters == maxiter - 1)
+            converged = tested & (iters > 0) & (rr < tol2)
+            return (iters < maxiter) & ~converged
+
+        state = (xp, r, jnp.zeros_like(bp), jnp.zeros((), bp.dtype),
+                 rdot(r, r), jnp.zeros((), jnp.int32))
+        x, _, _, _, rr, iters = jax.lax.while_loop(cond, body, state)
+        return x, iters, rr < tol2
+
+    solve.__name__ = solve.__qualname__ = f"dist_cg_{layout}"
+    fn = jax.jit(solve)
+    while len(A._cg_fns) >= _CG_PROGRAMS_KEPT:
+        A._cg_fns.pop(next(iter(A._cg_fns)))
+    A._cg_fns[key] = (fn, M)  # M is held so that its id stays its own
+    return fn
+
+
 def make_dist_cg(
     A: DistCSR,
     tol: float = 1e-8,
@@ -841,66 +1115,16 @@ def make_dist_cg(
     conv_test_iters: int = 25,
     M=None,
 ):
-    """Build the compiled mesh-CG program once; returns run(bp, xp).
-
-    Callers that time repeated solves (benchmarks) should hold on to the
-    returned function — each call to :func:`dist_cg` builds a fresh
-    ``jax.jit`` wrapper and therefore recompiles.
-    """
+    """The compiled mesh-CG program with its tolerances bound: returns
+    ``run(bp, xp) -> (xp, iters, converged)``, asynchronous like any jitted
+    call. The explicit form of :func:`dist_cg`, for callers that fence and
+    time for themselves; both share the programs kept on ``A``."""
     if maxiter is None:
         maxiter = A.shape[0] * 10
-    # M may be a padded-vector callable (the historic contract) or a
-    # LinearOperator-shaped object (ISSUE 14: e.g. a multigrid V-cycle
-    # promoted via parallel.multigrid.vcycle_operator) — resolve to the
-    # traceable apply either way
-    if M is None:
-        precond = lambda r: r  # noqa: E731 - identity, traced away
-    elif hasattr(M, "matvec"):
-        precond = M.matvec
-    else:
-        precond = M
-
-    # The matrix blocks are ARGUMENTS of the compiled loop, never closure
-    # constants: captured, they are baked into the executable (2.68 GB at
-    # 8192^2 over four chips — too large to serialize, and on the TPU its
-    # outputs then came back without a sharding; chip_smoke.py, PR 22).
-    @jax.jit
-    def solve(bp, xp, *blocks):
-        def spmv(v):
-            return A.spmv_padded(v, blocks)
-
-        r = bp - spmv(xp)
-        bnorm2 = jnp.real(jnp.vdot(bp, bp))
-        tol2 = jnp.maximum(
-            jnp.asarray(tol, dtype=bnorm2.dtype) ** 2 * bnorm2,
-            jnp.asarray(atol, dtype=bnorm2.dtype) ** 2,
-        )
-
-        def body(state):
-            x, r, p, rho, iters = state
-            z = precond(r)
-            rho_new = jnp.vdot(r, z)
-            beta = rho_new / jnp.where(rho == 0, 1, rho)
-            p = jnp.where(iters == 0, z, z + beta * p)
-            q = spmv(p)
-            pq = jnp.vdot(p, q)
-            alpha = rho_new / jnp.where(pq == 0, 1, pq)
-            return x + alpha * p, r - alpha * q, p, rho_new, iters + 1
-
-        def cond(state):
-            _, r, _, _, iters = state
-            rnorm2 = jnp.real(jnp.vdot(r, r))
-            tested = (iters % conv_test_iters == 0) | (iters == maxiter - 1)
-            converged = tested & (iters > 0) & (rnorm2 < tol2)
-            return (iters < maxiter) & ~converged
-
-        state = (xp, r, jnp.zeros_like(bp), jnp.zeros((), bp.dtype), jnp.zeros((), jnp.int32))
-        x, r, _, _, iters = jax.lax.while_loop(cond, body, state)
-        rnorm2 = jnp.real(jnp.vdot(r, r))
-        return x, iters, rnorm2 < tol2
+    solve = _cg_program(A, maxiter, conv_test_iters, M)
 
     def run(bp, xp):
-        return solve(bp, xp, *A._blocks())
+        return solve(bp, xp, tol, atol, *A._blocks())
 
     return run
 
@@ -919,14 +1143,18 @@ def dist_cg(
 
     Mirrors ``linalg.cg`` (reference linalg.py:499) but every vector is a
     padded mesh-sharded array and every reduction (dot products, norms) is a
-    GSPMD ``psum`` inserted by XLA. One compiled ``lax.while_loop``; the host
-    syncs once at the end — strictly less blocking than the reference's
-    every-25-iterations future read.
+    GSPMD ``psum`` inserted by XLA. One compiled ``lax.while_loop``, kept on
+    ``A`` (:func:`_cg_program`): a second call with the same ``maxiter``,
+    ``conv_test_iters`` and ``M`` neither traces nor compiles, whatever its
+    tolerances. The host syncs once at the end — strictly less blocking
+    than the reference's every-25-iterations future read.
 
     ``M``: optional traceable preconditioner on padded vectors
     (zp = M(rp)) — e.g. a distributed AMG V-cycle. Convergence uses scipy
     semantics: ||r|| < max(tol * ||b||, atol). Returns (xp, iters, converged).
     """
+    from .. import telemetry
+
     bp = b if isinstance(b, jax.Array) and b.shape == (A.m_pad,) else A.pad_out_vector(np.asarray(b))
     xp = (
         jnp.zeros_like(bp)
@@ -937,12 +1165,19 @@ def dist_cg(
         A, tol=tol, atol=atol, maxiter=maxiter,
         conv_test_iters=conv_test_iters, M=M,
     )
-    import time as _time
-
-    t0 = _time.perf_counter()
-    xp, iters, converged = run(bp, xp)
-    iters, converged = int(iters), bool(converged)  # host fetch = fence
-    solve_s = _time.perf_counter() - t0
+    # One `dist.cg.solve` span a call: `dist.cg.dispatch` is the program's
+    # call until it returns (asynchronous: the host's part, and on a first
+    # call the trace and the compile), `dist.cg.wait` the fence, the fetch
+    # of the iteration count. Both are trace annotations and aggregates
+    # only; their lengths go onto the solve's event.
+    with telemetry.span("dist.cg.solve", layout=A.layout, S=A.S) as solve:
+        with telemetry.span("dist.cg.dispatch", emit=False) as sp:
+            xp, iters, converged = run(bp, xp)
+        dispatch_s = sp.dur_s or 0.0
+        with telemetry.span("dist.cg.wait", emit=False) as sp:
+            iters, converged = int(iters), bool(converged)  # host fetch = fence
+        solve.annotate(dispatch_s=round(dispatch_s, 9),
+                       wait_s=round(sp.dur_s or 0.0, 9), iters=iters)
     # the compiled loop runs one SpMV per iteration plus the initial
     # residual SpMV; commit that many executions of the traced program's
     # measured collective volume into the always-on metrics
@@ -950,7 +1185,6 @@ def dist_cg(
     led = getattr(A, "_comm_ledger", None)
     if led is not None and led.entries:
         led.commit(executions, A.S)
-    from .. import telemetry
 
     if telemetry.enabled():
         # whole-solve collective volume from the structural model x the
@@ -976,7 +1210,7 @@ def dist_cg(
             # omits — both shrink with iteration count)
             comm.record_measured(
                 "dist.cg", led, executions=executions, shards=A.S,
-                model_bytes=model_bytes, solve_s=solve_s,
+                model_bytes=model_bytes, solve_s=solve.dur_s,
                 mode=A.mode, iters=iters,
             )
         telemetry.record(

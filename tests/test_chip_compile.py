@@ -261,6 +261,42 @@ def test_dist_cg_program_compiles_on_four_chips(chip):
     assert used < HBM_BYTES
 
 
+def test_dist_cg_dia_program_compiles_on_four_chips(chip):
+    """The banded mesh CG of the cell pde_cg_4chip at the largest per-chip
+    grid its rule allows (3200^2), with uneven row blocks as nnz-balanced
+    splits give them (a block's halo is cut and placed dynamically): the
+    halo exchange and the reductions are in the program, nothing gathers,
+    and the program is named after the layout."""
+    from sparse_tpu.parallel import dist
+
+    mesh = Mesh(np.array(chip.devices[:4]), ("shards",))
+    S, g = 4, 3200
+    n = 2 * g  # weak scaling: the global side grows as sqrt(chips)
+    rows = np.array([g * g + n // 5, g * g - n // 5, g * g - n // 5,
+                     g * g + n // 5])
+    splits = np.concatenate([[0], np.cumsum(rows)])
+    R = int(rows.max())
+    A = dist.DistCSR(
+        mesh=mesh, axis="shards", shape=(S * g * g, S * g * g),
+        row_splits=splits, col_splits=splits, R=R, C=R, HL=n, HR=n,
+        mode="halo", layout="dia", dtype=np.dtype(np.float32),
+        dia_offsets=(-n, -1, 0, 1, n),
+    )
+    vec = _sds((S * R,), jnp.float32, NamedSharding(mesh, P("shards")))
+
+    solve = dist._cg_program(A, maxiter=300, conv_test_iters=25, M=None)
+    assert solve.__name__ == "dist_cg_dia"
+    c = solve.lower(vec, vec, 0.0, 0.0, *(vec,) * 5).compile()  # 5 planes
+    hlo = c.as_text()
+    assert "collective-permute" in hlo  # the halo exchange
+    assert "all-reduce" in hlo  # the CG dot products
+    assert "gather(" not in hlo  # shifted slices, no index loads
+    assert "dist.halo" in hlo and "dist.local_spmv" in hlo
+    used = _device_bytes(c)
+    print(f"dist_cg dia g={g}/chip: {used / 2**30:.2f} GiB per device")
+    assert used < HBM_BYTES
+
+
 # ---------------------------------------------------------------------------
 # the two opt-in SELL Pallas kernels: Mosaic refuses both today. These pin
 # the refusal, so the PR that makes them lower (or deletes them, ROADMAP S3)

@@ -340,6 +340,44 @@ def test_layout_span_fires_once_an_operator(tel, monkeypatch):
     assert telemetry.summary()["spans"]["layout.dia_build"]["n"] == 2
 
 
+# -- the mesh: shard_csr's build and dist_cg's solve (PR 27) -------------------
+def test_mesh_spans_carry_their_fields(tel):
+    from sparse_tpu.parallel import dist_cg, get_mesh, shard_csr
+
+    D0, b = _pde()
+    Dd = shard_csr(D0.tocsr(), mesh=get_mesh(4))
+    for _ in range(2):
+        dist_cg(Dd, b, tol=0.0, maxiter=12)
+    spans = [e for e in telemetry.events("span")]
+    (build,) = [e for e in spans if e["name"] == "dist.shard_csr"]
+    assert {k: build[k] for k in ("layout", "mode", "S", "rows", "nnz")} == {
+        "layout": "dia", "mode": "halo", "S": 4, "rows": b.shape[0],
+        "nnz": int(D0.tocsr().nnz)}
+    assert (build["R"], build["HL"], build["HR"]) == (Dd.R, Dd.HL, Dd.HR)
+    solves = [e for e in spans if e["name"] == "dist.cg.solve"]
+    assert len(solves) == 2
+    for e in solves:
+        assert e["iters"] == 12 and e["layout"] == "dia" and e["S"] == 4
+        assert 0 <= e["dispatch_s"] and 0 <= e["wait_s"]
+        assert e["dispatch_s"] + e["wait_s"] <= e["dur_s"]
+    # the first call's dispatch holds the trace and the compile
+    assert solves[0]["dispatch_s"] > solves[1]["dispatch_s"]
+    agg = telemetry.summary()["spans"]
+    assert agg["dist.cg.dispatch"]["n"] == agg["dist.cg.wait"]["n"] == 2
+    assert not [e for e in spans
+                if e["name"] in ("dist.cg.dispatch", "dist.cg.wait")]
+
+
+def test_off_the_mesh_records_no_span(off):
+    from sparse_tpu.parallel import dist_cg, get_mesh, shard_csr
+
+    D0, b = _pde()
+    Dd = shard_csr(D0.tocsr(), mesh=get_mesh(4))
+    _, iters, _ = dist_cg(Dd, b, tol=0.0, maxiter=12)
+    assert iters == 12
+    assert telemetry.events() == [] and telemetry.summary()["spans"] == {}
+
+
 # -- telemetry off -----------------------------------------------------------
 def test_off_every_site_gets_the_null_span_and_nothing_is_recorded(
         off, monkeypatch):
