@@ -18,8 +18,8 @@ Classes
   SpMV/SpMM via the SELL slab formulation (vmap-compatible XLA path;
   the Pallas row-block kernel gains a batch grid dimension under
   ``spmv_mode='pallas'``, with the usual one-time XLA failover).
-* :class:`BatchedDIA` — stacked diagonal planes for banded patterns,
-  batched zero-gather SpMV (vmapped ``ops.dia_spmv``).
+* :class:`BatchedDIA` — stacked diagonal planes (row layout) for banded
+  patterns, batched zero-gather SpMV (``ops.dia_spmv.dia_planes_matvec``).
 * :func:`make_batched_operator` — coercion entry point (stacks of
   csr_arrays / scipy matrices, dense ``[B, m, n]`` stacks, callables).
 
@@ -130,35 +130,87 @@ class SparsityPattern:
         telemetry.count("batch.pattern_pack")
         return _SellPatternPack(plan, idx_slabs, pos, srcs)
 
-    # -- DIA pattern pack (plan-cached) ------------------------------------
+    # -- plane (DIA, row layout) pattern pack (plan-cached) ------------------
+    def plane_pack(self):
+        """The banded rule's answer for this pattern, via the plan cache:
+        its :class:`_PlanePatternPack` when ``dia.few_diagonals`` lays the
+        pattern out as planes (the rule ``linalg`` and ``shard_csr`` use,
+        counted here on the host arrays), else None. What
+        :class:`~sparse_tpu.batch.service.SolveSession` asks before it
+        builds a bucket program's matvec. The answer, "no" included, is a
+        vault artifact like the SELL pack, so a warm restart builds
+        neither the count nor the map."""
+
+        def vault_key():
+            from ..vault import _codecs
+
+            return _codecs.plane_pattern_key(self)
+
+        # a "no" is kept as False: the plan cache does not keep a None
+        return plan_cache.get(
+            self, "planes.pattern", self._build_planes,
+            vault_kind="plane_pattern", vault_key=vault_key,
+        ) or None
+
     def dia_pack(self, max_diags: int | None = None):
-        """Offsets + ``[D, n]`` nnz source map for banded patterns, via the
-        plan cache; raises ``ValueError`` when the pattern exceeds
-        ``max_diags`` (default ``settings.dia_max_diags``) diagonals."""
-        pack = plan_cache.get(self, "dia.pattern",
+        """The explicit banded view (``BatchedCSR.todia``): the same pack
+        whatever its fill; raises ``ValueError`` when the pattern exceeds
+        ``max_diags`` (default ``settings.dia_max_diags``) diagonals, or
+        stores an entry twice (a plane has one slot for it)."""
+        return plan_cache.get(self, "dia.pattern",
                               lambda: self._build_dia(max_diags))
-        return pack
+
+    def _entry_rows(self):
+        return np.repeat(np.arange(self.shape[0], dtype=np.int64),
+                         self.indptr[1:] - self.indptr[:-1])
+
+    def _build_planes(self):
+        from ..dia import banded_offsets
+
+        rows = self._entry_rows()
+        banded = banded_offsets(
+            self.indices.astype(np.int64) - rows, self.shape[0]
+        )
+        if banded is None:
+            return False
+        # a pattern that stores an entry twice keeps the SELL program,
+        # which sums what it gathers
+        return self._plane_map(*banded, rows) or False
 
     def _build_dia(self, max_diags):
         limit = int(max_diags or settings.dia_max_diags)
-        counts = self.indptr[1:] - self.indptr[:-1]
-        rows = np.repeat(np.arange(self.shape[0], dtype=np.int64), counts)
-        offs_all = self.indices.astype(np.int64) - rows
-        offsets = np.unique(offs_all)
+        rows = self._entry_rows()
+        offsets, k_of = np.unique(
+            self.indices.astype(np.int64) - rows, return_inverse=True
+        )
         if len(offsets) > limit:
             raise ValueError(
                 f"pattern has {len(offsets)} distinct diagonals "
                 f"(> {limit}); not DIA-shaped"
             )
-        D, n = len(offsets), self.shape[1]
-        k_of = np.searchsorted(offsets, offs_all)
-        src = np.full((D, n), -1, dtype=np.int64)
-        # scipy DIA convention: data[k, j] holds A[j - o_k, j]
-        src[k_of, self.indices.astype(np.int64)] = np.arange(self.nnz)
-        src_dt = np.int32 if self.nnz < 2**31 else np.int64
-        (src_dev,) = commit_to_exec_device((jnp.asarray(src.astype(src_dt)),))
-        valid = jnp.asarray(src >= 0)
-        return (tuple(int(o) for o in offsets), src_dev, valid)
+        pack = self._plane_map(offsets, k_of, rows)
+        if pack is None:
+            raise ValueError(
+                "pattern stores duplicate (row, col) entries; sum them "
+                "(scipy's sum_duplicates) before the DIA view"
+            )
+        return pack
+
+    def _plane_map(self, offsets, k_of, rows):
+        """Row layout, as on the mesh (``parallel/dist.py``): slot ``i`` of
+        plane ``k`` holds ``A[i, i + o_k]``; ``src`` is its position in a
+        CSR value row, -1 where the diagonal leaves the matrix (or the
+        pattern has no entry there). None when two stored entries share
+        a slot (a non-canonical CSR with duplicates): the map is one
+        gather per slot and would keep only the last of them."""
+        src = np.full((len(offsets), self.shape[0]), -1,
+                      dtype=np.int32 if self.nnz < 2**31 else np.int64)
+        src[k_of, rows] = np.arange(self.nnz)
+        if np.count_nonzero(src >= 0) != self.nnz:
+            return None
+        (src_dev,) = commit_to_exec_device((jnp.asarray(src),))
+        telemetry.count("batch.pattern_pack")
+        return _PlanePatternPack(tuple(int(o) for o in offsets), src_dev)
 
     def __repr__(self):
         return (
@@ -170,6 +222,7 @@ class _SellPatternPack:
     """Device-resident pattern half of the batched SELL layout."""
 
     __slots__ = ("plan", "idx_slabs", "pos", "srcs")
+    form = "sell"  # what `batch.dispatch` reports as `matvec`
 
     def __init__(self, plan, idx_slabs, pos, srcs):
         self.plan, self.idx_slabs, self.pos, self.srcs = (
@@ -189,6 +242,57 @@ class _SellPatternPack:
                           jnp.zeros((), dtype=values.dtype))
             )
         return tuple(out)
+
+
+class _PlanePatternPack:
+    """Device-resident pattern half of the batched plane (DIA, row)
+    layout: static ``offsets`` and the ``[D, m]`` slot -> nnz-position
+    map every lane's values gather through."""
+
+    __slots__ = ("offsets", "src")
+    form = "planes"
+
+    def __init__(self, offsets, src):
+        self.offsets, self.src = offsets, src
+
+    def pack_values(self, values):
+        """Gather a ``(B, nnz)`` value stack into ``(B, D, m)`` planes
+        (empty slots zero) — jit-safe, one gather."""
+        values = jnp.asarray(values)
+        return jnp.where((self.src >= 0)[None, :, :],
+                         values[:, jnp.maximum(self.src, 0)],
+                         jnp.zeros((), dtype=values.dtype))
+
+
+def pattern_matvec(pattern: SparsityPattern):
+    """``(pack, product)``: the matvec an exact ``cg``/``bicgstab`` bucket
+    program compiles in, chosen from the pattern and nothing else. Planes
+    for a pattern the banded rule lays out as planes
+    (:meth:`SparsityPattern.plane_pack`): D shifted multiply-adds, no index
+    loads; else the SELL slabs' gathers; the form not chosen is not built.
+    ``pack.pack_values`` gathers a dispatch's ``(B, nnz)`` value stack into
+    the form's layout once; ``product(vals, X)`` is the batched ``A @ X``
+    over it; ``pack.form`` names the choice, and the program built on it
+    carries that name as its ``matvec``."""
+    from ..ops.dia_spmv import dia_planes_matvec
+
+    pack = pattern.plane_pack()
+    if pack is not None:
+        offsets = pack.offsets
+
+        def product(vals, X):
+            return dia_planes_matvec(vals, offsets, X)
+
+        return pack, product
+    pack = pattern.sell_pack()
+    idx_slabs, pos, zero_rows = pack.idx_slabs, pack.pos, pack.plan.zero_rows
+
+    def product(vals, X):
+        return spmv_ops.csr_spmv_sell_batched(
+            idx_slabs, vals, pos, X, zero_rows
+        )
+
+    return pack, product
 
 
 class BatchedOperator:
@@ -420,19 +524,21 @@ class BatchedCSR(BatchedOperator):
 
 
 class BatchedDIA(BatchedOperator):
-    """Stacked diagonal planes ``(B, D, n)`` over shared offsets — the
+    """Stacked diagonal planes ``(B, D, m)`` over shared offsets — the
     batched zero-gather SpMV for banded patterns (every PDE/mesh serving
-    shape): one vmapped ``ops.dia_spmv.dia_spmv_xla`` pass, no index
-    loads at all."""
+    shape). ROW layout, as on the mesh and in the session's bucket
+    program: ``data[b, k, i]`` holds ``A_b[i, i + o_k]`` (scipy's DIA
+    indexes a plane by column; :meth:`lane` converts). One
+    ``ops.dia_spmv.dia_planes_matvec`` pass, no index loads at all."""
 
     def __init__(self, data, offsets, shape):
         data = asjnp(data)
         if data.ndim != 3:
-            raise ValueError("BatchedDIA data must be (B, D, n)")
+            raise ValueError("BatchedDIA data must be (B, D, m)")
         self.data = data
         self.offsets = tuple(int(o) for o in offsets)
         m, n = int(shape[0]), int(shape[1])
-        if data.shape[1] != len(self.offsets) or data.shape[2] != n:
+        if data.shape[1] != len(self.offsets) or data.shape[2] != m:
             raise ValueError(
                 f"data {data.shape} inconsistent with offsets "
                 f"D={len(self.offsets)} and shape {shape}"
@@ -442,24 +548,26 @@ class BatchedDIA(BatchedOperator):
 
     @classmethod
     def from_batched_csr(cls, bcsr: BatchedCSR, max_diags=None):
-        offsets, src, valid = bcsr.pattern.dia_pack(max_diags=max_diags)
-        planes = jnp.where(
-            valid[None, :, :],
-            bcsr.values[:, jnp.maximum(src, 0)],
-            jnp.zeros((), dtype=bcsr.values.dtype),
-        )
-        return cls(planes, offsets, bcsr.pattern.shape)
+        pack = bcsr.pattern.dia_pack(max_diags=max_diags)
+        return cls(pack.pack_values(bcsr.values), pack.offsets,
+                   bcsr.pattern.shape)
 
     def lane(self, i: int):
+        """Lane ``i`` as a ``dia_array`` (scipy convention: plane ``k``
+        indexed by column, ``data[k, j] = A[j - o_k, j]``)."""
         from ..dia import dia_array
 
-        return dia_array(
-            (self.data[i], np.asarray(self.offsets)),
-            shape=(self.shape[1], self.shape[2]),
-        )
+        _, m, n = self.shape
+        pad = max((abs(o) for o in self.offsets), default=0)
+        rows = jnp.pad(self.data[i], ((0, 0), (pad, pad + max(n - m, 0))))
+        data = jnp.stack([
+            jax.lax.slice_in_dim(rows[k], pad - o, pad - o + n)
+            for k, o in enumerate(self.offsets)
+        ]) if self.offsets else jnp.zeros((0, n), self.dtype)
+        return dia_array((data, np.asarray(self.offsets)), shape=(m, n))
 
     def matvec(self, X):
-        from ..ops.dia_spmv import dia_spmv_xla
+        from ..ops.dia_spmv import dia_planes_matvec
 
         X = asjnp(X)
         if X.ndim != 2 or X.shape != (self.batch, self.shape[2]):
@@ -468,10 +576,7 @@ class BatchedDIA(BatchedOperator):
                 f"{self.shape[2]}); got {X.shape}"
             )
         telemetry.count("batch.spmv")
-        offsets, shape = self.offsets, (self.shape[1], self.shape[2])
-        return jax.vmap(
-            lambda d, x: dia_spmv_xla(d, offsets, x, shape)
-        )(self.data, X)
+        return dia_planes_matvec(self.data, self.offsets, X)
 
     def __repr__(self):
         return (

@@ -284,6 +284,37 @@ def few_diagonals(n_diags: int, n: int, nnz: int) -> bool:
             and n_diags * n <= settings.dia_max_fill * nnz)
 
 
+def banded_offsets(off: np.ndarray, n: int):
+    """``(offsets, plane)``: the distinct diagonals (column - row, ascending)
+    of a host CSR whose per-entry ``off`` is given, and each entry's index
+    into them, when the operator is banded by the rule above
+    (:func:`few_diagonals`); else None. The diagonals are counted on the
+    host, where ``parallel.dist.shard_csr`` and ``batch.SparsityPattern``
+    hold their arrays. ``off`` is the caller's scratch: it is overwritten."""
+    from .config import settings
+
+    nnz = off.shape[0]
+    if nnz == 0:
+        return None
+    # a general matrix is turned away by a strided sample of its entries
+    sample = off[:: max(nnz // 8192, 1)]
+    if len(np.unique(sample)) > settings.dia_max_diags:
+        return None
+    lo, hi = int(off.min()), int(off.max())
+    table = hi - lo < (1 << 22)  # a table over the band: two passes, no sort
+    if table:
+        off -= lo
+        seen = np.bincount(off, minlength=hi - lo + 1) > 0
+        offs = np.flatnonzero(seen) + lo
+    else:
+        offs, plane = np.unique(off, return_inverse=True)
+    if not few_diagonals(len(offs), n, nnz):
+        return None
+    if table:
+        plane = (np.cumsum(seen) - 1)[off]
+    return offs, plane
+
+
 def _coo_to_dia(c):
     """COO -> (data, offsets, shape). Host-syncs the distinct-offset set."""
     m, n = c.shape

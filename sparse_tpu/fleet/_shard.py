@@ -126,10 +126,6 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
 
     from ..parallel.mesh import mesh_fingerprint
 
-    pack = pattern.sell_pack()
-    idx_slabs, pos, zero_rows = (
-        pack.idx_slabs, pack.pos, pack.plan.zero_rows
-    )
     loop = krylov._cg_loop if solver == "cg" else krylov._bicgstab_loop
     cti = int(conv_test_iters)
     led = batch_ledger(mesh_fingerprint(mesh), solver, bkt, dt)
@@ -156,6 +152,10 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
         inner_iters = int(mixed["inner_iters"])
         max_outer = int(mixed["max_outer"])
         eta = float(mixed["eta"])
+        pack = pattern.sell_pack()
+        idx_slabs, pos, zero_rows = (
+            pack.idx_slabs, pack.pos, pack.plan.zero_rows
+        )
 
         def body(values, rhs, x0, tols, maxiter):
             req_dt = values.dtype
@@ -186,15 +186,16 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
 
         out_specs = (P(axis), P(axis), P(axis), P(axis), P())
     else:
+        # the single-device program's matvec, so its lanes stay
+        # bit-identical to that program's: chosen from the pattern
+        from ..batch.operator import pattern_matvec
+
+        pack, product = pattern_matvec(pattern)
+
         def body(values, rhs, x0, tols, maxiter):
-            vals = pack.pack_values(values)
-
-            def mv(X):
-                return spmv_ops.csr_spmv_sell_batched(
-                    idx_slabs, vals, pos, X, zero_rows
-                )
-
-            fmv = krylov._maybe_faulty_mv(mv)
+            fmv = krylov._maybe_faulty_mv(
+                partial(product, pack.pack_values(values))
+            )
             # lane-local numeric factorization from this shard's value
             # stack; the factory's maps ride in as replicated constants
             Mvec = None if m_factory is None else m_factory(values, fmv)
@@ -222,4 +223,5 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
     def run(values, rhs, x0, tols, maxiter):
         return sharded(values, rhs, x0, tols, jnp.asarray(maxiter))
 
+    run.matvec = pack.form  # as the single-device program tags it
     return run

@@ -1,7 +1,7 @@
 """Background pattern onboarding: the control plane of the ingest tier.
 
 Everything expensive about an unseen matrix — parsing, the distributed
-sort, the SELL pack, bucket-program compiles, vault persistence — runs
+sort, the pattern pack, bucket-program compiles, vault persistence — runs
 on ONE bounded daemon worker (:class:`Onboarder`, generalizing the
 warm-replay thread of ISSUE 13) so the serving path never blocks on an
 arrival. The serving-side handle is :class:`IngestTicket`: future-style
@@ -396,7 +396,7 @@ class Onboarder:
         ticket.state = "onboarding"
         pat = SparsityPattern.from_csr(csr)
         pattern = self.session._patterns.setdefault(pat.fingerprint, pat)
-        pattern.sell_pack()
+        # the prebuild packs the pattern in the form its program compiles
         try:
             self.session._prebuild(pattern, self.session.solver,
                                    int(bucket), dtype)

@@ -44,3 +44,26 @@ def dia_spmv_xla(data, offsets: tuple, x, shape: tuple, acc_dtype=None):
     for k, o in enumerate(offsets):
         y = y + jax.lax.dynamic_slice_in_dim(padded[k], B + int(o), m)
     return y
+
+
+def dia_planes_matvec(planes, offsets: tuple, X):
+    """``Y[..., i] = sum_k planes[..., k, i] * X[..., i + o_k]``: the banded
+    product in the ROW layout (plane ``k`` holds ``A[i, i + o_k]`` at slot
+    ``i``, zero where the diagonal leaves the matrix), over any leading
+    (batch) axes. ``X`` is padded once by the band's overhangs and every
+    plane multiplies a static slice of it: no index loads, and no
+    ``(…, D, n)`` temporary (the product-then-shift of
+    :func:`dia_spmv_xla` makes two). ``offsets`` is a static tuple."""
+    m, n = planes.shape[-1], X.shape[-1]
+    if not offsets:
+        return jnp.zeros(X.shape[:-1] + (m,),
+                         jnp.result_type(planes.dtype, X.dtype))
+    left = max(-min(offsets), 0)
+    right = max(max(offsets) + m - n, 0)
+    Xp = jnp.pad(X, ((0, 0),) * (X.ndim - 1) + ((left, right),))
+    out = None
+    for k, o in enumerate(offsets):
+        seg = jax.lax.slice_in_dim(Xp, left + o, left + o + m, axis=X.ndim - 1)
+        term = planes[..., k, :] * seg
+        out = term if out is None else out + term
+    return out

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..dia import banded_offsets
 from ..telemetry import _metrics
 from ..utils import asjnp
 from . import comm
@@ -756,38 +757,6 @@ def shard_csr_cols(
     )
 
 
-def _banded_offsets(off: np.ndarray, n: int):
-    """``(offsets, plane)``: the distinct diagonals (column - row, ascending)
-    of a host CSR whose per-entry ``off`` is given, and each entry's index
-    into them, when the operator is banded by the one-chip rule
-    (``dia.few_diagonals``); else None. The diagonals are counted here on
-    the host, where ``shard_csr`` holds the arrays. ``off`` is the caller's
-    scratch: it is overwritten."""
-    from ..config import settings
-    from ..dia import few_diagonals
-
-    nnz = off.shape[0]
-    if nnz == 0:
-        return None
-    # a general matrix is turned away by a strided sample of its entries
-    sample = off[:: max(nnz // 8192, 1)]
-    if len(np.unique(sample)) > settings.dia_max_diags:
-        return None
-    lo, hi = int(off.min()), int(off.max())
-    table = hi - lo < (1 << 22)  # a table over the band: two passes, no sort
-    if table:
-        off -= lo
-        seen = np.bincount(off, minlength=hi - lo + 1) > 0
-        offs = np.flatnonzero(seen) + lo
-    else:
-        offs, plane = np.unique(off, return_inverse=True)
-    if not few_diagonals(len(offs), n, nnz):
-        return None
-    if table:
-        plane = (np.cumsum(seen) - 1)[off]
-    return offs, plane
-
-
 def _dia_fit(indices, nnz_row, m, n, row_splits, col_splits, S, C,
              halo_max_ratio):
     """Whether the 'dia' layout can hold the operator: ``(offsets, each
@@ -796,7 +765,7 @@ def _dia_fit(indices, nnz_row, m, n, row_splits, col_splits, S, C,
 
     if m != n or not np.array_equal(row_splits, col_splits):
         return "its rows and columns do not share one split"
-    banded = _banded_offsets(indices.astype(np.int64) - nnz_row, n)
+    banded = banded_offsets(indices.astype(np.int64) - nnz_row, n)
     if banded is None:
         return ("it is not banded by settings.dia_max_diags and "
                 "settings.dia_max_fill")

@@ -109,6 +109,11 @@ class _Program:
                 self.compiled = None
         return self.fn(*args)
 
+    def __getattr__(self, name):
+        # what the builder tagged its callable with (a bucket program's
+        # `matvec`) reads the same through the compiled wrapper
+        return getattr(object.__getattribute__(self, "fn"), name)
+
 
 def _register(program: str, info: dict) -> None:
     with _LOCK:

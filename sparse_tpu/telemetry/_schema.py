@@ -150,8 +150,9 @@ KINDS: dict[str, frozenset] = {
     "plan_cache.compile": frozenset({"program"}),
     # -- vault (sparse_tpu.vault, the persistent plan-cache tier) -----------
     # one artifact write attempt: artifact is the codec kind ('pattern' |
-    # 'sell_pattern' | 'prepared_csr' | 'prepared_dia'), ok whether the
-    # atomic write landed (False = cleaned up, vault unchanged)
+    # 'sell_pattern' | 'plane_pattern' | 'prepared_csr' | 'prepared_dia'),
+    # ok whether the atomic write landed (False = cleaned up, vault
+    # unchanged)
     "vault.store": frozenset({"artifact", "ok"}),
     # one successful verified artifact load (disk-tier hit)
     "vault.load": frozenset({"artifact", "hit"}),

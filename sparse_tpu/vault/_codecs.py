@@ -20,6 +20,11 @@ Registered kinds:
                       shape): what the warm-start manifest replays.
 * ``sell_pattern``  — a pattern's ``_SellPatternPack`` (plan, idx slabs,
                       pos, per-slab nnz source maps).
+* ``plane_pattern`` — a pattern's ``_PlanePatternPack`` (offsets, the
+                      ``[D, m]`` slot map), or the banded rule's "no":
+                      what ``SparsityPattern.plane_pack`` answers, so a
+                      warm restart neither counts diagonals nor rebuilds
+                      the map.
 * ``prepared_csr``  — a full ``PreparedCSR`` (plan, idx+val slabs, pos).
 * ``prepared_dia``  — a ``PreparedDia`` (DiaPlan geometry incl. the
                       autotuned row tile, packed plane buffer) — the
@@ -94,6 +99,13 @@ def pattern_key(pattern) -> str:
 
 def sell_pattern_key(pattern) -> str:
     return digest("sellpat", pattern.fingerprint[2], *_sell_settings())
+
+
+def plane_pattern_key(pattern) -> str:
+    return digest(
+        "planepat", pattern.fingerprint[2],
+        "diags", settings.dia_max_diags, "fill", settings.dia_max_fill,
+    )
 
 
 def prepared_csr_key(indptr, indices, data, shape) -> str:
@@ -176,6 +188,24 @@ def _dec_sell_pattern(meta, arrays):
     srcs = _commit([arrays[f"src{i}"] for i in range(int(meta["nsrcs"]))])
     (pos,) = _commit([arrays["pos"]])
     return _SellPatternPack(plan, idx_slabs, pos, srcs)
+
+
+# -- plane_pattern (_PlanePatternPack, or False: not laid out as planes) -----
+def _enc_plane_pattern(pack):
+    if pack is False:
+        return {"banded": False, "dtype": "structure"}, {}
+    meta = {"banded": True, "dtype": "structure",
+            "offsets": [int(o) for o in pack.offsets]}
+    return meta, {"src": np.asarray(pack.src)}
+
+
+def _dec_plane_pattern(meta, arrays):
+    from ..batch.operator import _PlanePatternPack
+
+    if not meta["banded"]:
+        return False
+    (src,) = _commit([arrays["src"]])
+    return _PlanePatternPack(tuple(int(o) for o in meta["offsets"]), src)
 
 
 # -- prepared_csr (PreparedCSR) ---------------------------------------------
@@ -289,6 +319,7 @@ def _dec_ingest_fpindex(meta, arrays):
 
 register("pattern", _enc_pattern, _dec_pattern)
 register("sell_pattern", _enc_sell_pattern, _dec_sell_pattern)
+register("plane_pattern", _enc_plane_pattern, _dec_plane_pattern)
 register("prepared_csr", _enc_prepared_csr, _dec_prepared_csr)
 register("prepared_dia", _enc_prepared_dia, _dec_prepared_dia)
 register("precond_diag", _enc_precond_diag, _dec_precond_diag)

@@ -30,6 +30,7 @@ from sparse_tpu.batch import (
     pad_pattern,
     pow2_ceil,
 )
+from sparse_tpu.batch.operator import pattern_matvec
 from sparse_tpu.config import settings
 
 
@@ -411,7 +412,7 @@ def test_session_one_miss_per_bucket():
     mats, rhs = _tridiag_stack(B=4, seed=19)
     ses = SolveSession("cg", batch_max=4)
     pattern = ses.pattern_of(mats[0])
-    pattern.sell_pack()  # pattern warm (its own, separate entry)
+    pattern_matvec(pattern)  # pattern pack warm (its own, separate entry)
     before = plan_cache.snapshot()
     ses.solve_many(mats, rhs, tol=1e-8, maxiter=100)
     d = plan_cache.delta(before)

@@ -220,6 +220,13 @@ def test_bucket_program_ops_carry_their_scope(one_chip, monkeypatch):
     ).compile().as_text()
     for scope in ("bucket.matvec", "bucket.dots", "bucket.axpy"):
         assert f"/{scope}/" in hlo, scope
+    # a banded pattern's loop multiplies by planes (PR 28): no gather inside
+    # an iteration, and the loop's vectors are laid out row-minor (the gather
+    # form's were lane-minor: 4 lanes in a 128-wide tile)
+    body = [ln for ln in hlo.splitlines() if "/while/body/" in ln]
+    assert body and not any("gather" in ln for ln in body)
+    assert any(f"f32[{B},{n}]{{1,0:" in ln for ln in body)
+    assert not any(f"f32[{B},{n}]{{0,1:" in ln for ln in body)
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,7 @@ import sparse_tpu
 from sparse_tpu import fleet, linalg, plan_cache, telemetry, vault
 from sparse_tpu.batch import SolveSession
 from sparse_tpu.batch import bucket as bucketing
-from sparse_tpu.batch.operator import SparsityPattern
+from sparse_tpu.batch.operator import SparsityPattern, pattern_matvec
 from sparse_tpu.config import settings
 from sparse_tpu.parallel.mesh import mesh_fingerprint
 from sparse_tpu.resilience import faults
@@ -151,7 +151,7 @@ def test_fleet_off_env_default_is_single():
 def test_one_plan_cache_miss_per_bucket_and_mesh():
     mats, rhs = _traffic(B=16)
     pat = SparsityPattern.from_csr(mats[0])
-    pat.sell_pack()  # warm the pattern pack outside the window
+    pattern_matvec(pat)  # warm the pattern pack (of its form) outside the window
     vals = [np.asarray(A.data) for A in mats]
 
     def serve(ses):

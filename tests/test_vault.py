@@ -250,6 +250,31 @@ class TestRoundTrip:
         for a, b in zip(p1.srcs, p0.srcs):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.parametrize("banded", [True, False])
+    def test_plane_pattern_pack(self, banded):
+        """The banded rule's answer is an artifact, its "no" included: a
+        restart neither counts the diagonals nor rebuilds the slot map."""
+        A = _spd(100) if banded else _skewed(100)
+        p0 = SparsityPattern.from_csr(A).plane_pack()
+        assert (p0 is not None) == banded
+        plan_cache.clear()
+        snap = plan_cache.snapshot()
+        p1 = SparsityPattern.from_csr(A).plane_pack()
+        d = plan_cache.delta(snap)
+        assert d["disk_hits"] == 1 and d["misses"] == 0
+        assert (p1 is not None) == banded
+        if banded:
+            assert p1.offsets == p0.offsets == (-1, 0, 1)
+            np.testing.assert_array_equal(np.asarray(p1.src),
+                                          np.asarray(p0.src))
+            assert p1.src.dtype == p0.src.dtype
+
+    def test_plane_pattern_key_separates_settings(self, monkeypatch):
+        pat = SparsityPattern.from_csr(_spd(80))
+        k1 = _codecs.plane_pattern_key(pat)
+        monkeypatch.setattr(settings, "dia_max_fill", 1.0)
+        assert _codecs.plane_pattern_key(pat) != k1
+
     @pytest.mark.parametrize("dtype", [np.float32, np.float64, np.complex64])
     def test_prepared_csr_matvec_parity(self, dtype, monkeypatch):
         monkeypatch.setattr(settings, "spmv_mode", "sell")
@@ -390,6 +415,20 @@ class TestWarmRestart:
         d = plan_cache.delta(snap)
         assert d["misses"] == 0 and d["hits"] >= 1
         np.testing.assert_allclose(X0, X1, atol=1e-12)
+
+    def test_replay_packs_only_the_form_the_program_compiles(self):
+        """A banded pattern's exact program multiplies by planes: neither
+        the first process nor the replay builds (or stores) a SELL pack."""
+        mats, rhs = _traffic()
+        SolveSession("cg", warm_start=False).solve_many(mats, rhs, tol=1e-10)
+        plan_cache.clear()
+        ses = SolveSession("cg", warm_async=False)
+        assert ses.warm_replayed >= 1
+        (pat,) = ses._patterns.values()
+        assert plan_cache.lookup(pat, "planes.pattern") is not None
+        assert plan_cache.lookup(pat, "sell.pattern") is None
+        kinds = set(os.listdir(os.path.join(settings.vault, "objects")))
+        assert "plane_pattern" in kinds and "sell_pattern" not in kinds
 
     def test_replay_emits_event_and_counts(self):
         settings.telemetry = True
