@@ -285,23 +285,13 @@ def kernel_sweep(n: int, platform: str) -> dict:
         sprep = PreparedCSR(indptr, cols, vals, (N, N))
         sell_bytes = sprep.plan.stored_slots * 8 + N * 8
         attempt("sell_xla", sprep.matvec_xla, sell_bytes)
-        # the Pallas row-block kernel runs in interpret mode off the TPU
-        # (pure debugging) and is not selected on it (Mosaic refuses the
-        # in-VMEM gather, tests/test_chip_compile.py) — the measured
-        # path IS sell_xla above
-        out["sell_pallas"] = {"note": "not selected on TPU, interpret-only off it; measured path is sell_xla"}
     except Exception as e:
         out["sell_xla"] = {"error": str(e)[:200]}
         traceback.print_exc(file=sys.stderr)
 
     if platform == "tpu":
-        from sparse_tpu.kernels.dia_spmv import PreparedDia, dia_spmv_pallas
+        from sparse_tpu.kernels.dia_spmv import PreparedDia
 
-        attempt(
-            "dia_pallas",
-            lambda xx: dia_spmv_pallas(planes, offsets, xx, (N, N)),
-            dia_bytes,
-        )
         # packed prepared layout: planes resident, per-call cost is the
         # kernel plus x pad / y trim (the honest drop-in form)
         prep = PreparedDia(planes, offsets, (N, N))

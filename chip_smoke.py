@@ -298,10 +298,8 @@ def phase_general(args, jax, sparse, linalg, telemetry, failovers):
     telemetry.reset()
     # the one-time layout build (host_scope), before the commit to the chip
     with clock() as t:
-        built = A._maybe_sell() or A._maybe_ell()
-    if built is not None:
-        planes = built.slabs[0][1] if hasattr(built, "slabs") else built[1]
-        say(f"  layout built in {t.s:.2f} s; value planes on {where(planes)}")
+        A.prepare()
+    say(f"  layouts built in {t.s:.2f} s")
     x = rng.standard_normal(rows).astype(np.float32)
     with clock() as t:
         y = jax.block_until_ready(A @ x)

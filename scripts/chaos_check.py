@@ -10,8 +10,8 @@ chains are missing:
    (``sparse_tpu.resilience.policy``) within its attempt budget, and the
    session log must contain the full ``fault.injected -> solver.retry ->
    solver.recovered`` chain.
-2. **Forced Pallas failure** — a ``fail:pallas`` clause against the SELL
-   kernel: the result must stay correct through the XLA failover, a
+2. **Forced Pallas failure** — a ``fail:pallas`` clause against the packed
+   DIA kernel: the result must stay correct through the XLA failover, a
    consistent ``kernel.failover`` event must be emitted, and the
    probe-based reinstate hook must clear the latch
    (``kernel.reinstate``).
@@ -255,21 +255,25 @@ def run(report: dict) -> list:
 
     # -- 2. forced Pallas failure + probe reinstate -------------------------
     tel.reset()
-    faults.configure("fail:pallas:kernel=sell_spmv,n=1")
+    faults.configure("fail:pallas:kernel=dia_spmv,n=1")
     old_mode = settings.spmv_mode
     try:
-        from sparse_tpu.kernels.sell_spmv import PreparedCSR
+        from sparse_tpu import plan_cache
+        from sparse_tpu.kernels.dia_spmv import DIA_KERNEL
 
         settings.spmv_mode = "pallas"
         G = _tridiag(32).astype(np.float32)
-        prep = PreparedCSR(G.indptr, G.indices, G.data, G.shape)
+        band = sparse_tpu.csr_array(G)
         xs = np.random.default_rng(2).standard_normal(32).astype(np.float32)
-        y = np.asarray(prep(xs))
+        y = np.asarray(band @ xs)
         ok = np.allclose(y, G @ xs, rtol=1e-5, atol=1e-5)
         kinds = _event_kinds(tel)
-        latched = failover.failed(prep.KERNEL, prep)
+        latched = failover.failed(DIA_KERNEL, band)
         faults.clear()
-        reinstated = prep.probe_pallas(xs.astype(np.float32))
+        prepared = plan_cache.lookup(band, "_dia_prepared")
+        reinstated = prepared is not None and failover.probe(
+            DIA_KERNEL, band, lambda: jax.block_until_ready(prepared(xs))
+        )
         report["pallas_failover"] = {
             "result_ok": bool(ok), "latched": bool(latched),
             "reinstated": bool(reinstated), "events": _event_kinds(tel),
@@ -278,7 +282,7 @@ def run(report: dict) -> list:
             problems.append("pallas failover: XLA fallback result wrong")
         if not latched or kinds.get("kernel.failover", 0) == 0:
             problems.append("pallas failover: no kernel.failover latch/event")
-        if not reinstated or failover.failed(prep.KERNEL, prep):
+        if not reinstated or failover.failed(DIA_KERNEL, band):
             problems.append("pallas failover: probe did not reinstate")
     finally:
         settings.spmv_mode = old_mode

@@ -15,9 +15,7 @@ Classes
 * :class:`SparsityPattern` — host-held shared CSR structure; THE
   plan-cache key for everything batched.
 * :class:`BatchedCSR` — stacked values over one pattern, batched
-  SpMV/SpMM via the SELL slab formulation (vmap-compatible XLA path;
-  the Pallas row-block kernel gains a batch grid dimension under
-  ``spmv_mode='pallas'``, with the usual one-time XLA failover).
+  SpMV/SpMM via the SELL slab formulation (vmap-compatible XLA path).
 * :class:`BatchedDIA` — stacked diagonal planes (row layout) for banded
   patterns, batched zero-gather SpMV (``ops.dia_spmv.dia_planes_matvec``).
 * :func:`make_batched_operator` — coercion entry point (stacks of
@@ -352,10 +350,6 @@ class BatchedCSR(BatchedOperator):
     keyed on the pattern) across the whole batch: values repack on device
     through the pattern's source maps, SpMV/SpMM run the vmap-batched
     slab gathers (``ops.spmv.csr_spmv_sell_batched``). Under
-    ``spmv_mode='pallas'`` the batch-grid Pallas row-block kernel is
-    attempted first, failing over to the XLA formulation once —
-    remembered per operator, same discipline as
-    :class:`~sparse_tpu.kernels.sell_spmv.PreparedCSR`. Under
     ``spmv_mode='segment'`` (and for in-trace first use with a cold plan
     cache) the vmapped segment path runs instead — identical results,
     no host-side pack.
@@ -427,27 +421,6 @@ class BatchedCSR(BatchedOperator):
             self._vals_packed = packed
         return pack, self._vals_packed
 
-    #: failover-registry kernel name; latched per PATTERN (failure is a
-    #: geometry/backend property, so `with_values` siblings share it)
-    KERNEL = "sell_spmv_batched"
-
-    def _pallas_viable(self, pack, X) -> bool:
-        from ..kernels.sell_spmv import PALLAS_MAX_K, PALLAS_MAX_X
-        from ..resilience import failover
-
-        if jax.default_backend() == "tpu":
-            # Mosaic refuses the batched SELL kernel's (1, R) index block
-            # (pinned by tests/test_chip_compile.py): on a TPU the XLA
-            # slab form is the choice, not a failover
-            return False
-        if failover.failed(self.KERNEL, self.pattern) or not pack.idx_slabs:
-            return False
-        if X.shape[1] > PALLAS_MAX_X:
-            return False
-        if any(K > PALLAS_MAX_K for K, _, _ in pack.plan.slab_meta):
-            return False
-        return jnp.result_type(self.dtype, X.dtype) == jnp.float32
-
     def matvec(self, X):
         X = asjnp(X)
         if X.ndim != 2 or X.shape != (self.batch, self.shape[2]):
@@ -456,31 +429,23 @@ class BatchedCSR(BatchedOperator):
                 f"{self.shape[2]}); got {X.shape}"
             )
         telemetry.count("batch.spmv")
-        mode = settings.spmv_mode
-        if mode == "segment" or self.pattern.nnz == 0:
-            return self._matvec_segment(X)
-        if in_trace() and plan_cache.lookup(self.pattern, "sell.pattern") is None:
-            # in-trace first use with a cold cache: packing needs host
-            # work — degrade to the jit-safe segment path, same
-            # discipline as csr_array._maybe_sell
+        if self._segment_form():
             return self._matvec_segment(X)
         pack, vals = self._packed()
-        if mode == "pallas" and self._pallas_viable(pack, X):
-            from ..resilience import failover
-
-            try:
-                from ..kernels.sell_spmv import sell_spmv_pallas_batched
-
-                # forced-failure injection + the shared one-time
-                # Pallas->XLA failover ladder (resilience/failover.py)
-                failover.maybe_inject(self.KERNEL)
-                return sell_spmv_pallas_batched(
-                    pack.plan, pack.idx_slabs, vals, pack.pos, X
-                )
-            except (ValueError, NotImplementedError) as e:
-                failover.handle(self.KERNEL, self.pattern, e)
         return spmv_ops.csr_spmv_sell_batched(
             pack.idx_slabs, vals, pack.pos, X, pack.plan.zero_rows
+        )
+
+    def _segment_form(self) -> bool:
+        """The vmapped segment product, not the slab gathers: asked for by
+        ``spmv_mode='segment'``, for an empty pattern, and for an in-trace
+        first use with a cold plan cache (packing needs host work — same
+        discipline as ``csr_array._maybe_sell``)."""
+        return (
+            settings.spmv_mode == "segment"
+            or self.pattern.nnz == 0
+            or (in_trace()
+                and plan_cache.lookup(self.pattern, "sell.pattern") is None)
         )
 
     def _matvec_segment(self, X):
@@ -496,10 +461,7 @@ class BatchedCSR(BatchedOperator):
                 f"matmat expects X of shape ({self.batch}, "
                 f"{self.shape[2]}, k); got {X.shape}"
             )
-        if settings.spmv_mode == "segment" or self.pattern.nnz == 0 or (
-            in_trace()
-            and plan_cache.lookup(self.pattern, "sell.pattern") is None
-        ):
+        if self._segment_form():
             return jax.vmap(
                 lambda d, x: spmv_ops.csr_spmm_segment(
                     asjnp(self.pattern.indptr), asjnp(self.pattern.indices),

@@ -29,6 +29,39 @@ from .utils import (
 )
 
 
+# How a matrix multiplies. For each ``spmv_mode``: the layouts a product may
+# use, for a vector and for a 2-D operand, in the order they are tried. The
+# first one the matrix offers is taken; a matrix that offers none (no
+# entries, or a first use inside a trace, which can build nothing) takes the
+# segment form. ``ell?`` and ``sell?`` are the two sides of the one ELL-ratio
+# gate (``csr_array._tight``): padded rows for a tight row profile, SELL
+# slabs for a skewed one; bare ``ell``/``sell`` are built whatever the
+# profile. ``dia`` needs a banded matrix (``dia.few_diagonals``); ``dia+`` is
+# the packed Pallas kernel on its planes, which declines a band too wide for
+# VMEM. So ``pallas`` accelerates a banded matrix's vector product and
+# nothing else. docs/performance.md shows the table by row profile;
+# tests/test_matvec_choice.py pins it.
+_LAYOUTS = {
+    "auto": (("dia", "sell?", "ell?"), ("ell?", "sell?")),
+    "pallas": (("dia+", "dia", "sell", "ell?"), ("ell?", "sell?")),
+    "sell": (("sell",), ("sell",)),
+    "ell": (("ell",), ("ell",)),
+    "segment": ((), ()),
+}
+
+
+def _layouts(ndim: int, mode: str | None = None) -> tuple:
+    """The table's row for a ``ndim``-D operand under ``mode`` (default: the
+    ambient ``settings.spmv_mode``, read here and nowhere else in this
+    module)."""
+    mode = settings.spmv_mode if mode is None else mode
+    if mode not in _LAYOUTS:
+        raise ValueError(
+            f"unknown spmv_mode {mode!r}: expected one of {sorted(_LAYOUTS)}"
+        )
+    return _LAYOUTS[mode][ndim - 1]
+
+
 @jax.tree_util.register_pytree_node_class
 class csr_array(SparseArray):
     format = "csr"
@@ -125,11 +158,16 @@ class csr_array(SparseArray):
                 )
         return self._ell_width_cache
 
-    def _maybe_ell(self):
-        """Build/cache the padded-row layout when profitable (settings.spmv_mode)."""
-        mode = settings.spmv_mode
-        if mode in ("segment", "sell"):
-            return None
+    def _tight(self) -> bool:
+        """The ELL-ratio gate: the longest row is within
+        ``settings.ell_max_ratio`` of the mean row, so padding every row to
+        it wastes little. Past it the SELL slabs take the matrix."""
+        mean = max(self.nnz / self.shape[0], 1.0)
+        return self._ell_width() <= settings.ell_max_ratio * mean
+
+    def _maybe_ell(self, gated: bool = False):
+        """Build/cache the padded-row layout; with ``gated``, only for a
+        tight row profile (``_tight``)."""
         m = self.shape[0]
         if m == 0 or self.nnz == 0:
             return None
@@ -139,36 +177,30 @@ class csr_array(SparseArray):
             # spmv_mode), but building ELL here would store TRACER arrays
             # on self._ell and poison every later eager matvec
             return None
-        k = self._ell_width()
-        mean = max(self.nnz / m, 1.0)
-        if mode in ("ell", "pallas") or k <= settings.ell_max_ratio * mean:
-            if self._ell is None:
-                with host_scope():  # one-time layout build: on the host
-                    self._ell = conv.csr_to_ell(
-                        self.indptr, self.indices, self.data, m, max(k, 1)
-                    )
-            return self._ell
-        return None
+        if gated and not self._tight():
+            return None
+        if self._ell is None:
+            with host_scope():  # one-time layout build: on the host
+                self._ell = conv.csr_to_ell(
+                    self.indptr, self.indices, self.data, m,
+                    max(self._ell_width(), 1),
+                )
+        return self._ell
 
     # -- SELL-C-sigma prepared path ----------------------------------------
-    def _maybe_sell(self):
+    def _maybe_sell(self, gated: bool = False):
         """Packed SELL-C-sigma operator via the library-wide plan cache.
 
         The prepared general-SpMV path for skewed row profiles
-        (kernels/sell_spmv.py): under ``spmv_mode='sell'``/``'pallas'`` it
-        applies whenever the matrix has nonzeros; under ``'auto'`` only
-        when the padded-row (ELL) gate declined (max degree beyond
-        ``ell_max_ratio`` x mean — exactly where the segment path used to
-        be the only option). One host-side pack on first eager use,
+        (kernels/sell_spmv.py); with ``gated``, only where the padded-row
+        (ELL) gate declines (``_tight`` false: max degree beyond
+        ``ell_max_ratio`` x mean). One host-side pack on first eager use,
         cached in ``sparse_tpu.plan_cache`` keyed on this object; in-trace
         first use degrades to the jit-safe segment path without caching
         (same discipline as ``_maybe_ell``/``_maybe_dia``).
         """
         from . import plan_cache
 
-        mode = settings.spmv_mode
-        if mode not in ("auto", "sell", "pallas"):
-            return None
         if self.shape[0] == 0 or self.nnz == 0:
             return None
         if in_trace():
@@ -176,11 +208,8 @@ class csr_array(SparseArray):
             # planes become compile-time constants, like the ELL cache);
             # packing here would need host syncs, so a cold cache skips
             return plan_cache.lookup(self, "sell")
-        if mode == "auto":
-            k = self._ell_width()
-            mean = max(self.nnz / self.shape[0], 1.0)
-            if k <= settings.ell_max_ratio * mean:
-                return None  # tight profile: the ELL path takes it
+        if gated and self._tight():
+            return None  # tight profile: the ELL path takes it
 
         def build():
             from .kernels.sell_spmv import PreparedCSR
@@ -216,9 +245,32 @@ class csr_array(SparseArray):
             expect={"dtype": str(jax.dtypes.canonicalize_dtype(self.dtype))},
         )
 
+    def _offer(self, name: str):
+        """Build (first use) or fetch the layout one entry of ``_LAYOUTS``
+        names; None where this matrix does not offer it."""
+        kind, gated = name.rstrip("?+"), name.endswith("?")
+        if kind == "sell":
+            return self._maybe_sell(gated)
+        if kind == "ell":
+            lay = self._maybe_ell(gated)
+        else:
+            lay = self._maybe_dia()
+        if lay is None or in_trace():
+            return lay
+        # layouts are BUILT under host_scope; on accelerator hot paths
+        # commit them to the execution device once (they are jit
+        # arguments — CPU-resident planes would re-transfer per matvec)
+        # and re-cache
+        if kind == "ell":
+            lay = self._ell = commit_to_exec_device(lay)
+        else:
+            lay = self._dia = (*commit_to_exec_device(lay[:1]), lay[1])
+        return lay
+
     def prepare(self, mode: str | None = None):
         """One-time eager layout/pack warm for the current (or given)
-        ``spmv_mode``; returns ``self`` for chaining.
+        ``spmv_mode``: every layout a vector or a 2-D product may take under
+        it. Returns ``self`` for chaining.
 
         The prepare half of the prepare/execute split: solvers whose first
         matvec happens inside a compiled loop (multigrid operators, eigsh
@@ -228,23 +280,16 @@ class csr_array(SparseArray):
         """
         if in_trace():
             return self  # layout detection needs host syncs; no-op in-trace
-        prev = settings.spmv_mode
-        try:
-            if mode is not None:
-                settings.spmv_mode = mode
-            if settings.spmv_mode in ("auto", "pallas"):
-                self._maybe_dia()
-            if settings.plan_cache:
+        for name in dict.fromkeys(_layouts(1, mode) + _layouts(2, mode)):
+            if name.startswith("sell") and not settings.plan_cache:
                 # with the plan cache DISABLED the pack has nowhere to
                 # live — plan_cache.get builds and discards — so an eager
                 # warm would charge every one-shot solve the full SELL
                 # pack cost for nothing (tests/test_plan_cache.py pins
                 # this). Execute-time _maybe_sell still packs when a
                 # matvec actually needs it.
-                self._maybe_sell()
-            self._maybe_ell()
-        finally:
-            settings.spmv_mode = prev
+                continue
+            self._offer(name)
         return self
 
     # -- products ----------------------------------------------------------
@@ -370,65 +415,53 @@ class csr_array(SparseArray):
         return (planes, tuple(int(o) for o in offsets))
 
     def _spmv(self, x):
-        mode = settings.spmv_mode
-        if mode in ("auto", "pallas"):
-            dia = self._maybe_dia()
-            if dia is not None:
-                if not in_trace():
-                    # layouts are BUILT under host_scope; on accelerator
-                    # hot paths commit them to the execution device once
-                    # (they are jit arguments — CPU-resident planes would
-                    # re-transfer per matvec) and re-cache
-                    planes = commit_to_exec_device((dia[0],))[0]
-                    if planes is not dia[0]:
-                        dia = (planes, dia[1])
-                        self._dia = dia
-                if mode == "pallas":
-                    from .kernels.dia_spmv import cached_prepared_spmv
+        for name in _layouts(1):
+            lay = self._offer(name)
+            if lay is None:
+                continue
+            if name == "dia+":
+                from .kernels.dia_spmv import cached_prepared_spmv
 
-                    y = cached_prepared_spmv(
-                        self, "_dia_prepared", dia[0], dia[1], self.shape, x
-                    )
-                    if y is not None:  # None: band too wide for VMEM
-                        return y
+                y = cached_prepared_spmv(
+                    self, "_dia_prepared", lay[0], lay[1], self.shape, x
+                )
+                if y is None:  # band too wide for VMEM: the XLA form
+                    continue
+                return y
+            if name == "dia":
                 from .ops.dia_spmv import dia_spmv_xla
 
-                return dia_spmv_xla(dia[0], dia[1], x, self.shape)
-        # prepared SELL-C-sigma path: forced by mode 'sell', attempted for
-        # non-banded matrices under 'pallas', and the 'auto' fallthrough
-        # for skewed row profiles where the ELL gate declines (the shapes
-        # that used to pay the scatter-shaped segment path per matvec)
-        prep = self._maybe_sell()
-        if prep is not None:
-            return prep(x)
-        ell = self._maybe_ell()
-        if ell is not None:
-            if not in_trace():
-                ell2 = commit_to_exec_device(ell)
-                if ell2[0] is not ell[0]:
-                    ell = self._ell = ell2
-            # spmv_mode='pallas' accelerates DIA-profiled matrices only
-            # (kernels/dia_spmv above). A Pallas ELL kernel needs a
-            # windowed in-VMEM gather, which Mosaic cannot lower yet
-            # (single-tile take_along_axis only) — general bounded-degree
-            # matrices take XLA's HBM-gather formulation, the fastest
-            # path that actually runs on hardware (VERDICT r2 #8:
-            # the dead interpret-only kernel was removed, not shipped).
-            return spmv_ops.csr_spmv_ell(ell[0], ell[1], x)
+                return dia_spmv_xla(lay[0], lay[1], x, self.shape)
+            if name.startswith("sell"):
+                return lay(x)
+            # XLA's HBM-gather formulation: a Pallas ELL kernel needs a
+            # windowed in-VMEM gather, which Mosaic cannot lower (single-
+            # tile take_along_axis only)
+            return spmv_ops.csr_spmv_ell(lay[0], lay[1], x)
         return spmv_ops.csr_spmv_segment(
             self.indptr, self.indices, self.data, x, self.shape[0]
         )
 
     def _spmm(self, B):
-        ell = self._maybe_ell()
-        if ell is not None:
-            return spmv_ops.csr_spmm_ell(ell[0], ell[1], B)
-        prep = self._maybe_sell()  # skewed profiles: slab gathers, XLA form
-        if prep is not None:
-            return prep.matmat(B)
+        for name in _layouts(2):
+            lay = self._offer(name)
+            if lay is None:
+                continue
+            if name.startswith("sell"):  # slab gathers, XLA form
+                return lay.matmat(B)
+            return spmv_ops.csr_spmm_ell(lay[0], lay[1], B)
         return spmv_ops.csr_spmm_segment(
             self.indptr, self.indices, self.data, B, self.shape[0]
         )
+
+    def _ell_idx(self):
+        """The padded-row index plane where a 2-D product may take that
+        layout (the tropical ops gather through it), else None."""
+        for name in _layouts(2):
+            if name.startswith("ell"):
+                lay = self._offer(name)
+                return None if lay is None else lay[0]
+        return None
 
     def _rdot(self, other):
         """other @ A for dense other (SPMM_DENSE_CSR, csr.py:1209)."""
@@ -461,10 +494,9 @@ class csr_array(SparseArray):
         """
         from .ops import tropical
 
-        ell = self._maybe_ell()
         return tropical.tropical_spmv(
             self.indptr, self.indices, self.data, asjnp(x), self.shape[0],
-            ell_idx=ell[0] if ell is not None else None,
+            ell_idx=self._ell_idx(),
         )
 
     @track_provenance
@@ -478,11 +510,10 @@ class csr_array(SparseArray):
         """
         from .ops import tropical
 
-        ell = self._maybe_ell()
         return tropical.mis_flags(
             self.indptr, self.indices, self.data, self.shape[0], k=k,
             invalid=invalid, seed=seed,
-            ell_idx=ell[0] if ell is not None else None,
+            ell_idx=self._ell_idx(),
         )
 
     @track_provenance
@@ -491,10 +522,9 @@ class csr_array(SparseArray):
         nearest-root routing (reference amg.py:259-283), on device."""
         from .ops import tropical
 
-        ell = self._maybe_ell()
         return tropical.mis_aggregate_cols(
             self.indptr, self.indices, self.data, self.shape[0], flags,
-            ell_idx=ell[0] if ell is not None else None,
+            ell_idx=self._ell_idx(),
         )
 
     # -- elementwise -------------------------------------------------------

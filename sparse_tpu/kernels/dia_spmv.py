@@ -1,13 +1,13 @@
 """Pallas TPU kernel: DIA SpMV with explicit VMEM windowing.
 
 The XLA formulation (``ops.dia_spmv``) already avoids gathers; this kernel
-additionally controls the memory schedule: data and x stay in HBM, each grid
-step DMAs the [D, TM + 2B] data tile and the [TM + 2B] x window its row tile
-needs into VMEM, and the diagonal contributions — **including the data*x
-multiply** — are computed in VMEM as statically-shifted slices on the VPU.
-Per element that is one data load + one (windowed) x load + one y store,
-plus a one-time [D, 2B]-per-row-tile halo pad of the data planes — no
-full-size intermediate product array ever exists in HBM.
+additionally controls the memory schedule: the packed planes and x stay in
+HBM, each grid step DMAs the D ``[TM]`` plane rows and the ``[TM + 2B]`` x
+window its row tile needs into VMEM, and the diagonal contributions —
+**including the data*x multiply** — are computed in VMEM as
+statically-shifted slices on the VPU. Per element that is one plane load +
+one (windowed) x load + one y store — no full-size intermediate product
+array ever exists in HBM.
 
 Reference analog: the cuSPARSE-backed CSR SpMV task
 (``src/sparse/array/csr/spmv.cu:42-116``) with the shifted-pointer trick;
@@ -30,17 +30,21 @@ def _round_up(v: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Prepared-layout variant: row-indexed planes, packed once, reused per SpMV.
+# Prepared layout: row-indexed planes, packed once, reused per SpMV.
 #
-# The original kernel below re-pads the scipy-layout planes on every call —
-# an extra read+write of the whole matrix per SpMV — and DMAs Dp = ceil8(D)
-# column-indexed planes with a 2B halo each. Preparing a row-indexed flat
-# plane array once removes both: plane k's coefficient for row i is
-# pr[k, i] = data[k, i + o_k], so each grid step needs exactly [D, TM]
-# plane elements (no halo, no pad planes) fetched as D aligned 1-D DMAs
-# from the flattened [D * m_pad] buffer. Only the x window keeps the 2B
-# halo. Per-element traffic drops from ~Dp(TM+2B)/D·TM to 1 plane load +
-# ~1 x load + 1 y store — the true bandwidth floor for DIA SpMV.
+# Plane k's coefficient for row i is pr[k, i] = data[k, i + o_k], so each
+# grid step needs exactly [D, TM] plane elements (no halo, no pad planes)
+# fetched as D aligned 1-D DMAs from the flattened [D * m_pad] buffer. Only
+# the x window keeps the 2B halo. Per-element traffic is 1 plane load +
+# ~1 x load + 1 y store — the bandwidth floor for DIA SpMV. (Padding the
+# scipy-layout, column-indexed planes on every call instead costs an extra
+# read+write of the whole matrix per SpMV and a 2B halo on each of
+# ceil8(D) planes.)
+#
+# Mosaic DMA alignment: 1-D HBM memrefs carry a (1024,) tiling, so the row
+# tile TM rounds to 1024 and the halo B to 512 (``dia_plan``) — then the
+# window win = TM + 2B, every window start g*TM, and each plane's base
+# k*m_pad in the flat buffer are all multiples of 1024.
 # ---------------------------------------------------------------------------
 
 
@@ -500,11 +504,10 @@ def cached_prepared_spmv(obj, attr: str, data, offsets, shape, x):
     so mutation invalidates the plan for free.
 
     Failure handling lives in the shared failover registry
-    (``sparse_tpu.resilience.failover``): this site classifies with the
-    strict lowering-unavailability vocabulary (``vocab=True`` — on a
-    real TPU nothing but an injected failure is benign, a Mosaic
-    compile regression stays LOUD; off-TPU any lowering-availability
-    wording qualifies), honors
+    (``sparse_tpu.resilience.failover``): it classifies with the strict
+    lowering-unavailability vocabulary (on a real TPU nothing but an
+    injected failure is benign, a Mosaic compile regression stays LOUD;
+    off-TPU any lowering-availability wording qualifies), honors
     ``SPARSE_TPU_STRICT_PALLAS``, emits the consistent
     ``kernel.failover`` event, and latches per matrix object — a latch
     :func:`~sparse_tpu.resilience.failover.probe` can clear again when
@@ -534,122 +537,5 @@ def cached_prepared_spmv(obj, attr: str, data, offsets, shape, x):
         failover.maybe_inject(DIA_KERNEL)
         return prepared(x)
     except (ValueError, NotImplementedError) as e:
-        failover.handle(DIA_KERNEL, obj, e, vocab=True)
+        failover.handle(DIA_KERNEL, obj, e)
         return None
-
-
-def dia_spmv_pallas(data, offsets, x, shape, tile=16384, interpret=None):
-    """See ``_dia_spmv_pallas``; ``interpret=None`` auto-selects interpret
-    mode off-TPU (Pallas TPU kernels only compile natively on tpu)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _dia_spmv_pallas(
-        data, tuple(offsets), x, tuple(shape), tile=tile, interpret=interpret
-    )
-
-
-@partial(jax.jit, static_argnames=("offsets", "shape", "tile", "interpret"))
-def _dia_spmv_pallas(
-    data, offsets: tuple, x, shape: tuple, tile: int = 16384, interpret: bool = False
-):
-    """y = A @ x, A in DIA layout (scipy convention), banded offsets.
-
-    ``tile`` rows per grid step (multiple of 128). The per-tile x/data window
-    is [tile + 2B] where B is the bandwidth; windows of neighboring tiles
-    overlap by 2B — the halo. Both are DMA'd from HBM per step and multiplied
-    in VMEM (contribution of diagonal o to row i is data[k, i+o] * x[i+o]).
-    """
-    m, n = shape
-    D = len(offsets)
-    # Mosaic DMA alignment: 1-D HBM memrefs carry a (1024,) tiling, so the
-    # row tile TM rounds to 1024 and the halo B to 512 — then the window
-    # win = TM + 2B, every window start g*TM, and each plane's base k*L in
-    # the flattened plane array are all multiples of 1024. (Geometry shared
-    # with the prepared path via dia_plan — single source.)
-    _p = dia_plan(offsets, shape, tile=tile)
-    B, TM, G = _p.B, _p.TM, _p.G
-    m_pad = G * TM
-    win = TM + 2 * B
-    L = m_pad + 2 * B  # padded plane length (multiple of 1024)
-
-    # Halo-pad data planes and x into a shared padded coordinate system
-    # (index j' = j + B); a copy of the inputs, NOT a product intermediate.
-    # The plane count pads to a sublane multiple of 8 (zero planes) so each
-    # window is one aligned [Dp, win] DMA.
-    Dp = _round_up(D, 8)
-    pad_hi = max(m_pad - n, 0) + B
-    data_p = jnp.pad(data, ((0, Dp - D), (B, pad_hi)))[:, :L]
-    x_p = jnp.pad(x, (B, pad_hi))[:L]
-    out_dt = jnp.result_type(data.dtype, x.dtype)
-
-    def kernel(data_hbm, x_hbm, y_ref, dwinA, dwinB, xwinA, xwinB, semA, semB):
-        # Cross-step double buffering: step g waits on the DMAs it (or the
-        # warm-up) issued into its slot's buffers and prefetches step g+1
-        # into the other slot's, overlapping HBM reads with VPU compute —
-        # scratch and semaphores persist across the sequential TPU grid.
-        # The two slots are unrolled statically (Mosaic cannot scalar-index
-        # the tiled dims of a VMEM ref, so buffer choice must be static).
-        g = pl.program_id(0)
-        G_ = pl.num_programs(0)
-
-        def issue(dwin, xwin, sem, gg):
-            pltpu.make_async_copy(
-                data_hbm.at[:, pl.ds(gg * TM, win)], dwin, sem.at[0]
-            ).start()
-            pltpu.make_async_copy(
-                x_hbm.at[pl.ds(gg * TM, win)], xwin, sem.at[1]
-            ).start()
-
-        def wait(dwin, xwin, sem, gg):
-            pltpu.make_async_copy(
-                data_hbm.at[:, pl.ds(gg * TM, win)], dwin, sem.at[0]
-            ).wait()
-            pltpu.make_async_copy(
-                x_hbm.at[pl.ds(gg * TM, win)], xwin, sem.at[1]
-            ).wait()
-
-        def step(dwin, xwin, sem, dwin_n, xwin_n, sem_n):
-            @pl.when(g == 0)
-            def _():
-                issue(dwin, xwin, sem, g)
-
-            @pl.when(g + 1 < G_)
-            def _():
-                issue(dwin_n, xwin_n, sem_n, g + 1)
-
-            wait(dwin, xwin, sem, g)
-            acc = jnp.zeros((TM,), dtype=y_ref.dtype)
-            for k, o in enumerate(offsets):
-                lo = B + int(o)
-                acc = acc + dwin[k, lo : lo + TM] * xwin[lo : lo + TM]
-            y_ref[:] = acc
-
-        @pl.when(g % 2 == 0)
-        def _():
-            step(dwinA, xwinA, semA, dwinB, xwinB, semB)
-
-        @pl.when(g % 2 == 1)
-        def _():
-            step(dwinB, xwinB, semB, dwinA, xwinA, semA)
-
-    y = pl.pallas_call(
-        kernel,
-        name="dia_spmv_pallas",
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((TM,), lambda g: (g,), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m_pad,), out_dt),
-        scratch_shapes=[
-            pltpu.VMEM((Dp, win), data.dtype),
-            pltpu.VMEM((Dp, win), data.dtype),
-            pltpu.VMEM((win,), x.dtype),
-            pltpu.VMEM((win,), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(data_p, x_p)
-    return y[:m]

@@ -1,4 +1,4 @@
-"""SELL-C-sigma packing + Pallas row-block SpMV for general (non-banded) CSR.
+"""SELL-C-sigma packing and the prepared operator for general (non-banded) CSR.
 
 Older records put a ~1000x gap between the banded fast path and the
 general one (not measured on the current chip; ROADMAP S3). DIA only covers
@@ -21,21 +21,15 @@ Packing is one-time host-side work (the prepare/execute split — the
 reference keeps its CSR stores resident across task launches the same
 way; legate.sparse ``set_key_partition``, SURVEY §1); the packed operator
 is cached library-wide in ``sparse_tpu.plan_cache`` so solvers reuse it
-across a whole solve. The pure-XLA formulation (``ops.spmv.csr_spmv_sell``)
-is the portable default; the Pallas row-block kernel here additionally
-pins x and the slab planes in VMEM (grid over row blocks of chunks) and
-runs in interpret mode off-TPU like ``dia_spmv.py``.
+across a whole solve. The product is the pure-XLA slab formulation
+(``ops.spmv.csr_spmv_sell``): Mosaic has no lowering for a gather inside
+VMEM ("Cannot do int indexing on TPU"), so there is no Pallas kernel here.
 """
 
 from __future__ import annotations
 
-from functools import partial
-
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.spmv import csr_spmm_sell, csr_spmv_sell
 
@@ -44,16 +38,11 @@ def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-# Slab rows pad to a sublane multiple so the Pallas row blocks tile exactly
-# (the row-block tile is the largest power-of-two divisor, see
-# ``sell_spmv_pallas``); pad rows carry idx 0 / val 0 (contribute 0 * x[0])
-# and are dropped by the pos-gather, which only addresses real rows. Kept
-# small: slab-count x ROW_ALIGN x K is pure pad storage.
+# Slab rows pad to a sublane multiple; pad rows carry idx 0 / val 0
+# (contribute 0 * x[0]) and are dropped by the pos-gather, which only
+# addresses real rows. Kept small: slab-count x ROW_ALIGN x K is pure pad
+# storage.
 ROW_ALIGN = 8
-# Pallas attempt gates (beyond these the XLA formulation is simply better
-# suited: x must fit VMEM whole, and every plane is unrolled in the trace).
-PALLAS_MAX_X = 1 << 20
-PALLAS_MAX_K = 128
 
 
 class SellPlan:
@@ -199,153 +188,6 @@ def sell_pack(indptr, indices, data, shape, C=None, sigma=None, max_slabs=None,
     return plan, tuple(slabs), jnp.asarray(pos.astype(pos_dt))
 
 
-# ---------------------------------------------------------------------------
-# Pallas row-block kernel: x + one slab's [K, TM] plane window in VMEM,
-# grid over TM-row blocks of the slab (TM rows = TM/C chunks per step).
-# ---------------------------------------------------------------------------
-
-
-@partial(jax.jit, static_argnames=("K", "TM", "interpret", "acc_dtype"))
-def _sell_slab_pallas(idx_t, val_t, x, K: int, TM: int, interpret: bool = False,
-                      acc_dtype=None):
-    R = idx_t.shape[1]
-    out_dt = acc_dtype or jnp.result_type(val_t.dtype, x.dtype)
-
-    def kernel(x_ref, idx_ref, val_ref, y_ref):
-        acc = jnp.zeros((TM,), dtype=out_dt)
-        for k in range(K):  # static per slab: plane loads unroll
-            # value planes load at their storage width; the in-register
-            # convert widens the product to the accumulation dtype
-            # (a no-op when acc_dtype is None — ISSUE 15)
-            acc = acc + (
-                val_ref[k, :].astype(out_dt)
-                * x_ref[idx_ref[k, :]].astype(out_dt)
-            )
-        y_ref[:] = acc
-
-    return pl.pallas_call(
-        kernel,
-        name="sell_slab_pallas",
-        grid=(R // TM,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # x resident whole
-            pl.BlockSpec((K, TM), lambda g: (0, g), memory_space=pltpu.VMEM),
-            pl.BlockSpec((K, TM), lambda g: (0, g), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((TM,), lambda g: (g,), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((R,), out_dt),
-        interpret=interpret,
-    )(x, idx_t, val_t)
-
-
-def sell_spmv_pallas(plan: SellPlan, slabs, pos, x, interpret=None,
-                     acc_dtype=None):
-    """y = A @ x via the per-slab Pallas row-block kernel (+ XLA glue for
-    the concat/pos-gather). ``interpret=None`` auto-selects interpret mode
-    off-TPU like ``dia_spmv.py``. Raises when Mosaic cannot lower the
-    in-VMEM gather — callers go through :class:`PreparedCSR`, which fails
-    over to the XLA formulation once and remembers. ``acc_dtype`` is the
-    storage/accumulation split (ISSUE 15): narrow value planes, wide
-    in-register accumulation."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    out_dt = acc_dtype or jnp.result_type(
-        slabs[0][1].dtype if slabs else x.dtype, x.dtype
-    )
-    parts = []
-    for (idx_t, val_t), (K, R, _) in zip(slabs, plan.slab_meta):
-        TM = ROW_ALIGN  # rows are ROW_ALIGN-padded, so this always divides
-        while TM * 2 <= 1024 and R % (TM * 2) == 0:
-            TM *= 2
-        parts.append(
-            _sell_slab_pallas(idx_t, val_t, x, K, TM, interpret,
-                              acc_dtype=acc_dtype).astype(out_dt)
-        )
-    if plan.zero_rows:
-        parts.append(jnp.zeros((plan.zero_rows,), dtype=out_dt))
-    if not parts:
-        return jnp.zeros((plan.m,), dtype=out_dt)
-    packed = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-    return packed[pos]
-
-
-@partial(jax.jit, static_argnames=("K", "TM", "interpret", "acc_dtype"))
-def _sell_slab_pallas_batched(idx_t, val_bt, X, K: int, TM: int,
-                              interpret: bool = False, acc_dtype=None):
-    """Batched form of :func:`_sell_slab_pallas`: the grid gains a leading
-    batch dimension, the shared ``[K, R]`` index planes stay resident while
-    value planes ``[B, K, R]`` and per-lane x vectors ``[B, n]`` stream one
-    lane at a time — the whole same-pattern stack runs as one kernel launch
-    instead of B dispatches. ``acc_dtype`` widens the per-plane products
-    in-register (ISSUE 15) while the value planes stream at storage
-    width."""
-    B, _, R = val_bt.shape
-    out_dt = acc_dtype or jnp.result_type(val_bt.dtype, X.dtype)
-
-    def kernel(x_ref, idx_ref, val_ref, y_ref):
-        acc = jnp.zeros((TM,), dtype=out_dt)
-        for k in range(K):  # static per slab: plane loads unroll
-            acc = acc + (
-                val_ref[0, k, :].astype(out_dt)
-                * x_ref[0, idx_ref[k, :]].astype(out_dt)
-            )
-        y_ref[0, :] = acc
-
-    return pl.pallas_call(
-        kernel,
-        name="sell_slab_pallas_batched",
-        grid=(B, R // TM),
-        in_specs=[
-            # one lane of x resident per grid step
-            pl.BlockSpec((1, X.shape[1]), lambda b, g: (b, 0),
-                         memory_space=pltpu.VMEM),
-            # index planes are PATTERN state: shared by every lane
-            pl.BlockSpec((K, TM), lambda b, g: (0, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, K, TM), lambda b, g: (b, 0, g),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, TM), lambda b, g: (b, g),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, R), out_dt),
-        interpret=interpret,
-    )(X, idx_t, val_bt)
-
-
-def sell_spmv_pallas_batched(plan: SellPlan, idx_slabs, val_slabs, pos, X,
-                             interpret=None, acc_dtype=None):
-    """Y = A_b @ x_b per lane via the batch-grid Pallas row-block kernel.
-
-    ``idx_slabs`` are the shared pattern index planes, ``val_slabs`` the
-    stacked ``[B, K, R]`` value planes (``sparse_tpu.batch.operator`` packs
-    them through the pattern's source maps), ``X`` is ``[B, n]``. Same
-    failover contract as :func:`sell_spmv_pallas` — callers catch the
-    Mosaic lowering error once and fall back to the XLA formulation.
-    ``acc_dtype`` is the storage/accumulation split (ISSUE 15)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B = X.shape[0]
-    out_dt = acc_dtype or jnp.result_type(
-        val_slabs[0].dtype if val_slabs else X.dtype, X.dtype
-    )
-    parts = []
-    for idx_t, val_bt, (K, R, _) in zip(idx_slabs, val_slabs, plan.slab_meta):
-        TM = ROW_ALIGN  # rows are ROW_ALIGN-padded, so this always divides
-        while TM * 2 <= 1024 and R % (TM * 2) == 0:
-            TM *= 2
-        parts.append(
-            _sell_slab_pallas_batched(idx_t, val_bt, X, K, TM, interpret,
-                                      acc_dtype=acc_dtype)
-            .astype(out_dt)
-        )
-    if plan.zero_rows:
-        parts.append(jnp.zeros((B, plan.zero_rows), dtype=out_dt))
-    if not parts:
-        return jnp.zeros((B, plan.m), dtype=out_dt)
-    packed = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    return packed[:, pos]
-
-
 class PreparedCSR:
     """A general CSR operator packed once into the SELL slab layout.
 
@@ -355,16 +197,10 @@ class PreparedCSR:
     Format classes obtain one through ``sparse_tpu.plan_cache`` so solver
     loops (and repeated ``A @ x`` calls) never repack.
 
-    ``__call__`` dispatches per ``settings.spmv_mode``: the Pallas kernel
-    under ``'pallas'`` (gated on f32 / VMEM-resident x / bounded plane
-    count, failing over to XLA once — remembered — when the backend has no
-    lowering), the XLA slab formulation otherwise.
+    ``__call__`` is the XLA slab formulation, whatever the mode.
     """
 
     __slots__ = ("plan", "slabs", "pos", "__weakref__")
-
-    #: failover-registry kernel name (resilience/failover.py)
-    KERNEL = "sell_spmv"
 
     def __init__(self, indptr, indices, data, shape, C=None, sigma=None,
                  max_slabs=None):
@@ -391,31 +227,9 @@ class PreparedCSR:
     def shape(self):
         return (self.plan.m, self.plan.n)
 
-    def _pallas_viable(self, x) -> bool:
-        from ..resilience import failover
-
-        if jax.default_backend() == "tpu":
-            # Mosaic refuses the in-VMEM gather ("Cannot do int indexing
-            # on TPU", pinned by tests/test_chip_compile.py): on a TPU
-            # the XLA slab form is the choice, not a failover
-            return False
-        if failover.failed(self.KERNEL, self) or not self.slabs:
-            return False
-        if x.shape[0] > PALLAS_MAX_X:
-            return False
-        if any(K > PALLAS_MAX_K for K, _, _ in self.plan.slab_meta):
-            return False
-        dt = jnp.result_type(self.slabs[0][1].dtype, x.dtype)
-        return dt == jnp.float32
-
     def matvec_xla(self, x):
         return csr_spmv_sell(
             self.slabs, self.pos, jnp.asarray(x), self.plan.zero_rows
-        )
-
-    def matvec_pallas(self, x, interpret=None):
-        return sell_spmv_pallas(
-            self.plan, self.slabs, self.pos, jnp.asarray(x), interpret
         )
 
     def matmat(self, B):
@@ -423,31 +237,8 @@ class PreparedCSR:
             self.slabs, self.pos, jnp.asarray(B), self.plan.zero_rows
         )
 
-    def probe_pallas(self, x=None) -> bool:
-        """Probe-based reinstate hook: run one real Pallas matvec; on
-        success any failover latch for this operator clears
-        (``kernel.reinstate`` event) and later calls retry the kernel."""
-        from ..resilience import failover
-
-        if x is None:
-            x = jnp.zeros((self.plan.n,), dtype=jnp.float32)
-        return failover.probe(
-            self.KERNEL, self,
-            lambda: jax.block_until_ready(self.matvec_pallas(x)),
-        )
-
     def __call__(self, x):
         from .. import telemetry
-        from ..config import settings
-        from ..resilience import failover
 
         telemetry.count("kernel.sell_spmv")
-        if settings.spmv_mode == "pallas" and self._pallas_viable(x):
-            try:
-                # forced-failure injection + the shared one-time
-                # Pallas->XLA failover ladder (resilience/failover.py)
-                failover.maybe_inject(self.KERNEL)
-                return self.matvec_pallas(x)
-            except (ValueError, NotImplementedError) as e:
-                failover.handle(self.KERNEL, self, e)
         return self.matvec_xla(x)

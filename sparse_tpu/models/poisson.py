@@ -157,16 +157,18 @@ def make_cg_step_dia(offsets: tuple, n: int, use_pallas: bool | None = None):
     """One CG iteration with the diagonal-layout SpMV; offsets are static
     structure, closed over so the returned fn is jittable on arrays alone.
 
-    On TPU the SpMV is the Pallas VMEM-windowed kernel (1.4-1.9x the XLA
-    formulation on a v5e: 88 vs 62 CG iters/s at 6000^2, vs the reference's
-    75.9 on a V100 — BASELINE.md); elsewhere the XLA zero-gather path.
-    XLA hoists the kernel's loop-invariant plane padding out of the CG
-    ``fori_loop``, so the padding copy is one-time, not per-iteration.
+    On TPU the SpMV is the packed Pallas VMEM-windowed kernel (not measured
+    against the XLA formulation on the current chip); elsewhere the XLA
+    zero-gather path. The step takes scipy-layout planes, so it packs them
+    on every call, and the chip's compiler keeps that pack inside the body
+    of ``cg_dia``'s ``fori_loop`` (compiled for a described v5e, PR 29): a
+    solver that iterates packs once (``PreparedDia``; ``linalg.cg``'s
+    fused kernel does).
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        from ..kernels.dia_spmv import dia_spmv_pallas as _spmv_dia
+        from ..kernels.dia_spmv import dia_spmv_pallas_v2 as _spmv_dia
     else:
         from ..ops.dia_spmv import dia_spmv_xla as _spmv_dia
 

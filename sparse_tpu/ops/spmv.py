@@ -4,8 +4,8 @@ Reference analog: the CSR_SPMV_ROW_SPLIT / CSR_SPMV_COL_SPLIT / CSC_SPMV_COL_SPL
 SPMM_* task families (``src/sparse/array/csr/spmv.*``, ``spmm.*`` — SURVEY §2b).
 The cuSPARSE calls become pure-XLA gather/segment-reduce pipelines here, with a
 padded-row (ELL) fast path that turns SpMV into gathers + dense reductions — the
-shape TPUs like (no scatter in the hot loop). A Pallas kernel variant lives in
-``sparse_tpu.kernels``; dispatch is by ``config.settings.spmv_mode``.
+shape TPUs like (no scatter in the hot loop). Which form a matrix takes is
+``csr._LAYOUTS``; a banded one has a Pallas kernel (``kernels.dia_spmv``).
 
 All functions are jit-safe: static shapes, no host syncs.
 """
@@ -116,8 +116,8 @@ def csr_spmv_sell(slabs, pos, x, zero_rows: int, out_dtype=None,
     output; ``zero_rows`` is the trailing all-empty-row block. Every step is
     a contiguous 1-D gather + VPU add — no scatter, no segment ids, and
     near-zero pad waste even under row-length skew (vs. ELL's global-max
-    padding). The portable default for prepared general SpMV; the Pallas
-    row-block variant lives in ``sparse_tpu.kernels.sell_spmv``.
+    padding). The one form of the prepared general SpMV
+    (``kernels.sell_spmv.PreparedCSR`` packs for it).
     """
     x = jnp.asarray(x)  # numpy x would fail the fori-loop gather branch
     out_dt = out_dtype or acc_dtype or jnp.result_type(

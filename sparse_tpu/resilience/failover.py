@@ -1,12 +1,8 @@
 """Unified Pallas->XLA failover registry.
 
-Before this module, three call sites each carried their own one-time
-failover latch: ``kernels/sell_spmv.PreparedCSR`` (a ``_pallas_ok``
-attribute), ``kernels/dia_spmv.cached_prepared_spmv`` (a plan-cache
-sentinel) and ``batch/operator.BatchedCSR`` (another ``_pallas_ok``) —
-three copies of the classification logic, three slightly different
-event shapes, and no way to *undo* a failover once latched. This
-registry is the one place failover state lives:
+The one place failover state lives, for every site that tries a Pallas
+kernel and has an XLA formulation to fall back on (today one:
+``kernels/dia_spmv.cached_prepared_spmv``, kernel ``dia_spmv``):
 
 * ``failed(kernel, obj)`` — is the Pallas path latched off for this
   (kernel, operator) pair? Checked at dispatch, one dict probe.
@@ -14,9 +10,8 @@ registry is the one place failover state lives:
   backend a Pallas error is an error: everything but an injected
   failure re-raises (a kernel that quietly gives way to a reference on
   the one platform it exists for hides the device). Off the TPU
-  (CPU/interpret): classify the error (vocabulary match for DIA's
-  rules, any ``ValueError``/``NotImplementedError`` for the SELL
-  sites), honor ``SPARSE_TPU_STRICT_PALLAS``, warn once, emit a
+  (CPU/interpret): classify the error (``classify_unavailable``'s
+  vocabulary match), honor ``SPARSE_TPU_STRICT_PALLAS``, warn once, emit a
   consistent ``kernel.failover`` event + ``kernel.failovers`` metrics
   counter, and latch. Returns when the caller should take the XLA
   path; re-raises otherwise.
@@ -177,10 +172,9 @@ def maybe_inject(kernel: str) -> None:
 def classify_unavailable(e: Exception) -> bool:
     """Backend-aware classification of a Pallas error as
     lowering-unavailable (failover-eligible) vs a genuine caller/kernel
-    bug (must re-raise). The DIA site's rules, shared: on the TPU
-    backend nothing but an injected failure is benign; off-TPU any
-    lowering-availability wording (or a bare ``NotImplementedError``)
-    qualifies."""
+    bug (must re-raise): on the TPU backend nothing but an injected
+    failure is benign; off-TPU any lowering-availability wording (or a
+    bare ``NotImplementedError``) qualifies."""
     import jax
 
     if isinstance(e, InjectedPallasFailure):
@@ -201,26 +195,18 @@ def classify_unavailable(e: Exception) -> bool:
     )
 
 
-def handle(kernel: str, obj, e: Exception, vocab: bool = False) -> None:
+def handle(kernel: str, obj, e: Exception) -> None:
     """The shared failover ladder for a caught Pallas error.
 
-    On the TPU backend every site re-raises anything but an
-    :class:`InjectedPallasFailure`. Elsewhere ``vocab=True`` applies
-    :func:`classify_unavailable` first (the DIA site's stricter
-    contract); the SELL sites fail over on any caught
-    ``ValueError``/``NotImplementedError``. Strict mode re-raises
-    pattern-matched ``ValueError``s in both regimes; a bare
-    ``NotImplementedError`` (including injected failures) always takes
-    the failover. On return the caller takes the XLA path; otherwise
-    this re-raises ``e``.
+    On the TPU backend anything but an :class:`InjectedPallasFailure`
+    re-raises; elsewhere so does an error :func:`classify_unavailable`
+    does not know as lowering-unavailable (a Mosaic compile regression
+    stays LOUD). Strict mode re-raises pattern-matched ``ValueError``s; a
+    bare ``NotImplementedError`` (including injected failures) always
+    takes the failover. On return the caller takes the XLA path;
+    otherwise this re-raises ``e``.
     """
-    import jax
-
-    if jax.default_backend() == "tpu" and not isinstance(
-        e, InjectedPallasFailure
-    ):
-        raise e
-    if vocab and not classify_unavailable(e):
+    if not classify_unavailable(e):
         raise e
     if strict() and not isinstance(e, NotImplementedError):
         raise e

@@ -17,7 +17,7 @@ semicolon-separated list of clauses::
     inf:matvec:p=0.005                 # Inf instead of NaN
     bitflip:matvec:p=0.01,scale=1e18   # scale one element (bitflip-like)
     fail:pallas                        # force Pallas launch failure
-    fail:pallas:kernel=sell_spmv,n=1   # ...for one kernel, first try only
+    fail:pallas:kernel=dia_spmv,n=1    # ...for one kernel, first try only
     drop:dispatch:p=0.5                # SolveSession dispatch failure
     delay:dispatch:ms=25               # dispatch latency injection
     preempt:chunk:p=0.1,seed=3         # preemption at chunk boundaries

@@ -83,8 +83,8 @@ def test_batched_csr_spmv_matches_lanes():
 @pytest.mark.parametrize("mode", ["segment", "sell", "pallas", "auto"])
 def test_batched_csr_modes_agree(monkeypatch, mode):
     """Every spmv_mode produces the same batched SpMV on a skewed
-    pattern (the pallas row dispatches the batch-grid kernel in
-    interpret mode off-TPU, failing over like PreparedCSR)."""
+    pattern ('pallas' has no kernel for a gather layout: it takes the
+    XLA slab form, as 'sell' and 'auto' do)."""
     monkeypatch.setattr(settings, "spmv_mode", mode)
     A = _skewed()
     mats = []

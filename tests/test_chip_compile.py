@@ -302,34 +302,3 @@ def test_dist_cg_dia_program_compiles_on_four_chips(chip):
     used = _device_bytes(c)
     print(f"dist_cg dia g={g}/chip: {used / 2**30:.2f} GiB per device")
     assert used < HBM_BYTES
-
-
-# ---------------------------------------------------------------------------
-# the two opt-in SELL Pallas kernels: Mosaic refuses both today. These pin
-# the refusal, so the PR that makes them lower (or deletes them, ROADMAP S3)
-# has a test to flip.
-# ---------------------------------------------------------------------------
-def test_sell_slab_pallas_is_refused(one_chip):
-    from sparse_tpu.kernels.sell_spmv import _sell_slab_pallas
-
-    K, R, n, TM = 16, 4096, 65536, 1024
-    with pytest.raises(ValueError, match="int indexing"):
-        _sell_slab_pallas.lower(
-            _sds((K, R), jnp.int32, one_chip),
-            _sds((K, R), jnp.float32, one_chip),
-            _sds((n,), jnp.float32, one_chip),
-            K, TM, interpret=False,
-        ).compile()
-
-
-def test_sell_slab_pallas_batched_is_refused(one_chip):
-    from sparse_tpu.kernels.sell_spmv import _sell_slab_pallas_batched
-
-    B, K, R, n, TM = 8, 16, 4096, 65536, 1024
-    with pytest.raises(ValueError, match="divisible"):
-        _sell_slab_pallas_batched.lower(
-            _sds((K, R), jnp.int32, one_chip),
-            _sds((B, K, R), jnp.float32, one_chip),
-            _sds((B, n), jnp.float32, one_chip),
-            K, TM, interpret=False,
-        ).compile()

@@ -6,7 +6,6 @@ import scipy.sparse as sp
 
 import sparse_tpu
 from sparse_tpu.config import settings
-from sparse_tpu.kernels.dia_spmv import dia_spmv_pallas
 from sparse_tpu.ops.dia_spmv import dia_spmv_xla
 
 CASES = [
@@ -25,18 +24,6 @@ def test_dia_spmv_xla(m, n, offs):
     s = sp.dia_matrix((data, offs), shape=(m, n))
     x = rng.standard_normal(n)
     got = np.asarray(dia_spmv_xla(data, tuple(offs), x, (m, n)))
-    np.testing.assert_allclose(got, s @ x, rtol=1e-12, atol=1e-12)
-
-
-@pytest.mark.parametrize("m,n,offs", CASES)
-def test_dia_spmv_pallas_interpret(m, n, offs):
-    rng = np.random.default_rng(m)
-    data = rng.standard_normal((len(offs), n))
-    s = sp.dia_matrix((data, offs), shape=(m, n))
-    x = rng.standard_normal(n)
-    got = np.asarray(
-        dia_spmv_pallas(data, tuple(offs), x, (m, n), interpret=True)
-    )
     np.testing.assert_allclose(got, s @ x, rtol=1e-12, atol=1e-12)
 
 
@@ -127,16 +114,6 @@ def test_dia_transpose_nonsquare_dot():
     got = np.asarray(At @ np.ones(40))
     want = sp.dia_matrix((np.ones((1, 60)), [0]), shape=(40, 60)).T @ np.ones(40)
     np.testing.assert_allclose(got, want)
-
-
-def test_dia_pallas_wide_matrix():
-    m, n, offs = 100, 390, (0, 5)
-    rng = np.random.default_rng(9)
-    data = rng.standard_normal((2, n))
-    s = sp.dia_matrix((data, offs), shape=(m, n))
-    x = rng.standard_normal(n)
-    got = np.asarray(dia_spmv_pallas(data, offs, x, (m, n), interpret=True))
-    np.testing.assert_allclose(got, s @ x, rtol=1e-12, atol=1e-12)
 
 
 def test_spmv_mode_ell_overrides_dia():

@@ -90,11 +90,11 @@ def test_spec_parse_basic():
 
 def test_spec_parse_defaults_and_multi():
     cs = faults.parse_spec(
-        " fail:pallas:kernel=sell_spmv,n=1 ; drop:dispatch ;"
+        " fail:pallas:kernel=dia_spmv,n=1 ; drop:dispatch ;"
         " preempt:chunk:p=0.5 ;"
     )
     assert [c.site for c in cs] == ["pallas", "dispatch", "chunk"]
-    assert cs[0].kernel == "sell_spmv" and cs[0].n == 1 and cs[0].p == 1.0
+    assert cs[0].kernel == "dia_spmv" and cs[0].n == 1 and cs[0].p == 1.0
     assert cs[1].fault == "drop" and cs[1].seed == 0
     assert cs[2].p == 0.5
 
@@ -436,29 +436,6 @@ def test_registry_mark_reinstate_cycle():
     assert failover.failed("k1", o)
 
 
-def test_injected_pallas_failure_sell(monkeypatch):
-    from sparse_tpu.kernels.sell_spmv import PreparedCSR
-
-    settings.telemetry = True
-    monkeypatch.setattr(settings, "spmv_mode", "pallas")
-    G = _spd(32).astype(np.float32)
-    prep = PreparedCSR(G.indptr, G.indices, G.data, G.shape)
-    x = np.random.default_rng(0).standard_normal(32).astype(np.float32)
-    faults.configure("fail:pallas:kernel=sell_spmv,n=1")
-    with pytest.warns(UserWarning, match="failing over"):
-        y = np.asarray(prep(x))
-    np.testing.assert_allclose(y, G @ x, rtol=1e-5, atol=1e-5)
-    assert failover.failed(prep.KERNEL, prep)
-    assert telemetry.events("fault.injected")
-    (ev,) = telemetry.events("kernel.failover")
-    assert ev["kernel"] == "sell_spmv" and "injected" in ev["error"].lower()
-    # probe-based reinstate: injection cleared, the real kernel works
-    faults.clear()
-    assert prep.probe_pallas(x)
-    assert not failover.failed(prep.KERNEL, prep)
-    assert telemetry.events("kernel.reinstate")
-
-
 def test_injected_pallas_failure_dia():
     from sparse_tpu.kernels.dia_spmv import DIA_KERNEL, cached_prepared_spmv
 
@@ -478,38 +455,33 @@ def test_injected_pallas_failure_dia():
         out = cached_prepared_spmv(h, "dia", data, offsets, (n, n), x)
     assert out is None  # caller takes the XLA formulation
     assert failover.failed(DIA_KERNEL, h)
+    assert telemetry.events("fault.injected")
     (ev,) = telemetry.events("kernel.failover")
-    assert ev["kernel"] == "dia_spmv"
+    assert ev["kernel"] == "dia_spmv" and "injected" in ev["error"].lower()
+    assert cached_prepared_spmv(h, "dia", data, offsets, (n, n), x) is None
+    # probe-based reinstate: injection cleared, the real kernel works
+    import jax
+
+    from sparse_tpu import plan_cache
+
+    faults.clear()
+    prepared = plan_cache.lookup(h, "dia")
+    assert failover.probe(
+        DIA_KERNEL, h, lambda: jax.block_until_ready(prepared(x))
+    )
+    assert not failover.failed(DIA_KERNEL, h)
+    assert telemetry.events("kernel.reinstate")
+    want = data[0] * np.roll(x, 1) + data[1] * x + data[2] * np.roll(x, -1)
+    want[0] -= data[0][0] * x[-1]
+    want[-1] -= data[2][-1] * x[0]
+    got = cached_prepared_spmv(h, "dia", data, offsets, (n, n), x)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
 
 
-def test_injected_pallas_failure_batched(monkeypatch):
-    from sparse_tpu.batch import BatchedCSR
-
-    settings.telemetry = True
-    monkeypatch.setattr(settings, "spmv_mode", "pallas")
-    mats, _ = _stack(n=32, B=3)
-    bc = BatchedCSR.from_stack([m.astype(np.float32) for m in mats])
-    X = np.random.default_rng(1).standard_normal((3, 32)).astype(np.float32)
-    faults.configure("fail:pallas:kernel=sell_spmv_batched,n=1")
-    with pytest.warns(UserWarning, match="failing over"):
-        Y = np.asarray(bc.matvec(X))
-    for i in range(3):
-        np.testing.assert_allclose(
-            Y[i], mats[i] @ X[i], rtol=1e-4, atol=1e-4
-        )
-    # latched on the PATTERN: with_values siblings share the latch
-    assert failover.failed(bc.KERNEL, bc.pattern)
-    sib = bc.with_values(bc.values)
-    assert failover.failed(sib.KERNEL, sib.pattern)
-
-
-@pytest.mark.parametrize(
-    "kernel, vocab",
-    [("sell_spmv", False), ("sell_spmv_batched", False), ("dia_spmv", True)],
-)
-def test_tpu_backend_pallas_error_raises_at_every_site(monkeypatch, kernel, vocab):
+@pytest.mark.parametrize("kernel", ["dia_spmv"])
+def test_tpu_backend_pallas_error_raises_at_every_site(monkeypatch, kernel):
     """On the TPU backend a Pallas error that was not injected is an error
-    at each of the three failover sites — no quiet XLA substitute."""
+    at every failover site (one today) — no quiet XLA substitute."""
     import jax
 
     monkeypatch.delenv("SPARSE_TPU_STRICT_PALLAS", raising=False)
@@ -523,36 +495,12 @@ def test_tpu_backend_pallas_error_raises_at_every_site(monkeypatch, kernel, voca
                 NotImplementedError("unimplemented lowering"),
                 ValueError("only supported in interpret mode")):
         with pytest.raises(type(err)):
-            failover.handle(kernel, o, err, vocab=vocab)
+            failover.handle(kernel, o, err)
     assert not failover.failed(kernel, o)
     # the injected failure still rides the production failover path
     with pytest.warns(UserWarning, match="failing over"):
-        failover.handle(
-            kernel, o, failover.InjectedPallasFailure("injected"), vocab=vocab
-        )
+        failover.handle(kernel, o, failover.InjectedPallasFailure("injected"))
     assert failover.failed(kernel, o)
-
-
-def test_tpu_backend_does_not_select_the_sell_pallas_kernels(monkeypatch):
-    """While Mosaic refuses both SELL kernels (tests/test_chip_compile.py),
-    spmv_mode='pallas' picks the XLA slab form on a TPU by choice."""
-    import jax
-
-    from sparse_tpu.batch import BatchedCSR
-    from sparse_tpu.kernels.sell_spmv import PreparedCSR
-
-    monkeypatch.setattr(settings, "spmv_mode", "pallas")
-    G = _spd(32).astype(np.float32)
-    prep = PreparedCSR(G.indptr, G.indices, G.data, G.shape)
-    x = np.ones(32, np.float32)
-    mats, _ = _stack(n=32, B=2)
-    bc = BatchedCSR.from_stack([m.astype(np.float32) for m in mats])
-    pack, _vals = bc._packed()
-    X = np.ones((2, 32), np.float32)
-    assert prep._pallas_viable(x) and bc._pallas_viable(pack, X)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert not prep._pallas_viable(x)
-    assert not bc._pallas_viable(pack, X)
 
 
 # ---------------------------------------------------------------------------
