@@ -19,6 +19,13 @@ by exactly one block while window DMA starts (gg*TM - B) stay 1024-aligned.
 Row-indexed planes (data_row[k, i] = coefficient of diagonal k at ROW i)
 make the plane stream halo-free.
 
+Residency: the row-indexed planes depend on the operator alone, so they are
+packed by a program of their own (:func:`cg_dia_pack`) and kept by whoever
+keeps the operator; the padded state is made once a solve
+(:func:`cg_dia_start`) and threaded through the loop program
+(:func:`cg_dia_chunk`), which holds nothing but the iterations. Nothing is
+re-packed, padded or un-padded between the chunks of a solve.
+
 Reference analog: the fused AXPBY task family (linalg.py:479-496) taken to
 its limit — the reference fuses two vector ops per launch; the TPU version
 fuses the entire iteration into two memory passes.
@@ -450,57 +457,51 @@ def cg_dia_fused_onepass(
     return _unpad_vec(xp, m, TM), _unpad_vec(rp, m, TM), rho
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "offsets", "m", "iters", "tile", "plane_dtype", "interpret",
-        "return_state", "acc_dtype",
-    ),
-)
-def cg_dia_fused(
-    data, offsets: tuple, b, x0, m: int, iters: int = 300, tile: int = 16384,
-    plane_dtype=None, interpret: bool = False, state=None,
-    return_state: bool = False, acc_dtype=None,
-):
-    """``iters`` fixed CG iterations on the DIA matrix (throughput mode).
+@partial(jax.jit, static_argnames=("offsets", "m", "tile", "plane_dtype"))
+def cg_dia_pack(data, offsets: tuple, m: int, tile: int, plane_dtype):
+    """The operator half of :func:`cg_dia_fused`: scipy-layout planes ->
+    the kernels' flat row-indexed ``[D * m_pad]`` stream at ``plane_dtype``
+    (already resolved: :func:`_resolve_plane_dtype`). Depends on the planes
+    and the plan ``tile`` gives, on nothing of a solve: pack once an
+    operator and hand the result to every :func:`cg_dia_chunk`. The scope
+    names this work in each op's ``op_name``."""
+    TM, B, G = _plan(m, offsets, tile=tile)
+    with jax.named_scope("cg_dia.repack"):
+        return _row_planes(data.astype(plane_dtype), offsets, TM, B, G, m)
 
-    Returns (x, r, rho) with rho = ||r||^2. Matches ``cg_step_dia``'s
-    recurrence exactly (same beta/alpha guards) — two fused passes per
-    iteration instead of an SpMV plus a train of elementwise kernels.
-    ``x0=None`` starts from zero and skips the setup SpMV (r0 = b).
 
-    ``state``/``return_state`` thread the FULL padded CG state
-    (xp, rp, pp, rho_prev, rho) across calls, so a tolerance-driven caller
-    (``linalg.cg``'s fused fast path) can run in conv-test-sized chunks
-    with one host rho fetch per chunk — identical iterates to one long
-    run, no CG restart between chunks.
-
-    ``acc_dtype`` is the recurrence-scalar split (ISSUE 15): the
-    <p, q> / <r, r> dot partials reduce — and rho/beta/alpha carry —
-    at ``acc_dtype`` while vectors stream at ``dt`` (and planes at
-    ``plane_dtype``). ``None`` = historic single-dtype behavior,
-    byte-identical; callers threading ``state`` must keep the same
-    ``acc_dtype`` across chunks (the rho entries carry it).
-    """
+@partial(jax.jit, static_argnames=("offsets", "m", "tile", "acc_dtype"))
+def cg_dia_start(data, offsets: tuple, b, x0, m: int, tile: int, acc_dtype=None):
+    """The padded CG state ``(xp, rp, pp, rho_prev, rho)`` a solve starts
+    from: r0 = b - A x0 (r0 = b and no product when ``x0`` is None), padded
+    once; rho at ``acc_dtype`` (None: the vectors' dtype)."""
     dt = jnp.result_type(data.dtype, b.dtype)
     adt = jnp.dtype(acc_dtype) if acc_dtype is not None else dt
+    TM, _, G = _plan(m, offsets, tile=tile)
+    if x0 is None:
+        xp = jnp.zeros(((G + 2) * TM,), dt)
+        rp0 = _pad_vec(b.astype(dt), TM, G)  # r = b - A @ 0
+    else:
+        from ..ops.dia_spmv import dia_spmv_xla
+
+        xp = _pad_vec(x0.astype(dt), TM, G)
+        r0 = b.astype(dt) - dia_spmv_xla(
+            data.astype(dt), offsets, x0.astype(dt), (m, m)
+        )
+        rp0 = _pad_vec(r0, TM, G)
+    rho0 = jnp.vdot(rp0, rp0).real.astype(adt)
+    return xp, rp0, jnp.zeros_like(rp0), jnp.zeros((), adt), rho0
+
+
+def _chunk(planes_row, state, offsets: tuple, m: int, iters: int, tile: int,
+           interpret: bool):
+    xp, rp, _, _, rho = state
+    dt, adt, pdt = xp.dtype, rho.dtype, planes_row.dtype
     TM, B, G = _plan(m, offsets, tile=tile)
     win = TM + 2 * B
     m_pad = G * TM
     L = (G + 2) * TM
     D = len(offsets)
-
-    pdt = _resolve_plane_dtype(plane_dtype, dt, TM)
-    # every call re-packs the planes into the kernels' row layout and pads
-    # the vectors: the scope names that work in each op's `op_name`
-    with jax.named_scope("cg_dia.repack"):
-        planes_row = _row_planes(data.astype(pdt), offsets, TM, B, G, m)
-        bp = _pad_vec(b.astype(dt), TM, G)
-        xp = (
-            jnp.zeros(((G + 2) * TM,), dt)
-            if x0 is None
-            else _pad_vec(x0.astype(dt), TM, G)
-        )
 
     kA = pl.pallas_call(
         _kernel_a(offsets, TM, B, win, D, m_pad),
@@ -562,20 +563,6 @@ def cg_dia_fused(
         interpret=interpret,
     )
 
-    if state is None:
-        if x0 is None:
-            rp0 = bp  # r = b - A @ 0
-        else:
-            from ..ops.dia_spmv import dia_spmv_xla
-
-            r0 = b.astype(dt) - dia_spmv_xla(
-                data.astype(dt), offsets, x0.astype(dt), (m, m)
-            )
-            rp0 = _pad_vec(r0, TM, G)
-        rho0 = jnp.vdot(rp0, rp0).real.astype(adt)
-        pp0 = jnp.zeros_like(bp)
-        state = (xp, rp0, pp0, jnp.zeros((), adt), rho0)
-
     def body(_, state):
         xp, rp, pp, rho_prev, rho = state
         # the scalar recurrence runs at adt (the acc_dtype split); only
@@ -586,10 +573,83 @@ def cg_dia_fused(
         xp2, rp2, rr = kB(alpha.reshape(1, 1).astype(dt), xp, pnew, rp, q)
         return xp2, rp2, pnew, rho, rr[0, 0]
 
-    out_state = jax.lax.fori_loop(0, iters, body, state)
-    xp, rp, _, _, rho = out_state
-    x_out = _unpad_vec(xp, m, TM)
-    r_out = _unpad_vec(rp, m, TM)
-    if return_state:
-        return x_out, r_out, rho, out_state
-    return x_out, r_out, rho
+    # Two iterations a trip: a `while` keeps each carried array in ONE
+    # buffer, and a kernel cannot write x', r' or p' into the buffer it reads
+    # x, r or p from, so a one-iteration trip copies all three vectors to
+    # make room (three [L] copies an iteration: a quarter of the device time
+    # at 3200^2). Over two iterations the state ping-pongs between two sets
+    # of buffers and the trip ends where it began. Same ops, same order.
+    return jax.lax.fori_loop(0, iters, body, state, unroll=2)
+
+
+# The loop program. A profile and the benchmark's roofline reader find it
+# by the jitted function's name, which has to stay `cg_dia_fused` (with the
+# kernels' `cg_dia_a`/`cg_dia_b`). Two compilations of one function: the
+# chip's takes the threaded state's buffers for its outputs; the CPU has no
+# donation (interpret mode, the tests) and jax would warn at every call.
+_chunk.__name__ = _chunk.__qualname__ = "cg_dia_fused"
+_CHUNK_STATICS = ("offsets", "m", "iters", "tile", "interpret")
+_chunk_donating = jax.jit(
+    _chunk, static_argnames=_CHUNK_STATICS, donate_argnames=("state",)
+)
+_chunk_interpreted = jax.jit(_chunk, static_argnames=_CHUNK_STATICS)
+
+
+def cg_dia_chunk(planes_row, state, offsets: tuple, m: int, iters: int,
+                 tile: int = 16384, interpret: bool = False):
+    """``iters`` CG iterations from ``state`` to the next state: nothing but
+    the two kernels and the scalar recurrence. ``planes_row`` from
+    :func:`cg_dia_pack`, the first ``state`` from :func:`cg_dia_start`, both
+    for the same ``tile``; the dtypes ride the arrays. On the chip
+    (``interpret=False``) the state handed in is DONATED: its buffers become
+    the next state's, and the caller may not read it again. ``state[-1]`` is
+    rho = ||r||^2 and :func:`cg_dia_x` un-pads the iterate."""
+    run = _chunk_interpreted if interpret else _chunk_donating
+    return run(planes_row, state, offsets=offsets, m=m, iters=iters,
+               tile=tile, interpret=interpret)
+
+
+def cg_dia_x(state, offsets: tuple, m: int, tile: int = 16384):
+    """The iterate ``x`` ``[m]`` of a padded CG state."""
+    TM, _, _ = _plan(m, offsets, tile=tile)
+    return _unpad_vec(state[0], m, TM)
+
+
+def cg_dia_fused(
+    data, offsets: tuple, b, x0, m: int, iters: int = 300, tile: int = 16384,
+    plane_dtype=None, interpret: bool = False, state=None,
+    return_state: bool = False, acc_dtype=None,
+):
+    """``iters`` fixed CG iterations on the DIA matrix (throughput mode),
+    as one call: :func:`cg_dia_pack`, :func:`cg_dia_start` and one
+    :func:`cg_dia_chunk`. A caller that solves more than once with an
+    operator, or in chunks, keeps the pack and threads the state itself
+    (``linalg.cg``'s fused fast path).
+
+    Returns (x, r, rho) with rho = ||r||^2. Matches ``cg_step_dia``'s
+    recurrence exactly (same beta/alpha guards) — two fused passes per
+    iteration instead of an SpMV plus a train of elementwise kernels.
+    ``x0=None`` starts from zero and skips the setup SpMV (r0 = b).
+
+    ``state``/``return_state`` thread the FULL padded CG state
+    (xp, rp, pp, rho_prev, rho) across calls — identical iterates to one
+    long run, no CG restart between chunks. A ``state`` handed in is
+    consumed on the chip (:func:`cg_dia_chunk`).
+
+    ``acc_dtype`` is the recurrence-scalar split (ISSUE 15): the
+    <p, q> / <r, r> dot partials reduce — and rho/beta/alpha carry —
+    at ``acc_dtype`` while vectors stream at ``dt`` (and planes at
+    ``plane_dtype``). ``None`` = historic single-dtype behavior,
+    byte-identical; callers threading ``state`` must keep the same
+    ``acc_dtype`` across chunks (the rho entries carry it).
+    """
+    dt = jnp.result_type(data.dtype, b.dtype)
+    TM, _, _ = _plan(m, offsets, tile=tile)
+    planes_row = cg_dia_pack(
+        data, offsets, m, tile, _resolve_plane_dtype(plane_dtype, dt, TM)
+    )
+    if state is None:
+        state = cg_dia_start(data, offsets, b, x0, m, tile, acc_dtype)
+    state = cg_dia_chunk(planes_row, state, offsets, m, iters, tile, interpret)
+    out = cg_dia_x(state, offsets, m, tile), _unpad_vec(state[1], m, TM), state[4]
+    return (*out, state) if return_state else out

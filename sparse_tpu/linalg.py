@@ -27,7 +27,7 @@ from . import telemetry
 from .base import SparseArray
 from .coverage import track_provenance
 from .resilience import faults as _faults
-from .utils import asjnp, host_int
+from .utils import asjnp, host_int, resident_on
 from ._direct import (  # noqa: F401  (re-exported scipy.sparse.linalg surface)
     SpILU,
     SuperLU,
@@ -570,25 +570,9 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
     if x0 is not None:
         x0 = asjnp(x0)
 
-    from .kernels.cg_dia import cg_dia_fused
-
-    # RESIDENCY: the planes are jit ARGUMENTS of the fused kernel, so a
-    # host-resident layout (matrices built in a CPU-scoped construction
-    # phase) would re-transfer the whole matrix through the accelerator
-    # link on EVERY chunk (~720 MB at 6000^2). Commit once; cache back
-    # on the csr
-    # so later solves skip even that. device_put is a no-op when the
-    # array is already resident.
-    dev = jax.devices()[0]
-    if dev.platform != "cpu":
-        planes = jax.device_put(planes, dev)
-        if getattr(A, "_dia", None):
-            A._dia = (planes, offsets)
-        elif isinstance(A, dia_array):
-            A.data = planes  # dia storage IS the planes: commit in place
-        b = jax.device_put(b, dev)
-        if x0 is not None:
-            x0 = jax.device_put(x0, dev)
+    from .kernels.cg_dia import (
+        cg_dia_chunk, cg_dia_pack, cg_dia_start, cg_dia_x,
+    )
 
     # Known-best tile from the hardware sweeps (settings.fused_cg_tile,
     # 65536), clamped so the kernel's VMEM plane scratch (2 * D double-
@@ -599,19 +583,48 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
     tile = max(16384, min(int(settings.fused_cg_tile),
                           (6 << 20) // (max(2 * D + 10, 1) * 4)))
 
+    # RESIDENCY: the fused path's operands live in the kernels' layout for
+    # as long as the operator does. The planes are committed to the device
+    # once (a host-resident layout, built in a CPU-scoped construction
+    # phase, would go through the accelerator link again at every pack) and
+    # packed once into the row-indexed stream, which is kept on A beside
+    # the planes it was made from: (planes, key, planes_row). jax arrays
+    # are immutable, so the planes' identity is a sound test: an operator
+    # whose planes were replaced (`_dia` rebuilt, a dia_array's `.data`
+    # assigned) packs again, and so does another tile or dtype. A solve's
+    # chunks then thread the padded state alone (kernels/cg_dia.py).
+    dev = jax.devices()[0]
+    key = (offsets, m, tile, dt)
+    pack = getattr(A, "_cg_pack", None)
+    packs = 0
+    if pack is None or pack[0] is not planes or pack[1] != key:
+        if dev.platform != "cpu":
+            planes = resident_on(planes, dev)
+            if getattr(A, "_dia", None):
+                A._dia = (planes, offsets)
+            elif isinstance(A, dia_array):
+                A.data = planes  # dia storage IS the planes: commit in place
+        pack = A._cg_pack = (planes, key, cg_dia_pack(planes, offsets, m, tile, dt))
+        packs = 1
+    planes_row = pack[2]
+    if dev.platform != "cpu":
+        b = jax.device_put(b, dev)
+        if x0 is not None:
+            x0 = jax.device_put(x0, dev)
+
     tol2 = float(tol) ** 2
     chunk = max(int(conv_test_iters), 1)
     state = None
     iters = chunks = 0
     dispatch_s = fetch_s = 0.0
-    x = None
     rho_f = None
     info = None
     # One `cg.solve` span a call. Per chunk, `cg.chunk` is the kernel
     # program's call until it returns (asynchronous: the host's part of
-    # dispatching it) and `cg.rho_fetch` the wait for its rho on the host;
-    # both are trace annotations and aggregates only, and their sums go
-    # onto the solve's event as `dispatch_s` and `fetch_s`.
+    # dispatching it; the first one makes the padded state too) and
+    # `cg.rho_fetch` the wait for its rho on the host; both are trace
+    # annotations and aggregates only, and their sums go onto the solve's
+    # event as `dispatch_s` and `fetch_s`.
     with telemetry.span("cg.solve", path="fused") as solve:
         while iters < maxiter:
             if _faults.ACTIVE:
@@ -626,15 +639,17 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
             k = min(chunk, max(maxiter - 1 - iters, 1))
             k = min(k, maxiter - iters)
             with telemetry.span("cg.chunk", emit=False) as sp:
-                x, _r, rho, state = cg_dia_fused(
-                    planes, offsets, b, x0, m, iters=k, tile=tile,
-                    state=state, return_state=True, interpret=interpret,
+                if state is None:
+                    state = cg_dia_start(planes, offsets, b, x0, m, tile)
+                # on the chip the state handed in is donated to the next
+                state = cg_dia_chunk(
+                    planes_row, state, offsets, m, k, tile, interpret
                 )
             dispatch_s += sp.dur_s or 0.0
             iters += k
             chunks += 1
             with telemetry.span("cg.rho_fetch", emit=False) as sp:
-                rho_f = float(rho)
+                rho_f = float(state[-1])
             fetch_s += sp.dur_s or 0.0
             if telemetry.enabled():
                 # one event per conv-test chunk, reusing the rho scalar
@@ -654,8 +669,9 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
             if rho_f < tol2:
                 info = 0
                 break
+        x = cg_dia_x(state, offsets, m, tile)  # un-padded once, at the end
         if solve.t0 is not None:  # live: telemetry on, not under a trace
-            solve.annotate(chunks=chunks, iters=iters,
+            solve.annotate(chunks=chunks, iters=iters, packs=packs,
                            dispatch_s=round(dispatch_s, 9),
                            fetch_s=round(fetch_s, 9))
     if info is None:  # maxiter exhausted

@@ -142,6 +142,15 @@ def host_scope():
     return jax.default_device(jax.local_devices(backend="cpu")[0])
 
 
+def resident_on(a, device):
+    """``a`` committed to ``device``: ``a`` ITSELF where it already is (a
+    ``device_put`` would hand back another object over the same buffer, and
+    what a layout cache keys on the identity of its source would miss)."""
+    if getattr(a, "committed", False) and a.devices() == {device}:
+        return a
+    return jax.device_put(a, device)
+
+
 def commit_to_exec_device(arrs):
     """Commit a tuple of arrays to the ACTIVE execution device.
 
@@ -157,4 +166,4 @@ def commit_to_exec_device(arrs):
     target = jax.config.jax_default_device or jax.devices()[0]
     if getattr(target, "platform", "cpu") == "cpu":
         return arrs
-    return tuple(jax.device_put(a, target) for a in arrs)
+    return tuple(resident_on(a, target) for a in arrs)

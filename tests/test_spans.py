@@ -307,7 +307,10 @@ def test_fused_cg_solve_is_one_span_event_with_its_chunks(tel, monkeypatch):
     evs = telemetry.events("span")[n0:]
     assert [e["name"] for e in evs] == ["cg.solve"]  # one event a call
     (ev,) = evs
+    assert set(ev) >= {"path", "chunks", "iters", "packs", "dispatch_s", "fetch_s"}
     assert ev["path"] == "fused" and ev["iters"] == int(iters)
+    first = next(e for e in telemetry.events("span") if e["name"] == "cg.solve")
+    assert (first["packs"], ev["packs"]) == (1, 0)  # one pack an operator
     assert ev["chunks"] == len(telemetry.events("solver.iter")) // 2
     assert ev["chunks"] >= 2
     assert 0 < ev["dispatch_s"] and 0 < ev["fetch_s"]
