@@ -62,6 +62,23 @@ def _layouts(ndim: int, mode: str | None = None) -> tuple:
     return _LAYOUTS[mode][ndim - 1]
 
 
+def form_matvec(kind: str, meta, arrays, x):
+    """``A @ x`` through one layout, as ``csr_array._spmv_form`` names it: a
+    pure function of the layout's arrays, jit-safe, the matrix an argument."""
+    if kind == "dia":
+        from .ops.dia_spmv import dia_spmv_xla
+
+        return dia_spmv_xla(arrays, meta[0], x, meta[1])
+    if kind == "sell":
+        return spmv_ops.csr_spmv_sell(*arrays, x, meta)
+    if kind == "ell":
+        # XLA's HBM-gather formulation: a Pallas ELL kernel needs a
+        # windowed in-VMEM gather, which Mosaic cannot lower (single-
+        # tile take_along_axis only)
+        return spmv_ops.csr_spmv_ell(*arrays, x)
+    return spmv_ops.csr_spmv_segment(*arrays, x, meta)
+
+
 @jax.tree_util.register_pytree_node_class
 class csr_array(SparseArray):
     format = "csr"
@@ -180,7 +197,8 @@ class csr_array(SparseArray):
         if gated and not self._tight():
             return None
         if self._ell is None:
-            with host_scope():  # one-time layout build: on the host
+            # one-time layout build: on the host
+            with telemetry.span("layout.ell_build"), host_scope():
                 self._ell = conv.csr_to_ell(
                     self.indptr, self.indices, self.data, m,
                     max(self._ell_width(), 1),
@@ -214,7 +232,8 @@ class csr_array(SparseArray):
         def build():
             from .kernels.sell_spmv import PreparedCSR
 
-            with host_scope():  # one-time pack: on the host
+            # one-time pack: on the host
+            with telemetry.span("layout.sell_build"), host_scope():
                 prep = PreparedCSR(
                     self.indptr, self.indices, self.data, self.shape
                 )
@@ -386,61 +405,88 @@ class csr_array(SparseArray):
 
     @staticmethod
     def _fetch_offsets(offs_dev):
-        """Host fetch of the bounded-unique diagonal offsets — the one
-        device->host transfer of banded detection, split out so tests can
-        make it fail."""
+        """Host fetch of diagonal offsets — the device->host transfer of
+        banded detection (one for a matrix the strided sample turns away, a
+        second, of the bounded unique, for one it does not), split out so
+        tests can make it fail."""
         return np.unique(np.asarray(offs_dev))
 
     def _maybe_dia_detect(self, m, n, nnz):
-        rows = expand_rows(self.indptr, nnz)
-        # bounded-size unique: >max_diags distinct offsets still yields
-        # max_diags+1 values, which the gate below rejects
+        from .dia import _coo_to_dia
+
+        # `layout.detect` is the decision alone, banded or not: all that a
+        # general matrix pays here
+        with telemetry.span("layout.detect"):
+            banded = self._few_diagonals(n, nnz)
+        if not banded:
+            return None
+        # duplicate-summing plane build
+        planes, offsets, _ = _coo_to_dia(self.tocoo())
+        return (planes, tuple(int(o) for o in offsets))
+
+    def _few_diagonals(self, n, nnz) -> bool:
+        """The banded rule (``dia.few_diagonals``) on this matrix's arrays."""
+        from .dia import few_diagonals
+
         # col - row fits int32 whenever both dims do (values < 2**31 each,
         # difference in (-2**31, 2**31)); int64 here would just warn-and-
         # truncate under the default no-x64 config
-        offs_dev = jnp.unique(self.indices.astype(jnp.int32) - rows.astype(jnp.int32),
+        idt = jnp.int32
+        # a general matrix is turned away by a strided sample of its entries
+        # (the rule of `dia.banded_offsets`, here on the arrays as they are):
+        # more diagonals in the sample than a banded matrix has in all
+        at = jnp.arange(0, nnz, max(nnz // 8192, 1), dtype=self.indptr.dtype)
+        rows = jnp.searchsorted(self.indptr, at, side="right") - 1
+        sample = self._fetch_offsets(
+            self.indices[at].astype(idt) - rows.astype(idt))
+        if len(sample) > settings.dia_max_diags:
+            return False
+        rows = expand_rows(self.indptr, nnz)
+        # bounded-size unique: >max_diags distinct offsets still yields
+        # max_diags+1 values, which the gate below rejects
+        offs_dev = jnp.unique(self.indices.astype(idt) - rows.astype(idt),
                               size=min(settings.dia_max_diags + 1, nnz),
-                              fill_value=jnp.iinfo(jnp.int32).max)
+                              fill_value=jnp.iinfo(idt).max)
         # a fetch that fails raises: a banded matrix must not go down the
         # gather path in silence (tests/test_sell_spmv.py)
         offs = self._fetch_offsets(offs_dev)
         offs = offs[offs != np.iinfo(np.int32).max]
-        from .dia import _coo_to_dia, few_diagonals
+        return few_diagonals(len(offs), n, nnz)
 
-        if not few_diagonals(len(offs), n, nnz):
-            return None
-        # duplicate-summing plane build
-
-        planes, offsets, _ = _coo_to_dia(self.tocoo())
-        return (planes, tuple(int(o) for o in offsets))
-
-    def _spmv(self, x):
+    def _spmv_form(self):
+        """``(kind, arrays, meta)`` of the layout a vector product takes now:
+        the ``_LAYOUTS`` walk of the ambient mode, each layout built on its
+        first eager use. ``arrays`` are the layout's jax arrays and ``meta``
+        its hashable rest, as :func:`form_matvec` takes them, so that a
+        compiled solver can have the matrix as an argument and nothing of
+        it as a constant of its program. ``"dia+"`` is the packed Pallas
+        kernel over the planes of ``"dia"``, which keeps an operator of its
+        own (``_spmv``)."""
         for name in _layouts(1):
             lay = self._offer(name)
             if lay is None:
                 continue
-            if name == "dia+":
-                from .kernels.dia_spmv import cached_prepared_spmv
-
-                y = cached_prepared_spmv(
-                    self, "_dia_prepared", lay[0], lay[1], self.shape, x
-                )
-                if y is None:  # band too wide for VMEM: the XLA form
-                    continue
-                return y
-            if name == "dia":
-                from .ops.dia_spmv import dia_spmv_xla
-
-                return dia_spmv_xla(lay[0], lay[1], x, self.shape)
+            if name.startswith("dia"):
+                return name, lay[0], (lay[1], self.shape)
             if name.startswith("sell"):
-                return lay(x)
-            # XLA's HBM-gather formulation: a Pallas ELL kernel needs a
-            # windowed in-VMEM gather, which Mosaic cannot lower (single-
-            # tile take_along_axis only)
-            return spmv_ops.csr_spmv_ell(lay[0], lay[1], x)
-        return spmv_ops.csr_spmv_segment(
-            self.indptr, self.indices, self.data, x, self.shape[0]
-        )
+                return "sell", (lay.slabs, lay.pos), lay.plan.zero_rows
+            return "ell", lay, None
+        return "segment", (self.indptr, self.indices, self.data), self.shape[0]
+
+    def _spmv(self, x):
+        kind, arrays, meta = self._spmv_form()
+        if kind == "dia+":
+            from .kernels.dia_spmv import cached_prepared_spmv
+
+            y = cached_prepared_spmv(
+                self, "_dia_prepared", arrays, meta[0], self.shape, x
+            )
+            if y is not None:
+                return y
+            kind = "dia"  # band too wide for VMEM: the XLA form
+        if kind == "sell":  # once a product here, as `PreparedCSR.__call__`
+            telemetry.count("kernel.sell_spmv")
+        return form_matvec(kind, meta, arrays, x)
 
     def _spmm(self, B):
         for name in _layouts(2):

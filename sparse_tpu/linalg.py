@@ -17,6 +17,7 @@ the reference's periodic-sync behavior.
 
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -27,6 +28,7 @@ from . import telemetry
 from .base import SparseArray
 from .coverage import track_provenance
 from .resilience import faults as _faults
+from .telemetry import _metrics
 from .utils import asjnp, host_int, resident_on
 from ._direct import (  # noqa: F401  (re-exported scipy.sparse.linalg surface)
     SpILU,
@@ -422,25 +424,32 @@ def _solve_event(
     )
 
 
+def _iter_tapping() -> bool:
+    """Whether compiled solver loops tap their iterations out. Taps run on
+    the CPU backend only: a host callback per iteration out of a device
+    loop stalls the loop it observes, and the TPU-relevant solve paths
+    (fused CG chunks, GMRES restart cycles) already report through scalars
+    they fetch anyway."""
+    return telemetry.enabled() and jax.default_backend() == "cpu"
+
+
+def _iter_tap(solver: str, path: str, i, rn2) -> None:
+    """Host side of a tap: one ``solver.iter`` event."""
+    telemetry.record(
+        "solver.iter", solver=solver, path=path,
+        iter=int(i), resid2=float(rn2),
+    )
+    # same concrete scalars feed the health monitor's residual
+    # history + NaN/stall/divergence detectors (telemetry/_health.py)
+    telemetry.health.observe(solver, int(i), float(rn2), path=path)
+
+
 def _make_iter_tap(solver: str, path: str = "device"):
     """Host-side tap for jax.debug.callback inside compiled solver loops,
-    or None when tapping is off. Taps run on the CPU backend only: a host
-    callback per iteration out of a device loop stalls the loop it
-    observes, and the TPU-relevant solve paths (fused CG chunks, GMRES
-    restart cycles) already report through scalars they fetch anyway."""
-    if not telemetry.enabled() or jax.default_backend() != "cpu":
+    or None when tapping is off (:func:`_iter_tapping`)."""
+    if not _iter_tapping():
         return None
-
-    def tap(i, rn2):
-        telemetry.record(
-            "solver.iter", solver=solver, path=path,
-            iter=int(i), resid2=float(rn2),
-        )
-        # same concrete scalars feed the health monitor's residual
-        # history + NaN/stall/divergence detectors (telemetry/_health.py)
-        telemetry.health.observe(solver, int(i), float(rn2), path=path)
-
-    return tap
+    return functools.partial(_iter_tap, solver, path)
 
 
 def _effects_barrier() -> None:
@@ -487,6 +496,11 @@ def cg(
             )
             return x_f, it_f
     A = make_linear_operator(A)
+    if M is None and callback is None:
+        out = _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters)
+        if out is not None:
+            _solve_event("cg", n, out[1], "device")
+            return out
     M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
     x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
 
@@ -679,23 +693,21 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
     return x, iters, rho_f, info
 
 
-def _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters):
-    """Whole-solve lax.while_loop: scalars stay on device, one final sync.
-
-    With telemetry enabled, each iteration taps (iter, ||r||^2) out to the
-    recorder through ``jax.debug.callback`` — the loop stays one compiled
-    program; the extra reduction exists only in the instrumented trace.
-    """
-    tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
-    tap = _make_iter_tap("cg")
+def _cg_while(matvec, precond, b, x, r, tol2, maxiter, conv_test_iters, tap):
+    """The whole-solve ``lax.while_loop`` of CG, as traced values ``(x,
+    iters)``: the one recurrence and stopping rule (absolute ||r||^2 < tol2,
+    tested every ``conv_test_iters`` iterations and at the last) behind the
+    closure loop and the compiled general program. With ``tap``, each
+    iteration taps (iter, ||r||^2) out through ``jax.debug.callback``; the
+    extra reduction exists only in the instrumented trace."""
 
     def body(state):
         x, r, p, rho, iters = state
-        z = M.matvec(r)
+        z = precond(r)
         rho1 = rho
         rho_new = _vdot(r, z)
         p = jnp.where(iters == 0, z, z + (rho_new / jnp.where(rho1 == 0, 1, rho1)) * p)
-        q = A.matvec(p)
+        q = matvec(p)
         pq = _vdot(p, q)
         alpha = rho_new / jnp.where(pq == 0, 1, pq)  # 0/0 guard: b=0 or exact x0
         x = x + alpha * p
@@ -715,10 +727,119 @@ def _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters):
     rho0 = jnp.zeros((), dtype=b.dtype)
     state = (x, r, p0, rho0, jnp.zeros((), dtype=jnp.int32))
     x, r, p, rho, iters = jax.lax.while_loop(cond, body, state)
+    return x, iters
+
+
+def _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters):
+    """Whole-solve lax.while_loop: scalars stay on device, one final sync.
+    The loop closes over ``A`` and ``M``, so each call traces and compiles
+    it anew with whatever they hold as constants: the path of a
+    preconditioned solve and of an operator that is no matrix (a
+    ``csr_array`` without ``M`` runs :func:`_cg_general`).
+
+    With telemetry enabled, each iteration taps (iter, ||r||^2) out to the
+    recorder through ``jax.debug.callback`` — the loop stays one compiled
+    program; the extra reduction exists only in the instrumented trace.
+    """
+    tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
+    tap = _make_iter_tap("cg")
+    x, iters = _cg_while(
+        A.matvec, M.matvec, b, x, r, tol2, maxiter, conv_test_iters, tap
+    )
     out = x, host_int(iters)
     if tap is not None:
         _effects_barrier()
     return out
+
+
+_CG_GENERAL_TRACES = _metrics.counter(
+    "cg.general.traces",
+    help="traces of the compiled general CG program (linalg._cg_general): "
+    "one per program built, none for a call that reuses one",
+)
+
+
+def _cg_general(arrays, b, start, tol, maxiter, *, kind, meta,
+                conv_test_iters, tapped):
+    """Whole-solve CG on one layout of a matrix (``csr.form_matvec``), the
+    layout's arrays, ``b``, the start, ``tol`` and ``maxiter`` all
+    arguments: nothing of the matrix is a constant of the program, and
+    jit's own key (layout kind and geometry, shapes, dtypes, test cadence)
+    is what a second solve, another operator of the same pattern or new
+    values over it, must match to run the program that is there. ``start``
+    is None (x = 0, r = b) or ``(x0, b - A x0)``. Same recurrence and
+    stopping rule as the closure loop (:func:`_cg_while`)."""
+    from .csr import form_matvec
+
+    _CG_GENERAL_TRACES.inc()
+    matvec = functools.partial(form_matvec, kind, meta, arrays)
+    x, r = (jnp.zeros_like(b), b) if start is None else start
+    tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
+    tap = functools.partial(_iter_tap, "cg", "device") if tapped else None
+    return _cg_while(
+        matvec, lambda v: v, b, x, r, tol2, maxiter, conv_test_iters, tap
+    )
+
+
+_cg_general.__name__ = _cg_general.__qualname__ = "cg_general"
+_cg_general_program = jax.jit(
+    _cg_general,
+    static_argnames=("kind", "meta", "conv_test_iters", "tapped"),
+)
+
+
+def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
+    """Unpreconditioned CG on a matrix through the compiled general program
+    (the trace names it ``jit_cg_general``): ``(x, iters)``, or None where
+    it does not apply — an operator that is no ``csr_array`` (or is wrapped
+    for fault injection), the packed Pallas DIA product, a call under an
+    outer trace — and the closure loop takes the solve.
+
+    What an operator keeps is its layout (``csr_array._spmv_form``: built
+    on the first product, committed to the device once); the program is
+    jit's, found again by the layout's kind and shapes, so no call after
+    the first of a pattern traces or compiles."""
+    from .utils import in_trace
+
+    csr = getattr(A, "A", None)
+    if (type(A) is not _SparseMatrixLinearOperator
+            or not hasattr(csr, "_spmv_form") or b.ndim != 1 or in_trace()
+            or not jnp.issubdtype(jnp.result_type(csr.dtype, b.dtype),
+                                  jnp.inexact)):
+        return None
+    kind, arrays, meta = csr._spmv_form()
+    if kind == "dia+":
+        return None
+    tapped = _iter_tapping()
+    # One `cg.solve` span a call, with the fields the fused path's has:
+    # `cg.dispatch` is the program's call until it returns (asynchronous:
+    # the host's part, and on a pattern's first call the trace and the
+    # compile), `cg.iters_fetch` the wait for the iteration count, the
+    # solve's one fence. Both are trace annotations and aggregates only;
+    # their lengths go onto the solve's event.
+    with telemetry.span("cg.solve", path="device", layout=kind) as solve:
+        start = None
+        if x0 is not None:
+            # the start's residual is a product of its own, before the
+            # program (as the closure loop's is): inside it the compiler
+            # would round it another way
+            x0 = asjnp(x0)
+            start = (x0, b - A.matvec(x0))
+        with telemetry.span("cg.dispatch", emit=False) as sp:
+            x, iters = _cg_general_program(
+                arrays, b, start, tol,
+                min(int(maxiter), np.iinfo(np.int32).max),
+                kind=kind, meta=meta,
+                conv_test_iters=int(conv_test_iters), tapped=tapped,
+            )
+        dispatch_s = sp.dur_s or 0.0
+        with telemetry.span("cg.iters_fetch", emit=False) as sp:
+            iters = host_int(iters)
+        solve.annotate(iters=iters, dispatch_s=round(dispatch_s, 9),
+                       fetch_s=round(sp.dur_s or 0.0, 9))
+    if tapped:
+        _effects_barrier()
+    return x, iters
 
 
 def _cg_host_loop(A, b, x, tol, maxiter, M, callback, conv_test_iters):

@@ -350,3 +350,31 @@ def test_dist_cg_dia_program_compiles_on_four_chips(chip):
     used = _device_bytes(c)
     print(f"dist_cg dia g={g}/chip: {used / 2**30:.2f} GiB per device")
     assert used < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the general CG: what linalg.cg takes for a matrix that is not banded, at
+# the size of the benchmark's unstructured SPD cell (1108^2 rows, rows padded
+# to 9 entries)
+# ---------------------------------------------------------------------------
+def test_cg_general_compiles_with_the_matrix_as_an_argument(one_chip):
+    import re
+
+    from sparse_tpu import linalg
+
+    n, width = 1108 * 1108, 9
+    arrays = (_sds((n, width), jnp.int32, one_chip),
+              _sds((n, width), jnp.float32, one_chip))
+    b = _sds((n,), jnp.float32, one_chip)
+    lowered = linalg._cg_general_program.lower(
+        arrays, b, None, 1e-8, 50,
+        kind="ell", meta=None, conv_test_iters=25, tapped=False)
+    c = lowered.compile()
+    text = c.as_text()
+    assert "jit_cg_general" in text
+    assert _device_bytes(c) < HBM_BYTES
+    # no constant of the compiled program is larger than a scalar: the
+    # layout's planes are its parameters (PR 22: 2.68 GB of captured blocks)
+    for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
+        assert int(np.prod([int(d) for d in dims.split(",") if d] or [1],
+                           dtype=np.int64)) <= 1, dims

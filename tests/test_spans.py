@@ -343,6 +343,61 @@ def test_layout_span_fires_once_an_operator(tel, monkeypatch):
     assert telemetry.summary()["spans"]["layout.dia_build"]["n"] == 2
 
 
+def _general(side=24, seed=5):
+    from .utils.spd import spd_data
+
+    d = spd_data(side, seed)
+    n = d["rows"]
+    A = sparse_tpu.csr_array((d["data"], d["indices"], d["indptr"]), shape=(n, n))
+    return A, d["b"]
+
+
+def test_general_cg_solve_is_one_span_event_with_its_fields(tel):
+    """A matrix that is not banded: the compiled general program's solve is
+    a ``cg.solve`` span too, with the fused path's fields."""
+    A, b = _general()
+    linalg.cg(A, b, maxiter=60)  # builds the layout, compiles
+    n0 = len(telemetry.events("span"))
+    agg0 = telemetry.summary()["spans"]
+    _x, iters = linalg.cg(A, b, maxiter=60)
+    (ev,) = telemetry.events("span")[n0:]  # one event a call
+    assert ev["name"] == "cg.solve"
+    assert (ev["path"], ev["layout"], ev["iters"]) == ("device", "ell", int(iters))
+    assert 0 < ev["dispatch_s"] and 0 < ev["fetch_s"]
+    assert ev["dispatch_s"] + ev["fetch_s"] <= ev["dur_s"]
+    first = next(e for e in telemetry.events("span") if e["name"] == "cg.solve")
+    # the first call's dispatch holds the trace and the compile
+    assert first["dispatch_s"] > ev["dispatch_s"]
+    # the inner spans are annotations and aggregates, not events
+    agg = telemetry.summary()["spans"]
+    for name in ("cg.dispatch", "cg.iters_fetch"):
+        assert agg[name]["n"] - agg0[name]["n"] == 1
+    assert telemetry.events("solver.solve")[-1]["path"] == "device"
+
+
+def test_general_layout_spans_fire_once_an_operator(tel, monkeypatch):
+    A, b = _general()
+    assert telemetry.events("span") == []
+    linalg.cg(A, b, maxiter=30)
+    linalg.cg(A, b, maxiter=30)  # cached: no second build
+    A @ b
+    names = [e["name"] for e in telemetry.events("span")]
+    # the banded rule's decision lies inside the span that would build planes
+    assert [n for n in names if n.startswith("layout.")] == [
+        "layout.detect", "layout.dia_build", "layout.ell_build"]
+    agg = telemetry.summary()["spans"]
+    assert agg["layout.detect"]["total_s"] <= agg["layout.dia_build"]["total_s"]
+    # a skewed row profile packs SELL slabs instead
+    from .test_sell_spmv import powerlaw_csr
+
+    R = sparse_tpu.csr_array(powerlaw_csr(100, seed=8).astype(np.float32))
+    R @ np.ones(100, np.float32)
+    R @ np.ones(100, np.float32)
+    agg = telemetry.summary()["spans"]
+    assert agg["layout.sell_build"]["n"] == 1 and agg["layout.ell_build"]["n"] == 1
+    assert agg["layout.detect"]["n"] == 2
+
+
 # -- the mesh: shard_csr's build and dist_cg's solve (PR 27) -------------------
 def test_mesh_spans_carry_their_fields(tel):
     from sparse_tpu.parallel import dist_cg, get_mesh, shard_csr
@@ -399,8 +454,11 @@ def test_off_every_site_gets_the_null_span_and_nothing_is_recorded(
     D, b = _pde()
     A = D.tocsr()
     linalg.cg(A, b, maxiter=30)
+    G, bg = _general()
+    linalg.cg(G, bg, maxiter=30)
     assert {n for n, _ in got} >= set(SESSION_SPANS) | {
-        "cg.solve", "cg.chunk", "cg.rho_fetch", "layout.dia_build"}
+        "cg.solve", "cg.chunk", "cg.rho_fetch", "layout.dia_build",
+        "cg.dispatch", "cg.iters_fetch", "layout.detect", "layout.ell_build"}
     assert all(s is _NULL for _, s in got)
     assert telemetry.events() == []
     assert telemetry.summary()["spans"] == {}
