@@ -14,6 +14,8 @@ SpMV/SpMM from scatter-shaped to gather-shaped kernels.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,12 +40,20 @@ from .utils import (
 # slabs for a skewed one; bare ``ell``/``sell`` are built whatever the
 # profile. ``dia`` needs a banded matrix (``dia.few_diagonals``); ``dia+`` is
 # the packed Pallas kernel on its planes, which declines a band too wide for
-# VMEM. So ``pallas`` accelerates a banded matrix's vector product and
-# nothing else. docs/performance.md shows the table by row profile;
+# VMEM. ``well`` is the windowed padded-row layout, whose product is a
+# Pallas kernel that gathers from x held in VMEM (kernels/well_spmv.py): the
+# matrix reordered to a band once, its padded rows tiled, each tile's columns
+# local to a short window of x. Nobody sets it: ``csr_array._maybe_well``
+# offers it from what it measures (a TPU, float32, square with a symmetric
+# pattern, a tight row profile, enough rows, x fitting VMEM, and windows
+# that stayed narrow after the reordering) and everywhere else the walk goes
+# on to the layouts below it as if it were not in the table. So ``pallas``
+# accelerates a banded matrix's vector product and nothing else.
+# docs/performance.md shows the table by row profile;
 # tests/test_matvec_choice.py pins it.
 _LAYOUTS = {
-    "auto": (("dia", "sell?", "ell?"), ("ell?", "sell?")),
-    "pallas": (("dia+", "dia", "sell", "ell?"), ("ell?", "sell?")),
+    "auto": (("dia", "well", "sell?", "ell?"), ("ell?", "sell?")),
+    "pallas": (("dia+", "dia", "well", "sell", "ell?"), ("ell?", "sell?")),
     "sell": (("sell",), ("sell",)),
     "ell": (("ell",), ("ell",)),
     "segment": ((), ()),
@@ -62,9 +72,62 @@ def _layouts(ndim: int, mode: str | None = None) -> tuple:
     return _LAYOUTS[mode][ndim - 1]
 
 
+# The rule of ``well`` (``csr_array._maybe_well``), from the chip's readings
+# (PERF.md section 6, PR 32): XLA's gather costs 8.6 ns a stored entry, the
+# kernel 11.9 ns a plane and step of a tile's window.
+# x whole in VMEM beside the double-buffered blocks: 4 bytes a padded row
+_WELL_X_BYTES = 16 << 20
+# below this the host's build and the kernel's compile (a second or two)
+# outlast what a few solves save: one ELL product here is 8.6 ns x 7 x rows
+_WELL_MIN_ROWS = 1 << 17
+# the mean steps a tile past which the kernel no longer beats the ELL gather
+# by 2x (a tile's plane is 1024 entries, 8.8 us there; a step 11.9 ns a plane)
+_WELL_MAX_CHUNKS = 360
+# every tile's step list lives in SMEM for the product: 1 MiB on the v5e, of
+# which the lists may take half
+_WELL_MAX_STEPS = 1 << 17
+
+
+def _well_platform() -> bool:
+    """The kernel's platform: Mosaic's lane gather exists on a TPU only."""
+    return jax.default_backend() == "tpu"
+
+
+def form_space(kind: str, meta, arrays):
+    """``(enter, matvec, leave)`` of one layout: the product in the space
+    the layout multiplies in, and the maps of a vector into it and of a
+    result out of it. Every layout but ``well`` multiplies in the caller's
+    own space (identities); ``well`` in its permuted, padded one, so that a
+    solver pays the two permutations once a solve and not once a product.
+    Pure functions of the layout's arrays, jit-safe."""
+    if kind != "well":
+        matvec = functools.partial(form_matvec, kind, meta, arrays)
+        return (lambda v: v), matvec, (lambda v: v)
+    from .kernels.well_spmv import LEAD, well_spmv
+
+    n, n_pad = meta
+    interpret = jax.default_backend() != "tpu"  # a test's CPU drive
+
+    def matvec(v):
+        return well_spmv(
+            arrays["ptr"], arrays["starts"], arrays["idx"], arrays["val"],
+            v.reshape(n_pad // 128, 128), interpret=interpret,
+        ).reshape(n_pad)
+
+    return (
+        lambda v: jnp.pad(v.astype(jnp.float32)[arrays["perm"]],
+                          (LEAD, n_pad - n - LEAD)),
+        matvec,
+        lambda v: v[arrays["inv_perm"]],
+    )
+
+
 def form_matvec(kind: str, meta, arrays, x):
     """``A @ x`` through one layout, as ``csr_array._spmv_form`` names it: a
     pure function of the layout's arrays, jit-safe, the matrix an argument."""
+    if kind == "well":  # both permutations around the kernel
+        enter, matvec, leave = form_space(kind, meta, arrays)
+        return leave(matvec(enter(x)))
     if kind == "dia":
         from .ops.dia_spmv import dia_spmv_xla
 
@@ -72,9 +135,10 @@ def form_matvec(kind: str, meta, arrays, x):
     if kind == "sell":
         return spmv_ops.csr_spmv_sell(*arrays, x, meta)
     if kind == "ell":
-        # XLA's HBM-gather formulation: a Pallas ELL kernel needs a
-        # windowed in-VMEM gather, which Mosaic cannot lower (single-
-        # tile take_along_axis only)
+        # XLA's HBM-gather formulation, one gather a plane. Mosaic lowers
+        # the single-tile take_along_axis only; the windowed gather made of
+        # it is the layout ``well`` (kernels/well_spmv.py), for a matrix
+        # whose reordering leaves every row tile a short window of x
         return spmv_ops.csr_spmv_ell(*arrays, x)
     return spmv_ops.csr_spmv_segment(*arrays, x, meta)
 
@@ -127,6 +191,7 @@ class csr_array(SparseArray):
         self._dtype = np.dtype(self.data.dtype)
         self._ell = None  # lazy (ell_indices, ell_data) cache
         self._dia = False  # False = unchecked, None = not banded, else planes
+        self._well = False  # False = unchecked, None = not offered, else WellLayout
         self._balanced_splits = None
 
     @classmethod
@@ -139,6 +204,7 @@ class csr_array(SparseArray):
         obj._dtype = np.dtype(obj.data.dtype)
         obj._ell = None
         obj._dia = False
+        obj._well = False
         obj._balanced_splits = None
         return obj
 
@@ -264,10 +330,66 @@ class csr_array(SparseArray):
             expect={"dtype": str(jax.dtypes.canonicalize_dtype(self.dtype))},
         )
 
-    def _offer(self, name: str):
+    # -- windowed padded rows (kernels/well_spmv.py) ------------------------
+    def _maybe_well(self, xdtype=None):
+        """The windowed padded-row layout, where this matrix offers it; the
+        rule is the comment above ``_WELL_X_BYTES``. Built once an operator
+        on the host (the answer, None too, is cached); a product of another
+        result type than float32 passes it over."""
+        if xdtype is not None and jnp.result_type(self.dtype, xdtype) != jnp.float32:
+            return None  # the kernel multiplies in float32 alone
+        if self._well is not False or in_trace():
+            return self._well or None
+        m, n = self.shape
+        self._well = None
+        if (_well_platform() and m == n and self.dtype == np.float32
+                and _WELL_MIN_ROWS <= n and 4 * n <= _WELL_X_BYTES
+                and self.nnz and self._tight()):
+            self._well = self._well_build(n)
+        return self._well
+
+    def _well_build(self, n):
+        from .csgraph import band_order
+        from .kernels import well_spmv as ws
+
+        indptr, indices, data = (
+            np.asarray(a) for a in (self.indptr, self.indices, self.data))
+        n_pad = ws.padded_size(n)
+        with telemetry.span("layout.reorder") as sp:
+            order = None
+            if ws.symmetric_pattern(indptr, indices, n):
+                # a graph of very many components or of path-like depth
+                # (one numpy round trip a level) is turned away
+                order = band_order(indptr, indices, n, budget=n // 64 + 4096)
+            if order is None:
+                sp.annotate(offered=False)
+                return None
+            new_ptr, rows, cols, data, rank = ws.permuted_csr(
+                indptr, indices, data, order)
+            ptr, starts, step, stats = ws.windows(new_ptr, rows, cols, n, n_pad)
+            offered = (stats["window_chunks_mean"] <= _WELL_MAX_CHUNKS
+                       and stats["steps"] <= _WELL_MAX_STEPS)
+            sp.annotate(offered=offered, **stats)
+        if not offered:
+            return None
+        with telemetry.span("layout.ell_build"):
+            idx, val = ws.padded_rows(new_ptr, rows, cols, data, step, n_pad)
+        arrays = {
+            "ptr": ptr.astype(np.int32), "starts": starts.astype(np.int32),
+            "idx": idx, "val": val, "perm": order.astype(np.int32),
+            "inv_perm": (rank + ws.LEAD).astype(np.int32),
+        }
+        arrays = dict(zip(arrays, commit_to_exec_device(
+            tuple(jnp.asarray(a) for a in arrays.values()))))
+        return ws.WellLayout(arrays, (n, n_pad), stats)
+
+    def _offer(self, name: str, xdtype=None):
         """Build (first use) or fetch the layout one entry of ``_LAYOUTS``
-        names; None where this matrix does not offer it."""
+        names; None where this matrix does not offer it (``xdtype``: the
+        operand's type, for a layout that multiplies in one type only)."""
         kind, gated = name.rstrip("?+"), name.endswith("?")
+        if kind == "well":
+            return self._maybe_well(xdtype)
         if kind == "sell":
             return self._maybe_sell(gated)
         if kind == "ell":
@@ -299,16 +421,18 @@ class csr_array(SparseArray):
         """
         if in_trace():
             return self  # layout detection needs host syncs; no-op in-trace
-        for name in dict.fromkeys(_layouts(1, mode) + _layouts(2, mode)):
-            if name.startswith("sell") and not settings.plan_cache:
-                # with the plan cache DISABLED the pack has nowhere to
-                # live — plan_cache.get builds and discards — so an eager
-                # warm would charge every one-shot solve the full SELL
-                # pack cost for nothing (tests/test_plan_cache.py pins
-                # this). Execute-time _maybe_sell still packs when a
-                # matvec actually needs it.
-                continue
-            self._offer(name)
+        for ndim in (1, 2):
+            for name in _layouts(ndim, mode):
+                if name.startswith("sell") and not settings.plan_cache:
+                    # with the plan cache DISABLED the pack has nowhere to
+                    # live — plan_cache.get builds and discards — so an
+                    # eager warm would charge every one-shot solve the full
+                    # SELL pack cost for nothing (tests/test_plan_cache.py
+                    # pins this). Execute-time _maybe_sell still packs when
+                    # a matvec actually needs it.
+                    continue
+                if self._offer(name) is not None:
+                    break  # the one a product takes: the walk stops there
         return self
 
     # -- products ----------------------------------------------------------
@@ -453,19 +577,23 @@ class csr_array(SparseArray):
         offs = offs[offs != np.iinfo(np.int32).max]
         return few_diagonals(len(offs), n, nnz)
 
-    def _spmv_form(self):
+    def _spmv_form(self, xdtype=None):
         """``(kind, arrays, meta)`` of the layout a vector product takes now:
         the ``_LAYOUTS`` walk of the ambient mode, each layout built on its
         first eager use. ``arrays`` are the layout's jax arrays and ``meta``
         its hashable rest, as :func:`form_matvec` takes them, so that a
         compiled solver can have the matrix as an argument and nothing of
-        it as a constant of its program. ``"dia+"`` is the packed Pallas
+        it as a constant of its program. ``xdtype`` is the operand's type
+        (a layout that multiplies in one type only is passed over for
+        another). ``"dia+"`` is the packed Pallas
         kernel over the planes of ``"dia"``, which keeps an operator of its
         own (``_spmv``)."""
         for name in _layouts(1):
-            lay = self._offer(name)
+            lay = self._offer(name, xdtype)
             if lay is None:
                 continue
+            if name == "well":
+                return name, lay.arrays, lay.meta
             if name.startswith("dia"):
                 return name, lay[0], (lay[1], self.shape)
             if name.startswith("sell"):
@@ -474,7 +602,7 @@ class csr_array(SparseArray):
         return "segment", (self.indptr, self.indices, self.data), self.shape[0]
 
     def _spmv(self, x):
-        kind, arrays, meta = self._spmv_form()
+        kind, arrays, meta = self._spmv_form(x.dtype)
         if kind == "dia+":
             from .kernels.dia_spmv import cached_prepared_spmv
 
@@ -484,8 +612,8 @@ class csr_array(SparseArray):
             if y is not None:
                 return y
             kind = "dia"  # band too wide for VMEM: the XLA form
-        if kind == "sell":  # once a product here, as `PreparedCSR.__call__`
-            telemetry.count("kernel.sell_spmv")
+        if kind in ("sell", "well"):  # once a product here, as `PreparedCSR.__call__`
+            telemetry.count(f"kernel.{kind}_spmv")
         return form_matvec(kind, meta, arrays, x)
 
     def _spmm(self, B):

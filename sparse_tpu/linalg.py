@@ -763,22 +763,27 @@ def _cg_general(arrays, b, start, tol, maxiter, *, kind, meta,
                 conv_test_iters, tapped):
     """Whole-solve CG on one layout of a matrix (``csr.form_matvec``), the
     layout's arrays, ``b``, the start, ``tol`` and ``maxiter`` all
-    arguments: nothing of the matrix is a constant of the program, and
+    arguments (``csr.form_space``): nothing of the matrix is a constant of the program, and
     jit's own key (layout kind and geometry, shapes, dtypes, test cadence)
     is what a second solve, another operator of the same pattern or new
     values over it, must match to run the program that is there. ``start``
     is None (x = 0, r = b) or ``(x0, b - A x0)``. Same recurrence and
     stopping rule as the closure loop (:func:`_cg_while`)."""
-    from .csr import form_matvec
+    from .csr import form_space
 
     _CG_GENERAL_TRACES.inc()
-    matvec = functools.partial(form_matvec, kind, meta, arrays)
-    x, r = (jnp.zeros_like(b), b) if start is None else start
+    # the recurrence runs in the layout's own space (the caller's, but for
+    # the windowed layout's permuted one): b, the start and the answer
+    # cross over once a solve, outside the loop
+    enter, matvec, leave = form_space(kind, meta, arrays)
+    b = enter(b)
+    x, r = (jnp.zeros_like(b), b) if start is None else map(enter, start)
     tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
     tap = functools.partial(_iter_tap, "cg", "device") if tapped else None
-    return _cg_while(
+    x, iters = _cg_while(
         matvec, lambda v: v, b, x, r, tol2, maxiter, conv_test_iters, tap
     )
+    return leave(x), iters
 
 
 _cg_general.__name__ = _cg_general.__qualname__ = "cg_general"
@@ -807,7 +812,7 @@ def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
             or not jnp.issubdtype(jnp.result_type(csr.dtype, b.dtype),
                                   jnp.inexact)):
         return None
-    kind, arrays, meta = csr._spmv_form()
+    kind, arrays, meta = csr._spmv_form(b.dtype)
     if kind == "dia+":
         return None
     tapped = _iter_tapping()

@@ -378,3 +378,63 @@ def test_cg_general_compiles_with_the_matrix_as_an_argument(one_chip):
     for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
         assert int(np.prod([int(d) for d in dims.split(",") if d] or [1],
                            dtype=np.int64)) <= 1, dims
+
+
+# ---------------------------------------------------------------------------
+# the windowed padded-row layout (kernels/well_spmv.py): the kernel alone and
+# the general CG through it, at the benchmark cell's size and at the rule's
+# far corner (x filling its VMEM budget, the step lists their SMEM budget)
+# ---------------------------------------------------------------------------
+def _well_arrays(n, width, steps, sharding):
+    from sparse_tpu.kernels import well_spmv as ws
+
+    n_pad = ws.padded_size(n)
+    planes = (width, n_pad // ws.LANES, ws.LANES)
+    return n_pad, {
+        "ptr": _sds((n_pad // ws.TILE + 1,), jnp.int32, sharding),
+        "starts": _sds((steps,), jnp.int32, sharding),
+        "idx": _sds(planes, jnp.int32, sharding),
+        "val": _sds(planes, jnp.float32, sharding),
+        "perm": _sds((n,), jnp.int32, sharding),
+        "inv_perm": _sds((n,), jnp.int32, sharding),
+    }
+
+
+@pytest.mark.parametrize("n,width,steps", [
+    (1108 * 1108, 9, 18182),  # spd_general_1chip, seed 3100000511
+    (4 << 20, 16, 1 << 17),   # csr._WELL_X_BYTES of x, _WELL_MAX_STEPS
+], ids=["cell", "rule-corner"])
+def test_well_spmv_kernel_compiles(one_chip, n, width, steps):
+    from sparse_tpu import csr
+    from sparse_tpu.kernels import well_spmv as ws
+
+    assert 4 * n <= csr._WELL_X_BYTES and steps <= csr._WELL_MAX_STEPS
+    n_pad, a = _well_arrays(n, width, steps, one_chip)
+    x2 = _sds((n_pad // ws.LANES, ws.LANES), jnp.float32, one_chip)
+    c = ws.well_spmv.lower(a["ptr"], a["starts"], a["idx"], a["val"], x2).compile()
+    assert "well_spmv" in c.as_text() and "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+def test_cg_general_compiles_through_the_windowed_layout(one_chip, monkeypatch):
+    import re
+
+    from sparse_tpu import linalg
+
+    # `csr.form_space` interprets the kernel off a TPU; this process's
+    # backend is the CPU and the program is compiled for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 1108 * 1108
+    n_pad, arrays = _well_arrays(n, 9, 18182, one_chip)
+    lowered = linalg._cg_general_program.lower(
+        arrays, _sds((n,), jnp.float32, one_chip), None, 1e-8, 50,
+        kind="well", meta=(n, n_pad), conv_test_iters=25, tapped=False)
+    c = lowered.compile()
+    text = c.as_text()
+    assert "jit_cg_general" in text and _device_bytes(c) < HBM_BYTES
+    # the kernel is the loop's product; XLA's gathers are the two
+    # permutations, once a solve, outside the loop
+    body = re.search(r"\n%[\w.]*region_0[\w.]* \(.*?\n}\n", text, re.S)
+    assert body and "well_spmv" in body.group(0)
+    assert " gather(" not in body.group(0) and "kCustom" not in body.group(0)
+    assert len(re.findall(r"= f32\[\d+\][^\n]* fusion\([^\n]*kind=kCustom", text)) == 2

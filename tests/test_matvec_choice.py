@@ -3,8 +3,12 @@
 The table below is ``csr._LAYOUTS`` (docs/performance.md) read from the
 outside: each case multiplies a fresh matrix and names the layout from what
 the product left behind on it (``_dia``, the plan cache's ``_dia_prepared``
-and ``sell`` entries, ``_ell``), so the file does not depend on how the
-choice is written.
+and ``sell`` entries, ``_ell``, ``_well``), so the file does not depend on
+how the choice is written.
+
+``well`` (the windowed padded rows, kernels/well_spmv.py) is a TPU's: on this
+backend nothing offers it, and with its platform gate opened by a test it is
+offered by the matrix alone (``MESH``), never by a mode that names a layout.
 """
 
 import numpy as np
@@ -12,7 +16,7 @@ import pytest
 import scipy.sparse as sp
 
 import sparse_tpu
-from sparse_tpu import plan_cache
+from sparse_tpu import csr, plan_cache
 from sparse_tpu.config import settings
 
 from .test_sell_spmv import powerlaw_csr
@@ -82,6 +86,8 @@ def left_behind(A):
         built.append("ell")
     if plan_cache.lookup(A, "sell") is not None:
         built.append("sell")
+    if A._well:
+        built.append("well")
     assert len(built) <= 1, built
     return built[0] if built else "segment"
 
@@ -96,12 +102,48 @@ def test_profiles_are_what_they_say():
         assert banded == (name == "banded"), name
 
 
-@pytest.mark.parametrize("profile", list(PROFILES))
+def _open_the_well_gate(monkeypatch):
+    """As on a TPU, at these sizes: the kernel then runs interpreted."""
+    monkeypatch.setattr(csr, "_well_platform", lambda: True)
+    monkeypatch.setattr(csr, "_WELL_MIN_ROWS", 1)
+
+
+def _mesh():
+    """A triangulated grid under a random permutation (the benchmark's
+    unstructured SPD class): square, symmetric pattern, tight rows, and a
+    band once reordered."""
+    from .utils.spd import as_scipy, spd_data
+
+    return as_scipy(spd_data(40, 9))
+
+
+# mode -> (layout of A @ x, layout of A @ X) for ``_mesh`` with the gate open
+MESH = {
+    "auto": ("well", "ell"),
+    "pallas": ("well", "ell"),
+    "sell": ("sell", "sell"),
+    "ell": ("ell", "ell"),
+    "segment": ("segment", "segment"),
+}
+
+
+@pytest.mark.parametrize("gate", ["this-backend", "well-gate-open"])
+@pytest.mark.parametrize("profile", list(PROFILES) + ["mesh"])
 @pytest.mark.parametrize("mode", list(TABLE))
-def test_mode_and_profile_choose_the_layout(mode, profile, monkeypatch):
+def test_mode_and_profile_choose_the_layout(mode, profile, gate, monkeypatch):
     monkeypatch.setattr(settings, "spmv_mode", mode)
-    s = PROFILES[profile]()
-    want_vec, want_mat = TABLE[mode][profile]
+    if gate == "well-gate-open":
+        _open_the_well_gate(monkeypatch)
+    if profile == "mesh":
+        s = _mesh()
+        # with the gate shut the mesh is one more tight matrix
+        want_vec, want_mat = (MESH[mode] if gate == "well-gate-open"
+                              else TABLE[mode]["tight"])
+    else:
+        # what the rule turns away (banded: dia comes first; not symmetric;
+        # a hub row; no entries) multiplies as if the layout were not there
+        s = PROFILES[profile]()
+        want_vec, want_mat = TABLE[mode][profile]
     rng = np.random.default_rng(17)
     x = rng.standard_normal(s.shape[1]).astype(np.float32)
     X = rng.standard_normal((s.shape[1], 3)).astype(np.float32)
@@ -125,6 +167,19 @@ def test_pallas_spmm_of_a_skewed_matrix_builds_no_full_width_ell(monkeypatch):
     np.testing.assert_allclose(np.asarray(A @ X), s @ X, rtol=2e-4, atol=2e-4)
     assert A._ell is None
     assert plan_cache.lookup(A, "sell") is not None
+
+
+def test_prepare_warms_what_a_product_takes(monkeypatch):
+    """``prepare`` stops each row's walk where a product would: the mesh
+    gets the windowed layout for its vector product and the padded rows for
+    its 2-D one; with the gate shut the padded rows serve both."""
+    _open_the_well_gate(monkeypatch)
+    A = sparse_tpu.csr_array(_mesh()).prepare()
+    assert A._well and A._ell is not None
+    assert plan_cache.lookup(A, "sell") is None
+    monkeypatch.setattr(csr, "_well_platform", lambda: False)
+    B = sparse_tpu.csr_array(_mesh()).prepare()
+    assert B._well is None and B._ell is not None
 
 
 def test_prepare_mode_writes_no_global(monkeypatch):
