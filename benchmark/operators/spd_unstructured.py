@@ -3,16 +3,32 @@ and its plain reference.
 
 The class (Schmid/thermal2: 1,228,045 rows, 8,580,313 entries, 6.99 a row,
 longest row 11; an unstructured finite-element steady-state thermal problem)
-is generated, because there is no network to fetch the file: the vertices of
-an s x s grid, each cell triangulated by one diagonal whose direction is drawn
-from the seed, so that an inner vertex has 4 to 8 neighbours; edge weights
-w ~ U(0.5, 1.5); A = the weighted graph Laplacian plus, on the outer ring's
-rows, the weights of the edges to an eliminated Dirichlet ring (one ghost
-edge for each axis neighbour a ring vertex lacks); rows and columns under one
-seeded random permutation, so that nothing of the grid's band is left; the
-right-hand side b ~ U(0.5, 1.5). n = s^2 rows, 7 s^2 - 8 s + 2 entries, rows of
-3 to 9 entries. The system is handed over as the three CSR arrays a reader of
-a Matrix Market file would hold (sorted rows, sorted columns in a row).
+is generated, because there is no network to fetch the file. The source is
+ONE matrix of the collection, so the **pattern** is one too: it comes from the
+``pattern_seed`` the configuration's file states, and ``--seed`` draws the
+**values** and the right-hand side alone (data take the place of weights).
+
+The pattern: the vertices of an s x s grid, each cell triangulated by one
+diagonal whose direction is drawn from ``pattern_seed``, so that an inner
+vertex has 4 to 8 neighbours; rows and columns under one random permutation
+drawn from ``pattern_seed``, so that nothing of the grid's band is left. The
+values, from the seed: edge weights w ~ U(0.5, 1.5); A = the weighted graph
+Laplacian plus, on the outer ring's rows, the weights of the edges to an
+eliminated Dirichlet ring (one ghost edge for each axis neighbour a ring
+vertex lacks); the right-hand side b ~ U(0.5, 1.5). n = s^2 rows,
+7 s^2 - 8 s + 2 entries, rows of 3 to 9 entries. The system is handed over as
+the three CSR arrays a reader of a Matrix Market file would hold (sorted
+rows, sorted columns in a row).
+
+Both generators walk one stream in one order (diagonals, weights, the four
+ring draws, permutation, b); the pattern's keeps the diagonals and the
+permutation, the values' keeps the weights, the ring and b, and what each
+does not keep it draws and drops. So the pattern of ``pattern_seed`` = P is,
+entry for entry, the matrix this generator gave for ``--seed`` P when it
+drew everything from the seed (PR 31 to PR 33), and with ``pattern_seed``
+equal to the seed the values and b are that matrix's too, bit for bit:
+every reading PERF.md has of a named seed is a reading of a pattern the
+configuration can still state.
 
 Nothing here imports the program. The reference is a textbook CG whose
 product is the plain form over the COO triplets (``jax.ops.segment_sum`` of
@@ -24,24 +40,48 @@ from __future__ import annotations
 import numpy as np
 
 
+def _draws(seed: int, s: int) -> dict:
+    """Every draw of the generator's one stream for side ``s``, in its one
+    order. A caller keeps the draws that are its own and drops the others."""
+    rng = np.random.default_rng(seed)
+    n, edges = s * s, 2 * s * (s - 1) + (s - 1) * (s - 1)
+    return {
+        "flip": rng.integers(0, 2, size=(s - 1, s - 1)).astype(bool),
+        "w": rng.uniform(0.5, 1.5, size=edges).astype(np.float32),
+        "ring": [rng.uniform(0.5, 1.5, size=s).astype(np.float32)
+                 for _ in range(4)],
+        "perm": rng.permutation(n),
+        "b": rng.uniform(0.5, 1.5, size=n).astype(np.float32),
+    }
+
+
 def make(sizes: dict, seed: int) -> dict:
-    """Host data of one run, all of it drawn from the seed."""
+    """Host data of one run: the pattern (triangulation and permutation) from
+    ``sizes["pattern_seed"]``, the values (weights, ring) and b from ``seed``.
+
+    A configuration states its pattern: every configuration file that names
+    this operator holds ``pattern_seed`` (benchmark/tests pin that). A caller
+    that gives none gets the pattern of ``seed``, which is what this
+    generator gave before it had the key; ``tests/utils/spd.py`` still calls
+    it so, and a PR that may edit it should make the missing key an error
+    (PERF.md section 7)."""
     s = int(sizes["side"])
     n = s * s
-    rng = np.random.default_rng(seed)
+    pattern = _draws(int(sizes.get("pattern_seed", seed)), s)
+    values = _draws(int(seed), s)
+    flip, perm = pattern["flip"], pattern["perm"]
+    w, b = values["w"], values["b"]
     idx = np.arange(n, dtype=np.int64).reshape(s, s)
     # a cell's diagonal: (i, j)-(i+1, j+1), or, flipped, (i, j+1)-(i+1, j)
-    flip = rng.integers(0, 2, size=(s - 1, s - 1)).astype(bool)
     u = np.concatenate([idx[:, :-1].ravel(), idx[:-1, :].ravel(),
                         np.where(flip, idx[:-1, 1:], idx[:-1, :-1]).ravel()])
     v = np.concatenate([idx[:, 1:].ravel(), idx[1:, :].ravel(),
                         np.where(flip, idx[1:, :-1], idx[1:, 1:]).ravel()])
-    w = rng.uniform(0.5, 1.5, size=u.shape[0]).astype(np.float32)
     ghost = np.zeros((s, s))
-    for ring in (ghost[0], ghost[-1], ghost[:, 0], ghost[:, -1]):
-        ring += rng.uniform(0.5, 1.5, size=s).astype(np.float32)
+    for ring, draw in zip((ghost[0], ghost[-1], ghost[:, 0], ghost[:, -1]),
+                          values["ring"]):
+        ring += draw
     diag = (np.bincount(u, w, n) + np.bincount(v, w, n) + ghost.ravel())
-    perm = rng.permutation(n)
     here = np.arange(n, dtype=np.int64)
     rows = perm[np.concatenate([u, v, here])]
     cols = perm[np.concatenate([v, u, here])]
@@ -49,7 +89,6 @@ def make(sizes: dict, seed: int) -> dict:
     order = np.argsort(rows * n + cols)  # no entry is stored twice
     indptr = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(np.bincount(rows, minlength=n), out=indptr[1:])
-    b = rng.uniform(0.5, 1.5, size=n).astype(np.float32)
     return {
         "side": s,
         "rows": n,

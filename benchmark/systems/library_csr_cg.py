@@ -34,9 +34,10 @@ class System:
         self.reseed(data)
 
     def reseed(self, data) -> None:
-        """Another seed is another matrix (weights, triangulation and
-        permutation are all drawn from it): the operator is built anew, and
-        its layout with its first solve."""
+        """Another seed is other values and another b on the configuration's
+        one pattern (the triangulation and the permutation come from its
+        ``pattern_seed``): the operator is built anew all the same, from the
+        three host arrays, and its layout with its first solve."""
         import jax.numpy as jnp
 
         self.maxiter = data["iterations"]
@@ -70,10 +71,16 @@ class System:
         ctx.events_default()
         ctx.guarantee("solver_path_not_device", 0.0 if paths == ["device"] else 1.0)
         self.traces0 = self.traces.value
+        # the set-up's spans with their fields (``layout.reorder``'s step
+        # count): a window's events push them out of the recorder's ring
+        self.setup_spans = self.telemetry.events("span")
 
     def check_events(self, events: dict) -> None:
         """A traced run records the window's own ``cg.solve`` spans: each of
-        them has to name the compiled path too."""
+        them has to name the compiled path too. The set-up's spans join the
+        window's events under a kind of their own, ``setup.span``, for the
+        metrics that read a set-up span's field."""
+        events["setup.span"] = self.setup_spans
         solves = [e for e in events.get("span", []) if e.get("name") == "cg.solve"]
         off = [e for e in solves if e.get("path") != "device"]
         self.ctx.guarantee("window_solver_path_not_device",
