@@ -114,17 +114,21 @@ class SparsityPattern:
     def _build_sell(self):
         from ..kernels.sell_spmv import sell_pack
 
-        with host_scope():  # one-time pack: on the host
-            plan, slabs, pos, srcs = sell_pack(
-                self.indptr, self.indices,
-                np.zeros(self.nnz, dtype=np.float32),  # pattern-only pack
-                self.shape, with_srcs=True,
+        with telemetry.span("session.pattern_pack", form="sell",
+                            rows=self.shape[0], nnz=self.nnz) as sp:
+            with host_scope():  # one-time pack: on the host
+                plan, slabs, pos, srcs = sell_pack(
+                    self.indptr, self.indices,
+                    np.zeros(self.nnz, dtype=np.float32),  # pattern-only pack
+                    self.shape, with_srcs=True,
+                )
+            idx_slabs = tuple(
+                commit_to_exec_device((it,))[0] for it, _vt in slabs
             )
-        idx_slabs = tuple(
-            commit_to_exec_device((it,))[0] for it, _vt in slabs
-        )
-        srcs = tuple(commit_to_exec_device(srcs)) if srcs else ()
-        (pos,) = commit_to_exec_device((pos,))
+            srcs = tuple(commit_to_exec_device(srcs)) if srcs else ()
+            (pos,) = commit_to_exec_device((pos,))
+            # slots / nnz is the padding every lane's gathers pay
+            sp.annotate(slabs=len(plan.slab_meta), slots=plan.stored_slots)
         telemetry.count("batch.pattern_pack")
         return _SellPatternPack(plan, idx_slabs, pos, srcs)
 
@@ -173,7 +177,10 @@ class SparsityPattern:
             return False
         # a pattern that stores an entry twice keeps the SELL program,
         # which sums what it gathers
-        return self._plane_map(*banded, rows) or False
+        with telemetry.span("session.pattern_pack", form="planes",
+                            rows=self.shape[0], nnz=self.nnz,
+                            diagonals=len(banded[0])):
+            return self._plane_map(*banded, rows) or False
 
     def _build_dia(self, max_diags):
         limit = int(max_diags or settings.dia_max_diags)

@@ -1,0 +1,177 @@
+"""A pattern that is no stencil, served (PR 35): FEM heat steps on the
+unstructured mesh of the benchmark's own generator
+(``benchmark/operators/fem_heat_step.py``, through ``tests/utils/spd.py``)
+through ``SolveSession("cg")``, as the cell ``fem_heat_served_closed`` sends
+them: each client's own values on one pattern, tolerance relative to the
+right-hand side, the last answer as the starting iterate.
+
+Nothing sets the bucket program's product. The session takes it from the
+pattern (``batch/operator.py`` ``pattern_matvec``): the SELL slabs' gathers
+for this mesh, planes for the 5-point grid beside it. The one-time pattern
+pack is the span ``session.pattern_pack``, once a pattern.
+"""
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+import scipy.sparse.linalg as spla
+
+from sparse_tpu import plan_cache, telemetry
+from sparse_tpu.batch import SolveSession
+from sparse_tpu.config import settings
+
+from .utils.spd import fem_heat_data, operator_module
+
+SIDE = 24  # 576 rows, 3,842 entries, rows of 3 to 9
+
+
+@pytest.fixture
+def tel(tmp_path, monkeypatch):
+    telemetry.reset()
+    monkeypatch.setattr(settings, "telemetry", True)
+    telemetry.configure(str(tmp_path / "records.jsonl"))
+    yield
+    telemetry.configure(None)
+    telemetry.reset()
+
+
+def _pack_spans():
+    return [e for e in telemetry.events("span")
+            if e["name"] == "session.pattern_pack"]
+
+
+def _step(ses, pattern, d, state, batch_max):
+    """Every client's next step: (tickets, right-hand sides). Whole buckets
+    are flushed without waiting, as the benchmark's adaptor does it."""
+    tickets, rhs = [], []
+    for k in range(d["clients"]):
+        b = np.float32(d["carry"]) * state[k] + d["source"][k]
+        tol = d["rel_tol"] * float(np.linalg.norm(b.astype(np.float64)))
+        tickets.append(ses.submit(d["values"][k], b, tol=tol, x0=state[k],
+                                  pattern=pattern))
+        rhs.append(b)
+        if ses.pending >= batch_max:
+            ses.flush(wait=False)
+    ses.flush()
+    return tickets, rhs
+
+
+@pytest.mark.parametrize("batch_max", [4, 8])
+@pytest.mark.parametrize("seed", [5, 2147483659])
+def test_fem_heat_steps_through_the_session(tel, seed, batch_max):
+    d = fem_heat_data(SIDE, seed, clients=8)
+    op = operator_module("fem_heat_step")
+    P, n = d["pattern"], d["rows"]
+    assert len(np.unique(P.indices - np.repeat(np.arange(n), np.diff(P.indptr)))) > n // 2
+    ses = SolveSession("cg", batch_max=batch_max, warm_start=False)
+    pattern = ses.pattern_of(P)
+    state = list(d["initial"])
+    # the guarantee for x: kappa (Gershgorin, from the generated matrices)
+    # times the residual's 2 x asked
+    assert d["kappa_bound"] <= 19.0
+    x_limit = d["kappa_bound"] * 2.0 * d["rel_tol"]
+    for step in (1, 2):  # the second step starts from the first one's answer
+        tickets, rhs = _step(ses, pattern, d, state, batch_max)
+        answers = [np.asarray(t.result()[0]) for t in tickets]
+        x_ref = op.reference_cg(d, d["coef"], np.stack(rhs))
+        for k, (x, b) in enumerate(zip(answers, rhs)):
+            assert x.dtype == np.float32
+            A = sp.csr_matrix((d["values"][k].astype(np.float64), P.indices,
+                               P.indptr), shape=P.shape)
+            exact = spla.spsolve(A.tocsc(), b.astype(np.float64))
+            scale = np.linalg.norm(exact)
+            # the cell's two limits: the true residual within 2 x asked,
+            # hence x within kappa x that of the solution
+            assert op.true_relres(d, x, d["values"][k], b) <= 2.0 * d["rel_tol"]
+            assert np.linalg.norm(x - exact) <= x_limit * scale
+            # the plain reference runs to the float32 floor, a few 1e-7 of
+            # the solution at this condition; the program's answer lies
+            # within the same limit of it as of the solution
+            assert np.linalg.norm(x_ref[k] - exact) <= 5e-6 * scale
+            assert np.linalg.norm(x - x_ref[k]) <= x_limit * np.linalg.norm(x_ref[k])
+        state = answers
+        # one pack a pattern: the first dispatch's, none after it
+        assert len(_pack_spans()) == 1
+    dispatches = telemetry.events("batch.dispatch")
+    assert len(dispatches) == 2 * d["clients"] // batch_max
+    assert {e["matvec"] for e in dispatches} == {"sell"}
+    assert {e["bucket"] for e in dispatches} == {batch_max}
+    # every lane stops at the first convergence test, in both steps
+    assert {e["iters_max"] for e in dispatches} == {25}
+    assert plan_cache.lookup(pattern, "sell.pattern").form == "sell"
+    assert not plan_cache.lookup(pattern, "planes.pattern")
+
+
+def test_pattern_pack_span_carries_the_slabs_and_their_padding(tel):
+    d = fem_heat_data(SIDE, 11, clients=4)
+    ses = SolveSession("cg", batch_max=4, warm_start=False)
+    pattern = ses.pattern_of(d["pattern"])
+    state = list(d["initial"])
+    for _ in range(2):
+        tickets, _ = _step(ses, pattern, d, state, 4)
+        state = [np.asarray(t.result()[0]) for t in tickets]
+    (ev,) = _pack_spans()
+    plan = plan_cache.lookup(pattern, "sell.pattern").plan
+    assert ev["form"] == "sell" and ev["dur_s"] > 0
+    assert (ev["rows"], ev["nnz"]) == (d["rows"], d["nnz"])
+    assert ev["slabs"] == len(plan.slab_meta) >= 2
+    assert ev["slots"] == sum(K * R for K, R, _ in plan.slab_meta)
+    assert d["nnz"] <= ev["slots"] <= 2 * d["nnz"]  # what the gathers pay
+    assert telemetry.summary()["spans"]["session.pattern_pack"]["n"] == 1
+    # another session on the same pattern object packs nothing
+    again = SolveSession("cg", batch_max=4, warm_start=False)
+    tickets, _ = _step(again, again.pattern_of(pattern), d, state, 4)
+    assert all(t.result()[0].shape == (d["rows"],) for t in tickets)
+    assert len(_pack_spans()) == 1
+
+
+def test_five_point_grid_beside_it_gets_planes(tel):
+    g = 12
+    T = sp.diags([-1.0, -1.0], [-1, 1], shape=(g, g))
+    L = (sp.kron(sp.identity(g), T) + sp.kron(T, sp.identity(g))
+         + 5.5 * sp.identity(g * g)).tocsr().astype(np.float32)
+    L.sort_indices()
+    rng = np.random.default_rng(3)
+    ses = SolveSession("cg", batch_max=4, warm_start=False)
+    pattern = ses.pattern_of(L)
+    rhs = rng.standard_normal((4, g * g)).astype(np.float32)
+    tickets = [ses.submit(L.data, b, tol=1e-5 * float(np.linalg.norm(b)),
+                          pattern=pattern) for b in rhs]
+    ses.flush()
+    for t, b in zip(tickets, rhs):
+        x = np.asarray(t.result()[0])
+        assert np.linalg.norm(L @ x - b) <= 2e-5 * np.linalg.norm(b)
+    assert {e["matvec"] for e in telemetry.events("batch.dispatch")} == {"planes"}
+    (ev,) = _pack_spans()
+    assert ev["form"] == "planes" and ev["diagonals"] == 5
+    assert (ev["rows"], ev["nnz"]) == (g * g, L.nnz)
+    assert plan_cache.lookup(pattern, "sell.pattern") is None
+
+
+def test_off_the_pack_is_no_span(monkeypatch):
+    telemetry.reset()
+    monkeypatch.setattr(settings, "telemetry", False)
+    d = fem_heat_data(SIDE, 12, clients=2)
+    ses = SolveSession("cg", batch_max=2, warm_start=False)
+    pattern = ses.pattern_of(d["pattern"])
+    tickets, _ = _step(ses, pattern, d, list(d["initial"]), 2)
+    assert all(t.done or t.result() is not None for t in tickets)
+    assert plan_cache.lookup(pattern, "sell.pattern").form == "sell"
+    assert "session.pattern_pack" not in telemetry.summary().get("spans", {})
+
+
+@pytest.mark.parametrize("seed", [7, 3200000103])
+def test_spd_data_states_its_pattern_and_gives_the_matrix_it_gave(seed):
+    """``tests/utils/spd.py`` passes ``pattern_seed=seed``: the generator then
+    gives what it gave when it drew everything from the seed."""
+    from .utils.spd import spd_data
+
+    gen = operator_module()
+    mine = spd_data(24, seed)
+    bare = gen.make({"side": 24, "iterations": 50}, seed)  # the seed's pattern
+    for key in ("indptr", "indices", "data", "b"):
+        assert np.array_equal(mine[key], bare[key])
+    other = gen.make({"side": 24, "iterations": 50, "pattern_seed": seed},
+                     seed + 1)
+    assert np.array_equal(mine["indices"], other["indices"])
+    assert not np.array_equal(mine["data"], other["data"])
