@@ -226,13 +226,28 @@ class SparsityPattern:
 class _SellPatternPack:
     """Device-resident pattern half of the batched SELL layout."""
 
-    __slots__ = ("plan", "idx_slabs", "pos", "srcs")
+    __slots__ = ("plan", "idx_slabs", "pos", "srcs", "_order")
     form = "sell"  # what `batch.dispatch` reports as `matvec`
+    # whole-vector row gathers a product in the caller's row order makes:
+    # the closing `pos` gather of `ops.spmv.csr_spmv_sell`
+    product_row_gathers = 1
 
     def __init__(self, plan, idx_slabs, pos, srcs):
         self.plan, self.idx_slabs, self.pos, self.srcs = (
             plan, idx_slabs, pos, srcs
         )
+        self._order = None
+
+    def own_order(self):
+        """The pack's own row order as a space a Krylov loop can run in
+        (:class:`_PackOrder`), derived from what the pack holds, once a
+        pack; None for a pattern that is not square (its products map one
+        space to another)."""
+        if self.plan.m != self.plan.n:
+            return None
+        if self._order is None:
+            self._order = _PackOrder(self)
+        return self._order
 
     def pack_values(self, values):
         """Gather a ``(B, nnz)`` value stack into per-slab ``[B, K, R]``
@@ -249,6 +264,63 @@ class _SellPatternPack:
         return tuple(out)
 
 
+class _PackOrder:
+    """A SELL pack's own row order (rows sorted by length inside sigma
+    windows, grouped into slabs, each slab padded to ``ROW_ALIGN``) as the
+    space of a Krylov loop: the recurrences do not care how their rows are
+    numbered, so a loop whose vectors are held in this order needs no
+    ``pos`` gather a product. ``enter`` and ``leave`` are the only
+    whole-vector row gathers of a program built on it: one a vector, once
+    a dispatch.
+
+    Derived from the pack once (nothing of it is stored in the vault: a
+    loaded pack derives it again), on the host with numpy like the pack
+    itself: op by op on an accelerator the few gathers and the scatter
+    each pay a compile, five seconds of a first dispatch on a v5e.
+    ``idx_slabs`` are the pack's column indices renumbered into packed
+    positions (``pos[idx]``) and ``rows`` maps a packed position to the
+    caller's row, -1 on the slabs' pad rows, which hold zero in every
+    vector of the loop (their value slots are zero, so a product leaves
+    them zero)."""
+
+    __slots__ = ("idx_slabs", "rows", "pos", "zero_rows")
+
+    def __init__(self, pack):
+        plan, pos = pack.plan, np.asarray(pack.pos)
+        packed_rows = plan.zero_rows + sum(r for _k, r, _p in plan.slab_meta)
+        rows = np.full((packed_rows,), -1, pos.dtype)
+        rows[pos] = np.arange(plan.m, dtype=pos.dtype)
+        with host_scope():
+            built = tuple(
+                jnp.asarray(a)
+                for a in (*(pos[np.asarray(it)] for it in pack.idx_slabs),
+                          rows)
+            )
+        *idx_slabs, self.rows = commit_to_exec_device(built)
+        self.idx_slabs = tuple(idx_slabs)
+        self.pos, self.zero_rows = pack.pos, plan.zero_rows
+
+    def enter(self, V):
+        """``(B, m)`` vectors in the caller's row order -> the pack's
+        (pad rows zero, or a residual's would enter ``r.r``); the idiom
+        of ``pack_values``."""
+        V = jnp.asarray(V)
+        return jnp.where((self.rows >= 0)[None, :],
+                         V[:, jnp.maximum(self.rows, 0)],
+                         jnp.zeros((), dtype=V.dtype))
+
+    def leave(self, V):
+        """Back to the caller's row order; the pad rows are dropped."""
+        return V[:, self.pos]
+
+    def product(self, vals, X):
+        """The batched ``A @ X`` with ``X`` and the result in this order:
+        the slabs' gathers and sums alone."""
+        return spmv_ops.csr_spmv_sell_batched(
+            self.idx_slabs, vals, None, X, self.zero_rows
+        )
+
+
 class _PlanePatternPack:
     """Device-resident pattern half of the batched plane (DIA, row)
     layout: static ``offsets`` and the ``[D, m]`` slot -> nnz-position
@@ -256,9 +328,14 @@ class _PlanePatternPack:
 
     __slots__ = ("offsets", "src")
     form = "planes"
+    product_row_gathers = 0  # shifted multiply-adds: no row is permuted
 
     def __init__(self, offsets, src):
         self.offsets, self.src = offsets, src
+
+    def own_order(self):
+        """Planes are laid out in the caller's row order."""
+        return None
 
     def pack_values(self, values):
         """Gather a ``(B, nnz)`` value stack into ``(B, D, m)`` planes
@@ -277,8 +354,12 @@ def pattern_matvec(pattern: SparsityPattern):
     loads; else the SELL slabs' gathers; the form not chosen is not built.
     ``pack.pack_values`` gathers a dispatch's ``(B, nnz)`` value stack into
     the form's layout once; ``product(vals, X)`` is the batched ``A @ X``
-    over it; ``pack.form`` names the choice, and the program built on it
-    carries that name as its ``matvec``."""
+    over it, vectors and result in the caller's row order;
+    ``pack.form`` names the choice, and the program built on it carries
+    that name as its ``matvec``. ``pack.own_order()`` offers a gather
+    pack's own row order to a builder whose loop can run in it
+    (:class:`_PackOrder`: the product there has no closing ``pos``
+    gather)."""
     from ..ops.dia_spmv import dia_planes_matvec
 
     pack = pattern.plane_pack()

@@ -24,6 +24,13 @@ is cached library-wide in ``sparse_tpu.plan_cache`` so solvers reuse it
 across a whole solve. The product is the pure-XLA slab formulation
 (``ops.spmv.csr_spmv_sell``): Mosaic has no lowering for a gather inside
 VMEM ("Cannot do int indexing on TPU"), so there is no Pallas kernel here.
+
+The slabs produce ``A @ x`` in the pack's own row order;
+``csr_spmv_sell`` ends in one more gather, through ``pos``, back into the
+caller's. A caller that keeps its vectors in the pack's order needs none
+(``ops.spmv.csr_spmv_sell_packed`` is the product without it, and
+``batch.operator._PackOrder`` the session's gather bucket program built
+on it); the pack itself, and its vault artifact, are the same for both.
 """
 
 from __future__ import annotations
@@ -39,9 +46,9 @@ def _round_up(v: int, m: int) -> int:
 
 
 # Slab rows pad to a sublane multiple; pad rows carry idx 0 / val 0
-# (contribute 0 * x[0]) and are dropped by the pos-gather, which only
-# addresses real rows. Kept small: slab-count x ROW_ALIGN x K is pure pad
-# storage.
+# (contribute 0 * x[0]: a pad row of the packed output is zero) and are
+# dropped by the pos-gather, which only addresses real rows. Kept small:
+# slab-count x ROW_ALIGN x K is pure pad storage.
 ROW_ALIGN = 8
 
 

@@ -105,19 +105,23 @@ def _sell_slab_spmv(idx_t, val_t, x, acc_dtype=None):
     return jax.lax.fori_loop(0, K, body, acc0)
 
 
-def csr_spmv_sell(slabs, pos, x, zero_rows: int, out_dtype=None,
-                  acc_dtype=None):
-    """y = A @ x on the SELL-C-sigma layout (see ``kernels.sell_spmv``).
+def csr_spmv_sell_packed(slabs, x, zero_rows: int, out_dtype=None,
+                         acc_dtype=None):
+    """A @ x on the SELL-C-sigma layout (see ``kernels.sell_spmv``), left in
+    the pack's own row order: the slabs' outputs one after another, then
+    the ``zero_rows`` all-empty rows. The one slab loop of the vector
+    products.
 
     ``slabs`` is a static tuple of plane-major ``(idx_t, val_t)`` pairs
     ([K_s, R_s] each — rows degree-sorted within sigma-windows, chunked into
     C-row chunks padded to each chunk's max degree, chunks grouped by padded
-    width); ``pos`` maps original row -> position in the concatenated packed
-    output; ``zero_rows`` is the trailing all-empty-row block. Every step is
-    a contiguous 1-D gather + VPU add — no scatter, no segment ids, and
-    near-zero pad waste even under row-length skew (vs. ELL's global-max
-    padding). The one form of the prepared general SpMV
-    (``kernels.sell_spmv.PreparedCSR`` packs for it).
+    width). Every step is a contiguous 1-D gather + VPU add — no scatter,
+    no segment ids, and near-zero pad waste even under row-length skew (vs.
+    ELL's global-max padding). ``x`` is indexed by the slabs' indices as
+    they stand: the caller's column numbering for :func:`csr_spmv_sell`,
+    packed positions for a caller that keeps its vectors in the pack's
+    order (``batch.operator._PackOrder``). A slab's alignment pad rows
+    (``ROW_ALIGN``) come out zero.
     """
     x = jnp.asarray(x)  # numpy x would fail the fori-loop gather branch
     out_dt = out_dtype or acc_dtype or jnp.result_type(
@@ -129,9 +133,24 @@ def csr_spmv_sell(slabs, pos, x, zero_rows: int, out_dtype=None,
     ]
     if zero_rows:
         parts.append(jnp.zeros((zero_rows,), dtype=out_dt))
-    if not parts:  # empty matrix: pos is empty too
-        return jnp.zeros(pos.shape, dtype=out_dt)
-    packed = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    if not parts:  # empty matrix
+        return jnp.zeros((0,), dtype=out_dt)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def csr_spmv_sell(slabs, pos, x, zero_rows: int, out_dtype=None,
+                  acc_dtype=None):
+    """y = A @ x on the SELL-C-sigma layout, in the caller's row order:
+    :func:`csr_spmv_sell_packed`, then one gather through ``pos``
+    (original row -> position in the packed output), which also drops the
+    slabs' pad rows. ``pos=None`` leaves the result in the pack's order.
+    The one form of the prepared general SpMV
+    (``kernels.sell_spmv.PreparedCSR`` packs for it).
+    """
+    packed = csr_spmv_sell_packed(slabs, x, zero_rows, out_dtype,
+                                  acc_dtype=acc_dtype)
+    if pos is None or not packed.shape[0]:  # empty matrix: pos is empty too
+        return packed
     return packed[pos]
 
 
@@ -177,7 +196,13 @@ def csr_spmv_sell_batched(idx_slabs, val_slabs, pos, X, zero_rows: int,
     packed once, ``val_slabs`` is a tuple of stacked ``[B, K, R]`` value
     planes — the vmap-compatible XLA path of the batched subsystem
     (``sparse_tpu.batch``). Every lane rides the same contiguous 1-D
-    gathers as :func:`csr_spmv_sell`; XLA batches them for free.
+    gathers as :func:`csr_spmv_sell`, its closing ``pos`` gather (one of
+    the whole ``[B, m]`` stack a product) included; XLA batches them for
+    free. ``pos=None`` leaves ``Y`` in the pack's row order: with
+    ``idx_slabs`` renumbered into packed positions and ``X`` held in that
+    order it is the whole product of a Krylov loop that runs in the
+    pack's order (``batch.operator._PackOrder``), no ``pos`` gather a
+    product.
 
     ``acc_dtype`` is the storage/accumulation split (ISSUE 15): value
     planes may be stored bf16/f32 while every plane product and the

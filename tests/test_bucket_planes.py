@@ -3,9 +3,16 @@
 A pattern the banded rule (``dia.few_diagonals``) lays out as planes gets a
 bucket program whose matvec is D shifted multiply-adds over row-layout
 planes (``ops.dia_spmv.dia_planes_matvec``); any other pattern compiles the
-SELL gather program it always did, text for text. Nothing sets the form:
-these tests force the gather form by patching ``SparsityPattern.plane_pack``
-in the test, never through an option of the program.
+SELL gather program. Nothing sets the form: these tests force the gather form
+by patching ``SparsityPattern.plane_pack`` in the test, never through an
+option of the program.
+
+Since PR 36 the gather program without a preconditioner runs its loop in the
+SELL pack's own row order (``batch.operator._PackOrder``): the ``pos`` gather
+that closes a product in the caller's order leaves the loop, and the program
+agrees with the parent's (``_parent_program``, kept here as the row-order
+oracle) to rounding. With a preconditioner it is still the parent's, text for
+text.
 """
 
 from functools import partial
@@ -282,11 +289,12 @@ def test_duplicate_entries_keep_the_sell_program(monkeypatch, solver):
         assert np.linalg.norm(x - x_ref) <= 1e-7 * np.linalg.norm(x_ref)
     ses = SolveSession(solver, batch_max=4, warm_start=False)
     pattern = ses.pattern_of(base)
-    args = _program_args(pattern, 4, np.float32)
-    run = ses._build_program(pattern, 4, np.dtype(np.float32))
+    run = ses._build_program(pattern, 4, np.dtype(np.float64))
     assert run.matvec == "sell"
-    assert run.lower(*args).as_text() == _parent_program(
-        ses, pattern, solver).lower(*args).as_text()
+    values = [base.data * c for c in (1.0, 1.5, 2.5, 4.0)]
+    _agrees_with_parent(run, _parent_program(ses, pattern, solver),
+                        _lane_args(values, 64, np.float64, seed=10))
+    _jacobi_program_is_the_parents(ses, pattern, solver)
 
 
 def test_dia_view_refuses_duplicate_entries():
@@ -301,8 +309,9 @@ def _program_args(pattern, B, dtype):
             np.zeros((B, n), dtype), np.zeros((B,), np.float64), n * 10)
 
 
-def _parent_program(ses, pattern, solver):
-    """The bucket program as the parent commit built it for every pattern."""
+def _parent_program(ses, pattern, solver, mfac=None):
+    """The bucket program as the parent commit built it for every pattern:
+    the loop in the caller's row order, every product closed by ``pos``."""
     pack = pattern.sell_pack()
     idx_slabs, pos, zero_rows = pack.idx_slabs, pack.pos, pack.plan.zero_rows
     loop = krylov._cg_loop if solver == "cg" else krylov._bicgstab_loop
@@ -319,29 +328,82 @@ def _parent_program(ses, pattern, solver):
                 )
 
         fmv = krylov._maybe_faulty_mv(mv)
-        return loop(fmv, rhs, x0, tols, maxiter, cti, Mvec=None)
+        Mvec = None if mfac is None else mfac(values, fmv)
+        return loop(fmv, rhs, x0, tols, maxiter, cti, Mvec=Mvec)
 
     return run
 
 
+def _lane_args(values, n, dtype, seed):
+    """Arguments of a bucket program with work in them: the lanes' value
+    rows, a start that is not zero, tolerances mixed by lane."""
+    values = np.asarray(values, dtype)
+    B = values.shape[0]
+    rng = np.random.default_rng(seed)
+    rhs = rng.standard_normal((B, n)).astype(dtype)
+    x0 = (0.1 * rng.standard_normal((B, n))).astype(dtype)
+    eps = 1e-4 if np.dtype(dtype) == np.float32 else 1e-10
+    tols = np.asarray([eps * (10.0 if i % 2 else 1.0) for i in range(B)])
+    return (values, rhs, x0, tols * np.linalg.norm(rhs, axis=1), n * 10)
+
+
+def _agrees_with_parent(run, parent, args):
+    """Answers, iteration counts, residual norms and the converged mask of
+    the two programs: the same recurrences and test points; a dot product
+    sums its rows in another order, which is rounding."""
+    X, iters, resid2, conv = (np.asarray(o) for o in run(*args))
+    Xp, itp, rp, cp = (np.asarray(o) for o in parent(*args))
+    eps = np.finfo(X.dtype).eps
+    assert conv.all() and cp.all()
+    np.testing.assert_array_equal(iters, itp)
+    assert iters.max() >= 5  # there was a loop to agree on
+    scale = np.linalg.norm(Xp, axis=1)
+    assert (np.linalg.norm(X - Xp, axis=1) <= 1e3 * eps * scale).all()
+    # a residual norm is the sum of the same squares in another order
+    # after the same number of steps
+    tol2 = np.asarray(args[3]) ** 2
+    assert (np.abs(resid2 - rp) <= 1e-2 * tol2 + 1e-3 * rp).all()
+
+
+def _jacobi_program_is_the_parents(ses, pattern, solver):
+    """A preconditioner is built and applied in the caller's row order, so
+    the program that has one keeps the row-order loop, text for text."""
+    args = _program_args(pattern, 4, np.float32)
+    run = ses._build_program(pattern, 4, np.dtype(np.float32), solver=solver,
+                             precond="jacobi")
+    parent = _parent_program(ses, pattern, solver,
+                             ses.precond.factory(pattern, "jacobi"))
+    assert run.matvec == "sell"
+    assert run.lower(*args).as_text() == parent.lower(*args).as_text()
+
+
 @pytest.mark.parametrize("solver", ["cg", "bicgstab"])
 @pytest.mark.parametrize("name", list(GENERAL))
-def test_general_pattern_compiles_the_parents_program(monkeypatch, name,
-                                                      solver):
-    """Not banded, or banded over ``dia_max_fill``: the program text is the
-    parent's, and the one built with the selection forced off."""
+def test_general_pattern_agrees_with_the_parents_program(monkeypatch, name,
+                                                         solver):
+    """Not banded, or banded over ``dia_max_fill``: the gather program, in
+    the pack's row order. It agrees with the parent's to rounding, is the
+    program built with the selection forced off, and with a preconditioner
+    it is the parent's text."""
     A = GENERAL[name]()
     ses = SolveSession(solver, batch_max=4, warm_start=False)
     pattern = ses.pattern_of(A)
+    run = ses._build_program(pattern, 4, np.dtype(np.float32))
+    parent = _parent_program(ses, pattern, solver)
+    for dtype, seed in ((np.float32, 21), (np.float64, 22)):
+        mats, _ = _lanes(A, 4, dtype, seed)
+        _agrees_with_parent(
+            ses._build_program(pattern, 4, np.dtype(dtype)), parent,
+            _lane_args([M.data for M in mats], A.shape[0], dtype, seed))
     args = _program_args(pattern, 4, np.float32)
-    text = ses._build_program(pattern, 4, np.dtype(np.float32)).lower(
-        *args).as_text()
-    assert text == _parent_program(ses, pattern, solver).lower(*args).as_text()
+    text = run.lower(*args).as_text()
+    assert text != parent.lower(*args).as_text()
     with monkeypatch.context() as m:
         m.setattr(SparsityPattern, "plane_pack", lambda self: None)
         forced = ses._build_program(pattern, 4, np.dtype(np.float32))
     assert text == forced.lower(*args).as_text()
     assert "gather" in text
+    _jacobi_program_is_the_parents(ses, pattern, solver)
 
 
 @pytest.mark.parametrize("name", ["grid5", "skewed"])
@@ -446,3 +508,187 @@ def test_plane_product_without_diagonals_is_zero():
                                   np.ones((2, 7), np.float32))
     assert Y.shape == (2, 5) and Y.dtype == np.float32
     assert not np.asarray(Y).any()
+
+
+# ---------------------------------------------------------------------------
+# the gather program in the SELL pack's own row order (PR 36)
+# ---------------------------------------------------------------------------
+def empty_rows(n=70):
+    """``skewed`` with every seventh row and column emptied: the pack's
+    trailing block of all-empty rows (``zero_rows`` > 0). Not solvable."""
+    A = skewed(n).tolil()
+    for i in range(0, n, 7):
+        A[i, :] = 0.0
+        A[:, i] = 0.0
+    A = sp.csr_matrix(A)
+    A.eliminate_zeros()
+    return _finish(A)
+
+
+ORDERED = {**GENERAL, "empty_rows": empty_rows,
+           # 37 rows in slabs whose rows pad to multiples of ROW_ALIGN
+           "pad_rows": lambda: skewed(37, seed=4)}
+
+
+def _order_of(A):
+    pattern = SparsityPattern.from_csr(A)
+    pack = pattern.sell_pack()
+    return pack, pack.own_order()
+
+
+@pytest.mark.parametrize("name", list(ORDERED))
+def test_packed_product_then_pos_is_the_row_order_product(name):
+    """Bit for bit: the one slab loop, with and without its closing gather,
+    and through the renumbered indices on vectors held in the pack's order."""
+    A = ORDERED[name]()
+    pack, order = _order_of(A)
+    plan = pack.plan
+    if name == "empty_rows":
+        assert plan.zero_rows > 0
+    if name == "pad_rows":
+        assert sum(p for _k, _r, p in plan.slab_meta) > 0
+    rng = np.random.default_rng(31)
+    values = rng.standard_normal((3, A.nnz)).astype(np.float32)
+    X = rng.standard_normal((3, A.shape[0])).astype(np.float32)
+    vals = pack.pack_values(values)
+    want = np.asarray(spmv_ops.csr_spmv_sell_batched(
+        pack.idx_slabs, vals, pack.pos, X, plan.zero_rows))
+    packed = spmv_ops.csr_spmv_sell_batched(
+        pack.idx_slabs, vals, None, X, plan.zero_rows)
+    assert packed.shape == (3, order.rows.shape[0])
+    np.testing.assert_array_equal(np.asarray(packed[:, pack.pos]), want)
+    mine = order.leave(order.product(vals, order.enter(X)))
+    np.testing.assert_array_equal(np.asarray(mine), want)
+    for i in range(3):  # ... and it is the product
+        Ai = sp.csr_matrix((values[i], A.indices, A.indptr), shape=A.shape)
+        np.testing.assert_allclose(want[i], Ai @ X[i], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", list(ORDERED))
+def test_pad_rows_are_zero_and_stay_zero(name):
+    """``enter`` zeroes the slabs' alignment pad rows and a product leaves
+    them zero, so R and P never carry anything there into a dot product."""
+    A = ORDERED[name]()
+    pack, order = _order_of(A)
+    rows = np.asarray(order.rows)
+    real = rows >= 0
+    assert real.sum() == A.shape[0]
+    assert (~real).sum() == sum(p for _k, _r, p in pack.plan.slab_meta)
+    np.testing.assert_array_equal(np.asarray(pack.pos)[rows[real]],
+                                  np.nonzero(real)[0])
+    rng = np.random.default_rng(32)
+    mats, rhs = _lanes(A, 2, np.float64, seed=33)
+    vals = pack.pack_values(np.stack([M.data for M in mats]))
+    x0 = rng.standard_normal(rhs.shape)
+    B, X = np.asarray(order.enter(rhs)), np.asarray(order.enter(x0))
+    assert not B[:, ~real].any() and not X[:, ~real].any()
+    np.testing.assert_array_equal(np.asarray(order.leave(B)), rhs)
+    # three steps of the recurrence, as `krylov._cg_loop` writes them
+    R = B - np.asarray(order.product(vals, X))
+    P, rho = np.zeros_like(R), None
+    for k in range(3):
+        rho_new = (R * R).sum(axis=1)
+        P = R if k == 0 else R + (rho_new / rho)[:, None] * P
+        Q = np.asarray(order.product(vals, P))
+        alpha = rho_new / (P * Q).sum(axis=1)
+        R, rho = R - alpha[:, None] * Q, rho_new
+        for V in (Q, R, P):
+            assert not V[:, ~real].any()
+    # whatever a pad row held, a product gives it zero
+    junk = rng.standard_normal(B.shape)
+    assert not np.asarray(order.product(vals, junk))[:, ~real].any()
+
+
+def _gathers(jaxpr, inside_while=False, out=None):
+    """Shapes (operand, result) of every gather of a jaxpr, by whether a
+    ``while`` encloses it."""
+    out = {True: [], False: []} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out[inside_while].append((eqn.invars[0].aval.shape,
+                                      eqn.outvars[0].aval.shape))
+        inner = inside_while or eqn.primitive.name == "while"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _gathers(sub, inner, out)
+    return out
+
+
+@pytest.mark.parametrize("solver", ["cg", "bicgstab"])
+@pytest.mark.parametrize("name", ["skewed", "pad_rows"])
+def test_no_row_permutation_in_the_loop(name, solver):
+    """The while body gathers through the slabs' slots and nothing else: the
+    parent's has one more gather a product, of the whole row count."""
+    A = ORDERED[name]()
+    ses = SolveSession(solver, batch_max=2, warm_start=False)
+    pattern = ses.pattern_of(A)
+    plan = pattern.sell_pack().plan
+    slots = sum(k for k, _r, _p in plan.slab_meta)
+    products = 1 if solver == "cg" else 2
+    args = _program_args(pattern, 2, np.float32)
+    m = A.shape[0]
+
+    def body_gathers(run):
+        return _gathers(jax.make_jaxpr(run)(*args).jaxpr)
+
+    mine = body_gathers(ses._build_program(pattern, 2, np.dtype(np.float32)))
+    slab_rows = {r for _k, r, _p in plan.slab_meta}
+    assert len(mine[True]) == products * slots
+    assert {res[-1] for _op, res in mine[True]} <= slab_rows
+    parents = body_gathers(_parent_program(ses, pattern, solver))
+    assert len(parents[True]) == products * (slots + 1)
+    assert sum(res[-1] == m for _op, res in parents[True]) >= products
+    # outside the loop: the value stack's gathers and the first residual's
+    # slots in both; rhs and x0 in and X out here, one `pos` there
+    assert len(mine[False]) == len(parents[False]) + 2
+
+
+def _dispatch_events(monkeypatch, solver, base, **kw):
+    monkeypatch.setattr(settings, "telemetry", True)
+    telemetry.reset()
+    ses = SolveSession(solver, batch_max=4, warm_start=False, **kw)
+    mats, rhs = _lanes(base, 3, np.float64, seed=41)
+    ses.solve_many(mats, rhs, tol=1e-8, maxiter=300)
+    evs = telemetry.events("batch.dispatch")
+    assert evs and all(telemetry.schema.validate(e) == [] for e in evs)
+    return evs
+
+
+@pytest.mark.parametrize("solver, products", [("cg", 1), ("bicgstab", 2)])
+def test_dispatch_events_count_the_row_gathers(monkeypatch, solver, products):
+    """`row_gathers`: rhs, x0 and X in the pack's order; the first
+    residual's product and every product of the loop where a preconditioner
+    keeps the caller's order; none for planes."""
+    try:
+        (ev,) = _dispatch_events(monkeypatch, solver, skewed())
+        assert ev["matvec"] == "sell" and ev["iters_max"] >= 5
+        assert ev["row_gathers"] == 3
+        (ev,) = _dispatch_events(monkeypatch, solver, skewed(),
+                                 precond="jacobi")
+        assert ev["matvec"] == "sell"
+        # 3 real lanes in a bucket of 4: the pad lane ends at the first test
+        trips = max(ev["iters_max"], 25)
+        assert ev["row_gathers"] == 1 + products * trips
+        (ev,) = _dispatch_events(monkeypatch, solver, grid5())
+        assert ev["matvec"] == "planes" and ev["row_gathers"] == 0
+    finally:
+        telemetry.reset()
+
+
+def test_programs_of_other_builders_report_no_row_gathers(monkeypatch):
+    """GMRES and the refinement programs multiply through `pos` as they
+    did; their builders tag no count and the event leaves the field out."""
+    try:
+        for solver, kw in (("gmres", {}), ("cg", {"dtype_policy": "f32ir"})):
+            evs = _dispatch_events(monkeypatch, solver, skewed(), **kw)
+            assert all("row_gathers" not in e for e in evs)
+    finally:
+        telemetry.reset()
+
+
+def test_a_pattern_that_is_not_square_has_no_order_of_its_own():
+    A = _rect(9, 14, (-3, 0, 2, 6, 9), 51)
+    pack = SparsityPattern.from_csr(A).sell_pack()
+    assert pack.own_order() is None
+    pack, order = _order_of(skewed())
+    assert order is not None and pack.own_order() is order  # once a pack
+    assert SparsityPattern.from_csr(grid5()).plane_pack().own_order() is None

@@ -277,6 +277,50 @@ def test_bucket_program_ops_carry_their_scope(one_chip, monkeypatch):
     assert not any(f"f32[{B},{n}]{{0,1:" in ln for ln in body)
 
 
+@pytest.mark.parametrize("precond, in_body, outside", [
+    ("none", 0, 3),    # the loop in the SELL pack's order: rhs, x0 in, X out
+    # the caller's order: `pos` closes every product, the first residual's
+    # too; the other one outside is Jacobi's gather of the diagonals
+    ("jacobi", 1, 2),
+])
+def test_gather_bucket_program_permutes_rows_outside_the_loop(
+        one_chip, monkeypatch, precond, in_body, outside):
+    """The gather program as the chip's compiler leaves it (PR 36): a gather
+    fusion a slot plane of a slab in the while body, and the whole-vector
+    row permutations (`[rows, lanes]`, lane-minor) where the builder put
+    them. An unstructured FEM pattern, as `fem_heat_served_closed` serves."""
+    import re
+
+    from sparse_tpu.batch import service
+
+    from .utils.spd import fem_heat_data
+
+    monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    P = fem_heat_data(72, 3, clients=1)["pattern"]
+    n, B = P.shape[0], 8
+    ses = service.SolveSession("cg", batch_max=B, warm_start=False)
+    pattern = ses.pattern_of(P)
+    run = ses._build_program(pattern, B, np.dtype(np.float32),
+                             precond=precond)
+    assert run.matvec == "sell"
+    hlo = run.lower(
+        _sds((B, pattern.nnz), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile().as_text()
+    plan = pattern.sell_pack().plan
+    assert all(r != n for _k, r, _p in plan.slab_meta)  # no slab is whole
+    gathers = [ln for ln in hlo.splitlines()
+               if "kind=kCustom" in ln and " fusion(" in ln]
+    whole = [ln for ln in gathers if re.search(rf"= f32\[{n},{B}\]", ln)]
+    body = [ln for ln in gathers if "/while/body/" in ln]
+    assert len(body) == sum(k for k, _r, _p in plan.slab_meta) + in_body
+    assert sum("/while/body/" in ln for ln in whole) == in_body
+    assert sum("/while/body/" not in ln for ln in whole) == outside
+
+
 # ---------------------------------------------------------------------------
 # four chips: make_dist_cg's program over a Mesh of described devices
 # ---------------------------------------------------------------------------

@@ -7,8 +7,9 @@ right-hand side, the last answer as the starting iterate.
 
 Nothing sets the bucket program's product. The session takes it from the
 pattern (``batch/operator.py`` ``pattern_matvec``): the SELL slabs' gathers
-for this mesh, planes for the 5-point grid beside it. The one-time pattern
-pack is the span ``session.pattern_pack``, once a pattern.
+for this mesh (its loop in the SELL pack's own row order since PR 36: three
+whole-vector row gathers a dispatch), planes for the 5-point grid beside it.
+The one-time pattern pack is the span ``session.pattern_pack``, once a pattern.
 """
 
 import numpy as np
@@ -98,6 +99,9 @@ def test_fem_heat_steps_through_the_session(tel, seed, batch_max):
     assert {e["bucket"] for e in dispatches} == {batch_max}
     # every lane stops at the first convergence test, in both steps
     assert {e["iters_max"] for e in dispatches} == {25}
+    # the loop runs in the pack's row order (PR 36): rhs, x0 and X are the
+    # only whole-vector row gathers of a dispatch, not one a product
+    assert all(e["row_gathers"] <= 3 for e in dispatches)
     assert plan_cache.lookup(pattern, "sell.pattern").form == "sell"
     assert not plan_cache.lookup(pattern, "planes.pattern")
 
@@ -141,7 +145,8 @@ def test_five_point_grid_beside_it_gets_planes(tel):
     for t, b in zip(tickets, rhs):
         x = np.asarray(t.result()[0])
         assert np.linalg.norm(L @ x - b) <= 2e-5 * np.linalg.norm(b)
-    assert {e["matvec"] for e in telemetry.events("batch.dispatch")} == {"planes"}
+    assert {(e["matvec"], e["row_gathers"])
+            for e in telemetry.events("batch.dispatch")} == {("planes", 0)}
     (ev,) = _pack_spans()
     assert ev["form"] == "planes" and ev["diagonals"] == 5
     assert (ev["rows"], ev["nnz"]) == (g * g, L.nnz)
