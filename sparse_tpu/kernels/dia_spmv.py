@@ -419,12 +419,6 @@ def autotune_dia_tile(
     else:
         result = (min(timings, key=timings.get), timings)
     _TILE_CACHE[key] = result
-    telemetry.record(
-        "autotune.probe", tile=result[0], shape=list(shape),
-        diags=len(offsets), dtype=str(np.dtype(data.dtype)),
-        timings_us={str(t): round(s * 1e6, 1) for t, s in result[1].items()},
-        clock="host" if _CHAIN_RETIRED[0] else "compiled",
-    )
     return result
 
 

@@ -44,9 +44,6 @@ KINDS: dict[str, frozenset] = {
     # CheckpointManager.load() skipped a corrupt/truncated .npz
     "checkpoint.corrupt": frozenset({"path"}),
     # -- kernels (kernels/dia_spmv.py) -------------------------------------
-    # a completed tile-autotune race: timings_us maps probed tile -> best
-    # seconds-per-SpMV in microseconds; clock is 'compiled' | 'host'
-    "autotune.probe": frozenset({"tile", "shape", "timings_us"}),
     # an autotune decision that did NOT probe (gate/cache) — never cached
     # as if it were a probe result
     "autotune.result": frozenset({"tile", "probed"}),
@@ -72,7 +69,11 @@ KINDS: dict[str, frozenset] = {
     "comm.sort": frozenset({"bytes", "S"}),
     # -- batched solves (sparse_tpu.batch) ----------------------------------
     # one per bucket a SolveSession dispatches: real lane count, padded
-    # bucket size, pad waste, queue latency and per-lane iteration stats
+    # bucket size, pad waste, queue latency and per-lane iteration stats;
+    # on every dispatch of a session but its first also the account of
+    # the period that ended at its launch (service._Period): period_ms =
+    # caller_ms + submit_ms + spanned_ms + unspanned_ms, with inside_ms
+    # and submits (optional fields: not required, a first dispatch has none)
     "batch.dispatch": frozenset({"solver", "batch", "bucket"}),
     # one per completed batched Krylov solve (any entry point); B is the
     # lane count, iters_max the slowest lane's iteration count

@@ -184,9 +184,12 @@ def test_session_spans_lie_on_the_profile_in_order(tel, tmp_path):
     finally:
         jax.profiler.stop_trace()
     anns = _host_annotations(str(tmp_path / "trace"), "session.")
-    assert [a[0] for a in anns] == list(SESSION_SPANS) * 2
+    # a bucket's four submits lie on the same line, before its seven
+    assert [a[0] for a in anns] == (
+        ["session.submit"] * 4 + list(SESSION_SPANS)) * 2
     for (_n0, s0, d0), (_n1, s1, _d1) in zip(anns, anns[1:]):
         assert s0 + d0 <= s1  # in order and not overlapping
+    anns = [a for a in anns if a[0] != "session.submit"]  # emit=False
     # the same extents as the events hold, on the profile's own axis
     evs = [e for e in telemetry.events("span")
            if e["name"] in SESSION_SPANS][-14:]
@@ -209,8 +212,20 @@ def test_seven_session_spans_sum_to_the_dispatch(tel):
             by_seq.setdefault(e["seq"], {})[e["name"]] = e
     for seq, spans in by_seq.items():
         assert tuple(spans) == SESSION_SPANS  # recorded in this order
-        total_ms = 1e3 * sum(e["dur_s"] for e in spans.values())
-        assert total_ms == pytest.approx(disp[seq]["dispatch_ms"], rel=0.05)
+        # what holds on any machine, from the spans' own extents and the
+        # event's stamp (how little lies outside the spans is
+        # `unspanned_ms`'s to say, with a number): the seven do not
+        # overlap, the first six end before `batch.dispatch` is recorded,
+        # inside `session.scatter`, and so sum to less than `dispatch_ms`,
+        # which runs from the launch's entry to that event
+        seven = list(spans.values())
+        for e0, e1 in zip(seven, seven[1:]):
+            assert e0["t0"] + e0["dur_s"] <= e1["t0"] + 2e-6
+        d, scatter = disp[seq], spans["session.scatter"]
+        assert seven[5]["t0"] + seven[5]["dur_s"] <= d["tm"] + 2e-6
+        assert scatter["t0"] - 2e-6 <= d["tm"]
+        assert d["tm"] <= scatter["t0"] + scatter["dur_s"] + 2e-6
+        assert 1e3 * sum(e["dur_s"] for e in seven[:6]) <= d["dispatch_ms"]
         # the dispatch's tag and the span's own extent, nothing computed
         # for the event's sake
         for e in spans.values():
@@ -482,3 +497,168 @@ def test_off_the_session_still_accounts_its_solve_time(off):
     ses._fleet_account = account
     _two_buckets(ses)
     assert len(seen) == 2 and all(0 < s < 60 for s in seen)
+
+
+# -- the period's account (PR 37) --------------------------------------------
+PERIOD_FIELDS = ("period_ms", "inside_ms", "caller_ms", "submit_ms",
+                 "submits", "spanned_ms", "unspanned_ms")
+PARTS = ("caller_ms", "submit_ms", "spanned_ms", "unspanned_ms")
+
+
+def _steady(ses, buckets=4, lanes=4, n=300, between=None, wait=True,
+            sent=None):
+    """A caller's loop: ``lanes`` submits, a flush, and (``wait``) the
+    results, ``buckets`` times; ``between(k)`` runs outside the session
+    after bucket ``k``. Returns the ``batch.dispatch`` events by ``seq``;
+    ``sent`` takes each bucket's tickets."""
+    S = _tridiag(n)
+    pat = ses.pattern_of(sparse_tpu.csr_array(S))
+    rng = np.random.default_rng(3)
+    for k in range(buckets):
+        ts = [ses.submit(S.data * (1 + 0.1 * i), rng.standard_normal(n),
+                         tol=1e-8, pattern=pat) for i in range(lanes)]
+        if sent is not None:
+            sent.append(ts)
+        if ses.auto_flush is None:
+            ses.flush(wait=wait)
+        if wait:
+            for t in ts:
+                t.result()
+        if between is not None:
+            between(k)
+    ses.drain()
+    return {e["seq"]: e for e in telemetry.events("batch.dispatch")}
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+@pytest.mark.parametrize("how", ["flush_and_wait", "streaming", "auto_flush"])
+def test_a_period_is_its_four_parts_on_every_dispatch_but_the_first(
+        tel, inflight, how):
+    ses = SolveSession("cg", inflight=inflight, warm_start=False,
+                       auto_flush=4 if how == "auto_flush" else None)
+    sent = []
+    disp = _steady(ses, buckets=5, wait=how == "flush_and_wait", sent=sent)
+    assert sorted(disp) == [1, 2, 3, 4, 5]
+    # a session's first dispatch has no interval before it: no field at all
+    assert not set(PERIOD_FIELDS) & set(disp[1])
+    for seq in (2, 3, 4, 5):
+        e = disp[seq]
+        assert set(PERIOD_FIELDS) <= set(e) and not telemetry.schema.validate(e)
+        assert e["period_ms"] == pytest.approx(
+            sum(e[k] for k in PARTS), abs=0.01)
+        assert e["inside_ms"] == pytest.approx(
+            e["period_ms"] - e["caller_ms"], abs=0.01)
+        assert e["submits"] == e["batch"] == 4  # the bucket's lanes
+        # re-entrancy counts once (auto_flush nests a flush in a submit):
+        # no part is negative, and the intake is not the nested flush
+        assert all(e[k] >= 0 for k in PARTS)
+        assert e["submit_ms"] < e["spanned_ms"]
+    # the period is the spacing of the launches' entries, where the
+    # tickets' queue ends: the first lane's submit plus the longest wait
+    t0 = {seq: min(t.t_submit for t in sent[seq - 1]) * 1e3
+          + disp[seq]["queue_ms_max"] for seq in disp}
+    for seq in (2, 3, 4, 5):
+        assert disp[seq]["period_ms"] == pytest.approx(
+            t0[seq] - t0[seq - 1], abs=0.01)
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+def test_a_callers_pause_between_buckets_is_caller_ms(tel, inflight):
+    ses = SolveSession("cg", inflight=inflight, warm_start=False)
+    disp = _steady(ses, buckets=5,
+                   between=lambda k: time.sleep(0.05) if k == 2 else None)
+    slow, others = disp[4], [disp[s] for s in (2, 3, 5)]
+    assert slow["caller_ms"] >= 50.0
+    assert slow["period_ms"] >= slow["caller_ms"]
+    for e in others:
+        assert e["caller_ms"] < 50.0
+    # and in nothing else: the other three parts read what they read
+    # beside it, tens of milliseconds below the pause
+    for k in ("submit_ms", "unspanned_ms"):
+        assert slow[k] < 25.0
+    assert slow["inside_ms"] <= slow["period_ms"] - 50.0 + 0.01
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+def test_time_between_the_spans_is_unspanned_ms(tel, monkeypatch, inflight):
+    ses = SolveSession("cg", inflight=inflight, warm_start=False)
+    decide, calls = ses.fleet.decide, []
+
+    def slow_decide(*a, **kw):  # in `_launch`, before session.pack: no span
+        calls.append(1)
+        if len(calls) == 3:
+            time.sleep(0.05)
+        return decide(*a, **kw)
+
+    monkeypatch.setattr(ses.fleet, "decide", slow_decide)
+    disp = _steady(ses, buckets=5)
+    # launch 3's decision lies in the period that launch 4 ends
+    slow = disp[4]
+    assert slow["unspanned_ms"] >= 50.0
+    assert slow["caller_ms"] < 25.0 and slow["submit_ms"] < 25.0
+    for s in (2, 3, 5):
+        assert disp[s]["unspanned_ms"] < 50.0
+
+
+def test_a_blocking_admission_is_not_submit_ms(tel):
+    """``submit`` at ``max_queue_depth`` drives the pipeline itself: what it
+    dispatches and retires there is spans and the rest, like a nested
+    flush, and ``submit_ms`` stays the intake. Here every fourth submit
+    finds three tickets out: it launches them (a launch inside
+    ``session.submit``: that period has three submits) and retires them,
+    and is then flushed alone."""
+    ses = SolveSession("cg", inflight=2, warm_start=False,
+                       max_queue_depth=3, admission="block")
+    disp = _steady(ses, buckets=3, wait=False)
+    assert len(telemetry.events("batch.admission")) == 3
+    assert [disp[s]["batch"] for s in sorted(disp)] == [3, 1] * 3
+    for seq in sorted(disp)[1:]:
+        e = disp[seq]
+        assert e["period_ms"] == pytest.approx(
+            sum(e[k] for k in PARTS), abs=0.01)
+        assert e["submits"] == e["batch"]
+        assert 0 <= e["submit_ms"] < e["spanned_ms"]
+        # the fourth submit's few microseconds before its launch are the
+        # period's before it, and counted in the one after
+        assert e["unspanned_ms"] > -5.0
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+def test_off_the_session_keeps_no_account_and_reads_no_clock_for_it(
+        off, monkeypatch, inflight):
+    """Telemetry off: the entry points read the clock where the parent
+    did (a ticket's ``t_submit`` and ``t_done``; a launch's entry and the
+    four instants the retire's arithmetic needs, for which a live span
+    would have stood) and nowhere else, and nothing is summed."""
+    reads = []
+    monkeypatch.setattr(telemetry, "clock",
+                        lambda: reads.append(1) or time.monotonic())
+    ses = SolveSession("cg", inflight=inflight, warm_start=False)
+    S = _tridiag(300)
+    pat = ses.pattern_of(sparse_tpu.csr_array(S))
+
+    def count(call):
+        n = len(reads)
+        out = call()
+        return len(reads) - n, out
+
+    for bucket in range(3):
+        tickets = []
+        for _ in range(4):
+            n, t = count(lambda: ses.submit(S.data, np.ones(300), tol=1e-8,
+                                            pattern=pat))
+            assert n == 1  # the ticket's t_submit
+            tickets.append(t)
+        # the launch's entry, session.plan's and session.call's starts (the
+        # first one's build: two more), the results' arrival, the
+        # readback's end, four tickets' t_done: the parent's count
+        assert count(ses.flush)[0] == 3 + (2 if bucket == 0 else 0) + 2 + 4
+        assert count(ses.poll)[0] == 0
+        assert count(lambda: tickets[0].result())[0] == 0
+        assert count(lambda: tickets[1].ready)[0] == 0
+        assert count(ses.drain)[0] == 0
+    acct = ses._period
+    assert (acct.depth, acct.entered, acct.last_t0, acct.submits) == (
+        0, None, None, 0)
+    assert acct.inside == acct.submit == acct.spanned == 0.0
+    assert telemetry.events() == []
