@@ -127,8 +127,10 @@ class SparsityPattern:
             )
             srcs = tuple(commit_to_exec_device(srcs)) if srcs else ()
             (pos,) = commit_to_exec_device((pos,))
-            # slots / nnz is the padding every lane's gathers pay
-            sp.annotate(slabs=len(plan.slab_meta), slots=plan.stored_slots)
+            # slots / nnz is the padding every lane's gathers pay;
+            # pad_rows the zero rows `slab_rows` gave the slabs
+            sp.annotate(slabs=len(plan.slab_meta), slots=plan.stored_slots,
+                        pad_rows=plan.pad_rows)
         telemetry.count("batch.pattern_pack")
         return _SellPatternPack(plan, idx_slabs, pos, srcs)
 
@@ -238,6 +240,12 @@ class _SellPatternPack:
         )
         self._order = None
 
+    @property
+    def pad_rows(self) -> int:
+        """Zero rows the pack added (what a program built on it reports
+        as ``batch.dispatch``'s ``pad_rows``)."""
+        return self.plan.pad_rows
+
     def own_order(self):
         """The pack's own row order as a space a Krylov loop can run in
         (:class:`_PackOrder`), derived from what the pack holds, once a
@@ -266,7 +274,10 @@ class _SellPatternPack:
 
 class _PackOrder:
     """A SELL pack's own row order (rows sorted by length inside sigma
-    windows, grouped into slabs, each slab padded to ``ROW_ALIGN``) as the
+    windows, grouped into slabs, each slab stored with the rows
+    ``kernels.sell_spmv.slab_rows`` gives it, then the all-empty rows; the
+    whole passed through ``slab_rows`` once more, by trailing pad rows, so
+    that ``enter``'s gathers get the step the slabs' get) as the
     space of a Krylov loop: the recurrences do not care how their rows are
     numbered, so a loop whose vectors are held in this order needs no
     ``pos`` gather a product. ``enter`` and ``leave`` are the only
@@ -279,15 +290,20 @@ class _PackOrder:
     each pay a compile, five seconds of a first dispatch on a v5e.
     ``idx_slabs`` are the pack's column indices renumbered into packed
     positions (``pos[idx]``) and ``rows`` maps a packed position to the
-    caller's row, -1 on the slabs' pad rows, which hold zero in every
-    vector of the loop (their value slots are zero, so a product leaves
-    them zero)."""
+    caller's row, -1 on the pad rows (the slabs' and the trailing ones),
+    which hold zero in every vector of the loop (their value slots are
+    zero, so a product leaves them zero; the trailing ones ride with the
+    all-empty rows, ``zero_rows``). ``pad_rows`` counts them all."""
 
-    __slots__ = ("idx_slabs", "rows", "pos", "zero_rows")
+    __slots__ = ("idx_slabs", "rows", "pos", "zero_rows", "pad_rows")
 
     def __init__(self, pack):
+        from ..kernels.sell_spmv import slab_rows
+
         plan, pos = pack.plan, np.asarray(pack.pos)
-        packed_rows = plan.zero_rows + sum(r for _k, r, _p in plan.slab_meta)
+        stored = plan.zero_rows + sum(r for _k, r, _p in plan.slab_meta)
+        packed_rows = slab_rows(stored)
+        trailing = packed_rows - stored
         rows = np.full((packed_rows,), -1, pos.dtype)
         rows[pos] = np.arange(plan.m, dtype=pos.dtype)
         with host_scope():
@@ -298,7 +314,8 @@ class _PackOrder:
             )
         *idx_slabs, self.rows = commit_to_exec_device(built)
         self.idx_slabs = tuple(idx_slabs)
-        self.pos, self.zero_rows = pack.pos, plan.zero_rows
+        self.pos, self.zero_rows = pack.pos, plan.zero_rows + trailing
+        self.pad_rows = plan.pad_rows + trailing
 
     def enter(self, V):
         """``(B, m)`` vectors in the caller's row order -> the pack's
@@ -329,6 +346,7 @@ class _PlanePatternPack:
     __slots__ = ("offsets", "src")
     form = "planes"
     product_row_gathers = 0  # shifted multiply-adds: no row is permuted
+    pad_rows = None  # no slab, no row added: the event leaves the field out
 
     def __init__(self, offsets, src):
         self.offsets, self.src = offsets, src

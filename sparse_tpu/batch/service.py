@@ -574,13 +574,13 @@ class _InFlight:
                  "bkt", "nb", "out", "built", "snap", "seq", "t0",
                  "t_packed", "t_solve0", "t_dispatched", "sampled",
                  "policy", "auto", "staged", "matvec", "row_gathers",
-                 "period", "_ready")
+                 "pad_rows", "period", "_ready")
 
     def __init__(self, reqs, dt, solver, allow_requeue, plan, key, bkt,
                  nb, out, built, snap, seq, t0, t_packed, t_solve0,
                  t_dispatched, sampled, policy=mixed_mod.EXACT, auto=None,
                  staged="host", matvec="sell", row_gathers=None,
-                 period=None):
+                 pad_rows=None, period=None):
         self.reqs, self.dt, self.solver = reqs, dt, solver
         self.allow_requeue, self.plan, self.key = allow_requeue, plan, key
         self.bkt, self.nb, self.out = bkt, nb, out
@@ -606,6 +606,9 @@ class _InFlight:
         # iterations -> whole-vector row gathers the program executed, as
         # its builder tagged it; None for a builder that does not
         self.row_gathers = row_gathers
+        # zero rows the program's SELL pack (and the space built on it)
+        # added, as its builder tagged it; None for a program without
+        self.pad_rows = pad_rows
         # the period that ended at this launch, as `batch.dispatch`
         # fields (`_Period.close`); empty with telemetry off and for a
         # session's first launch
@@ -2052,7 +2055,8 @@ class SolveSession:
             built, snap, seq, t0, t_packed, t_solve0, t_dispatched,
             sampled, policy=pol, auto=auto, staged=staged,
             matvec=getattr(prog, "matvec", "sell"),
-            row_gathers=getattr(prog, "row_gathers", None), period=period,
+            row_gathers=getattr(prog, "row_gathers", None),
+            pad_rows=getattr(prog, "pad_rows", None), period=period,
         )
 
     def _degrade(self, reqs, dt, solver, nb, e) -> None:
@@ -2304,6 +2308,8 @@ class SolveSession:
                 # included
                 **({"row_gathers": fl.row_gathers(iters.max(initial=0))}
                    if fl.row_gathers is not None else {}),
+                **({"pad_rows": fl.pad_rows}
+                   if fl.pad_rows is not None else {}),
                 inflight=len(self._inflight), staged=fl.staged,
                 # the period that ended at this dispatch's launch and its
                 # four parts (`_Period`); a session's first has none
@@ -2764,6 +2770,10 @@ class SolveSession:
             3 if order is not None
             else pack.product_row_gathers * (1 + products * int(iterations))
         )
+        # ... and the zero rows the gather form's vectors carry for the
+        # pack's sake (`kernels.sell_spmv.slab_rows`): the slabs', and the
+        # trailing ones of the pack's order where the loop runs in it
+        run.pad_rows = (order or pack).pad_rows
         return run
 
     def _build_gmres_program(self, pattern, bkt, dt,

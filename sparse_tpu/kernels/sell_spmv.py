@@ -45,18 +45,54 @@ def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-# Slab rows pad to a sublane multiple; pad rows carry idx 0 / val 0
-# (contribute 0 * x[0]: a pad row of the packed output is zero) and are
-# dropped by the pos-gather, which only addresses real rows. Kept small:
-# slab-count x ROW_ALIGN x K is pure pad storage.
+# Slab rows pad to a sublane multiple. A pad row carries idx 0 / val 0 in
+# every slot (it contributes 0 * x[0]: a pad row of the packed output is
+# zero), `pos` addresses real rows only and so drops it, and a loop that
+# keeps its vectors in the pack's order holds zero there
+# (``batch.operator._PackOrder``). Kept small: slab-count x ROW_ALIGN x K
+# is pure pad storage.
 ROW_ALIGN = 8
+
+# Past WIDE_PERIOD rows a slab's row count is moved into the band WIDE_BAND
+# of remainders mod WIDE_PERIOD (:func:`slab_rows`). What the numbers are:
+# an observed property of the TPU compiler's gather lowering (libtpu
+# 0.0.34, v5e), not of the format. A gather of ``[R, lanes]`` rows at 64
+# lanes or more is lowered on a step of 256 rows or of 128, chosen from R;
+# the narrow step runs at 9.9 ns a row where the wide one runs at 4.0
+# (PERF.md sections 5 and 7). R = 1024 b is narrow, so is a stretch below
+# each multiple of 1024 whose start wanders (776 to 904 by b); every
+# remainder in [8, 768] compiled to the wide step. Sufficient, not
+# necessary. Held by ``tests/test_chip_compile.py``
+# (``test_gather_takes_the_wide_step_at_the_pack_row_counts`` and its
+# neighbours), which fail once the compiler stops telling the two apart.
+# If the numbers stop being true they cost the pad rows and nothing else:
+# at most 264 zero rows a slab. At or below 1024 rows a gather is
+# microseconds on either step, and the count stays what ROW_ALIGN gives.
+WIDE_PERIOD = 1024
+WIDE_BAND = (8, 768)
+
+
+def slab_rows(n: int) -> int:
+    """Rows a slab (or a space made of slabs) of ``n`` real rows is stored
+    with: ``n`` rounded up to ``ROW_ALIGN``, and past ``WIDE_PERIOD`` rows
+    moved up into ``WIDE_BAND`` mod ``WIDE_PERIOD``. A function of the row
+    count alone: the pack is a vault artifact and must not depend on where
+    it was built. Idempotent."""
+    R = _round_up(n, ROW_ALIGN)
+    lo, hi = WIDE_BAND
+    rem = R % WIDE_PERIOD
+    if R <= WIDE_PERIOD or lo <= rem <= hi:
+        return R
+    return R - rem + lo + (WIDE_PERIOD if rem > hi else 0)
 
 
 class SellPlan:
     """Static geometry of a packed SELL operator (hashable => jit-static).
 
     ``slab_meta`` is a tuple of ``(K, rows, pad_rows)`` per slab —
-    ``rows`` includes the alignment padding, ``pad_rows`` counts it.
+    ``rows`` is what :func:`slab_rows` gives the slab's real rows (the
+    alignment to ``ROW_ALIGN`` and, past 1024 rows, the move into the
+    gather's wide band), ``pad_rows`` counts the zero rows that added.
     """
 
     __slots__ = ("m", "n", "C", "sigma", "slab_meta", "zero_rows", "nnz")
@@ -70,6 +106,11 @@ class SellPlan:
     @property
     def stored_slots(self) -> int:
         return sum(k * r for k, r, _ in self.slab_meta)
+
+    @property
+    def pad_rows(self) -> int:
+        """Zero rows the pack added to its slabs (:func:`slab_rows`)."""
+        return sum(p for _k, _r, p in self.slab_meta)
 
     @property
     def pad_ratio(self) -> float:
@@ -154,7 +195,7 @@ def sell_pack(indptr, indices, data, shape, C=None, sigma=None, max_slabs=None,
     for K in widths.tolist():
         chunks = np.nonzero(chunk_w == K)[0]
         rws = np.concatenate([perm[c * C : (c + 1) * C] for c in chunks])
-        R = _round_up(len(rws), ROW_ALIGN)
+        R = slab_rows(len(rws))
         idx_t = np.zeros((K, R), dtype=idt)
         val_t = np.zeros((K, R), dtype=data.dtype)
         L = counts[rws]

@@ -73,7 +73,9 @@ KINDS: dict[str, frozenset] = {
     # on every dispatch of a session but its first also the account of
     # the period that ended at its launch (service._Period): period_ms =
     # caller_ms + submit_ms + spanned_ms + unspanned_ms, with inside_ms
-    # and submits (optional fields: not required, a first dispatch has none)
+    # and submits (optional fields: not required, a first dispatch has none);
+    # on a dispatch of the exact gather (`sell`) program pad_rows, the zero
+    # rows its SELL pack added (kernels.sell_spmv.slab_rows; optional too)
     "batch.dispatch": frozenset({"solver", "batch", "bucket"}),
     # one per completed batched Krylov solve (any entry point); B is the
     # lane count, iters_max the slowest lane's iteration count

@@ -84,9 +84,15 @@ def digest(*parts) -> str:
 
 
 def _sell_settings() -> tuple:
+    from ..kernels import sell_spmv
+
     return (
         "C", settings.sell_chunk, "sigma", settings.sell_sigma,
         "slabs", settings.sell_max_slabs,
+        # the rule that gives a slab its row count (`slab_rows`): a pack
+        # written under another rule has other slabs
+        "rows", sell_spmv.ROW_ALIGN, sell_spmv.WIDE_PERIOD,
+        *sell_spmv.WIDE_BAND,
     )
 
 

@@ -28,6 +28,7 @@ from sparse_tpu.batch import BatchedCSR, BatchedDIA, SolveSession, SparsityPatte
 from sparse_tpu.batch import krylov, service
 from sparse_tpu.config import settings
 from sparse_tpu.dia import few_diagonals
+from sparse_tpu.kernels.sell_spmv import slab_rows
 from sparse_tpu.ops import dia_spmv as dia_ops
 from sparse_tpu.ops import spmv as spmv_ops
 
@@ -525,9 +526,22 @@ def empty_rows(n=70):
     return _finish(A)
 
 
+def fem_mesh(side):
+    """The served general cell's pattern class at a small side."""
+    from .utils.spd import fem_heat_data
+
+    return _dominant(fem_heat_data(side, 3, clients=1)["pattern"])
+
+
 ORDERED = {**GENERAL, "empty_rows": empty_rows,
            # 37 rows in slabs whose rows pad to multiples of ROW_ALIGN
-           "pad_rows": lambda: skewed(37, seed=4)}
+           "pad_rows": lambda: skewed(37, seed=4),
+           # 5184 rows: the slab of 1856 rows (832 past 1024: off the
+           # gather's wide band, `slab_rows`) is stored with 2056
+           "slab_pad": lambda: fem_mesh(72),
+           # 4096 rows, every slab in the band, their sum (4096) not:
+           # the space ends in 8 trailing pad rows
+           "space_pad": lambda: fem_mesh(64)}
 
 
 def _order_of(A):
@@ -545,8 +559,18 @@ def test_packed_product_then_pos_is_the_row_order_product(name):
     plan = pack.plan
     if name == "empty_rows":
         assert plan.zero_rows > 0
+    stored = plan.zero_rows + sum(r for _k, r, _p in plan.slab_meta)
     if name == "pad_rows":
-        assert sum(p for _k, _r, p in plan.slab_meta) > 0
+        assert plan.pad_rows > 0
+    if name == "slab_pad":
+        assert (7, 2056, 200) in plan.slab_meta
+    if name == "space_pad":
+        assert plan.pad_rows == 0 and order.pad_rows == 8
+    # the space: the slabs and the all-empty rows, through the slabs' rule
+    assert order.rows.shape[0] == slab_rows(stored) == stored + (
+        order.pad_rows - plan.pad_rows)
+    if stored > 1024:
+        assert 8 <= order.rows.shape[0] % 1024 <= 768
     rng = np.random.default_rng(31)
     values = rng.standard_normal((3, A.nnz)).astype(np.float32)
     X = rng.standard_normal((3, A.shape[0])).astype(np.float32)
@@ -555,7 +579,7 @@ def test_packed_product_then_pos_is_the_row_order_product(name):
         pack.idx_slabs, vals, pack.pos, X, plan.zero_rows))
     packed = spmv_ops.csr_spmv_sell_batched(
         pack.idx_slabs, vals, None, X, plan.zero_rows)
-    assert packed.shape == (3, order.rows.shape[0])
+    assert packed.shape == (3, stored)
     np.testing.assert_array_equal(np.asarray(packed[:, pack.pos]), want)
     mine = order.leave(order.product(vals, order.enter(X)))
     np.testing.assert_array_equal(np.asarray(mine), want)
@@ -566,14 +590,15 @@ def test_packed_product_then_pos_is_the_row_order_product(name):
 
 @pytest.mark.parametrize("name", list(ORDERED))
 def test_pad_rows_are_zero_and_stay_zero(name):
-    """``enter`` zeroes the slabs' alignment pad rows and a product leaves
-    them zero, so R and P never carry anything there into a dot product."""
+    """``enter`` zeroes the pad rows (the slabs', and the space's trailing
+    ones) and a product leaves them zero, so R and P never carry anything
+    there into a dot product."""
     A = ORDERED[name]()
     pack, order = _order_of(A)
     rows = np.asarray(order.rows)
     real = rows >= 0
     assert real.sum() == A.shape[0]
-    assert (~real).sum() == sum(p for _k, _r, p in pack.plan.slab_meta)
+    assert (~real).sum() == order.pad_rows >= pack.plan.pad_rows
     np.testing.assert_array_equal(np.asarray(pack.pos)[rows[real]],
                                   np.nonzero(real)[0])
     rng = np.random.default_rng(32)
@@ -631,9 +656,9 @@ def test_no_row_permutation_in_the_loop(name, solver):
         return _gathers(jax.make_jaxpr(run)(*args).jaxpr)
 
     mine = body_gathers(ses._build_program(pattern, 2, np.dtype(np.float32)))
-    slab_rows = {r for _k, r, _p in plan.slab_meta}
+    rows_of_slabs = {r for _k, r, _p in plan.slab_meta}
     assert len(mine[True]) == products * slots
-    assert {res[-1] for _op, res in mine[True]} <= slab_rows
+    assert {res[-1] for _op, res in mine[True]} <= rows_of_slabs
     parents = body_gathers(_parent_program(ses, pattern, solver))
     assert len(parents[True]) == products * (slots + 1)
     assert sum(res[-1] == m for _op, res in parents[True]) >= products
@@ -670,6 +695,27 @@ def test_dispatch_events_count_the_row_gathers(monkeypatch, solver, products):
         assert ev["row_gathers"] == 1 + products * trips
         (ev,) = _dispatch_events(monkeypatch, solver, grid5())
         assert ev["matvec"] == "planes" and ev["row_gathers"] == 0
+    finally:
+        telemetry.reset()
+
+
+@pytest.mark.parametrize("name", ["pad_rows", "slab_pad", "space_pad"])
+def test_dispatch_events_count_the_pad_rows(monkeypatch, name):
+    """`pad_rows`: the zero rows the program's vectors carry for the pack's
+    sake: the slabs', and in the pack's order the space's trailing ones."""
+    A = ORDERED[name]()
+    try:
+        (ev,) = _dispatch_events(monkeypatch, "cg", A)
+        pack, order = _order_of(A)
+        assert ev["matvec"] == "sell" and ev["row_gathers"] == 3
+        assert ev["pad_rows"] == order.pad_rows > 0
+        (ev,) = _dispatch_events(monkeypatch, "cg", A, precond="jacobi")
+        assert ev["pad_rows"] == pack.plan.pad_rows  # the caller's order
+        if name == "pad_rows":
+            (ev,) = _dispatch_events(monkeypatch, "cg", grid5())
+            assert ev["matvec"] == "planes" and "pad_rows" not in ev
+            evs = _dispatch_events(monkeypatch, "gmres", A)
+            assert all("pad_rows" not in e for e in evs)
     finally:
         telemetry.reset()
 

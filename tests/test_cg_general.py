@@ -29,6 +29,14 @@ LAYOUTS = {"ell": ("auto", 0), "sell": ("auto", 5), "segment": ("segment", 0)}
 TRACES = _metrics.counter("cg.general.traces")
 
 
+@pytest.fixture(autouse=True)
+def _fresh_general_program():
+    """These tests count traces of ``jit_cg_general``; a test of another
+    file that solved a system of the same shapes earlier in this process
+    (``tests/test_spans.py`` does) would leave them none to count."""
+    linalg._cg_general_program.clear_cache()
+
+
 def _system(layout, side, seed, dtype=np.float32, monkeypatch=None, skew=None):
     mode, per_row = LAYOUTS[layout]
     if monkeypatch is not None:

@@ -14,6 +14,7 @@ written for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -289,8 +290,6 @@ def test_gather_bucket_program_permutes_rows_outside_the_loop(
     fusion a slot plane of a slab in the while body, and the whole-vector
     row permutations (`[rows, lanes]`, lane-minor) where the builder put
     them. An unstructured FEM pattern, as `fem_heat_served_closed` serves."""
-    import re
-
     from sparse_tpu.batch import service
 
     from .utils.spd import fem_heat_data
@@ -303,22 +302,148 @@ def test_gather_bucket_program_permutes_rows_outside_the_loop(
     run = ses._build_program(pattern, B, np.dtype(np.float32),
                              precond=precond)
     assert run.matvec == "sell"
-    hlo = run.lower(
+    hlo = _bucket_program_text(run, pattern, B, one_chip)
+    pack = pattern.sell_pack()
+    plan = pack.plan
+    # the pack's order is 200 rows longer than the caller's (PR 39: the
+    # 7-slot slab's pad rows), so `enter`'s results are told from `leave`'s
+    space = pack.own_order().rows.shape[0]
+    assert space == n + 200
+    assert all(r not in (n, space) for _k, r, _p in plan.slab_meta)
+    gathers = _gather_fusions(hlo)
+    whole = [ln for ln in gathers
+             if re.search(rf"= f32\[({n}|{space}),{B}\]", ln)]
+    body = [ln for ln in gathers if "/while/body/" in ln]
+    assert len(body) == sum(k for k, _r, _p in plan.slab_meta) + in_body
+    assert sum("/while/body/" in ln for ln in whole) == in_body
+    assert sum("/while/body/" not in ln for ln in whole) == outside
+    # in the pack's order two of the three are `enter`'s, of the space's rows
+    assert sum(f"= f32[{space},{B}]" in ln for ln in whole) == (
+        2 if precond == "none" else 0)
+
+
+# ---------------------------------------------------------------------------
+# the gather's step (PR 39). The TPU compiler lowers a gather of `[R, lanes]`
+# rows on a step of 256 rows or of 128, chosen from R; on the chip the narrow
+# one runs at 9.9 ns a row and the wide one at 4.0 (PERF.md section 5).
+# `kernels.sell_spmv.slab_rows` moves a slab's row count into a band of
+# remainders mod 1024 where every count tried got the wide step. These cases
+# hold that observation: once the compiler stops telling the two apart, or
+# tells them apart elsewhere, they fail, and the pad rows are dead weight.
+# ---------------------------------------------------------------------------
+def _bucket_program_text(run, pattern, B, one_chip) -> str:
+    n = pattern.shape[0]
+    return run.lower(
         _sds((B, pattern.nnz), jnp.float32, one_chip),
         _sds((B, n), jnp.float32, one_chip),
         _sds((B, n), jnp.float32, one_chip),
         _sds((B,), jnp.float32, one_chip),
         _sds((), jnp.int32, one_chip),
     ).compile().as_text()
-    plan = pattern.sell_pack().plan
-    assert all(r != n for _k, r, _p in plan.slab_meta)  # no slab is whole
-    gathers = [ln for ln in hlo.splitlines()
-               if "kind=kCustom" in ln and " fusion(" in ln]
-    whole = [ln for ln in gathers if re.search(rf"= f32\[{n},{B}\]", ln)]
+
+
+def _gather_fusions(hlo: str) -> list:
+    return [ln for ln in hlo.splitlines()
+            if "kind=kCustom" in ln and " fusion(" in ln]
+
+
+def _gather_step(line: str) -> int:
+    return int(re.search(r'"integer_config":\{"integer":"(\d+)"\}', line).group(1))
+
+
+def _fusion_rows(line: str) -> int:
+    return int(re.search(r"= f32\[(\d+),\d+\]", line).group(1))
+
+
+# what `sell_pack` gives the cell's pattern class (`fem_heat_data`,
+# `pattern_seed` 3200000103): side 960 (`fem_heat_served_closed`; the slab
+# of 229,344 real rows is the one the rule moved), its space of 921,640
+# rows, and side 1108 (thermal2's rows) with its space
+CELL_SLABS = (128, 59_560, 229_512, 345_112, 229_384, 57_944, 921_640)
+THERMAL2_SLABS = (80, 78_256, 307_208, 459_784, 305_160, 77_832, 1_228_320)
+# a dozen more, over the multiples of 1024 and the band's two ends
+BAND_ROWS = tuple(1024 * b + o for b, o in (
+    (1, 8), (1, 768), (2, 400), (56, 8), (56, 768), (75, 768), (128, 768),
+    (223, 8), (297, 768), (512, 264), (899, 768), (1200, 8)))
+
+
+@pytest.mark.parametrize(
+    "rows", sorted(set(CELL_SLABS + THERMAL2_SLABS + BAND_ROWS)))
+def test_gather_takes_the_wide_step_at_the_pack_row_counts(one_chip, rows):
+    """The standalone gather of a slab's slot plane at 64 lanes: one
+    `kCustom` fusion, on the 256-row step at every row count the pack's
+    rule gives (slabs of 1024 rows or fewer are microseconds either way and
+    get whatever step the compiler likes)."""
+    from sparse_tpu.kernels.sell_spmv import slab_rows
+
+    assert slab_rows(rows) == rows
+    (fusion,) = _gather_fusions(_standalone_gather(one_chip, rows))
+    assert _fusion_rows(fusion) == rows
+    if rows > 1024:
+        assert _gather_step(fusion) == 256, fusion
+
+
+def _standalone_gather(one_chip, rows, lanes=64, length=921_600):
+    run = jax.jit(lambda X, idx: jax.vmap(lambda x: x[idx])(X) * 2.0)
+    return run.lower(_sds((lanes, length), jnp.float32, one_chip),
+                     _sds((rows,), jnp.int32, one_chip)).compile().as_text()
+
+
+@pytest.mark.parametrize("rows, moved_to", [
+    (229_344, 229_384),   # the cell's slab: 2.275 ms a gather on the chip
+    (921_600, 921_608),   # a multiple of 1024
+    (304_920, 305_160),   # thermal2's rows, 792 past a multiple
+])
+def test_gather_takes_the_narrow_step_off_the_band(one_chip, rows, moved_to):
+    """The other side: the row counts ROW_ALIGN alone gave are on the
+    128-row step. A compiler that no longer tells them apart fails here,
+    so that the rule's pad rows do not outlive their reason unseen."""
+    from sparse_tpu.kernels.sell_spmv import slab_rows
+
+    assert slab_rows(rows) == moved_to
+    (fusion,) = _gather_fusions(_standalone_gather(one_chip, rows))
+    assert _gather_step(fusion) == 128, fusion
+
+
+def test_gather_bucket_program_takes_the_wide_step_in_its_loop(
+        one_chip, monkeypatch):
+    """One whole bucket program at 64 lanes on an FEM pattern of 360,000
+    rows (side 600; under 270,000 rows the loop's vectors fit the chip's
+    fast memory and the compiler lowers the gathers another way, with no
+    step to read). Under ROW_ALIGN alone its 7-slot slab has 134,072 rows
+    and sits on the 128-row step; `slab_rows` stores it with 134,152, the
+    6-slot slab's 89,984 with 90,120, and the space gets 240 trailing pad
+    rows: every gather fusion of more than 1024 rows in the while body is
+    on the 256-row step, and so are the two permutations into the pack's
+    order."""
+    from sparse_tpu.batch import service
+
+    from .utils.spd import fem_heat_data
+
+    monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    P = fem_heat_data(600, 3, clients=1)["pattern"]
+    n, B = P.shape[0], 64
+    ses = service.SolveSession("cg", batch_max=B, warm_start=False)
+    pattern = ses.pattern_of(P)
+    run = ses._build_program(pattern, B, np.dtype(np.float32))
+    pack = pattern.sell_pack()
+    assert pack.plan.slab_meta == (
+        (4, 224, 0), (5, 23_664, 0), (6, 90_120, 136), (7, 134_152, 80),
+        (8, 89_416, 0), (9, 22_640, 0))
+    space = pack.own_order().rows.shape[0]
+    assert (n, space) == (360_000, 360_456)
+    assert run.pad_rows == pack.own_order().pad_rows == 216 + 240
+    hlo = _bucket_program_text(run, pattern, B, one_chip)
+    gathers = _gather_fusions(hlo)
     body = [ln for ln in gathers if "/while/body/" in ln]
-    assert len(body) == sum(k for k, _r, _p in plan.slab_meta) + in_body
-    assert sum("/while/body/" in ln for ln in whole) == in_body
-    assert sum("/while/body/" not in ln for ln in whole) == outside
+    assert len(body) == sum(k for k, _r, _p in pack.plan.slab_meta)
+    wide = [ln for ln in body if _fusion_rows(ln) > 1024]
+    assert len(wide) == 5 + 6 + 7 + 8 + 9
+    assert {_gather_step(ln) for ln in wide} == {256}, [
+        ln for ln in wide if _gather_step(ln) != 256]
+    enter = [ln for ln in gathers if f"= f32[{space},{B}]" in ln]
+    assert len(enter) == 2
+    assert {_gather_step(ln) for ln in enter} == {256}
 
 
 # ---------------------------------------------------------------------------

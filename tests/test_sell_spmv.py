@@ -15,7 +15,9 @@ import scipy.sparse as sp
 import sparse_tpu
 from sparse_tpu import plan_cache
 from sparse_tpu.config import Settings, settings
+from sparse_tpu.kernels import sell_spmv
 from sparse_tpu.kernels.sell_spmv import sell_pack
+from sparse_tpu.ops.spmv import csr_spmv_sell
 
 from .utils.sample import sample_csr, sample_vec
 
@@ -219,3 +221,111 @@ def test_sell_plan_dies_with_matrix(monkeypatch):
     del A, A2
     gc.collect()
     assert plan_cache.stats()["size"] < before
+
+
+# ---------------------------------------------------------------------------
+# the rule that gives a slab its row count (PR 39)
+# ---------------------------------------------------------------------------
+BAND_LO, BAND_HI = sell_spmv.WIDE_BAND
+
+ROW_COUNTS = [
+    0, 1, 7, 8, 9, 1000, 1017, 1024,                  # what ROW_ALIGN gives
+    1025, 1032, 1033,                                 # the band's lower end
+    1024 + BAND_HI - 1, 1024 + BAND_HI, 1024 + BAND_HI + 1,  # ... its upper
+    2040, 2047, 2048, 2049, 2056,                     # about a multiple of 1024
+    # fem_heat_served_closed's slabs under ROW_ALIGN alone, its rows, and
+    # thermal2's rows' three narrow slabs (PERF.md section 5)
+    229_344, 229_512, 345_112, 921_600, 921_640, 77_680, 304_920, 307_144,
+    459_584, 5_000_000,
+]
+
+
+@pytest.mark.parametrize("n", ROW_COUNTS)
+def test_slab_rows_is_aligned_close_and_in_the_band(n):
+    R = sell_spmv.slab_rows(n)
+    aligned = -(-n // sell_spmv.ROW_ALIGN) * sell_spmv.ROW_ALIGN
+    assert R % sell_spmv.ROW_ALIGN == 0
+    assert n <= R < n + 272
+    if aligned <= sell_spmv.WIDE_PERIOD:
+        assert R == aligned  # a small slab keeps the count it had
+    else:
+        assert BAND_LO <= R % sell_spmv.WIDE_PERIOD <= BAND_HI
+        # the least such count
+        assert R == next(
+            r for r in range(aligned, aligned + 272, sell_spmv.ROW_ALIGN)
+            if BAND_LO <= r % sell_spmv.WIDE_PERIOD <= BAND_HI)
+    assert sell_spmv.slab_rows(R) == R  # idempotent
+
+
+@pytest.mark.parametrize("n,want", [
+    (229_344, 229_384), (229_512, 229_512), (921_600, 921_608),
+    (77_680, 77_832), (304_920, 305_160), (307_144, 307_208),
+    (459_584, 459_784), (78_256, 78_256),
+])
+def test_slab_rows_at_the_cells_row_counts(n, want):
+    assert sell_spmv.slab_rows(n) == want
+
+
+def three_widths():
+    """600, 2040 and 504 rows of 2, 3 and 5 entries, shuffled: under
+    ``C=8, sigma=0`` one slab a length, and the middle one's 2040 rows sit
+    8 short of 2048."""
+    rng = np.random.default_rng(6)
+    deg = rng.permutation(np.repeat((2, 3, 5), (600, 2040, 504)))
+    m = deg.shape[0]
+    r = np.repeat(np.arange(m), deg)
+    c = np.concatenate([rng.choice(m, size=d, replace=False) for d in deg])
+    v = rng.standard_normal(r.shape[0])
+    return sp.coo_matrix((v, (r, c)), shape=(m, m)).tocsr()
+
+
+def test_a_slab_past_1024_rows_gets_pad_rows_and_multiplies_as_scipy():
+    s = three_widths()
+    plan, slabs, pos, srcs = sell_pack(
+        s.indptr, s.indices, s.data, s.shape, C=8, sigma=0, with_srcs=True)
+    # 2040 rows -> 2056: the first count past them in the band; the two
+    # small slabs keep their rows
+    assert plan.slab_meta == ((2, 600, 0), (3, 2056, 16), (5, 504, 0))
+    assert plan.pad_rows == 16
+    (_i2, _v2), (it, vt), _ = slabs
+    assert it.shape == vt.shape == (3, 2056)
+    # the pad rows are ROW_ALIGN's own kind: index 0, value 0, no source
+    assert not np.asarray(it)[:, 2040:].any()
+    assert not np.asarray(vt)[:, 2040:].any()
+    assert (np.asarray(srcs[1])[:, 2040:] == -1).all()
+    pos = np.asarray(pos)
+    assert len(np.unique(pos)) == s.shape[0]
+    assert not ((pos >= 600 + 2040) & (pos < 600 + 2056)).any()
+    x = np.random.default_rng(1).standard_normal(s.shape[1])
+    got = np.asarray(csr_spmv_sell(slabs, pos, x, plan.zero_rows))
+    np.testing.assert_allclose(got, s @ x, rtol=1e-10, atol=1e-10)
+    packed = np.asarray(csr_spmv_sell(slabs, None, x, plan.zero_rows))
+    assert packed.shape == (600 + 2056 + 504,)
+    assert not packed[600 + 2040:600 + 2056].any()  # a pad row comes out zero
+    # ... and through the matrix's own prepared operator
+    A = sparse_tpu.csr_array(s)
+    A.prepare(mode="sell")
+    np.testing.assert_allclose(np.asarray(A @ x), s @ x, rtol=1e-10,
+                               atol=1e-10)
+
+
+def test_the_vault_keys_carry_the_row_rule(monkeypatch):
+    """A pack written under another rounding rule has other slabs: it must
+    not be loaded in this one's place."""
+    from sparse_tpu.batch.operator import SparsityPattern
+    from sparse_tpu.vault import _codecs
+
+    s = three_widths()
+    pattern = SparsityPattern.from_csr(s)
+    mine = (_codecs.sell_pattern_key(pattern),
+            _codecs.prepared_csr_key(s.indptr, s.indices, s.data, s.shape))
+    # the parent's rule: ROW_ALIGN alone, nothing of the band in the key
+    monkeypatch.setattr(_codecs, "_sell_settings", lambda: (
+        "C", settings.sell_chunk, "sigma", settings.sell_sigma,
+        "slabs", settings.sell_max_slabs))
+    parents = (_codecs.sell_pattern_key(pattern),
+               _codecs.prepared_csr_key(s.indptr, s.indices, s.data, s.shape))
+    assert parents[0] != mine[0] and parents[1] != mine[1]
+    monkeypatch.undo()
+    monkeypatch.setattr(sell_spmv, "WIDE_BAND", (8, 760))
+    assert _codecs.sell_pattern_key(pattern) != mine[0]

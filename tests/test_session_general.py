@@ -121,12 +121,86 @@ def test_pattern_pack_span_carries_the_slabs_and_their_padding(tel):
     assert ev["slabs"] == len(plan.slab_meta) >= 2
     assert ev["slots"] == sum(K * R for K, R, _ in plan.slab_meta)
     assert d["nnz"] <= ev["slots"] <= 2 * d["nnz"]  # what the gathers pay
+    # the zero rows the slabs were given (PR 39), as the dispatches count them
+    assert ev["pad_rows"] == plan.pad_rows
+    order = plan_cache.lookup(pattern, "sell.pattern").own_order()
+    assert {e["pad_rows"] for e in telemetry.events("batch.dispatch")} == {
+        order.pad_rows}
     assert telemetry.summary()["spans"]["session.pattern_pack"]["n"] == 1
     # another session on the same pattern object packs nothing
     again = SolveSession("cg", batch_max=4, warm_start=False)
     tickets, _ = _step(again, again.pattern_of(pattern), d, state, 4)
     assert all(t.result()[0].shape == (d["rows"],) for t in tickets)
     assert len(_pack_spans()) == 1
+
+
+PADDED_SIDE = 72  # 5184 rows; the 7-slot slab's 1856 rows are stored as 2056
+
+
+def _one_step(d, batch_max):
+    """(answers, rhs, dispatch events, pack) of every client's first step
+    through a fresh session and a fresh pattern object."""
+    telemetry.reset()
+    ses = SolveSession("cg", batch_max=batch_max, warm_start=False)
+    pattern = ses.pattern_of(sp.csr_matrix(d["pattern"]))
+    tickets, rhs = _step(ses, pattern, d, list(d["initial"]), batch_max)
+    answers = np.stack([np.asarray(t.result()[0]) for t in tickets])
+    return (answers, np.stack(rhs), telemetry.events("batch.dispatch"),
+            plan_cache.lookup(pattern, "sell.pattern"))
+
+
+@pytest.mark.parametrize("batch_max", [8, 64])
+def test_a_slab_moved_into_the_wide_band_steps_as_the_plain_pack(
+        tel, monkeypatch, batch_max):
+    """A pattern whose middle slab gets pad rows from `slab_rows` (PR 39),
+    through the session's `cg` program in the pack's own order: the
+    answers are scipy's, every lane takes the iterations it takes on the
+    pack rounded to ROW_ALIGN alone, and the pad rows of the loop's X are
+    zero when it ends (those of r and p: tests/test_bucket_planes.py,
+    `test_pad_rows_are_zero_and_stay_zero[slab_pad]`)."""
+    from sparse_tpu.batch import krylov
+    from sparse_tpu.kernels import sell_spmv
+
+    d = fem_heat_data(PADDED_SIDE, 9, clients=batch_max)
+    P = d["pattern"]
+    answers, rhs, events, pack = _one_step(d, batch_max)
+    with monkeypatch.context() as m:
+        m.setattr(sell_spmv, "slab_rows",
+                  lambda n: -(-n // sell_spmv.ROW_ALIGN) * sell_spmv.ROW_ALIGN)
+        plain_answers, _, plain_events, plain_pack = _one_step(d, batch_max)
+    assert (7, 1856, 0) in plain_pack.plan.slab_meta
+    assert (7, 2056, 200) in pack.plan.slab_meta
+    order = pack.own_order()
+    assert order.pad_rows == pack.plan.pad_rows >= 200
+    assert 8 <= order.rows.shape[0] % 1024 <= 768
+    (ev,), (plain_ev,) = events, plain_events
+    assert (ev["bucket"], ev["matvec"], ev["row_gathers"]) == (batch_max, "sell", 3)
+    assert ev["pad_rows"] == order.pad_rows
+    assert plain_ev["pad_rows"] == plain_pack.own_order().pad_rows < 200
+    assert (ev["iters_max"], ev["iters_mean"]) == (
+        plain_ev["iters_max"], plain_ev["iters_mean"]) == (25, 25.0)
+    x_limit = d["kappa_bound"] * 2.0 * d["rel_tol"]
+    for k in range(0, batch_max, max(batch_max // 8, 1)):
+        A = sp.csr_matrix((d["values"][k].astype(np.float64), P.indices,
+                           P.indptr), shape=P.shape)
+        exact = spla.spsolve(A.tocsc(), rhs[k].astype(np.float64))
+        assert np.linalg.norm(answers[k] - exact) <= x_limit * np.linalg.norm(exact)
+    # float32 sums over a longer vector of the same numbers and zeros
+    np.testing.assert_allclose(answers, plain_answers, rtol=0, atol=2e-5)
+    # the loop itself, in the pack's order: nothing enters a pad row
+    pad = np.asarray(order.rows) < 0
+    assert pad.sum() == order.pad_rows
+    vals = pack.pack_values(d["values"])
+    tols = d["rel_tol"] * np.linalg.norm(rhs, axis=1)
+    X, iters, _resid2, conv = krylov._cg_loop(
+        lambda V: order.product(vals, V), order.enter(rhs),
+        order.enter(np.stack(d["initial"])), tols.astype(np.float32), 200,
+        25)  # the session's default `conv_test_iters`
+    assert set(np.asarray(iters)) == {25}
+    assert np.asarray(conv).all()
+    assert not np.asarray(X)[:, pad].any()
+    np.testing.assert_allclose(np.asarray(order.leave(X)), answers, rtol=0,
+                               atol=2e-6)
 
 
 def test_five_point_grid_beside_it_gets_planes(tel):
