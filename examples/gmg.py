@@ -341,7 +341,8 @@ def build_dist_cycle(mg, mesh, replicate_below: int = 2048):
 def main_grid():
     """Structured-grid pipeline (sparse_tpu/models/gmg_grid.py): stencil
     hierarchy via comb-probed Galerkin products, grid-space V-cycle, the
-    whole PCG one compiled while_loop. Numerically the same hierarchy as
+    whole PCG one compiled program (jit_pcg) that the next solve reuses.
+    Numerically the same hierarchy as
     the generic path (oracle-pinned in tests/test_gmg_grid.py); replaces
     its two dominant costs — host COO sorts + eager power iteration in
     init (~52 s at n=4000 measured r3) and CSR/gather ops in the cycle."""
@@ -394,14 +395,12 @@ def main_grid():
                 for (st, w, n) in hier
             ]
             b = commit_to_exec_device((b,))[0]
-        st0 = hier[0][0]
-        vc = gg.make_vcycle(hier, args.gridop)
-        mv = jax.jit(
-            lambda v: gg.stencil_apply(st0, v.reshape(N, N)).reshape(-1)
-        )
-        npdt = np.float64 if common.precision == "f64" else np.float32
-        A_op = linalg.LinearOperator((N * N, N * N), dtype=npdt, matvec=mv)
-        M = linalg.LinearOperator((N * N, N * N), dtype=npdt, matvec=vc)
+        # both operators declare what they hold (the planes and weights),
+        # so linalg.cg runs ONE compiled program, jit_pcg, with the
+        # hierarchy as its arguments: the second solve compiles nothing
+        A_op = gg.grid_operator(hier)
+        M = gg.make_vcycle(hier, args.gridop)
+        mv = A_op.matvec
 
         from benchmark import solve_timed_best_of_2
 

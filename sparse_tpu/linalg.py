@@ -17,6 +17,7 @@ the reference's periodic-sync behavior.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 
@@ -66,18 +67,40 @@ def use_solver(**kwargs):
 # LinearOperator protocol (linalg.py:128-459)
 # ---------------------------------------------------------------------------
 class LinearOperator:
-    def __init__(self, shape, matvec=None, rmatvec=None, matmat=None, dtype=None):
+    """scipy's ``LinearOperator``, with one more way to say what the
+    product is: ``LinearOperator(shape, apply=f, operands=tree)`` DECLARES
+    the arrays the product reads (``operands``, any pytree of arrays) apart
+    from the function that reads them (``apply(operands, v)``: pure,
+    traceable, and equal by value to the ``apply`` of every operator of the
+    same structure: a module-level function or a frozen dataclass holding
+    the static sizes, not a fresh lambda). A solver can then hand the arrays
+    to one compiled program as arguments (:func:`cg`) where a closure
+    ``matvec=f`` could only reach it as constants of a program of its own.
+    ``describe`` is a small dict of static facts (``{"precond": "jacobi"}``)
+    that a solve's ``cg.solve`` span carries for the preconditioner."""
+
+    def __init__(self, shape, matvec=None, rmatvec=None, matmat=None, dtype=None,
+                 *, apply=None, operands=None, describe=None):
         self.shape = tuple(int(s) for s in shape)
         self.dtype = np.dtype(dtype) if dtype is not None else np.dtype(np.float32)
         self._matvec_impl = matvec
         self._rmatvec_impl = rmatvec
         self._matmat_impl = matmat
+        self.apply = apply
+        self.operands = operands
+        self.describe = dict(describe or {})
 
     def matvec(self, x, out=None):
         """out= is advisory (jax arrays are immutable); kept for API parity."""
         if self._matvec_impl is None:
-            raise NotImplementedError
+            if self.apply is None:
+                raise NotImplementedError
+            return _applied(self.apply, self.operands, x)
         return self._matvec_impl(x)
+
+    def __call__(self, x):
+        """scipy's ``op(x)``: the operator applied to a vector or a matrix."""
+        return self @ x
 
     def rmatvec(self, x, out=None):
         if self._rmatvec_impl is None:
@@ -171,6 +194,13 @@ class LinearOperator:
                 dtype=self.dtype,
             )
         return self.H
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _applied(apply, operands, x):
+    """A declared operator's product outside a solver: one small program an
+    ``apply``, the operands its arguments."""
+    return apply(operands, x)
 
 
 class IdentityOperator(LinearOperator):
@@ -477,7 +507,19 @@ def cg(
     conv_test_iters=25,
 ):
     """Conjugate gradient. Returns (x, iters), reference semantics:
-    absolute ||r|| < tol tested every conv_test_iters iterations."""
+    absolute ||r|| < tol tested every conv_test_iters iterations.
+
+    Which calls compile once: a banded float32 matrix without ``M`` on a
+    TPU (the fused kernels); any other ``csr_array`` without ``M``
+    (``jit_cg_general``); and, with or without ``M``, every call whose
+    operator and preconditioner say what they hold (a matrix, or a
+    ``LinearOperator(shape, apply=..., operands=...)``): ``jit_pcg``, whose
+    arguments are A's operands, M's operands, ``b``, the start, ``tol`` and
+    ``maxiter``, so that a later call of the same structure and shapes,
+    whatever the values, traces and compiles nothing. A closure
+    (``LinearOperator(shape, matvec=f)``) on either side still compiles
+    its loop in every call (:func:`_cg_device_loop`), and a ``callback``
+    runs the host loop."""
     assert atol is None, "atol is not supported."
     b = asjnp(b)
     n = b.shape[0]
@@ -502,6 +544,11 @@ def cg(
             _solve_event("cg", n, out[1], "device")
             return out
     M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    if callback is None:
+        out = _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters)
+        if out is not None:
+            _solve_event("cg", n, out[1], "device")
+            return out
     x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
 
     if callback is not None:
@@ -509,6 +556,7 @@ def cg(
         _solve_event("cg", n, out[1], "host")
         return out
 
+    # a closure on either side: the loop of this call
     r = b - A.matvec(x)
     try:
         # warm the preconditioner EAGERLY once: layout detection
@@ -733,9 +781,11 @@ def _cg_while(matvec, precond, b, x, r, tol2, maxiter, conv_test_iters, tap):
 def _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters):
     """Whole-solve lax.while_loop: scalars stay on device, one final sync.
     The loop closes over ``A`` and ``M``, so each call traces and compiles
-    it anew with whatever they hold as constants: the path of a
-    preconditioned solve and of an operator that is no matrix (a
-    ``csr_array`` without ``M`` runs :func:`_cg_general`).
+    it anew with whatever they hold as constants: the path of a solve
+    whose operator or preconditioner is a closure (``LinearOperator(shape,
+    matvec=f)``). A ``csr_array`` without ``M`` runs :func:`_cg_general`,
+    and operators that declare their operands run :func:`_pcg`: those
+    compile once.
 
     With telemetry enabled, each iteration taps (iter, ||r||^2) out to the
     recorder through ``jax.debug.callback`` — the loop stays one compiled
@@ -793,6 +843,22 @@ _cg_general_program = jax.jit(
 )
 
 
+def _matrix_form(op, dtype):
+    """``(kind, arrays, meta)`` of the layout a wrapped matrix multiplies
+    ``dtype`` vectors through (``csr_array._spmv_form``: built and committed
+    to the device on its first use), or None: an operator that is no plain
+    ``csr_array`` wrapper (a closure, a composite, one wrapped for fault
+    injection), an integer product, the packed Pallas DIA layout."""
+    csr = getattr(op, "A", None)
+    if (type(op) is not _SparseMatrixLinearOperator
+            or not hasattr(csr, "_spmv_form")
+            or not jnp.issubdtype(jnp.result_type(csr.dtype, dtype),
+                                  jnp.inexact)):
+        return None
+    form = csr._spmv_form(dtype)
+    return None if form[0] == "dia+" else form
+
+
 def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
     """Unpreconditioned CG on a matrix through the compiled general program
     (the trace names it ``jit_cg_general``): ``(x, iters)``, or None where
@@ -806,15 +872,10 @@ def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
     the first of a pattern traces or compiles."""
     from .utils import in_trace
 
-    csr = getattr(A, "A", None)
-    if (type(A) is not _SparseMatrixLinearOperator
-            or not hasattr(csr, "_spmv_form") or b.ndim != 1 or in_trace()
-            or not jnp.issubdtype(jnp.result_type(csr.dtype, b.dtype),
-                                  jnp.inexact)):
+    form = None if b.ndim != 1 or in_trace() else _matrix_form(A, b.dtype)
+    if form is None:
         return None
-    kind, arrays, meta = csr._spmv_form(b.dtype)
-    if kind == "dia+":
-        return None
+    kind, arrays, meta = form
     tapped = _iter_tapping()
     # One `cg.solve` span a call, with the fields the fused path's has:
     # `cg.dispatch` is the program's call until it returns (asynchronous:
@@ -843,6 +904,144 @@ def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
         solve.annotate(iters=iters, dispatch_s=round(dispatch_s, 9),
                        fetch_s=round(sp.dur_s or 0.0, 9))
     if tapped:
+        _effects_barrier()
+    return x, iters
+
+
+_CG_PRECOND_TRACES = _metrics.counter(
+    "cg.precond.traces",
+    help="traces of the compiled CG over declared operators (linalg._pcg, "
+    "the program jit_pcg): one per program built, none for a call that "
+    "reuses one",
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class _FormApply:
+    """``apply`` of a matrix: ``A @ v`` through one layout
+    (``csr.form_matvec``), the layout's arrays the operands; equal by value
+    for two matrices of one layout kind and geometry."""
+
+    kind: str
+    meta: object
+
+    def __call__(self, arrays, v):
+        from .csr import form_matvec
+
+        return form_matvec(self.kind, self.meta, arrays, v)
+
+
+def _identity_apply(operands, v):
+    return v
+
+
+def _declared(op, dtype):
+    """``(apply, operands)`` of an operator that says what it holds: a
+    ``LinearOperator`` built with ``apply``/``operands``, the identity, a
+    matrix through its layout (:func:`_matrix_form`: nothing is left to
+    warm inside the program). None for a closure, a composite, an operator
+    wrapped for fault injection."""
+    if type(op) is IdentityOperator:
+        return _identity_apply, ()
+    if getattr(op, "apply", None) is not None:
+        return op.apply, op.operands
+    form = _matrix_form(op, dtype)
+    if form is None:
+        return None
+    kind, arrays, meta = form
+    return _FormApply(kind, meta), arrays
+
+
+def _pcg(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply, m_apply,
+         conv_test_iters, tapped):
+    """Whole-solve preconditioned CG over declared operators: A's operands,
+    M's operands, ``b``, the start ``x0``, ``tol`` and ``maxiter`` all
+    arguments, only structure static (the two ``apply`` functions, the
+    operands' treedefs and shapes, the test cadence), so nothing an
+    operator holds is a constant of the program. The start is always an
+    argument and its residual is computed here, so that a solve from a
+    start runs the program of a solve from zero (whose residual,
+    ``b - A 0``, is ``b`` to the bit). Same recurrence and stopping rule as
+    the closure loop (:func:`_cg_while`)."""
+    _CG_PRECOND_TRACES.inc()
+    matvec = functools.partial(a_apply, a_operands)
+    precond = functools.partial(m_apply, m_operands)
+    r = b - matvec(x0)
+    tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
+    tap = functools.partial(_iter_tap, "cg", "device") if tapped else None
+    return _cg_while(
+        matvec, precond, b, x0, r, tol2, maxiter, conv_test_iters, tap
+    )
+
+
+_pcg.__name__ = _pcg.__qualname__ = "pcg"
+_pcg_program = jax.jit(
+    _pcg,
+    static_argnames=("a_apply", "m_apply", "conv_test_iters", "tapped"),
+)
+
+
+def _pcg_call(A, M, b, x0, tol, maxiter, conv_test_iters):
+    """``(args, static)`` of the compiled program for this solve, so that
+    ``_pcg_program(*args, **static)`` runs it, or None where either side is
+    a closure, or under an outer trace."""
+    from .utils import in_trace
+
+    if b.ndim != 1 or in_trace():
+        return None
+    a = _declared(A, b.dtype)
+    m = a and _declared(M, b.dtype)
+    if not m:
+        return None
+    args = (a[1], m[1], b, jnp.zeros_like(b) if x0 is None else asjnp(x0),
+            tol, min(int(maxiter), np.iinfo(np.int32).max))
+    return args, dict(a_apply=a[0], m_apply=m[0],
+                      conv_test_iters=int(conv_test_iters),
+                      tapped=_iter_tapping())
+
+
+def _pcg_compiled(A, b, M=None, conv_test_iters=25):
+    """The compiled program ``cg(A, b, M=M)`` runs (``jax.stages.Compiled``:
+    its HLO text with every op's ``named_scope``, its memory analysis), or
+    None where that call takes another path. It is jit's own: after a solve
+    of the same structure this traces and compiles nothing. For tools that
+    read a device trace against the program (the benchmark's per-level
+    shares)."""
+    A = make_linear_operator(A)
+    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    call = _pcg_call(A, M, asjnp(b), None, 1e-8, 1, conv_test_iters)
+    return call and _pcg_program.lower(*call[0], **call[1]).compile()
+
+
+def _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters):
+    """CG through the compiled program over declared operators (the trace
+    names it ``jit_pcg``): ``(x, iters)``, or None where either side is a
+    closure, or under an outer trace, and the closure loop takes the solve.
+
+    The program is jit's, found again by the two ``apply`` functions and
+    the operands' structure, so no call after the first of a structure
+    traces or compiles: another ``b``, ``x0``, ``tol``, ``maxiter``, other
+    values in the operands, another operator object of the same shapes."""
+    call = _pcg_call(A, M, b, x0, tol, maxiter, conv_test_iters)
+    if call is None:
+        return None
+    args, static = call
+    fields = ({"precond": "none"} if type(M) is IdentityOperator
+              else {"precond": "declared", **getattr(M, "describe", {})})
+    # One `cg.solve` span a call, with the fields the fused and the general
+    # paths' have: `cg.dispatch` is the program's call until it returns
+    # (asynchronous: the host's part, and on a structure's first call the
+    # trace and the compile), `cg.iters_fetch` the wait for the iteration
+    # count, the solve's one fence.
+    with telemetry.span("cg.solve", path="device", **fields) as solve:
+        with telemetry.span("cg.dispatch", emit=False) as sp:
+            x, iters = _pcg_program(*args, **static)
+        dispatch_s = sp.dur_s or 0.0
+        with telemetry.span("cg.iters_fetch", emit=False) as sp:
+            iters = host_int(iters)
+        solve.annotate(iters=iters, dispatch_s=round(dispatch_s, 9),
+                       fetch_s=round(sp.dur_s or 0.0, 9))
+    if static["tapped"]:
         _effects_barrier()
     return x, iters
 

@@ -19,10 +19,13 @@ a <=9-point stencil, so nothing needs a general sparse format at all —
   TPU has no fast scatter;
 * the weighted-Jacobi omega power iteration is one jitted ``fori_loop``.
 
-The whole V-cycle is traceable, so ``linalg.cg(A, b, M=vcycle)`` inlines
-hierarchy application into the compiled while_loop — one XLA program per
-solve, one host sync per convergence test, zero host round-trips per
-iteration.
+The whole V-cycle is traceable, and :func:`make_vcycle` and
+:func:`grid_operator` return operators that DECLARE what they hold (the
+hierarchy's planes and weights as ``operands``, the level sizes static), so
+``linalg.cg(A, b, M=vcycle)`` runs ONE compiled program, ``jit_pcg``, with
+the hierarchy as its arguments: the next solve, another right-hand side,
+another hierarchy of the same sizes compile nothing. Inside it each level's
+ops stand under ``jax.named_scope("gmg.l<k>")``.
 
 Exactness: ``galerkin_stencil`` equals the explicit R @ A @ P product and
 ``prolong_grid``/``restrict_grid`` equal the explicit P/R SpMVs
@@ -31,11 +34,14 @@ Exactness: ``galerkin_stencil`` equals the explicit R @ A @ P product and
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .. import telemetry
 
 __all__ = [
     "poisson_stencil",
@@ -44,6 +50,7 @@ __all__ = [
     "prolong_grid",
     "galerkin_stencil",
     "build_hierarchy",
+    "grid_operator",
     "make_vcycle",
     "shard_hierarchy_grid",
 ]
@@ -141,7 +148,13 @@ def galerkin_stencil(planes: dict, fn: int, cn: int, gridop: str) -> dict:
     Equal to the explicit R @ A @ P SpGEMM product (oracle-tested); costs
     9 grid applies instead of two unstructured SpGEMMs + sorts.
     """
-    ii, jj = np.meshgrid(np.arange(cn), np.arange(cn), indexing="ij")
+    # the combs and the selections are made on the device from index
+    # vectors: as numpy constants they were 9 combs and 18 index planes of
+    # cn^2 entries in the program's text, which the compiler then folded
+    # the whole probe pipeline over (3.8 GB of HLO and 105 s a build at
+    # n = 4480 on a TPU v5e, too large for the compile cache to keep)
+    ii = jnp.arange(cn)[:, None]
+    jj = jnp.arange(cn)[None, :]
     dtype = next(iter(planes.values())).dtype
 
     def T(comb):
@@ -149,25 +162,24 @@ def galerkin_stencil(planes: dict, fn: int, cn: int, gridop: str) -> dict:
             stencil_apply(planes, prolong_grid(comb, fn, cn, gridop)), cn, gridop
         )
 
-    probes = {}
-    for a in range(3):
-        for b in range(3):
-            comb = ((ii % 3 == a) & (jj % 3 == b)).astype(dtype)
-            probes[(a, b)] = T(jnp.asarray(comb))
+    probes = {
+        (a, b): T(((ii % 3 == a) & (jj % 3 == b)).astype(dtype))
+        for a in range(3) for b in range(3)
+    }
 
     out = {}
     for di in (-1, 0, 1):
         for dj in (-1, 0, 1):
-            # plane[i,j] = probes[(i+di)%3, (j+dj)%3][i,j]
-            sel = jnp.stack(
-                [probes[(a, b)] for a in range(3) for b in range(3)]
-            ).reshape(3, 3, cn, cn)
-            plane = sel[(ii + di) % 3, (jj + dj) % 3, ii, jj]
             if gridop == "injection" and (di, dj) != (0, 0):
                 # injection Galerkin on a <=1-reach fine stencil couples
                 # only even fine points two apart — identically zero
                 # off-diagonal; drop the planes rather than carry zeros
                 continue
+            # plane[i,j] = probes[(i+di)%3, (j+dj)%3][i,j]
+            plane = jnp.zeros((cn, cn), dtype)
+            for (a, b), t in probes.items():
+                mine = ((ii + di) % 3 == a) & ((jj + dj) % 3 == b)
+                plane = jnp.where(mine, t, plane)
             out[(di, dj)] = plane
     return out
 
@@ -211,14 +223,26 @@ def build_hierarchy(
     """
     st = poisson_stencil(n, dtype) if planes is None else planes
     out = []
-    for lvl in range(levels):
-        D_inv = 1.0 / st[(0, 0)]
-        w = jnp.asarray(omega / _rho(st, D_inv, n), dtype) * D_inv
-        out.append((st, w, n))
-        if lvl < levels - 1:
-            cn = n // 2
-            st = galerkin_stencil(st, n, cn, gridop)
-            n = cn
+    rhos = []
+    # one span a build: what a first solve pays before its program
+    # (the comb probes' and the power iterations' compiles are in it)
+    with telemetry.span("gmg.build_hierarchy", levels=int(levels)) as sp:
+        for lvl in range(levels):
+            D_inv = 1.0 / st[(0, 0)]
+            rhos.append(_rho(st, D_inv, n))
+            w = jnp.asarray(omega / rhos[-1], dtype) * D_inv
+            out.append((st, w, n))
+            if lvl < levels - 1:
+                cn = n // 2
+                st = galerkin_stencil(st, n, cn, gridop)
+                n = cn
+        sp.set_sync(out)
+        sp.annotate(
+            sizes=[n for _, _, n in out], rho=[round(r, 6) for r in rhos],
+            bytes=sum(int(a.size) * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(out)
+                      if hasattr(a, "dtype")),
+        )
     return out
 
 
@@ -272,26 +296,71 @@ def shard_hierarchy_grid(hierarchy, mesh, axis: str = "shards",
     return out, vec_sharding
 
 
+@dataclasses.dataclass(frozen=True)
+class _Cycle:
+    """``apply`` of the V-cycle operator: equal by value for two
+    hierarchies of the same level sizes, offsets and grid operator, which
+    is what lets ``linalg.cg`` find its compiled program again."""
+
+    static: tuple  # per level (n, offsets)
+    gridop: str
+
+    def level(self, arrays, r, lvl):
+        (planes, w), (n, offsets) = arrays[lvl], self.static[lvl]
+        st = dict(zip(offsets, planes))
+        with jax.named_scope(f"gmg.l{lvl}"):
+            x = w * r
+            if lvl == len(self.static) - 1:
+                return x
+            cn = self.static[lvl + 1][0]
+            coarse_r = restrict_grid(r - stencil_apply(st, x), cn, self.gridop)
+        coarse_x = self.level(arrays, coarse_r, lvl + 1)
+        with jax.named_scope(f"gmg.l{lvl}"):
+            x = x + prolong_grid(coarse_x, n, cn, self.gridop)
+            return x + w * (r - stencil_apply(st, x))
+
+    def __call__(self, arrays, r_flat):
+        n0 = self.static[0][0]
+        return self.level(arrays, r_flat.reshape(n0, n0), 0).reshape(-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class _GridApply:
+    """``apply`` of one level's operator on flat vectors."""
+
+    n: int
+    offsets: tuple
+
+    def __call__(self, planes, v):
+        st = dict(zip(self.offsets, planes))
+        return stencil_apply(st, v.reshape(self.n, self.n)).reshape(-1)
+
+
+def _declare(apply, operands, n: int, **describe):
+    from ..linalg import LinearOperator
+
+    dtype = jax.tree_util.tree_leaves(operands)[0].dtype
+    return LinearOperator((n * n, n * n), dtype=np.dtype(dtype), apply=apply,
+                          operands=operands, describe=describe)
+
+
+def grid_operator(hierarchy, lvl: int = 0):
+    """Level ``lvl``'s operator as a ``LinearOperator`` on flat [N] vectors
+    that declares its planes: the ``A`` of ``linalg.cg(A, b, M=vcycle)``."""
+    st, _, n = hierarchy[lvl]
+    return _declare(_GridApply(n, tuple(st.keys())), tuple(st.values()), n)
+
+
 def make_vcycle(hierarchy, gridop: str = "linear"):
-    """One V-cycle as a traceable [N] -> [N] map (flat vectors, the
-    LinearOperator/M contract of ``linalg.cg``): pre-smooth, restrict the
+    """One V-cycle as a ``LinearOperator`` on flat [N] vectors (the ``M`` of
+    ``linalg.cg``; also callable, ``vc(r)``): pre-smooth, restrict the
     residual, recurse, prolong-correct, post-smooth; the coarsest level
-    applies the smoother once (examples/gmg.py:GMG._cycle)."""
-
-    def cycle_2d(r, lvl):
-        st, w, n = hierarchy[lvl]
-        if lvl == len(hierarchy) - 1:
-            return w * r
-        x = w * r
-        fine_r = r - stencil_apply(st, x)
-        cn = hierarchy[lvl + 1][2]
-        coarse_x = cycle_2d(restrict_grid(fine_r, cn, gridop), lvl + 1)
-        x = x + prolong_grid(coarse_x, n, cn, gridop)
-        return x + w * (r - stencil_apply(st, x))
-
-    n0 = hierarchy[0][2]
-
-    def cycle(r_flat):
-        return cycle_2d(r_flat.reshape(n0, n0), 0).reshape(-1)
-
-    return cycle
+    applies the smoother once (examples/gmg.py:GMG._cycle). It declares the
+    hierarchy's planes and weights as its operands, with the level sizes
+    and ``gridop`` static."""
+    # per level the planes in the stencil's own order, then the weight; the
+    # grid size and the planes' offsets are the static rest
+    arrays = tuple((tuple(st.values()), w) for st, w, _ in hierarchy)
+    static = tuple((n, tuple(st.keys())) for st, _, n in hierarchy)
+    return _declare(_Cycle(static, gridop), arrays, hierarchy[0][2],
+                    precond="gmg_grid", levels=len(hierarchy))

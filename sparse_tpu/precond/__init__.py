@@ -134,9 +134,28 @@ def make_M(A, kind: str = "jacobi", solver: str = "cg",
         raise ValueError(f"precond kind {kind!r} resolves to none here")
     bmv = BatchedCSR(pattern, values).matvec
     Mvec = fac(values, bmv)
+    n = pattern.shape[0]
+    from ..resilience import faults as _faults
+
+    if resolved == "jacobi" and not (_faults.ACTIVE and _faults.targets("precond")):
+        # point-Jacobi is a scaling by one array, Mvec(1): an operator
+        # that declares it, so that linalg.cg(A, b, M=M) runs its compiled
+        # program (jit_pcg) with the array as an argument. The other kinds
+        # hold factors and maps inside their closures and stay closures.
+        import jax.numpy as jnp
+
+        return LinearOperator(
+            (n, n), dtype=np.dtype(values.dtype), apply=_scale,
+            operands=(Mvec(jnp.ones((1, n), values.dtype))[0],),
+            describe={"precond": "jacobi"},
+        )
 
     def mv(x):
         return Mvec(asjnp(x)[None, :])[0]
 
-    n = pattern.shape[0]
     return LinearOperator((n, n), matvec=mv, dtype=np.dtype(values.dtype))
+
+
+def _scale(operands, v):
+    """``apply`` of a declared diagonal preconditioner."""
+    return v * operands[0]
