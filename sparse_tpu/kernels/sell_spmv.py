@@ -53,37 +53,56 @@ def _round_up(v: int, m: int) -> int:
 # is pure pad storage.
 ROW_ALIGN = 8
 
-# Past WIDE_PERIOD rows a slab's row count is moved into the band WIDE_BAND
-# of remainders mod WIDE_PERIOD (:func:`slab_rows`). What the numbers are:
-# an observed property of the TPU compiler's gather lowering (libtpu
-# 0.0.34, v5e), not of the format. A gather of ``[R, lanes]`` rows at 64
-# lanes or more is lowered on a step of 256 rows or of 128, chosen from R;
-# the narrow step runs at 9.9 ns a row where the wide one runs at 4.0
-# (PERF.md sections 5 and 7). R = 1024 b is narrow, so is a stretch below
-# each multiple of 1024 whose start wanders (776 to 904 by b); every
-# remainder in [8, 768] compiled to the wide step. Sufficient, not
-# necessary. Held by ``tests/test_chip_compile.py``
-# (``test_gather_takes_the_wide_step_at_the_pack_row_counts`` and its
-# neighbours), which fail once the compiler stops telling the two apart.
-# If the numbers stop being true they cost the pad rows and nothing else:
-# at most 264 zero rows a slab. At or below 1024 rows a gather is
-# microseconds on either step, and the count stays what ROW_ALIGN gives.
+# Past WIDE_PERIOD rows a slab's row count is a multiple of WINDOW_ROWS inside
+# the band WIDE_BAND of remainders mod WIDE_PERIOD (:func:`slab_rows`). The
+# numbers are observed properties of one TPU compiler (libtpu 0.0.34, v5e),
+# not of the format, and the row count buys two things with them:
+#
+# * the gather's step. A gather of ``[R, lanes]`` rows at 64 lanes or more is
+#   lowered on a step of 256 rows or of 128, chosen from R; the narrow step
+#   runs at 9.9 ns a row where the wide one runs at 4.0 (PERF.md sections 5
+#   and 7). R = 1024 b is narrow, so is a stretch below each multiple of
+#   1024 whose start wanders (776 to 904 by b); every remainder in [8, 768]
+#   compiled to the wide step. Sufficient, not necessary.
+# * the multiply-sum's window. A slab's product is written into its rows of
+#   the whole product in place (a loop fusion rooted in a
+#   dynamic-update-slice), and for such a fusion the compiler takes as the
+#   window of a trip an exact divisor of the slab's count of 8-row tiles,
+#   R / 8, the largest its fast memory holds beside the slab's operands
+#   (152 tiles at 7 operands, 89 at 11). A count with no such divisor
+#   (8 x a prime: 57,944; 8 x 179 x 241: 345,112) is left the window of one
+#   tile, 4 KB an operand a trip, and ran at 2.8 and 3.4 ns a slot row on
+#   the chip where its neighbours ran at 1.3 to 1.5. A multiple of
+#   WINDOW_ROWS has every power of two up to WINDOW_ROWS / 8 tiles to
+#   offer; on the chip a window of 32 tiles ran as fast as one of 152, one
+#   of 8 three percent slower (PERF.md section 6, PR 41).
+#
+# Held by ``tests/test_chip_compile.py`` (the cases on the gather's step and
+# on the slab fusions' windows, each with its other side), which fail once
+# the compiler stops telling the counts apart. If the numbers stop being
+# true they cost the pad rows and nothing else: at most 511 zero rows a
+# slab. At or below 1024 rows a gather and a multiply-sum are microseconds
+# either way, and the count stays what ROW_ALIGN gives.
 WIDE_PERIOD = 1024
 WIDE_BAND = (8, 768)
+WINDOW_ROWS = 256
 
 
 def slab_rows(n: int) -> int:
     """Rows a slab (or a space made of slabs) of ``n`` real rows is stored
     with: ``n`` rounded up to ``ROW_ALIGN``, and past ``WIDE_PERIOD`` rows
-    moved up into ``WIDE_BAND`` mod ``WIDE_PERIOD``. A function of the row
-    count alone: the pack is a vault artifact and must not depend on where
-    it was built. Idempotent."""
+    to the next multiple of ``WINDOW_ROWS`` whose remainder mod
+    ``WIDE_PERIOD`` is in ``WIDE_BAND``. A function of the row count alone:
+    the pack is a vault artifact and must not depend on where it was built.
+    Idempotent."""
     R = _round_up(n, ROW_ALIGN)
-    lo, hi = WIDE_BAND
-    rem = R % WIDE_PERIOD
-    if R <= WIDE_PERIOD or lo <= rem <= hi:
+    if R <= WIDE_PERIOD:
         return R
-    return R - rem + lo + (WIDE_PERIOD if rem > hi else 0)
+    lo, hi = WIDE_BAND
+    R = _round_up(R, WINDOW_ROWS)
+    while not lo <= R % WIDE_PERIOD <= hi:  # twice at most
+        R += WINDOW_ROWS
+    return R
 
 
 class SellPlan:
@@ -91,8 +110,9 @@ class SellPlan:
 
     ``slab_meta`` is a tuple of ``(K, rows, pad_rows)`` per slab —
     ``rows`` is what :func:`slab_rows` gives the slab's real rows (the
-    alignment to ``ROW_ALIGN`` and, past 1024 rows, the move into the
-    gather's wide band), ``pad_rows`` counts the zero rows that added.
+    alignment to ``ROW_ALIGN`` and, past 1024 rows, to a multiple of
+    ``WINDOW_ROWS`` in the gather's wide band), ``pad_rows`` counts the
+    zero rows that added.
     """
 
     __slots__ = ("m", "n", "C", "sigma", "slab_meta", "zero_rows", "nnz")

@@ -92,7 +92,7 @@ def _sell_settings() -> tuple:
         # the rule that gives a slab its row count (`slab_rows`): a pack
         # written under another rule has other slabs
         "rows", sell_spmv.ROW_ALIGN, sell_spmv.WIDE_PERIOD,
-        *sell_spmv.WIDE_BAND,
+        *sell_spmv.WIDE_BAND, sell_spmv.WINDOW_ROWS,
     )
 
 

@@ -13,6 +13,7 @@ process from ``ShapeDtypeStruct``s carrying a described device's sharding,
 written for a described chip cannot be read back without one).
 """
 
+import json
 import os
 import re
 
@@ -305,10 +306,11 @@ def test_gather_bucket_program_permutes_rows_outside_the_loop(
     hlo = _bucket_program_text(run, pattern, B, one_chip)
     pack = pattern.sell_pack()
     plan = pack.plan
-    # the pack's order is 200 rows longer than the caller's (PR 39: the
-    # 7-slot slab's pad rows), so `enter`'s results are told from `leave`'s
+    # the pack's order is 704 rows longer than the caller's (the pad rows
+    # of the three slabs past 1024 rows and the space's trailing ones), so
+    # `enter`'s results are told from `leave`'s
     space = pack.own_order().rows.shape[0]
-    assert space == n + 200
+    assert space == n + 704
     assert all(r not in (n, space) for _k, r, _p in plan.slab_meta)
     gathers = _gather_fusions(hlo)
     whole = [ln for ln in gathers
@@ -327,9 +329,10 @@ def test_gather_bucket_program_permutes_rows_outside_the_loop(
 # rows on a step of 256 rows or of 128, chosen from R; on the chip the narrow
 # one runs at 9.9 ns a row and the wide one at 4.0 (PERF.md section 5).
 # `kernels.sell_spmv.slab_rows` moves a slab's row count into a band of
-# remainders mod 1024 where every count tried got the wide step. These cases
-# hold that observation: once the compiler stops telling the two apart, or
-# tells them apart elsewhere, they fail, and the pad rows are dead weight.
+# remainders mod 1024 where every count tried got the wide step (since PR 41
+# onto a multiple of 256 rows in it: the slab fusions' windows, below). These
+# cases hold that observation: once the compiler stops telling the two apart,
+# or tells them apart elsewhere, they fail, and the pad rows are dead weight.
 # ---------------------------------------------------------------------------
 def _bucket_program_text(run, pattern, B, one_chip) -> str:
     n = pattern.shape[0]
@@ -356,15 +359,15 @@ def _fusion_rows(line: str) -> int:
 
 
 # what `sell_pack` gives the cell's pattern class (`fem_heat_data`,
-# `pattern_seed` 3200000103): side 960 (`fem_heat_served_closed`; the slab
-# of 229,344 real rows is the one the rule moved), its space of 921,640
+# `pattern_seed` 3200000103): side 960 (`fem_heat_served_closed`; real rows
+# 128, 59,560, 229,512, 345,112, 229,344, 57,944), its space of 922,880
 # rows, and side 1108 (thermal2's rows) with its space
-CELL_SLABS = (128, 59_560, 229_512, 345_112, 229_384, 57_944, 921_640)
-THERMAL2_SLABS = (80, 78_256, 307_208, 459_784, 305_160, 77_832, 1_228_320)
-# a dozen more, over the multiples of 1024 and the band's two ends
+CELL_SLABS = (128, 59_648, 229_632, 345_344, 229_632, 58_112, 922_880)
+THERMAL2_SLABS = (80, 78_336, 307_456, 460_032, 305_408, 78_080, 1_229_568)
+# a dozen more, over the multiples of 1024 and the band's multiples of 256
 BAND_ROWS = tuple(1024 * b + o for b, o in (
-    (1, 8), (1, 768), (2, 400), (56, 8), (56, 768), (75, 768), (128, 768),
-    (223, 8), (297, 768), (512, 264), (899, 768), (1200, 8)))
+    (1, 256), (1, 768), (2, 512), (56, 256), (56, 768), (75, 768), (128, 768),
+    (223, 256), (297, 768), (512, 512), (899, 768), (1200, 256)))
 
 
 @pytest.mark.parametrize(
@@ -390,9 +393,9 @@ def _standalone_gather(one_chip, rows, lanes=64, length=921_600):
 
 
 @pytest.mark.parametrize("rows, moved_to", [
-    (229_344, 229_384),   # the cell's slab: 2.275 ms a gather on the chip
-    (921_600, 921_608),   # a multiple of 1024
-    (304_920, 305_160),   # thermal2's rows, 792 past a multiple
+    (229_344, 229_632),   # the cell's slab: 2.275 ms a gather on the chip
+    (921_600, 921_856),   # a multiple of 1024
+    (304_920, 305_408),   # thermal2's rows, 792 past a multiple
 ])
 def test_gather_takes_the_narrow_step_off_the_band(one_chip, rows, moved_to):
     """The other side: the row counts ROW_ALIGN alone gave are on the
@@ -405,35 +408,50 @@ def test_gather_takes_the_narrow_step_off_the_band(one_chip, rows, moved_to):
     assert _gather_step(fusion) == 128, fusion
 
 
-def test_gather_bucket_program_takes_the_wide_step_in_its_loop(
-        one_chip, monkeypatch):
-    """One whole bucket program at 64 lanes on an FEM pattern of 360,000
-    rows (side 600; under 270,000 rows the loop's vectors fit the chip's
-    fast memory and the compiler lowers the gathers another way, with no
-    step to read). Under ROW_ALIGN alone its 7-slot slab has 134,072 rows
-    and sits on the 128-row step; `slab_rows` stores it with 134,152, the
-    6-slot slab's 89,984 with 90,120, and the space gets 240 trailing pad
-    rows: every gather fusion of more than 1024 rows in the while body is
-    on the 256-row step, and so are the two permutations into the pack's
-    order."""
+_SIDE_600 = {}  # the rule's WINDOW_ROWS it was built under -> (run, pack, hlo)
+
+
+def _side_600_program(one_chip, monkeypatch):
+    """`(run, pack, hlo)`: one whole bucket program at 64 lanes on an FEM
+    pattern of 360,000 rows (side 600; under 270,000 rows the loop's
+    vectors fit the chip's fast memory and the compiler lowers the gathers
+    another way, with no step to read), compiled for the described chip
+    under `slab_rows` as it is at the call; once a WINDOW_ROWS."""
     from sparse_tpu.batch import service
+    from sparse_tpu.kernels import sell_spmv
 
     from .utils.spd import fem_heat_data
 
+    if sell_spmv.WINDOW_ROWS in _SIDE_600:
+        return _SIDE_600[sell_spmv.WINDOW_ROWS]
     monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
     P = fem_heat_data(600, 3, clients=1)["pattern"]
-    n, B = P.shape[0], 64
+    B = 64
     ses = service.SolveSession("cg", batch_max=B, warm_start=False)
     pattern = ses.pattern_of(P)
     run = ses._build_program(pattern, B, np.dtype(np.float32))
-    pack = pattern.sell_pack()
-    assert pack.plan.slab_meta == (
-        (4, 224, 0), (5, 23_664, 0), (6, 90_120, 136), (7, 134_152, 80),
-        (8, 89_416, 0), (9, 22_640, 0))
+    built = run, pattern.sell_pack(), _bucket_program_text(run, pattern, B, one_chip)
+    return _SIDE_600.setdefault(sell_spmv.WINDOW_ROWS, built)
+
+
+SIDE_600_SLABS = ((4, 224, 0), (5, 23_808, 144), (6, 90_368, 384),
+                  (7, 134_400, 328), (8, 89_600, 184), (9, 22_784, 144))
+
+
+def test_gather_bucket_program_takes_the_wide_step_in_its_loop(
+        one_chip, monkeypatch):
+    """Under ROW_ALIGN alone the side-600 pattern's 7-slot slab has 134,072
+    rows and sits on the 128-row step; `slab_rows` stores it with 134,400,
+    every other slab past 1024 rows with a multiple of 256 rows in the band
+    too, and the space gets 32 trailing pad rows: every gather fusion of
+    more than 1024 rows in the while body is on the 256-row step, and so
+    are the two permutations into the pack's order."""
+    run, pack, hlo = _side_600_program(one_chip, monkeypatch)
+    n, B = 360_000, 64
+    assert pack.plan.slab_meta == SIDE_600_SLABS
     space = pack.own_order().rows.shape[0]
-    assert (n, space) == (360_000, 360_456)
-    assert run.pad_rows == pack.own_order().pad_rows == 216 + 240
-    hlo = _bucket_program_text(run, pattern, B, one_chip)
+    assert space == 361_216
+    assert run.pad_rows == pack.own_order().pad_rows == 1184 + 32
     gathers = _gather_fusions(hlo)
     body = [ln for ln in gathers if "/while/body/" in ln]
     assert len(body) == sum(k for k, _r, _p in pack.plan.slab_meta)
@@ -444,6 +462,88 @@ def test_gather_bucket_program_takes_the_wide_step_in_its_loop(
     enter = [ln for ln in gathers if f"= f32[{space},{B}]" in ln]
     assert len(enter) == 2
     assert {_gather_step(ln) for ln in enter} == {256}
+
+
+# ---------------------------------------------------------------------------
+# the slab fusions' windows (PR 41). A slab's multiply-sum is a `kLoop`
+# fusion rooted in a `dynamic-update-slice` into the whole `f32[lanes, space]`
+# product, and for such a fusion the TPU compiler takes as the window of a
+# trip an exact divisor of the slab's count of 8-row tiles, R / 8: the
+# largest one its fast memory holds beside the slab's operands. A count with
+# no such divisor is left one tile a trip, and on the chip those fusions ran
+# at 2.8 and 3.4 ns a slot row where the windowed ones ran at 1.3 to 1.5
+# (PERF.md section 5). `slab_rows` gives a slab past 1024 rows a multiple of
+# 256 rows, whose tiles every power of two up to 32 divides. Both sides are
+# held here, as for the gather's step.
+# ---------------------------------------------------------------------------
+def _slab_windows(hlo: str, lanes: int) -> dict:
+    """`{rows: (window, trips)}` in 8-row tiles, of every `kLoop` fusion of
+    the program whose root writes an `f32[lanes, rows]` update into a larger
+    array by `dynamic-update-slice` (the slabs' multiply-sums: the while
+    body's and the first residual's are the same fusions twice)."""
+    computations = {
+        m.group(1): m.group(2) for m in re.finditer(
+            r"^%([\w.\-]+) \(.*?\{\n(.*?)^\}", hlo, re.S | re.M)}
+    out = {}
+    for ln in hlo.splitlines():
+        if "kind=kLoop" not in ln or " fusion(" not in ln:
+            continue
+        lines = computations[
+            re.search(r"calls=%([\w.\-]+)", ln).group(1)].splitlines()
+        (root,) = [l for l in lines if l.lstrip().startswith("ROOT ")]
+        operands = re.search(r" dynamic-update-slice\(([^)]*)\)", root)
+        if operands is None:
+            continue
+        update = operands.group(1).split(", ")[1]
+        (made,) = [l for l in lines
+                   if l.lstrip().removeprefix("ROOT ").startswith(update + " = ")]
+        shape = re.search(rf"= f32\[{lanes},(\d+)\]", made)
+        if shape is None:
+            continue
+        window = json.loads(re.search(
+            r"backend_config=(\{.*\})\s*$", ln).group(1))["window_config"]
+        got = (int(window["output_window_bounds"][0]),
+               int(window["iteration_bounds"][0]))
+        rows = int(shape.group(1))
+        assert out.setdefault(rows, got) == got, (rows, got, out)
+    return out
+
+
+def test_gather_bucket_program_windows_every_slab_fusion(one_chip, monkeypatch):
+    """At the rule's row counts every slab past 1024 rows of the side-600
+    program multiplies and sums over a window of WINDOW_ROWS or more (here
+    32 to 124 tiles), and window x trips is the slab."""
+    from sparse_tpu.kernels.sell_spmv import WINDOW_ROWS
+
+    _run, pack, hlo = _side_600_program(one_chip, monkeypatch)
+    assert pack.plan.slab_meta == SIDE_600_SLABS
+    windows = _slab_windows(hlo, 64)
+    large = [r for _k, r, _p in pack.plan.slab_meta if r > 1024]
+    assert len(large) == 5 and set(large) <= set(windows), windows
+    for rows in large:
+        window, trips = windows[rows]
+        assert window * trips * 8 == rows
+        assert window * 8 >= WINDOW_ROWS, (rows, window, trips)
+
+
+def test_a_slab_of_eight_times_a_prime_rows_is_left_one_tile_a_trip(
+        one_chip, monkeypatch):
+    """The other side: under PR 39's rule (the band alone, which is this
+    rule at a WINDOW_ROWS of ROW_ALIGN) the same pattern's 8-slot slab has
+    89,416 rows = 8 x 11,177, a prime, and its fusion is left a window of
+    one tile; the 7-slot slab beside it (134,152 = 8 x 41 x 409) gets 41. A
+    compiler that windows such a fusion some other way fails here, so that
+    the rule's pad rows do not outlive their reason unseen."""
+    from sparse_tpu.kernels import sell_spmv
+
+    monkeypatch.setattr(sell_spmv, "WINDOW_ROWS", sell_spmv.ROW_ALIGN)
+    _run, pack, hlo = _side_600_program(one_chip, monkeypatch)
+    assert pack.plan.slab_meta == (
+        (4, 224, 0), (5, 23_664, 0), (6, 90_120, 136), (7, 134_152, 80),
+        (8, 89_416, 0), (9, 22_640, 0))
+    windows = _slab_windows(hlo, 64)
+    assert windows[89_416] == (1, 11_177)
+    assert windows[134_152] == (41, 409)
 
 
 # ---------------------------------------------------------------------------

@@ -224,9 +224,12 @@ def test_sell_plan_dies_with_matrix(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the rule that gives a slab its row count (PR 39)
+# the rule that gives a slab its row count (PR 39: the gather's wide band;
+# PR 41: a multiple of WINDOW_ROWS inside it)
 # ---------------------------------------------------------------------------
 BAND_LO, BAND_HI = sell_spmv.WIDE_BAND
+WINDOW = sell_spmv.WINDOW_ROWS
+MAX_PAD = 2 * WINDOW - 1  # up to the next multiple, and over one off the band
 
 ROW_COUNTS = [
     0, 1, 7, 8, 9, 1000, 1017, 1024,                  # what ROW_ALIGN gives
@@ -237,6 +240,10 @@ ROW_COUNTS = [
     # thermal2's rows' three narrow slabs (PERF.md section 5)
     229_344, 229_512, 345_112, 921_600, 921_640, 77_680, 304_920, 307_144,
     459_584, 5_000_000,
+    # PR 41: about a multiple of WINDOW_ROWS, the most pad rows, and the
+    # cell's slabs whose count of 8-row tiles had no divisor to window by
+    1024 + WINDOW, 1024 + WINDOW + 1, 5 * 1024 + BAND_HI + 1, 57_944, 59_560,
+    89_416,
 ]
 
 
@@ -245,22 +252,46 @@ def test_slab_rows_is_aligned_close_and_in_the_band(n):
     R = sell_spmv.slab_rows(n)
     aligned = -(-n // sell_spmv.ROW_ALIGN) * sell_spmv.ROW_ALIGN
     assert R % sell_spmv.ROW_ALIGN == 0
-    assert n <= R < n + 272
+    assert n <= R <= n + MAX_PAD
     if aligned <= sell_spmv.WIDE_PERIOD:
         assert R == aligned  # a small slab keeps the count it had
     else:
+        # every power of two up to WINDOW_ROWS / 8 divides its 8-row tiles
+        assert R % WINDOW == 0
         assert BAND_LO <= R % sell_spmv.WIDE_PERIOD <= BAND_HI
         # the least such count
         assert R == next(
-            r for r in range(aligned, aligned + 272, sell_spmv.ROW_ALIGN)
+            r for r in range(-(-aligned // WINDOW) * WINDOW,
+                             aligned + MAX_PAD + 1, WINDOW)
             if BAND_LO <= r % sell_spmv.WIDE_PERIOD <= BAND_HI)
     assert sell_spmv.slab_rows(R) == R  # idempotent
 
 
+def test_slab_rows_is_a_function_of_the_row_count_alone():
+    """Every count up to a few thousand and a random draw of large ones:
+    never fewer rows than asked, never more than MAX_PAD more, monotone,
+    and the same on a second asking (the pack is a vault artifact)."""
+    rng = np.random.default_rng(41)
+    ns = np.concatenate([np.arange(0, 6000),
+                         rng.integers(6000, 5_000_000, size=4000)])
+    ns.sort()
+    Rs = np.array([sell_spmv.slab_rows(int(n)) for n in ns])
+    assert (Rs >= ns).all() and (Rs - ns <= MAX_PAD).all()
+    assert (np.diff(Rs) >= 0).all()
+    assert (Rs == [sell_spmv.slab_rows(int(R)) for R in Rs]).all()
+    big = Rs > sell_spmv.WIDE_PERIOD
+    assert (Rs[big] % WINDOW == 0).all()
+    assert (Rs[~big] - ns[~big] < sell_spmv.ROW_ALIGN).all()
+
+
 @pytest.mark.parametrize("n,want", [
-    (229_344, 229_384), (229_512, 229_512), (921_600, 921_608),
-    (77_680, 77_832), (304_920, 305_160), (307_144, 307_208),
-    (459_584, 459_784), (78_256, 78_256),
+    (229_344, 229_632), (229_512, 229_632), (921_600, 921_856),
+    (77_680, 78_080), (304_920, 305_408), (307_144, 307_456),
+    (459_584, 460_032), (78_256, 78_336),
+    # PR 41: the cell's two slabs whose tiles had no divisor (8 x 179 x 241,
+    # 8 x a prime), side 600's (8 x a prime), the cell's other slabs
+    (345_112, 345_344), (57_944, 58_112), (89_416, 89_600),
+    (59_560, 59_648), (1024, 1024), (1032, 1280),
 ])
 def test_slab_rows_at_the_cells_row_counts(n, want):
     assert sell_spmv.slab_rows(n) == want
@@ -269,7 +300,7 @@ def test_slab_rows_at_the_cells_row_counts(n, want):
 def three_widths():
     """600, 2040 and 504 rows of 2, 3 and 5 entries, shuffled: under
     ``C=8, sigma=0`` one slab a length, and the middle one's 2040 rows sit
-    8 short of 2048."""
+    8 short of 2048, a multiple of 256 rows off the band."""
     rng = np.random.default_rng(6)
     deg = rng.permutation(np.repeat((2, 3, 5), (600, 2040, 504)))
     m = deg.shape[0]
@@ -283,25 +314,25 @@ def test_a_slab_past_1024_rows_gets_pad_rows_and_multiplies_as_scipy():
     s = three_widths()
     plan, slabs, pos, srcs = sell_pack(
         s.indptr, s.indices, s.data, s.shape, C=8, sigma=0, with_srcs=True)
-    # 2040 rows -> 2056: the first count past them in the band; the two
-    # small slabs keep their rows
-    assert plan.slab_meta == ((2, 600, 0), (3, 2056, 16), (5, 504, 0))
-    assert plan.pad_rows == 16
+    # 2040 rows -> 2304: the first multiple of 256 past them in the band;
+    # the two small slabs keep their rows
+    assert plan.slab_meta == ((2, 600, 0), (3, 2304, 264), (5, 504, 0))
+    assert plan.pad_rows == 264
     (_i2, _v2), (it, vt), _ = slabs
-    assert it.shape == vt.shape == (3, 2056)
+    assert it.shape == vt.shape == (3, 2304)
     # the pad rows are ROW_ALIGN's own kind: index 0, value 0, no source
     assert not np.asarray(it)[:, 2040:].any()
     assert not np.asarray(vt)[:, 2040:].any()
     assert (np.asarray(srcs[1])[:, 2040:] == -1).all()
     pos = np.asarray(pos)
     assert len(np.unique(pos)) == s.shape[0]
-    assert not ((pos >= 600 + 2040) & (pos < 600 + 2056)).any()
+    assert not ((pos >= 600 + 2040) & (pos < 600 + 2304)).any()
     x = np.random.default_rng(1).standard_normal(s.shape[1])
     got = np.asarray(csr_spmv_sell(slabs, pos, x, plan.zero_rows))
     np.testing.assert_allclose(got, s @ x, rtol=1e-10, atol=1e-10)
     packed = np.asarray(csr_spmv_sell(slabs, None, x, plan.zero_rows))
-    assert packed.shape == (600 + 2056 + 504,)
-    assert not packed[600 + 2040:600 + 2056].any()  # a pad row comes out zero
+    assert packed.shape == (600 + 2304 + 504,)
+    assert not packed[600 + 2040:600 + 2304].any()  # a pad row comes out zero
     # ... and through the matrix's own prepared operator
     A = sparse_tpu.csr_array(s)
     A.prepare(mode="sell")
@@ -329,3 +360,9 @@ def test_the_vault_keys_carry_the_row_rule(monkeypatch):
     monkeypatch.undo()
     monkeypatch.setattr(sell_spmv, "WIDE_BAND", (8, 760))
     assert _codecs.sell_pattern_key(pattern) != mine[0]
+    monkeypatch.undo()
+    # ... and PR 39's, the band without the multiple of WINDOW_ROWS in it
+    monkeypatch.setattr(sell_spmv, "WINDOW_ROWS", sell_spmv.ROW_ALIGN)
+    assert _codecs.sell_pattern_key(pattern) != mine[0]
+    assert (_codecs.prepared_csr_key(s.indptr, s.indices, s.data, s.shape)
+            != mine[1])

@@ -134,7 +134,7 @@ def test_pattern_pack_span_carries_the_slabs_and_their_padding(tel):
     assert len(_pack_spans()) == 1
 
 
-PADDED_SIDE = 72  # 5184 rows; the 7-slot slab's 1856 rows are stored as 2056
+PADDED_SIDE = 72  # 5184 rows; the 7-slot slab's 1856 rows are stored as 2304
 
 
 def _one_step(d, batch_max):
@@ -152,7 +152,8 @@ def _one_step(d, batch_max):
 @pytest.mark.parametrize("batch_max", [8, 64])
 def test_a_slab_moved_into_the_wide_band_steps_as_the_plain_pack(
         tel, monkeypatch, batch_max):
-    """A pattern whose middle slab gets pad rows from `slab_rows` (PR 39),
+    """A pattern whose middle slabs get pad rows from `slab_rows` (PR 39:
+    into the gather's wide band; PR 41: onto a multiple of 256 rows in it),
     through the session's `cg` program in the pack's own order: the
     answers are scipy's, every lane takes the iterations it takes on the
     pack rounded to ROW_ALIGN alone, and the pad rows of the loop's X are
@@ -169,10 +170,11 @@ def test_a_slab_moved_into_the_wide_band_steps_as_the_plain_pack(
                   lambda n: -(-n // sell_spmv.ROW_ALIGN) * sell_spmv.ROW_ALIGN)
         plain_answers, _, plain_events, plain_pack = _one_step(d, batch_max)
     assert (7, 1856, 0) in plain_pack.plan.slab_meta
-    assert (7, 2056, 200) in pack.plan.slab_meta
+    assert (7, 2304, 448) in pack.plan.slab_meta
     order = pack.own_order()
-    assert order.pad_rows == pack.plan.pad_rows >= 200
-    assert 8 <= order.rows.shape[0] % 1024 <= 768
+    assert (pack.plan.pad_rows, order.pad_rows) == (528, 704)
+    assert order.rows.shape[0] == 5888  # 5 x 1024 + 768
+    assert all(r % 256 == 0 for _k, r, _p in pack.plan.slab_meta if r > 1024)
     (ev,), (plain_ev,) = events, plain_events
     assert (ev["bucket"], ev["matvec"], ev["row_gathers"]) == (batch_max, "sell", 3)
     assert ev["pad_rows"] == order.pad_rows
