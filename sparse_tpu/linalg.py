@@ -952,6 +952,29 @@ def _declared(op, dtype):
     return _FormApply(kind, meta), arrays
 
 
+def _declared_pair(A, M, b):
+    """``(_declared(A), _declared(M))`` for a solve of the vector ``b`` that
+    a whole-solve program over declared operators can take, or None: a
+    closure on either side, a ``b`` that is no vector, an outer trace."""
+    from .utils import in_trace
+
+    if b.ndim != 1 or in_trace():
+        return None
+    a = _declared(A, b.dtype)
+    m = a and _declared(M, b.dtype)
+    return (a, m) if m else None
+
+
+def _precond_fields(M) -> dict:
+    """What a solve's span says of its preconditioner."""
+    if type(M) is IdentityOperator:
+        return {"precond": "none"}
+    if getattr(M, "apply", None) is not None:
+        return {"precond": "declared", **M.describe}
+    return {"precond": "matrix" if type(M) is _SparseMatrixLinearOperator
+            else "closure"}
+
+
 def _pcg(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply, m_apply,
          conv_test_iters, tapped):
     """Whole-solve preconditioned CG over declared operators: A's operands,
@@ -985,14 +1008,10 @@ def _pcg_call(A, M, b, x0, tol, maxiter, conv_test_iters):
     """``(args, static)`` of the compiled program for this solve, so that
     ``_pcg_program(*args, **static)`` runs it, or None where either side is
     a closure, or under an outer trace."""
-    from .utils import in_trace
-
-    if b.ndim != 1 or in_trace():
+    pair = _declared_pair(A, M, b)
+    if pair is None:
         return None
-    a = _declared(A, b.dtype)
-    m = a and _declared(M, b.dtype)
-    if not m:
-        return None
+    a, m = pair
     args = (a[1], m[1], b, jnp.zeros_like(b) if x0 is None else asjnp(x0),
             tol, min(int(maxiter), np.iinfo(np.int32).max))
     return args, dict(a_apply=a[0], m_apply=m[0],
@@ -1026,14 +1045,13 @@ def _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters):
     if call is None:
         return None
     args, static = call
-    fields = ({"precond": "none"} if type(M) is IdentityOperator
-              else {"precond": "declared", **getattr(M, "describe", {})})
     # One `cg.solve` span a call, with the fields the fused and the general
     # paths' have: `cg.dispatch` is the program's call until it returns
     # (asynchronous: the host's part, and on a structure's first call the
     # trace and the compile), `cg.iters_fetch` the wait for the iteration
     # count, the solve's one fence.
-    with telemetry.span("cg.solve", path="device", **fields) as solve:
+    with telemetry.span("cg.solve", path="device",
+                        **_precond_fields(M)) as solve:
         with telemetry.span("cg.dispatch", emit=False) as sp:
             x, iters = _pcg_program(*args, **static)
         dispatch_s = sp.dur_s or 0.0
@@ -1347,6 +1365,143 @@ def _sync_fetch(x):
     return np.asarray(x)
 
 
+_GMRES_TRACES = _metrics.counter(
+    "gmres.traces",
+    help="traces of the compiled restarted GMRES over declared operators "
+    "(linalg._gmres, the program jit_gmres): one per program built, none "
+    "for a call that reuses one",
+)
+
+
+def _gmres_cycle_event(path: str, iters, beta, inner) -> None:
+    """One ``solver.iter`` event a restart cycle that did work, and the
+    health monitor's observation of the cycle's entry residual (squared to
+    its resid2 convention)."""
+    telemetry.record(
+        "solver.iter", solver="gmres", path=path,
+        iter=int(iters), resid=float(beta), inner=int(inner),
+    )
+    telemetry.health.observe("gmres", int(iters), float(beta) ** 2, path=path)
+
+
+def _gmres_tap(iters, beta, inner, stop) -> None:
+    """Host side of the compiled solve's tap, once a cycle; the cycle that
+    found the solve converged on entry did no work and reports nothing."""
+    if not stop:
+        _gmres_cycle_event("device", iters, beta, inner)
+
+
+def _gmres(a_operands, m_operands, b, x0, target, maxiter, *, a_apply,
+           m_apply, restart, tapped):
+    """Whole-solve restarted GMRES over declared operators: A's operands,
+    M's operands, ``b``, the start ``x0``, the residual ``target`` and the
+    cycle count ``maxiter`` all arguments, only structure static (the two
+    ``apply`` functions, the operands' treedefs and shapes, ``restart``,
+    whether it is tapped), so nothing an operator holds is a constant of
+    the program. An outer ``lax.while_loop`` over the restart cycles of
+    :func:`_gmres_cycle`, ended as the cycle path's host loop ends: by the
+    cycle count, or by a cycle that finds the residual at the target on
+    entry; a breakdown ends its own cycle and the next one decides.
+    Returns ``(x, [iters, cycles])``: the Arnoldi steps counted as the host
+    loop counts them, and the cycles that did work."""
+    _GMRES_TRACES.inc()
+    matvec = functools.partial(a_apply, a_operands)
+    precond = functools.partial(m_apply, m_operands)
+
+    def cond(state):
+        _x, cycles, _iters, stop = state
+        return (cycles < maxiter) & ~stop
+
+    def body(state):
+        x, cycles, iters, _stop = state
+        x, k, beta, bdown = _gmres_cycle(matvec, precond, x, b, target, restart)
+        stop = (k == 0) & ~bdown  # converged on entry
+        iters = iters + k + bdown.astype(jnp.int32)
+        if tapped:
+            jax.debug.callback(_gmres_tap, iters, beta, k, stop)
+        return x, cycles + (~stop).astype(jnp.int32), iters, stop
+
+    zero = jnp.zeros((), dtype=jnp.int32)
+    x, cycles, iters, _stop = jax.lax.while_loop(
+        cond, body, (x0, zero, zero, jnp.bool_(False)))
+    return x, jnp.stack([iters, cycles])
+
+
+_gmres.__name__ = _gmres.__qualname__ = "gmres"
+_gmres_program = jax.jit(
+    _gmres, static_argnames=("a_apply", "m_apply", "restart", "tapped"),
+)
+
+
+def _gmres_call(A, M, b, x, target, restart, maxiter):
+    """``(args, static)`` of the compiled program for this solve, so that
+    ``_gmres_program(*args, **static)`` runs it, or None where either side
+    is a closure, or under an outer trace."""
+    pair = _declared_pair(A, M, b)
+    if pair is None:
+        return None
+    a, m = pair
+    args = (a[1], m[1], b, x, target,
+            min(int(maxiter), np.iinfo(np.int32).max))
+    return args, dict(a_apply=a[0], m_apply=m[0], restart=int(restart),
+                      tapped=_iter_tapping())
+
+
+def _gmres_compiled(A, b, restart, M=None):
+    """The compiled program ``gmres(A, b, restart=restart, M=M)`` runs
+    (``jax.stages.Compiled``: its HLO text with every op's ``named_scope``
+    — ``gmres.spmv``, ``gmres.orth``, ``gmres.small``, ``gmres.update`` —
+    and its memory analysis), or None where that call takes another path.
+    It is jit's own: after a solve of the same structure this traces and
+    compiles nothing. For tools that read a device trace against the
+    program (the benchmark's per-scope shares)."""
+    A = make_linear_operator(A)
+    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    b = asjnp(b)
+    b = b.astype(jnp.result_type(b.dtype, A.dtype))
+    target = jnp.zeros((), jnp.finfo(b.dtype).dtype)  # shapes and types alone count
+    call = _gmres_call(A, M, b, jnp.zeros_like(b), target,
+                       min(int(restart), b.shape[0]), 1)
+    return call and _gmres_program.lower(*call[0], **call[1]).compile()
+
+
+def _try_gmres_program(A, M, b, x, target, restart, maxiter):
+    """GMRES through the compiled program over declared operators (the
+    trace names it ``jit_gmres``): ``(x, iters)``, or None where either
+    side is a closure, or under an outer trace, and the cycle path takes
+    the solve.
+
+    The program is jit's, found again by the two ``apply`` functions, the
+    operands' structure and ``restart``, so no call after the first of a
+    structure traces or compiles: another ``b``, ``x0``, ``tol``, ``atol``,
+    ``maxiter``, other values in the operands, another operator object of
+    the same shapes. One dispatch and one fetch a call."""
+    call = _gmres_call(A, M, b, x, target, restart, maxiter)
+    if call is None:
+        return None
+    args, static = call
+    # One `gmres.solve` span a call, with the fields `cg.solve` has:
+    # `gmres.dispatch` is the program's call until it returns
+    # (asynchronous: the host's part, and on a structure's first call the
+    # trace and the compile), `gmres.fetch` the wait for the packed
+    # (iters, cycles) pair, the solve's one fence.
+    with telemetry.span("gmres.solve", path="device", restart=int(restart),
+                        **_precond_fields(M)) as solve:
+        syncs0 = HOST_SYNCS
+        with telemetry.span("gmres.dispatch", emit=False) as sp:
+            x, counts = _gmres_program(*args, **static)
+        dispatch_s = sp.dur_s or 0.0
+        with telemetry.span("gmres.fetch", emit=False) as sp:
+            iters, cycles = (int(v) for v in _sync_fetch(counts))
+        solve.annotate(cycles=cycles, iters=iters,
+                       fetches=HOST_SYNCS - syncs0,
+                       dispatch_s=round(dispatch_s, 9),
+                       fetch_s=round(sp.dur_s or 0.0, 9))
+    if static["tapped"]:
+        _effects_barrier()
+    return x, iters
+
+
 @track_provenance
 def gmres(
     A,
@@ -1359,6 +1514,21 @@ def gmres(
     callback=None,
     atol=None,
 ):
+    """Restarted GMRES (left-preconditioned by ``M``). Returns ``(x,
+    iters)``: ``maxiter`` counts restart cycles, ``iters`` Arnoldi steps.
+
+    Which calls compile once: every call without a ``callback`` whose
+    operator and preconditioner say what they hold (a matrix, the identity,
+    a ``LinearOperator(shape, apply=..., operands=...)``) runs ``jit_gmres``
+    (:func:`_gmres`), the whole solve one program whose arguments are A's
+    operands, M's operands, ``b``, the start, the residual target and the
+    cycle count: a later call of the same structure, shapes and ``restart``,
+    whatever the values, traces and compiles nothing, and the call makes
+    one dispatch and one host fetch. A closure (``LinearOperator(shape,
+    matvec=f)``) on either side, a ``callback`` or an outer trace keeps the
+    cycle path: one restart cycle compiled in every call
+    (:func:`_make_gmres_cycle`) and one host fetch a cycle. Both run the
+    one Arnoldi cycle of :func:`_gmres_cycle`."""
     b = asjnp(b)
     n = b.shape[0]
     A = make_linear_operator(A)
@@ -1382,60 +1552,85 @@ def gmres(
     target = jnp.maximum(tol * bnorm, atol if atol is not None else 0.0)
     target = jnp.maximum(target, 1e-30)
 
-    try:
-        # warm host-side format dispatch (e.g. csr_array._maybe_dia) with
-        # one eager matvec so the traced cycle sees pure jnp paths
-        r0 = b - A.matvec(x)
-        # warm a non-identity preconditioner EAGERLY as well, aligned
-        # with cg's warm-up (ISSUE 14 satellite): M's layout detection
-        # (_maybe_dia/_maybe_ell) host-syncs on first use and is skipped
-        # inside a trace, so an M first applied inside the first
-        # compiled cycle would silently take its slowest kernel path for
-        # the whole solve — the host-sync-count test in
-        # tests/test_precond.py pins that no M syncs land per cycle
-        if not isinstance(M, IdentityOperator):
-            M.matvec(r0)
-        cycle = _make_gmres_cycle(A, M, restart, jnp.dtype(b.dtype))
-        total_iters = 0
-        for _outer in range(maxiter):
-            x, info = cycle(x, b, target)
-            # ONE host sync per restart cycle (VERDICT r2 #5): the packed
-            # (inner-count, residual-norm, breakdown) triple — the whole
-            # Arnoldi cycle, Givens recurrences and triangular solve ran
-            # on device
-            inner, _beta, bdown = _sync_fetch(info)
-            inner = int(inner.real)
-            if inner == 0 and not bdown:
-                break  # converged on entry (beta <= target)
-            # a breakdown stage did a matvec but contributes no column to
-            # the solve; count it (like the host path) so iters reflects
-            # work and the outer loop stays bounded by maxiter
-            total_iters += inner + (1 if bdown else 0)
-            if telemetry.enabled():
-                # restart-cycle granularity, reusing the one packed fetch
-                # the cycle already makes (no extra syncs)
-                telemetry.record(
-                    "solver.iter", solver="gmres", path="device",
-                    iter=total_iters, resid=float(abs(_beta)), inner=inner,
-                )
-                # cycle granularity: the entry residual the cycle already
-                # fetched, squared to the monitor's resid2 convention
-                telemetry.health.observe(
-                    "gmres", total_iters, float(abs(_beta)) ** 2,
-                    path="device",
-                )
-            if callback is not None:
-                callback(x)
-        _solve_event("gmres", n, total_iters, "device")
-        return x, total_iters
-    except (
-        jax.errors.TracerArrayConversionError,
-        jax.errors.TracerBoolConversionError,
-        jax.errors.ConcretizationTypeError,
-    ):
-        pass
-    # A or M is a host-side Python operator: reference-style host cycles
-    total_iters = 0
+    if callback is None:
+        # operators that declare what they hold: nothing lazy is left in
+        # them (`_matrix_form` builds the layout before the program is
+        # called), so neither eager warm-up below is needed
+        out = _try_gmres_program(A, M, b, x, target, restart, maxiter)
+        if out is not None:
+            _solve_event("gmres", n, out[1], "device")
+            return out
+
+    with telemetry.span("gmres.solve", path="cycle", restart=int(restart),
+                        **_precond_fields(M)) as solve:
+        syncs0 = HOST_SYNCS
+        try:
+            x, total_iters, cycles = _gmres_cycles(
+                A, M, b, x, target, restart, maxiter, callback)
+            path = "device"
+        except (
+            jax.errors.TracerArrayConversionError,
+            jax.errors.TracerBoolConversionError,
+            jax.errors.ConcretizationTypeError,
+        ):
+            # A or M is a host-side Python operator: reference-style host
+            # cycles
+            x, total_iters, cycles = _gmres_host_cycles(
+                A, M, b, x, target, restart, maxiter, callback)
+            path = "host"
+            solve.annotate(path="host")
+        solve.annotate(cycles=cycles, iters=total_iters,
+                       fetches=HOST_SYNCS - syncs0)
+    _solve_event("gmres", n, total_iters, path)
+    return x, total_iters
+
+
+def _gmres_cycles(A, M, b, x, target, restart, maxiter, callback):
+    """The cycle path: one compiled restart cycle for this call
+    (:func:`_make_gmres_cycle`), driven from the host with one fetch a
+    cycle. ``(x, iters, cycles that did work)``."""
+    # warm host-side format dispatch (e.g. csr_array._maybe_dia) with
+    # one eager matvec so the traced cycle sees pure jnp paths
+    r0 = b - A.matvec(x)
+    # warm a non-identity preconditioner EAGERLY as well, aligned
+    # with cg's warm-up (ISSUE 14 satellite): M's layout detection
+    # (_maybe_dia/_maybe_ell) host-syncs on first use and is skipped
+    # inside a trace, so an M first applied inside the first
+    # compiled cycle would silently take its slowest kernel path for
+    # the whole solve — the host-sync-count test in
+    # tests/test_precond.py pins that no M syncs land per cycle
+    if not isinstance(M, IdentityOperator):
+        M.matvec(r0)
+    cycle = _make_gmres_cycle(A, M, restart, jnp.dtype(b.dtype))
+    total_iters = cycles = 0
+    for _outer in range(maxiter):
+        x, info = cycle(x, b, target)
+        # ONE host sync per restart cycle (VERDICT r2 #5): the packed
+        # (inner-count, residual-norm, breakdown) triple — the whole
+        # Arnoldi cycle, Givens recurrences and triangular solve ran
+        # on device
+        inner, beta, bdown = _sync_fetch(info)
+        inner = int(inner.real)
+        if inner == 0 and not bdown:
+            break  # converged on entry (beta <= target)
+        # a breakdown stage did a matvec but contributes no column to
+        # the solve; count it (like the host path) so iters reflects
+        # work and the outer loop stays bounded by maxiter
+        total_iters += inner + (1 if bdown else 0)
+        cycles += 1
+        if telemetry.enabled():
+            # restart-cycle granularity, reusing the one packed fetch
+            # the cycle already makes (no extra syncs)
+            _gmres_cycle_event("device", total_iters, abs(beta), inner)
+        if callback is not None:
+            callback(x)
+    return x, total_iters, cycles
+
+
+def _gmres_host_cycles(A, M, b, x, target, restart, maxiter, callback):
+    """Reference-style host cycles, for an ``A`` or ``M`` that cannot be
+    traced. ``(x, iters, cycles that did work)``."""
+    total_iters = cycles = 0
     for _outer in range(maxiter):
         r = M.matvec(b - A.matvec(x))
         beta = jnp.linalg.norm(r)
@@ -1443,18 +1638,12 @@ def gmres(
             break
         x, inner = _gmres_cycle_host(A, M, x, r, beta, restart, target)
         total_iters += inner
+        cycles += 1
         if telemetry.enabled():
-            telemetry.record(
-                "solver.iter", solver="gmres", path="host",
-                iter=total_iters, resid=float(beta), inner=inner,
-            )
-            telemetry.health.observe(
-                "gmres", total_iters, float(beta) ** 2, path="host"
-            )
+            _gmres_cycle_event("host", total_iters, beta, inner)
         if callback is not None:
             callback(x)
-    _solve_event("gmres", n, total_iters, "host")
-    return x, total_iters
+    return x, total_iters, cycles
 
 
 def _gmres_cycle_host(A, M, x, r, beta, restart, target):
@@ -1517,43 +1706,85 @@ def _gmres_cycle_host(A, M, x, r, beta, restart, target):
     return x, k
 
 
-def _make_gmres_cycle(A, M, restart: int, dt):
-    """Build the fully device-resident restart cycle (VERDICT r2 #5).
+def _gmres_cycle(matvec, precond, x, b, target, restart: int):
+    """One fully device-resident restart cycle (VERDICT r2 #5), as traced
+    values: the body of the compiled whole solve (:func:`_gmres`) and of the
+    cycle path's per-call program (:func:`_make_gmres_cycle`). The residual
+    of the iterate, the Arnoldi process from it (:func:`_gmres_arnoldi`,
+    the one in this file), the small triangular solve, x += V y.
+
+    Returns ``(x', inner, beta, breakdown)``: the Arnoldi steps that gave
+    a column, the entry residual norm, and whether a step broke down;
+    ``inner == 0`` with no breakdown means converged on entry. Four
+    ``jax.named_scope``s name the work in the compiled program's text:
+    ``gmres.spmv`` (the operator's and the preconditioner's applies),
+    ``gmres.orth`` (the four contractions against the basis and the updates
+    of w between them, the sum of squares of what is left), ``gmres.small``
+    (scalars: the norm's root, the Givens rotations, the Hessenberg column,
+    the triangular solve) and ``gmres.update`` (the basis row's write,
+    x += V y, the cycle's residual)."""
+    dt = b.dtype
+    rdt = jnp.zeros((), dt).real.dtype
+    with jax.named_scope("gmres.spmv"):
+        ax = matvec(x)
+    with jax.named_scope("gmres.update"):
+        r = b - ax
+    with jax.named_scope("gmres.spmv"):
+        r = precond(r)
+    with jax.named_scope("gmres.update"):
+        beta = jnp.linalg.norm(r)
+    V, H, g, k, bdown = _gmres_arnoldi(matvec, precond, r, beta, target, restart)
+    with jax.named_scope("gmres.small"):
+        # masked triangular solve of H[:k, :k] y = g[:k] on device: columns
+        # past k are zeroed and given a unit diagonal, their rhs zeroed
+        idx = jnp.arange(restart)
+        mk = (idx < k).astype(rdt)
+        Hs = H[:restart, :restart] * (mk[:, None] * mk[None, :])
+        Hs = Hs + jnp.diag(1.0 - mk).astype(dt)
+        gv = g[:restart] * mk
+        y = jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
+    with jax.named_scope("gmres.update"):
+        x = x + y @ V[:restart]
+    return x, k, beta, bdown
+
+
+def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
+    """The Arnoldi process of a restart cycle from the (preconditioned)
+    residual ``r`` of norm ``beta``: at most ``restart`` steps of masked
+    Gram-Schmidt with one re-orthogonalisation pass, the Givens recurrences
+    on the Hessenberg column of each step, ended early by a breakdown or by
+    the recurrence's residual ``|g[k+1]|`` under ``target``; no step at all
+    where ``beta`` is at the target already. ``(V, H, g, k, breakdown)``:
+    the basis ``[restart + 1, n]`` (rows past ``k`` zero), the rotated
+    (upper triangular) Hessenberg, the rotated right-hand side, the steps
+    that gave a column.
 
     The reference keeps its Hessenberg recurrences asynchronous via futures
     (linalg.py:670-795); here the [restart]^2 scalar Givens/Hessenberg math
     runs in ``lax`` control flow INSIDE the compiled cycle — beaten, not
     tied: zero mid-cycle host round trips (the old implementation paid 2
-    device->host fetches per Arnoldi stage, each far above a kernel).
-
-    Returns ``cycle(x, b, target) -> (x', info)`` with ``info = [inner
-    iterations, entry residual norm, breakdown flag]``; ``inner == 0``
-    with no breakdown means converged on entry. (The compiled cycle is
-    built once per gmres() call and reused across all outer restarts; it
-    is not cached across calls — the jitted closure captures the
-    operator's buffers.)"""
+    device->host fetches per Arnoldi stage, each far above a kernel)."""
+    dt = r.dtype
     rdt = jnp.zeros((), dt).real.dtype
-
-    @jax.jit
-    def cycle(x, b, target):
-        n = b.shape[0]
-        r = M.matvec(b - A.matvec(x))
-        beta = jnp.linalg.norm(r)
+    n = r.shape[0]
+    with jax.named_scope("gmres.update"):
         start_ok = beta > target
         beta_safe = jnp.where(start_ok, beta, 1.0)
         V = jnp.zeros((restart + 1, n), dtype=dt).at[0].set(r / beta_safe)
-        H = jnp.zeros((restart + 1, restart), dtype=dt)
-        cs = jnp.zeros((restart,), dtype=rdt)
-        sn = jnp.zeros((restart,), dtype=dt)
-        g = jnp.zeros((restart + 1,), dtype=dt).at[0].set(beta.astype(dt))
+    H = jnp.zeros((restart + 1, restart), dtype=dt)
+    cs = jnp.zeros((restart,), dtype=rdt)
+    sn = jnp.zeros((restart,), dtype=dt)
+    g = jnp.zeros((restart + 1,), dtype=dt).at[0].set(beta.astype(dt))
 
-        def cond(st):
-            _V, _H, _cs, _sn, _g, k, done, _bd = st
-            return (k < restart) & ~done
+    def cond(st):
+        _V, _H, _cs, _sn, _g, k, done, _bd = st
+        return (k < restart) & ~done
 
-        def body(st):
-            V, H, cs, sn, g, k, done, bd = st
-            w = M.matvec(A.matvec(V[k]))
+    def body(st):
+        V, H, cs, sn, g, k, done, bd = st
+        with jax.named_scope("gmres.spmv"):
+            w = precond(matvec(V[k]))
+        with jax.named_scope("gmres.orth"):
             # modified Gram-Schmidt + one reorthogonalization pass against
             # V[:k+1], batched as masked full-basis matmuls (MXU-shaped;
             # 2x the triangular FLOPs, zero host involvement)
@@ -1563,11 +1794,16 @@ def _make_gmres_cycle(A, M, restart: int, dt):
             h2 = (V.conj() @ w) * mask
             w = w - h2 @ V
             hcol = hcol + h2
-            hkk = jnp.linalg.norm(w)
+            # ||w||: jnp.linalg.norm's own sum, its root among the scalars
+            ww = jnp.sum(jnp.real(w * jnp.conj(w)))
+        with jax.named_scope("gmres.small"):
+            hkk = jnp.sqrt(ww)
             grew = hkk > 1e-30
+        with jax.named_scope("gmres.update"):
             V = V.at[k + 1].set(
                 jnp.where(grew, w / jnp.where(grew, hkk, 1.0), 0.0)
             )
+        with jax.named_scope("gmres.small"):
             col = hcol.at[k + 1].set(hkk.astype(dt))
 
             # apply the k accumulated Givens rotations (masked fori —
@@ -1609,19 +1845,32 @@ def _make_gmres_cycle(A, M, restart: int, dt):
                 bd | breakdown,
             )
 
-        V, H, cs, sn, g, k, _done, bdown = jax.lax.while_loop(
-            cond, body,
-            (V, H, cs, sn, g, jnp.int32(0), ~start_ok, jnp.bool_(False)),
-        )
-        # masked triangular solve of H[:k, :k] y = g[:k] on device: columns
-        # past k are zeroed and given a unit diagonal, their rhs zeroed
-        idx = jnp.arange(restart)
-        mk = (idx < k).astype(rdt)
-        Hs = H[:restart, :restart] * (mk[:, None] * mk[None, :])
-        Hs = Hs + jnp.diag(1.0 - mk).astype(dt)
-        gv = g[:restart] * mk
-        y = jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
-        x = x + y @ V[:restart]
+    V, H, cs, sn, g, k, _done, bdown = jax.lax.while_loop(
+        cond, body,
+        (V, H, cs, sn, g, jnp.int32(0), ~start_ok, jnp.bool_(False)),
+    )
+    return V, H, g, k, bdown
+
+
+def _make_gmres_cycle(A, M, restart: int, dt):
+    """The cycle path's program: :func:`_gmres_cycle` jitted over the bound
+    methods ``A.matvec`` and ``M.matvec``, for a closure on either side, a
+    ``callback`` or an outer trace.
+
+    Returns ``cycle(x, b, target) -> (x', info)`` with ``info = [inner
+    iterations, entry residual norm, breakdown flag]``; ``inner == 0``
+    with no breakdown means converged on entry. The compiled cycle is
+    built once per gmres() call and reused across all outer restarts; it
+    is NOT cached across calls — the jitted closure captures the
+    operator's buffers as constants of its program, so every call of this
+    path traces and compiles. Operators that declare what they hold run
+    the whole solve as one program that the next call reuses
+    (:func:`_gmres`)."""
+    rdt = jnp.zeros((), dt).real.dtype
+
+    @jax.jit
+    def cycle(x, b, target):
+        x, k, beta, bdown = _gmres_cycle(A.matvec, M.matvec, x, b, target, restart)
         info = jnp.stack(
             [k.astype(rdt), beta.astype(rdt), bdown.astype(rdt)]
         )

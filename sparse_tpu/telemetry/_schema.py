@@ -250,8 +250,10 @@ KINDS: dict[str, frozenset] = {
     # one per live telemetry.span that emits: its name and length, and the
     # fields its site annotates, optional by design and listed per name in
     # docs/telemetry.md (cg.solve: path, iters, dispatch_s, fetch_s and,
-    # over declared operators, precond and levels; gmg.build_hierarchy:
-    # levels, sizes, rho, bytes)
+    # over declared operators, precond and levels; gmres.solve: path,
+    # restart, cycles, iters, fetches, precond and, on the compiled whole
+    # solve, dispatch_s and fetch_s; gmg.build_hierarchy: levels, sizes,
+    # rho, bytes)
     "span": frozenset({"name", "dur_s"}),
     # bench.py session record (always written by a bench run, even when
     # the TPU probe timed out)

@@ -707,3 +707,85 @@ def test_cg_general_compiles_through_the_windowed_layout(one_chip, monkeypatch):
     assert body and "well_spmv" in body.group(0)
     assert " gather(" not in body.group(0) and "kCustom" not in body.group(0)
     assert len(re.findall(r"= f32\[\d+\][^\n]* fusion\([^\n]*kind=kCustom", text)) == 2
+
+
+# ---------------------------------------------------------------------------
+# restarted GMRES over declared operators (PR 42): the whole-solve program
+# jit_gmres at the size of the benchmark's nonsymmetric cell, SuiteSparse
+# atmosmodd's 148 x 148 x 58 box (1,270,432 rows, seven planes), restart 30
+# ---------------------------------------------------------------------------
+GMRES_BOX = (148, 148, 58)
+GMRES_SCOPES = ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update")
+
+
+def _gmres_compiled(one_chip, restart=30):
+    from sparse_tpu import linalg
+
+    a, b, c = GMRES_BOX
+    n = a * b * c
+    offsets = (-a * b, -a, -1, 0, 1, a, a * b)
+    vec = _sds((n,), jnp.float32, one_chip)
+    return n, linalg._gmres_program.lower(
+        _sds((len(offsets), n), jnp.float32, one_chip), (), vec, vec,
+        _sds((), jnp.float32, one_chip), 10,
+        a_apply=linalg._FormApply("dia", (offsets, (n, n))),
+        m_apply=linalg._identity_apply, restart=restart, tapped=False).compile()
+
+
+def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
+    n, c = _gmres_compiled(one_chip)
+    text = c.as_text()
+    assert "jit_gmres" in text and _device_bytes(c) < HBM_BYTES
+    ma = c.memory_analysis()
+    # the matrix and the vectors are arguments: 7 planes, b and the start
+    assert ma.argument_size_in_bytes >= 9 * 4 * n
+    # the Krylov basis [31, n] (157.5 MB) is updated in place, a row a step:
+    # a copy a step, or a second basis, would pass 0.3 GB
+    assert 31 * 4 * n < ma.temp_size_in_bytes < 0.25e9
+    assert re.search(r"dynamic-update-slice\(\S*f32\[31,%d\]" % n, text) or \
+        re.search(r"f32\[31,%d\]\S* dynamic-update-slice\(" % n, text)
+    # the four contractions against the basis run in float32 on the vector
+    # unit: nothing for the MXU's default bfloat16 pass to touch
+    assert "convolution" not in text and "bf16" not in text
+    assert not re.search(r"\bdot\(", text)
+    # no constant of the program is larger than the (iters, cycles) pair:
+    # nothing of the matrix is folded into it
+    for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
+        assert int(np.prod([int(d) for d in dims.split(",") if d] or [1],
+                           dtype=np.int64)) <= 2, dims
+
+
+def test_gmres_program_ops_carry_their_scope(one_chip):
+    """What ``benchmark/reducers/op_scope_share.py`` reads the cell's
+    per-scope shares from: in the Arnoldi loop's body every fusion that
+    carries an ``op_name`` stands under exactly one of the four scopes, each
+    scope has one there, and the compiler's own fusions without an
+    ``op_name`` (it shapes the new basis row for its write with one) are
+    few."""
+    n, c = _gmres_compiled(one_chip)
+    text = c.as_text()
+    bodies = [m.group(0) for m in re.finditer(
+        r"\n%[\w.\-]+ \([^\n]*\{\n.*?\n\}\n", text, re.S)]
+    # the Arnoldi body: the computation that writes the basis row
+    (body,) = [b for b in bodies if "gmres.update/scatter" in b
+               and "gmres.orth/dot_general" in b]
+    fusions = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]* fusion\(([^\n]*)$",
+                         body, re.M)
+    assert len(fusions) >= 12
+    named, unnamed = {}, []
+    for name, rest in fusions:
+        m = re.search(r'op_name="([^"]*)"', rest)
+        if m:
+            named[name] = m.group(1)
+        else:
+            unnamed.append(name)
+    for name, op_name in named.items():
+        under = [s for s in GMRES_SCOPES if f"/{s}/" in op_name]
+        assert len(under) == 1, (name, op_name)
+    for scope in GMRES_SCOPES:
+        assert any(f"/{scope}/" in v for v in named.values()), scope
+    assert len(unnamed) <= 6, unnamed
+    # the four contractions read the whole basis: two give 31 coefficients,
+    # two give a vector
+    orth = [k for k, v in named.items() if v.endswith("gmres.orth/dot_general")]
+    assert len(orth) == 4

@@ -1,0 +1,401 @@
+"""``linalg.gmres`` over operators that declare what they hold: one compiled
+whole-solve program (``jit_gmres``) with A's and M's arrays as arguments
+(PR 42).
+
+The clients: the 7-point nonsymmetric box of the benchmark's
+``cfd_7pt`` generator (the pattern of SuiteSparse's atmosmodd at small
+boxes) and a general ``csr_array`` under ``precond.make_M``'s declared
+point-Jacobi. A second solve of the same structure, whatever the values,
+traces nothing (``gmres.traces``) and makes one host fetch; the answer is
+the cycle path's (``_make_gmres_cycle`` a call, one fetch a cycle), which a
+closure on either side, a ``callback`` or an outer trace still runs.
+"""
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+import jax
+import jax.numpy as jnp
+
+import sparse_tpu
+from sparse_tpu import linalg, precond, telemetry
+from sparse_tpu.config import settings
+from sparse_tpu.telemetry import _metrics
+from .utils.spd import operator_module
+
+TRACES = _metrics.counter("gmres.traces")
+GEN = operator_module("cfd_7pt")
+
+BOXES = [(6, 5, 4), (9, 8, 3), (12, 7, 5)]
+CASES = [(box, restart) for box in BOXES for restart in (10, 30)]
+CASE_IDS = [f"{'x'.join(map(str, b))}-m{m}" for b, m in CASES]
+
+
+@pytest.fixture(autouse=True)
+def _fresh_program():
+    """These tests count traces of ``jit_gmres``; an earlier test of this
+    process that solved the same structure would leave them none."""
+    linalg._gmres_program.clear_cache()
+
+
+@pytest.fixture
+def tel(tmp_path, monkeypatch):
+    telemetry.reset()
+    monkeypatch.setattr(settings, "telemetry", True)
+    telemetry.configure(str(tmp_path / "records.jsonl"))
+    yield
+    telemetry.configure(None)
+    telemetry.reset()
+
+
+def _box(box, seed=3, dtype=np.float32):
+    """(A, b) on the box: the generator's CSR arrays; complex: the same
+    pattern with an imaginary part on the diagonal."""
+    d = GEN.make({"box": list(box), "restart": 30, "cycles": 1}, seed)
+    n = d["rows"]
+    data, b = d["data"].astype(dtype), d["b"].astype(dtype)
+    if np.issubdtype(dtype, np.complexfloating):
+        S = sp.csr_matrix((data, d["indices"], d["indptr"]), shape=(n, n))
+        shift = np.random.default_rng(seed).uniform(-1, 1, n)
+        S = (S + 0.3j * sp.diags(shift)).tocsr().astype(dtype)
+        S.sort_indices()
+        data, b = S.data, b * (1 + 0.5j)
+    A = sparse_tpu.csr_array((data, d["indices"], d["indptr"]), shape=(n, n))
+    return A, jnp.asarray(b)
+
+
+def _general(n=300, scale=1.0, seed=5):
+    """A nonsymmetric, diagonally dominant matrix that is not banded, its
+    declared point-Jacobi, and b."""
+    rng = np.random.default_rng(seed)
+    S = sp.random(n, n, density=0.03, random_state=rng, dtype=np.float32)
+    S = (S + sp.diags(np.asarray(abs(S).sum(axis=1)).ravel() + 1.0
+                      + rng.random(n))) * scale
+    A = sparse_tpu.csr_array(S.tocsr().astype(np.float32))
+    b = jnp.asarray(rng.uniform(0.5, 1.5, n), jnp.float32)
+    return A, precond.make_M(A, "jacobi"), b
+
+
+def _as_closure(op):
+    op = linalg.make_linear_operator(op)
+    return linalg.LinearOperator(op.shape, matvec=op.matvec, dtype=op.dtype)
+
+
+def _cycle_path(A, b, M=None, **kw):
+    """The same solve through the old path: A rewrapped as a closure."""
+    t0 = TRACES.value
+    out = linalg.gmres(_as_closure(A), b, M=M, **kw)
+    assert TRACES.value == t0  # the cycle path is not the program
+    return out
+
+
+# -- one program a structure ---------------------------------------------------
+def _again_same(A, b, mk):
+    return A, b, {}
+
+
+def _again_other_b(A, b, mk):
+    return A, 2.0 * b[::-1], {}
+
+
+def _again_x0(A, b, mk):
+    return A, b, {"x0": 0.5 * b}
+
+
+def _again_other_tol_atol_maxiter(A, b, mk):
+    return A, b, {"tol": 1e-3, "atol": 1e-4, "maxiter": 2}
+
+
+def _again_other_values(A, b, mk):
+    return mk()[0], b, {}  # another matrix object, other values
+
+
+AGAIN = [_again_same, _again_other_b, _again_x0,
+         _again_other_tol_atol_maxiter, _again_other_values]
+AGAIN_IDS = [f.__name__[len("_again_"):] for f in AGAIN]
+
+
+@pytest.mark.parametrize("again", AGAIN, ids=AGAIN_IDS)
+@pytest.mark.parametrize("box,restart", CASES, ids=CASE_IDS)
+def test_a_later_solve_on_the_box_traces_nothing(box, restart, again):
+    A, b = _box(box)
+    t0 = TRACES.value
+    linalg.gmres(A, b, restart=restart, maxiter=3, tol=1e-30)
+    assert TRACES.value == t0 + 1
+    A2, b2, kw = again(A, b, lambda: _box(box, seed=11))
+    x, iters = linalg.gmres(A2, b2, restart=restart, **{
+        "maxiter": 3, "tol": 1e-30, **kw})
+    assert TRACES.value == t0 + 1
+    assert iters > 0 and np.all(np.isfinite(np.asarray(x)))
+
+
+@pytest.mark.parametrize("again", AGAIN, ids=AGAIN_IDS)
+def test_a_later_solve_of_a_general_matrix_under_jacobi_traces_nothing(again):
+    A, M, b = _general()
+    t0 = TRACES.value
+    linalg.gmres(A, b, restart=10, maxiter=3, M=M)
+    assert TRACES.value == t0 + 1
+    if again is _again_other_values:
+        A2, M2, _ = _general(scale=2.0)
+        b2, kw = b, {}
+    else:
+        (A2, b2, kw), M2 = again(A, b, None), M
+    x, _ = linalg.gmres(A2, b2, restart=10, M=M2, **{"maxiter": 3, **kw})
+    assert TRACES.value == t0 + 1
+    assert np.all(np.isfinite(np.asarray(x)))
+
+
+@pytest.mark.parametrize("other", ["restart", "shape", "dtype", "same"])
+def test_another_structure_is_one_more_program(other):
+    A, b = _box((6, 5, 4))
+    linalg.gmres(A, b, restart=10, maxiter=2, tol=1e-30)
+    t0 = TRACES.value
+    if other == "restart":
+        linalg.gmres(A, b, restart=12, maxiter=2, tol=1e-30)
+    elif other == "shape":
+        A2, b2 = _box((7, 5, 4))
+        linalg.gmres(A2, b2, restart=10, maxiter=2, tol=1e-30)
+    elif other == "dtype":
+        A2, b2 = _box((6, 5, 4), dtype=np.complex64)
+        linalg.gmres(A2, b2, restart=10, maxiter=2, tol=1e-30)
+    else:  # the control: the same structure again
+        A2, b2 = _box((6, 5, 4), seed=8)
+        linalg.gmres(A2, b2, restart=10, maxiter=2, tol=1e-30)
+    assert TRACES.value == t0 + (other != "same")
+    linalg.gmres(A, b, restart=10, maxiter=4)  # the first one's is still there
+    assert TRACES.value == t0 + (other != "same")
+
+
+# -- the cycle path's answer -----------------------------------------------------
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+@pytest.mark.parametrize("box,restart", CASES, ids=CASE_IDS)
+def test_the_program_gives_the_cycle_paths_answer(box, restart, dtype):
+    """Both run the one Arnoldi cycle (``_gmres_cycle``), the program inside
+    an outer ``while_loop`` and the cycle path as a program of its own with
+    the matrix as constants. On the CPU the two compile to the same
+    arithmetic in the same order and the answers agree to the last bit; the
+    test allows 1e-6 of the answer, because nothing makes a compiler fuse
+    the cycle's residual the same way in both programs (``tests/
+    test_pcg_program.py`` found a few ulps there), and holds ``iters``
+    exactly."""
+    A, b = _box(box, dtype=dtype)
+    x, iters = linalg.gmres(A, b, restart=restart, maxiter=3, tol=1e-30)
+    xc, ic = _cycle_path(A, b, restart=restart, maxiter=3, tol=1e-30)
+    assert iters == ic == 3 * min(restart, b.shape[0])
+    assert x.dtype == xc.dtype == dtype
+    assert float(jnp.linalg.norm(x - xc)) <= 1e-6 * float(jnp.linalg.norm(xc))
+
+
+@pytest.mark.parametrize("with_x0", [False, True], ids=["zero-start", "x0"])
+def test_a_matrix_under_declared_jacobi_gives_the_cycle_paths_answer(with_x0):
+    A, M, b = _general()
+    kw = {"x0": 0.1 * b} if with_x0 else {}
+    x, iters = linalg.gmres(A, b, restart=10, maxiter=4, M=M, tol=1e-30, **kw)
+    t0 = TRACES.value
+    xc, ic = linalg.gmres(A, b, restart=10, maxiter=4, M=_as_closure(M),
+                          tol=1e-30, **kw)  # a closure M: the cycle path
+    assert TRACES.value == t0
+    assert iters == ic == 40
+    assert float(jnp.linalg.norm(x - xc)) <= 1e-6 * float(jnp.linalg.norm(xc))
+    assert float(jnp.linalg.norm(A @ x - b)) < 1e-4 * float(jnp.linalg.norm(b))
+
+
+# -- how a solve ends ---------------------------------------------------------------
+def _ends(A, b, **kw):
+    """(x, iters, host syncs) of the program and of the cycle path."""
+    out = []
+    for solve in (linalg.gmres, _cycle_path):
+        linalg.HOST_SYNCS = 0
+        x, iters = solve(A, b, **kw)
+        out.append((np.asarray(x), iters, linalg.HOST_SYNCS))
+    return out
+
+
+def test_converged_on_entry_ends_as_the_cycle_path_ends():
+    A, b = _box((6, 5, 4))
+    x_exact, _ = linalg.gmres(A, b, restart=30, maxiter=20, tol=1e-7)
+    (x, iters, syncs), (xc, ic, _) = _ends(A, b, x0=x_exact, tol=1e-3,
+                                           restart=10, maxiter=5)
+    assert iters == ic == 0 and syncs == 1
+    assert np.array_equal(x, np.asarray(x_exact)) and np.array_equal(x, xc)
+
+
+def test_convergence_inside_a_cycle_ends_as_the_cycle_path_ends():
+    A, b = _box((6, 5, 4))
+    (x, iters, syncs), (xc, ic, sc) = _ends(A, b, restart=10, maxiter=50,
+                                            tol=1e-4)
+    assert iters == ic and 0 < iters < 500
+    assert syncs == 1 and sc == -(-ic // 10) + 1  # one a cycle, one on entry
+    assert np.linalg.norm(x - xc) <= 1e-6 * np.linalg.norm(xc)
+    r = np.asarray(b) - np.asarray(A @ jnp.asarray(x))
+    assert np.linalg.norm(r) <= 1.5e-4 * np.linalg.norm(np.asarray(b))
+
+
+def test_maxiter_exhausted_ends_as_the_cycle_path_ends():
+    A, b = _box((9, 8, 3))
+    (x, iters, syncs), (xc, ic, sc) = _ends(A, b, restart=10, maxiter=4,
+                                            tol=1e-30)
+    assert iters == ic == 40 and (syncs, sc) == (1, 4)
+    assert np.linalg.norm(x - xc) <= 1e-6 * np.linalg.norm(xc)
+
+
+@pytest.mark.parametrize("maxiter", [1, 3])
+def test_a_breakdown_ends_as_the_cycle_path_ends(maxiter):
+    """A matrix whose Krylov space closes early: twice the identity. The
+    first step's w is a multiple of v0, nothing is left of it, and the
+    rotation's denominator is not zero (h00 = 2), so the step is a happy
+    breakdown that the recurrence sees as convergence; a nilpotent shift
+    from a start in its kernel's image breaks down with nothing to rotate."""
+    n = 12
+    Id = sparse_tpu.csr_array(sp.identity(n, dtype=np.float32, format="csr") * 2)
+    b = jnp.asarray(np.arange(1.0, n + 1), jnp.float32)
+    (x, iters, _), (xc, ic, _) = _ends(Id, b, restart=5, maxiter=maxiter,
+                                       tol=1e-5)
+    assert iters == ic == 1
+    assert np.allclose(x, np.asarray(b) / 2) and np.array_equal(x, xc)
+    # A e1 = 0 for the shift below: the first Hessenberg column is all zero,
+    # the rotation has nothing to work on (denom 0): a true breakdown, which
+    # both paths count as one step a cycle until the cycles are spent
+    N = sparse_tpu.csr_array(sp.diags([np.ones(n - 1, np.float32)], [1],
+                                      format="csr"))
+    e1 = jnp.zeros(n, jnp.float32).at[0].set(1.0)
+    (x, iters, _), (xc, ic, _) = _ends(N, e1, restart=5, maxiter=maxiter)
+    assert iters == ic == maxiter
+    assert np.array_equal(x, xc) and np.all(np.isfinite(x))
+
+
+@pytest.mark.parametrize("box,restart", CASES[:3], ids=CASE_IDS[:3])
+def test_one_host_sync_a_call(box, restart):
+    A, b = _box(box)
+    for k in range(3):
+        linalg.HOST_SYNCS = 0
+        linalg.gmres(A, (k + 1.0) * b, restart=restart, maxiter=2 + k, tol=1e-30)
+        assert linalg.HOST_SYNCS == 1
+
+
+# -- what keeps the old path ----------------------------------------------------------
+@pytest.mark.parametrize("side", ["A", "M", "both", "callback", "outer-trace"])
+def test_the_old_path_still_solves(side, tel):
+    A, M, b = _general()
+    seen = []
+    kw = {"restart": 10, "maxiter": 30, "tol": 1e-6}
+    t0 = TRACES.value
+    if side == "callback":
+        x, iters = linalg.gmres(A, b, M=M, callback=seen.append, **kw)
+    elif side == "outer-trace":
+        # under an outer trace nothing can be fetched: the host cycles raise
+        # as they did, and the program is not tried
+        with pytest.raises(jax.errors.ConcretizationTypeError):
+            jax.jit(lambda v: linalg.gmres(A, v, **kw)[0])(b)
+        assert TRACES.value == t0
+        return
+    else:
+        x, iters = linalg.gmres(
+            _as_closure(A) if side in ("A", "both") else A, b,
+            M=_as_closure(M) if side in ("M", "both") else M, **kw)
+    assert TRACES.value == t0
+    (ev,) = [e for e in telemetry.events("span") if e["name"] == "gmres.solve"]
+    assert ev["path"] == "cycle" and ev["iters"] == iters
+    assert ev["fetches"] == ev["cycles"] + 1  # one a cycle, one on entry
+    assert ev["precond"] == ("jacobi" if side in ("A", "callback") else "closure")
+    assert len(seen) == (ev["cycles"] if side == "callback" else 0)
+    assert float(jnp.linalg.norm(A @ x - b)) < 1e-4 * float(jnp.linalg.norm(b))
+
+
+def test_an_operator_wrapped_for_fault_injection_keeps_the_old_path():
+    from sparse_tpu.resilience import faults
+
+    A, b = _box((6, 5, 4))
+    t0 = TRACES.value
+    faults.configure("nonfinite:matvec:p=0")
+    try:
+        x, _ = linalg.gmres(A, b, restart=10, maxiter=3)
+    finally:
+        faults.clear()
+    assert TRACES.value == t0 and np.all(np.isfinite(np.asarray(x)))
+
+
+# -- spans, events, the compiled program ------------------------------------------------
+@pytest.mark.parametrize("box,restart", CASES[2:5], ids=CASE_IDS[2:5])
+def test_one_gmres_solve_span_a_call_and_the_cycles_events(box, restart, tel):
+    A, b = _box(box)
+    for k in range(2):
+        n0, i0 = len(telemetry.events("span")), len(telemetry.events("solver.iter"))
+        _x, iters = linalg.gmres(A, b, restart=restart, maxiter=2 + k, tol=1e-30)
+        (ev,) = [e for e in telemetry.events("span")[n0:]  # one a call; the
+                 if e["name"] == "gmres.solve"]  # first builds the layout too
+        assert (ev["path"], ev["restart"], ev["cycles"], ev["iters"],
+                ev["fetches"], ev["precond"]) == (
+            "device", restart, 2 + k, iters, 1, "none")
+        assert 0 < ev["dispatch_s"] and 0 <= ev["fetch_s"]
+        assert ev["dispatch_s"] + ev["fetch_s"] <= ev["dur_s"]
+        assert telemetry.schema.validate(ev) == []
+        # a `solver.iter` event a cycle, as the cycle path records them
+        cyc = telemetry.events("solver.iter")[i0:]
+        assert [(e["solver"], e["path"], e["iter"], e["inner"]) for e in cyc] == [
+            ("gmres", "device", restart * (j + 1), restart) for j in range(2 + k)]
+        assert all(e["resid"] > 0 for e in cyc)
+        assert cyc[0]["resid"] > cyc[-1]["resid"]
+    assert telemetry.events("solver.solve")[-1]["path"] == "device"
+    # the cycle path's events of the same solve: the same but for rounding
+    i0 = len(telemetry.events("solver.iter"))
+    _cycle_path(A, b, restart=restart, maxiter=3, tol=1e-30)
+    old = telemetry.events("solver.iter")[i0:]
+    assert [(e["iter"], e["inner"]) for e in old] == [
+        (e["iter"], e["inner"]) for e in cyc]
+    assert np.allclose([e["resid"] for e in old], [e["resid"] for e in cyc],
+                       rtol=1e-4)
+
+
+def test_the_span_names_a_declared_preconditioner(tel):
+    A, M, b = _general()
+    linalg.gmres(A, b, restart=10, maxiter=2, M=M)
+    (ev,) = [e for e in telemetry.events("span") if e["name"] == "gmres.solve"]
+    assert (ev["path"], ev["precond"], ev["fetches"]) == ("device", "jacobi", 1)
+
+
+def test_off_the_program_records_nothing_and_carries_no_tap():
+    telemetry.reset()
+    A, b = _box((6, 5, 4))
+    linalg.gmres(A, b, restart=10, maxiter=2)
+    assert telemetry.events() == []
+    text = linalg._gmres_compiled(A, b, 10).as_text()
+    assert "callback" not in text
+
+
+def test_the_compiled_program_names_its_scopes_and_is_jits_own():
+    A, b = _box((12, 7, 5))
+    assert linalg._gmres_compiled(_as_closure(A), b, 30) is None
+    linalg.gmres(A, b, restart=30, maxiter=2)
+    t0 = TRACES.value
+    text = linalg._gmres_compiled(A, b, 30).as_text()
+    assert TRACES.value == t0  # found again, not traced again
+    assert "jit_gmres" in text
+    for scope in ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update"):
+        assert f"/{scope}/" in text
+    # the scopes do not nest: an op stands under its own scope alone
+    import re
+
+    assert not re.search(r"gmres\.\w+/[^\"]*gmres\.\w+/", text)
+
+
+def test_the_arnoldi_basis_is_orthonormal():
+    """The question the chip run of PR 42 answers at atmosmodd's size, here
+    on the CPU: after a cycle ``V V^H`` is the identity to float32's
+    rounding, and the residual the recurrence believes is the true one."""
+    A, b = _box((12, 7, 5))
+    mv = linalg.make_linear_operator(A).matvec
+    beta = jnp.linalg.norm(b)
+    m = 12  # far from converged, so that the residual is no rounding
+    V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta,
+                                           jnp.float32(1e-30), m)
+    assert int(k) == m and not bool(bd)
+    V64 = np.asarray(V, np.float64)
+    assert np.abs(V64 @ V64.T - np.eye(m + 1)).max() < 5e-6
+    y = np.linalg.solve(np.triu(np.asarray(H, np.float64)[:m, :m]),
+                        np.asarray(g, np.float64)[:m])
+    x64 = jnp.asarray(y @ V64[:m])  # x64 is on: conftest.py
+    r = np.asarray(b, np.float64) - np.asarray(A @ x64, np.float64)
+    assert abs(float(g[m])) == pytest.approx(np.linalg.norm(r), rel=1e-3)
