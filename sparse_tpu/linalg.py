@@ -1495,6 +1495,7 @@ def _try_gmres_program(A, M, b, x, target, restart, maxiter):
             iters, cycles = (int(v) for v in _sync_fetch(counts))
         solve.annotate(cycles=cycles, iters=iters,
                        fetches=HOST_SYNCS - syncs0,
+                       orth_rows=_gmres_orth_rows(int(restart), iters),
                        dispatch_s=round(dispatch_s, 9),
                        fetch_s=round(sp.dur_s or 0.0, 9))
     if static["tapped"]:
@@ -1581,6 +1582,8 @@ def gmres(
             solve.annotate(path="host")
         solve.annotate(cycles=cycles, iters=total_iters,
                        fetches=HOST_SYNCS - syncs0)
+        if path == "device":  # the compiled cycle's Arnoldi process, staged
+            solve.annotate(orth_rows=_gmres_orth_rows(int(restart), total_iters))
     _solve_event("gmres", n, total_iters, path)
     return x, total_iters
 
@@ -1748,9 +1751,48 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
     return x, k, beta, bdown
 
 
+# The orthogonalisation reads the basis in stages of whole tile groups: the
+# TPU compiler lays the ``[restart + 1, n]`` basis out in ``(8, 128)`` tiles,
+# so eight rows are the least a read of it can take, and a static slice
+# ``V[:8 j]`` is a whole number of them (it fuses into the contraction; no
+# copy of the basis is planned: tests/test_chip_compile.py). A stage is its
+# own set of fusions in the program, so a long restart widens the block and
+# keeps the stages few.
+_ORTH_TILE_ROWS = 8
+_ORTH_MAX_STAGES = 8
+
+
+def _orth_stages(restart: int) -> tuple:
+    """``(block, his)``: step ``k`` of a cycle orthogonalises against the
+    basis rows ``V[:his[k // block]]``, the least whole number of blocks
+    that holds rows 0..k, capped at the basis' ``restart + 1`` rows. The
+    block is the tile group's 8 rows times the least factor that leaves at
+    most ``_ORTH_MAX_STAGES`` stages: ``restart`` 30 gives 8 and (8, 16, 24,
+    31), 200 gives 32 and seven stages, 7 or less one stage, the whole
+    basis."""
+    rows = restart + 1
+    groups = -(-rows // _ORTH_TILE_ROWS)
+    block = _ORTH_TILE_ROWS * -(-groups // _ORTH_MAX_STAGES)
+    return block, tuple(min(hi, rows) for hi in
+                        range(block, rows + block, block))
+
+
+def _gmres_orth_rows(restart: int, iters: int) -> float:
+    """Basis rows one contraction of :func:`_gmres_arnoldi` read, averaged
+    over the ``iters`` steps a solve made (the ``orth_rows`` field of the
+    ``gmres.solve`` span): the host's count from the stages of ``restart``,
+    every cycle but the last taken as whole."""
+    if iters <= 0:
+        return 0.0
+    block, his = _orth_stages(restart)
+    cycle = [his[k // block] for k in range(restart)]
+    whole, last = divmod(iters, restart)
+    return round((whole * sum(cycle) + sum(cycle[:last])) / iters, 3)
+
+
 def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     """The Arnoldi process of a restart cycle from the (preconditioned)
-    residual ``r`` of norm ``beta``: at most ``restart`` steps of masked
+    residual ``r`` of norm ``beta``: at most ``restart`` steps of classical
     Gram-Schmidt with one re-orthogonalisation pass, the Givens recurrences
     on the Hessenberg column of each step, ended early by a breakdown or by
     the recurrence's residual ``|g[k+1]|`` under ``target``; no step at all
@@ -1758,6 +1800,14 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     the basis ``[restart + 1, n]`` (rows past ``k`` zero), the rotated
     (upper triangular) Hessenberg, the rotated right-hand side, the steps
     that gave a column.
+
+    The four contractions of a step (``V^H w``, ``h V``, and both again) are
+    bound by the bytes of the basis they read, on the vector unit, so step
+    ``k`` reads the rows it holds and not all ``restart + 1``: the stage
+    ``V[:his[k // block]]`` of :func:`_orth_stages`, a static slice chosen
+    by ``k`` on the device (``lax.switch``), under a mask within the last
+    block. Rows past ``k`` are zero and their coefficients were masked to
+    zero, so leaving them out changes no term of any sum.
 
     The reference keeps its Hessenberg recurrences asynchronous via futures
     (linalg.py:670-795); here the [restart]^2 scalar Givens/Hessenberg math
@@ -1776,6 +1826,22 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     sn = jnp.zeros((restart,), dtype=dt)
     g = jnp.zeros((restart + 1,), dtype=dt).at[0].set(beta.astype(dt))
 
+    def orth(V, w, k, *, hi):
+        # classical Gram-Schmidt and one re-orthogonalisation pass against
+        # the rows V[:k+1], as masked contractions over the stage's rows
+        Vs = V[:hi]
+        mask = (jnp.arange(hi) <= k).astype(rdt)
+        hcol = (Vs.conj() @ w) * mask
+        w = w - hcol @ Vs
+        h2 = (Vs.conj() @ w) * mask
+        w = w - h2 @ Vs
+        # ||w||: jnp.linalg.norm's own sum, its root among the scalars
+        ww = jnp.sum(jnp.real(w * jnp.conj(w)))
+        return jnp.pad(hcol + h2, (0, restart + 1 - hi)), w, ww
+
+    block, his = _orth_stages(restart)
+    stages = [functools.partial(orth, hi=hi) for hi in his]
+
     def cond(st):
         _V, _H, _cs, _sn, _g, k, done, _bd = st
         return (k < restart) & ~done
@@ -1785,17 +1851,8 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
         with jax.named_scope("gmres.spmv"):
             w = precond(matvec(V[k]))
         with jax.named_scope("gmres.orth"):
-            # modified Gram-Schmidt + one reorthogonalization pass against
-            # V[:k+1], batched as masked full-basis matmuls (MXU-shaped;
-            # 2x the triangular FLOPs, zero host involvement)
-            mask = (jnp.arange(restart + 1) <= k).astype(rdt)
-            hcol = (V.conj() @ w) * mask
-            w = w - hcol @ V
-            h2 = (V.conj() @ w) * mask
-            w = w - h2 @ V
-            hcol = hcol + h2
-            # ||w||: jnp.linalg.norm's own sum, its root among the scalars
-            ww = jnp.sum(jnp.real(w * jnp.conj(w)))
+            # one stage (restart <= 7): lax.switch calls it, no conditional
+            hcol, w, ww = jax.lax.switch(k // block, stages, V, w, k)
         with jax.named_scope("gmres.small"):
             hkk = jnp.sqrt(ww)
             grew = hkk > 1e-30

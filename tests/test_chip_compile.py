@@ -748,6 +748,10 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
     # unit: nothing for the MXU's default bfloat16 pass to touch
     assert "convolution" not in text and "bf16" not in text
     assert not re.search(r"\bdot\(", text)
+    # the orthogonalisation's stages read static slices of the basis that
+    # fuse into their contractions (PR 43): no stage plans a copy of it
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"\bcopy(-start)?\(", ln) and f"f32[31,{n}]" in ln]
     # no constant of the program is larger than the (iters, cycles) pair:
     # nothing of the matrix is folded into it
     for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
@@ -757,35 +761,63 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
 
 def test_gmres_program_ops_carry_their_scope(one_chip):
     """What ``benchmark/reducers/op_scope_share.py`` reads the cell's
-    per-scope shares from: in the Arnoldi loop's body every fusion that
-    carries an ``op_name`` stands under exactly one of the four scopes, each
-    scope has one there, and the compiler's own fusions without an
+    per-scope shares from: in the Arnoldi loop's body, and in the branches
+    of the orthogonalisation's ``conditional`` (one a stage: PR 43), every
+    fusion that carries an ``op_name`` stands under exactly one of the four
+    scopes, each scope has one, and the compiler's own fusions without an
     ``op_name`` (it shapes the new basis row for its write with one) are
-    few."""
+    few. Every stage has its four contractions, the basis operand sliced to
+    the stage's rows inside the fusion."""
+    from sparse_tpu import linalg
+
     n, c = _gmres_compiled(one_chip)
     text = c.as_text()
-    bodies = [m.group(0) for m in re.finditer(
-        r"\n%[\w.\-]+ \([^\n]*\{\n.*?\n\}\n", text, re.S)]
-    # the Arnoldi body: the computation that writes the basis row
-    (body,) = [b for b in bodies if "gmres.update/scatter" in b
-               and "gmres.orth/dot_general" in b]
-    fusions = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]* fusion\(([^\n]*)$",
-                         body, re.M)
-    assert len(fusions) >= 12
+    computations = {m.group(1): m.group(0) for m in re.finditer(
+        r"\n%([\w.\-]+) \([^\n]*\{\n.*?\n\}\n", text, re.S)}
+    # the Arnoldi body: the computation that writes the basis row and
+    # chooses the stage
+    (body,) = [b for b in computations.values() if "gmres.update/scatter" in b
+               and re.search(r" conditional\([^\n]*gmres\.orth/", b)]
+    (branches,) = re.findall(
+        r" conditional\([^\n]*branch_computations=\{([^}]*)\}[^\n]*gmres\.orth/",
+        body)
+    branches = [computations[b.strip().lstrip("%")] for b in branches.split(",")]
+    _block, his = linalg._orth_stages(30)
+    assert his == (8, 16, 24, 31) and len(branches) == len(his)
+
+    def fusions(computation):
+        return re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = ([^\n]*?) fusion\(([^\n]*)$",
+            computation, re.M)
+
     named, unnamed = {}, []
-    for name, rest in fusions:
+    for name, _result, rest in [f for comp in [body, *branches]
+                                for f in fusions(comp)]:
         m = re.search(r'op_name="([^"]*)"', rest)
         if m:
             named[name] = m.group(1)
         else:
             unnamed.append(name)
+    assert len(named) + len(unnamed) >= 12 + 4 * len(his)
     for name, op_name in named.items():
         under = [s for s in GMRES_SCOPES if f"/{s}/" in op_name]
         assert len(under) == 1, (name, op_name)
     for scope in GMRES_SCOPES:
         assert any(f"/{scope}/" in v for v in named.values()), scope
     assert len(unnamed) <= 6, unnamed
-    # the four contractions read the whole basis: two give 31 coefficients,
-    # two give a vector
-    orth = [k for k, v in named.items() if v.endswith("gmres.orth/dot_general")]
-    assert len(orth) == 4
+    # a stage's four contractions read the stage's rows of the basis and no
+    # others: two give the stage's coefficients, two give a vector
+    for hi, branch in zip(his, branches):
+        orth = [(result, re.search(r"calls=%([\w.\-]+)", rest).group(1))
+                for _name, result, rest in fusions(branch)
+                if re.search(r'op_name="[^"]*gmres\.orth/[^"]*dot_general"', rest)]
+        assert len(orth) == 4, (hi, orth)
+        assert sorted(re.match(r"f32\[\d+\]", r).group(0) for r, _ in orth) == \
+            sorted([f"f32[{hi}]"] * 2 + [f"f32[{n}]"] * 2)
+        for _result, fused in orth:
+            inner = computations[fused]
+            # the whole basis is the fusion's parameter (handed on by
+            # reference), the stage's tile groups its slice of it
+            assert re.search(r"= f32\[31,%d\]\S* parameter\(" % n, inner)
+            if hi < 31:
+                assert f"slice={{[0:{hi}], [0:{n}]}}" in inner, (hi, fused)

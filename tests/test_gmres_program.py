@@ -11,6 +11,8 @@ the cycle path's (``_make_gmres_cycle`` a call, one fetch a cycle), which a
 closure on either side, a ``callback`` or an outer trace still runs.
 """
 
+import re
+
 import numpy as np
 import pytest
 import scipy.sparse as sp
@@ -376,19 +378,20 @@ def test_the_compiled_program_names_its_scopes_and_is_jits_own():
     for scope in ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update"):
         assert f"/{scope}/" in text
     # the scopes do not nest: an op stands under its own scope alone
-    import re
-
     assert not re.search(r"gmres\.\w+/[^\"]*gmres\.\w+/", text)
 
 
-def test_the_arnoldi_basis_is_orthonormal():
+@pytest.mark.parametrize("m", [12, 20], ids=["one-stage-edge", "two-stage-edges"])
+def test_the_arnoldi_basis_is_orthonormal(m):
     """The question the chip run of PR 42 answers at atmosmodd's size, here
     on the CPU: after a cycle ``V V^H`` is the identity to float32's
-    rounding, and the residual the recurrence believes is the true one."""
+    rounding, and the residual the recurrence believes is the true one.
+    ``m`` is far from converged, so that the residual is no rounding, and
+    its steps cross the edges of the orthogonalisation's stages (rows 8 and
+    16 of the basis)."""
     A, b = _box((12, 7, 5))
     mv = linalg.make_linear_operator(A).matvec
     beta = jnp.linalg.norm(b)
-    m = 12  # far from converged, so that the residual is no rounding
     V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta,
                                            jnp.float32(1e-30), m)
     assert int(k) == m and not bool(bd)
@@ -399,3 +402,147 @@ def test_the_arnoldi_basis_is_orthonormal():
     x64 = jnp.asarray(y @ V64[:m])  # x64 is on: conftest.py
     r = np.asarray(b, np.float64) - np.asarray(A @ x64, np.float64)
     assert abs(float(g[m])) == pytest.approx(np.linalg.norm(r), rel=1e-3)
+
+
+# -- the orthogonalisation's stages (PR 43) -------------------------------------------------
+@pytest.mark.parametrize("restart,block,his", [
+    (1, 8, (2,)), (5, 8, (6,)), (7, 8, (8,)),  # one stage: the whole basis
+    (8, 8, (8, 9)), (12, 8, (8, 13)), (30, 8, (8, 16, 24, 31)),
+    (33, 8, (8, 16, 24, 32, 34)), (63, 8, tuple(range(8, 65, 8))),
+    (64, 16, (16, 32, 48, 64, 65)), (70, 16, (16, 32, 48, 64, 71)),
+    (200, 32, (32, 64, 96, 128, 160, 192, 201)),
+])
+def test_the_stages_follow_from_restart_alone(restart, block, his):
+    assert linalg._orth_stages(restart) == (block, his)
+    assert len(his) <= 8 and his[-1] == restart + 1
+    for k in range(restart):  # the least stage that holds rows 0..k
+        stage = k // block
+        assert (his[stage - 1] if stage else 0) < k + 1 <= his[stage]
+        assert his[stage] % 8 == 0 or his[stage] == restart + 1
+
+
+def _masked_arnoldi(mv, r, beta, target, m):
+    """The Arnoldi process as the tree had it before the stages, step by
+    step on the host: the four contractions of a step run over ALL ``m + 1``
+    rows of the basis under the mask of the rows the step holds; the Givens
+    recurrences in numpy. ``(V, H, g, k, breakdown, |g[1:]|)``."""
+    dt = np.dtype(r.dtype)
+    n = r.shape[0]
+    V = jnp.zeros((m + 1, n), dt).at[0].set(r / beta)
+    H = np.zeros((m + 1, m), dt)
+    cs, sn = np.zeros(m, dt), np.zeros(m, dt)
+    g = np.zeros(m + 1, dt)
+    g[0] = float(beta)
+    k, history = 0, []
+    while k < m:
+        w = mv(V[k])
+        mask = (jnp.arange(m + 1) <= k).astype(beta.dtype)
+        hcol = (V.conj() @ w) * mask
+        w = w - hcol @ V
+        h2 = (V.conj() @ w) * mask
+        w = w - h2 @ V
+        hkk = jnp.sqrt(jnp.sum(jnp.real(w * jnp.conj(w))))
+        if float(hkk) > 1e-30:
+            V = V.at[k + 1].set(w / hkk)
+        col = np.asarray(hcol + h2).copy()
+        col[k + 1] = float(hkk)
+        for i in range(k):
+            col[i], col[i + 1] = (cs[i] * col[i] + sn[i] * col[i + 1],
+                                  -np.conj(sn[i]) * col[i] + cs[i] * col[i + 1])
+        a0, a1 = abs(col[k]), abs(col[k + 1])
+        denom = np.hypot(a0, a1)
+        if denom == 0:
+            return V, H, g, k, True, history
+        cs[k] = a0 / denom
+        sn[k] = ((col[k] / a0 if a0 else 1.0) * np.conj(col[k + 1])
+                 / (denom if a0 else a1))
+        col[k], col[k + 1] = cs[k] * col[k] + sn[k] * col[k + 1], 0.0
+        H[:, k] = col
+        g[k + 1] = -np.conj(sn[k]) * g[k]
+        g[k] = cs[k] * g[k]
+        k += 1
+        history.append(abs(g[k]))
+        if abs(g[k]) < float(target):
+            break
+    return V, H, g, k, False, history
+
+
+# restart: the step (no multiple of 8) at which the run that converges and
+# the run that breaks down end, inside a stage
+STAGED = {5: 3, 8: 6, 12: 9, 30: 19, 33: 27, 70: 37}
+
+
+@pytest.mark.parametrize("ends", ["whole", "converges", "breaks-down"])
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+@pytest.mark.parametrize("restart", sorted(STAGED))
+def test_the_staged_pass_is_the_whole_masked_pass(restart, dtype, ends):
+    """One stage (5); a stage's edge (8); several (12, 30); past 32 rows
+    (33); the widened block (70). Rows past the step's are zero, so the
+    stage's contractions drop terms that are zero and nothing else: the
+    process agrees with the masked full-basis one to float32's rounding."""
+    stop = STAGED[restart]
+    if ends == "breaks-down":
+        # a nilpotent shift from e_stop: the Krylov space closes after
+        # `stop` steps with nothing left to rotate
+        n = 96
+        phase = (0.6 + 0.8j) if np.issubdtype(dtype, np.complexfloating) else 1.0
+        A = sparse_tpu.csr_array(sp.diags(
+            [np.full(n - 1, phase, dtype)], [1], format="csr"))
+        b = jnp.zeros(n, dtype).at[stop].set(2.0)
+    else:
+        A, b = _box((12, 7, 5), dtype=dtype)
+    mv = linalg.make_linear_operator(A).matvec
+    beta = jnp.linalg.norm(b)
+    target = jnp.asarray(1e-30, beta.dtype)
+    if ends == "converges":
+        history = _masked_arnoldi(mv, b, beta, target, restart)[5]
+        target = jnp.asarray(np.sqrt(history[stop - 1] * history[stop - 2]),
+                             beta.dtype)  # between two steps' residuals
+    Vr, Hr, gr, kr, bdr, _ = _masked_arnoldi(mv, b, beta, target, restart)
+    V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta, target,
+                                           restart)
+    assert V.dtype == dtype and V.shape == (restart + 1, b.shape[0])
+    assert (int(k), bool(bd)) == (kr, bdr)
+    assert (kr, bdr) == {"whole": (restart, False), "converges": (stop, False),
+                         "breaks-down": (stop, True)}[ends]
+    k = kr
+    # rows past the last one written are zero in both
+    assert not np.asarray(V[k + 1 + (not bdr):]).any()
+    assert np.abs(np.asarray(V) - np.asarray(Vr)).max() <= 5e-5
+    assert np.abs(np.asarray(H)[:, :k] - Hr[:, :k]).max() <= 5e-5 * max(
+        1.0, np.abs(Hr).max())
+    assert np.abs(np.asarray(g)[:k + 1] - gr[:k + 1]).max() <= 5e-5 * float(beta)
+
+
+@pytest.mark.parametrize("path", ["program", "cycle-path"])
+@pytest.mark.parametrize("restart,kw", [
+    (30, {"maxiter": 2, "tol": 1e-30}),  # whole cycles: 570 / 30
+    (10, {"maxiter": 50, "tol": 1e-4}),  # ends inside a cycle
+    (5, {"maxiter": 3, "tol": 1e-30}),  # one stage: the whole basis
+], ids=["m30-whole", "m10-ends-early", "m5-one-stage"])
+def test_the_solve_span_counts_the_basis_rows_read(restart, kw, path, tel):
+    A, b = _box((6, 5, 4))
+    solve = linalg.gmres if path == "program" else _cycle_path
+    _x, iters = solve(A, b, restart=restart, **kw)
+    (ev,) = [e for e in telemetry.events("span") if e["name"] == "gmres.solve"]
+    assert ev["path"] == ("device" if path == "program" else "cycle")
+    rows = [min(8 * (k % restart // 8 + 1), restart + 1) for k in range(iters)]
+    assert ev["orth_rows"] == pytest.approx(sum(rows) / iters, abs=1e-3)
+    if restart == 30:
+        assert iters == 60 and ev["orth_rows"] == 19.0
+    elif restart == 10:
+        assert iters % restart and 8.0 < ev["orth_rows"] < 8.6
+    else:
+        assert ev["orth_rows"] == 6.0
+    assert telemetry.schema.validate(ev) == []
+
+
+@pytest.mark.parametrize("restart,staged", [(5, False), (7, False), (8, True),
+                                            (30, True)])
+def test_a_restart_of_one_stage_has_no_conditional(restart, staged):
+    """``restart + 1`` rows within one tile group: the whole masked pass, the
+    program the tree had; past it one ``conditional`` chooses the stage."""
+    A, b = _box((6, 5, 4))
+    text = linalg._gmres_compiled(A, b, restart).as_text()
+    assert len(re.findall(r" conditional\(", text)) == int(staged)
