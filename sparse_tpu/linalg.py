@@ -975,6 +975,18 @@ def _precond_fields(M) -> dict:
             else "closure"}
 
 
+def _operator_fields(A, M) -> dict:
+    """What a solve's span says of its two operators: the preconditioner's
+    fields, and the counts a declared ``A`` states of its own product
+    (``describe``: ``fine_stencil_kernels`` of ``gmg_grid.grid_operator``)
+    added to ``M``'s of the same name, so that the span's count is one
+    iteration's, the product and the preconditioner together."""
+    fields = _precond_fields(M)
+    for name, count in getattr(A, "describe", {}).items():
+        fields[name] = fields.get(name, 0) + count
+    return fields
+
+
 def _pcg(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply, m_apply,
          conv_test_iters, tapped):
     """Whole-solve preconditioned CG over declared operators: A's operands,
@@ -1051,7 +1063,7 @@ def _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters):
     # trace and the compile), `cg.iters_fetch` the wait for the iteration
     # count, the solve's one fence.
     with telemetry.span("cg.solve", path="device",
-                        **_precond_fields(M)) as solve:
+                        **_operator_fields(A, M)) as solve:
         with telemetry.span("cg.dispatch", emit=False) as sp:
             x, iters = _pcg_program(*args, **static)
         dispatch_s = sp.dur_s or 0.0

@@ -7,9 +7,19 @@ computed with general SpGEMM tasks, gmg.py:289-381).
 TPU-first redesign: on a structured grid every operator in the hierarchy is
 a <=9-point stencil, so nothing needs a general sparse format at all —
 
-* each level operator is a dict ``{(di, dj): [n, n] coefficient plane}``;
-  applying it is pad + 9 shifted multiply-adds, pure VPU work that XLA
-  fuses into one pass (no gather, no CSR indices, no Pallas pad/trim);
+* each level operator is a dict ``{(di, dj): [n, n] coefficient plane}``
+  (the fine level's 5-point Poisson stencil: five scalars);
+  applying it is pad + 9 shifted multiply-adds (:func:`stencil_apply`),
+  VPU work that XLA fuses into one pass: no gather, no CSR indices. Its
+  slices start one row or one column off the TPU's ``(8, 128)`` tile, which
+  at the fine level's size costs 3.8 times the pass's HBM time, so the
+  fine level's three applies of a preconditioned CG iteration (``A p``, the
+  cycle's residual and post-smoothing) take a Pallas kernel with the shifts
+  as rotations in VMEM, ``kernels/grid_stencil.py``, where the level's own
+  arrays show that it applies (:func:`_fine_kernel`: five float32 scalars,
+  a side that is a multiple of 128, one TPU). Nothing else does, and no
+  setting chooses: the coarse levels' planes, the builds here, a hierarchy
+  laid over a mesh and the CPU keep :func:`stencil_apply`;
 * the Galerkin product R A P is computed EXACTLY by probing the composed
   operator with period-3 comb vectors — 9 grid applies per level instead
   of two SpGEMMs + sorts (the r3-measured init was 52 s at n=4000, almost
@@ -42,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
+from ..kernels import grid_stencil
 
 __all__ = [
     "poisson_stencil",
@@ -296,27 +307,77 @@ def shard_hierarchy_grid(hierarchy, mesh, axis: str = "shards",
     return out, vec_sharding
 
 
+def _fine_kernel(st: dict, n: int, w=None) -> bool:
+    """Whether a level's applies take ``kernels.grid_stencil`` (the on-tile
+    form) rather than :func:`stencil_apply`, read off the level's own arrays
+    where the operator is declared: a 5-point stencil of float32 scalars
+    (and a scalar weight, where the smoother reads one), a side of whole
+    128-lane vregs, everything resident on one TPU. Plane coefficients (the
+    coarse levels), another side or dtype, a hierarchy laid over a mesh (its
+    scalars are replicated: several devices), the CPU and an outer trace all
+    read False and keep ``stencil_apply``, whose pad and slices GSPMD
+    derives its halo exchanges from."""
+    scalars = [*st.values()] + ([] if w is None else [w])
+    return (
+        n % grid_stencil.LANES == 0
+        and set(st) == grid_stencil.FIVE_POINT
+        and all(getattr(c, "shape", None) == () and c.dtype == jnp.float32
+                for c in scalars)
+        and _on_one_device(scalars)
+    )
+
+
+# the platform the kernel is compiled for. A test's CPU drive sets "cpu",
+# and the kernel then runs interpreted (_fine_stencil)
+_KERNEL_PLATFORM = "tpu"
+
+
+def _on_one_device(arrays) -> bool:
+    try:
+        devices = set().union(*(a.devices() for a in arrays))
+    except (AttributeError, TypeError):  # a numpy scalar; a tracer
+        return False
+    return len(devices) == 1 and next(iter(devices)).platform == _KERNEL_PLATFORM
+
+
+def _fine_stencil(form: str, offsets, planes, w, x, r=None):
+    """One use of the fine level's stencil through the kernel (the forms:
+    ``kernels/grid_stencil.py``)."""
+    scalars = jnp.stack([*planes] + ([] if w is None else [w]))
+    return grid_stencil.stencil5(
+        scalars, x, r, form=form, offsets=offsets,
+        interpret=jax.default_backend() != "tpu")  # a test's CPU drive
+
+
 @dataclasses.dataclass(frozen=True)
 class _Cycle:
     """``apply`` of the V-cycle operator: equal by value for two
     hierarchies of the same level sizes, offsets and grid operator, which
-    is what lets ``linalg.cg`` find its compiled program again."""
+    is what lets ``linalg.cg`` find its compiled program again.
+    ``fine_kernel``: level 0's two stencil applies are the kernel's
+    (:func:`_fine_kernel`, decided where the operator is declared)."""
 
     static: tuple  # per level (n, offsets)
     gridop: str
+    fine_kernel: bool = False
 
     def level(self, arrays, r, lvl):
         (planes, w), (n, offsets) = arrays[lvl], self.static[lvl]
         st = dict(zip(offsets, planes))
+        kernel = self.fine_kernel and lvl == 0 and r.dtype == jnp.float32
         with jax.named_scope(f"gmg.l{lvl}"):
             x = w * r
             if lvl == len(self.static) - 1:
                 return x
             cn = self.static[lvl + 1][0]
-            coarse_r = restrict_grid(r - stencil_apply(st, x), cn, self.gridop)
+            residual = (_fine_stencil("residual", offsets, planes, w, r)
+                        if kernel else r - stencil_apply(st, x))
+            coarse_r = restrict_grid(residual, cn, self.gridop)
         coarse_x = self.level(arrays, coarse_r, lvl + 1)
         with jax.named_scope(f"gmg.l{lvl}"):
             x = x + prolong_grid(coarse_x, n, cn, self.gridop)
+            if kernel:
+                return _fine_stencil("smooth", offsets, planes, w, x, r)
             return x + w * (r - stencil_apply(st, x))
 
     def __call__(self, arrays, r_flat):
@@ -326,14 +387,18 @@ class _Cycle:
 
 @dataclasses.dataclass(frozen=True)
 class _GridApply:
-    """``apply`` of one level's operator on flat vectors."""
+    """``apply`` of one level's operator on flat vectors; ``fine_kernel``
+    as :class:`_Cycle`'s."""
 
     n: int
     offsets: tuple
+    fine_kernel: bool = False
 
     def __call__(self, planes, v):
-        st = dict(zip(self.offsets, planes))
-        return stencil_apply(st, v.reshape(self.n, self.n)).reshape(-1)
+        x = v.reshape(self.n, self.n)
+        if self.fine_kernel and v.dtype == jnp.float32:
+            return _fine_stencil("apply", self.offsets, planes, None, x).reshape(-1)
+        return stencil_apply(dict(zip(self.offsets, planes)), x).reshape(-1)
 
 
 def _declare(apply, operands, n: int, **describe):
@@ -348,7 +413,9 @@ def grid_operator(hierarchy, lvl: int = 0):
     """Level ``lvl``'s operator as a ``LinearOperator`` on flat [N] vectors
     that declares its planes: the ``A`` of ``linalg.cg(A, b, M=vcycle)``."""
     st, _, n = hierarchy[lvl]
-    return _declare(_GridApply(n, tuple(st.keys())), tuple(st.values()), n)
+    kernel = _fine_kernel(st, n)
+    return _declare(_GridApply(n, tuple(st.keys()), kernel), tuple(st.values()),
+                    n, fine_stencil_kernels=int(kernel))
 
 
 def make_vcycle(hierarchy, gridop: str = "linear"):
@@ -362,5 +429,10 @@ def make_vcycle(hierarchy, gridop: str = "linear"):
     # grid size and the planes' offsets are the static rest
     arrays = tuple((tuple(st.values()), w) for st, w, _ in hierarchy)
     static = tuple((n, tuple(st.keys())) for st, _, n in hierarchy)
-    return _declare(_Cycle(static, gridop), arrays, hierarchy[0][2],
-                    precond="gmg_grid", levels=len(hierarchy))
+    st, w, n = hierarchy[0]
+    kernel = len(hierarchy) > 1 and _fine_kernel(st, n, w)
+    # an iteration's fine-level applies that take the kernel: the residual
+    # and the post-smoothing here, the product in grid_operator
+    return _declare(_Cycle(static, gridop, kernel), arrays, n,
+                    precond="gmg_grid", levels=len(hierarchy),
+                    fine_stencil_kernels=2 * int(kernel))

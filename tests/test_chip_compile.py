@@ -821,3 +821,111 @@ def test_gmres_program_ops_carry_their_scope(one_chip):
             assert re.search(r"= f32\[31,%d\]\S* parameter\(" % n, inner)
             if hi < 31:
                 assert f"slice={{[0:{hi}], [0:{n}]}}" in inner, (hi, fused)
+
+
+# ---------------------------------------------------------------------------
+# multigrid-preconditioned CG over declared operators (PR 40), the fine
+# level's three stencil applies through kernels/grid_stencil.py (PR 44): the
+# whole-solve program jit_pcg at the size of the benchmark's multigrid cell,
+# 4480^2 unknowns, three levels, full weighting
+# ---------------------------------------------------------------------------
+GMG_GRID, GMG_LEVELS = 4480, 3
+GMG_COMPILE_SECONDS = 30.0  # 2.7 s here alone; the parent's form 6 s
+
+
+def _gmg_pcg_compiled(one_chip, monkeypatch, fine_kernel: bool):
+    import time
+
+    from sparse_tpu import linalg
+    from sparse_tpu.models import gmg_grid
+
+    # `gmg_grid._fine_stencil` interprets the kernel off a TPU; this
+    # process's backend is the CPU and the program is compiled for the
+    # described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    five = tuple(gmg_grid.poisson_stencil(GMG_GRID))
+    nine = tuple((a, b) for a in (-1, 0, 1) for b in (-1, 0, 1))
+    sides = [GMG_GRID // 2 ** k for k in range(GMG_LEVELS)]
+    scalar = _sds((), jnp.float32, one_chip)
+    plane = lambda s: _sds((s, s), jnp.float32, one_chip)  # noqa: E731
+    m_operands = ((tuple(scalar for _ in five), scalar),) + tuple(
+        (tuple(plane(s) for _ in nine), plane(s)) for s in sides[1:])
+    static = ((GMG_GRID, five),) + tuple((s, nine) for s in sides[1:])
+    vec = _sds((GMG_GRID ** 2,), jnp.float32, one_chip)
+    lowered = linalg._pcg_program.lower(
+        tuple(scalar for _ in five), m_operands, vec, vec, scalar, 50,
+        a_apply=gmg_grid._GridApply(GMG_GRID, five, fine_kernel),
+        m_apply=gmg_grid._Cycle(static, "linear", fine_kernel),
+        conv_test_iters=25, tapped=False)
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _loop_body(text: str) -> str:
+    """The computation of the program's one ``while`` that holds the
+    V-cycle: CG's loop body."""
+    bodies = [m.group(0) for m in re.finditer(
+        r"\n%[\w.\-]+ \([^\n]*\{\n.*?\n\}\n", text, re.S)
+        if "/while/body/gmg.l0/" in m.group(0) and "fused_computation" not in
+        m.group(0).split("(", 1)[0]]
+    (body,) = bodies
+    return body
+
+
+def _fine_stencil_ops(text: str) -> list:
+    """The lines of XLA's own form of the fine level's stencil: the pad of
+    the grid to 4482^2 and the slices of it, which the compiler names by
+    ``stencil_apply``'s frame (``restrict_grid`` pads to 4482^2 too, under
+    its own name, and stays)."""
+    return [ln for ln in text.splitlines()
+            if re.search(r'op_name="[^"]*stencil_apply', ln)
+            and re.search(r"f32\[448[02],448[02]\]", ln)]
+
+
+def test_gmg_pcg_program_takes_the_fine_levels_kernels(one_chip, monkeypatch):
+    c, seconds = _gmg_pcg_compiled(one_chip, monkeypatch, fine_kernel=True)
+    text = c.as_text()
+    assert "jit_pcg" in text and _device_bytes(c) < HBM_BYTES
+    assert seconds < GMG_COMPILE_SECONDS, seconds
+    ma = c.memory_analysis()
+    # the hierarchy's planes and weights are arguments (level 1's ten grids;
+    # of the coarsest level, which only smooths, the weight alone is read),
+    # with b and the start; the loop's temporaries are the parent's (778.7
+    # MB there), no grid more for the kernels
+    held = 10 * 4 * 2240 ** 2 + 4 * 1120 ** 2 + 2 * 4 * GMG_GRID ** 2
+    assert held <= ma.argument_size_in_bytes < 0.4e9
+    assert ma.temp_size_in_bytes < 0.8e9
+    # no pad of the fine grid and no slice of it is left anywhere
+    assert not _fine_stencil_ops(text)
+    # the loop's three applies are the kernel's custom calls: the cycle's
+    # two under level 0's scope, A p under no level's
+    calls = [ln for ln in _loop_body(text).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = sorted(re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls)
+    assert len(names) == 3, names
+    scoped = [n for n in names if "/gmg.l0/" in n]
+    assert len(scoped) == 2 and not [n for n in names if re.search(r"gmg\.l[1-9]", n)]
+    assert sorted(re.search(r"grid_stencil5_(\w+)", n).group(1) for n in scoped) == [
+        "residual", "smooth"]
+    (product,) = [n for n in names if n not in scoped]
+    assert "grid_stencil5_apply" in product and "gmg." not in product
+    # a fourth, outside the loop: the start's residual b - A x0
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 4
+    # level 1 keeps stencil_apply under its own scope (level 2 only smooths)
+    assert re.search(r'op_name="[^"]*/gmg\.l1/jit\(stencil_apply\)', text)
+
+
+def test_gmg_pcg_program_without_the_kernels_is_the_parents(one_chip, monkeypatch):
+    """The other side: the program every other hierarchy keeps (a side off
+    128, planes, a mesh) pads the fine grid three times in the loop and
+    slices each pad off the tile."""
+    c, _seconds = _gmg_pcg_compiled(one_chip, monkeypatch, fine_kernel=False)
+    text = c.as_text()
+    assert _device_bytes(c) < HBM_BYTES and "tpu_custom_call" not in text
+    body_pads = [ln for ln in _fine_stencil_ops(text)
+                 if " pad(" in ln and "/while/body/" in ln]
+    assert len(body_pads) == 3, body_pads
+    assert len([ln for ln in body_pads if "/gmg.l0/" in ln]) == 2
+    assert [ln for ln in _fine_stencil_ops(text)
+            if "slice={[1:4481], [2:4482]}" in ln]

@@ -255,3 +255,129 @@ def test_sharded_grid_hierarchy_odd_sizes_replicate():
     got = jax.jit(gg.make_vcycle(hs, "linear"))(rs)
     want = jax.jit(gg.make_vcycle(hier, "linear"))(jnp.asarray(r))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-11)
+
+
+# -- the fine level's on-tile form (kernels/grid_stencil.py, PR 44) -----------
+FIVE = {(0, 0): 4.25, (-1, 0): -1.5, (1, 0): -0.75, (0, -1): -1.25, (0, 1): -0.5}
+USES = {
+    "apply": lambda A, w, x, r: A(x),
+    "residual": lambda A, w, x, r: x - A(w * x),
+    "smooth": lambda A, w, x, r: x + w * (r - A(x)),
+}
+
+
+def five_point_sp(N, coef):
+    """The 5-point stencil ``coef`` ({(di, dj): value}) as a scipy matrix on
+    the flat grid, couplings across the grid's edge dropped."""
+    i, j = np.divmod(np.arange(N * N), N)
+    rows, cols, vals = [], [], []
+    for (di, dj), c in coef.items():
+        ok = (i + di >= 0) & (i + di < N) & (j + dj >= 0) & (j + dj < N)
+        rows.append(np.arange(N * N)[ok])
+        cols.append(((i + di) * N + j + dj)[ok])
+        vals.append(np.full(int(ok.sum()), float(c)))
+    return sp.coo_matrix((np.concatenate(vals), (np.concatenate(rows),
+                                                 np.concatenate(cols))),
+                         shape=(N * N, N * N)).tocsr()
+
+
+def _edge_heavy(n, seed):
+    """A grid whose boundary rows and columns are its largest entries: the
+    edge mask and the halo rows are where the on-tile form can go wrong."""
+    g = np.random.default_rng(seed).standard_normal((n, n))
+    for edge in (g[0], g[-1], g[:, 0], g[:, -1]):
+        edge *= 50.0
+    return g.astype(np.float32)
+
+
+@pytest.mark.parametrize("coef", [None, FIVE], ids=["poisson", "five-distinct"])
+@pytest.mark.parametrize("n", [128, 256, 384])
+@pytest.mark.parametrize("use", list(USES))
+def test_on_tile_stencil_equals_stencil_apply_and_scipy(use, n, coef):
+    """Each of the fine level's three uses through the kernel (interpreted
+    on the CPU), at one, two and three row blocks: a first, a middle and a
+    last block, halo rows from both neighbours. Distinct coefficients tell
+    the four neighbours apart, which Poisson's -1s do not."""
+    st = gg.poisson_stencil(n) if coef is None else {
+        d: jnp.asarray(c, jnp.float32) for d, c in coef.items()}
+    S = poisson_sp(n) if coef is None else five_point_sp(n, coef)
+    w = jnp.asarray(0.3, jnp.float32)
+    x, r = _edge_heavy(n, 1), _edge_heavy(n, 2)
+    got = gg._fine_stencil(use, tuple(st), tuple(st.values()),
+                           None if use == "apply" else w,
+                           jnp.asarray(x), jnp.asarray(r) if use == "smooth" else None)
+    assert got.dtype == jnp.float32 and got.shape == (n, n)
+    by_xla = USES[use](lambda v: gg.stencil_apply(st, v), w, jnp.asarray(x),
+                       jnp.asarray(r))
+    by_scipy = USES[use](
+        lambda v: (S @ v.astype(np.float64).reshape(-1)).reshape(n, n),
+        0.3, x, r)
+    scale = np.abs(by_scipy).max()
+    assert np.abs(np.asarray(got) - np.asarray(by_xla)).max() <= 2e-6 * scale
+    assert np.abs(np.asarray(got) - by_scipy).max() <= 2e-6 * scale
+    # row 0 and the last row, column 0 and the last column, by themselves
+    for edge in (np.s_[0], np.s_[-1], np.s_[:, 0], np.s_[:, -1]):
+        assert np.abs(np.asarray(got)[edge] - by_scipy[edge]).max() <= 2e-6 * scale
+
+
+def _shard8(hier):
+    from sparse_tpu.parallel.mesh import get_mesh
+
+    return gg.shard_hierarchy_grid(hier, get_mesh(8), replicate_below=1024)[0]
+
+
+WHO_KEEPS_STENCIL_APPLY = {
+    # a side off a multiple of 128
+    "side-130": lambda: gg.build_hierarchy(130, 2),
+    # nine coefficient planes: a coarse level's operator as the fine one
+    "planes": lambda: gg.build_hierarchy(
+        128, 2, planes=gg.build_hierarchy(256, 2)[1][0]),
+    # float64 scalars
+    "float64": lambda: gg.build_hierarchy(128, 2, dtype=jnp.float64),
+    # scalars replicated over a mesh: GSPMD's halo exchanges come from the
+    # pad and the slices
+    "sharded": lambda: _shard8(gg.build_hierarchy(128, 2)),
+}
+
+
+@pytest.mark.parametrize("who", list(WHO_KEEPS_STENCIL_APPLY))
+def test_who_does_not_fit_the_kernel_keeps_stencil_apply_and_its_bits(
+        who, monkeypatch):
+    """With the kernel's platform set to this process's (the CPU: a
+    hierarchy of float32 scalars at a side of 128 on one device would take
+    the kernel, interpreted), these keep the operators they had: equal
+    ``apply`` objects, so the same programs, and the same bits."""
+    hier = WHO_KEEPS_STENCIL_APPLY[who]()
+    n = hier[0][2]
+    A0, M0 = gg.grid_operator(hier), gg.make_vcycle(hier)
+    monkeypatch.setattr(gg, "_KERNEL_PLATFORM", "cpu")
+    A, M = gg.grid_operator(hier), gg.make_vcycle(hier)
+    assert A.apply == A0.apply == gg._GridApply(n, tuple(hier[0][0]))
+    assert M.apply == M0.apply and not M.apply.fine_kernel
+    assert A.describe == {"fine_stencil_kernels": 0}
+    assert M.describe == {"precond": "gmg_grid", "levels": 2,
+                          "fine_stencil_kernels": 0}
+    r = jnp.asarray(np.random.default_rng(5).random(n * n), hier[0][1].dtype)
+    assert np.array_equal(np.asarray(A.matvec(r)), np.asarray(A0.matvec(r)))
+    assert np.array_equal(np.asarray(M.matvec(r)), np.asarray(M0.matvec(r)))
+    want = gg.stencil_apply(hier[0][0], r.reshape(n, n)).reshape(-1)
+    assert np.array_equal(np.asarray(A.matvec(r)), np.asarray(want))
+    # the control: the plain hierarchy at this side does take the kernel
+    plain = gg.build_hierarchy(128, 2)
+    assert gg.grid_operator(plain).apply.fine_kernel
+    assert gg.make_vcycle(plain).describe["fine_stencil_kernels"] == 2
+
+
+def test_the_kernels_cycle_is_the_matrix_cycle(monkeypatch):
+    """One V-cycle with level 0's residual and post-smoothing through the
+    kernel against the same cycle through ``stencil_apply``."""
+    monkeypatch.setattr(gg, "_KERNEL_PLATFORM", "cpu")
+    hier = gg.build_hierarchy(256, 3)
+    M = gg.make_vcycle(hier)
+    assert M.apply.fine_kernel
+    r = jnp.asarray(_edge_heavy(256, 4).reshape(-1))
+    want = gg._Cycle(M.apply.static, "linear")(M.operands, r)
+    got = M.matvec(r)
+    assert float(jnp.abs(got - want).max()) <= 2e-6 * float(jnp.abs(want).max())
+    # a one-level "hierarchy" applies no stencil: nothing for the kernel
+    assert not gg.make_vcycle(hier[:1]).apply.fine_kernel

@@ -10,6 +10,8 @@ solve of the same structure, whatever the values, traces nothing
 (``_cg_device_loop``), which an operator without operands still runs.
 """
 
+import re
+
 import numpy as np
 import pytest
 import scipy.sparse as sp
@@ -234,7 +236,9 @@ def test_a_declared_operator_is_a_linear_operator_with_its_product():
     r = _rhs(33 * 33)
     assert A.shape == M.shape == (33 * 33, 33 * 33)
     assert A.dtype == M.dtype == np.float32
-    assert M.describe == {"precond": "gmg_grid", "levels": 3}
+    assert M.describe == {"precond": "gmg_grid", "levels": 3,
+                          "fine_stencil_kernels": 0}
+    assert A.describe == {"fine_stencil_kernels": 0}
     want = gg.stencil_apply(hier[0][0], r.reshape(33, 33)).reshape(-1)
     assert np.array_equal(np.asarray(A.matvec(r)), np.asarray(want))
     assert np.array_equal(np.asarray(M(r)), np.asarray(M.matvec(r)))
@@ -293,6 +297,7 @@ def test_one_cg_solve_span_a_call_and_one_build_span_a_hierarchy(
         assert ev["name"] == "cg.solve"
         assert (ev["path"], ev["precond"], ev["levels"], ev["iters"]) == (
             "device", "gmg_grid", levels, iters)
+        assert ev["fine_stencil_kernels"] == 0  # the CPU: stencil_apply
         assert 0 < ev["dispatch_s"] and 0 <= ev["fetch_s"]
         assert ev["dispatch_s"] + ev["fetch_s"] <= ev["dur_s"]
     assert telemetry.events("solver.solve")[-1]["path"] == "device"
@@ -317,3 +322,75 @@ def test_the_compiled_program_names_each_level_and_is_jits_own():
     assert "/gmg.l3/" not in text
     # the scopes do not nest: an op stands under its own level alone
     assert "gmg.l0/gmg.l1" not in text and "gmg.l1/gmg.l2" not in text
+
+
+# -- the fine level's kernel inside the program (PR 44) --------------------------------
+@pytest.fixture
+def kernel_here(monkeypatch):
+    """The fine level's kernel is taken where the hierarchy's scalars sit on
+    one TPU; here the platform is this process's, and it runs interpreted."""
+    monkeypatch.setattr(gg, "_KERNEL_PLATFORM", jax.default_backend())
+
+
+@pytest.mark.parametrize("again", AGAIN, ids=AGAIN_IDS)
+def test_a_later_solve_through_the_kernel_traces_nothing(again, kernel_here):
+    A, M, b = _grid(128, 2, "linear")
+    assert A.apply.fine_kernel and M.apply.fine_kernel
+    t0 = TRACES.value
+    linalg.cg(A, b, maxiter=6, M=M)
+    assert TRACES.value == t0 + 1
+    A2, M2, b2, kw = again(A, M, b, lambda: _grid(128, 2, "linear", omega=1.1))
+    kw = {"maxiter": 6, **kw}
+    x, iters = linalg.cg(A2, b2, M=M2, **kw)
+    assert TRACES.value == t0 + 1
+    assert 0 < iters <= kw["maxiter"] and np.all(np.isfinite(np.asarray(x)))
+
+
+def test_the_kernel_is_part_of_the_programs_identity(kernel_here, monkeypatch):
+    A, M, b = _grid(128, 2, "linear")
+    linalg.cg(A, b, maxiter=3, M=M)
+    monkeypatch.setattr(gg, "_KERNEL_PLATFORM", "tpu")  # no kernel here
+    A0, M0, _ = _grid(128, 2, "linear")
+    assert (A0.apply, M0.apply) != (A.apply, M.apply)
+    assert A0.apply == gg._GridApply(128, A.apply.offsets)
+    t0 = TRACES.value
+    linalg.cg(A0, b, maxiter=3, M=M0)
+    assert TRACES.value == t0 + 1
+
+
+@pytest.mark.parametrize("n,levels", [(128, 2), (256, 3)])
+def test_the_kernel_program_gives_the_closure_loops_answer(n, levels, kernel_here,
+                                                           monkeypatch, tel):
+    A, M, b = _grid(n, levels, "linear")
+    x, iters = linalg.cg(A, b, maxiter=20, M=M)
+    (ev,) = [e for e in telemetry.events("span") if e["name"] == "cg.solve"]
+    assert (ev["path"], ev["precond"], ev["levels"]) == ("device", "gmg_grid", levels)
+    assert ev["fine_stencil_kernels"] == 3  # A p, the residual, the post-smoothing
+    # the closure loop over the same two products: the kernel's, op for op
+    t0 = TRACES.value
+    Ac, Mc = _as_closures(A, M)
+    xc, ic = linalg.cg(Ac, b, maxiter=20, M=Mc)
+    assert TRACES.value == t0 and iters == ic
+    assert float(jnp.linalg.norm(x - xc)) <= 1e-6 * float(jnp.linalg.norm(xc))
+    # and the program without the kernel: stencil_apply's sum, rounded apart
+    monkeypatch.setattr(gg, "_KERNEL_PLATFORM", "tpu")
+    A0, M0, _ = _grid(n, levels, "linear")
+    x0, i0 = linalg.cg(A0, b, maxiter=20, M=M0)
+    assert i0 == iters
+    assert float(jnp.linalg.norm(x - x0)) <= 1e-4 * float(jnp.linalg.norm(x0))
+    assert telemetry.events("span")[-1]["fine_stencil_kernels"] == 0
+
+
+def test_the_kernels_stand_under_level_0s_scope_and_the_product_under_none(
+        kernel_here):
+    """What ``pcg_vcycle_pct`` and ``pcg_coarse_pct`` read: the cycle's two
+    kernels carry ``gmg.l0`` in their ops' names, ``A p``'s carries no
+    level's (here the kernels are interpreted, plain ops under the kernel's
+    name; ``tests/test_chip_compile.py`` reads the custom calls)."""
+    A, M, b = _grid(128, 3, "linear")
+    text = linalg._pcg_compiled(A, b, M).as_text()
+    names = set(re.findall(r'op_name="([^"]*grid_stencil5_\w+)', text))
+    assert names and not [n for n in names if "gmg.l1" in n or "gmg.l2" in n]
+    for form, scoped in (("residual", True), ("smooth", True), ("apply", False)):
+        mine = [n for n in names if n.endswith("grid_stencil5_" + form)]
+        assert mine and all(("/gmg.l0/" in n) == scoped for n in mine), (form, mine)
