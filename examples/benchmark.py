@@ -117,8 +117,7 @@ def solve_timed_best_of_2(solve, timer):
 
     ``solve`` is a zero-arg callable returning (x, iters) with identical
     arguments each call, so the timed calls reuse the compiled while_loop.
-    Prints the disclosure lines (bench.py parses "Iterations / sec
-    (mean)") and returns (x, iters, min_ms).
+    Prints the disclosure lines ("Iterations / sec (mean)") and returns (x, iters, min_ms).
     """
     _ = solve()
     timer.start()

@@ -752,8 +752,8 @@ class SolveSession:
         of a program the replay is still building wait for it instead
         of rebuilding (zero serving-path builds, chaos scenario 10).
         ``False`` replays synchronously during construction (the
-        pre-pipeline behavior; bench's ``cold_start`` row uses it so
-        ``replay_s`` keeps measuring the replay itself). Reading
+        pre-pipeline behavior, under which ``replay_s`` measures the
+        replay itself). Reading
         ``warm_replayed`` joins the thread.
     profile_every : sampled timed-dispatch device profiling (ISSUE 12):
         every Nth dispatched bucket splits its solve wall clock into

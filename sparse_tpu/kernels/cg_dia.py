@@ -37,7 +37,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -212,249 +211,6 @@ def _kernel_b():
         rr_ref[0, 0] += jnp.sum(rr * rr)
 
     return kernel
-
-
-def _kernel_cgcg(offsets: tuple, TM: int, B: int, win: int, D: int, m_pad: int):
-    """One-pass Chronopoulos-Gear CG iteration.
-
-    Given beta_j and alpha_j (scalars), one sweep computes
-        s_j = w_j + beta s_{j-1}          (in the halo window)
-        r_{j+1} = r_j - alpha s_j         (in the halo window)
-        w_{j+1} = A r_{j+1}               (row-indexed planes)
-        p_j = r_j + beta p_{j-1};  x_{j+1} = x_j + alpha p_j
-    plus both reduction partials rho_{j+1} = <r,r> and mu_{j+1} = <w,r>.
-    The halo regions of s/r are recomputed redundantly per tile (same
-    trade as kernel A: FLOPs for a barrier). Sem slots: 0=r, 1=w, 2=s
-    (windows), 3=p, 4=x (tiles), 5..5+D-1=planes. Planes land in D
-    separate 1-D (TM,) VMEM buffers per slot (Mosaic DMA alignment)."""
-
-    def kernel(ab_ref, r_hbm, w_hbm, s_hbm, p_hbm, x_hbm, planes_hbm,
-               xo_ref, ro_ref, po_ref, so_ref, wo_ref, dots_ref,
-               *scr):
-        rwinA, wwinA, swinA, ptileA, xtileA = scr[:5]
-        dwinA = scr[5 : 5 + D]
-        rwinB, wwinB, swinB, ptileB, xtileB = scr[5 + D : 10 + D]
-        dwinB = scr[10 + D : 10 + 2 * D]
-        semA, semB = scr[10 + 2 * D :]
-        bufA = (rwinA, wwinA, swinA, ptileA, xtileA, dwinA)
-        bufB = (rwinB, wwinB, swinB, ptileB, xtileB, dwinB)
-        gg = pl.program_id(0)
-        Gp2 = pl.num_programs(0)
-
-        @pl.when(gg == 0)
-        def _():
-            dots_ref[0, 0] = jnp.zeros((), dots_ref.dtype)
-            dots_ref[0, 1] = jnp.zeros((), dots_ref.dtype)
-
-        def copies(buf, sem, g2):
-            # see _kernel_a: assert 1024-divisibility past the subtraction
-            start = pl.multiple_of(g2 * TM - B, 1024)
-            rwin, wwin, swin, ptile, xtile, dwin = buf
-            yield pltpu.make_async_copy(
-                r_hbm.at[pl.ds(start, win)], rwin, sem.at[0]
-            )
-            yield pltpu.make_async_copy(
-                w_hbm.at[pl.ds(start, win)], wwin, sem.at[1]
-            )
-            yield pltpu.make_async_copy(
-                s_hbm.at[pl.ds(start, win)], swin, sem.at[2]
-            )
-            yield pltpu.make_async_copy(
-                p_hbm.at[pl.ds(g2 * TM, TM)], ptile, sem.at[3]
-            )
-            yield pltpu.make_async_copy(
-                x_hbm.at[pl.ds(g2 * TM, TM)], xtile, sem.at[4]
-            )
-            for k in range(D):
-                yield pltpu.make_async_copy(
-                    planes_hbm.at[
-                        pl.ds(pl.multiple_of(k * m_pad + (g2 - 1) * TM, TM), TM)
-                    ],
-                    dwin[k],
-                    sem.at[5 + k],
-                )
-
-        def issue(buf, sem, g2):
-            for c in copies(buf, sem, g2):
-                c.start()
-
-        def wait(buf, sem, g2):
-            for c in copies(buf, sem, g2):
-                c.wait()
-
-        def interior(buf, sem, buf_n, sem_n):
-            @pl.when(gg == 1)
-            def _():
-                issue(buf, sem, gg)
-
-            @pl.when(gg + 1 < Gp2 - 1)
-            def _():
-                issue(buf_n, sem_n, gg + 1)
-
-            wait(buf, sem, gg)
-            rwin, wwin, swin, ptile, xtile, dwin = buf
-            beta = ab_ref[0, 0]
-            alpha = ab_ref[0, 1]
-            s_new = wwin[:] + beta * swin[:]        # s_j on the window
-            r_new = rwin[:] - alpha * s_new         # r_{j+1} on the window
-            acc = jnp.zeros((TM,), dtype=wo_ref.dtype)
-            for k, o in enumerate(offsets):
-                lo = B + int(o)
-                acc = acc + dwin[k][:].astype(acc.dtype) * r_new[lo : lo + TM]
-            p_new = rwin[B : B + TM] + beta * ptile[:]
-            xo_ref[:] = xtile[:] + alpha * p_new
-            r_mid = r_new[B : B + TM]
-            ro_ref[:] = r_mid
-            po_ref[:] = p_new
-            so_ref[:] = s_new[B : B + TM]
-            wo_ref[:] = acc
-            # both recurrence dot partials reduce at dots_ref's dtype
-            # (the acc_dtype split — ISSUE 15)
-            r_wide = r_mid.astype(dots_ref.dtype)
-            dots_ref[0, 0] += jnp.sum(r_wide * r_wide)
-            dots_ref[0, 1] += jnp.sum(acc.astype(dots_ref.dtype) * r_wide)
-
-        def halo():
-            z = jnp.zeros((TM,), xo_ref.dtype)
-            xo_ref[:] = z
-            ro_ref[:] = z
-            po_ref[:] = z
-            so_ref[:] = z
-            wo_ref[:] = z
-
-        is_halo = (gg == 0) | (gg == Gp2 - 1)
-
-        @pl.when(~is_halo & (gg % 2 == 1))
-        def _():
-            interior(bufA, semA, bufB, semB)
-
-        @pl.when(~is_halo & (gg % 2 == 0))
-        def _():
-            interior(bufB, semB, bufA, semA)
-
-        @pl.when(is_halo)
-        def _():
-            halo()
-
-    return kernel
-
-
-@partial(
-    jax.jit,
-    static_argnames=("offsets", "m", "iters", "tile", "plane_dtype",
-                     "interpret", "acc_dtype"),
-)
-def cg_dia_fused_onepass(
-    data, offsets: tuple, b, x0, m: int, iters: int = 300, tile: int = 16384,
-    plane_dtype=None, interpret: bool = False, acc_dtype=None
-):
-    """``iters`` Chronopoulos-Gear CG iterations — ONE fused pass each.
-
-    Mathematically equivalent to CG (exact arithmetic): the two dot
-    products <r,r> and <Ar, r> of the NEXT iteration are computed inside
-    the same sweep that applies the current update, so each iteration is a
-    single kernel launch + one scalar recurrence instead of two passes.
-    alpha comes from the CG-CG recurrence
-        alpha_j = rho_j / (mu_j - (beta_j / alpha_{j-1}) rho_j)
-    Slightly weaker numerically than two-pass CG (classic s-step result);
-    the bench checks residual parity before preferring it.
-
-    ``acc_dtype`` splits the recurrence scalars from the vector dtype
-    (ISSUE 15): the rho/mu dot partials reduce — and the beta/alpha
-    recurrence runs — at ``acc_dtype`` while vectors and plane streams
-    stay at ``dt``/``plane_dtype``. ``None`` = historic single-dtype
-    behavior, byte-identical.
-
-    Returns (x, r, rho).
-    """
-    dt = jnp.result_type(data.dtype, b.dtype)
-    adt = jnp.dtype(acc_dtype) if acc_dtype is not None else dt
-    TM, B, G = _plan(m, offsets, tile=tile)
-    win = TM + 2 * B
-    m_pad = G * TM
-    L = (G + 2) * TM
-    D = len(offsets)
-
-    pdt = _resolve_plane_dtype(plane_dtype, dt, TM)
-    planes_row = _row_planes(data.astype(pdt), offsets, TM, B, G, m)
-
-    kern = pl.pallas_call(
-        _kernel_cgcg(offsets, TM, B, win, D, m_pad),
-        name="cg_dia_onepass",
-        grid=(G + 2,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * 6,
-        out_specs=[
-            pl.BlockSpec((TM,), lambda gg: (gg,), memory_space=pltpu.VMEM)
-            for _ in range(5)
-        ]
-        + [pl.BlockSpec((1, 2), lambda gg: (0, 0), memory_space=pltpu.SMEM)],
-        out_shape=[jax.ShapeDtypeStruct((L,), dt) for _ in range(5)]
-        + [jax.ShapeDtypeStruct((1, 2), adt)],
-        scratch_shapes=(
-            [
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((TM,), dt),
-                pltpu.VMEM((TM,), dt),
-            ]
-            + [pltpu.VMEM((TM,), pdt)] * D
-            + [
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((win,), dt),
-                pltpu.VMEM((TM,), dt),
-                pltpu.VMEM((TM,), dt),
-            ]
-            + [pltpu.VMEM((TM,), pdt)] * D
-            + [
-                pltpu.SemaphoreType.DMA((5 + D,)),
-                pltpu.SemaphoreType.DMA((5 + D,)),
-            ]
-        ),
-        interpret=interpret,
-    )
-
-    from ..ops.dia_spmv import dia_spmv_xla
-
-    if x0 is None:
-        r0 = b.astype(dt)
-        xp = jnp.zeros((L,), dt)
-    else:
-        r0 = b.astype(dt) - dia_spmv_xla(
-            data.astype(dt), offsets, x0.astype(dt), (m, m)
-        )
-        xp = _pad_vec(x0.astype(dt), TM, G)
-    rp0 = _pad_vec(r0, TM, G)
-    w0 = dia_spmv_xla(data.astype(dt), offsets, r0, (m, m))
-    wp0 = _pad_vec(w0, TM, G)
-    rho0 = jnp.vdot(r0, r0).real.astype(adt)
-    mu0 = jnp.vdot(w0, r0).real.astype(adt)
-    z = jnp.zeros((L,), dt)
-
-    def body(j, state):
-        xp, rp, pp, sp, wp, rho, mu, rho_prev, alpha_prev = state
-        # Converged-state guards: once rho hits exact zero every later
-        # alpha/beta must collapse to 0 (not NaN) so the frozen x survives
-        # the remaining fixed iterations. The scalar recurrence runs at
-        # adt (the acc_dtype split); only the SMEM kernel inputs cast
-        # down to the vector dtype.
-        beta = jnp.where(rho_prev == 0, 0.0, rho / jnp.where(rho_prev == 0, 1, rho_prev)).astype(adt)
-        ratio = jnp.where(alpha_prev == 0, 0.0, beta / jnp.where(alpha_prev == 0, 1, alpha_prev))
-        denom = mu - ratio * rho
-        alpha = jnp.where(denom == 0, 0.0, rho / jnp.where(denom == 0, 1, denom)).astype(adt)
-        ab = jnp.stack([beta.astype(dt), alpha.astype(dt)]).reshape(1, 2)
-        xp2, rp2, pp2, sp2, wp2, dots = kern(ab, rp, wp, sp, pp, xp, planes_row)
-        alpha_next = jnp.where(alpha == 0, 1.0, alpha).astype(adt)
-        return (
-            xp2, rp2, pp2, sp2, wp2,
-            dots[0, 0], dots[0, 1], rho, alpha_next,
-        )
-
-    state = (xp, rp0, z, z, wp0, rho0, mu0, jnp.zeros((), adt), jnp.ones((), adt))
-    xp, rp, _, _, _, rho, _, _, _ = jax.lax.fori_loop(0, iters, body, state)
-    return _unpad_vec(xp, m, TM), _unpad_vec(rp, m, TM), rho
 
 
 @partial(jax.jit, static_argnames=("offsets", "m", "tile", "plane_dtype"))

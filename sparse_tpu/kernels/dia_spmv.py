@@ -247,7 +247,7 @@ def _spmv_chain(planes_flat, x_padded, plan: DiaPlan, iters: int,
     """``iters`` dependent SpMVs compiled as ONE dispatch (y feeds the next
     x window), for wall-clock timing that per-dispatch host latency
     cannot contaminate — the best-of-chain measurement discipline
-    behind the autotuner and the bench's packed-DIA row."""
+    behind the autotuner."""
 
     def body(_, xp):
         y = dia_spmv_packed(planes_flat, xp, plan, interpret=interpret)
@@ -314,7 +314,7 @@ def autotune_dia_tile(
         telemetry.count("autotune.cache_hit")
         return _TILE_CACHE[key]
     # the off-switch (SPARSE_TPU_PALLAS_AUTOTUNE=0) gates EVERY probe
-    # path, incl. bench's direct calls — it exists so an operator can
+    # path, direct calls included — it exists so an operator can
     # forbid the extra cold Mosaic compiles.
     # The gate result is NOT memoized (ADVICE r5): caching it under the
     # geometry key would make a later same-session flip of the setting

@@ -522,39 +522,44 @@ def cg(
     runs the host loop."""
     assert atol is None, "atol is not supported."
     b = asjnp(b)
-    n = b.shape[0]
     if maxiter is None:
-        maxiter = n * 10
-    if M is None and callback is None:
-        fused = _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters)
-        if fused is not None:
-            x_f, it_f, rho_f, info_f = fused
-            # info_f != 0 distinguishes a nonfinite-rho exit (-1) and a
-            # maxiter exit (iters) from convergence (0) — the final rho
-            # rides the health report so the recovery policy engine sees
-            # breakdowns even on paths with no per-iter taps (ISSUE 5)
-            _solve_event(
-                "cg", n, it_f, "fused", resid2=rho_f, converged=info_f == 0
-            )
-            return x_f, it_f
-    A = make_linear_operator(A)
-    if M is None and callback is None:
-        out = _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters)
-        if out is not None:
-            _solve_event("cg", n, out[1], "device")
-            return out
-    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
-    if callback is None:
-        out = _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters)
-        if out is not None:
-            _solve_event("cg", n, out[1], "device")
-            return out
-    x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
+        maxiter = b.shape[0] * 10
+    x, iters, path, health = _cg_take(
+        A, b, x0, tol, maxiter, M, callback, conv_test_iters)
+    _solve_event("cg", b.shape[0], iters, path, **health)
+    return x, iters
 
+
+def _cg_take(A, b, x0, tol, maxiter, M, callback, conv_test_iters):
+    """:func:`cg`'s drivers, tried in order; the first to take the solve
+    gives ``(x, iters, path, health)``, ``health`` what only it knows of
+    the fields of the solve's event."""
+    plain = M is None and callback is None
+    fused = plain and _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters)
+    if fused:
+        x, iters, rho_f, info = fused
+        # info != 0 distinguishes a nonfinite-rho exit (-1) and a maxiter
+        # exit (iters) from convergence (0) — the final rho rides the
+        # health report so the recovery policy engine sees breakdowns even
+        # on paths with no per-iter taps (ISSUE 5)
+        return x, iters, "fused", {"resid2": rho_f, "converged": info == 0}
+    A = make_linear_operator(A)
+    out = plain and _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters)
+    if out:
+        return *out, "device", {}
+    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
     if callback is not None:
-        out = _cg_host_loop(A, b, x, tol, maxiter, M, callback, conv_test_iters)
-        _solve_event("cg", n, out[1], "host")
-        return out
+        return *_cg_host_loop(A, b, x, tol, maxiter, M, callback,
+                              conv_test_iters), "host", {}
+    # both sides declared: `jit_pcg`, found again by the two `apply`
+    # functions and the operands' structure (another b, x0, tol, maxiter,
+    # other values, another operator of the same shapes: no trace)
+    call = _declared_call(A, M, b, x, tol, maxiter,
+                          conv_test_iters=int(conv_test_iters))
+    if call is not None:
+        out = _run_compiled_solve(_PCG, call, _operator_fields(A, M))
+        return *out, "device", {}
 
     # a closure on either side: the loop of this call
     r = b - A.matvec(x)
@@ -566,9 +571,8 @@ def cg(
         # kernel path for the whole solve
         if not isinstance(M, IdentityOperator):
             M.matvec(r)
-        out = _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters)
-        _solve_event("cg", n, out[1], "device")
-        return out
+        return *_cg_device_loop(A, b, x, r, tol, maxiter, M,
+                                conv_test_iters), "device", {}
     except (
         jax.errors.TracerArrayConversionError,
         jax.errors.TracerBoolConversionError,
@@ -576,9 +580,8 @@ def cg(
     ):
         # A or M is a host-side Python operator (e.g. a numpy-based
         # preconditioner): run the reference-style host loop instead
-        out = _cg_host_loop(A, b, x, tol, maxiter, M, None, conv_test_iters)
-        _solve_event("cg", n, out[1], "host")
-        return out
+        return *_cg_host_loop(A, b, x, tol, maxiter, M, None,
+                              conv_test_iters), "host", {}
 
 
 def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
@@ -802,6 +805,74 @@ def _cg_device_loop(A, b, x, r, tol, maxiter, M, conv_test_iters):
     return out
 
 
+# -- compiled whole solves ---------------------------------------------------
+# `_cg_general` (a matrix, no M), `_pcg` and `_gmres` (A and M declared) each
+# run a whole solve as ONE compiled program over the operators' operands.
+# Theirs are the program and a `_CompiledSolve`; the rest is shared below.
+
+
+@dataclasses.dataclass(frozen=True)
+class _CompiledSolve:
+    """A compiled whole-solve program as its driver needs it: the
+    module-level ``jax.jit`` (``program(*args, **static)`` gives ``(x,
+    counts)``), the names of the solve's span, of the call's and of the
+    fetch's, and ``fields(counts, static)``, which fetches ``counts`` (the
+    solve's one fence) and gives the span's fields, ``iters`` among them."""
+
+    program: object
+    spans: tuple
+    fields: object
+
+
+def _run_compiled_solve(solve_of, call, fields):
+    """The host's side of a compiled whole solve, ``call`` its ``(args,
+    static)``: ``(x, iters)``. One ``<solver>.solve`` span a call, with
+    ``path="device"`` and the caller's ``fields``; inside it
+    ``<solver>.dispatch`` is the program's call until it returns
+    (asynchronous: the host's part, and on a structure's first call the
+    trace and the compile) and the fetch span the wait for the counts.
+    Both are trace annotations and aggregates only; their lengths go onto
+    the solve's event as ``dispatch_s`` and ``fetch_s``."""
+    name, dispatch, fetch = solve_of.spans
+    args, static = call
+    with telemetry.span(name, path="device", **fields) as solve:
+        with telemetry.span(dispatch, emit=False) as sp:
+            x, counts = solve_of.program(*args, **static)
+        dispatch_s = sp.dur_s or 0.0
+        with telemetry.span(fetch, emit=False) as sp:
+            counted = solve_of.fields(counts, static)
+        solve.annotate(**counted, dispatch_s=round(dispatch_s, 9),
+                       fetch_s=round(sp.dur_s or 0.0, 9))
+    if static["tapped"]:
+        _effects_barrier()
+    return x, counted["iters"]
+
+
+def _compiled_solve(solve_of, A, M, b, x, stop, **static):
+    """The executable the solve of ``b`` over ``A`` and ``M`` runs
+    (``jax.stages.Compiled``: its HLO text with every op's ``named_scope``,
+    its memory analysis), or None where that solve takes another path. It is
+    jit's own: after a solve of the same structure this traces and compiles
+    nothing. For tools that read a device trace against the program (the
+    benchmark's per-level and per-scope shares)."""
+    A = make_linear_operator(A)
+    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    call = _declared_call(A, M, b, x, stop, 1, **static)
+    return call and solve_of.program.lower(*call[0], **call[1]).compile()
+
+
+_CG_SPANS = ("cg.solve", "cg.dispatch", "cg.iters_fetch")
+
+
+def _cg_counts(iters, static):
+    return {"iters": host_int(iters)}
+
+
+def _i32(maxiter) -> int:
+    """An iteration bound as the programs' int32 counters hold it."""
+    return min(int(maxiter), np.iinfo(np.int32).max)
+
+
 _CG_GENERAL_TRACES = _metrics.counter(
     "cg.general.traces",
     help="traces of the compiled general CG program (linalg._cg_general): "
@@ -841,6 +912,7 @@ _cg_general_program = jax.jit(
     _cg_general,
     static_argnames=("kind", "meta", "conv_test_iters", "tapped"),
 )
+_CG_GENERAL = _CompiledSolve(_cg_general_program, _CG_SPANS, _cg_counts)
 
 
 def _matrix_form(op, dtype):
@@ -864,48 +936,25 @@ def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
     (the trace names it ``jit_cg_general``): ``(x, iters)``, or None where
     it does not apply — an operator that is no ``csr_array`` (or is wrapped
     for fault injection), the packed Pallas DIA product, a call under an
-    outer trace — and the closure loop takes the solve.
-
-    What an operator keeps is its layout (``csr_array._spmv_form``: built
-    on the first product, committed to the device once); the program is
-    jit's, found again by the layout's kind and shapes, so no call after
-    the first of a pattern traces or compiles."""
-    from .utils import in_trace
-
-    form = None if b.ndim != 1 or in_trace() else _matrix_form(A, b.dtype)
-    if form is None:
+    outer trace. What an operator keeps is its layout
+    (``csr_array._spmv_form``: built on the first product, committed to the
+    device once); the program is jit's, found again by the layout's kind
+    and shapes, so no call after the first of a pattern traces or compiles."""
+    pair = _declared_pair(A, None, b)
+    if pair is None or type(pair[0][0]) is not _FormApply:
         return None
-    kind, arrays, meta = form
-    tapped = _iter_tapping()
-    # One `cg.solve` span a call, with the fields the fused path's has:
-    # `cg.dispatch` is the program's call until it returns (asynchronous:
-    # the host's part, and on a pattern's first call the trace and the
-    # compile), `cg.iters_fetch` the wait for the iteration count, the
-    # solve's one fence. Both are trace annotations and aggregates only;
-    # their lengths go onto the solve's event.
-    with telemetry.span("cg.solve", path="device", layout=kind) as solve:
-        start = None
-        if x0 is not None:
-            # the start's residual is a product of its own, before the
-            # program (as the closure loop's is): inside it the compiler
-            # would round it another way
-            x0 = asjnp(x0)
-            start = (x0, b - A.matvec(x0))
-        with telemetry.span("cg.dispatch", emit=False) as sp:
-            x, iters = _cg_general_program(
-                arrays, b, start, tol,
-                min(int(maxiter), np.iinfo(np.int32).max),
-                kind=kind, meta=meta,
-                conv_test_iters=int(conv_test_iters), tapped=tapped,
-            )
-        dispatch_s = sp.dur_s or 0.0
-        with telemetry.span("cg.iters_fetch", emit=False) as sp:
-            iters = host_int(iters)
-        solve.annotate(iters=iters, dispatch_s=round(dispatch_s, 9),
-                       fetch_s=round(sp.dur_s or 0.0, 9))
-    if tapped:
-        _effects_barrier()
-    return x, iters
+    (form, arrays), _m = pair
+    start = None
+    if x0 is not None:
+        # the start's residual is a product of its own, before the program
+        # (as the closure loop's is): inside it the compiler would round
+        # it another way
+        x0 = asjnp(x0)
+        start = (x0, b - A.matvec(x0))
+    call = (arrays, b, start, tol, _i32(maxiter)), dict(
+        kind=form.kind, meta=form.meta, conv_test_iters=int(conv_test_iters),
+        tapped=_iter_tapping())
+    return _run_compiled_solve(_CG_GENERAL, call, {"layout": form.kind})
 
 
 _CG_PRECOND_TRACES = _metrics.counter(
@@ -953,16 +1002,29 @@ def _declared(op, dtype):
 
 
 def _declared_pair(A, M, b):
-    """``(_declared(A), _declared(M))`` for a solve of the vector ``b`` that
-    a whole-solve program over declared operators can take, or None: a
+    """``(_declared(A), _declared(M))`` for a solve of ``b`` that a compiled
+    whole-solve program can take (``M`` None: the identity), or None: a
     closure on either side, a ``b`` that is no vector, an outer trace."""
     from .utils import in_trace
 
     if b.ndim != 1 or in_trace():
         return None
     a = _declared(A, b.dtype)
-    m = a and _declared(M, b.dtype)
+    m = a and ((_identity_apply, ()) if M is None else _declared(M, b.dtype))
     return (a, m) if m else None
+
+
+def _declared_call(A, M, b, x, stop, maxiter, **static):
+    """``(args, static)`` with which the compiled program over declared
+    operators (``_pcg_program``, ``_gmres_program``) runs this solve from
+    ``x`` to ``stop``, or None where :func:`_declared_pair` says so."""
+    pair = _declared_pair(A, M, b)
+    if pair is None:
+        return None
+    (a_apply, a_operands), (m_apply, m_operands) = pair
+    return ((a_operands, m_operands, b, x, stop, _i32(maxiter)),
+            dict(a_apply=a_apply, m_apply=m_apply, tapped=_iter_tapping(),
+                 **static))
 
 
 def _precond_fields(M) -> dict:
@@ -1015,65 +1077,15 @@ _pcg_program = jax.jit(
     static_argnames=("a_apply", "m_apply", "conv_test_iters", "tapped"),
 )
 
-
-def _pcg_call(A, M, b, x0, tol, maxiter, conv_test_iters):
-    """``(args, static)`` of the compiled program for this solve, so that
-    ``_pcg_program(*args, **static)`` runs it, or None where either side is
-    a closure, or under an outer trace."""
-    pair = _declared_pair(A, M, b)
-    if pair is None:
-        return None
-    a, m = pair
-    args = (a[1], m[1], b, jnp.zeros_like(b) if x0 is None else asjnp(x0),
-            tol, min(int(maxiter), np.iinfo(np.int32).max))
-    return args, dict(a_apply=a[0], m_apply=m[0],
-                      conv_test_iters=int(conv_test_iters),
-                      tapped=_iter_tapping())
+_PCG = _CompiledSolve(_pcg_program, _CG_SPANS, _cg_counts)
 
 
 def _pcg_compiled(A, b, M=None, conv_test_iters=25):
-    """The compiled program ``cg(A, b, M=M)`` runs (``jax.stages.Compiled``:
-    its HLO text with every op's ``named_scope``, its memory analysis), or
-    None where that call takes another path. It is jit's own: after a solve
-    of the same structure this traces and compiles nothing. For tools that
-    read a device trace against the program (the benchmark's per-level
-    shares)."""
-    A = make_linear_operator(A)
-    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
-    call = _pcg_call(A, M, asjnp(b), None, 1e-8, 1, conv_test_iters)
-    return call and _pcg_program.lower(*call[0], **call[1]).compile()
-
-
-def _try_pcg(A, M, b, x0, tol, maxiter, conv_test_iters):
-    """CG through the compiled program over declared operators (the trace
-    names it ``jit_pcg``): ``(x, iters)``, or None where either side is a
-    closure, or under an outer trace, and the closure loop takes the solve.
-
-    The program is jit's, found again by the two ``apply`` functions and
-    the operands' structure, so no call after the first of a structure
-    traces or compiles: another ``b``, ``x0``, ``tol``, ``maxiter``, other
-    values in the operands, another operator object of the same shapes."""
-    call = _pcg_call(A, M, b, x0, tol, maxiter, conv_test_iters)
-    if call is None:
-        return None
-    args, static = call
-    # One `cg.solve` span a call, with the fields the fused and the general
-    # paths' have: `cg.dispatch` is the program's call until it returns
-    # (asynchronous: the host's part, and on a structure's first call the
-    # trace and the compile), `cg.iters_fetch` the wait for the iteration
-    # count, the solve's one fence.
-    with telemetry.span("cg.solve", path="device",
-                        **_operator_fields(A, M)) as solve:
-        with telemetry.span("cg.dispatch", emit=False) as sp:
-            x, iters = _pcg_program(*args, **static)
-        dispatch_s = sp.dur_s or 0.0
-        with telemetry.span("cg.iters_fetch", emit=False) as sp:
-            iters = host_int(iters)
-        solve.annotate(iters=iters, dispatch_s=round(dispatch_s, 9),
-                       fetch_s=round(sp.dur_s or 0.0, 9))
-    if static["tapped"]:
-        _effects_barrier()
-    return x, iters
+    """The compiled program ``cg(A, b, M=M)`` runs, or None where that call
+    takes another path (:func:`_compiled_solve`)."""
+    b = asjnp(b)
+    return _compiled_solve(_PCG, A, M, b, jnp.zeros_like(b), 1e-8,
+                           conv_test_iters=int(conv_test_iters))
 
 
 def _cg_host_loop(A, b, x, tol, maxiter, M, callback, conv_test_iters):
@@ -1445,74 +1457,28 @@ _gmres_program = jax.jit(
 )
 
 
-def _gmres_call(A, M, b, x, target, restart, maxiter):
-    """``(args, static)`` of the compiled program for this solve, so that
-    ``_gmres_program(*args, **static)`` runs it, or None where either side
-    is a closure, or under an outer trace."""
-    pair = _declared_pair(A, M, b)
-    if pair is None:
-        return None
-    a, m = pair
-    args = (a[1], m[1], b, x, target,
-            min(int(maxiter), np.iinfo(np.int32).max))
-    return args, dict(a_apply=a[0], m_apply=m[0], restart=int(restart),
-                      tapped=_iter_tapping())
+def _gmres_counts(counts, static):
+    syncs0 = HOST_SYNCS
+    iters, cycles = (int(v) for v in _sync_fetch(counts))
+    return {"cycles": cycles, "iters": iters, "fetches": HOST_SYNCS - syncs0,
+            "orth_rows": _gmres_orth_rows(static["restart"], iters)}
+
+
+_GMRES = _CompiledSolve(
+    _gmres_program, ("gmres.solve", "gmres.dispatch", "gmres.fetch"),
+    _gmres_counts)
 
 
 def _gmres_compiled(A, b, restart, M=None):
-    """The compiled program ``gmres(A, b, restart=restart, M=M)`` runs
-    (``jax.stages.Compiled``: its HLO text with every op's ``named_scope``
-    — ``gmres.spmv``, ``gmres.orth``, ``gmres.small``, ``gmres.update`` —
-    and its memory analysis), or None where that call takes another path.
-    It is jit's own: after a solve of the same structure this traces and
-    compiles nothing. For tools that read a device trace against the
-    program (the benchmark's per-scope shares)."""
-    A = make_linear_operator(A)
-    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+    """The compiled program ``gmres(A, b, restart=restart, M=M)`` runs (its
+    scopes: ``gmres.spmv``, ``gmres.orth``, ``gmres.small``,
+    ``gmres.update``), or None where that call takes another path
+    (:func:`_compiled_solve`)."""
     b = asjnp(b)
     b = b.astype(jnp.result_type(b.dtype, A.dtype))
     target = jnp.zeros((), jnp.finfo(b.dtype).dtype)  # shapes and types alone count
-    call = _gmres_call(A, M, b, jnp.zeros_like(b), target,
-                       min(int(restart), b.shape[0]), 1)
-    return call and _gmres_program.lower(*call[0], **call[1]).compile()
-
-
-def _try_gmres_program(A, M, b, x, target, restart, maxiter):
-    """GMRES through the compiled program over declared operators (the
-    trace names it ``jit_gmres``): ``(x, iters)``, or None where either
-    side is a closure, or under an outer trace, and the cycle path takes
-    the solve.
-
-    The program is jit's, found again by the two ``apply`` functions, the
-    operands' structure and ``restart``, so no call after the first of a
-    structure traces or compiles: another ``b``, ``x0``, ``tol``, ``atol``,
-    ``maxiter``, other values in the operands, another operator object of
-    the same shapes. One dispatch and one fetch a call."""
-    call = _gmres_call(A, M, b, x, target, restart, maxiter)
-    if call is None:
-        return None
-    args, static = call
-    # One `gmres.solve` span a call, with the fields `cg.solve` has:
-    # `gmres.dispatch` is the program's call until it returns
-    # (asynchronous: the host's part, and on a structure's first call the
-    # trace and the compile), `gmres.fetch` the wait for the packed
-    # (iters, cycles) pair, the solve's one fence.
-    with telemetry.span("gmres.solve", path="device", restart=int(restart),
-                        **_precond_fields(M)) as solve:
-        syncs0 = HOST_SYNCS
-        with telemetry.span("gmres.dispatch", emit=False) as sp:
-            x, counts = _gmres_program(*args, **static)
-        dispatch_s = sp.dur_s or 0.0
-        with telemetry.span("gmres.fetch", emit=False) as sp:
-            iters, cycles = (int(v) for v in _sync_fetch(counts))
-        solve.annotate(cycles=cycles, iters=iters,
-                       fetches=HOST_SYNCS - syncs0,
-                       orth_rows=_gmres_orth_rows(int(restart), iters),
-                       dispatch_s=round(dispatch_s, 9),
-                       fetch_s=round(sp.dur_s or 0.0, 9))
-    if static["tapped"]:
-        _effects_barrier()
-    return x, iters
+    return _compiled_solve(_GMRES, A, M, b, jnp.zeros_like(b), target,
+                           restart=min(int(restart), b.shape[0]))
 
 
 @track_provenance
@@ -1565,21 +1531,37 @@ def gmres(
     target = jnp.maximum(tol * bnorm, atol if atol is not None else 0.0)
     target = jnp.maximum(target, 1e-30)
 
-    if callback is None:
-        # operators that declare what they hold: nothing lazy is left in
-        # them (`_matrix_form` builds the layout before the program is
-        # called), so neither eager warm-up below is needed
-        out = _try_gmres_program(A, M, b, x, target, restart, maxiter)
-        if out is not None:
-            _solve_event("gmres", n, out[1], "device")
-            return out
+    # operators that declare what they hold run `jit_gmres`, found again by
+    # the two `apply` functions, the operands' structure and `restart`:
+    # one dispatch and one fetch a call. Nothing lazy is left in them
+    # (`_matrix_form` builds the layout before the program is called), so
+    # neither eager warm-up of the cycle path is needed
+    call = None if callback is not None else _declared_call(
+        A, M, b, x, target, maxiter, restart=int(restart))
+    if call is not None:
+        x, iters = _run_compiled_solve(
+            _GMRES, call, {"restart": int(restart), **_precond_fields(M)})
+        path = "device"
+    else:
+        x, iters, path = _gmres_cycle_path(
+            A, M, b, x, target, restart, maxiter, callback)
+    _solve_event("gmres", n, iters, path)
+    return x, iters
 
+
+def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback):
+    """The solve one restart cycle at a time, under its ``gmres.solve``
+    span (``path="cycle"``): the compiled cycle driven from the host, or
+    host cycles for operators that cannot be traced. ``(x, iters, path)``,
+    ``path`` that of the solve's event: ``"device"`` or ``"host"``."""
     with telemetry.span("gmres.solve", path="cycle", restart=int(restart),
                         **_precond_fields(M)) as solve:
         syncs0 = HOST_SYNCS
         try:
-            x, total_iters, cycles = _gmres_cycles(
+            x, iters, cycles = _gmres_cycles(
                 A, M, b, x, target, restart, maxiter, callback)
+            # the compiled cycle's Arnoldi process, staged
+            solve.annotate(orth_rows=_gmres_orth_rows(int(restart), iters))
             path = "device"
         except (
             jax.errors.TracerArrayConversionError,
@@ -1588,16 +1570,12 @@ def gmres(
         ):
             # A or M is a host-side Python operator: reference-style host
             # cycles
-            x, total_iters, cycles = _gmres_host_cycles(
+            x, iters, cycles = _gmres_host_cycles(
                 A, M, b, x, target, restart, maxiter, callback)
             path = "host"
             solve.annotate(path="host")
-        solve.annotate(cycles=cycles, iters=total_iters,
-                       fetches=HOST_SYNCS - syncs0)
-        if path == "device":  # the compiled cycle's Arnoldi process, staged
-            solve.annotate(orth_rows=_gmres_orth_rows(int(restart), total_iters))
-    _solve_event("gmres", n, total_iters, path)
-    return x, total_iters
+        solve.annotate(cycles=cycles, iters=iters, fetches=HOST_SYNCS - syncs0)
+    return x, iters, path
 
 
 def _gmres_cycles(A, M, b, x, target, restart, maxiter, callback):
@@ -2802,6 +2780,18 @@ def _augmented_cycle(A, Mop, r, inner_m, aug):
     return dx, AZ @ y
 
 
+@functools.partial(jax.jit, static_argnames="sign")
+def _carry_off(c, r, u, y, sign: int):
+    """Take ``c``'s component out of ``r`` and carry it to ``y`` along
+    ``u``: ``(y + sign * a * u, r - a * c)`` with ``a = <c, r>``. One
+    program, not three eager updates: on arrays laid out over a mesh of CPU
+    devices the eager ``y + a * u`` aborted the process about once in a
+    dozen runs of tests/test_dist_solvers.py (as ``gmg_grid.stencil_apply``'s
+    did before it was jitted)."""
+    a = jnp.vdot(c, r)
+    return (y + a * u if sign > 0 else y - a * u), r - a * c
+
+
 @track_provenance
 def lgmres(A, b, x0=None, tol=1e-5, atol=0.0, maxiter=1000, M=None,
            callback=None, inner_m=30, outer_k=3):
@@ -2866,9 +2856,7 @@ def gcrotmk(A, b, x0=None, tol=1e-5, atol=0.0, maxiter=1000, M=None,
         r = b - A.matvec(x)
         # oblique projection onto the recycled image space
         for u, c in recycled:
-            alpha = jnp.vdot(c, r)
-            x = x + alpha * u
-            r = r - alpha * c
+            x, r = _carry_off(c, r, u, x, 1)
         if float(jnp.linalg.norm(r)) <= target:
             return x, 0
         dx, adx = _augmented_cycle(
@@ -2881,9 +2869,7 @@ def gcrotmk(A, b, x0=None, tol=1e-5, atol=0.0, maxiter=1000, M=None,
         # kept ones, applying the same combination to u so c == A u holds
         unew, cnew = dx, adx
         for u, c in recycled:
-            beta = jnp.vdot(c, cnew)
-            cnew = cnew - beta * c
-            unew = unew - beta * u
+            unew, cnew = _carry_off(c, cnew, u, unew, -1)
         an = jnp.linalg.norm(cnew)
         if float(an) > 1e-12:
             recycled.append((unew / an, cnew / an))

@@ -2,7 +2,7 @@
 
 Reference analog: the workload-construction halves of ``examples/pde.py``,
 ``examples/gmg.py``, ``examples/amg.py`` — kept importable here so the driver
-entrypoint (``__graft_entry__.py``), ``bench.py``, and the example scripts all
+entrypoint (``__graft_entry__.py``) and the example scripts
 share one implementation.
 """
 

@@ -241,7 +241,7 @@ def stats() -> dict:
 
 def snapshot() -> dict:
     """Copy of the raw always-on counters, for delta accounting without a
-    global reset (bench rows, ``batch.SolveSession`` dispatch telemetry —
+    global reset (``batch.SolveSession`` dispatch telemetry, tests —
     concurrent users must not clobber each other's baselines)."""
     with _LOCK:
         return {k: int(c.value) for k, c in _COUNTERS.items()}

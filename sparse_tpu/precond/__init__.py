@@ -83,7 +83,7 @@ def _build_event(kind: str, pattern, build_s: float = 0.0, **fields) -> None:
     """One pattern-level build: always-on counters + cost attribution +
     (telemetry on) a ``precond.build`` event. Called from the
     plan-cache build closures, so the cadence is exactly one per
-    (pattern, kind) per vault — the same instrument the bench row's
+    (pattern, kind) per vault — the instrument a
     one-symbolic-factorization assertion reads."""
     _metrics.counter(
         "precond.builds", kind=kind,

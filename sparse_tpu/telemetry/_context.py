@@ -41,8 +41,8 @@ import os
 import threading
 
 # ticket ids are process-unique and sortable: tk-<pid%0x10000 hex>-<seq>.
-# The pid fragment keeps ids distinct when bench worker subprocesses
-# append to the SAME records.jsonl as the parent.
+# The pid fragment keeps ids distinct when worker subprocesses append
+# to the SAME records.jsonl as the parent.
 _SEQ = itertools.count(1)
 _SEQ_LOCK = threading.Lock()
 _PREFIX = f"tk-{os.getpid() % 0x10000:04x}"

@@ -345,8 +345,7 @@ def metrics_text() -> str:
 
 def snapshot() -> dict:
     """JSON-friendly flat view: ``{name{labels}: value}`` for counters
-    and gauges, ``{name{labels}: {"count", "sum"}}`` for histograms —
-    what bench.py embeds in its session record."""
+    and gauges, ``{name{labels}: {"count", "sum"}}`` for histograms."""
     with _LOCK:
         items = list(_REGISTRY.items())
     out = {}

@@ -1,6 +1,6 @@
 """Aggregation API: one dict summarizing the session's telemetry.
 
-The shape bench.py embeds into its session record — counts, per-kind
+Counts, per-kind
 event totals, span latency percentiles, and structural bytes moved per
 collective family. Pure host arithmetic over the recorder's in-memory
 state; never touches a device.

@@ -240,7 +240,7 @@ def to_chrome_trace(events) -> dict:
 
 
 def read_events_jsonl(path: str) -> list:
-    """Telemetry events of a records.jsonl (bench metric records — no
+    """Telemetry events of a records.jsonl (bare metric records — no
     ``kind`` — and unparseable lines are skipped, by the same contract
     as ``schema.validate_jsonl``)."""
     events = []

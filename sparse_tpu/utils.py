@@ -104,8 +104,8 @@ def enable_compilation_cache() -> None:
     directory; where it is not, the cache is ``<repo>/.jax_cache``
     (fixed, gitignored — the path is part of the cache key, so a
     directory that moves never hits). Every entry point that wants
-    cross-process compile reuse (``SolveSession``, ``bench.py``,
-    ``chip_smoke.py``, the examples) goes through this one function.
+    cross-process compile reuse (``SolveSession``, ``chip_smoke.py``,
+    the examples) goes through this one function.
     """
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _REPO_CACHE)

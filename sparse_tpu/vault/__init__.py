@@ -1,7 +1,8 @@
 """Vault: the crash-safe persistent tier of the plan cache (ISSUE 9).
 
-The bench's ``batched_cg`` row shows the serving tax of a cold process:
-16x cold vs ~109x warm — everything between those numbers is SELL
+A cold process pays a serving tax (a CPU reading of the measuring
+script the tree had until PR 45: 16x cold vs ~109x warm over the
+sequential loop) — everything between those numbers is SELL
 packs, DIA preps and per-bucket compiles a fresh process re-derives
 from scratch. The vault persists those prepared artifacts across
 processes (ROADMAP item 4's second cache tier), and treats persistence

@@ -111,41 +111,6 @@ def test_cg_fused_multi_tile():
     assert np.linalg.norm(A.tocsr() @ x - b) < 1e-2
 
 
-@pytest.mark.parametrize("n,iters", [(16, 150), (40, 120)])
-def test_cg_onepass_matches_twopass(n, iters):
-    """Chronopoulos-Gear one-pass CG converges like the two-pass kernel."""
-    from sparse_tpu.kernels.cg_dia import cg_dia_fused_onepass
-    from sparse_tpu.ops.dia_spmv import dia_spmv_xla
-
-    N = n * n
-    planes, offsets = laplacian_2d_dia(n)
-    b = np.asarray(
-        jax.random.normal(jax.random.PRNGKey(3), (N,), jnp.float32)
-    )
-    x2 = cg_dia_fused(planes, offsets, jnp.asarray(b), None, N,
-                      iters=iters, tile=1024, interpret=True)[0]
-    x1 = cg_dia_fused_onepass(planes, offsets, jnp.asarray(b), None, N,
-                              iters=iters, tile=1024, interpret=True)[0]
-    r2 = np.linalg.norm(np.asarray(dia_spmv_xla(planes, offsets, x2, (N, N))) - b)
-    r1 = np.linalg.norm(np.asarray(dia_spmv_xla(planes, offsets, x1, (N, N))) - b)
-    assert r1 < max(4 * r2, 1e-3)
-
-
-def test_cg_onepass_multi_tile_and_x0():
-    from sparse_tpu.kernels.cg_dia import cg_dia_fused_onepass
-    from sparse_tpu.ops.dia_spmv import dia_spmv_xla
-
-    n = 50  # 2500 rows -> G=3 at tile=1024
-    N = n * n
-    planes, offsets = laplacian_2d_dia(n)
-    b = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (N,), jnp.float32))
-    x0 = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (N,), jnp.float32))
-    x1 = cg_dia_fused_onepass(planes, offsets, jnp.asarray(b), jnp.asarray(x0),
-                              N, iters=150, tile=1024, interpret=True)[0]
-    r1 = np.linalg.norm(np.asarray(dia_spmv_xla(planes, offsets, x1, (N, N))) - b)
-    assert r1 < 1e-2
-
-
 def test_cg_fused_bf16_planes_exact():
     """bf16 plane streaming with exactly-representable stencil values
     reproduces the f32 result bit-for-bit at the solver level.

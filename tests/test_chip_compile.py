@@ -153,20 +153,6 @@ def test_cg_dia_fused_compiles_at_pde_size(one_chip):
     assert _device_bytes(c) + 5 * PDE_N * PDE_N * 4 < HBM_BYTES
 
 
-def test_cg_dia_fused_onepass_compiles_at_pde_size(one_chip):
-    from sparse_tpu.kernels.cg_dia import cg_dia_fused_onepass
-
-    n = PDE_N * PDE_N
-    offsets = (-PDE_N, -1, 0, 1, PDE_N)
-    planes = _sds((len(offsets), n), jnp.float32, one_chip)
-    b = _sds((n,), jnp.float32, one_chip)
-    c = cg_dia_fused_onepass.lower(
-        planes, offsets, b, None, n, iters=25, tile=65536, interpret=False,
-    ).compile()
-    assert "tpu_custom_call" in c.as_text()
-    assert _device_bytes(c) < HBM_BYTES
-
-
 # ---------------------------------------------------------------------------
 # the served bucket program (SolveSession._build_program), from shapes
 # ---------------------------------------------------------------------------

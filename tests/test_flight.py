@@ -579,8 +579,9 @@ def test_report_trend_joins_bench_rounds(tmp_path):
         ["BENCH_r02.json", 120.5], ["BENCH_r03.json", 140.25],
     ]
     assert trend["rounds"][1]["cold_start"]["warm_s"] == 0.1
-    # the CLI path over the committed rounds always succeeds
-    assert report.main(["--trend", "--quiet"]) == 0
+    # the CLI path over the same rounds
+    assert report.main(
+        ["--trend", "--quiet", str(tmp_path / "BENCH_r0*.json")]) == 0
 
 
 def test_report_programs_table_gains_device_column(tmp_path):

@@ -278,21 +278,15 @@ def test_one_host_sync_a_call(box, restart):
 
 
 # -- what keeps the old path ----------------------------------------------------------
-@pytest.mark.parametrize("side", ["A", "M", "both", "callback", "outer-trace"])
+@pytest.mark.parametrize("side", ["A", "M", "both", "callback"])
 def test_the_old_path_still_solves(side, tel):
+    """(A call under an outer trace: ``tests/test_compiled_solve.py``.)"""
     A, M, b = _general()
     seen = []
     kw = {"restart": 10, "maxiter": 30, "tol": 1e-6}
     t0 = TRACES.value
     if side == "callback":
         x, iters = linalg.gmres(A, b, M=M, callback=seen.append, **kw)
-    elif side == "outer-trace":
-        # under an outer trace nothing can be fetched: the host cycles raise
-        # as they did, and the program is not tried
-        with pytest.raises(jax.errors.ConcretizationTypeError):
-            jax.jit(lambda v: linalg.gmres(A, v, **kw)[0])(b)
-        assert TRACES.value == t0
-        return
     else:
         x, iters = linalg.gmres(
             _as_closure(A) if side in ("A", "both") else A, b,
