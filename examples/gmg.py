@@ -367,10 +367,14 @@ def main_grid():
 
     with solve:
         if args.dist:
-            # GSPMD distribution: row-shard every level's planes and the
-            # vectors; the SAME vcycle/cg code below then compiles into a
-            # multi-device program with XLA-inserted halo collectives
-            # (oracle-pinned vs single-device in tests/test_gmg_grid.py)
+            # every level's planes and the vectors in row blocks over the
+            # mesh; the SAME vcycle/cg code below then runs each stencil
+            # apply and transfer on a shard's own rows and one row from each
+            # neighbour (gmg_grid's row-block forms: two exchanges an apply,
+            # one a transfer, the fine level's kernel a shard; CG's dot
+            # products are the partitioner's psums). Held to the one-device
+            # solve and the plain reference in tests/test_gmg_mesh.py; on
+            # four v5e chips: PERF.md section 5 (the builder's readings, PR 46)
             from sparse_tpu.parallel.mesh import get_mesh
 
             hier, vec_sharding = gg.shard_hierarchy_grid(hier, get_mesh())

@@ -16,7 +16,11 @@ reach the sum as rotations in VMEM:
 * columns: each 128-lane vreg is rotated by one lane, and lane 0 (lane 127)
   takes the rotated neighbour vreg's: that is the carry between vregs and,
   where there is no neighbour vreg, the grid's edge, which reads zero;
-* the grid's first and last rows read a zero tile where the halo would be.
+* the grid's first and last rows read a zero tile where the halo would be,
+  or, where the grid is one shard's row block of a grid laid over a mesh, the
+  two rows its neighbours sent (``halo``: one row of ``n`` each, zero at the
+  mesh's two ends; ``gmg_grid._fine_stencil`` exchanges them under
+  ``shard_map``).
 
 The consumer's arithmetic is inside, one ``form`` a use of the fine level
 (``gmg_grid._Cycle.level``, ``_GridApply``), so that no use makes a pass over
@@ -53,13 +57,15 @@ FIVE_POINT = frozenset({(0, 0), (-1, 0), (1, 0), (0, -1), (0, 1)})
 _BLOCK_BYTES = 15 * 1024 * 1024 // 6
 
 
-def block_rows(n: int) -> int:
-    """Rows of a grid step's block: 128, halved (down to two row groups)
-    while a float32 block of ``n`` columns would not fit the default scoped
-    VMEM six times over (``smooth`` has three block operands,
-    double-buffered). 128 at 4480."""
+def block_rows(n: int, m: int | None = None) -> int:
+    """Rows of a grid step's block on an ``[m, n]`` grid (``m`` None: ``n``):
+    128, halved (down to two row groups) while a float32 block of ``n``
+    columns would not fit the default scoped VMEM six times over (``smooth``
+    has three block operands, double-buffered) or does not divide ``m`` (a
+    shard's rows). 128 at 4480; 64 on the 1600 rows of a shard of 6400."""
+    m = n if m is None else m
     rows = 128
-    while rows > 2 * SUBLANES and rows * n * 4 > _BLOCK_BYTES:
+    while rows > 2 * SUBLANES and (rows * n * 4 > _BLOCK_BYTES or m % rows):
         rows //= 2
     return rows
 
@@ -83,7 +89,15 @@ def _lane_neighbours(x):
     return jnp.concatenate(left, axis=1), jnp.concatenate(right, axis=1)
 
 
-def _kernel(s_ref, x_ref, above_ref, below_ref, *rest, form, offsets, blocks):
+def _kernel(s_ref, x_ref, above_ref, below_ref, *rest, form, offsets, blocks,
+            halo):
+    # the rows beyond the grid's first and last: the neighbour shards'
+    # (one row each, read into every sublane of a tile), else zero
+    top = bottom = 0.0
+    if halo:
+        top_ref, bottom_ref, *rest = rest
+        top, bottom = (jnp.broadcast_to(ref[...], (SUBLANES, x_ref.shape[1]))
+                       for ref in (top_ref, bottom_ref))
     r_ref, o_ref = rest if form == "smooth" else (None, *rest)
     i = pl.program_id(0)
     groups = x_ref.shape[0] // SUBLANES
@@ -119,8 +133,8 @@ def _kernel(s_ref, x_ref, above_ref, below_ref, *rest, form, offsets, blocks):
             ax = cur + w * (rows(r_ref, g) - ax)
         o_ref[pl.ds(pl.multiple_of(g * SUBLANES, SUBLANES), SUBLANES), :] = ax
 
-    above = jnp.where(i > 0, above_ref[...], 0.0)
-    below = jnp.where(i < blocks - 1, below_ref[...], 0.0)
+    above = jnp.where(i > 0, above_ref[...], top)
+    below = jnp.where(i < blocks - 1, below_ref[...], bottom)
     emit(0, above, rows(x_ref, 1))
 
     def body(g, carry):
@@ -133,21 +147,25 @@ def _kernel(s_ref, x_ref, above_ref, below_ref, *rest, form, offsets, blocks):
 
 @partial(jax.jit, static_argnames=("form", "offsets", "interpret"))
 def stencil5(scalars, x, r=None, *, form: str, offsets: tuple,
-             interpret: bool = False):
-    """One use of the 5-point stencil ``A`` on the ``[n, n]`` float32 grid
-    ``x`` (``n`` a multiple of 128), by ``form`` (the module's table).
+             interpret: bool = False, halo=None):
+    """One use of the 5-point stencil ``A`` on the ``[m, n]`` float32 grid
+    ``x`` (``n`` a multiple of 128, ``m`` of 16), by ``form`` (the module's
+    table).
 
     ``scalars`` holds the coefficients in the order of ``offsets`` (the five
     of :data:`FIVE_POINT`, a neighbour's (row, column) each, as
     ``gmg_grid.stencil_apply`` reads them) and then, but for ``"apply"``,
-    ``w``. ``r`` is ``"smooth"``'s second grid."""
-    n = x.shape[0]
+    ``w``. ``r`` is ``"smooth"``'s second grid. ``halo``: the ``[1, n]`` rows
+    above ``x``'s first and below its last, where ``x`` is a row block of a
+    larger grid; None: ``x`` is the whole grid, and zero lies beyond it."""
+    m, n = x.shape
     assert form in FORMS and set(offsets) == FIVE_POINT and len(offsets) == 5
-    assert x.shape == (n, n) and n % LANES == 0 and x.dtype == jnp.float32
+    assert n % LANES == 0 and x.dtype == jnp.float32
     assert (r is not None) == (form == "smooth")
     assert scalars.shape == (5 + (form != "apply"),)
-    tr = block_rows(n)
-    blocks, tiles = n // tr, tr // SUBLANES
+    tr = block_rows(n, m)
+    assert m % tr == 0, (m, tr)
+    blocks, tiles = m // tr, tr // SUBLANES
     block = pl.BlockSpec((tr, n), lambda i: (i, 0))
     # the eight-row tile that ends above the block, the one that starts
     # below it; at the grid's edge any tile, read as zero
@@ -155,15 +173,18 @@ def stencil5(scalars, x, r=None, *, form: str, offsets: tuple,
         (SUBLANES, n), lambda i: (jnp.maximum(i * tiles - 1, 0), 0))
     below = pl.BlockSpec(
         (SUBLANES, n),
-        lambda i: (jnp.minimum((i + 1) * tiles, n // SUBLANES - 1), 0))
-    grids = (x, x, x) if r is None else (x, x, x, r)
+        lambda i: (jnp.minimum((i + 1) * tiles, m // SUBLANES - 1), 0))
+    row = pl.BlockSpec((1, n), lambda i: (0, 0))
+    halo = () if halo is None else tuple(halo)
+    grids = (x, x, x, *halo) if r is None else (x, x, x, *halo, r)
     return pl.pallas_call(
-        partial(_kernel, form=form, offsets=offsets, blocks=blocks),
+        partial(_kernel, form=form, offsets=offsets, blocks=blocks,
+                halo=bool(halo)),
         name=f"grid_stencil5_{form}",
         grid=(blocks,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), block, above, below]
-        + [block] * (r is not None),
+        + [row] * len(halo) + [block] * (r is not None),
         out_specs=block,
-        out_shape=jax.ShapeDtypeStruct((n, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
     )(scalars, *grids)

@@ -435,15 +435,27 @@ def _vdot(a, b):
 # it already fetches per conv-test chunk (zero extra syncs).
 
 
+def _mesh_fields(x) -> dict:
+    """``{"devices": count}`` for an answer that lives on more than one
+    device, nothing for one: what tells a solve over a mesh from a one-chip
+    solve in its span and its event."""
+    try:
+        devices = len(x.sharding.device_set)
+    except AttributeError:  # a host array
+        return {}
+    return {"devices": devices} if devices > 1 else {}
+
+
 def _solve_event(
-    solver: str, n, iters, path: str, resid2=None, converged=None
+    solver: str, n, iters, path: str, resid2=None, converged=None, x=None
 ) -> None:
-    """One ``solver.solve`` event per completed solve (any path); also
-    finalizes the health monitor's report for this solve
-    (``telemetry.last_solve_report()``)."""
+    """One ``solver.solve`` event per completed solve (any path; ``x`` the
+    answer, for :func:`_mesh_fields`); also finalizes the health monitor's
+    report for this solve (``telemetry.last_solve_report()``)."""
     if not telemetry.enabled():
         return
-    fields = {"solver": solver, "n": int(n), "iters": int(iters), "path": path}
+    fields = {"solver": solver, "n": int(n), "iters": int(iters), "path": path,
+              **_mesh_fields(x)}
     if resid2 is not None:
         fields["resid2"] = float(resid2)
     if converged is not None:
@@ -526,7 +538,7 @@ def cg(
         maxiter = b.shape[0] * 10
     x, iters, path, health = _cg_take(
         A, b, x0, tol, maxiter, M, callback, conv_test_iters)
-    _solve_event("cg", b.shape[0], iters, path, **health)
+    _solve_event("cg", b.shape[0], iters, path, x=x, **health)
     return x, iters
 
 
@@ -832,7 +844,8 @@ def _run_compiled_solve(solve_of, call, fields):
     (asynchronous: the host's part, and on a structure's first call the
     trace and the compile) and the fetch span the wait for the counts.
     Both are trace annotations and aggregates only; their lengths go onto
-    the solve's event as ``dispatch_s`` and ``fetch_s``."""
+    the solve's event as ``dispatch_s`` and ``fetch_s``, and ``devices``
+    where the answer lives on more than one."""
     name, dispatch, fetch = solve_of.spans
     args, static = call
     with telemetry.span(name, path="device", **fields) as solve:
@@ -841,7 +854,8 @@ def _run_compiled_solve(solve_of, call, fields):
         dispatch_s = sp.dur_s or 0.0
         with telemetry.span(fetch, emit=False) as sp:
             counted = solve_of.fields(counts, static)
-        solve.annotate(**counted, dispatch_s=round(dispatch_s, 9),
+        solve.annotate(**counted, **_mesh_fields(x),
+                       dispatch_s=round(dispatch_s, 9),
                        fetch_s=round(sp.dur_s or 0.0, 9))
     if static["tapped"]:
         _effects_barrier()
@@ -1040,7 +1054,8 @@ def _precond_fields(M) -> dict:
 def _operator_fields(A, M) -> dict:
     """What a solve's span says of its two operators: the preconditioner's
     fields, and the counts a declared ``A`` states of its own product
-    (``describe``: ``fine_stencil_kernels`` of ``gmg_grid.grid_operator``)
+    (``describe``: ``fine_stencil_kernels`` and, over a mesh,
+    ``halo_exchanges`` of ``gmg_grid.grid_operator``)
     added to ``M``'s of the same name, so that the span's count is one
     iteration's, the product and the preconditioner together."""
     fields = _precond_fields(M)
@@ -1545,7 +1560,7 @@ def gmres(
     else:
         x, iters, path = _gmres_cycle_path(
             A, M, b, x, target, restart, maxiter, callback)
-    _solve_event("gmres", n, iters, path)
+    _solve_event("gmres", n, iters, path, x=x)
     return x, iters
 
 

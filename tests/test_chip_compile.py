@@ -915,3 +915,83 @@ def test_gmg_pcg_program_without_the_kernels_is_the_parents(one_chip, monkeypatc
     assert len([ln for ln in body_pads if "/gmg.l0/" in ln]) == 2
     assert [ln for ln in _fine_stencil_ops(text)
             if "slice={[1:4481], [2:4482]}" in ln]
+
+
+# ---------------------------------------------------------------------------
+# the same program over a hierarchy laid over four chips in row blocks (PR
+# 46), 1280 x 5120 a chip (the smallest side ISSUE 46 lists): every apply and transfer under
+# shard_map with its neighbours' edge rows, the fine level's kernel a shard
+# ---------------------------------------------------------------------------
+GMG_MESH_GRID = 5120
+
+
+def _gmg_pcg_mesh_compiled(chip, monkeypatch, row_blocks: bool):
+    from sparse_tpu import linalg
+    from sparse_tpu.models import gmg_grid
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(chip.devices[:4]), ("shards",))
+    blocks, whole, flat = (NamedSharding(mesh, s) for s in (
+        P("shards", None), P(), P("shards")))
+    rows = gmg_grid._Rows(mesh, "shards") if row_blocks else None
+    g = GMG_MESH_GRID
+    five = tuple(gmg_grid.poisson_stencil(g))
+    nine = tuple((a, b) for a in (-1, 0, 1) for b in (-1, 0, 1))
+    sides = [g // 2 ** k for k in range(GMG_LEVELS)]
+    scalar = _sds((), jnp.float32, whole)
+    plane = lambda s: _sds((s, s), jnp.float32, blocks)  # noqa: E731
+    m_operands = ((tuple(scalar for _ in five), scalar),) + tuple(
+        (tuple(plane(s) for _ in nine), plane(s)) for s in sides[1:])
+    static = ((g, five),) + tuple((s, nine) for s in sides[1:])
+    vec = _sds((g * g,), jnp.float32, flat)
+    return linalg._pcg_program.lower(
+        tuple(scalar for _ in five), m_operands, vec, vec, scalar, 50,
+        a_apply=gmg_grid._GridApply(g, five, row_blocks, rows),
+        m_apply=gmg_grid._Cycle(static, "linear", row_blocks,
+                                (rows,) * GMG_LEVELS if row_blocks else ()),
+        conv_test_iters=25, tapped=False).compile()
+
+
+def _loop_collectives(text: str) -> dict:
+    """{opcode: result types} of the collectives under ``/while/body/``."""
+    out: dict = {}
+    for ln in text.splitlines():
+        m = re.search(r" = (.*?) (collective-permute|all-reduce|all-gather|"
+                      r"all-to-all)(?:-start)?\(", ln)
+        if m and "/while/body/" in ln:
+            out.setdefault(m.group(2), []).append(m.group(1))
+    return out
+
+
+def test_gmg_pcg_mesh_program_exchanges_one_row_a_side(chip, monkeypatch):
+    c = _gmg_pcg_mesh_compiled(chip, monkeypatch, row_blocks=True)
+    text = c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+    loop = _loop_collectives(text)
+    # an iteration: five applies x 2 and four transfers x 1 exchanges, each
+    # of one row of its level's side; CG's two dot products; no gather
+    assert set(loop) == {"collective-permute", "all-reduce"}, loop
+    assert len(loop["collective-permute"]) == 14 and len(loop["all-reduce"]) == 2
+    rows = sorted(int(re.search(r"f32\[1,(\d+)\]", t).group(1))
+                  for t in loop["collective-permute"])
+    assert rows == [1280] + [2560] * 6 + [5120] * 7, rows
+    assert not re.search(r" (all-gather|all-to-all)(-start)?\(", text)
+    # the fine level's three applies are the kernel's, on a shard's block
+    calls = [ln for ln in _loop_body(text).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 3 and all("f32[1280,5120]" in ln.split(" custom-call(")[0]
+                                   for ln in calls)
+    assert not _fine_stencil_ops(text.replace("f32[5120", "f32[4480"))
+
+
+def test_gmg_pcg_mesh_program_left_to_the_partitioner_is_the_other_side(
+        chip, monkeypatch):
+    """What the parent ran over the same lay-out, and why the row-block forms
+    exist: the partitioner's own answer to ``stencil_apply``'s pad and
+    slices is five exchanges an apply (29 an iteration with the transfers'),
+    and the restriction's strided slices become gathers of index vectors."""
+    c = _gmg_pcg_mesh_compiled(chip, monkeypatch, row_blocks=False)
+    loop = _loop_collectives(c.as_text())
+    assert len(loop["collective-permute"]) > 2 * 14
+    assert all("f32[" not in t for t in loop.get("all-gather", []))
+

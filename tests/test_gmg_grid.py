@@ -334,9 +334,9 @@ WHO_KEEPS_STENCIL_APPLY = {
         128, 2, planes=gg.build_hierarchy(256, 2)[1][0]),
     # float64 scalars
     "float64": lambda: gg.build_hierarchy(128, 2, dtype=jnp.float64),
-    # scalars replicated over a mesh: GSPMD's halo exchanges come from the
-    # pad and the slices
-    "sharded": lambda: _shard8(gg.build_hierarchy(128, 2)),
+    # float64 laid over a mesh, three rows a shard: no kernel (what a mesh
+    # changes, the row-block forms, is tests/test_gmg_mesh.py's)
+    "sharded-float64": lambda: _shard8(gg.build_hierarchy(24, 2, dtype=jnp.float64)),
 }
 
 
@@ -352,11 +352,13 @@ def test_who_does_not_fit_the_kernel_keeps_stencil_apply_and_its_bits(
     A0, M0 = gg.grid_operator(hier), gg.make_vcycle(hier)
     monkeypatch.setattr(gg, "_KERNEL_PLATFORM", "cpu")
     A, M = gg.grid_operator(hier), gg.make_vcycle(hier)
-    assert A.apply == A0.apply == gg._GridApply(n, tuple(hier[0][0]))
+    assert A.apply == A0.apply == gg._GridApply(n, tuple(hier[0][0]),
+                                                rows=A0.apply.rows)
     assert M.apply == M0.apply and not M.apply.fine_kernel
-    assert A.describe == {"fine_stencil_kernels": 0}
-    assert M.describe == {"precond": "gmg_grid", "levels": 2,
-                          "fine_stencil_kernels": 0}
+    assert (A.apply.rows is not None) == who.startswith("sharded")
+    assert A.describe["fine_stencil_kernels"] == 0 and A.describe == A0.describe
+    assert M.describe["fine_stencil_kernels"] == 0 and M.describe == M0.describe
+    assert (M.describe["precond"], M.describe["levels"]) == ("gmg_grid", 2)
     r = jnp.asarray(np.random.default_rng(5).random(n * n), hier[0][1].dtype)
     assert np.array_equal(np.asarray(A.matvec(r)), np.asarray(A0.matvec(r)))
     assert np.array_equal(np.asarray(M.matvec(r)), np.asarray(M0.matvec(r)))
