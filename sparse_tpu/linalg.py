@@ -1476,7 +1476,8 @@ def _gmres_counts(counts, static):
     syncs0 = HOST_SYNCS
     iters, cycles = (int(v) for v in _sync_fetch(counts))
     return {"cycles": cycles, "iters": iters, "fetches": HOST_SYNCS - syncs0,
-            "orth_rows": _gmres_orth_rows(static["restart"], iters)}
+            "orth_rows": _gmres_orth_rows(static["restart"], iters),
+            "basis_write_rows": _BASIS_WRITE_ROWS}
 
 
 _GMRES = _CompiledSolve(
@@ -1575,8 +1576,9 @@ def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback):
         try:
             x, iters, cycles = _gmres_cycles(
                 A, M, b, x, target, restart, maxiter, callback)
-            # the compiled cycle's Arnoldi process, staged
-            solve.annotate(orth_rows=_gmres_orth_rows(int(restart), iters))
+            # the compiled cycle's Arnoldi process: staged, a row to a tile
+            solve.annotate(orth_rows=_gmres_orth_rows(int(restart), iters),
+                           basis_write_rows=_BASIS_WRITE_ROWS)
             path = "device"
         except (
             jax.errors.TracerArrayConversionError,
@@ -1729,8 +1731,8 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
     ``gmres.orth`` (the four contractions against the basis and the updates
     of w between them, the sum of squares of what is left), ``gmres.small``
     (scalars: the norm's root, the Givens rotations, the Hessenberg column,
-    the triangular solve) and ``gmres.update`` (the basis row's write,
-    x += V y, the cycle's residual)."""
+    the triangular solve) and ``gmres.update`` (the basis row's write with
+    the division by the norm inside it, x += V y, the cycle's residual)."""
     dt = b.dtype
     rdt = jnp.zeros((), dt).real.dtype
     with jax.named_scope("gmres.spmv"):
@@ -1752,18 +1754,65 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
         gv = g[:restart] * mk
         y = jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
     with jax.named_scope("gmres.update"):
-        x = x + y @ V[:restart]
+        x = x + _basis_flat(_basis_combine(y, V[:restart]), x.shape[0])
     return x, k, beta, bdown
 
 
-# The orthogonalisation reads the basis in stages of whole tile groups: the
-# TPU compiler lays the ``[restart + 1, n]`` basis out in ``(8, 128)`` tiles,
-# so eight rows are the least a read of it can take, and a static slice
-# ``V[:8 j]`` is a whole number of them (it fuses into the contraction; no
-# copy of the basis is planned: tests/test_chip_compile.py). A stage is its
-# own set of fusions in the program, so a long restart widens the block and
-# keeps the stages few.
-_ORTH_TILE_ROWS = 8
+# The Krylov basis is held a row to a tile: ``[restart + 1, R, 128]`` with
+# ``R = 8 * ceil(n / 1024)``, each row the flat vector padded with zeros to
+# ``R * 128`` elements and viewed as ``[R, 128]``. The TPU compiler tiles the
+# two minor dimensions ``(8, 128)``, so a row is a run of whole tiles of its
+# own: a step writes and reads one row's bytes and no neighbour's (under
+# ``[restart + 1, n]`` a row was a sublane of tiles it shared with seven
+# others, and its write moved all eight), a flat vector of ``R * 128``
+# elements and its ``[R, 128]`` view are the same bytes, and a static slice
+# ``V[:hi]`` is whole tiles for any ``hi``. The pad (under 1024 elements a
+# row) is zero at the start and every operation of the loop keeps it zero:
+# the product's result is padded with zeros, the updates are linear, 0 / ||w||
+# is 0; the contractions run over it and add nothing.
+_BASIS_TILE = (8, 128)
+# basis rows one step's write moves (the ``basis_write_rows`` field of the
+# ``gmres.solve`` span): a row is whole tiles, so its own alone
+_BASIS_WRITE_ROWS = 1
+
+
+def _basis_tiles(v):
+    """The flat vector ``v`` as a basis row: padded with zeros to whole
+    ``(8, 128)`` tiles, ``[R, 128]``."""
+    sub, lanes = _BASIS_TILE
+    rows = sub * -(-v.shape[0] // (sub * lanes))
+    return jnp.pad(v, (0, rows * lanes - v.shape[0])).reshape(rows, lanes)
+
+
+def _basis_flat(V, n: int):
+    """The flat view of a basis (or of one row of it): ``[..., R, 128]`` as
+    ``[..., n]``, the pad cut off."""
+    return V.reshape(*V.shape[:-2], -1)[..., :n]
+
+
+def _basis_project(Vs, w):
+    """``V^H w``: the rows ``Vs [hi, R, 128]`` against ``w [R, 128]``, a
+    multiply and a sum in the basis' dtype (no ``dot``: nothing for a
+    matrix unit's lower-precision pass)."""
+    return jnp.sum(Vs.conj() * w, axis=(1, 2))
+
+
+def _basis_combine(h, Vs):
+    """``h V``: the combination ``[R, 128]`` of the rows ``Vs [hi, R, 128]``
+    with the coefficients ``h [hi]``, as :func:`_basis_project` a multiply
+    and a sum."""
+    return jnp.sum(h[:, None, None] * Vs, axis=0)
+
+
+# The orthogonalisation reads the basis in stages of whole blocks of rows. A
+# stage is its own set of fusions in the program (one branch of a
+# ``lax.switch``), so the stages are kept few and a long restart widens the
+# block; under the layout above any count of rows is whole tiles, so the
+# block is 4 rows and not a tile group's 8 (read on the chip, PR 47: eight
+# stages at restart 30 are 3 % of a solve ahead of four). The static slice
+# ``V[:hi]`` of a stage fuses into its contractions; no copy of the basis is
+# planned (tests/test_chip_compile.py).
+_ORTH_BLOCK_ROWS = 4
 _ORTH_MAX_STAGES = 8
 
 
@@ -1771,13 +1820,13 @@ def _orth_stages(restart: int) -> tuple:
     """``(block, his)``: step ``k`` of a cycle orthogonalises against the
     basis rows ``V[:his[k // block]]``, the least whole number of blocks
     that holds rows 0..k, capped at the basis' ``restart + 1`` rows. The
-    block is the tile group's 8 rows times the least factor that leaves at
-    most ``_ORTH_MAX_STAGES`` stages: ``restart`` 30 gives 8 and (8, 16, 24,
-    31), 200 gives 32 and seven stages, 7 or less one stage, the whole
+    block is ``_ORTH_BLOCK_ROWS`` times the least factor that leaves at most
+    ``_ORTH_MAX_STAGES`` stages: ``restart`` 30 gives 4 and (4, 8, ..., 28,
+    31), 200 gives 28 and eight stages, 3 or less one stage, the whole
     basis."""
     rows = restart + 1
-    groups = -(-rows // _ORTH_TILE_ROWS)
-    block = _ORTH_TILE_ROWS * -(-groups // _ORTH_MAX_STAGES)
+    groups = -(-rows // _ORTH_BLOCK_ROWS)
+    block = _ORTH_BLOCK_ROWS * -(-groups // _ORTH_MAX_STAGES)
     return block, tuple(min(hi, rows) for hi in
                         range(block, rows + block, block))
 
@@ -1802,9 +1851,10 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     on the Hessenberg column of each step, ended early by a breakdown or by
     the recurrence's residual ``|g[k+1]|`` under ``target``; no step at all
     where ``beta`` is at the target already. ``(V, H, g, k, breakdown)``:
-    the basis ``[restart + 1, n]`` (rows past ``k`` zero), the rotated
-    (upper triangular) Hessenberg, the rotated right-hand side, the steps
-    that gave a column.
+    the basis a row to a tile, ``[restart + 1, R, 128]`` (the layout above;
+    :func:`_basis_flat` gives ``[restart + 1, n]``; rows past ``k`` zero),
+    the rotated (upper triangular) Hessenberg, the rotated right-hand side,
+    the steps that gave a column.
 
     The four contractions of a step (``V^H w``, ``h V``, and both again) are
     bound by the bytes of the basis they read, on the vector unit, so step
@@ -1825,7 +1875,8 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     with jax.named_scope("gmres.update"):
         start_ok = beta > target
         beta_safe = jnp.where(start_ok, beta, 1.0)
-        V = jnp.zeros((restart + 1, n), dtype=dt).at[0].set(r / beta_safe)
+        v0 = _basis_tiles(r / beta_safe)
+        V = jnp.zeros((restart + 1, *v0.shape), dtype=dt).at[0].set(v0)
     H = jnp.zeros((restart + 1, restart), dtype=dt)
     cs = jnp.zeros((restart,), dtype=rdt)
     sn = jnp.zeros((restart,), dtype=dt)
@@ -1836,10 +1887,10 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
         # the rows V[:k+1], as masked contractions over the stage's rows
         Vs = V[:hi]
         mask = (jnp.arange(hi) <= k).astype(rdt)
-        hcol = (Vs.conj() @ w) * mask
-        w = w - hcol @ Vs
-        h2 = (Vs.conj() @ w) * mask
-        w = w - h2 @ Vs
+        hcol = _basis_project(Vs, w) * mask
+        w = w - _basis_combine(hcol, Vs)
+        h2 = _basis_project(Vs, w) * mask
+        w = w - _basis_combine(h2, Vs)
         # ||w||: jnp.linalg.norm's own sum, its root among the scalars
         ww = jnp.sum(jnp.real(w * jnp.conj(w)))
         return jnp.pad(hcol + h2, (0, restart + 1 - hi)), w, ww
@@ -1854,19 +1905,24 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     def body(st):
         V, H, cs, sn, g, k, done, bd = st
         with jax.named_scope("gmres.spmv"):
-            w = precond(matvec(V[k]))
+            w = _basis_tiles(precond(matvec(_basis_flat(V[k], n))))
         with jax.named_scope("gmres.orth"):
-            # one stage (restart <= 7): lax.switch calls it, no conditional
+            # one stage (restart <= 3): lax.switch calls it, no conditional
             hcol, w, ww = jax.lax.switch(k // block, stages, V, w, k)
         with jax.named_scope("gmres.small"):
             hkk = jnp.sqrt(ww)
             grew = hkk > 1e-30
         with jax.named_scope("gmres.update"):
-            V = V.at[k + 1].set(
-                jnp.where(grew, w / jnp.where(grew, hkk, 1.0), 0.0)
-            )
+            # k + 1 <= restart: a plain in-place write of the one row, the
+            # division inside its fusion
+            V = jax.lax.dynamic_update_index_in_dim(
+                V, jnp.where(grew, w / jnp.where(grew, hkk, 1.0), 0.0
+                             ).astype(dt), k + 1, 0)
         with jax.named_scope("gmres.small"):
-            col = hcol.at[k + 1].set(hkk.astype(dt))
+            # (a select, not a scatter: the TPU compiler then keeps the
+            # column in fast memory through the rotations' inner loop)
+            col = jnp.where(jnp.arange(restart + 1) == k + 1, hkk.astype(dt),
+                            hcol)
 
             # apply the k accumulated Givens rotations (masked fori —
             # [restart]^2 scalars, exactly the lax.fori_loop case)

@@ -701,6 +701,7 @@ def test_cg_general_compiles_through_the_windowed_layout(one_chip, monkeypatch):
 # atmosmodd's 148 x 148 x 58 box (1,270,432 rows, seven planes), restart 30
 # ---------------------------------------------------------------------------
 GMRES_BOX = (148, 148, 58)
+GMRES_BASIS_ROWS = 9928  # 8 * ceil(1,270,432 / 1024): a basis row is [9928, 128]
 GMRES_SCOPES = ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update")
 
 
@@ -718,6 +719,31 @@ def _gmres_compiled(one_chip, restart=30):
         m_apply=linalg._identity_apply, restart=restart, tapped=False).compile()
 
 
+def _computations(text: str) -> dict:
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"\n%([\w.\-]+) \([^\n]*\{\n.*?\n\}\n", text, re.S)}
+
+
+def _fusions(computation: str) -> list:
+    """(name, result, the rest of the line) of a computation's fusions."""
+    return re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = ([^\n]*?) fusion\(([^\n]*)$",
+        computation, re.M)
+
+
+def _called(fusion_rest: str) -> str:
+    """The name of the computation a fusion's line calls."""
+    return re.search(r"calls=%([\w.\-]+)", fusion_rest).group(1)
+
+
+def _arnoldi_body(computations: dict) -> str:
+    """The Arnoldi loop's body: the computation that chooses the
+    orthogonalisation's stage."""
+    (body,) = [c for c in computations.values()
+               if re.search(r" conditional\([^\n]*gmres\.orth/", c)]
+    return body
+
+
 def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
     n, c = _gmres_compiled(one_chip)
     text = c.as_text()
@@ -725,11 +751,63 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
     ma = c.memory_analysis()
     # the matrix and the vectors are arguments: 7 planes, b and the start
     assert ma.argument_size_in_bytes >= 9 * 4 * n
-    # the Krylov basis [31, n] (157.5 MB) is updated in place, a row a step:
-    # a copy a step, or a second basis, would pass 0.3 GB
-    assert 31 * 4 * n < ma.temp_size_in_bytes < 0.25e9
-    assert re.search(r"dynamic-update-slice\(\S*f32\[31,%d\]" % n, text) or \
-        re.search(r"f32\[31,%d\]\S* dynamic-update-slice\(" % n, text)
+    # the Krylov basis, a row to a tile (PR 47): [31, R, 128] with R = 8 *
+    # ceil(n / 1024), tiled (8, 128) on its two minor dimensions, so a row is
+    # whole tiles of its own (157.6 MB); it is updated in place, a row a
+    # step: a copy a step, or a second basis, would pass 0.3 GB
+    rows = GMRES_BASIS_ROWS
+    assert rows == 8 * -(-n // 1024)
+    basis, row = f"f32[31,{rows},128]", f"f32[1,{rows},128]"
+    assert basis + "{2,1,0:T(8,128)}" in text
+    assert f"f32[31,{n}]" not in text  # the layout the tree had
+    assert 31 * rows * 128 * 4 < ma.temp_size_in_bytes < 0.25e9
+    computations = _computations(text)
+    body = _arnoldi_body(computations)
+    # the step's write: the root of a fusion whose result is the basis, a
+    # dynamic-update-slice of one [1, R, 128] row into its own parameter, the
+    # division by the norm inside it, and no read of the basis beside it
+    (write,) = [(res, rest) for _name, res, rest in _fusions(body)
+                if res.startswith(basis)]
+    assert "gmres.update/" in write[1]
+    inner = computations[_called(write[1])]
+    (root,) = re.findall(r"ROOT [^\n]*", inner)
+    assert re.search(r"%s\S* dynamic-update-slice\(" % re.escape(basis), root)
+    (param,) = re.findall(r"%%([\w.\-]+) = %s\S* parameter\(" % re.escape(basis),
+                          inner)
+    (update,) = re.findall(r"dynamic-update-slice\(%([\w.\-]+), %([\w.\-]+),", root)
+    assert update[0] == param
+    assert re.search(r"%%%s = %s\S* bitcast\(" % (re.escape(update[1]),
+                                                 re.escape(row)), inner)
+    assert " divide(" in inner and "dynamic-slice(" not in inner
+    assert len(re.findall(r"%" + re.escape(param) + r"\b", inner)) == 2
+    # the row's read for the product: a dynamic-slice of one row, handed on
+    # flat by a bitcast (the same bytes)
+    reads = [inner for inner in (computations[_called(rest)]
+                                 for _name, _res, rest in _fusions(body)
+                                 if "gmres.spmv/" in rest) if basis in inner]
+    assert reads and all(
+        "dynamic_slice_sizes={1,%d,128}" % rows in r for r in reads)
+    # nothing in the Arnoldi body, its fusions or the stages' branches
+    # reshapes a basis row other than by a bitcast, or shapes it for the write
+    (branch_names,) = re.findall(
+        r" conditional\([^\n]*branch_computations=\{([^}]*)\}", body)
+    step = [body] + [computations[b.strip().lstrip("%")]
+                     for b in branch_names.split(",")]
+    step += [computations[_called(rest)] for comp in list(step)
+             for _name, _res, rest in _fusions(comp)]
+    for comp in step:
+        assert not re.search(r" (reshape|transpose)\(", comp)
+        # (the plane product slices its [7, n] planes a row at a time)
+        assert all("gmres.spmv/" in ln for ln in comp.splitlines()
+                   if f"f32[1,{n}]" in ln)
+    # the rotations' inner loop carries its Hessenberg column in fast memory
+    # (`S(1)`), as it does the rotations: made by a scatter and not a select
+    # the column was left in HBM and a trip's six ops read 3.96 us where 2.23
+    # (my chip runs, PR 47)
+    (givens,) = [ln for ln in body.splitlines()
+                 if re.search(r" while\([^\n]*gmres\.small/while", ln)]
+    carried = givens[:givens.index(" while(")]
+    assert re.findall(r"f32\[3[01]\]\{0:T\(128\)(S\(1\))?\}", carried) == ["S(1)"] * 3
     # the four contractions against the basis run in float32 on the vector
     # unit: nothing for the MXU's default bfloat16 pass to touch
     assert "convolution" not in text and "bf16" not in text
@@ -737,7 +815,7 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
     # the orthogonalisation's stages read static slices of the basis that
     # fuse into their contractions (PR 43): no stage plans a copy of it
     assert not [ln for ln in text.splitlines()
-                if re.search(r"\bcopy(-start)?\(", ln) and f"f32[31,{n}]" in ln]
+                if re.search(r"\bcopy(-start)?\(", ln) and basis in ln]
     # no constant of the program is larger than the (iters, cycles) pair:
     # nothing of the matrix is folded into it
     for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
@@ -748,37 +826,31 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
 def test_gmres_program_ops_carry_their_scope(one_chip):
     """What ``benchmark/reducers/op_scope_share.py`` reads the cell's
     per-scope shares from: in the Arnoldi loop's body, and in the branches
-    of the orthogonalisation's ``conditional`` (one a stage: PR 43), every
+    of the orthogonalisation's ``conditional`` (one a stage: PR 43; eight
+    stages of four rows since PR 47), every
     fusion that carries an ``op_name`` stands under exactly one of the four
     scopes, each scope has one, and the compiler's own fusions without an
-    ``op_name`` (it shapes the new basis row for its write with one) are
-    few. Every stage has its four contractions, the basis operand sliced to
-    the stage's rows inside the fusion."""
+    ``op_name`` are few. Every stage has its four contractions, multiplies
+    and sums over the basis ``[31, R, 128]`` (PR 47) sliced to the stage's
+    rows of the major dimension inside the fusion."""
     from sparse_tpu import linalg
 
     n, c = _gmres_compiled(one_chip)
+    rows = GMRES_BASIS_ROWS
     text = c.as_text()
-    computations = {m.group(1): m.group(0) for m in re.finditer(
-        r"\n%([\w.\-]+) \([^\n]*\{\n.*?\n\}\n", text, re.S)}
-    # the Arnoldi body: the computation that writes the basis row and
-    # chooses the stage
-    (body,) = [b for b in computations.values() if "gmres.update/scatter" in b
-               and re.search(r" conditional\([^\n]*gmres\.orth/", b)]
+    computations = _computations(text)
+    body = _arnoldi_body(computations)
+    assert "gmres.update/dynamic_update_slice" in body
     (branches,) = re.findall(
         r" conditional\([^\n]*branch_computations=\{([^}]*)\}[^\n]*gmres\.orth/",
         body)
     branches = [computations[b.strip().lstrip("%")] for b in branches.split(",")]
     _block, his = linalg._orth_stages(30)
-    assert his == (8, 16, 24, 31) and len(branches) == len(his)
-
-    def fusions(computation):
-        return re.findall(
-            r"^\s*(?:ROOT )?%?([\w.\-]+) = ([^\n]*?) fusion\(([^\n]*)$",
-            computation, re.M)
+    assert his == (4, 8, 12, 16, 20, 24, 28, 31) and len(branches) == len(his)
 
     named, unnamed = {}, []
     for name, _result, rest in [f for comp in [body, *branches]
-                                for f in fusions(comp)]:
+                                for f in _fusions(comp)]:
         m = re.search(r'op_name="([^"]*)"', rest)
         if m:
             named[name] = m.group(1)
@@ -786,7 +858,8 @@ def test_gmres_program_ops_carry_their_scope(one_chip):
             unnamed.append(name)
     assert len(named) + len(unnamed) >= 12 + 4 * len(his)
     for name, op_name in named.items():
-        under = [s for s in GMRES_SCOPES if f"/{s}/" in op_name]
+        # (a fusion of two ops lists both names, under the one scope)
+        under = {s for s in GMRES_SCOPES if f"/{s}/" in op_name}
         assert len(under) == 1, (name, op_name)
     for scope in GMRES_SCOPES:
         assert any(f"/{scope}/" in v for v in named.values()), scope
@@ -794,19 +867,21 @@ def test_gmres_program_ops_carry_their_scope(one_chip):
     # a stage's four contractions read the stage's rows of the basis and no
     # others: two give the stage's coefficients, two give a vector
     for hi, branch in zip(his, branches):
-        orth = [(result, re.search(r"calls=%([\w.\-]+)", rest).group(1))
-                for _name, result, rest in fusions(branch)
-                if re.search(r'op_name="[^"]*gmres\.orth/[^"]*dot_general"', rest)]
+        orth = [(result, _called(rest))
+                for _name, result, rest in _fusions(branch)
+                if re.search(r'op_name="[^"]*gmres\.orth/[^"]*reduce_sum"', rest)
+                and f"f32[31,{rows},128]" in computations[_called(rest)]]
         assert len(orth) == 4, (hi, orth)
-        assert sorted(re.match(r"f32\[\d+\]", r).group(0) for r, _ in orth) == \
-            sorted([f"f32[{hi}]"] * 2 + [f"f32[{n}]"] * 2)
+        assert sorted(re.match(r"f32\[[\d,]+\]", r).group(0) for r, _ in orth) == \
+            sorted([f"f32[{hi}]"] * 2 + [f"f32[{rows},128]"] * 2)
         for _result, fused in orth:
             inner = computations[fused]
             # the whole basis is the fusion's parameter (handed on by
-            # reference), the stage's tile groups its slice of it
-            assert re.search(r"= f32\[31,%d\]\S* parameter\(" % n, inner)
+            # reference), the stage's rows its slice of it: whole tiles for
+            # any count of rows
+            assert re.search(r"= f32\[31,%d,128\]\S* parameter\(" % rows, inner)
             if hi < 31:
-                assert f"slice={{[0:{hi}], [0:{n}]}}" in inner, (hi, fused)
+                assert f"slice={{[0:{hi}], [0:{rows}], [0:128]}}" in inner, (hi, fused)
 
 
 # ---------------------------------------------------------------------------

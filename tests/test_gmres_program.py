@@ -375,21 +375,21 @@ def test_the_compiled_program_names_its_scopes_and_is_jits_own():
     assert not re.search(r"gmres\.\w+/[^\"]*gmres\.\w+/", text)
 
 
-@pytest.mark.parametrize("m", [12, 20], ids=["one-stage-edge", "two-stage-edges"])
+@pytest.mark.parametrize("m", [12, 20], ids=["three-stage-edges", "five-stage-edges"])
 def test_the_arnoldi_basis_is_orthonormal(m):
     """The question the chip run of PR 42 answers at atmosmodd's size, here
     on the CPU: after a cycle ``V V^H`` is the identity to float32's
     rounding, and the residual the recurrence believes is the true one.
     ``m`` is far from converged, so that the residual is no rounding, and
-    its steps cross the edges of the orthogonalisation's stages (rows 8 and
-    16 of the basis)."""
+    its steps cross the edges of the orthogonalisation's stages (every
+    fourth row of the basis)."""
     A, b = _box((12, 7, 5))
     mv = linalg.make_linear_operator(A).matvec
     beta = jnp.linalg.norm(b)
     V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta,
                                            jnp.float32(1e-30), m)
     assert int(k) == m and not bool(bd)
-    V64 = np.asarray(V, np.float64)
+    V64 = np.asarray(linalg._basis_flat(V, b.shape[0]), np.float64)
     assert np.abs(V64 @ V64.T - np.eye(m + 1)).max() < 5e-6
     y = np.linalg.solve(np.triu(np.asarray(H, np.float64)[:m, :m]),
                         np.asarray(g, np.float64)[:m])
@@ -398,13 +398,15 @@ def test_the_arnoldi_basis_is_orthonormal(m):
     assert abs(float(g[m])) == pytest.approx(np.linalg.norm(r), rel=1e-3)
 
 
-# -- the orthogonalisation's stages (PR 43) -------------------------------------------------
+# -- the orthogonalisation's stages (PR 43; blocks of 4 rows since PR 47) --------------------
 @pytest.mark.parametrize("restart,block,his", [
-    (1, 8, (2,)), (5, 8, (6,)), (7, 8, (8,)),  # one stage: the whole basis
-    (8, 8, (8, 9)), (12, 8, (8, 13)), (30, 8, (8, 16, 24, 31)),
-    (33, 8, (8, 16, 24, 32, 34)), (63, 8, tuple(range(8, 65, 8))),
-    (64, 16, (16, 32, 48, 64, 65)), (70, 16, (16, 32, 48, 64, 71)),
-    (200, 32, (32, 64, 96, 128, 160, 192, 201)),
+    (1, 4, (2,)), (2, 4, (3,)), (3, 4, (4,)),  # one stage: the whole basis
+    (4, 4, (4, 5)), (5, 4, (4, 6)), (7, 4, (4, 8)), (8, 4, (4, 8, 9)),
+    (12, 4, (4, 8, 12, 13)), (30, 4, (*range(4, 29, 4), 31)),
+    (31, 4, tuple(range(4, 33, 4))), (33, 8, (8, 16, 24, 32, 34)),
+    (63, 8, tuple(range(8, 65, 8))), (64, 12, (12, 24, 36, 48, 60, 65)),
+    (70, 12, (12, 24, 36, 48, 60, 71)),
+    (200, 28, (*range(28, 197, 28), 201)),
 ])
 def test_the_stages_follow_from_restart_alone(restart, block, his):
     assert linalg._orth_stages(restart) == (block, his)
@@ -412,7 +414,7 @@ def test_the_stages_follow_from_restart_alone(restart, block, his):
     for k in range(restart):  # the least stage that holds rows 0..k
         stage = k // block
         assert (his[stage - 1] if stage else 0) < k + 1 <= his[stage]
-        assert his[stage] % 8 == 0 or his[stage] == restart + 1
+        assert his[stage] % 4 == 0 or his[stage] == restart + 1
 
 
 def _masked_arnoldi(mv, r, beta, target, m):
@@ -461,9 +463,9 @@ def _masked_arnoldi(mv, r, beta, target, m):
     return V, H, g, k, False, history
 
 
-# restart: the step (no multiple of 8) at which the run that converges and
-# the run that breaks down end, inside a stage
-STAGED = {5: 3, 8: 6, 12: 9, 30: 19, 33: 27, 70: 37}
+# restart: the step (no multiple of the block) at which the run that converges
+# and the run that breaks down end, inside a stage
+STAGED = {3: 2, 5: 3, 8: 6, 12: 9, 30: 19, 33: 27, 70: 37}
 
 
 @pytest.mark.parametrize("ends", ["whole", "converges", "breaks-down"])
@@ -471,8 +473,8 @@ STAGED = {5: 3, 8: 6, 12: 9, 30: 19, 33: 27, 70: 37}
                          ids=["float32", "complex64"])
 @pytest.mark.parametrize("restart", sorted(STAGED))
 def test_the_staged_pass_is_the_whole_masked_pass(restart, dtype, ends):
-    """One stage (5); a stage's edge (8); several (12, 30); past 32 rows
-    (33); the widened block (70). Rows past the step's are zero, so the
+    """One stage (3); two (5); a stage's edge (8); several (12, 30); past 32
+    rows, the block widened to 8 (33) and to 12 (70). Rows past the step's are zero, so the
     stage's contractions drop terms that are zero and nothing else: the
     process agrees with the masked full-basis one to float32's rounding."""
     stop = STAGED[restart]
@@ -496,7 +498,8 @@ def test_the_staged_pass_is_the_whole_masked_pass(restart, dtype, ends):
     Vr, Hr, gr, kr, bdr, _ = _masked_arnoldi(mv, b, beta, target, restart)
     V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta, target,
                                            restart)
-    assert V.dtype == dtype and V.shape == (restart + 1, b.shape[0])
+    assert V.dtype == dtype and V.shape[0] == restart + 1
+    V = linalg._basis_flat(V, b.shape[0])
     assert (int(k), bool(bd)) == (kr, bdr)
     assert (kr, bdr) == {"whole": (restart, False), "converges": (stop, False),
                          "breaks-down": (stop, True)}[ends]
@@ -509,34 +512,106 @@ def test_the_staged_pass_is_the_whole_masked_pass(restart, dtype, ends):
     assert np.abs(np.asarray(g)[:k + 1] - gr[:k + 1]).max() <= 5e-5 * float(beta)
 
 
+# -- the basis a row to a tile (PR 47) -------------------------------------------------------
+def _random_band(n, dtype, seed=7):
+    """A nonsymmetric, diagonally dominant banded matrix of any size ``n``
+    (the boxes' sizes are products; the layout's edges are 1024's), and b."""
+    rng = np.random.default_rng(seed)
+    offs = [-17, -3, -1, 0, 1, 5, 29]
+    diags = [rng.uniform(-1, 1, n - abs(o)) for o in offs]
+    diags[3] = 8.0 + rng.random(n)
+    S = sp.diags(diags, offs, format="csr").astype(dtype)
+    b = rng.uniform(0.5, 1.5, n).astype(dtype)
+    if np.issubdtype(dtype, np.complexfloating):
+        S = (S + 0.3j * sp.diags(rng.uniform(-1, 1, n))).tocsr().astype(dtype)
+        b = b * (1 + 0.5j)
+    return sparse_tpu.csr_array(S), jnp.asarray(b)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+@pytest.mark.parametrize("n", [420, 1024, 1025, 2048 + 7])
+def test_the_basis_is_a_row_to_a_tile_and_its_pad_stays_zero(n, dtype):
+    """``[m + 1, R, 128]`` with ``R = 8 ceil(n / 1024)``: under, on and one
+    past a whole number of ``(8, 128)`` tiles, and two tiles and a bit. After
+    a cycle the pad of every row is exactly zero (nothing of the loop writes
+    it), ``V V^H`` is the identity to float32's rounding, and basis,
+    Hessenberg and right-hand side are the masked full-basis process's on
+    ``[m + 1, n]``; the iterate of a solve is the cycle path's."""
+    m = 12
+    A, b = _random_band(n, dtype)
+    mv = linalg.make_linear_operator(A).matvec
+    beta = jnp.linalg.norm(b)
+    target = jnp.asarray(1e-30, beta.dtype)
+    V, H, g, k, bd = linalg._gmres_arnoldi(mv, lambda v: v, b, beta, target, m)
+    rows = 8 * -(-n // 1024)
+    assert V.shape == (m + 1, rows, 128) and V.dtype == dtype
+    assert (int(k), bool(bd)) == (m, False)
+    whole = np.asarray(V).reshape(m + 1, rows * 128)
+    assert whole[:, n:].size == (m + 1) * (rows * 128 - n)
+    assert not whole[:, n:].any()  # exactly zero
+    flat = np.asarray(linalg._basis_flat(V, n))
+    assert flat.shape == (m + 1, n) and np.array_equal(flat, whole[:, :n])
+    assert np.array_equal(np.asarray(linalg._basis_flat(V[3], n)), flat[3])
+    V64 = flat.astype(np.complex128)
+    assert np.abs(V64 @ V64.conj().T - np.eye(m + 1)).max() < 5e-6
+    Vr, Hr, gr, kr, bdr, _ = _masked_arnoldi(mv, b, beta, target, m)
+    assert (kr, bdr) == (m, False)
+    assert np.abs(flat - np.asarray(Vr)).max() <= 5e-5
+    assert np.abs(np.asarray(H) - Hr).max() <= 5e-5 * max(1.0, np.abs(Hr).max())
+    assert np.abs(np.asarray(g) - gr).max() <= 5e-5 * float(beta)
+    # the iterate: x += V y over the padded rows, cut to n
+    x, iters = linalg.gmres(A, b, restart=m, maxiter=2, tol=1e-30)
+    xc, ic = _cycle_path(A, b, restart=m, maxiter=2, tol=1e-30)
+    assert x.shape == (n,) and iters == ic == 2 * m
+    assert float(jnp.linalg.norm(x - xc)) <= 1e-6 * float(jnp.linalg.norm(xc))
+    y = np.linalg.solve(np.triu(np.asarray(H, np.complex128)[:m, :m]),
+                        np.asarray(g, np.complex128)[:m])
+    x1, _ = linalg.gmres(A, b, restart=m, maxiter=1, tol=1e-30)
+    assert np.abs(np.asarray(x1) - y @ V64[:m]).max() <= 5e-5 * np.abs(y).max()
+
+
+def test_the_basis_helpers_are_each_others_inverse():
+    v = jnp.arange(1.0, 1031.0, dtype=jnp.float32)
+    t = linalg._basis_tiles(v)
+    assert t.shape == (16, 128) and not np.asarray(t).reshape(-1)[1030:].any()
+    assert np.array_equal(np.asarray(linalg._basis_flat(t, 1030)), np.asarray(v))
+    Vs = jnp.stack([t, 2 * t])
+    h = jnp.asarray([0.5, -1.0], jnp.float32)
+    assert np.allclose(np.asarray(linalg._basis_project(Vs, t)),
+                       np.asarray(Vs).reshape(2, -1) @ np.asarray(t).reshape(-1))
+    assert np.allclose(np.asarray(linalg._basis_combine(h, Vs)), -1.5 * np.asarray(t))
+
+
 @pytest.mark.parametrize("path", ["program", "cycle-path"])
 @pytest.mark.parametrize("restart,kw", [
     (30, {"maxiter": 2, "tol": 1e-30}),  # whole cycles: 570 / 30
     (10, {"maxiter": 50, "tol": 1e-4}),  # ends inside a cycle
-    (5, {"maxiter": 3, "tol": 1e-30}),  # one stage: the whole basis
-], ids=["m30-whole", "m10-ends-early", "m5-one-stage"])
+    (3, {"maxiter": 3, "tol": 1e-30}),  # one stage: the whole basis
+], ids=["m30-whole", "m10-ends-early", "m3-one-stage"])
 def test_the_solve_span_counts_the_basis_rows_read(restart, kw, path, tel):
     A, b = _box((6, 5, 4))
     solve = linalg.gmres if path == "program" else _cycle_path
     _x, iters = solve(A, b, restart=restart, **kw)
     (ev,) = [e for e in telemetry.events("span") if e["name"] == "gmres.solve"]
     assert ev["path"] == ("device" if path == "program" else "cycle")
-    rows = [min(8 * (k % restart // 8 + 1), restart + 1) for k in range(iters)]
+    rows = [min(4 * (k % restart // 4 + 1), restart + 1) for k in range(iters)]
     assert ev["orth_rows"] == pytest.approx(sum(rows) / iters, abs=1e-3)
+    assert ev["basis_write_rows"] == 1  # a row to a tile (PR 47)
     if restart == 30:
-        assert iters == 60 and ev["orth_rows"] == 19.0
+        assert iters == 60 and ev["orth_rows"] == 17.0
     elif restart == 10:
-        assert iters % restart and 8.0 < ev["orth_rows"] < 8.6
+        assert iters % restart and 6.0 < ev["orth_rows"] < 7.3
     else:
-        assert ev["orth_rows"] == 6.0
+        assert ev["orth_rows"] == 4.0
     assert telemetry.schema.validate(ev) == []
 
 
-@pytest.mark.parametrize("restart,staged", [(5, False), (7, False), (8, True),
+@pytest.mark.parametrize("restart,staged", [(2, False), (3, False), (4, True),
                                             (30, True)])
 def test_a_restart_of_one_stage_has_no_conditional(restart, staged):
-    """``restart + 1`` rows within one tile group: the whole masked pass, the
-    program the tree had; past it one ``conditional`` chooses the stage."""
+    """``restart + 1`` rows within one block: the whole masked pass; past it
+    one ``conditional`` chooses the stage."""
     A, b = _box((6, 5, 4))
     text = linalg._gmres_compiled(A, b, restart).as_text()
     assert len(re.findall(r" conditional\(", text)) == int(staged)
