@@ -633,7 +633,7 @@ def band_order(indptr, indices, n: int, budget=None):
 def reverse_cuthill_mckee(csgraph, symmetric_mode=False):
     """Bandwidth-reducing RCM ordering (host numpy, a level at a time; feeds
     this library's banded DIA fast path — reorder, then convert to DIA —
-    and the windowed padded-row layout, ``csr_array._maybe_well``)."""
+    and the windowed step-major layout, ``csr_array._maybe_well``)."""
     row, col, w, n = _graph_coo(csgraph, directed=True)
     # the ordering always works on the symmetrized pattern
     row, col = np.concatenate([row, col]), np.concatenate([col, row])

@@ -40,15 +40,16 @@ from .utils import (
 # slabs for a skewed one; bare ``ell``/``sell`` are built whatever the
 # profile. ``dia`` needs a banded matrix (``dia.few_diagonals``); ``dia+`` is
 # the packed Pallas kernel on its planes, which declines a band too wide for
-# VMEM. ``well`` is the windowed padded-row layout, whose product is a
+# VMEM. ``well`` is the windowed step-major layout, whose product is a
 # Pallas kernel that gathers from x held in VMEM (kernels/well_spmv.py): the
-# matrix reordered to a band once, its padded rows tiled, each tile's columns
-# local to a short window of x. Nobody sets it: ``csr_array._maybe_well``
-# offers it from what it measures (a TPU, float32, square with a symmetric
-# pattern, a tight row profile, enough rows, x fitting VMEM, and windows
-# that stayed narrow after the reordering) and everywhere else the walk goes
-# on to the layouts below it as if it were not in the table. So ``pallas``
-# accelerates a banded matrix's vector product and nothing else.
+# matrix reordered to a band once, its rows tiled, each tile's columns local
+# to a short window of x and its entries stored a vreg to a step and depth.
+# Nobody sets it: ``csr_array._maybe_well`` offers it from what it measures
+# (a TPU, float32, square with a symmetric pattern, a tight row profile,
+# enough rows, x fitting VMEM, and windows that stayed narrow after the
+# reordering) and everywhere else the walk goes on to the layouts below it
+# as if it were not in the table. So ``pallas`` accelerates a banded
+# matrix's vector product and nothing else.
 # docs/performance.md shows the table by row profile;
 # tests/test_matvec_choice.py pins it.
 _LAYOUTS = {
@@ -73,19 +74,27 @@ def _layouts(ndim: int, mode: str | None = None) -> tuple:
 
 
 # The rule of ``well`` (``csr_array._maybe_well``), from the chip's readings
-# (PERF.md section 6, PR 32): XLA's gather costs 8.6 ns a stored entry, the
-# kernel 11.9 ns a plane and step of a tile's window.
-# x whole in VMEM beside the double-buffered blocks: 4 bytes a padded row
+# (PERF.md section 6, PR 32 and PR 48): XLA's gather costs 8.6 ns a slot of
+# the padded rows (rows x the longest row); the kernel 13 ns a unit of a
+# tile's window (a unit is an (8, 128) vreg of values and one of lanes: its
+# 8 KB from HBM at 740 GB/s, with its lane gather, one of sixteen in flight,
+# hidden beside that).
+# x whole in VMEM: 4 bytes a padded row
 _WELL_X_BYTES = 16 << 20
+# the blocks of lanes and of values in flight beside x, two of each: 16 KB a
+# unit of the fullest grid step
+_WELL_BLOCK_BYTES = 32 << 20
 # below this the host's build and the kernel's compile (a second or two)
 # outlast what a few solves save: one ELL product here is 8.6 ns x 7 x rows
 _WELL_MIN_ROWS = 1 << 17
-# the mean steps a tile past which the kernel no longer beats the ELL gather
-# by 2x (a tile's plane is 1024 entries, 8.8 us there; a step 11.9 ns a plane)
-_WELL_MAX_CHUNKS = 360
-# every tile's step list lives in SMEM for the product: 1 MiB on the v5e, of
-# which the lists may take half
-_WELL_MAX_STEPS = 1 << 17
+# the units a slot of the padded rows past which the kernel no longer beats
+# the ELL gather by 2x: 8.6 ns a slot over twice 13 ns a unit
+_WELL_MAX_UNITS_A_SLOT = 0.33
+# every unit's chunk lives in SMEM for the product (1 MiB on the v5e, of
+# which the lists may take half), and a stored unit is 8 KB of HBM: the cap
+# holds the units stored (every grid step the fullest one's count), so the
+# layout is 1.07 GB at most
+_WELL_MAX_UNITS = 1 << 17
 
 
 def _well_platform() -> bool:
@@ -110,7 +119,7 @@ def form_space(kind: str, meta, arrays):
 
     def matvec(v):
         return well_spmv(
-            arrays["ptr"], arrays["starts"], arrays["idx"], arrays["val"],
+            arrays["uptr"], arrays["ustart"], arrays["lane"], arrays["val"],
             v.reshape(n_pad // 128, 128), interpret=interpret,
         ).reshape(n_pad)
 
@@ -330,9 +339,9 @@ class csr_array(SparseArray):
             expect={"dtype": str(jax.dtypes.canonicalize_dtype(self.dtype))},
         )
 
-    # -- windowed padded rows (kernels/well_spmv.py) ------------------------
+    # -- windowed step-major units (kernels/well_spmv.py) --------------------
     def _maybe_well(self, xdtype=None):
-        """The windowed padded-row layout, where this matrix offers it; the
+        """The windowed step-major layout, where this matrix offers it; the
         rule is the comment above ``_WELL_X_BYTES``. Built once an operator
         on the host (the answer, None too, is cached); a product of another
         result type than float32 passes it over."""
@@ -366,17 +375,20 @@ class csr_array(SparseArray):
                 return None
             new_ptr, rows, cols, data, rank = ws.permuted_csr(
                 indptr, indices, data, order)
-            ptr, starts, step, stats = ws.windows(new_ptr, rows, cols, n, n_pad)
-            offered = (stats["window_chunks_mean"] <= _WELL_MAX_CHUNKS
-                       and stats["steps"] <= _WELL_MAX_STEPS)
+            uptr, ustart, unit, stats = ws.windows(new_ptr, rows, cols, n, n_pad)
+            block = stats["units_stored"] // (n_pad // ws.GRID_ROWS)
+            offered = (
+                stats["units"] <= _WELL_MAX_UNITS_A_SLOT * n * self._ell_width()
+                and stats["units_stored"] <= _WELL_MAX_UNITS
+                and 2 * ws.UNIT_BYTES * block <= _WELL_BLOCK_BYTES)
             sp.annotate(offered=offered, **stats)
         if not offered:
             return None
         with telemetry.span("layout.ell_build"):
-            idx, val = ws.padded_rows(new_ptr, rows, cols, data, step, n_pad)
+            lane, val = ws.step_units(uptr, rows, cols, data, unit)
         arrays = {
-            "ptr": ptr.astype(np.int32), "starts": starts.astype(np.int32),
-            "idx": idx, "val": val, "perm": order.astype(np.int32),
+            "uptr": uptr.astype(np.int32), "ustart": ustart.astype(np.int32),
+            "lane": lane, "val": val, "perm": order.astype(np.int32),
             "inv_perm": (rank + ws.LEAD).astype(np.int32),
         }
         arrays = dict(zip(arrays, commit_to_exec_device(

@@ -1,4 +1,4 @@
-"""Windowed padded-row SpMV: a Pallas kernel that gathers from x held in VMEM.
+"""Windowed step-major SpMV: a Pallas kernel that gathers from x held in VMEM.
 
 XLA's gather on the TPU costs the same whatever its indices (8.6 ns an
 element on the v5e: PERF.md section 5), so the padded-row product
@@ -10,19 +10,26 @@ of that by keeping each row tile's columns inside a short **window** of x:
 * the matrix is reordered once, on the host, by a bandwidth-reducing
   symmetric permutation (``csgraph.band_order``: Cuthill-McKee done a level
   at a time in numpy, reversed), so that a row's columns lie near the row;
-* the permuted matrix's padded rows are stored plane-major, ``[k, rows]``,
-  rows cut into tiles of ``TILE`` = 8 x 128 (one vreg a plane: sublane s
-  holds the tile's rows 128 s to 128 s + 127);
+* the permuted matrix's rows are cut into tiles of ``TILE`` = 8 x 128 (one
+  vreg: sublane s holds the tile's rows 128 s to 128 s + 127);
 * a tile's window is a list of **steps**. A step is eight consecutive
   128-lane chunks of x, one a sublane (an ``(8, 128)`` load at a dynamic
   sublane offset): sublane s of step d reads chunk d + s. In a band, rows
   128 s further on read columns 128 s further on, so one step serves all
   eight sublanes, and a window with holes (the levels before, of and after
   a row's own) lists only the chunks that hold an entry;
-* the kernel walks a tile's steps: the step's eight chunks are lane-gathered
-  through ``idx & 127`` and kept where ``idx >> 7`` names this step.
+* a tile's entries are stored step-major, as a list of **units**. A unit is
+  one ``(8, 128)`` vreg of values and one of lanes that belongs to one
+  step: a step has as many units as its fullest row has entries there, and
+  unit j of a step holds, for every row of the tile, the row's j-th entry
+  at that step (value 0, lane 0 where the row has fewer);
+* the kernel walks a tile's units, ``GROUP`` a trip: a unit's step's eight
+  chunks are lane-gathered through the unit's lanes, multiplied by its
+  values and added. Every gather's result is used as it is, and a trip's
+  gathers are independent, so that their latency (85 ns on the v5e, where
+  a unit's 8 KB from HBM take 11) is paid once a trip.
 
-x stays in VMEM whole for the product (4 bytes a row) and the step lists in
+x stays in VMEM whole for the product (4 bytes a row) and the unit lists in
 SMEM (the layout is offered only where both fit, ``csr_array._maybe_well``),
 and the CG runs in the permuted, padded space so that the two permutations
 are paid once a solve (``linalg._cg_general`` through ``csr.form_space``).
@@ -42,10 +49,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-TILE = 8 * LANES  # rows a tile: one (8, 128) vreg a plane
+TILE = 8 * LANES  # rows a tile: one (8, 128) vreg a unit
 LEAD = TILE  # zeros before the first row: sublane s of a step reads chunk d + s
-STEP_TILES = 8  # tiles one grid step multiplies (1 to 16 read the same on the chip)
-PLANE_GROUP = 12  # planes gathered in one pass over a tile's window
+STEP_TILES = 16  # tiles one grid step multiplies
+GRID_ROWS = TILE * STEP_TILES  # rows of the padded space a grid step
+UNIT_BYTES = 2 * 4 * TILE  # a stored unit: a vreg of lanes and one of values
+GROUP = 16  # units whose gathers the kernel keeps in flight together (a power of two)
+
+
 def symmetric_pattern(indptr, indices, n: int) -> bool:
     """Whether every stored (i, j) has a stored (j, i): the strictly upper
     entries' keys against the sorted keys of the strictly lower ones,
@@ -61,11 +72,11 @@ def symmetric_pattern(indptr, indices, n: int) -> bool:
 
 
 class WellLayout(NamedTuple):
-    """The windowed padded-row layout of one matrix: the arrays the kernel
-    and the two permutations take (``arrays``: ``ptr``, ``starts``, ``idx``,
-    ``val``, ``perm``, ``inv_perm``: the place in the padded space of every
-    index of the caller's), the hashable geometry (``meta`` = rows, padded
-    rows) and what the reordering left (``stats``)."""
+    """The windowed step-major layout of one matrix: the arrays the kernel
+    and the two permutations take (``arrays``: ``uptr``, ``ustart``,
+    ``lane``, ``val``, ``perm``, ``inv_perm``: the place in the padded space
+    of every index of the caller's), the hashable geometry (``meta`` = rows,
+    padded rows) and what the reordering left (``stats``)."""
 
     arrays: dict
     meta: tuple
@@ -92,10 +103,14 @@ def permuted_csr(indptr, indices, data, order):
 
 def windows(new_ptr, rows, cols, n: int, n_pad: int):
     """Per tile of ``TILE`` rows of the padded space (row r sits at ``LEAD +
-    r``): the steps its entries read. Returns ``(ptr, starts, step, stats)``:
-    tile t's steps are ``starts[ptr[t]:ptr[t + 1]]`` (the chunk its sublane
-    0 reads; ascending), ``step`` is every entry's place in its tile's list,
-    ``stats`` the reordering's figures for the span ``layout.reorder``."""
+    r``): the steps its entries read and the units that hold them. Returns
+    ``(uptr, ustart, unit, stats)``: tile t's units are ``uptr[t]:uptr[t +
+    1]``, step after step (a step's chunks ascend in a tile) and a step's by
+    depth; unit u reads from chunk ``ustart[u]`` (the chunk its sublane 0
+    reads); ``unit`` is every entry's unit (an entry's depth is its rank
+    among its row's entries at its step, so a step has as many units as the
+    most entries one row of the tile has there); ``stats`` the reordering's
+    figures for the span ``layout.reorder``."""
     n_tiles = n_pad // TILE
     at = rows + LEAD
     tile = at >> 10  # TILE rows
@@ -115,81 +130,123 @@ def windows(new_ptr, rows, cols, n: int, n_pad: int):
     used[flat] = True
     owner = np.repeat(np.arange(n_tiles), width)
     starts = np.flatnonzero(used) + (lo - off)[owner[used]]
-    count = np.bincount(owner[used], minlength=n_tiles)
+    steps = np.bincount(owner[used], minlength=n_tiles)
     ptr = np.zeros(n_tiles + 1, dtype=np.int64)
-    np.cumsum(count, out=ptr[1:])
-    # a flag's place in its tile's list: its rank among the flags set,
-    # less the tile's first
-    place = np.cumsum(used) - 1 - ptr[owner]
+    np.cumsum(steps, out=ptr[1:])
+    # an entry's step: its flag's rank among the flags set
+    step = (np.cumsum(used) - 1)[flat]
+    depth = _depths(rows, step - ptr[tile], int(steps.max()))
+    # a step's depths are 0 to its units less one: one flag a step and depth
+    held = np.zeros((int(ptr[-1]), int(depth.max(initial=0)) + 1), dtype=bool)
+    held[step, depth] = True
+    count = held.sum(axis=1)
+    first = np.concatenate([[0], np.cumsum(count)])  # a step's first unit
+    uptr = first[ptr]
     away = cols - rows
     stats = {
         "bandwidth": int(max(away.max(), -int(away.min()))) if cols.shape[0] else 0,
-        "window_chunks_max": int(count.max()),
-        "window_chunks_mean": float(count[live].mean()) if live.any() else 0.0,
+        "window_chunks_max": int(steps.max()),
+        "window_chunks_mean": float(steps[live].mean()) if live.any() else 0.0,
         "steps": int(ptr[-1]),
+        "units": int(uptr[-1]),
+        "units_stored": _block(uptr) * (n_pad // GRID_ROWS),
         "tile": TILE,
     }
-    return ptr, starts, place[flat], stats
+    return uptr, np.repeat(starts, count), first[step] + depth, stats
 
 
-def padded_rows(new_ptr, rows, cols, data, step, n_pad: int):
-    """The permuted matrix's padded rows, plane-major ``[k, n_pad / 128,
-    128]``: an entry's index is its step in the tile's list and its lane; a
-    padding slot has value 0 and reads lane 0 of the first step."""
-    k = max(int(np.diff(new_ptr).max()), 1)
-    slot = np.arange(rows.shape[0]) - new_ptr[rows]
-    idx = np.zeros((k, n_pad), dtype=np.int32)
-    val = np.zeros((k, n_pad), dtype=data.dtype)
-    idx[slot, rows + LEAD] = step * LANES + (cols & (LANES - 1))  # LEAD is whole chunks
-    val[slot, rows + LEAD] = data
-    shape = (k, n_pad // LANES, LANES)
-    return idx.reshape(shape), val.reshape(shape)
+def _block(uptr) -> int:
+    """The units of the fullest grid step (n_pad is whole grid steps)."""
+    return max(int(np.diff(uptr[::STEP_TILES]).max()), 1)
+
+
+def _depths(rows, place, places: int):
+    """Every entry's rank among its row's entries at its step (``place``:
+    the step's place in its tile's list, below ``places``). A row's entries
+    are consecutive, so the stable sort by (row, place) moves an entry
+    within its row alone."""
+    nnz = rows.shape[0]
+    # numpy sorts int32 keys five times faster than int64 ones
+    small = nnz and (int(rows[-1]) + 1) * places < 2**31
+    kt = np.int32 if small else np.int64
+    key = rows.astype(kt) * kt(places) + place.astype(kt)
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    at = np.arange(nnz, dtype=np.int32)
+    first = np.ones(nnz, dtype=bool)
+    np.not_equal(key[1:], key[:-1], out=first[1:])
+    depth = np.empty(nnz, dtype=np.int32)
+    depth[order] = at - np.maximum.accumulate(np.where(first, at, 0))
+    return depth
+
+
+def step_units(uptr, rows, cols, data, unit):
+    """The stored units ``(lane, val)``, each ``[grid steps * block, 8,
+    128]``: the units of one grid step's ``STEP_TILES`` tiles stand together
+    at the head of a block of as many units as the fullest grid step holds
+    (what the kernel's pipeline moves a grid step; the block's rest is never
+    read). A slot without an entry has value 0 and reads lane 0."""
+    head, block = uptr[::STEP_TILES], _block(uptr)
+    at = rows + LEAD
+    # a grid step's first stored unit, less its first unit
+    shift = np.arange(head.shape[0] - 1) * block - head[:-1]
+    stored = unit + shift[(at >> 10) // STEP_TILES]
+    lane = np.zeros(((head.shape[0] - 1) * block, TILE), dtype=np.int32)
+    val = np.zeros(lane.shape, dtype=data.dtype)
+    lane[stored, at & (TILE - 1)] = cols & (LANES - 1)  # LEAD is whole chunks
+    val[stored, at & (TILE - 1)] = data
+    return lane.reshape(-1, 8, LANES), val.reshape(-1, 8, LANES)
 
 
 def padded_size(n: int) -> int:
     """The padded space's length: the lead, the rows and a tail of eight
     chunks, up to whole grid steps."""
-    step = TILE * STEP_TILES
-    return -(-(LEAD + n + TILE) // step) * step
+    return -(-(LEAD + n + TILE) // GRID_ROWS) * GRID_ROWS
 
 
-def _kernel(ptr_ref, starts_ref, x_ref, idx_ref, val_ref, out_ref, *, k):
-    grid_step = pl.program_id(0)
-    for t in range(STEP_TILES):
-        tile = grid_step * STEP_TILES + t
-        lo = ptr_ref[tile]
-        count = ptr_ref[tile + 1] - lo
-        rows = pl.ds(t * 8, 8)
-        q = jnp.zeros((8, LANES), jnp.float32)
-        for g0 in range(0, k, PLANE_GROUP):
-            planes = range(g0, min(g0 + PLANE_GROUP, k))
-            idx = [idx_ref[p, rows, :] for p in planes]
-            # bit operations: `%` recurses in Mosaic under x64
-            lane = [i & (LANES - 1) for i in idx]
-            step = [i >> 7 for i in idx]
+def _kernel(uptr_ref, ustart_ref, x_ref, lane_ref, val_ref, out_ref):
+    tile0 = pl.program_id(0) * STEP_TILES
+    head = uptr_ref[tile0]  # the block's first unit
 
-            def body(c, accs, lane=lane, step=step, lo=lo):
-                x8 = x_ref[pl.ds(starts_ref[lo + c], 8), :]
-                return tuple(
-                    jnp.where(st == c, jnp.take_along_axis(x8, ln, axis=1), a)
-                    for ln, st, a in zip(lane, step, accs))
+    def tile(t, carry):
+        lo, hi = uptr_ref[tile0 + t], uptr_ref[tile0 + t + 1]
 
-            accs = jax.lax.fori_loop(
-                0, count, body,
-                tuple(jnp.zeros((8, LANES), jnp.float32) for _ in planes))
-            for p, a in zip(planes, accs):
-                q = q + val_ref[p, rows, :] * a
-        out_ref[rows, :] = q
+        def trip(i, acc):
+            # GROUP units a trip, their gathers in flight together: one
+            # alone waits out the lane gather's latency (88 ns; PERF.md
+            # section 6, PR 48). Past the tile's last unit a slot reads
+            # that unit again and adds nothing.
+            terms = []
+            for j in range(GROUP):
+                u = lo + i * GROUP + j
+                at = jnp.minimum(u, hi - 1)
+                x8 = x_ref[pl.ds(ustart_ref[at], 8), :]
+                stored = at - head
+                term = val_ref[stored] * jnp.take_along_axis(
+                    x8, lane_ref[stored], axis=1)
+                terms.append(jnp.where(u < hi, term, 0.0))
+            while len(terms) > 1:  # pairwise: no chain of GROUP adds
+                terms = [a + b for a, b in zip(terms[::2], terms[1::2])]
+            return acc + terms[0]
+
+        # GROUP is a power of two: a shift (`%` recurses in Mosaic under x64)
+        trips = (hi - lo + (GROUP - 1)) >> (GROUP.bit_length() - 1)
+        out_ref[pl.ds(pl.multiple_of(t * 8, 8), 8), :] = jax.lax.fori_loop(
+            0, trips, trip, jnp.zeros((8, LANES), jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, STEP_TILES, tile, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def well_spmv(ptr, starts, idx, val, x2, interpret=False):
+def well_spmv(uptr, ustart, lane, val, x2, interpret=False):
     """y = (P A P^T) x in the layout's space: ``x2`` is the permuted, padded
     vector as ``(n_pad / 128, 128)`` float32; the result has its shape."""
-    k, chunks, _ = idx.shape
+    chunks = x2.shape[0]
     rows = STEP_TILES * 8
+    block = lane.shape[0] // (chunks // rows)
     return pl.pallas_call(
-        functools.partial(_kernel, k=k),
+        _kernel,
         out_shape=jax.ShapeDtypeStruct((chunks, LANES), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -197,15 +254,16 @@ def well_spmv(ptr, starts, idx, val, x2, interpret=False):
             in_specs=[
                 # x whole and resident: brought in once a product
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec((k, rows, LANES), lambda i, *_: (0, i, 0)),
-                pl.BlockSpec((k, rows, LANES), lambda i, *_: (0, i, 0)),
+                pl.BlockSpec((block, 8, LANES), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((block, 8, LANES), lambda i, *_: (i, 0, 0)),
             ],
             out_specs=pl.BlockSpec((rows, LANES), lambda i, *_: (i, 0)),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(4 * chunks * LANES) + (16 << 20),
+            # x, and two blocks of lanes and of values in flight
+            vmem_limit_bytes=4 * chunks * LANES + 2 * UNIT_BYTES * block + (16 << 20),
         ),
         name="well_spmv",
         interpret=interpret,
-    )(ptr, starts, x2, idx, val)
+    )(uptr, ustart, x2, lane, val)

@@ -636,37 +636,47 @@ def test_cg_general_compiles_with_the_matrix_as_an_argument(one_chip):
 
 
 # ---------------------------------------------------------------------------
-# the windowed padded-row layout (kernels/well_spmv.py): the kernel alone and
+# the windowed step-major layout (kernels/well_spmv.py): the kernel alone and
 # the general CG through it, at the benchmark cell's size and at the rule's
-# far corner (x filling its VMEM budget, the step lists their SMEM budget)
+# far corner (x filling its VMEM budget, the unit lists their SMEM budget,
+# the fullest grid step's block its VMEM budget)
 # ---------------------------------------------------------------------------
-def _well_arrays(n, width, steps, sharding):
+WELL_CELL = (1108 * 1108, 39015, 582)  # spd_general_1chip: rows, units, block
+
+
+def _well_arrays(n, units, block, sharding):
+    """The layout's arrays as ``csr_array._well_build`` hands them over:
+    ``units`` units in all, ``block`` in the fullest grid step."""
     from sparse_tpu.kernels import well_spmv as ws
 
     n_pad = ws.padded_size(n)
-    planes = (width, n_pad // ws.LANES, ws.LANES)
+    stored = (n_pad // (ws.TILE * ws.STEP_TILES) * block, 8, ws.LANES)
     return n_pad, {
-        "ptr": _sds((n_pad // ws.TILE + 1,), jnp.int32, sharding),
-        "starts": _sds((steps,), jnp.int32, sharding),
-        "idx": _sds(planes, jnp.int32, sharding),
-        "val": _sds(planes, jnp.float32, sharding),
+        "uptr": _sds((n_pad // ws.TILE + 1,), jnp.int32, sharding),
+        "ustart": _sds((units,), jnp.int32, sharding),
+        "lane": _sds(stored, jnp.int32, sharding),
+        "val": _sds(stored, jnp.float32, sharding),
         "perm": _sds((n,), jnp.int32, sharding),
         "inv_perm": _sds((n,), jnp.int32, sharding),
     }
 
 
-@pytest.mark.parametrize("n,width,steps", [
-    (1108 * 1108, 9, 18182),  # spd_general_1chip, seed 3100000511
-    (4 << 20, 16, 1 << 17),   # csr._WELL_X_BYTES of x, _WELL_MAX_STEPS
+@pytest.mark.parametrize("n,units,block", [
+    WELL_CELL,
+    # csr._WELL_X_BYTES of x, _WELL_MAX_UNITS stored, one grid step's block
+    # at _WELL_BLOCK_BYTES (the stored units are then past the rule's cap:
+    # the corner of every budget at once)
+    (4 << 20, 1 << 17, 2048),
 ], ids=["cell", "rule-corner"])
-def test_well_spmv_kernel_compiles(one_chip, n, width, steps):
+def test_well_spmv_kernel_compiles(one_chip, n, units, block):
     from sparse_tpu import csr
     from sparse_tpu.kernels import well_spmv as ws
 
-    assert 4 * n <= csr._WELL_X_BYTES and steps <= csr._WELL_MAX_STEPS
-    n_pad, a = _well_arrays(n, width, steps, one_chip)
+    assert 4 * n <= csr._WELL_X_BYTES and units <= csr._WELL_MAX_UNITS
+    assert 2 * ws.UNIT_BYTES * block <= csr._WELL_BLOCK_BYTES
+    n_pad, a = _well_arrays(n, units, block, one_chip)
     x2 = _sds((n_pad // ws.LANES, ws.LANES), jnp.float32, one_chip)
-    c = ws.well_spmv.lower(a["ptr"], a["starts"], a["idx"], a["val"], x2).compile()
+    c = ws.well_spmv.lower(a["uptr"], a["ustart"], a["lane"], a["val"], x2).compile()
     assert "well_spmv" in c.as_text() and "tpu_custom_call" in c.as_text()
     assert _device_bytes(c) < HBM_BYTES
 
@@ -679,8 +689,8 @@ def test_cg_general_compiles_through_the_windowed_layout(one_chip, monkeypatch):
     # `csr.form_space` interprets the kernel off a TPU; this process's
     # backend is the CPU and the program is compiled for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n = 1108 * 1108
-    n_pad, arrays = _well_arrays(n, 9, 18182, one_chip)
+    n = WELL_CELL[0]
+    n_pad, arrays = _well_arrays(*WELL_CELL, one_chip)
     lowered = linalg._cg_general_program.lower(
         arrays, _sds((n,), jnp.float32, one_chip), None, 1e-8, 50,
         kind="well", meta=(n, n_pad), conv_test_iters=25, tapped=False)

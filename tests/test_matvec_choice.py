@@ -6,7 +6,7 @@ the product left behind on it (``_dia``, the plan cache's ``_dia_prepared``
 and ``sell`` entries, ``_ell``, ``_well``), so the file does not depend on
 how the choice is written.
 
-``well`` (the windowed padded rows, kernels/well_spmv.py) is a TPU's: on this
+``well`` (the windowed step-major units, kernels/well_spmv.py) is a TPU's: on this
 backend nothing offers it, and with its platform gate opened by a test it is
 offered by the matrix alone (``MESH``), never by a mode that names a layout.
 """

@@ -1,4 +1,4 @@
-"""The windowed padded-row layout (``well``): the host's reordering and
+"""The windowed step-major layout (``well``): the host's reordering and
 build, the Pallas kernel in interpret mode, the rule that offers it
 (``csr_array._maybe_well``), and ``linalg.cg`` through it.
 
@@ -37,17 +37,20 @@ def _layout(S, order=None):
     n_pad = ws.padded_size(n)
     new_ptr, rows, cols, data, rank = ws.permuted_csr(
         S.indptr, S.indices, S.data, order)
-    ptr, starts, step, stats = ws.windows(new_ptr, rows, cols, n, n_pad)
-    idx, val = ws.padded_rows(new_ptr, rows, cols, data, step, n_pad)
-    return dict(ptr=ptr.astype(np.int32), starts=starts.astype(np.int32),
-                idx=idx, val=val, perm=order, inv_perm=rank + ws.LEAD, n=n,
+    uptr, ustart, unit, stats = ws.windows(new_ptr, rows, cols, n, n_pad)
+    lane, val = ws.step_units(uptr, rows, cols, data, unit)
+    return dict(uptr=uptr.astype(np.int32), ustart=ustart.astype(np.int32),
+                lane=lane, val=val, perm=order, inv_perm=rank + ws.LEAD, n=n,
                 n_pad=n_pad, stats=stats)
 
 
-def _product(lay, x):
+def _product(lay, x, **other):
+    """The kernel's product in the caller's order (``other``: arrays that
+    take the layout's place)."""
+    lay = {**lay, **other}
     xp = np.zeros(lay["n_pad"], np.float32)
     xp[ws.LEAD: ws.LEAD + lay["n"]] = x[lay["perm"]]
-    y = ws.well_spmv(lay["ptr"], lay["starts"], lay["idx"], lay["val"],
+    y = ws.well_spmv(lay["uptr"], lay["ustart"], lay["lane"], lay["val"],
                      jnp.asarray(xp.reshape(-1, 128)), interpret=True)
     y = np.asarray(y).reshape(-1)
     # the lead and the tail multiply to zero
@@ -70,56 +73,156 @@ def _uneven(seed):
     return S
 
 
+def _long_rows():
+    """Rows longer than twelve entries (the plane-major kernel gathered
+    twelve planes a pass; a unit list has no such seam)."""
+    rng = np.random.default_rng(3)
+    n = 1500
+    R = sp.random(n, n, density=10.0 / n, random_state=rng, dtype=np.float32)
+    S = (R + R.T).tocsr()
+    assert np.diff(S.indptr).max() > 12
+    return S
+
+
 SYSTEMS = {
     # 3600 rows: three and a half tiles of 1024, padded to one grid step
     "grid-60": lambda: as_scipy(spd_data(60, 5)),
-    # 10000 rows: nine tiles and three quarters, two grid steps
+    # 10000 rows: nine tiles and three quarters
     "grid-100": lambda: as_scipy(spd_data(100, 6)),
     "uneven": lambda: _uneven(7),
 }
+PRODUCTS = {**SYSTEMS, "rows-longer-than-twelve-entries": _long_rows}
+
+
+def _rel(y, S, x):
+    ref = S.astype(np.float64) @ x.astype(np.float64)
+    return np.abs(y - ref).max() / np.abs(ref).max()
 
 
 # ---------------------------------------------------------------------------
 # the kernel
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("system", sorted(SYSTEMS))
-def test_kernel_product_against_the_ell_product_and_scipy(system):
-    S = SYSTEMS[system]()
-    n = S.shape[0]
-    lay = _layout(S)
-    assert lay["n_pad"] % (ws.TILE * ws.STEP_TILES) == 0 and n % ws.TILE
-    x = np.random.default_rng(1).standard_normal(n).astype(np.float32)
-    y = _product(lay, x)
-    ref = S.astype(np.float64) @ x.astype(np.float64)
-    scale = np.abs(ref).max()
-    assert np.abs(y - ref).max() / scale < 1e-6
-    A = sparse_tpu.csr_array(S)
-    ell = A._maybe_ell()
-    y_ell = np.asarray(spmv_ops.csr_spmv_ell(*ell, jnp.asarray(x)))
-    assert np.abs(y - y_ell).max() / scale < 1e-6
-
-
-def test_kernel_in_the_order_given_multiplies_the_same():
+@pytest.mark.parametrize("order", ["reordered", "given"])
+@pytest.mark.parametrize("system", sorted(PRODUCTS))
+def test_kernel_product_against_scipy_in_float64(system, order):
     """The layout is sound under any ordering (the identity: the cell's
     random order, every window all of x); the ordering only shortens it."""
-    S = SYSTEMS["grid-60"]()
+    S = PRODUCTS[system]()
     n = S.shape[0]
-    lay = _layout(S, order=np.arange(n))
-    assert lay["stats"]["window_chunks_max"] >= -(-n // 128)
-    x = np.random.default_rng(2).standard_normal(n).astype(np.float32)
-    ref = S.astype(np.float64) @ x.astype(np.float64)
-    assert np.abs(_product(lay, x) - ref).max() / np.abs(ref).max() < 1e-6
+    lay = _layout(S, order=np.arange(n) if order == "given" else None)
+    assert lay["n_pad"] % ws.GRID_ROWS == 0 and n % ws.TILE
+    if order == "given" and system != "uneven":  # (its band is in the order given)
+        assert lay["stats"]["window_chunks_max"] >= -(-n // 128) - 8
+    x = np.random.default_rng(1).standard_normal(n).astype(np.float32)
+    assert _rel(_product(lay, x), S, x) < 1e-6
 
 
-def test_more_planes_than_one_gather_pass_holds():
-    rng = np.random.default_rng(3)
-    n = 1500
-    R = sp.random(n, n, density=10.0 / n, random_state=rng, dtype=np.float32)
-    S = (R + R.T).tocsr()
-    assert np.diff(S.indptr).max() > ws.PLANE_GROUP
-    x = rng.standard_normal(n).astype(np.float32)
-    ref = S.astype(np.float64) @ x.astype(np.float64)
-    assert np.abs(_product(_layout(S), x) - ref).max() / np.abs(ref).max() < 1e-6
+@pytest.mark.parametrize("system", sorted(SYSTEMS))
+def test_kernel_product_against_the_ell_product(system):
+    S = SYSTEMS[system]()
+    x = np.random.default_rng(1).standard_normal(S.shape[0]).astype(np.float32)
+    y = _product(_layout(S), x)
+    ell = sparse_tpu.csr_array(S)._maybe_ell()
+    y_ell = np.asarray(spmv_ops.csr_spmv_ell(*ell, jnp.asarray(x)))
+    assert np.abs(y - y_ell).max() / np.abs(y_ell).max() < 1e-6
+
+
+# ---------------------------------------------------------------------------
+# the host builder, on hand-made patterns
+# ---------------------------------------------------------------------------
+def _by_hand(n, entries):
+    """A float32 CSR matrix of ``entries`` = {(row, col): value}."""
+    r, c = (np.array(a) for a in zip(*entries))
+    return sp.csr_matrix((np.array(list(entries.values()), np.float32), (r, c)),
+                         shape=(n, n))
+
+
+def _fullest_rows(S):
+    """{(tile, chunk a sublane-0 row would read): the most entries one row
+    of the tile has there}, counted entry by entry."""
+    per_row = {}
+    coo = S.tocoo()
+    for r, c in zip(coo.row.tolist(), coo.col.tolist()):
+        at = r + ws.LEAD
+        key = (at // ws.TILE, (c + ws.LEAD) // 128 - (at // 128) % 8, r)
+        per_row[key] = per_row.get(key, 0) + 1
+    fullest = {}
+    for (tile, start, _r), count in per_row.items():
+        fullest[tile, start] = max(fullest.get((tile, start), 0), count)
+    return fullest
+
+
+HAND_MADE = {
+    # row 5: five entries in the chunk of columns 0 to 127 (depths 0 to 4),
+    # one in the next chunk; row 6 two and one; row 1500 (another tile) alone
+    "five-in-one-chunk": lambda: _by_hand(2100, {
+        **{(5, c): 1.0 + c for c in (0, 3, 64, 100, 127)}, (5, 128): -2.0,
+        (6, 1): 0.5, (6, 2): 0.25, (6, 200): 4.0, (1500, 1400): 3.0}),
+    # rows 1100 to 2999 hold nothing: the tiles between are without rows
+    "tiles-without-rows": lambda: _by_hand(3000, {
+        (0, 0): 1.0, (3, 2999): 2.0, (1099, 5): 3.0, (2999, 3): 4.0}),
+    "one-entry": lambda: _by_hand(1000, {(999, 0): 7.0}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HAND_MADE) + sorted(SYSTEMS))
+def test_builder_counts_a_unit_for_every_entry_of_a_steps_fullest_row(case):
+    S = {**HAND_MADE, **SYSTEMS}[case]()
+    lay = _layout(S, order=np.arange(S.shape[0]))
+    fullest = _fullest_rows(S)
+    uptr, ustart = lay["uptr"], lay["ustart"]
+    assert lay["stats"]["steps"] == len(fullest)
+    assert lay["stats"]["units"] == sum(fullest.values()) == ustart.shape[0]
+    for t in range(uptr.shape[0] - 1):
+        # a tile's units: step after step in the chunks' order, a step as
+        # many as its fullest row has entries; a tile without rows has none
+        mine = sorted(s for (tile, s) in fullest if tile == t)
+        units = [s for s in mine for _ in range(fullest[t, s])]
+        assert ustart[uptr[t]:uptr[t + 1]].tolist() == units
+    # every entry stored once, the other slots value 0 and lane 0
+    assert np.count_nonzero(lay["val"]) == S.nnz
+    assert not lay["lane"][lay["val"] == 0].any()
+    x = np.random.default_rng(8).standard_normal(S.shape[0]).astype(np.float32)
+    assert _rel(_product(lay, x), S, x) < 1e-6
+
+
+def test_builder_gives_a_row_of_five_in_one_chunk_five_depths():
+    S = HAND_MADE["five-in-one-chunk"]()
+    lay = _layout(S, order=np.arange(S.shape[0]))
+    # tile 1 holds rows 0 to 1023 (after the lead): a step of five units
+    # (row 5's five; row 6's two lie in the first two), then one of one
+    assert lay["ustart"].tolist() == [8] * 5 + [9, 15] and lay["uptr"][:4].tolist() == [0, 0, 6, 7]
+    first = lay["uptr"][1]
+    at_5 = (0, 5)  # sublane 0, lane 5 of the tile's vreg
+    assert lay["lane"][first: first + 5, 0, 5].tolist() == [0, 3, 64, 100, 127]
+    assert lay["val"][first: first + 5][(..., *at_5)].tolist() == [1.0, 4.0, 65.0, 101.0, 128.0]
+    assert lay["lane"][first: first + 5, 0, 6].tolist() == [1, 2, 0, 0, 0]
+    assert lay["val"][first: first + 5, 0, 6].tolist() == [0.5, 0.25, 0.0, 0.0, 0.0]
+    x = np.arange(1.0, S.shape[0] + 1, dtype=np.float32)
+    y = _product(lay, x)
+    assert y[5] == 1 * 1 + 4 * 4 + 65 * 65 + 101 * 101 + 128 * 128 - 2 * 129
+    assert y[6] == 0.5 * 2 + 0.25 * 3 + 4 * 201 and y[1500] == 3 * 1401
+
+
+def test_padding_units_add_zero_and_are_never_read():
+    """A grid step's block is as long as the fullest grid step's: its rest
+    is zeros, and the kernel walks a tile's own units alone."""
+    S = as_scipy(spd_data(130, 8))  # 16,900 rows: two grid steps
+    lay = _layout(S)
+    steps = lay["n_pad"] // ws.GRID_ROWS
+    block = lay["val"].shape[0] // steps
+    own = np.diff(lay["uptr"][:: ws.STEP_TILES])
+    assert steps == 2 and own.max() == block and own.min() < block
+    assert lay["stats"]["units_stored"] == steps * block > lay["stats"]["units"]
+    rest = np.ones(lay["val"].shape[0], dtype=bool)
+    for g in range(steps):
+        rest[g * block: g * block + own[g]] = False
+    assert rest.any() and not lay["val"][rest].any() and not lay["lane"][rest].any()
+    x = np.random.default_rng(9).standard_normal(S.shape[0]).astype(np.float32)
+    y = _product(lay, x)
+    val = lay["val"].copy()
+    val[rest] = np.nan
+    np.testing.assert_array_equal(y, _product(lay, x, val=val))
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +314,10 @@ def _hub():
 DECLINED = {
     "nonsymmetric": (_nonsymmetric, {}),
     "hub-row": (_hub, {}),
-    "wide-window": (_wide, {"_WELL_MAX_CHUNKS": 8}),
+    # 118 units a tile of 1024 rows of 17 slots here: 0.0068 a slot
+    "wide-window": (_wide, {"_WELL_MAX_UNITS_A_SLOT": 0.005}),
+    "units-past-the-cap": (SYSTEMS["grid-60"], {"_WELL_MAX_UNITS": 64}),
+    "block-past-vmem": (SYSTEMS["grid-60"], {"_WELL_BLOCK_BYTES": 1 << 20}),
     "small": (SYSTEMS["grid-60"], {"_WELL_MIN_ROWS": 4000}),
     "x-past-vmem": (SYSTEMS["grid-60"], {"_WELL_X_BYTES": 8192}),
     "float64": (lambda: SYSTEMS["grid-60"]().astype(np.float64), {}),
@@ -272,6 +378,11 @@ def test_rule_offers_counts_and_records(on_the_chip, monkeypatch, tmp_path):
     ev = spans[0]  # once an operator, with what the reordering left
     assert ev["offered"] is True and ev["tile"] == ws.TILE
     assert 0 < ev["window_chunks_mean"] <= ev["window_chunks_max"] <= 16
+    # a step has a unit at least, and no more than the longest row's entries
+    k = int(np.diff(S.indptr).max())
+    assert ev["steps"] <= ev["units"] <= ev["steps"] * k
+    assert ev["units"] <= ev["units_stored"] == A._well.arrays["val"].shape[0]
+    assert ev["units"] == A._well.arrays["ustart"].shape[0] == 71
     assert 60 <= ev["bandwidth"] <= 180
     assert A._spmv_form(x.dtype)[0] == "well" and A._ell is None
     ref = S.astype(np.float64) @ x.astype(np.float64)
