@@ -16,7 +16,7 @@ data bound for transfer-restricted backends rides the stacked-real shim
 (two real planes recombined in a compiled program) — c64 batches work
 through the public API on such backends the same way unbatched solves do.
 
-The loop cores (``_cg_loop``/``_bicgstab_loop``) are pure jnp and
+The loop cores (``_cg_loop``/``_bicgstab_loop``/``_gmres_loop``) are pure jnp and
 jit-safe: :class:`~sparse_tpu.batch.service.SolveSession` closes them
 over a pattern's packed matvec inside ONE jitted program per batch
 bucket, which is where the compile-amortization of microbatching comes
@@ -26,6 +26,7 @@ from (one trace+compile serves every same-bucket dispatch).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -334,146 +335,178 @@ def batched_bicgstab(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
 
 
 # ---------------------------------------------------------------------------
-# GMRES — batched restart cycles, host-driven outer loop
+# GMRES — the library's Arnoldi cycle with a lane axis in front, restarts
+# inside the loop
 # ---------------------------------------------------------------------------
-def _make_batched_gmres_cycle(mv, Mv, restart: int, dt):
-    """The device-resident restart cycle of ``linalg._make_gmres_cycle``
-    with a leading batch dimension: per-lane Hessenberg/Givens scalars
-    become ``(B,)`` vectors, the Krylov basis is ``(B, restart+1, n)``,
-    and lanes that converge or break down mid-cycle freeze (their
-    carries mask on ``~done``) while the shared step counter finishes the
-    others. ONE host sync per cycle: the packed per-lane ``(inner, entry
-    residual, breakdown)`` triple."""
-    rdt = jnp.zeros((), dt).real.dtype
+def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
+    """The Arnoldi process of ``linalg._gmres_arnoldi`` for B lanes at once,
+    from the lanes' (preconditioned) residuals ``R [B, n]`` of norms ``beta
+    [B]``: the same step (``linalg._orth_against``, ``_givens_column``), the
+    same basis layout a row to a tile with the lane axis in front (``[B,
+    restart + 1, R, 128]``; ``linalg._basis_*``), and ONE step counter ``j``
+    for the bucket. A lane that converges, breaks down or starts at its
+    target is ``done``: its Hessenberg, rotations, right-hand side and
+    column count ``kk`` freeze under the mask while ``j`` finishes the
+    bucket's last lane (the loop ends at ``restart`` or when every lane is
+    done). The stage ``V[:, :his[j // block]]`` of ``linalg._orth_stages``
+    is chosen by ``j``, one ``lax.switch`` index for the bucket: a ``vmap``
+    of the library's loop would turn its carry into a select over the whole
+    basis and run every stage. One basis row is written a step and lane, in
+    place and unmasked: a done lane's later rows take no part in its answer
+    (their coefficients ``y`` are zero past ``kk``), and they stay finite
+    (normalised vectors, or zero after a breakdown).
 
-    @jax.jit
-    def cycle(X, b, target):
-        B, n = b.shape
-        R = Mv(b - mv(X))
-        beta = jnp.linalg.norm(R, axis=-1)
+    ``(V, H, g, kk, breakdown)``: per lane what the library's gives."""
+    from ..linalg import (_basis_flat, _basis_tiles, _givens_column,
+                          _orth_against, _orth_stages)
+
+    dt = R.dtype
+    rdt = jnp.zeros((), dt).real.dtype
+    B, n = R.shape
+    with jax.named_scope("bucket.gmres.update"):
         start_ok = beta > target
         beta_safe = jnp.where(start_ok, beta, 1.0)
-        V = jnp.zeros((B, restart + 1, n), dtype=dt)
-        V = V.at[:, 0].set(R / beta_safe[:, None].astype(dt))
-        H = jnp.zeros((B, restart + 1, restart), dtype=dt)
-        cs = jnp.zeros((B, restart), dtype=rdt)
-        sn = jnp.zeros((B, restart), dtype=dt)
-        g = jnp.zeros((B, restart + 1), dtype=dt)
-        g = g.at[:, 0].set(beta.astype(dt))
+        v0 = _basis_tiles(R / beta_safe[:, None].astype(dt))
+        V = jnp.zeros((B, restart + 1, *v0.shape[1:]), dtype=dt)
+        V = V.at[:, 0].set(v0)
+    H = jnp.zeros((B, restart + 1, restart), dtype=dt)
+    cs = jnp.zeros((B, restart), dtype=rdt)
+    sn = jnp.zeros((B, restart), dtype=dt)
+    g = jnp.zeros((B, restart + 1), dtype=dt).at[:, 0].set(beta.astype(dt))
 
-        def cond(st):
-            done, j = st[7], st[8]
-            return (j < restart) & jnp.any(~done)
+    block, his = _orth_stages(restart)
+    stages = [partial(_orth_against, hi=hi, restart=restart) for hi in his]
+    # the step's scalars are a lane's own; the column index is the bucket's
+    givens = jax.vmap(_givens_column, in_axes=(0, 0, 0, 0, 0, 0, None, 0))
 
-        def body(st):
-            V, H, cs, sn, g, kk, bd, done, j = st
-            w = Mv(mv(V[:, j]))
-            # masked modified Gram-Schmidt + one reorthogonalization pass,
-            # batched as full-basis einsums (MXU-shaped, like unbatched)
-            mask = (jnp.arange(restart + 1) <= j).astype(rdt)
-            hcol = jnp.einsum("bin,bn->bi", V.conj(), w) * mask
-            w = w - jnp.einsum("bi,bin->bn", hcol, V)
-            h2 = jnp.einsum("bin,bn->bi", V.conj(), w) * mask
-            w = w - jnp.einsum("bi,bin->bn", h2, V)
-            hcol = hcol + h2
-            hkk = jnp.linalg.norm(w, axis=-1)
+    def cond(st):
+        done, j = st[7], st[8]
+        return (j < restart) & jnp.any(~done)
+
+    def body(st):
+        V, H, cs, sn, g, kk, bd, done, j = st
+        with jax.named_scope("bucket.gmres.spmv"):
+            vj = jax.lax.dynamic_index_in_dim(V, j, 1, keepdims=False)
+            w = _basis_tiles(Mv(mv(_basis_flat(vj, n))))
+        with jax.named_scope("bucket.gmres.orth"):
+            hcol, w, ww = jax.lax.switch(j // block, stages, V, w, j)
+        with jax.named_scope("bucket.gmres.small"):
+            hkk = jnp.sqrt(ww)
             grew = hkk > 1e-30
+        with jax.named_scope("bucket.gmres.update"):
+            # j + 1 <= restart: a plain in-place write of each lane's one
+            # row, the division inside its fusion
+            scale = jnp.where(grew, hkk, 1.0)[:, None, None]
+            V = jax.lax.dynamic_update_index_in_dim(
+                V, jnp.where(grew[:, None, None], w / scale, 0.0
+                             ).astype(dt)[:, None], j + 1, 1)
+        with jax.named_scope("bucket.gmres.small"):
+            Hn, csn, snn, gn, breakdown, conv = givens(
+                hcol, hkk, H, cs, sn, g, j, target)
             upd = ~done
-            vnew = jnp.where(
-                grew[:, None],
-                w / jnp.where(grew, hkk, 1.0)[:, None].astype(dt),
-                0.0,
-            )
-            V = V.at[:, j + 1].set(
-                jnp.where(upd[:, None], vnew, V[:, j + 1])
-            )
-            col = hcol.at[:, j + 1].set(hkk.astype(dt))
-
-            def giv(i, c):
-                t = cs[:, i] * c[:, i] + sn[:, i] * c[:, i + 1]
-                bt = (
-                    -jnp.conj(sn[:, i]) * c[:, i] + cs[:, i] * c[:, i + 1]
-                )
-                app = i < j
-                c = c.at[:, i].set(jnp.where(app, t, c[:, i]))
-                return c.at[:, i + 1].set(jnp.where(app, bt, c[:, i + 1]))
-
-            col = jax.lax.fori_loop(0, restart, giv, col)
-            hk, hk1 = col[:, j], col[:, j + 1]
-            ahk = jnp.abs(hk)
-            ahk1 = jnp.abs(hk1)
-            denom = jnp.sqrt(ahk * ahk + ahk1 * ahk1)
-            breakdown = denom <= 0
-            denom_s = jnp.where(breakdown, 1.0, denom)
-            ck = jnp.where(ahk == 0, 0.0, ahk / denom_s)
-            hk_unit = jnp.where(
-                ahk == 0, 1.0, hk / jnp.where(ahk == 0, 1.0, ahk).astype(dt)
-            )
-            sk = jnp.where(
-                ahk == 0,
-                jnp.conj(hk1) / jnp.where(ahk1 == 0, 1.0, ahk1).astype(dt),
-                hk_unit * jnp.conj(hk1) / denom_s.astype(dt),
-            )
-            col = col.at[:, j].set(ck.astype(dt) * hk + sk * hk1)
-            col = col.at[:, j + 1].set(0.0)
-            H = H.at[:, :, j].set(
-                jnp.where(upd[:, None], col, H[:, :, j])
-            )
-            cs = cs.at[:, j].set(jnp.where(upd, ck, cs[:, j]))
-            sn = sn.at[:, j].set(jnp.where(upd, sk, sn[:, j]))
-            gk1 = -jnp.conj(sk) * g[:, j]
-            ok = upd & ~breakdown
-            g = g.at[:, j + 1].set(jnp.where(ok, gk1, g[:, j + 1]))
-            g = g.at[:, j].set(
-                jnp.where(ok, ck.astype(dt) * g[:, j], g[:, j])
-            )
-            conv = jnp.abs(gk1) < target
-            kk = kk + ok.astype(jnp.int32)
+            H = jnp.where(upd[:, None, None], Hn, H)
+            cs = jnp.where(upd[:, None], csn, cs)
+            sn = jnp.where(upd[:, None], snn, sn)
+            g = jnp.where(upd[:, None], gn, g)
+            kk = kk + (upd & ~breakdown).astype(jnp.int32)
             bd = bd | (upd & breakdown)
             done = done | (upd & (breakdown | conv))
-            return V, H, cs, sn, g, kk, bd, done, j + 1
+        return V, H, cs, sn, g, kk, bd, done, j + 1
 
-        st = (
-            V, H, cs, sn, g,
-            jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
-            ~start_ok, jnp.int32(0),
-        )
-        V, H, cs, sn, g, kk, bd, _done, _j = jax.lax.while_loop(
-            cond, body, st
-        )
-        # per-lane masked triangular solve: columns past each lane's kk
-        # get a unit diagonal and a zero rhs
-        idx = jnp.arange(restart)
-        mk = (idx[None, :] < kk[:, None]).astype(rdt)
-        Hs = H[:, :restart, :restart] * (mk[:, :, None] * mk[:, None, :])
-        Hs = Hs + jnp.einsum(
-            "bi,ij->bij", (1.0 - mk), jnp.eye(restart, dtype=rdt)
-        ).astype(dt)
-        gv = g[:, :restart] * mk
-        y = jax.vmap(
-            lambda h, rhs: jax.scipy.linalg.solve_triangular(
-                h, rhs, lower=False
-            )
-        )(Hs, gv)
-        X = X + jnp.einsum("bi,bin->bn", y, V[:, :restart])
-        info = jnp.stack(
-            [kk.astype(rdt), beta.astype(rdt), bd.astype(rdt)], axis=-1
-        )
-        return X, info
+    st = (V, H, cs, sn, g, jnp.zeros((B,), jnp.int32),
+          jnp.zeros((B,), bool), ~start_ok, jnp.int32(0))
+    V, H, _cs, _sn, g, kk, bd, _done, _j = jax.lax.while_loop(cond, body, st)
+    return V, H, g, kk, bd
 
-    return cycle
+
+def _gmres_cycle_lanes(mv, Mv, X, b, target, restart: int):
+    """One restart cycle of ``linalg._gmres_cycle`` for B lanes: the lanes'
+    residuals, the Arnoldi process from them (:func:`_gmres_arnoldi_lanes`),
+    each lane's small triangular solve, ``X += y V``. ``(X', kk, beta,
+    breakdown)``, each but ``X'`` a ``(B,)`` vector: the steps that gave a
+    column, the entry residual norm, whether a step broke down; ``kk == 0``
+    with no breakdown is a lane at its target on entry, whose ``X`` comes
+    back as it went in. The scopes ``bucket.gmres.spmv``, ``.orth``,
+    ``.small`` and ``.update`` name the work as the library's ``gmres.*``
+    name its own."""
+    from ..linalg import _basis_combine, _basis_flat, _hessenberg_solve
+
+    with jax.named_scope("bucket.gmres.spmv"):
+        AX = mv(X)
+    with jax.named_scope("bucket.gmres.update"):
+        R = b - AX
+    with jax.named_scope("bucket.gmres.spmv"):
+        R = Mv(R)
+    with jax.named_scope("bucket.gmres.update"):
+        beta = jnp.linalg.norm(R, axis=-1)
+    V, H, g, kk, bd = _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart)
+    with jax.named_scope("bucket.gmres.small"):
+        y = jax.vmap(_hessenberg_solve)(H, g, kk)
+    with jax.named_scope("bucket.gmres.update"):
+        X = X + _basis_flat(_basis_combine(y, V[:, :restart]), X.shape[-1])
+    return X, kk, beta, bd
+
+
+def _gmres_loop(matvec, b, X0, target, cycles, restart: int, Mvec=None):
+    """Masked batched restarted GMRES, the whole solve (pure jnp, jit-safe):
+    a ``lax.while_loop`` over the restart cycles of
+    :func:`_gmres_cycle_lanes`, as ``linalg._gmres`` has them over its own.
+    ``target [B]`` are the lanes' absolute residual targets and ``cycles``
+    bounds the passes (a traced or a Python integer). A lane is finished
+    when a cycle finds its (preconditioned) residual at its target ON ENTRY,
+    the library's rule: a lane whose recurrence says converged mid-cycle is
+    checked against its true residual by the next pass, and goes on if that
+    disagrees. A finished lane freezes: its ``X`` passes through every later
+    cycle unchanged (its ``y`` is zero), its counts stop. The loop ends when
+    every lane is finished or the passes are spent.
+
+    Returns ``(X, iters, resid2, converged, cycles_run)``: per lane the
+    Arnoldi steps counted as the library counts them (a breakdown's stage
+    included), the square of the last entry residual norm read while the
+    lane was not finished, whether it finished; and the passes in which some
+    lane made a step."""
+    Mv = (lambda r: r) if Mvec is None else Mvec
+    B = b.shape[0]
+    tap = _make_lanes_tap("gmres")
+
+    def cond(st):
+        done, c = st[3], st[4]
+        return (c < cycles) & jnp.any(~done)
+
+    def body(st):
+        X, iters, beta_last, done, c, worked = st
+        X, kk, beta, bd = _gmres_cycle_lanes(matvec, Mv, X, b, target,
+                                             restart)
+        if tap is not None:
+            # cycle granularity: the entry residuals, squared to the health
+            # monitor's resid2 convention
+            jax.debug.callback(tap, c + 1, beta * beta, target * target)
+        steps = jnp.where(done, 0, kk + bd.astype(jnp.int32))
+        beta_last = jnp.where(done, beta_last, beta)
+        done = done | ((kk == 0) & ~bd)
+        worked = worked + jnp.any(steps > 0).astype(jnp.int32)
+        return X, iters + steps, beta_last, done, c + 1, worked
+
+    zero = jnp.zeros((), jnp.int32)
+    st = (X0, jnp.zeros((B,), jnp.int32), jnp.zeros_like(target),
+          jnp.zeros((B,), bool), zero, zero)
+    X, iters, beta_last, done, _c, worked = jax.lax.while_loop(cond, body, st)
+    return X, iters, beta_last * beta_last, done, worked
 
 
 def batched_gmres(A, b, x0=None, tol=1e-08, restart=None, maxiter=None,
                   M=None, atol=None):
-    """Batched restarted GMRES: compiled batched Arnoldi cycles, one host
-    sync per restart, per-lane masks at both granularities (mid-cycle
-    freezing on device, converged lanes skipped across restarts on host).
+    """Batched restarted GMRES: the whole solve one masked loop
+    (:func:`_gmres_loop`: the restarts inside it, no host round trip a
+    cycle), per-lane masks at both granularities (a lane freezes mid-cycle
+    when its recurrence converges or breaks down, and for good once a cycle
+    finds it at its target).
 
     Same stopping rule as :func:`sparse_tpu.linalg.gmres`: relative
-    ``tol * ||b||`` floored by ``atol``, per lane. Returns
-    ``(X, BatchedSolveInfo)``; ``info.iters`` counts inner iterations
-    (breakdown stages included) exactly like the unbatched driver.
+    ``tol * ||b||`` floored by ``atol``, per lane; ``maxiter`` counts
+    restart cycles. Returns ``(X, BatchedSolveInfo)``; ``info.iters``
+    counts inner iterations (breakdown stages included) exactly like the
+    unbatched driver.
     """
     mv = _maybe_faulty_mv(as_batched_matvec(A))
     b = asjnp(b)
@@ -499,37 +532,11 @@ def batched_gmres(A, b, x0=None, tol=1e-08, restart=None, maxiter=None,
     bnorm = jnp.linalg.norm(b, axis=-1)
     tol_l = jnp.broadcast_to(jnp.asarray(tol, rdt), (B,))
     target = jnp.maximum(tol_l * bnorm, atol if atol is not None else 0.0)
-    target = jnp.maximum(target, 1e-30)
+    target = jnp.maximum(target, 1e-30).astype(rdt)
 
-    Mv = (lambda r: r) if M is None else as_batched_matvec(M)
-    cycle = _make_batched_gmres_cycle(mv, Mv, restart, jnp.dtype(dt))
-    iters = np.zeros((B,), dtype=np.int64)
-    lane_done = np.zeros((B,), dtype=bool)
-    beta_last = np.zeros((B,), dtype=np.float64)
-    tol2_h = np.asarray(target, dtype=np.float64) ** 2 if telemetry.enabled() else None
-    for _outer in range(int(maxiter)):
-        X, info = cycle(X, b, target)
-        info_h = np.asarray(info)  # ONE host sync per restart cycle
-        if tol2_h is not None:
-            # per-lane entry residuals the cycle already fetched, squared
-            # to the health monitor's resid2 convention — cycle granularity
-            telemetry.health.observe_lanes(
-                "gmres", _outer + 1, info_h[:, 1].astype(np.float64) ** 2,
-                tol2_h,
-            )
-        inner = info_h[:, 0].astype(np.int64)
-        beta_last = np.where(lane_done, beta_last, info_h[:, 1])
-        bdown = info_h[:, 2] > 0
-        newly_done = (inner == 0) & ~bdown
-        # breakdown stages did a matvec but contribute no column; count
-        # them like the unbatched driver so iters reflects work
-        iters += np.where(lane_done, 0, inner + bdown.astype(np.int64))
-        lane_done |= newly_done
-        if lane_done.all():
-            break
-    resid2 = jnp.asarray(beta_last.astype(np.dtype(rdt)) ** 2)
-    info = BatchedSolveInfo(
-        jnp.asarray(iters.astype(np.int32)), resid2, jnp.asarray(lane_done)
-    )
+    Mvec = None if M is None else as_batched_matvec(M)
+    X, iters, resid2, done, _cycles = _gmres_loop(
+        mv, b, X, target, int(maxiter), restart, Mvec)
+    info = BatchedSolveInfo(iters, resid2, done)
     _solve_event("gmres", info, n)
     return X, info

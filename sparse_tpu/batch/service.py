@@ -114,7 +114,7 @@ from ..resilience.policy import deadline_remaining_s
 from ..telemetry import _budget, _cost, _history, _metrics, _profiler
 from . import bucket as bucketing
 from . import krylov
-from .operator import BatchedCSR, SparsityPattern, pattern_matvec
+from .operator import SparsityPattern, as_batched_matvec, pattern_matvec
 
 _SOLVERS = ("cg", "bicgstab", "gmres")
 
@@ -124,6 +124,11 @@ _SOLVERS = ("cg", "bicgstab", "gmres")
 _QUEUE_DEPTH = _metrics.gauge("batch.queue_depth")
 _BUCKET_OCCUPANCY = _metrics.histogram("batch.bucket_occupancy")
 _DISPATCHES = _metrics.counter("batch.dispatches")
+_GMRES_TRACES = _metrics.counter(
+    "batch.gmres.traces",
+    help="traces of a session's GMRES bucket program (bucket_gmres): one "
+    "per program built, none for a dispatch that reuses one",
+)
 _PAD_WASTE = _metrics.counter("batch.pad_lanes")
 # resilience levels
 _REQUEUES = _metrics.counter("batch.requeues")
@@ -574,13 +579,13 @@ class _InFlight:
                  "bkt", "nb", "out", "built", "snap", "seq", "t0",
                  "t_packed", "t_solve0", "t_dispatched", "sampled",
                  "policy", "auto", "staged", "matvec", "row_gathers",
-                 "pad_rows", "period", "_ready")
+                 "pad_rows", "event_fields", "period", "_ready")
 
     def __init__(self, reqs, dt, solver, allow_requeue, plan, key, bkt,
                  nb, out, built, snap, seq, t0, t_packed, t_solve0,
                  t_dispatched, sampled, policy=mixed_mod.EXACT, auto=None,
                  staged="host", matvec="sell", row_gathers=None,
-                 pad_rows=None, period=None):
+                 pad_rows=None, event_fields=None, period=None):
         self.reqs, self.dt, self.solver = reqs, dt, solver
         self.allow_requeue, self.plan, self.key = allow_requeue, plan, key
         self.bkt, self.nb, self.out = bkt, nb, out
@@ -609,6 +614,10 @@ class _InFlight:
         # zero rows the program's SELL pack (and the space built on it)
         # added, as its builder tagged it; None for a program without
         self.pad_rows = pad_rows
+        # (real lanes, iters, the program's fifth output) -> the event's
+        # fields of the program's own, as its builder tagged it (the GMRES
+        # bucket program's); None for a program without
+        self.event_fields = event_fields
         # the period that ended at this launch, as `batch.dispatch`
         # fields (`_Period.close`); empty with telemetry off and for a
         # session's first launch
@@ -617,8 +626,8 @@ class _InFlight:
 
     def is_ready(self) -> bool:
         """Non-blocking: True when every device output has
-        materialized (host-returning programs — gmres/row — are ready
-        by construction). Latches once True — readiness never
+        materialized (a host-returning program — the row strategy's — is
+        ready by construction). Latches once True — readiness never
         regresses, so repeat polls are one attribute read."""
         if self._ready:
             return True
@@ -2056,7 +2065,8 @@ class SolveSession:
             sampled, policy=pol, auto=auto, staged=staged,
             matvec=getattr(prog, "matvec", "sell"),
             row_gathers=getattr(prog, "row_gathers", None),
-            pad_rows=getattr(prog, "pad_rows", None), period=period,
+            pad_rows=getattr(prog, "pad_rows", None),
+            event_fields=getattr(prog, "event_fields", None), period=period,
         )
 
     def _degrade(self, reqs, dt, solver, nb, e) -> None:
@@ -2112,14 +2122,13 @@ class SolveSession:
                     pass  # non-jax leaves (ints): np.asarray blocks below
             t_solved = _at(sp_wait.t1)
             with telemetry.span("session.readback", **tag) as sp:
-                # IR bucket programs (ISSUE 15) return a 5th output: the
+                # a 5th output is the program's own count: the GMRES
+                # bucket's working cycles (its builder tags `event_fields`
+                # to read it), else an IR bucket program's (ISSUE 15)
                 # shared refinement-sweep count
-                if len(fl.out) == 5:
-                    X, iters, resid2, conv, ir_outer = fl.out
-                    ir_outer = int(np.asarray(ir_outer))
-                else:
-                    X, iters, resid2, conv = fl.out
-                    ir_outer = None
+                X, iters, resid2, conv, *own = fl.out
+                own = int(np.asarray(own[0])) if own else None
+                ir_outer = own if fl.event_fields is None else None
                 X = np.asarray(X)
                 iters = np.asarray(iters)
                 resid2 = np.asarray(resid2)
@@ -2131,15 +2140,16 @@ class SolveSession:
         fl.out = None  # release device buffers promptly
         with telemetry.span("session.scatter", **tag) as sp_scatter:
             self._scatter(fl, X, iters, resid2, conv, ir_outer,
-                          t_solved, t_read)
+                          t_solved, t_read, own)
         self._period.spans(sp_wait, sp, sp_scatter)
 
     def _scatter(self, fl: _InFlight, X, iters, resid2, conv, ir_outer,
-                 t_solved, t_read) -> None:
+                 t_solved, t_read, own=None) -> None:
         """Results to tickets, requeue decisions, accounting, events and
         finalisation of one retired bucket. ``t_solved`` is where
         ``session.device_wait`` ended, ``t_read`` where
-        ``session.readback`` did."""
+        ``session.readback`` did; ``own`` is the program's fifth output,
+        for the builder's ``event_fields``."""
         reqs, dt, solver, plan = fl.reqs, fl.dt, fl.solver, fl.plan
         nb, bkt, key = fl.nb, fl.bkt, fl.key
         if ir_outer is not None:
@@ -2310,6 +2320,8 @@ class SolveSession:
                    if fl.row_gathers is not None else {}),
                 **({"pad_rows": fl.pad_rows}
                    if fl.pad_rows is not None else {}),
+                **(fl.event_fields(nb, iters, own)
+                   if fl.event_fields is not None else {}),
                 inflight=len(self._inflight), staged=fl.staged,
                 # the period that ended at this dispatch's launch and its
                 # four parts (`_Period`); a session's first has none
@@ -2638,7 +2650,7 @@ class SolveSession:
         ``plan`` routes the fleet strategies (ISSUE 10): 'batch' wraps
         the SAME loop cores in a ``shard_map`` over the mesh batch axis
         with the psum all-converged exit (gmres shards its inputs and
-        lets GSPMD partition the host-driven cycle), 'row' wraps
+        lets GSPMD partition its compiled whole solve), 'row' wraps
         ``DistCSR``/``dist_cg`` in a B=1 bucket signature. 'single' (or
         ``None``) is byte-identical to the classic path.
 
@@ -2778,39 +2790,77 @@ class SolveSession:
 
     def _build_gmres_program(self, pattern, bkt, dt,
                              precond: str = precond_mod.NONE):
-        """GMRES keeps its host-driven outer restart loop, so the bucket
-        'program' is a closure dispatching :func:`krylov.batched_gmres`
-        over a pattern-packed operator — restart cycles still compile
-        once per bucket (the jitted cycle is rebuilt per dispatch; the
-        XLA executable comes from jax's compile cache). ``precond``
-        resolves to a left preconditioner of the batched cycle."""
-        restart = self.restart
+        """The GMRES bucket program, of the kind ``cg`` and ``bicgstab``
+        have: ONE ``jax.jit`` a (pattern, bucket, dtype, restart) whose
+        arguments are the value stack, rhs, x0, tolerances and maxiter,
+        built once and found again in the plan cache. The whole solve runs
+        inside it (``krylov._gmres_loop``: the restarts a ``lax.while_loop``
+        over the library's Arnoldi cycle with a lane axis in front), so the
+        call returns device arrays at once, ``inflight`` pipelines it as it
+        does CG, and a bucket costs one host fetch, at its retire.
 
-        restart_eff = restart or min(20, pattern.shape[0])
-        # the closure's BatchedCSR multiplies through the pattern's SELL
-        # pack: built (or loaded from the vault) here, with the program,
-        # as the other builders build theirs
-        pattern.sell_pack()
+        ``maxiter`` bounds a lane's inner steps, rounded up to whole cycles
+        (at least one); ``iters`` counts inner steps, a breakdown's stage
+        included; the tolerances are the lanes' absolute targets.
+
+        The product is chosen from the pattern as ``_build_program``
+        chooses it (``operator.pattern_matvec``): planes for a pattern the
+        banded rule lays out so, else the SELL gathers, in the caller's row
+        order either way, the order a left preconditioner (``precond``) is
+        built in. ``run.matvec`` names the form. It returns a fifth output,
+        the passes in which some lane made a step, and ``run.event_fields``
+        makes the bucket's own ``batch.dispatch`` fields from it."""
+        n = pattern.shape[0]
+        restart = min(int(self.restart or min(20, n)), n)
         mfac = (
             None if precond == precond_mod.NONE
             else self.precond.factory(pattern, precond)
         )
+        pack, product = pattern_matvec(pattern)
 
-        def run(values, rhs, x0, tols, maxiter):
-            op = BatchedCSR(pattern, values)
-            M = (
+        @partial(jax.jit, donate_argnums=donate_argnums())
+        def bucket_gmres(values, rhs, x0, tols, maxiter):
+            _GMRES_TRACES.inc()
+            vals = pack.pack_values(values)
+            fmv = krylov._maybe_faulty_mv(partial(product, vals))
+            # batched numeric factorization from THIS dispatch's value
+            # stack, as the other programs make theirs
+            Mvec = (
                 None if mfac is None
-                else mfac(jnp.asarray(values), op.matvec)
+                else as_batched_matvec(mfac(values, fmv))
             )
-            # batched_gmres takes a scalar-or-(B,) relative tol; the
-            # session's per-lane ABSOLUTE targets ride the atol floor.
-            # Its maxiter counts OUTER restarts; bound inner work by the
-            # session's maxiter contract.
-            outer = max(-(-int(maxiter) // restart_eff), 1)
-            X, info = krylov.batched_gmres(
-                op, rhs, x0=x0, tol=0.0, atol=tols, restart=restart_eff,
-                maxiter=outer, M=M,
+            rdt = jnp.zeros((), rhs.dtype).real.dtype
+            target = jnp.maximum(tols.astype(rdt), 1e-30)
+            cycles = jnp.maximum(-(-jnp.asarray(maxiter) // restart), 1)
+            return krylov._gmres_loop(
+                fmv, rhs, x0, target, cycles, restart, Mvec
             )
-            return X, info.iters, info.resid2, info.converged
 
-        return run
+        bucket_gmres.matvec = pack.form
+        bucket_gmres.pad_rows = pack.pad_rows
+        # a lane's Krylov basis a row to a tile (`linalg._basis_tiles`)
+        basis_gb = (
+            bkt * (restart + 1) * 1024 * -(-n // 1024)
+            * np.dtype(dt).itemsize / 1e9
+        )
+
+        def event_fields(nb, iters, cycles):
+            """The GMRES bucket's own ``batch.dispatch`` fields, from the
+            real lanes' step counts and the program's fifth output.
+            ``frozen_lane_pct``: the share of lane-steps a lane that was
+            done spent waiting for its bucket's last (ROADMAP M6's cost);
+            ``fetches``: the host reads a bucket's solve waits on, the
+            retire's readback alone (the program makes none)."""
+            its = np.asarray(iters[:nb], dtype=np.int64)
+            top, total = int(its.max(initial=0)), int(its.sum())
+            return {
+                "restart": restart, "iters_sum": total,
+                "frozen_lane_pct": round(
+                    100.0 * (1.0 - total / (nb * top)), 3
+                ) if nb and top else 0.0,
+                "cycles_max": int(cycles), "fetches": 1,
+                "basis_gb": basis_gb,
+            }
+
+        bucket_gmres.event_fields = event_fields
+        return bucket_gmres

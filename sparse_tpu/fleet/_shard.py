@@ -20,13 +20,12 @@ and reconciles against :func:`batch_comm_model_bytes` in a
 measurement one per while-condition evaluation (iterations + 1) — the
 same small-positive expected divergence convention as ``dist.cg``.
 
-GMRES keeps its host-driven restart loop (one host sync per cycle), so
-its fleet form shards the *data* instead of the program: inputs are
+GMRES's fleet form shards the *data* instead of the program: inputs are
 ``device_put`` onto the mesh batch axis and GSPMD partitions the
-batched Arnoldi cycle (lanes independent ⇒ no resharding; the cycle's
-``jnp.any(~done)`` becomes the inserted all-reduce). Its collective
-traffic is GSPMD-inserted and thus model-only — the documented wrapper
-blind spot (docs/telemetry.md).
+session's compiled GMRES bucket program, the whole solve (lanes
+independent ⇒ no resharding; the two loops' ``jnp.any(~done)`` become
+the inserted all-reduces). Its collective traffic is GSPMD-inserted and
+thus model-only — the documented wrapper blind spot (docs/telemetry.md).
 """
 
 from __future__ import annotations
@@ -84,8 +83,9 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
     mesh size (``bucket.bucket_batch(..., multiple_of=S)``).
 
     cg/bicgstab run under ``shard_map`` with the global psum exit;
-    gmres wraps ``gmres_inner`` (the session's host-driven closure) with
-    input sharding and lets GSPMD partition the cycle.
+    gmres wraps ``gmres_inner`` (the session's compiled GMRES bucket
+    program, ``SolveSession._build_gmres_program``) with input sharding and
+    lets GSPMD partition the whole solve.
 
     ``m_factory`` is the resolved preconditioner's numeric factory
     (ISSUE 14, :mod:`sparse_tpu.precond`): its pattern-level maps are
@@ -119,9 +119,16 @@ def build_batch_program(pattern, bkt: int, dt, solver: str, mesh,
             raise ValueError("gmres strategy needs the inner closure")
 
         def run_gmres(values, rhs, x0, tols, maxiter):
+            """The session's GMRES bucket program over inputs sharded on
+            the mesh batch axis: one compiled whole solve, partitioned by
+            GSPMD, that returns device arrays at once (nothing here waits
+            for the solve)."""
             values, rhs, x0, tols = shard_inputs(mesh, values, rhs, x0, tols)
             return gmres_inner(values, rhs, x0, tols, maxiter)
 
+        # as the single-device program tags it
+        for tag in ("matvec", "pad_rows", "event_fields"):
+            setattr(run_gmres, tag, getattr(gmres_inner, tag))
         return run_gmres
 
     from ..parallel.mesh import mesh_fingerprint

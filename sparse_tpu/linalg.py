@@ -1733,8 +1733,6 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
     (scalars: the norm's root, the Givens rotations, the Hessenberg column,
     the triangular solve) and ``gmres.update`` (the basis row's write with
     the division by the norm inside it, x += V y, the cycle's residual)."""
-    dt = b.dtype
-    rdt = jnp.zeros((), dt).real.dtype
     with jax.named_scope("gmres.spmv"):
         ax = matvec(x)
     with jax.named_scope("gmres.update"):
@@ -1745,14 +1743,7 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
         beta = jnp.linalg.norm(r)
     V, H, g, k, bdown = _gmres_arnoldi(matvec, precond, r, beta, target, restart)
     with jax.named_scope("gmres.small"):
-        # masked triangular solve of H[:k, :k] y = g[:k] on device: columns
-        # past k are zeroed and given a unit diagonal, their rhs zeroed
-        idx = jnp.arange(restart)
-        mk = (idx < k).astype(rdt)
-        Hs = H[:restart, :restart] * (mk[:, None] * mk[None, :])
-        Hs = Hs + jnp.diag(1.0 - mk).astype(dt)
-        gv = g[:restart] * mk
-        y = jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
+        y = _hessenberg_solve(H, g, k)
     with jax.named_scope("gmres.update"):
         x = x + _basis_flat(_basis_combine(y, V[:restart]), x.shape[0])
     return x, k, beta, bdown
@@ -1777,11 +1768,13 @@ _BASIS_WRITE_ROWS = 1
 
 
 def _basis_tiles(v):
-    """The flat vector ``v`` as a basis row: padded with zeros to whole
-    ``(8, 128)`` tiles, ``[R, 128]``."""
+    """The flat vector ``v [..., n]`` as a basis row: padded with zeros to
+    whole ``(8, 128)`` tiles, ``[..., R, 128]``."""
     sub, lanes = _BASIS_TILE
-    rows = sub * -(-v.shape[0] // (sub * lanes))
-    return jnp.pad(v, (0, rows * lanes - v.shape[0])).reshape(rows, lanes)
+    n = v.shape[-1]
+    rows = sub * -(-n // (sub * lanes))
+    pad = [(0, 0)] * (v.ndim - 1) + [(0, rows * lanes - n)]
+    return jnp.pad(v, pad).reshape(*v.shape[:-1], rows, lanes)
 
 
 def _basis_flat(V, n: int):
@@ -1791,17 +1784,18 @@ def _basis_flat(V, n: int):
 
 
 def _basis_project(Vs, w):
-    """``V^H w``: the rows ``Vs [hi, R, 128]`` against ``w [R, 128]``, a
-    multiply and a sum in the basis' dtype (no ``dot``: nothing for a
-    matrix unit's lower-precision pass)."""
-    return jnp.sum(Vs.conj() * w, axis=(1, 2))
+    """``V^H w``: the rows ``Vs [..., hi, R, 128]`` against ``w [..., R,
+    128]`` (the leading axes lanes, each with its own basis), a multiply and
+    a sum in the basis' dtype (no ``dot``: nothing for a matrix unit's
+    lower-precision pass)."""
+    return jnp.sum(Vs.conj() * w[..., None, :, :], axis=(-2, -1))
 
 
 def _basis_combine(h, Vs):
-    """``h V``: the combination ``[R, 128]`` of the rows ``Vs [hi, R, 128]``
-    with the coefficients ``h [hi]``, as :func:`_basis_project` a multiply
-    and a sum."""
-    return jnp.sum(h[:, None, None] * Vs, axis=0)
+    """``h V``: the combination ``[..., R, 128]`` of the rows ``Vs [..., hi,
+    R, 128]`` with the coefficients ``h [..., hi]``, as
+    :func:`_basis_project` a multiply and a sum."""
+    return jnp.sum(h[..., None, None] * Vs, axis=-3)
 
 
 # The orthogonalisation reads the basis in stages of whole blocks of rows. A
@@ -1844,6 +1838,93 @@ def _gmres_orth_rows(restart: int, iters: int) -> float:
     return round((whole * sum(cycle) + sum(cycle[:last])) / iters, 3)
 
 
+def _orth_against(V, w, k, *, hi: int, restart: int):
+    """Step ``k``'s classical Gram-Schmidt and one re-orthogonalisation pass
+    of ``w [..., R, 128]`` against the rows ``V[..., :k + 1, :, :]``, as
+    masked contractions over one stage's rows ``V[..., :hi, :, :]`` (a
+    branch of the ``lax.switch`` of :func:`_gmres_arnoldi` and of the
+    session's lanes, ``batch.krylov._gmres_arnoldi_lanes``: the leading axes
+    are lanes, ``k`` is theirs in common). ``(h, w', ||w'||^2)``: the
+    coefficients padded to the basis' ``restart + 1`` rows, what is left of
+    ``w``, and its sum of squares."""
+    rdt = jnp.zeros((), w.dtype).real.dtype
+    Vs = V[..., :hi, :, :]
+    mask = (jnp.arange(hi) <= k).astype(rdt)
+    hcol = _basis_project(Vs, w) * mask
+    w = w - _basis_combine(hcol, Vs)
+    h2 = _basis_project(Vs, w) * mask
+    w = w - _basis_combine(h2, Vs)
+    # ||w||: jnp.linalg.norm's own sum, its root among the scalars
+    ww = jnp.sum(jnp.real(w * jnp.conj(w)), axis=(-2, -1))
+    pad = [(0, 0)] * (hcol.ndim - 1) + [(0, restart + 1 - hi)]
+    return jnp.pad(hcol + h2, pad), w, ww
+
+
+def _givens_column(hcol, hkk, H, cs, sn, g, k, target):
+    """The scalars of step ``k`` for ONE system (the session's lanes map it,
+    ``jax.vmap``): the new Hessenberg column ``hcol`` with ``hkk`` under it
+    through the ``k`` accumulated Givens rotations, the new rotation, the
+    rotated right-hand side. ``(H, cs, sn, g, breakdown, conv)`` with column
+    ``k`` of ``H`` and entry ``k`` of ``cs``/``sn`` written; ``g`` is left as
+    it was on a breakdown; ``conv`` is the recurrence's residual
+    ``|g[k + 1]|`` under ``target``."""
+    restart = cs.shape[0]
+    dt = H.dtype
+    # (a select, not a scatter: the TPU compiler then keeps the column in
+    # fast memory through the rotations' inner loop)
+    col = jnp.where(jnp.arange(restart + 1) == k + 1, hkk.astype(dt), hcol)
+
+    # apply the k accumulated Givens rotations (masked fori — [restart]^2
+    # scalars, exactly the lax.fori_loop case)
+    def giv(i, c):
+        t = cs[i] * c[i] + sn[i] * c[i + 1]
+        bt = -jnp.conj(sn[i]) * c[i] + cs[i] * c[i + 1]
+        app = i < k
+        c = c.at[i].set(jnp.where(app, t, c[i]))
+        return c.at[i + 1].set(jnp.where(app, bt, c[i + 1]))
+
+    col = jax.lax.fori_loop(0, restart, giv, col)
+    hk, hk1 = col[k], col[k + 1]
+    ahk = jnp.abs(hk)
+    ahk1 = jnp.abs(hk1)
+    denom = jnp.sqrt(ahk * ahk + ahk1 * ahk1)
+    breakdown = denom <= 0
+    denom_s = jnp.where(breakdown, 1.0, denom)
+    # new rotation: real c, possibly-complex s ([c, s; -conj(s), c])
+    ck = jnp.where(ahk == 0, 0.0, ahk / denom_s)
+    hk_unit = jnp.where(ahk == 0, 1.0, hk / jnp.where(ahk == 0, 1.0, ahk))
+    sk = jnp.where(
+        ahk == 0,
+        jnp.conj(hk1) / jnp.where(ahk1 == 0, 1.0, ahk1),
+        hk_unit * jnp.conj(hk1) / denom_s,
+    )
+    col = col.at[k].set(ck * hk + sk * hk1)
+    col = col.at[k + 1].set(0.0)
+    H = H.at[:, k].set(col)
+    cs = cs.at[k].set(ck.real)
+    sn = sn.at[k].set(sk)
+    gk1 = -jnp.conj(sk) * g[k]
+    g = g.at[k + 1].set(jnp.where(breakdown, g[k + 1], gk1))
+    g = g.at[k].set(jnp.where(breakdown, g[k], ck * g[k]))
+    return H, cs, sn, g, breakdown, jnp.abs(gk1) < target
+
+
+def _hessenberg_solve(H, g, k):
+    """``y [restart]`` of ONE system's cycle: the masked triangular solve of
+    ``H[:k, :k] y = g[:k]`` on the device; columns past ``k`` are zeroed and
+    given a unit diagonal, their right-hand side zeroed, so ``y`` is zero
+    there."""
+    restart = H.shape[1]
+    dt = H.dtype
+    rdt = jnp.zeros((), dt).real.dtype
+    idx = jnp.arange(restart)
+    mk = (idx < k).astype(rdt)
+    Hs = H[:restart, :restart] * (mk[:, None] * mk[None, :])
+    Hs = Hs + jnp.diag(1.0 - mk).astype(dt)
+    gv = g[:restart] * mk
+    return jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
+
+
 def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     """The Arnoldi process of a restart cycle from the (preconditioned)
     residual ``r`` of norm ``beta``: at most ``restart`` steps of classical
@@ -1882,21 +1963,9 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     sn = jnp.zeros((restart,), dtype=dt)
     g = jnp.zeros((restart + 1,), dtype=dt).at[0].set(beta.astype(dt))
 
-    def orth(V, w, k, *, hi):
-        # classical Gram-Schmidt and one re-orthogonalisation pass against
-        # the rows V[:k+1], as masked contractions over the stage's rows
-        Vs = V[:hi]
-        mask = (jnp.arange(hi) <= k).astype(rdt)
-        hcol = _basis_project(Vs, w) * mask
-        w = w - _basis_combine(hcol, Vs)
-        h2 = _basis_project(Vs, w) * mask
-        w = w - _basis_combine(h2, Vs)
-        # ||w||: jnp.linalg.norm's own sum, its root among the scalars
-        ww = jnp.sum(jnp.real(w * jnp.conj(w)))
-        return jnp.pad(hcol + h2, (0, restart + 1 - hi)), w, ww
-
     block, his = _orth_stages(restart)
-    stages = [functools.partial(orth, hi=hi) for hi in his]
+    stages = [functools.partial(_orth_against, hi=hi, restart=restart)
+              for hi in his]
 
     def cond(st):
         _V, _H, _cs, _sn, _g, k, done, _bd = st
@@ -1919,44 +1988,8 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
                 V, jnp.where(grew, w / jnp.where(grew, hkk, 1.0), 0.0
                              ).astype(dt), k + 1, 0)
         with jax.named_scope("gmres.small"):
-            # (a select, not a scatter: the TPU compiler then keeps the
-            # column in fast memory through the rotations' inner loop)
-            col = jnp.where(jnp.arange(restart + 1) == k + 1, hkk.astype(dt),
-                            hcol)
-
-            # apply the k accumulated Givens rotations (masked fori —
-            # [restart]^2 scalars, exactly the lax.fori_loop case)
-            def giv(i, c):
-                t = cs[i] * c[i] + sn[i] * c[i + 1]
-                bt = -jnp.conj(sn[i]) * c[i] + cs[i] * c[i + 1]
-                app = i < k
-                c = c.at[i].set(jnp.where(app, t, c[i]))
-                return c.at[i + 1].set(jnp.where(app, bt, c[i + 1]))
-
-            col = jax.lax.fori_loop(0, restart, giv, col)
-            hk, hk1 = col[k], col[k + 1]
-            ahk = jnp.abs(hk)
-            ahk1 = jnp.abs(hk1)
-            denom = jnp.sqrt(ahk * ahk + ahk1 * ahk1)
-            breakdown = denom <= 0
-            denom_s = jnp.where(breakdown, 1.0, denom)
-            # new rotation: real c, possibly-complex s ([c, s; -conj(s), c])
-            ck = jnp.where(ahk == 0, 0.0, ahk / denom_s)
-            hk_unit = jnp.where(ahk == 0, 1.0, hk / jnp.where(ahk == 0, 1.0, ahk))
-            sk = jnp.where(
-                ahk == 0,
-                jnp.conj(hk1) / jnp.where(ahk1 == 0, 1.0, ahk1),
-                hk_unit * jnp.conj(hk1) / denom_s,
-            )
-            col = col.at[k].set(ck * hk + sk * hk1)
-            col = col.at[k + 1].set(0.0)
-            H = H.at[:, k].set(col)
-            cs = cs.at[k].set(ck.real)
-            sn = sn.at[k].set(sk)
-            gk1 = -jnp.conj(sk) * g[k]
-            g = g.at[k + 1].set(jnp.where(breakdown, g[k + 1], gk1))
-            g = g.at[k].set(jnp.where(breakdown, g[k], ck * g[k]))
-            conv = jnp.abs(gk1) < target
+            H, cs, sn, g, breakdown, conv = _givens_column(
+                hcol, hkk, H, cs, sn, g, k, target)
             k_next = jnp.where(breakdown, k, k + 1)
             return (
                 V, H, cs, sn, g, k_next, done | breakdown | conv,

@@ -221,7 +221,8 @@ def test_selection_agrees_with_few_diagonals(name):
 
 
 @pytest.mark.parametrize("solver, kw, strategy, form", [
-    ("gmres", {}, "single", "sell"),
+    # the GMRES bucket program asks the pattern as cg's and bicgstab's do
+    ("gmres", {}, "single", "planes"),
     ("cg", {"dtype_policy": "f32ir"}, "single", "sell"),
     ("bicgstab", {"dtype_policy": "bf16ir"}, "single", "sell"),
     ("cg", {"fleet": "row", "row_shard_min_n": 8}, "row", "sell"),
@@ -718,15 +719,19 @@ def test_dispatch_events_count_the_pad_rows(monkeypatch, name):
         if name == "pad_rows":
             (ev,) = _dispatch_events(monkeypatch, "cg", grid5())
             assert ev["matvec"] == "planes" and "pad_rows" not in ev
+            # the GMRES bucket program multiplies through the pattern's
+            # pack too, in the caller's order
             evs = _dispatch_events(monkeypatch, "gmres", A)
-            assert all("pad_rows" not in e for e in evs)
+            assert evs and all(e["matvec"] == "sell" and e["pad_rows"]
+                               == pack.plan.pad_rows for e in evs)
     finally:
         telemetry.reset()
 
 
 def test_programs_of_other_builders_report_no_row_gathers(monkeypatch):
-    """GMRES and the refinement programs multiply through `pos` as they
-    did; their builders tag no count and the event leaves the field out."""
+    """GMRES (in the caller's row order, a left preconditioner's) and the
+    refinement programs multiply through `pos`; their builders tag no count
+    and the event leaves the field out."""
     try:
         for solver, kw in (("gmres", {}), ("cg", {"dtype_policy": "f32ir"})):
             evs = _dispatch_events(monkeypatch, solver, skewed(), **kw)

@@ -904,6 +904,105 @@ GMG_GRID, GMG_LEVELS = 4480, 3
 GMG_COMPILE_SECONDS = 30.0  # 2.7 s here alone; the parent's form 6 s
 
 
+# ---------------------------------------------------------------------------
+# the session's GMRES bucket program (PR 49): the library's pins, with a
+# lane axis in front
+# ---------------------------------------------------------------------------
+BUCKET_GMRES_BOX = (40, 32, 24)  # 30,720 rows: 30 tile rows of 1024
+BUCKET_GMRES_LANES = 4
+BUCKET_GMRES_SCOPES = tuple("bucket." + s for s in GMRES_SCOPES)
+
+
+def _bucket_gmres_compiled(one_chip, monkeypatch, restart=30):
+    from sparse_tpu.batch import service
+
+    from .utils.spd import operator_module
+
+    monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    d = operator_module("cfd_7pt").make(
+        {"box": BUCKET_GMRES_BOX, "restart": restart, "cycles": 1}, 1)
+    n, B = d["rows"], BUCKET_GMRES_LANES
+    P = sp.csr_matrix((d["data"], d["indices"], d["indptr"]), shape=(n, n))
+    ses = service.SolveSession("gmres", restart=restart, batch_max=B,
+                               warm_start=False)
+    pattern = ses.pattern_of(P)
+    run = ses._build_program(pattern, B, np.dtype(np.float32))
+    assert run.matvec == "planes"
+    return n, run.lower(
+        _sds((B, pattern.nnz), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B, n), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile()
+
+
+def test_bucket_gmres_program_writes_one_row_a_lane_and_copies_no_basis(
+        one_chip, monkeypatch):
+    n, c = _bucket_gmres_compiled(one_chip, monkeypatch)
+    text = c.as_text()
+    B, rows = BUCKET_GMRES_LANES, 8 * -(-n // 1024)
+    assert "jit_bucket_gmres" in text and _device_bytes(c) < HBM_BYTES
+    # every lane's basis a row to a tile, the lane axis in front
+    basis = f"f32[{B},31,{rows},128]"
+    assert basis + "{3,2,1,0:T(8,128)}" in text
+    assert f"f32[{B},31,{n}]" not in text  # the einsum form's layout
+    ma = c.memory_analysis()
+    assert B * 31 * rows * 128 * 4 < ma.temp_size_in_bytes
+    computations = _computations(text)
+    (body,) = [comp for comp in computations.values() if re.search(
+        r" conditional\([^\n]*bucket\.gmres\.orth/", comp)]
+    # the step's write: a dynamic-update-slice of one [B, 1, R, 128] row a
+    # lane into the basis, in place (its own operand), the division by the
+    # norm in the same fusion or the one that feeds it
+    writes = [ln for comp in computations.values() for ln in comp.splitlines()
+              if re.search(r"%s\S* dynamic-update-slice\(" % re.escape(basis),
+                           ln)]
+    assert len(writes) == 1 and "bucket.gmres.update/" in writes[0]
+    (update,) = re.findall(r"dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+),",
+                           writes[0])
+    (row,) = [ln for comp in computations.values() for ln in comp.splitlines()
+              if re.match(r"\s*%%%s = " % re.escape(update), ln)]
+    assert f"f32[{B},1,{rows},128]" in row
+    # the row's read for the product: one row a lane
+    assert "dynamic_slice_sizes={%d,1,%d,128}" % (B, rows) in text
+    # one conditional, eight stages, each with the static slice inside its
+    # contractions: no copy of the basis is planned anywhere
+    (branch_names,) = re.findall(
+        r" conditional\([^\n]*branch_computations=\{([^}]*)\}", body)
+    assert len(branch_names.split(",")) == 8
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"\bcopy(-start)?\(", ln) and basis in ln]
+    # float32 on the vector unit: nothing for the MXU's bfloat16 pass
+    assert "convolution" not in text and "bf16" not in text
+    assert not re.search(r"\bdot\(", text)
+    # the restarts are inside: a while over cycles around the Arnoldi while
+    assert "while/body/while/body/bucket.gmres.orth" in text
+    # nothing of a dispatch's values is folded into the program
+    for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\][^=\n]*\bconstant\(", text):
+        assert int(np.prod([int(d) for d in dims.split(",") if d] or [1],
+                           dtype=np.int64)) <= 7 * n, dims
+
+
+def test_bucket_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
+    """What ``benchmark/reducers/op_scope_share.py`` reads the served cell's
+    shares from: the four scopes are in the program's text, and every fusion
+    of the Arnoldi loop's body that carries an ``op_name`` stands under one
+    of them."""
+    _n, c = _bucket_gmres_compiled(one_chip, monkeypatch)
+    text = c.as_text()
+    for scope in BUCKET_GMRES_SCOPES:
+        assert f"/{scope}/" in text, scope
+    computations = _computations(text)
+    (body,) = [comp for comp in computations.values() if re.search(
+        r" conditional\([^\n]*bucket\.gmres\.orth/", comp)]
+    named = [rest for _name, _res, rest in _fusions(body)
+             if 'op_name="' in rest]
+    assert named
+    for rest in named:
+        assert sum(f"/{s}/" in rest for s in BUCKET_GMRES_SCOPES) == 1, rest
+
+
 def _gmg_pcg_compiled(one_chip, monkeypatch, fine_kernel: bool):
     import time
 
