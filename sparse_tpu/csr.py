@@ -38,9 +38,19 @@ from .utils import (
 # segment form. ``ell?`` and ``sell?`` are the two sides of the one ELL-ratio
 # gate (``csr_array._tight``): padded rows for a tight row profile, SELL
 # slabs for a skewed one; bare ``ell``/``sell`` are built whatever the
-# profile. ``dia`` needs a banded matrix (``dia.few_diagonals``); ``dia+`` is
-# the packed Pallas kernel on its planes, which declines a band too wide for
-# VMEM. ``well`` is the windowed step-major layout, whose product is a
+# profile. ``dia`` needs a banded matrix (``dia.few_diagonals``). How it
+# multiplies follows from what the matrix and the platform show
+# (``csr_array._dia_operands``, the rule beside ``_DIA_VMEM_BYTES``): on a TPU
+# a square float32 matrix whose window fits VMEM multiplies through the
+# windowed Pallas kernel ``kernels.dia_spmv.dia_spmv_rows`` on row-indexed
+# planes packed once with the layout (every plane, x and y cross HBM once);
+# everywhere else through ``ops.dia_spmv.dia_spmv_xla`` on the scipy-layout
+# planes. The layout's name and ``meta`` are the same in both. ``dia+`` is
+# the user's own route to the packed kernel (``PreparedDia``: an autotuned
+# tile, the vault's copy, the failover registry; a band past
+# ``settings.pallas_max_band`` declines), for an eager product only: a
+# compiled whole solve takes ``dia`` by its rule in its place. ``well`` is
+# the windowed step-major layout, whose product is a
 # Pallas kernel that gathers from x held in VMEM (kernels/well_spmv.py): the
 # matrix reordered to a band once, its rows tiled, each tile's columns local
 # to a short window of x and its entries stored a vreg to a step and depth.
@@ -48,8 +58,8 @@ from .utils import (
 # (a TPU, float32, square with a symmetric pattern, a tight row profile,
 # enough rows, x fitting VMEM, and windows that stayed narrow after the
 # reordering) and everywhere else the walk goes on to the layouts below it
-# as if it were not in the table. So ``pallas`` accelerates a banded
-# matrix's vector product and nothing else.
+# as if it were not in the table. So ``pallas`` changes a banded matrix's
+# eager vector product and nothing else.
 # docs/performance.md shows the table by row profile;
 # tests/test_matvec_choice.py pins it.
 _LAYOUTS = {
@@ -97,6 +107,21 @@ _WELL_MAX_UNITS_A_SLOT = 0.33
 _WELL_MAX_UNITS = 1 << 17
 
 
+# The rule of ``dia``'s kernel (``csr_array._dia_operands``): what
+# ``dia_spmv_rows`` holds in VMEM, 2 D TM plane elements, two x windows of
+# TM + 2 B and two blocks of y, 4 bytes each (at D = 7, TM = 64,512,
+# B = 22,528: 5.0 MB), has to stay under this, half of the 16 MiB Mosaic
+# plans within: the other half is the kernel body's own TM-long temporaries.
+# The row tile follows from it (``dia_rows_plan``), nothing is probed.
+_DIA_VMEM_BYTES = 8 << 20
+
+
+def _dia_platform() -> bool:
+    """The kernel's platform: a TPU, and x64 off (with it on, Mosaic's
+    lowering of the kernel's ``program_id % 2`` does not end)."""
+    return jax.default_backend() == "tpu" and not jax.config.jax_enable_x64
+
+
 def _well_platform() -> bool:
     """The kernel's platform: Mosaic's lane gather exists on a TPU only."""
     return jax.default_backend() == "tpu"
@@ -138,6 +163,8 @@ def form_matvec(kind: str, meta, arrays, x):
         enter, matvec, leave = form_space(kind, meta, arrays)
         return leave(matvec(enter(x)))
     if kind == "dia":
+        if form_kernels(kind, arrays):  # the packed planes: the kernel
+            return arrays.matvec(x, interpret=jax.default_backend() != "tpu")
         from .ops.dia_spmv import dia_spmv_xla
 
         return dia_spmv_xla(arrays, meta[0], x, meta[1])
@@ -150,6 +177,15 @@ def form_matvec(kind: str, meta, arrays, x):
         # whose reordering leaves every row tile a short window of x
         return spmv_ops.csr_spmv_ell(*arrays, x)
     return spmv_ops.csr_spmv_segment(*arrays, x, meta)
+
+
+def form_kernels(kind: str, arrays) -> int:
+    """Pallas kernels in one product through a layout, by what its arrays
+    show: the windowed gather of ``well``, the windowed planes of a ``dia``
+    that holds its packed rows (``DiaRows``); 0 for every XLA form."""
+    from .kernels.dia_spmv import DiaRows
+
+    return int(kind == "well" or (kind == "dia" and type(arrays) is DiaRows))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -200,6 +236,7 @@ class csr_array(SparseArray):
         self._dtype = np.dtype(self.data.dtype)
         self._ell = None  # lazy (ell_indices, ell_data) cache
         self._dia = False  # False = unchecked, None = not banded, else planes
+        self._dia_rows = None  # (planes packed from, DiaRows | None), lazy
         self._well = False  # False = unchecked, None = not offered, else WellLayout
         self._balanced_splits = None
 
@@ -213,6 +250,7 @@ class csr_array(SparseArray):
         obj._dtype = np.dtype(obj.data.dtype)
         obj._ell = None
         obj._dia = False
+        obj._dia_rows = None
         obj._well = False
         obj._balanced_splits = None
         return obj
@@ -420,6 +458,31 @@ class csr_array(SparseArray):
             lay = self._dia = (*commit_to_exec_device(lay[:1]), lay[1])
         return lay
 
+    def _dia_operands(self, lay, xdtype=None):
+        """What the layout ``dia`` multiplies ``xdtype`` vectors through: on
+        a TPU, for a square float32 matrix and a float32 operand whose window
+        fits VMEM (``_DIA_VMEM_BYTES``), the row-indexed planes packed once
+        on the device (``DiaRows``, kept beside the planes they were made
+        from); everywhere else the scipy-layout planes ``lay[0]``. Nothing
+        sets it. A first use inside a trace packs nothing."""
+        planes, offsets = lay
+        m, n = self.shape
+        if (m != n or planes.dtype != np.float32
+                or jnp.dtype(self.dtype if xdtype is None else xdtype) != np.float32
+                or not _dia_platform()):
+            return planes
+        kept = self._dia_rows
+        if kept is None or kept[0] is not planes:
+            if in_trace():
+                return planes
+            from .kernels.dia_spmv import DiaRows, dia_pack, dia_rows_plan
+
+            plan = dia_rows_plan(offsets, n, _DIA_VMEM_BYTES)
+            with telemetry.span("layout.dia_pack", fits=plan is not None):
+                rows = plan and DiaRows(dia_pack(planes, plan), plan)
+            kept = self._dia_rows = (planes, rows)
+        return kept[1] or planes
+
     def prepare(self, mode: str | None = None):
         """One-time eager layout/pack warm for the current (or given)
         ``spmv_mode``: every layout a vector or a 2-D product may take under
@@ -597,16 +660,19 @@ class csr_array(SparseArray):
         compiled solver can have the matrix as an argument and nothing of
         it as a constant of its program. ``xdtype`` is the operand's type
         (a layout that multiplies in one type only is passed over for
-        another). ``"dia+"`` is the packed Pallas
-        kernel over the planes of ``"dia"``, which keeps an operator of its
-        own (``_spmv``)."""
+        another). The arrays of ``"dia"`` are its scipy-layout planes or,
+        where the kernel multiplies, their packed rows
+        (``_dia_operands``); ``"dia+"`` is the user-set packed kernel over
+        the planes, which keeps an operator of its own (``_spmv``)."""
         for name in _layouts(1):
             lay = self._offer(name, xdtype)
             if lay is None:
                 continue
             if name == "well":
                 return name, lay.arrays, lay.meta
-            if name.startswith("dia"):
+            if name == "dia":
+                return name, self._dia_operands(lay, xdtype), (lay[1], self.shape)
+            if name == "dia+":
                 return name, lay[0], (lay[1], self.shape)
             if name.startswith("sell"):
                 return "sell", (lay.slabs, lay.pos), lay.plan.zero_rows
@@ -626,6 +692,8 @@ class csr_array(SparseArray):
             kind = "dia"  # band too wide for VMEM: the XLA form
         if kind in ("sell", "well"):  # once a product here, as `PreparedCSR.__call__`
             telemetry.count(f"kernel.{kind}_spmv")
+        elif form_kernels(kind, arrays):
+            telemetry.count("kernel.dia_spmv_rows")
         return form_matvec(kind, meta, arrays, x)
 
     def _spmm(self, B):

@@ -229,6 +229,179 @@ def dia_spmv_packed(planes_flat, x_padded, plan: DiaPlan, interpret: bool = Fals
     )(planes_flat, x_padded)
 
 
+# ---------------------------------------------------------------------------
+# The layout ``dia``'s own product on the chip (``csr.form_matvec``): the
+# same packed planes and the same window arithmetic, but x and y are the
+# caller's own vectors padded to whole 1024-element tiles, ``[n_tiles]``:
+# no copy of x with a halo at both ends and no ``[m_pad]`` result to cut.
+# A window that overhangs x (the first step's left halo, the last steps'
+# right one) is fetched as far as x goes and the rest of it zeroed in VMEM;
+# the packed planes are zero wherever a diagonal leaves the matrix, so the
+# zeros only keep 0 x junk from being NaN. The halo is rounded to 1024 (every
+# window start in x is then a whole tile) and is at most one row tile, so the
+# only steps with an overhang are the first and the last two.
+# ---------------------------------------------------------------------------
+
+DIA_TILE = 1024  # Mosaic's tiling of a 1-D float32 array in HBM
+
+
+def dia_rows_plan(offsets, n: int, vmem_bytes: int, tile: int = 65536):
+    """The :class:`DiaPlan` of :func:`dia_spmv_rows` for a square matrix of
+    ``n`` rows, or None where no row tile fits: the kernel holds ``2 D TM``
+    plane elements, two x windows of ``TM + 2 B`` and Pallas two blocks of y,
+    4 bytes each, and that has to stay under ``vmem_bytes`` with ``TM`` at
+    least the halo ``B``. The tile is the largest under ``tile`` that fits,
+    then evened out over the steps (the planes are stored to whole row
+    tiles: 1,270,432 rows take 20 steps of 64,512, not of 65,536)."""
+    D = len(offsets)
+    B = _round_up(max(max((abs(int(o)) for o in offsets), default=0), 1), DIA_TILE)
+    fit = (vmem_bytes // 4 - 4 * B) // (2 * D + 4) // DIA_TILE * DIA_TILE
+    cap = min(_round_up(tile, DIA_TILE), fit)
+    if n < 1 or cap < B:
+        return None
+    tiles = -(-n // DIA_TILE)
+    G = -(-tiles * DIA_TILE // cap)
+    return DiaPlan(offsets, n, n, DIA_TILE * -(-tiles // G), B, G)
+
+
+@jax.tree_util.register_pytree_node_class
+class DiaRows:
+    """What the layout ``dia`` multiplies through on the chip: the flat
+    row-indexed plane stream of :func:`dia_pack` (a jax array, a jit
+    argument) and its static :class:`DiaPlan` (part of a program's key)."""
+
+    __slots__ = ("planes", "plan")
+
+    def __init__(self, planes, plan: DiaPlan):
+        self.planes, self.plan = planes, plan
+
+    def tree_flatten(self):
+        return (self.planes,), self.plan
+
+    @classmethod
+    def tree_unflatten(cls, plan, children):
+        return cls(children[0], plan)
+
+    @property
+    def n_tiles(self) -> int:
+        """x's and y's length: the rows in whole 1024-element tiles."""
+        return _round_up(self.plan.m, DIA_TILE)
+
+    def matvec(self, x, interpret: bool = False):
+        """``A @ x`` for ``x [n]``: x padded to whole tiles, the result cut
+        to the rows (no-ops where ``n`` is whole tiles)."""
+        n = self.plan.m
+        xt = jnp.pad(x, (0, self.n_tiles - n))
+        return dia_spmv_rows(self.planes, xt, self.plan, interpret=interpret)[:n]
+
+
+@partial(jax.jit, static_argnames=("plan", "interpret"))
+def dia_spmv_rows(planes_flat, x_tiles, plan: DiaPlan, interpret: bool = False):
+    """y = A @ x from the packed planes of a plan of :func:`dia_rows_plan`;
+    ``x_tiles`` and the result are ``[n_tiles]``, the rows padded to whole
+    1024-element tiles (x's pad anything finite, y's exactly zero)."""
+    TM, B, G, D = plan.TM, plan.B, plan.G, plan.D
+    win = TM + 2 * B
+    m_pad = G * TM
+    n_tiles = _round_up(plan.m, DIA_TILE)
+    assert x_tiles.shape == (n_tiles,) and B % DIA_TILE == 0 and B <= TM, plan._key()
+
+    def xspan(g: int):
+        """Step g's part of x: (where its window starts in x, the offset of
+        what x holds of it inside the window, its length)."""
+        lo, hi = max(g * TM - B, 0), min(g * TM + TM + B, n_tiles)
+        return lo, lo - (g * TM - B), hi - lo
+
+    # the steps whose window overhangs x, each with static bounds of its own
+    edge = {g: xspan(g) for g in sorted({0, *range(max(G - 2, 0), G)})
+            if xspan(g)[1:] != (0, win)}
+
+    def kernel(planes_hbm, x_hbm, y_ref, *scr):
+        dwinsA, dwinsB = scr[:D], scr[D : 2 * D]
+        xwinA, xwinB, semA, semB = scr[2 * D :]
+        g = pl.program_id(0)
+        G_ = pl.num_programs(0)
+
+        def on_x_copy(xwin, sem, gg, do):
+            """``do`` on the copy of step gg's part of x into ``xwin``."""
+            inside = None
+            for s, (lo, off, size) in edge.items():
+                @pl.when(gg == s)
+                def _(lo=lo, off=off, size=size):
+                    do(pltpu.make_async_copy(
+                        x_hbm.at[pl.ds(lo, size)], xwin.at[pl.ds(off, size)],
+                        sem.at[D]))
+
+                inside = gg != s if inside is None else inside & (gg != s)
+            if len(edge) < G:
+                def whole():
+                    do(pltpu.make_async_copy(
+                        x_hbm.at[pl.ds(gg * TM - B, win)], xwin, sem.at[D]))
+
+                whole() if inside is None else pl.when(inside)(whole)
+
+        def on_copies(dwins, xwin, sem, gg, do):
+            for k in range(D):
+                do(pltpu.make_async_copy(
+                    planes_hbm.at[pl.ds(k * m_pad + gg * TM, TM)],
+                    dwins[k], sem.at[k]))
+            on_x_copy(xwin, sem, gg, do)
+
+        def step(dwins, xwin, sem, dwins_n, xwin_n, sem_n):
+            @pl.when(g == 0)
+            def _():
+                on_copies(dwins, xwin, sem, g, lambda c: c.start())
+
+            @pl.when(g + 1 < G_)
+            def _():
+                on_copies(dwins_n, xwin_n, sem_n, g + 1, lambda c: c.start())
+
+            # the overhang of an edge step's window: no copy writes it
+            for s, (_lo, off, size) in edge.items():
+                @pl.when(g == s)
+                def _(off=off, size=size):
+                    if off:
+                        xwin[0:off] = jnp.zeros((off,), xwin.dtype)
+                    if off + size < win:
+                        xwin[off + size : win] = jnp.zeros(
+                            (win - off - size,), xwin.dtype)
+
+            on_copies(dwins, xwin, sem, g, lambda c: c.wait())
+            acc = jnp.zeros((TM,), dtype=y_ref.dtype)
+            for k, o in enumerate(plan.offsets):
+                lo = B + o
+                acc = acc + dwins[k][:] * xwin[lo : lo + TM]
+            y_ref[:] = acc
+
+        @pl.when(g % 2 == 0)
+        def _():
+            step(dwinsA, xwinA, semA, dwinsB, xwinB, semB)
+
+        @pl.when(g % 2 == 1)
+        def _():
+            step(dwinsB, xwinB, semB, dwinsA, xwinA, semA)
+
+    return pl.pallas_call(
+        kernel,
+        name="dia_spmv_rows",
+        grid=(G,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((TM,), lambda g: (g,), memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((n_tiles,), x_tiles.dtype),
+        scratch_shapes=[pltpu.VMEM((TM,), planes_flat.dtype)] * (2 * D)
+        + [
+            pltpu.VMEM((win,), x_tiles.dtype),
+            pltpu.VMEM((win,), x_tiles.dtype),
+            pltpu.SemaphoreType.DMA((D + 1,)),
+            pltpu.SemaphoreType.DMA((D + 1,)),
+        ],
+        interpret=interpret,
+    )(planes_flat, x_tiles)
+
+
 def dia_spmv_pallas_v2(data, offsets, x, shape, tile=65536, interpret=None):
     """One-shot wrapper over the prepared path (packs per call — for tests
     and drop-in use; hot loops should pack once via PreparedDia)."""

@@ -934,22 +934,26 @@ def _matrix_form(op, dtype):
     ``dtype`` vectors through (``csr_array._spmv_form``: built and committed
     to the device on its first use), or None: an operator that is no plain
     ``csr_array`` wrapper (a closure, a composite, one wrapped for fault
-    injection), an integer product, the packed Pallas DIA layout."""
+    injection), an integer product. The user-set packed DIA product
+    (``"dia+"``: an eager product's, with an operator of its own) is taken as
+    the layout ``"dia"`` it stands on, which multiplies by its own rule."""
     csr = getattr(op, "A", None)
     if (type(op) is not _SparseMatrixLinearOperator
             or not hasattr(csr, "_spmv_form")
             or not jnp.issubdtype(jnp.result_type(csr.dtype, dtype),
                                   jnp.inexact)):
         return None
-    form = csr._spmv_form(dtype)
-    return None if form[0] == "dia+" else form
+    kind, arrays, meta = csr._spmv_form(dtype)
+    if kind == "dia+":
+        kind, arrays = "dia", csr._dia_operands((arrays, meta[0]), dtype)
+    return kind, arrays, meta
 
 
 def _try_general_cg(A, b, x0, tol, maxiter, conv_test_iters):
     """Unpreconditioned CG on a matrix through the compiled general program
     (the trace names it ``jit_cg_general``): ``(x, iters)``, or None where
     it does not apply — an operator that is no ``csr_array`` (or is wrapped
-    for fault injection), the packed Pallas DIA product, a call under an
+    for fault injection), a call under an
     outer trace. What an operator keeps is its layout
     (``csr_array._spmv_form``: built on the first product, committed to the
     device once); the program is jit's, found again by the layout's kind
@@ -992,6 +996,12 @@ class _FormApply:
         from .csr import form_matvec
 
         return form_matvec(self.kind, self.meta, arrays, v)
+
+    def kernels(self, arrays) -> int:
+        """Pallas kernels in one product (``csr.form_kernels``)."""
+        from .csr import form_kernels
+
+        return form_kernels(self.kind, arrays)
 
 
 def _identity_apply(operands, v):
@@ -1039,6 +1049,17 @@ def _declared_call(A, M, b, x, stop, maxiter, **static):
     return ((a_operands, m_operands, b, x, stop, _i32(maxiter)),
             dict(a_apply=a_apply, m_apply=m_apply, tapped=_iter_tapping(),
                  **static))
+
+
+def _spmv_kernels(declared) -> int:
+    """Pallas kernels in one ``A @ v`` of a solve (the ``spmv_kernels`` field
+    of the ``gmres.solve`` span), by what the operands of the declared
+    product ``(apply, operands)`` show (``_FormApply.kernels``): 1 for a
+    banded matrix on its packed rows or a windowed one, 0 for an XLA form
+    and for an operator that says nothing of its product (``declared``
+    None, an ``apply`` of its own)."""
+    kernels = getattr(declared and declared[0], "kernels", None)
+    return kernels(declared[1]) if kernels else 0
 
 
 def _precond_fields(M) -> dict:
@@ -1554,24 +1575,27 @@ def gmres(
     # neither eager warm-up of the cycle path is needed
     call = None if callback is not None else _declared_call(
         A, M, b, x, target, maxiter, restart=int(restart))
+    declared = ((call[1]["a_apply"], call[0][0]) if call is not None
+                else _declared(A, b.dtype))
+    fields = {"restart": int(restart), **_precond_fields(M),
+              "spmv_kernels": _spmv_kernels(declared)}
     if call is not None:
-        x, iters = _run_compiled_solve(
-            _GMRES, call, {"restart": int(restart), **_precond_fields(M)})
+        x, iters = _run_compiled_solve(_GMRES, call, fields)
         path = "device"
     else:
         x, iters, path = _gmres_cycle_path(
-            A, M, b, x, target, restart, maxiter, callback)
+            A, M, b, x, target, restart, maxiter, callback, fields)
     _solve_event("gmres", n, iters, path, x=x)
     return x, iters
 
 
-def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback):
+def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback, fields):
     """The solve one restart cycle at a time, under its ``gmres.solve``
-    span (``path="cycle"``): the compiled cycle driven from the host, or
-    host cycles for operators that cannot be traced. ``(x, iters, path)``,
-    ``path`` that of the solve's event: ``"device"`` or ``"host"``."""
-    with telemetry.span("gmres.solve", path="cycle", restart=int(restart),
-                        **_precond_fields(M)) as solve:
+    span (``path="cycle"`` and the caller's ``fields``): the compiled cycle
+    driven from the host, or host cycles for operators that cannot be
+    traced. ``(x, iters, path)``, ``path`` that of the solve's event:
+    ``"device"`` or ``"host"``."""
+    with telemetry.span("gmres.solve", path="cycle", **fields) as solve:
         syncs0 = HOST_SYNCS
         try:
             x, iters, cycles = _gmres_cycles(

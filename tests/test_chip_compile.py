@@ -89,13 +89,18 @@ def _five_point(g: int) -> sp.csr_matrix:
 # ---------------------------------------------------------------------------
 # DIA SpMV (A @ x under spmv_mode='pallas', and the SpMV microbenchmark row)
 # ---------------------------------------------------------------------------
+# SuiteSparse atmosmodd's box, 148 x 148 x 58: rows and the seven offsets
+ATMOSMODD = (1_270_432, (-21904, -148, -1, 0, 1, 148, 21904))
+
+
 @pytest.mark.parametrize(
     "rows, offsets",
     [
         (10_000_000, tuple(range(-5, 6))),  # BASELINE SpMV row: 10M x 11
         (PDE_N * PDE_N, (-PDE_N, -1, 0, 1, PDE_N)),  # PDE operator: 36M x 5
+        ATMOSMODD,  # the nonsymmetric cell's box: a band of 21,904
     ],
-    ids=["10Mx11", "36Mx5"],
+    ids=["10Mx11", "36Mx5", "atmosmodd"],
 )
 def test_dia_spmv_packed_compiles(one_chip, rows, offsets):
     from sparse_tpu.kernels.dia_spmv import dia_plan, dia_spmv_packed
@@ -106,6 +111,35 @@ def test_dia_spmv_packed_compiles(one_chip, rows, offsets):
     xpad = _sds((m_pad + 2 * plan.B,), jnp.float32, one_chip)
     c = dia_spmv_packed.lower(planes, xpad, plan, interpret=False).compile()
     assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+@pytest.mark.parametrize(
+    "rows, offsets, tile",
+    [
+        (*ATMOSMODD, (64512, 22528, 20)),
+        (3200 * 3200, (-3200, -1, 0, 1, 3200), (65536, 4096, 157)),
+        (10_000_000, tuple(range(-5, 6)), (65536, 1024, 153)),
+        (300_000, tuple(range(-13, 14)), (33792, 1024, 9)),  # 27 diagonals
+        (5000, (-70, -1, 0, 1, 70), (5120, 1024, 1)),  # one step, both halos cut
+    ],
+    ids=["atmosmodd", "10Mx5", "10Mx11", "300Kx27", "5Kx5"],
+)
+def test_dia_spmv_rows_compiles_at_the_tile_the_rule_picks(
+        one_chip, rows, offsets, tile):
+    """The layout ``dia``'s own product on the chip (PR 50): the kernel at
+    the plan ``csr_array._dia_operands`` makes from the geometry, x and y
+    the rows in whole tiles."""
+    from sparse_tpu import csr
+    from sparse_tpu.kernels.dia_spmv import dia_rows_plan, dia_spmv_rows
+
+    plan = dia_rows_plan(offsets, rows, csr._DIA_VMEM_BYTES)
+    assert (plan.TM, plan.B, plan.G) == tile
+    planes = _sds((plan.D * plan.G * plan.TM,), jnp.float32, one_chip)
+    vec = _sds((-(-rows // 1024) * 1024,), jnp.float32, one_chip)
+    c = dia_spmv_rows.lower(planes, vec, plan, interpret=False).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text and f"f32[{vec.shape[0]}]" in text
     assert _device_bytes(c) < HBM_BYTES
 
 
@@ -715,16 +749,29 @@ GMRES_BASIS_ROWS = 9928  # 8 * ceil(1,270,432 / 1024): a basis row is [9928, 128
 GMRES_SCOPES = ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update")
 
 
-def _gmres_compiled(one_chip, restart=30):
-    from sparse_tpu import linalg
+def _gmres_compiled(one_chip, monkeypatch, restart=30, kernel=True):
+    """``jit_gmres`` at the cell's box as the chip runs it since PR 50: the
+    matrix's operand the packed rows of the layout ``dia`` at the plan the
+    rule picks (``kernel=False``: the scipy-layout planes, the XLA form the
+    rule leaves everywhere else)."""
+    from sparse_tpu import csr, linalg
+    from sparse_tpu.kernels.dia_spmv import DiaRows, dia_rows_plan
 
+    # `csr.form_matvec` interprets the kernel off a TPU; this process's
+    # backend is the CPU and the program is compiled for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     a, b, c = GMRES_BOX
     n = a * b * c
     offsets = (-a * b, -a, -1, 0, 1, a, a * b)
+    assert (n, offsets) == ATMOSMODD
     vec = _sds((n,), jnp.float32, one_chip)
+    operand = _sds((len(offsets), n), jnp.float32, one_chip)
+    if kernel:
+        plan = dia_rows_plan(offsets, n, csr._DIA_VMEM_BYTES)
+        operand = DiaRows(
+            _sds((plan.D * plan.G * plan.TM,), jnp.float32, one_chip), plan)
     return n, linalg._gmres_program.lower(
-        _sds((len(offsets), n), jnp.float32, one_chip), (), vec, vec,
-        _sds((), jnp.float32, one_chip), 10,
+        operand, (), vec, vec, _sds((), jnp.float32, one_chip), 10,
         a_apply=linalg._FormApply("dia", (offsets, (n, n))),
         m_apply=linalg._identity_apply, restart=restart, tapped=False).compile()
 
@@ -754,8 +801,8 @@ def _arnoldi_body(computations: dict) -> str:
     return body
 
 
-def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
-    n, c = _gmres_compiled(one_chip)
+def test_gmres_program_compiles_at_atmosmodd_size(one_chip, monkeypatch):
+    n, c = _gmres_compiled(one_chip, monkeypatch)
     text = c.as_text()
     assert "jit_gmres" in text and _device_bytes(c) < HBM_BYTES
     ma = c.memory_analysis()
@@ -807,9 +854,23 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
              for _name, _res, rest in _fusions(comp)]
     for comp in step:
         assert not re.search(r" (reshape|transpose)\(", comp)
-        # (the plane product slices its [7, n] planes a row at a time)
-        assert all("gmres.spmv/" in ln for ln in comp.splitlines()
-                   if f"f32[1,{n}]" in ln)
+        assert f"f32[1,{n}]" not in comp
+    # the product (PR 50): one kernel call a step, under the product's
+    # scope, on the row in whole 1024-element tiles (the same length as its
+    # flat view); no [7, n] intermediate, padded or not, anywhere in the
+    # program. Around it two small fusions: the row's read with the cut to n
+    # in it (a select: the kernel's operand), and the result's cut and pad
+    # back to a row
+    calls = [ln for ln in body.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "gmres.spmv/" in calls[0], calls
+    flat = f"f32[{rows * 128}]"
+    assert re.match(r"\s*%%?[\w.\-]+ = %s\S* custom-call\(" % re.escape(flat),
+                    calls[0])
+    assert "dia_spmv_rows" in calls[0]
+    assert not re.search(r"= f32\[7,\d+\]", text)
+    in_scope = [ln for ln in body.splitlines() if "gmres.spmv/" in ln
+                and re.search(r" (fusion|custom-call|pad|slice|copy)\(", ln)]
+    assert len(in_scope) == 3, in_scope
     # the rotations' inner loop carries its Hessenberg column in fast memory
     # (`S(1)`), as it does the rotations: made by a scatter and not a select
     # the column was left in HBM and a trip's six ops read 3.96 us where 2.23
@@ -833,7 +894,20 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip):
                            dtype=np.int64)) <= 2, dims
 
 
-def test_gmres_program_ops_carry_their_scope(one_chip):
+def test_gmres_program_without_the_kernel_is_the_parents(one_chip, monkeypatch):
+    """The other side of the rule (float64, a band too wide, a rectangular
+    matrix on a TPU): the scipy-layout planes as the operand, the XLA form,
+    whose planes x x is a ``[7, n]`` array written and read again."""
+    n, c = _gmres_compiled(one_chip, monkeypatch, kernel=False)
+    text = c.as_text()
+    assert "jit_gmres" in text and _device_bytes(c) < HBM_BYTES
+    assert "tpu_custom_call" not in text
+    body = _arnoldi_body(_computations(text))
+    assert [ln for ln in body.splitlines()
+            if "gmres.spmv/" in ln and re.search(r"= f32\[7,%d\]" % n, ln)]
+
+
+def test_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
     """What ``benchmark/reducers/op_scope_share.py`` reads the cell's
     per-scope shares from: in the Arnoldi loop's body, and in the branches
     of the orthogonalisation's ``conditional`` (one a stage: PR 43; eight
@@ -845,7 +919,7 @@ def test_gmres_program_ops_carry_their_scope(one_chip):
     rows of the major dimension inside the fusion."""
     from sparse_tpu import linalg
 
-    n, c = _gmres_compiled(one_chip)
+    n, c = _gmres_compiled(one_chip, monkeypatch)
     rows = GMRES_BASIS_ROWS
     text = c.as_text()
     computations = _computations(text)
