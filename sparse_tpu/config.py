@@ -32,7 +32,9 @@ Flags (all env-overridable):
   SPARSE_TPU_TELEMETRY_PATH   - JSONL sink override (default: records.jsonl under
                                 telemetry's default root, a directory of the system's
                                 temporary directory: telemetry._recorder.default_root()).
-  SPARSE_TPU_TELEMETRY_RING   - in-memory event ring capacity (default 4096).
+  SPARSE_TPU_TELEMETRY_RING   - in-memory event ring capacity (default 16384: the next
+                                power of two above twice the events of the benchmark's
+                                fullest traced window, 7,646 in pde_cg_4chip).
   SPARSE_TPU_FAULTS           - fault-injection spec (sparse_tpu.resilience.faults), e.g.
                                 "nonfinite:matvec:p=0.01,seed=7;fail:pallas". Empty
                                 (default) = injection machinery entirely inert.
@@ -282,8 +284,11 @@ class Settings:
     telemetry_path: str = field(
         default_factory=lambda: _env_str("SPARSE_TPU_TELEMETRY_PATH", "")
     )
+    # the next power of two above twice the events of the fullest traced
+    # run of the benchmark's nine cells, 7,646 in `pde_cg_4chip` (my chip
+    # run, PR 51; docs/telemetry.md, Enabling)
     telemetry_ring: int = field(
-        default_factory=lambda: max(_env_int("SPARSE_TPU_TELEMETRY_RING", 4096), 16)
+        default_factory=lambda: max(_env_int("SPARSE_TPU_TELEMETRY_RING", 16384), 16)
     )
     # Fault injection (sparse_tpu.resilience.faults): a seeded chaos spec
     # ("fault:site:k=v,..." clauses, ";"-separated — docs/resilience.md).

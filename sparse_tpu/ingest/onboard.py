@@ -274,7 +274,7 @@ class Onboarder:
                 _QUEUE_DEPTH.set(len(self._queue))
             try:
                 # ticket-scope the whole onboarding so nested events
-                # (comm.sort, vault.store, plan_cache.*) carry the
+                # (comm.sort, vault.quarantine, plan_cache.*) carry the
                 # originating ingest ticket id, mirroring the solve path
                 with telemetry.ticket_scope(item[0].id):
                     self._process(*item)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 
 import jax
@@ -446,12 +447,107 @@ def _mesh_fields(x) -> dict:
     return {"devices": devices} if devices > 1 else {}
 
 
+class _CallAccount:
+    """A public solver's account of its own call, for the ``solver.solve``
+    event that ends it (docs/telemetry.md, "The call's account"): ``call_ms
+    = prep_ms + dispatch_ms + wait_ms + rest_ms``, and ``caller_ms`` since
+    the thread's previous call closed. One a thread, made by the thread's
+    first live call (:func:`_solver_call`); the outermost call alone keeps
+    it, a solver called inside one only counts the depth. Its instants are
+    the spans' own (``solver.call``'s start, the ``<solver>.solve`` span
+    its driver hands in); the one reading it makes is its close."""
+
+    __slots__ = ("depth", "call", "solve", "dispatch_s", "wait_s", "last",
+                 "closed")
+
+    def __init__(self):
+        self.depth = 0  # public solvers open on this thread
+        self.call = None  # the outermost one's `solver.call` span
+        self.solve = None  # the `.solve` span of its driver, once it closed
+        self.dispatch_s = self.wait_s = None
+        self.last = None  # where the thread's previous call closed
+        self.closed = None  # where this one did (None: it raised)
+
+    def __enter__(self):
+        if self.depth == 0:
+            self.solve = self.dispatch_s = self.wait_s = None
+            self.last, self.closed = self.closed, None
+            self.call.__enter__()
+        self.depth += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.depth -= 1
+        if self.depth == 0:
+            self.call.__exit__(*exc)
+            self.call = None
+        return False
+
+    def close(self) -> dict:
+        """The outermost call's fields for its ``solver.solve`` event, in
+        ms, and the account's end; a nested call's event gets none."""
+        if self.depth != 1:
+            return {}
+
+        def ms(seconds):
+            return round(seconds * 1e3, 3)
+
+        t0 = self.call.t0
+        self.closed = t1 = telemetry.clock()
+        out = {"call_ms": ms(t1 - t0)}
+        solve = self.solve
+        if solve is not None:
+            parts = {"prep_ms": ms(solve.t0 - t0)}
+            if self.dispatch_s is not None:  # a span that sums the two
+                parts.update(dispatch_ms=ms(self.dispatch_s),
+                             wait_ms=ms(self.wait_s))
+            # by subtraction from the rounded parts: the identity is exact
+            parts["rest_ms"] = max(
+                round(out["call_ms"] - sum(parts.values()), 3), 0.0)
+            out.update(parts)
+        if self.last is not None:
+            out["caller_ms"] = ms(t0 - self.last)
+        return out
+
+
+_CALLS = threading.local()
+
+
+def _solver_call():
+    """The scope of one public solver's call: ``with _solver_call():`` is
+    the first statement of the body of every solver that ends in a
+    ``solver.solve`` event. Live, it is the ``solver.call`` span
+    (``emit=False``: a profile's annotation and an aggregate) under the
+    thread's :class:`_CallAccount`. With telemetry off, and under a jit
+    trace, it is ``span()``'s shared no-op: no account, no clock."""
+    acct = getattr(_CALLS, "account", None)
+    if acct is not None and acct.depth:
+        return acct  # a solver inside a solver: the outer call's account
+    call = telemetry.span("solver.call", emit=False)
+    if call.__class__ is not telemetry.Span:
+        return call
+    if acct is None:
+        acct = _CALLS.account = _CallAccount()
+    acct.call = call
+    return acct
+
+
+def _call_solved(solve, dispatch_s=None, wait_s=None) -> None:
+    """A driver hands its closed ``<solver>.solve`` span, and the two sums
+    it put on it, to the open account of the outermost call."""
+    acct = getattr(_CALLS, "account", None)
+    if acct is not None and acct.depth == 1 and solve.t0 is not None:
+        acct.solve, acct.dispatch_s, acct.wait_s = solve, dispatch_s, wait_s
+
+
 def _solve_event(
     solver: str, n, iters, path: str, resid2=None, converged=None, x=None
 ) -> None:
     """One ``solver.solve`` event per completed solve (any path; ``x`` the
-    answer, for :func:`_mesh_fields`); also finalizes the health monitor's
-    report for this solve (``telemetry.last_solve_report()``)."""
+    answer, for :func:`_mesh_fields`), the last thing a call does: the
+    health monitor's report for this solve is finalized first
+    (``telemetry.last_solve_report()``), then the call's account closes
+    and its fields go onto the event."""
     if not telemetry.enabled():
         return
     fields = {"solver": solver, "n": int(n), "iters": int(iters), "path": path,
@@ -460,10 +556,13 @@ def _solve_event(
         fields["resid2"] = float(resid2)
     if converged is not None:
         fields["converged"] = bool(converged)
-    telemetry.record("solver.solve", **fields)
     telemetry.health.end_solve(
         solver, iters, resid2=resid2, converged=converged, path=path
     )
+    acct = getattr(_CALLS, "account", None)
+    if acct is not None:
+        fields.update(acct.close())
+    telemetry.record("solver.solve", **fields)
 
 
 def _iter_tapping() -> bool:
@@ -532,13 +631,14 @@ def cg(
     (``LinearOperator(shape, matvec=f)``) on either side still compiles
     its loop in every call (:func:`_cg_device_loop`), and a ``callback``
     runs the host loop."""
-    assert atol is None, "atol is not supported."
-    b = asjnp(b)
-    if maxiter is None:
-        maxiter = b.shape[0] * 10
-    x, iters, path, health = _cg_take(
-        A, b, x0, tol, maxiter, M, callback, conv_test_iters)
-    _solve_event("cg", b.shape[0], iters, path, x=x, **health)
+    with _solver_call():
+        assert atol is None, "atol is not supported."
+        b = asjnp(b)
+        if maxiter is None:
+            maxiter = b.shape[0] * 10
+        x, iters, path, health = _cg_take(
+            A, b, x0, tol, maxiter, M, callback, conv_test_iters)
+        _solve_event("cg", b.shape[0], iters, path, x=x, **health)
     return x, iters
 
 
@@ -751,6 +851,7 @@ def _try_fused_cg(A, b, x0, tol, maxiter, conv_test_iters):
             solve.annotate(chunks=chunks, iters=iters, packs=packs,
                            dispatch_s=round(dispatch_s, 9),
                            fetch_s=round(fetch_s, 9))
+    _call_solved(solve, dispatch_s, fetch_s)
     if info is None:  # maxiter exhausted
         info = 0 if (rho_f is not None and rho_f < tol2) else iters
     return x, iters, rho_f, info
@@ -854,9 +955,11 @@ def _run_compiled_solve(solve_of, call, fields):
         dispatch_s = sp.dur_s or 0.0
         with telemetry.span(fetch, emit=False) as sp:
             counted = solve_of.fields(counts, static)
+        fetch_s = sp.dur_s or 0.0
         solve.annotate(**counted, **_mesh_fields(x),
                        dispatch_s=round(dispatch_s, 9),
-                       fetch_s=round(sp.dur_s or 0.0, 9))
+                       fetch_s=round(fetch_s, 9))
+    _call_solved(solve, dispatch_s, fetch_s)
     if static["tapped"]:
         _effects_barrier()
     return x, counted["iters"]
@@ -1340,72 +1443,73 @@ def bicg(A, b, x0=None, tol=1e-08, maxiter=None, callback=None, conv_test_iters=
 # ---------------------------------------------------------------------------
 @track_provenance
 def bicgstab(A, b, x0=None, tol=1e-08, maxiter=None, callback=None, conv_test_iters=25):
-    b = asjnp(b)
-    n = b.shape[0]
-    if maxiter is None:
-        maxiter = n * 10
-    A = make_linear_operator(A)
-    x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
-    r = b - A.matvec(x)
-    rtilde = r
-    tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
-    base_tap = _make_iter_tap("bicgstab")
-    tap = None
-    if base_tap is not None:
-        # same tap cadence, two more scalars: |rho|, |omega| feed the
-        # health monitor's breakdown detector — the rho/omega breakdowns
-        # the recurrence silently where-guards become observable
-        # `solver.anomaly reason=breakdown` events the recovery policy
-        # escalates on (ISSUE 5)
-        def tap(i, rn2, abs_rho, abs_omega):
-            base_tap(i, rn2)
-            telemetry.health.observe_breakdown(
-                "bicgstab", int(i), float(abs_rho), float(abs_omega),
-                resid2=float(rn2),
-            )
+    with _solver_call():
+        b = asjnp(b)
+        n = b.shape[0]
+        if maxiter is None:
+            maxiter = n * 10
+        A = make_linear_operator(A)
+        x = jnp.zeros_like(b) if x0 is None else asjnp(x0)
+        r = b - A.matvec(x)
+        rtilde = r
+        tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
+        base_tap = _make_iter_tap("bicgstab")
+        tap = None
+        if base_tap is not None:
+            # same tap cadence, two more scalars: |rho|, |omega| feed the
+            # health monitor's breakdown detector — the rho/omega breakdowns
+            # the recurrence silently where-guards become observable
+            # `solver.anomaly reason=breakdown` events the recovery policy
+            # escalates on (ISSUE 5)
+            def tap(i, rn2, abs_rho, abs_omega):
+                base_tap(i, rn2)
+                telemetry.health.observe_breakdown(
+                    "bicgstab", int(i), float(abs_rho), float(abs_omega),
+                    resid2=float(rn2),
+                )
 
-    def body(state):
-        x, r, p, v, rho, alpha, omega, iters = state
-        rho_new = _vdot(rtilde, r)
-        first = iters == 0
-        beta = (rho_new / jnp.where(rho == 0, 1, rho)) * (
-            alpha / jnp.where(omega == 0, 1, omega)
-        )
-        p_n = jnp.where(first, r, r + beta * (p - omega * v))
-        v_n = A.matvec(p_n)
-        rv = _vdot(rtilde, v_n)
-        alpha_n = rho_new / jnp.where(rv == 0, 1, rv)  # 0/0 guard: b=0/exact x0
-        s = r - alpha_n * v_n
-        t = A.matvec(s)
-        omega_n = _vdot(t, s) / jnp.where(_vdot(t, t) == 0, 1, _vdot(t, t))
-        x_n = x + alpha_n * p_n + omega_n * s
-        r_n = s - omega_n * t
+        def body(state):
+            x, r, p, v, rho, alpha, omega, iters = state
+            rho_new = _vdot(rtilde, r)
+            first = iters == 0
+            beta = (rho_new / jnp.where(rho == 0, 1, rho)) * (
+                alpha / jnp.where(omega == 0, 1, omega)
+            )
+            p_n = jnp.where(first, r, r + beta * (p - omega * v))
+            v_n = A.matvec(p_n)
+            rv = _vdot(rtilde, v_n)
+            alpha_n = rho_new / jnp.where(rv == 0, 1, rv)  # 0/0 guard: b=0/exact x0
+            s = r - alpha_n * v_n
+            t = A.matvec(s)
+            omega_n = _vdot(t, s) / jnp.where(_vdot(t, t) == 0, 1, _vdot(t, t))
+            x_n = x + alpha_n * p_n + omega_n * s
+            r_n = s - omega_n * t
+            if tap is not None:
+                jax.debug.callback(
+                    tap, iters + 1, jnp.real(_vdot(r_n, r_n)),
+                    jnp.abs(rho_new), jnp.abs(omega_n),
+                )
+            return x_n, r_n, p_n, v_n, rho_new, alpha_n, omega_n, iters + 1
+
+        def cond(state):
+            r = state[1]
+            iters = state[-1]
+            rnorm2 = jnp.real(_vdot(r, r))
+            tested = (iters % conv_test_iters == 0) | (iters == maxiter - 1)
+            converged = tested & (iters > 0) & (rnorm2 < tol2)
+            return (iters < maxiter) & ~converged
+
+        z = jnp.zeros_like(b)
+        one = jnp.ones((), dtype=b.dtype)
+        state = (x, r, z, z, jnp.zeros((), b.dtype), one, one, jnp.zeros((), jnp.int32))
+        out = jax.lax.while_loop(cond, body, state)
+        x, iters = out[0], out[-1]
+        if callback is not None:
+            callback(x)
+        iters = host_int(iters)
         if tap is not None:
-            jax.debug.callback(
-                tap, iters + 1, jnp.real(_vdot(r_n, r_n)),
-                jnp.abs(rho_new), jnp.abs(omega_n),
-            )
-        return x_n, r_n, p_n, v_n, rho_new, alpha_n, omega_n, iters + 1
-
-    def cond(state):
-        r = state[1]
-        iters = state[-1]
-        rnorm2 = jnp.real(_vdot(r, r))
-        tested = (iters % conv_test_iters == 0) | (iters == maxiter - 1)
-        converged = tested & (iters > 0) & (rnorm2 < tol2)
-        return (iters < maxiter) & ~converged
-
-    z = jnp.zeros_like(b)
-    one = jnp.ones((), dtype=b.dtype)
-    state = (x, r, z, z, jnp.zeros((), b.dtype), one, one, jnp.zeros((), jnp.int32))
-    out = jax.lax.while_loop(cond, body, state)
-    x, iters = out[0], out[-1]
-    if callback is not None:
-        callback(x)
-    iters = host_int(iters)
-    if tap is not None:
-        _effects_barrier()
-    _solve_event("bicgstab", n, iters, "device")
+            _effects_barrier()
+        _solve_event("bicgstab", n, iters, "device")
     return x, iters
 
 
@@ -1545,47 +1649,48 @@ def gmres(
     cycle path: one restart cycle compiled in every call
     (:func:`_make_gmres_cycle`) and one host fetch a cycle. Both run the
     one Arnoldi cycle of :func:`_gmres_cycle`."""
-    b = asjnp(b)
-    n = b.shape[0]
-    A = make_linear_operator(A)
-    M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
-    # promote b to the result dtype of A AND x0 BEFORE sizing the Krylov
-    # basis: a real b with a complex A (or a complex warm-start x0) must
-    # build a complex basis — the jitted cycle would otherwise cast every
-    # Arnoldi vector to real
-    dt = jnp.result_type(b.dtype, A.dtype)
-    if x0 is not None:
-        x0 = asjnp(x0)
-        dt = jnp.result_type(dt, x0.dtype)
-    b = b.astype(dt)
-    if restart is None:
-        restart = min(20, n)
-    restart = min(restart, n)
-    if maxiter is None:
-        maxiter = max(n // restart, 1) * 10
-    x = jnp.zeros_like(b) if x0 is None else x0.astype(dt)
-    bnorm = jnp.linalg.norm(b)
-    target = jnp.maximum(tol * bnorm, atol if atol is not None else 0.0)
-    target = jnp.maximum(target, 1e-30)
+    with _solver_call():
+        b = asjnp(b)
+        n = b.shape[0]
+        A = make_linear_operator(A)
+        M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
+        # promote b to the result dtype of A AND x0 BEFORE sizing the
+        # Krylov basis: a real b with a complex A (or a complex warm-start
+        # x0) must build a complex basis — the jitted cycle would otherwise
+        # cast every Arnoldi vector to real
+        dt = jnp.result_type(b.dtype, A.dtype)
+        if x0 is not None:
+            x0 = asjnp(x0)
+            dt = jnp.result_type(dt, x0.dtype)
+        b = b.astype(dt)
+        if restart is None:
+            restart = min(20, n)
+        restart = min(restart, n)
+        if maxiter is None:
+            maxiter = max(n // restart, 1) * 10
+        x = jnp.zeros_like(b) if x0 is None else x0.astype(dt)
+        bnorm = jnp.linalg.norm(b)
+        target = jnp.maximum(tol * bnorm, atol if atol is not None else 0.0)
+        target = jnp.maximum(target, 1e-30)
 
-    # operators that declare what they hold run `jit_gmres`, found again by
-    # the two `apply` functions, the operands' structure and `restart`:
-    # one dispatch and one fetch a call. Nothing lazy is left in them
-    # (`_matrix_form` builds the layout before the program is called), so
-    # neither eager warm-up of the cycle path is needed
-    call = None if callback is not None else _declared_call(
-        A, M, b, x, target, maxiter, restart=int(restart))
-    declared = ((call[1]["a_apply"], call[0][0]) if call is not None
-                else _declared(A, b.dtype))
-    fields = {"restart": int(restart), **_precond_fields(M),
-              "spmv_kernels": _spmv_kernels(declared)}
-    if call is not None:
-        x, iters = _run_compiled_solve(_GMRES, call, fields)
-        path = "device"
-    else:
-        x, iters, path = _gmres_cycle_path(
-            A, M, b, x, target, restart, maxiter, callback, fields)
-    _solve_event("gmres", n, iters, path, x=x)
+        # operators that declare what they hold run `jit_gmres`, found
+        # again by the two `apply` functions, the operands' structure and
+        # `restart`: one dispatch and one fetch a call. Nothing lazy is left
+        # in them (`_matrix_form` builds the layout before the program is
+        # called), so neither eager warm-up of the cycle path is needed
+        call = None if callback is not None else _declared_call(
+            A, M, b, x, target, maxiter, restart=int(restart))
+        declared = ((call[1]["a_apply"], call[0][0]) if call is not None
+                    else _declared(A, b.dtype))
+        fields = {"restart": int(restart), **_precond_fields(M),
+                  "spmv_kernels": _spmv_kernels(declared)}
+        if call is not None:
+            x, iters = _run_compiled_solve(_GMRES, call, fields)
+            path = "device"
+        else:
+            x, iters, path = _gmres_cycle_path(
+                A, M, b, x, target, restart, maxiter, callback, fields)
+        _solve_event("gmres", n, iters, path, x=x)
     return x, iters
 
 
@@ -1616,6 +1721,7 @@ def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback, fields):
             path = "host"
             solve.annotate(path="host")
         solve.annotate(cycles=cycles, iters=iters, fetches=HOST_SYNCS - syncs0)
+    _call_solved(solve)  # this span sums no dispatch and no fetch
     return x, iters, path
 
 

@@ -1123,75 +1123,78 @@ def dist_cg(
     semantics: ||r|| < max(tol * ||b||, atol). Returns (xp, iters, converged).
     """
     from .. import telemetry
+    from ..linalg import _call_solved, _solve_event, _solver_call
 
-    bp = b if isinstance(b, jax.Array) and b.shape == (A.m_pad,) else A.pad_out_vector(np.asarray(b))
-    xp = (
-        jnp.zeros_like(bp)
-        if x0 is None
-        else (x0 if isinstance(x0, jax.Array) and x0.shape == (A.m_pad,) else A.pad_out_vector(np.asarray(x0)))
-    )
-    run = make_dist_cg(
-        A, tol=tol, atol=atol, maxiter=maxiter,
-        conv_test_iters=conv_test_iters, M=M,
-    )
-    # One `dist.cg.solve` span a call: `dist.cg.dispatch` is the program's
-    # call until it returns (asynchronous: the host's part, and on a first
-    # call the trace and the compile), `dist.cg.wait` the fence, the fetch
-    # of the iteration count. Both are trace annotations and aggregates
-    # only; their lengths go onto the solve's event.
-    with telemetry.span("dist.cg.solve", layout=A.layout, S=A.S) as solve:
-        with telemetry.span("dist.cg.dispatch", emit=False) as sp:
-            xp, iters, converged = run(bp, xp)
-        dispatch_s = sp.dur_s or 0.0
-        with telemetry.span("dist.cg.wait", emit=False) as sp:
-            iters, converged = int(iters), bool(converged)  # host fetch = fence
-        solve.annotate(dispatch_s=round(dispatch_s, 9),
-                       wait_s=round(sp.dur_s or 0.0, 9), iters=iters)
-    # the compiled loop runs one SpMV per iteration plus the initial
-    # residual SpMV; commit that many executions of the traced program's
-    # measured collective volume into the always-on metrics
-    executions = iters + 1
-    led = getattr(A, "_comm_ledger", None)
-    if led is not None and led.entries:
-        led.commit(executions, A.S)
-
-    if telemetry.enabled():
-        # whole-solve collective volume from the structural model x the
-        # measured iteration count — the Legion-profiler-style comm
-        # attribution for the compiled while_loop (which is opaque to
-        # per-call counters by design)
-        cs = comm_stats(A, conv_test_iters)
-        model_bytes = (
-            int(cs["cg_iter_collective_bytes_per_shard"]) * iters * A.S
+    with _solver_call():
+        bp = b if isinstance(b, jax.Array) and b.shape == (A.m_pad,) else A.pad_out_vector(np.asarray(b))
+        xp = (
+            jnp.zeros_like(bp)
+            if x0 is None
+            else (x0 if isinstance(x0, jax.Array) and x0.shape == (A.m_pad,) else A.pad_out_vector(np.asarray(x0)))
         )
-        telemetry.record(
-            "comm.cg", S=A.S, iters=iters, mode=A.mode,
-            bytes=model_bytes,
-            bytes_per_iter_per_shard=int(
-                cs["cg_iter_collective_bytes_per_shard"]
-            ),
+        run = make_dist_cg(
+            A, tol=tol, atol=atol, maxiter=maxiter,
+            conv_test_iters=conv_test_iters, M=M,
         )
+        # One `dist.cg.solve` span a call: `dist.cg.dispatch` is the
+        # program's call until it returns (asynchronous: the host's part,
+        # and on a first call the trace and the compile), `dist.cg.wait`
+        # the fence, the fetch of the iteration count. Both are trace
+        # annotations and aggregates only; their lengths go onto the
+        # solve's event, and the span to the call's account
+        # (`linalg._CallAccount`: what lies before it is `prep_ms`).
+        with telemetry.span("dist.cg.solve", layout=A.layout, S=A.S) as solve:
+            with telemetry.span("dist.cg.dispatch", emit=False) as sp:
+                xp, iters, converged = run(bp, xp)
+            dispatch_s = sp.dur_s or 0.0
+            with telemetry.span("dist.cg.wait", emit=False) as sp:
+                iters, converged = int(iters), bool(converged)  # host fetch = fence
+            wait_s = sp.dur_s or 0.0
+            solve.annotate(dispatch_s=round(dispatch_s, 9),
+                           wait_s=round(wait_s, 9), iters=iters)
+        _call_solved(solve, dispatch_s, wait_s)
+        # the compiled loop runs one SpMV per iteration plus the initial
+        # residual SpMV; commit that many executions of the traced program's
+        # measured collective volume into the always-on metrics
+        executions = iters + 1
+        led = getattr(A, "_comm_ledger", None)
         if led is not None and led.entries:
-            # trace-derived measured bytes reconciled against the model:
-            # divergence is the drift signal (expected residue: the model
-            # counts the GSPMD scalar psums the wrappers cannot see, the
-            # measurement counts the initial-residual SpMV the model
-            # omits — both shrink with iteration count)
-            comm.record_measured(
-                "dist.cg", led, executions=executions, shards=A.S,
-                model_bytes=model_bytes, solve_s=solve.dur_s,
-                mode=A.mode, iters=iters,
+            led.commit(executions, A.S)
+
+        if telemetry.enabled():
+            # whole-solve collective volume from the structural model x the
+            # measured iteration count — the Legion-profiler-style comm
+            # attribution for the compiled while_loop (which is opaque to
+            # per-call counters by design)
+            cs = comm_stats(A, conv_test_iters)
+            model_bytes = (
+                int(cs["cg_iter_collective_bytes_per_shard"]) * iters * A.S
             )
-        telemetry.record(
-            "solver.solve", solver="dist_cg", n=int(A.shape[0]),
-            iters=iters, path="device", converged=converged,
-        )
-        # the compiled mesh loop has no per-iteration visibility, but the
-        # health monitor still closes a report (outcome + anomaly sweep
-        # on the final residual) so last_solve_report() covers dist too
-        telemetry.health.end_solve(
-            "dist_cg", iters, converged=converged, path="device"
-        )
+            telemetry.record(
+                "comm.cg", S=A.S, iters=iters, mode=A.mode,
+                bytes=model_bytes,
+                bytes_per_iter_per_shard=int(
+                    cs["cg_iter_collective_bytes_per_shard"]
+                ),
+            )
+            if led is not None and led.entries:
+                # trace-derived measured bytes reconciled against the model:
+                # divergence is the drift signal (expected residue: the model
+                # counts the GSPMD scalar psums the wrappers cannot see, the
+                # measurement counts the initial-residual SpMV the model
+                # omits — both shrink with iteration count)
+                comm.record_measured(
+                    "dist.cg", led, executions=executions, shards=A.S,
+                    model_bytes=model_bytes, solve_s=solve.dur_s,
+                    mode=A.mode, iters=iters,
+                )
+        # the call's last act, after the `comm.*` events: the health
+        # monitor's report (the compiled mesh loop has no per-iteration
+        # visibility, but a report still closes, outcome and anomaly sweep
+        # on the final residual, so last_solve_report() covers dist too),
+        # then the `solver.solve` event with the call's account
+        _solve_event("dist_cg", A.shape[0], iters, "device",
+                     converged=converged)
     return xp, iters, converged
 
 

@@ -11,8 +11,9 @@ Surface
 * :func:`record` — ``record(kind, **fields)``: one structured event into
   a bounded in-memory ring + the JSONL session log (the sink:
   ``SPARSE_TPU_TELEMETRY_PATH``, else ``records.jsonl`` under
-  ``_recorder.default_root()``, outside the checkout). Zero overhead
-  when disabled.
+  ``_recorder.default_root()``, outside the checkout; its lines are
+  buffered and reach the file every 256 lines or second, at
+  :func:`flush` and at exit). Zero overhead when disabled.
 * :func:`count` / :func:`add_bytes` — hot-path counters (kernel
   dispatches, host syncs, per-SpMV comm volumes) where an event per
   call would flood the log; stored on the metrics registry.

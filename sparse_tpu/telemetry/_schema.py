@@ -19,7 +19,13 @@ KINDS: dict[str, frozenset] = {
     # one per iteration (host/fused paths) or per conv-test chunk /
     # restart cycle; resid2 = ||r||^2 in the solve dtype where available
     "solver.iter": frozenset({"solver", "iter"}),
-    # one per completed solve, every path
+    # one per completed solve, every path: the last thing a call does.
+    # Optional fields (not required): n, resid2, converged, devices, and on
+    # the outermost public solver's event the call's account in ms
+    # (linalg._CallAccount): call_ms = prep_ms + dispatch_ms + wait_ms +
+    # rest_ms, and caller_ms since the thread's previous call closed. A path
+    # without a `.solve` span has call_ms and caller_ms alone, a thread's
+    # first call no caller_ms, a solver called inside a solver none
     "solver.solve": frozenset({"solver", "iters", "path"}),
     # a health-monitor detection (telemetry/_health.py): reason is
     # 'nonfinite' | 'divergence' | 'stagnation' | 'breakdown'; batched
@@ -152,21 +158,14 @@ KINDS: dict[str, frozenset] = {
     # (flops, bytes, peak_bytes) — the roofline join key is `program`
     "plan_cache.compile": frozenset({"program"}),
     # -- vault (sparse_tpu.vault, the persistent plan-cache tier) -----------
-    # one artifact write attempt: artifact is the codec kind ('pattern' |
-    # 'sell_pattern' | 'plane_pattern' | 'prepared_csr' | 'prepared_dia'),
-    # ok whether the atomic write landed (False = cleaned up, vault
-    # unchanged)
-    "vault.store": frozenset({"artifact", "ok"}),
-    # one successful verified artifact load (disk-tier hit)
-    "vault.load": frozenset({"artifact", "hit"}),
+    # (writes, loads and sweeps are counters of the always-on registry,
+    # `vault.writes` / `.write_failed` / `.hits` / `.evictions`: no events)
     # a verify failure: the file was moved into the quarantine sidecar;
     # reason is the verify-ladder step that failed ('bad-magic' |
     # 'bad-header' | 'stale-format' | 'stale-jax' | 'key-mismatch' |
     # 'truncated' | 'checksum' | 'decode-error' | 'expect-*' |
     # 'manifest')
     "vault.quarantine": frozenset({"artifact", "reason"}),
-    # a size-budgeted LRU sweep that evicted artifacts
-    "vault.gc": frozenset({"evicted"}),
     # a SolveSession replayed the warm-start manifest on construction:
     # entries read, programs successfully replayed
     "vault.replay": frozenset({"entries", "programs"}),
@@ -294,9 +293,13 @@ def validate(event: dict) -> list:
 def validate_jsonl(path: str) -> list:
     """Validate every telemetry event line of a JSONL file; returns
     ``[(lineno, problem), ...]``. Lines without a ``kind`` field (bare
-    metric records sharing a session log) are skipped."""
+    metric records sharing a session log) are skipped. The live sink's
+    buffered lines are written out first, in case ``path`` is that file."""
     import json
 
+    from . import _recorder
+
+    _recorder.flush()
     problems = []
     with open(path) as f:
         for i, line in enumerate(f, 1):

@@ -242,7 +242,9 @@ def to_chrome_trace(events) -> dict:
 def read_events_jsonl(path: str) -> list:
     """Telemetry events of a records.jsonl (bare metric records — no
     ``kind`` — and unparseable lines are skipped, by the same contract
-    as ``schema.validate_jsonl``)."""
+    as ``schema.validate_jsonl``). The live sink's buffered lines are
+    written out first, in case ``path`` is that file."""
+    _recorder.flush()
     events = []
     with open(path) as f:
         for line in f:

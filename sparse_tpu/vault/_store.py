@@ -230,26 +230,15 @@ def store(kind: str, key: str, meta: dict, arrays: dict) -> bool:
         os.replace(tmp, final)
         tmp = None
         _fsync_dir(os.path.dirname(final))
-    except Exception as e:
+    except Exception:
         _COUNTERS["write_failed"].inc()
         if tmp is not None:
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-        tel = _telemetry()
-        if tel is not None:
-            tel.record(
-                "vault.store", artifact=kind, key=key, ok=False,
-                bytes=0, error=repr(e)[:200],
-            )
         return False
     _COUNTERS["writes"].inc()
-    tel = _telemetry()
-    if tel is not None:
-        tel.record(
-            "vault.store", artifact=kind, key=key, ok=True, bytes=len(blob)
-        )
     gc()  # size-budgeted LRU sweep; no-op while under the cap
     return True
 
@@ -285,9 +274,6 @@ def load(kind: str, key: str, expect: dict | None = None):
         os.utime(path, None)  # LRU touch for the mtime-ordered GC sweep
     except OSError:
         pass
-    tel = _telemetry()
-    if tel is not None:
-        tel.record("vault.load", artifact=kind, key=key, hit=True)
     return out
 
 
@@ -396,10 +382,6 @@ def gc(cap_mb: float | None = None, dry_run: bool = False) -> int:
         evicted += 1
         _COUNTERS["evictions"].inc()
     _SIZE_GAUGE.set(max(total, 0))
-    tel = _telemetry()
-    if evicted and tel is not None:
-        tel.record("vault.gc", evicted=evicted, bytes=int(total),
-                   cap_mb=cap, dry_run=bool(dry_run))
     return evicted
 
 

@@ -238,6 +238,7 @@ def test_events_carry_identity_and_session_start(tel):
     ev = telemetry.events("solver.solve")[-1]
     assert ev["pi"] == ident["pi"] and ev["pid"] == ident["pid"]
     assert isinstance(ev["tm"], float) and ev["tm"] >= 0.0
+    telemetry.flush()  # the sink is buffered: a reader flushes first
     lines = [json.loads(ln) for ln in open(telemetry.sink_path())]
     assert lines[0]["kind"] == "session.start"
     assert lines[0]["epoch"] > 0 and lines[0]["mono"] >= 0
@@ -257,6 +258,7 @@ def test_multi_controller_sink_splits_per_pid(tmp_path):
     telemetry.configure(str(tmp_path / "records.jsonl"))
     try:
         telemetry.record("span", name="x", dur_s=0.01)
+        telemetry.flush()
         path = telemetry.sink_path()
         assert path.endswith(f"records.{os.getpid()}.jsonl")
         assert os.path.exists(path)

@@ -389,6 +389,7 @@ def test_ingest_from_mtx_path(tmp_path, tel):
     for kind in ("ingest.arrive", "ingest.sort", "ingest.dedup",
                  "ingest.onboard"):
         assert kind in _schema.KINDS
+    telemetry.flush()
     events = [json.loads(ln) for ln in tel.read_text().splitlines()]
     ingest_events = [e for e in events if e["kind"].startswith("ingest.")]
     kinds = {e["kind"] for e in ingest_events}

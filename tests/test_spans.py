@@ -662,3 +662,267 @@ def test_off_the_session_keeps_no_account_and_reads_no_clock_for_it(
         0, None, None, 0)
     assert acct.inside == acct.submit == acct.spanned == 0.0
     assert telemetry.events() == []
+
+
+# -- the call's account (PR 51) ------------------------------------------------
+# A public solver that ends in a `solver.solve` event partitions its own call
+# from inside (linalg._CallAccount): call_ms = prep_ms + dispatch_ms + wait_ms
+# + rest_ms, and caller_ms since the thread's previous call closed.
+CALL_PARTS = ("prep_ms", "dispatch_ms", "wait_ms", "rest_ms")
+CALL_FIELDS = ("call_ms", *CALL_PARTS, "caller_ms")
+WHOLE = {"call_ms", *CALL_PARTS}
+
+
+def _account(ev):
+    return {k: ev[k] for k in CALL_FIELDS if k in ev}
+
+
+def _closure(A):
+    return linalg.LinearOperator(A.shape, matvec=A.dot, dtype=A.dtype)
+
+
+def _call_fused(monkeypatch):
+    monkeypatch.setattr(settings, "fused_cg", "force")
+    A, b = _pde()
+    A = A.tocsr()
+    return lambda: linalg.cg(A, b, maxiter=60)
+
+
+def _call_general(monkeypatch):
+    A, b = _general()
+    return lambda: linalg.cg(A, b, maxiter=40)
+
+
+def _call_declared(monkeypatch):
+    from sparse_tpu import precond
+
+    A, b = _general()
+    M = precond.make_M(A, "jacobi")
+    return lambda: linalg.cg(A, b, maxiter=40, M=M)
+
+
+def _call_gmres(monkeypatch):
+    A, b = _general()
+    return lambda: linalg.gmres(A, b, restart=8, maxiter=3)
+
+
+def _call_gmres_cycle(monkeypatch):
+    A, b = _general()
+    op = _closure(A)
+    return lambda: linalg.gmres(op, b, restart=8, maxiter=3)
+
+
+def _call_bicgstab(monkeypatch):
+    A, b = _general()
+    return lambda: linalg.bicgstab(A, b, maxiter=20)
+
+
+def _call_dist_cg(monkeypatch):
+    from sparse_tpu.parallel import dist_cg, get_mesh, shard_csr
+
+    D0, b = _pde()
+    Dd = shard_csr(D0.tocsr(), mesh=get_mesh(4))
+    return lambda: dist_cg(Dd, b, tol=0.0, maxiter=12)
+
+
+# path: (the call, the `.solve` span and its wait field, the fields it has)
+CALL_PATHS = {
+    "cg_fused": (_call_fused, ("cg.solve", "fetch_s"), WHOLE),
+    "cg_general": (_call_general, ("cg.solve", "fetch_s"), WHOLE),
+    "cg_declared_M": (_call_declared, ("cg.solve", "fetch_s"), WHOLE),
+    "gmres_device": (_call_gmres, ("gmres.solve", "fetch_s"), WHOLE),
+    # the cycle path's span sums no dispatch and no fetch
+    "gmres_cycle": (_call_gmres_cycle, ("gmres.solve", None),
+                    {"call_ms", "prep_ms", "rest_ms"}),
+    "bicgstab": (_call_bicgstab, None, {"call_ms"}),  # no `.solve` span
+    "dist_cg": (_call_dist_cg, ("dist.cg.solve", "wait_s"), WHOLE),
+}
+
+
+@pytest.mark.parametrize("path", list(CALL_PATHS))
+def test_a_call_is_its_four_parts_on_its_solve_event(tel, monkeypatch, path):
+    make, span_of, has = CALL_PATHS[path]
+    call = make(monkeypatch)
+    call()  # layout, trace, compile
+    n0 = len(telemetry.events())
+    agg0 = telemetry.summary()["spans"]["solver.call"]["n"]
+    call()
+    evs = telemetry.events()[n0:]
+    ev = evs[-1]  # the event is the last thing a call does
+    assert ev["kind"] == "solver.solve"
+    assert [e["kind"] for e in evs].count("solver.solve") == 1
+    acct = _account(ev)
+    assert set(acct) == has | {"caller_ms"}
+    assert all(v >= 0 for v in acct.values())
+    assert acct["call_ms"] > 0
+    if "rest_ms" in acct:
+        assert acct["call_ms"] == pytest.approx(
+            sum(acct.get(k, 0.0) for k in CALL_PARTS), abs=1e-6)
+    # `solver.call` is an annotation and an aggregate, no event
+    assert telemetry.summary()["spans"]["solver.call"]["n"] == agg0 + 1
+    assert not [e for e in evs if e.get("name") == "solver.call"]
+    if span_of is None:
+        return
+    name, wait = span_of
+    (solve,) = [e for e in evs if e["kind"] == "span" and e["name"] == name]
+    # prep ends where the `.solve` span starts; the span lies inside the call
+    assert solve["t0"] + solve["dur_s"] <= ev["tm"] + 1e-3
+    assert acct["prep_ms"] + solve["dur_s"] * 1e3 <= acct["call_ms"] + 2e-3
+    if wait is not None:
+        assert acct["dispatch_ms"] == pytest.approx(
+            solve["dispatch_s"] * 1e3, abs=1e-3)
+        assert acct["wait_ms"] == pytest.approx(solve[wait] * 1e3, abs=1e-3)
+    else:
+        assert "dispatch_s" not in solve
+
+
+def test_caller_ms_is_the_gap_since_the_threads_last_close(tel):
+    import threading
+
+    A, b = _general()
+    linalg.cg(A, b, maxiter=30)  # the main thread has called before
+    got = []
+
+    def client():
+        n0 = len(telemetry.events("solver.solve"))
+        linalg.cg(A, b, maxiter=30)
+        time.sleep(0.05)  # the caller's own time between two calls
+        linalg.cg(A, b, maxiter=30)
+        got.extend(telemetry.events("solver.solve")[n0:])
+
+    t = threading.Thread(target=client)
+    t.start()
+    t.join()
+    first, second = got
+    assert "caller_ms" not in first and "call_ms" in first  # a thread's first
+    assert second["caller_ms"] >= 50.0  # the pause is the caller's
+    # call + caller is the spacing of the two closes, event to event (an
+    # event's `tm` is read just after its account's close)
+    spacing = (second["tm"] - first["tm"]) * 1e3
+    assert second["call_ms"] + second["caller_ms"] == pytest.approx(
+        spacing, abs=25.0)
+
+
+def test_two_threads_keep_two_accounts(tel):
+    import threading
+
+    sides = (20, 24)
+    systems = {s * s: _general(side=s) for s in sides}
+    for A, b in systems.values():
+        linalg.cg(A, b, maxiter=30)  # compiled before the threads start
+    n0 = len(telemetry.events("solver.solve"))
+    gate = threading.Barrier(2)
+
+    def client(A, b):
+        gate.wait()
+        for _ in range(3):
+            linalg.cg(A, b, maxiter=30)
+
+    threads = [threading.Thread(target=client, args=ab)
+               for ab in systems.values()]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    evs = telemetry.events("solver.solve")[n0:]
+    assert len(evs) == 6
+    for n in systems:
+        mine = [e for e in evs if e["n"] == n]
+        assert ["caller_ms" in e for e in mine] == [False, True, True]
+        for e in mine:
+            a = _account(e)
+            assert WHOLE <= set(a)
+            assert a["call_ms"] == pytest.approx(
+                sum(a[k] for k in CALL_PARTS), abs=1e-6)
+        # a thread's calls follow one another: the other thread's are not
+        # in its gaps
+        for e0, e1 in zip(mine, mine[1:]):
+            assert e1["call_ms"] + e1["caller_ms"] == pytest.approx(
+                (e1["tm"] - e0["tm"]) * 1e3, abs=25.0)
+
+
+def test_a_solver_inside_a_solver_leaves_the_outer_account_whole(tel):
+    A, b = _general()
+    inner_calls = []
+
+    def apply(r):
+        inner_calls.append(1)
+        return linalg.cg(A, r, maxiter=3)[0]  # a public solver, nested
+
+    M = linalg.LinearOperator(A.shape, matvec=apply, dtype=A.dtype)
+    linalg.gmres(_closure(A), b, restart=4, maxiter=2, M=M)
+    n0 = len(telemetry.events())
+    inner_calls.clear()
+    linalg.gmres(_closure(A), b, restart=4, maxiter=2, M=M)
+    evs = telemetry.events()[n0:]
+    solves = [e for e in evs if e["kind"] == "solver.solve"]
+    inner, outer = solves[:-1], solves[-1]
+    assert evs[-1] is outer and outer["solver"] == "gmres"
+    assert inner and all(e["solver"] == "cg" for e in inner)
+    assert all(_account(e) == {} for e in inner)  # the inner events are bare
+    # the outer's account is of its own `gmres.solve` span, not of the
+    # nested `cg.solve` spans that closed inside it
+    acct = _account(outer)
+    assert set(acct) == {"call_ms", "prep_ms", "rest_ms", "caller_ms"}
+    (span,) = [e for e in evs if e.get("name") == "gmres.solve"]
+    assert acct["rest_ms"] >= span["dur_s"] * 1e3 - 2e-3
+    assert acct["call_ms"] == pytest.approx(
+        acct["prep_ms"] + acct["rest_ms"], abs=1e-6)
+    assert getattr(linalg._CALLS, "account").depth == 0
+
+
+def test_a_call_that_raises_leaves_the_next_one_no_caller_ms(tel):
+    A, b = _general()
+    linalg.cg(A, b, maxiter=30)
+    with pytest.raises(AssertionError):
+        linalg.cg(A, b, maxiter=30, atol=1.0)
+    acct = linalg._CALLS.account
+    assert (acct.depth, acct.call, acct.closed) == (0, None, None)
+    linalg.cg(A, b, maxiter=30)
+    assert "caller_ms" not in telemetry.events("solver.solve")[-1]
+    linalg.cg(A, b, maxiter=30)
+    assert "caller_ms" in telemetry.events("solver.solve")[-1]
+
+
+def test_under_jit_the_account_is_inert(tel):
+    A, b = _general()
+    linalg.cg(A, b, maxiter=30)
+    acct = linalg._CALLS.account
+    closed = acct.closed
+    seen = []
+
+    @jax.jit
+    def traced(v):
+        with linalg._solver_call() as scope:
+            seen.append(scope)
+            linalg._call_solved(telemetry.span("cg.solve"), 1.0, 1.0)
+        return v + 1
+
+    traced(np.ones(4))
+    assert seen == [_NULL]
+    assert (acct.depth, acct.call, acct.closed) == (0, None, closed)
+
+
+def test_off_a_solve_keeps_no_account_and_reads_no_clock(off, monkeypatch):
+    """Telemetry off: a library solve reads ``telemetry.clock`` as often as
+    it did before the account (never), gets the shared no-op for its call's
+    scope and makes no account."""
+    from sparse_tpu.parallel import dist_cg, get_mesh, shard_csr
+
+    reads = []
+    counting = lambda: reads.append(1) or time.monotonic()  # noqa: E731
+    monkeypatch.setattr(telemetry, "clock", counting)
+    monkeypatch.setattr(_recorder, "clock", counting)
+    monkeypatch.setattr(linalg, "_CALLS", type(linalg._CALLS)())
+    assert linalg._solver_call() is _NULL
+    A, b = _general()
+    D0, bd = _pde()
+    Dd = shard_csr(D0.tocsr(), mesh=get_mesh(4))
+    linalg.cg(A, b, maxiter=30)
+    linalg.gmres(A, b, restart=8, maxiter=2)
+    linalg.gmres(_closure(A), b, restart=8, maxiter=2)
+    linalg.bicgstab(A, b, maxiter=10)
+    dist_cg(Dd, bd, tol=0.0, maxiter=12)
+    assert reads == []
+    assert getattr(linalg._CALLS, "account", None) is None
+    assert telemetry.events() == []

@@ -1204,3 +1204,89 @@ def test_metrics_family_readback():
     assert sum(h.count for h in fam) == 2
     M.remove("wd.fam.test")
     assert M.family("wd.fam.test") == []
+
+
+# -- the buffered sink and the ring's default (PR 51) ---------------------------
+def _sink_lines(path):
+    return [json.loads(ln) for ln in open(path)] if path.exists() else []
+
+
+def _ticks(lines):
+    return [e["i"] for e in lines if e["kind"] == "custom.tick"]
+
+
+@pytest.mark.parametrize("how", ["flush", "configure", "lines", "a_second",
+                                 "a_reader"])
+def test_buffered_lines_reach_the_file(tel, tmp_path, monkeypatch, how):
+    """An event's line gathers in the sink's buffer: a ``record`` flushes
+    nothing until 256 lines have gathered or a second has passed since the
+    last flush; ``flush()``, ``configure()`` and the package's own readers
+    of a sink write the buffer out."""
+    from sparse_tpu.telemetry import _recorder
+
+    now = [1000.0]
+    monkeypatch.setattr(_recorder, "clock", lambda: now[0])
+    for i in range(10):
+        telemetry.record("custom.tick", i=i)
+    assert _sink_lines(tel) == []  # buffered, and the ring has them
+    assert len(telemetry.events("custom.tick")) == 10
+    if how == "flush":
+        telemetry.flush()
+    elif how == "configure":
+        telemetry.configure(str(tmp_path / "elsewhere.jsonl"))
+    elif how == "lines":
+        n = _recorder._FLUSH_LINES
+        for i in range(10, n - 1):
+            telemetry.record("custom.tick", i=i)
+        assert _sink_lines(tel) == []
+        telemetry.record("custom.tick", i=n - 1)  # the 256th event's line
+        assert _ticks(_sink_lines(tel)) == list(range(n))
+        telemetry.record("custom.tick", i=n)  # the next 256 start here
+        assert _ticks(_sink_lines(tel)) == list(range(n))
+        return
+    elif how == "a_second":
+        now[0] += _recorder._FLUSH_SECONDS - 0.01
+        telemetry.record("custom.tick", i=10)
+        assert _sink_lines(tel) == []
+        now[0] += 0.01  # tested at a record: no timer thread
+        assert _sink_lines(tel) == []
+        telemetry.record("custom.tick", i=11)
+        assert _ticks(_sink_lines(tel)) == list(range(12))
+        return
+    else:
+        assert telemetry.schema.validate_jsonl(str(tel)) == []
+    lines = _sink_lines(tel)
+    assert lines[0]["kind"] == "session.start"
+    assert _ticks(lines) == list(range(10))
+
+
+def test_buffered_lines_reach_the_file_at_exit(tmp_path):
+    sink = tmp_path / "records.jsonl"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", SPARSE_TPU_TELEMETRY="1",
+               SPARSE_TPU_TELEMETRY_PATH=str(sink))
+    code = ("from sparse_tpu import telemetry\n"
+            "for i in range(3):\n"
+            "    telemetry.record('custom.tick', i=i)\n"
+            "assert open(telemetry.sink_path()).read() == ''\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert _ticks(_sink_lines(sink)) == [0, 1, 2]
+
+
+def test_the_rings_default_holds_a_traced_window(tel, monkeypatch):
+    """docs/telemetry.md, Enabling: 16,384 events, over twice the fullest traced
+    window of the benchmark's cells; nothing is dropped under it."""
+    from sparse_tpu.config import Settings
+
+    monkeypatch.delenv("SPARSE_TPU_TELEMETRY_RING", raising=False)
+    assert Settings().telemetry_ring == 16384
+    monkeypatch.setattr(settings, "telemetry_ring", 16384)
+    telemetry.reset()
+    doc = open(os.path.join(REPO, "docs", "telemetry.md")).read()
+    assert "| `SPARSE_TPU_TELEMETRY_RING` | in-memory event ring capacity | 16384" in doc
+    for i in range(16384):
+        telemetry.record("custom.tick", i=i)
+    assert telemetry.dropped() == 0 and len(telemetry.events()) == 16384
+    telemetry.record("custom.tick", i=-1)
+    assert telemetry.dropped() == 1
