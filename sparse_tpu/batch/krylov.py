@@ -345,10 +345,11 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
     same basis layout a row to a tile with the lane axis in front (``[B,
     restart + 1, R, 128]``; ``linalg._basis_*``), and ONE step counter ``j``
     for the bucket. A lane that converges, breaks down or starts at its
-    target is ``done``: its Hessenberg, rotations, right-hand side and
-    column count ``kk`` freeze under the mask while ``j`` finishes the
-    bucket's last lane (the loop ends at ``restart`` or when every lane is
-    done). The stage ``V[:, :his[j // block]]`` of ``linalg._orth_stages``
+    target is ``done``: its Hessenberg, the matrix ``Q`` of its accumulated
+    rotations (so its right-hand side, ``beta Q[:, 0]``) and its column
+    count ``kk`` freeze under the mask while ``j`` finishes the bucket's
+    last lane (the loop ends at ``restart`` or when every lane is done).
+    The stage ``V[:, :his[j // block]]`` of ``linalg._orth_stages``
     is chosen by ``j``, one ``lax.switch`` index for the bucket: a ``vmap``
     of the library's loop would turn its carry into a select over the whole
     basis and run every stage. One basis row is written a step and lane, in
@@ -358,10 +359,9 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
 
     ``(V, H, g, kk, breakdown)``: per lane what the library's gives."""
     from ..linalg import (_basis_flat, _basis_tiles, _givens_column,
-                          _orth_against, _orth_stages)
+                          _givens_rhs, _orth_against, _orth_stages)
 
     dt = R.dtype
-    rdt = jnp.zeros((), dt).real.dtype
     B, n = R.shape
     with jax.named_scope("bucket.gmres.update"):
         start_ok = beta > target
@@ -370,21 +370,20 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
         V = jnp.zeros((B, restart + 1, *v0.shape[1:]), dtype=dt)
         V = V.at[:, 0].set(v0)
     H = jnp.zeros((B, restart + 1, restart), dtype=dt)
-    cs = jnp.zeros((B, restart), dtype=rdt)
-    sn = jnp.zeros((B, restart), dtype=dt)
-    g = jnp.zeros((B, restart + 1), dtype=dt).at[:, 0].set(beta.astype(dt))
+    Q = jnp.broadcast_to(jnp.eye(restart + 1, dtype=dt),
+                         (B, restart + 1, restart + 1))
 
     block, his = _orth_stages(restart)
     stages = [partial(_orth_against, hi=hi, restart=restart) for hi in his]
     # the step's scalars are a lane's own; the column index is the bucket's
-    givens = jax.vmap(_givens_column, in_axes=(0, 0, 0, 0, 0, 0, None, 0))
+    givens = jax.vmap(_givens_column, in_axes=(0, 0, 0, 0, 0, None, 0))
 
     def cond(st):
-        done, j = st[7], st[8]
+        done, j = st[5], st[6]
         return (j < restart) & jnp.any(~done)
 
     def body(st):
-        V, H, cs, sn, g, kk, bd, done, j = st
+        V, H, Q, kk, bd, done, j = st
         with jax.named_scope("bucket.gmres.spmv"):
             vj = jax.lax.dynamic_index_in_dim(V, j, 1, keepdims=False)
             w = _basis_tiles(Mv(mv(_basis_flat(vj, n))))
@@ -401,21 +400,21 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
                 V, jnp.where(grew[:, None, None], w / scale, 0.0
                              ).astype(dt)[:, None], j + 1, 1)
         with jax.named_scope("bucket.gmres.small"):
-            Hn, csn, snn, gn, breakdown, conv = givens(
-                hcol, hkk, H, cs, sn, g, j, target)
+            Hn, Qn, breakdown, conv = givens(
+                hcol, hkk, H, Q, beta, j, target)
             upd = ~done
             H = jnp.where(upd[:, None, None], Hn, H)
-            cs = jnp.where(upd[:, None], csn, cs)
-            sn = jnp.where(upd[:, None], snn, sn)
-            g = jnp.where(upd[:, None], gn, g)
+            Q = jnp.where(upd[:, None, None], Qn, Q)
             kk = kk + (upd & ~breakdown).astype(jnp.int32)
             bd = bd | (upd & breakdown)
             done = done | (upd & (breakdown | conv))
-        return V, H, cs, sn, g, kk, bd, done, j + 1
+        return V, H, Q, kk, bd, done, j + 1
 
-    st = (V, H, cs, sn, g, jnp.zeros((B,), jnp.int32),
+    st = (V, H, Q, jnp.zeros((B,), jnp.int32),
           jnp.zeros((B,), bool), ~start_ok, jnp.int32(0))
-    V, H, _cs, _sn, g, kk, bd, _done, _j = jax.lax.while_loop(cond, body, st)
+    V, H, Q, kk, bd, _done, _j = jax.lax.while_loop(cond, body, st)
+    with jax.named_scope("bucket.gmres.small"):
+        g = jax.vmap(_givens_rhs)(Q, beta)
     return V, H, g, kk, bd
 
 

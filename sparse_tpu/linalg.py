@@ -1860,9 +1860,11 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
     ``gmres.spmv`` (the operator's and the preconditioner's applies),
     ``gmres.orth`` (the four contractions against the basis and the updates
     of w between them, the sum of squares of what is left), ``gmres.small``
-    (scalars: the norm's root, the Givens rotations, the Hessenberg column,
-    the triangular solve) and ``gmres.update`` (the basis row's write with
-    the division by the norm inside it, x += V y, the cycle's residual)."""
+    (scalars: the norm's root, the Hessenberg column through the matrix of
+    the accumulated Givens rotations, the new rotation, the rotated
+    right-hand side, the triangular solve) and ``gmres.update`` (the basis
+    row's write with the division by the norm inside it, x += V y, the
+    cycle's residual)."""
     with jax.named_scope("gmres.spmv"):
         ax = matvec(x)
     with jax.named_scope("gmres.update"):
@@ -1990,31 +1992,26 @@ def _orth_against(V, w, k, *, hi: int, restart: int):
     return jnp.pad(hcol + h2, pad), w, ww
 
 
-def _givens_column(hcol, hkk, H, cs, sn, g, k, target):
+def _givens_column(hcol, hkk, H, Q, beta, k, target):
     """The scalars of step ``k`` for ONE system (the session's lanes map it,
-    ``jax.vmap``): the new Hessenberg column ``hcol`` with ``hkk`` under it
-    through the ``k`` accumulated Givens rotations, the new rotation, the
-    rotated right-hand side. ``(H, cs, sn, g, breakdown, conv)`` with column
-    ``k`` of ``H`` and entry ``k`` of ``cs``/``sn`` written; ``g`` is left as
-    it was on a breakdown; ``conv`` is the recurrence's residual
-    ``|g[k + 1]|`` under ``target``."""
-    restart = cs.shape[0]
+    ``jax.vmap``): the new Hessenberg column ``hcol [restart + 1]`` (zero
+    past ``k``) with ``hkk`` under it through the ``k`` accumulated Givens
+    rotations, then the new rotation. The accumulated rotations are carried
+    as their product ``Q [restart + 1, restart + 1] = G_{k-1} ... G_0`` (the
+    identity at the start of a cycle; rows past ``k`` are the identity's
+    still), so the column through them is one multiply-and-sum whatever
+    ``k``, and the new rotation turns rows ``k`` and ``k + 1`` of ``Q``. The
+    rotated right-hand side is ``beta Q[:, 0]`` (:func:`_givens_rhs`), no
+    recurrence of its own. ``(H, Q, breakdown, conv)`` with column ``k`` of
+    ``H`` written; ``Q`` is left as it was on a breakdown; ``conv`` is the
+    recurrence's residual ``|g[k + 1]|`` under ``target``."""
     dt = H.dtype
-    # (a select, not a scatter: the TPU compiler then keeps the column in
-    # fast memory through the rotations' inner loop)
-    col = jnp.where(jnp.arange(restart + 1) == k + 1, hkk.astype(dt), hcol)
-
-    # apply the k accumulated Givens rotations (masked fori — [restart]^2
-    # scalars, exactly the lax.fori_loop case)
-    def giv(i, c):
-        t = cs[i] * c[i] + sn[i] * c[i + 1]
-        bt = -jnp.conj(sn[i]) * c[i] + cs[i] * c[i + 1]
-        app = i < k
-        c = c.at[i].set(jnp.where(app, t, c[i]))
-        return c.at[i + 1].set(jnp.where(app, bt, c[i + 1]))
-
-    col = jax.lax.fori_loop(0, restart, giv, col)
-    hk, hk1 = col[k], col[k + 1]
+    idx = jnp.arange(Q.shape[0])
+    # a multiply and a sum, as the contractions against the basis: no ``dot``,
+    # nothing for a matrix unit's lower-precision pass. Row k + 1 of Q is
+    # e_{k+1}: the entry under the column's diagonal passes through as it is
+    col = jnp.sum(Q * hcol[None, :], axis=1)
+    hk, hk1 = col[k], hkk.astype(dt)
     ahk = jnp.abs(hk)
     ahk1 = jnp.abs(hk1)
     denom = jnp.sqrt(ahk * ahk + ahk1 * ahk1)
@@ -2028,15 +2025,26 @@ def _givens_column(hcol, hkk, H, cs, sn, g, k, target):
         jnp.conj(hk1) / jnp.where(ahk1 == 0, 1.0, ahk1),
         hk_unit * jnp.conj(hk1) / denom_s,
     )
-    col = col.at[k].set(ck * hk + sk * hk1)
-    col = col.at[k + 1].set(0.0)
+    # (selects, not scatters: PR 47)
+    col = jnp.where(idx < k, col, jnp.where(idx == k, ck * hk + sk * hk1, 0.0))
     H = H.at[:, k].set(col)
-    cs = cs.at[k].set(ck.real)
-    sn = sn.at[k].set(sk)
-    gk1 = -jnp.conj(sk) * g[k]
-    g = g.at[k + 1].set(jnp.where(breakdown, g[k + 1], gk1))
-    g = g.at[k].set(jnp.where(breakdown, g[k], ck * g[k]))
-    return H, cs, sn, g, breakdown, jnp.abs(gk1) < target
+    # rows k and k + 1 of Q take the rotation: a select over the row index
+    # on the whole matrix, one fusion
+    qk = Q[k]
+    ek1 = (idx == k + 1).astype(dt)
+    row = idx[:, None]
+    Qn = jnp.where(row == k, (ck * qk + sk * ek1)[None, :],
+                   jnp.where(row == k + 1,
+                             (-jnp.conj(sk) * qk + ck * ek1)[None, :], Q))
+    gk1 = -jnp.conj(sk) * (beta * qk[0])
+    return H, jnp.where(breakdown, Q, Qn), breakdown, jnp.abs(gk1) < target
+
+
+def _givens_rhs(Q, beta):
+    """The rotated right-hand side of ONE system's cycle, ``g [restart + 1]``:
+    ``beta e_0`` through the accumulated rotations ``Q`` of
+    :func:`_givens_column`."""
+    return beta.astype(Q.dtype) * Q[:, 0]
 
 
 def _hessenberg_solve(H, g, k):
@@ -2076,12 +2084,14 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     zero, so leaving them out changes no term of any sum.
 
     The reference keeps its Hessenberg recurrences asynchronous via futures
-    (linalg.py:670-795); here the [restart]^2 scalar Givens/Hessenberg math
-    runs in ``lax`` control flow INSIDE the compiled cycle — beaten, not
-    tied: zero mid-cycle host round trips (the old implementation paid 2
-    device->host fetches per Arnoldi stage, each far above a kernel)."""
+    (linalg.py:670-795); here the [restart]^2 Givens/Hessenberg math runs
+    INSIDE the compiled cycle — beaten, not tied: zero mid-cycle host round
+    trips (the old implementation paid 2 device->host fetches per Arnoldi
+    stage, each far above a kernel). The loop carries the rotations a cycle
+    has made as their product ``Q`` (:func:`_givens_column`): a step's
+    scalars are a handful of ops on ``[restart + 1]^2`` elements, no inner
+    loop over rotations, and ``g`` is read off ``Q`` when the loop is done."""
     dt = r.dtype
-    rdt = jnp.zeros((), dt).real.dtype
     n = r.shape[0]
     with jax.named_scope("gmres.update"):
         start_ok = beta > target
@@ -2089,20 +2099,18 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
         v0 = _basis_tiles(r / beta_safe)
         V = jnp.zeros((restart + 1, *v0.shape), dtype=dt).at[0].set(v0)
     H = jnp.zeros((restart + 1, restart), dtype=dt)
-    cs = jnp.zeros((restart,), dtype=rdt)
-    sn = jnp.zeros((restart,), dtype=dt)
-    g = jnp.zeros((restart + 1,), dtype=dt).at[0].set(beta.astype(dt))
+    Q = jnp.eye(restart + 1, dtype=dt)
 
     block, his = _orth_stages(restart)
     stages = [functools.partial(_orth_against, hi=hi, restart=restart)
               for hi in his]
 
     def cond(st):
-        _V, _H, _cs, _sn, _g, k, done, _bd = st
+        _V, _H, _Q, k, done, _bd = st
         return (k < restart) & ~done
 
     def body(st):
-        V, H, cs, sn, g, k, done, bd = st
+        V, H, Q, k, done, bd = st
         with jax.named_scope("gmres.spmv"):
             w = _basis_tiles(precond(matvec(_basis_flat(V[k], n))))
         with jax.named_scope("gmres.orth"):
@@ -2118,18 +2126,17 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
                 V, jnp.where(grew, w / jnp.where(grew, hkk, 1.0), 0.0
                              ).astype(dt), k + 1, 0)
         with jax.named_scope("gmres.small"):
-            H, cs, sn, g, breakdown, conv = _givens_column(
-                hcol, hkk, H, cs, sn, g, k, target)
+            H, Q, breakdown, conv = _givens_column(
+                hcol, hkk, H, Q, beta, k, target)
             k_next = jnp.where(breakdown, k, k + 1)
-            return (
-                V, H, cs, sn, g, k_next, done | breakdown | conv,
-                bd | breakdown,
-            )
+            return V, H, Q, k_next, done | breakdown | conv, bd | breakdown
 
-    V, H, cs, sn, g, k, _done, bdown = jax.lax.while_loop(
+    V, H, Q, k, _done, bdown = jax.lax.while_loop(
         cond, body,
-        (V, H, cs, sn, g, jnp.int32(0), ~start_ok, jnp.bool_(False)),
+        (V, H, Q, jnp.int32(0), ~start_ok, jnp.bool_(False)),
     )
+    with jax.named_scope("gmres.small"):
+        g = _givens_rhs(Q, beta)
     return V, H, g, k, bdown
 
 

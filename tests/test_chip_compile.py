@@ -871,14 +871,21 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip, monkeypatch):
     in_scope = [ln for ln in body.splitlines() if "gmres.spmv/" in ln
                 and re.search(r" (fusion|custom-call|pad|slice|copy)\(", ln)]
     assert len(in_scope) == 3, in_scope
-    # the rotations' inner loop carries its Hessenberg column in fast memory
-    # (`S(1)`), as it does the rotations: made by a scatter and not a select
-    # the column was left in HBM and a trip's six ops read 3.96 us where 2.23
-    # (my chip runs, PR 47)
-    (givens,) = [ln for ln in body.splitlines()
-                 if re.search(r" while\([^\n]*gmres\.small/while", ln)]
-    carried = givens[:givens.index(" while(")]
-    assert re.findall(r"f32\[3[01]\]\{0:T\(128\)(S\(1\))?\}", carried) == ["S(1)"] * 3
+    # the accumulated rotations are one matrix among the Arnoldi loop's
+    # carried values (PR 52), not lists an inner loop walks: no `while` under
+    # the scalars' scope in the body (the 30-trip loop the tree had there was
+    # 181 of a step's 771 us: my chip runs, PR 50), the column through them
+    # one multiply-and-sum over the matrix, read from fast memory
+    assert "f32[31,31]" in body.lstrip().splitlines()[0]  # its signature
+    assert not [ln for ln in body.splitlines()
+                if re.search(r" while\([^\n]*gmres\.small/", ln)]
+    (through,) = [computations[_called(rest)] for _name, _res, rest
+                  in _fusions(body) if re.search(
+                      r'op_name="[^"]*gmres\.small/[^"]*reduce_sum"', rest)]
+    assert re.search(r"= f32\[31,31\]\{1,0:T\(8,128\)S\(1\)\} parameter\(",
+                     through)
+    assert " multiply(" in through and re.search(r"f32\[31\]\S* reduce\(",
+                                                 through)
     # the four contractions against the basis run in float32 on the vector
     # unit: nothing for the MXU's default bfloat16 pass to touch
     assert "convolution" not in text and "bf16" not in text
@@ -1050,6 +1057,11 @@ def test_bucket_gmres_program_writes_one_row_a_lane_and_copies_no_basis(
     # float32 on the vector unit: nothing for the MXU's bfloat16 pass
     assert "convolution" not in text and "bf16" not in text
     assert not re.search(r"\bdot\(", text)
+    # every lane's accumulated rotations one matrix among the Arnoldi loop's
+    # carried values, and no loop over them under the scalars' scope (PR 52)
+    assert f"f32[{B},31,31]" in body.lstrip().splitlines()[0]  # its signature
+    assert not [ln for ln in body.splitlines()
+                if re.search(r" while\([^\n]*bucket\.gmres\.small/", ln)]
     # the restarts are inside: a while over cycles around the Arnoldi while
     assert "while/body/while/body/bucket.gmres.orth" in text
     # nothing of a dispatch's values is folded into the program

@@ -615,3 +615,154 @@ def test_a_restart_of_one_stage_has_no_conditional(restart, staged):
     A, b = _box((6, 5, 4))
     text = linalg._gmres_compiled(A, b, restart).as_text()
     assert len(re.findall(r" conditional\(", text)) == int(staged)
+
+
+# -- the accumulated rotations as one matrix (PR 52) -----------------------------------------
+def _rotations_reference(cols, beta):
+    """The Givens QR of a Hessenberg given column by column (``cols[k]`` its
+    ``k + 2`` entries), the rotations applied one after another in float64
+    or complex128 numpy: nothing of the code under test. After each column
+    ``(H's column, g, breakdown)``; a breakdown leaves ``g`` as it was and is
+    the last step."""
+    m = len(cols)
+    wide = np.result_type(cols[0].dtype, np.float64)
+    cs, sn = np.zeros(m), np.zeros(m, wide)
+    g = np.zeros(m + 1, wide)
+    g[0] = beta
+    steps = []
+    for k, entries in enumerate(cols):
+        col = np.zeros(m + 1, wide)
+        col[:k + 2] = entries
+        for i in range(k):
+            col[i], col[i + 1] = (cs[i] * col[i] + sn[i] * col[i + 1],
+                                  -np.conj(sn[i]) * col[i] + cs[i] * col[i + 1])
+        a0, a1 = abs(col[k]), abs(col[k + 1])
+        denom = np.hypot(a0, a1)
+        if denom == 0:
+            steps.append((col, g.copy(), True))
+            break
+        cs[k] = a0 / denom
+        sn[k] = ((col[k] / a0 if a0 else 1.0) * np.conj(col[k + 1])
+                 / (denom if a0 else a1))
+        col[k], col[k + 1] = cs[k] * col[k] + sn[k] * col[k + 1], 0.0
+        g[k], g[k + 1] = cs[k] * g[k], -np.conj(sn[k]) * g[k]
+        steps.append((col, g.copy(), False))
+    return steps
+
+
+def _hessenberg_columns(m, dtype, seed=11):
+    rng = np.random.default_rng(seed)
+    cols = [rng.uniform(-1, 1, k + 2) for k in range(m)]
+    if np.issubdtype(dtype, np.complexfloating):
+        cols = [c + 1j * rng.uniform(-1, 1, c.size) for c in cols]
+    for c in cols:
+        c[-1] = abs(c[-1]) + 0.1  # the entry under the diagonal: a norm
+    return [c.astype(dtype) for c in cols]
+
+
+def _givens_steps(cols, beta, target=0.0):
+    """``linalg._givens_column`` over the columns, one compiled step with the
+    index traced as the Arnoldi loop has it: after each step ``(H, Q, g,
+    breakdown, conv)`` on the host."""
+    m, dt = len(cols), cols[0].dtype
+    step = jax.jit(linalg._givens_column)
+    H, Q = jnp.zeros((m + 1, m), dt), jnp.eye(m + 1, dtype=dt)
+    beta = jnp.asarray(beta, np.zeros((), dt).real.dtype)
+    target = jnp.asarray(target, beta.dtype)
+    out = []
+    for k, entries in enumerate(cols):
+        hcol = np.zeros(m + 1, dt)
+        hcol[:k + 1] = entries[:k + 1]
+        H, Q, bd, conv = step(jnp.asarray(hcol), jnp.asarray(entries[k + 1].real),
+                              H, Q, beta, jnp.int32(k), target)
+        out.append((np.asarray(H), np.asarray(Q),
+                    np.asarray(linalg._givens_rhs(Q, beta)), bool(bd), bool(conv)))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+@pytest.mark.parametrize("restart", [1, 3, 30, 200])
+@pytest.mark.parametrize("zero", ["random", "zero-first", "zero-last"])
+def test_the_rotations_as_one_matrix_are_the_rotations_one_by_one(
+        restart, dtype, zero):
+    """Every step of a cycle against the plain reference: the Hessenberg's
+    column, the rotated right-hand side, ``Q`` unitary with the rows past the
+    step the identity's still. ``zero``: at the cycle's first or last step
+    the column's diagonal entry comes out of the accumulated rotations
+    exactly zero (c = 0: the rotation swaps the two rows)."""
+    m = restart
+    cols = _hessenberg_columns(m, dtype)
+    zero_at = {"random": None, "zero-first": 0, "zero-last": m - 1}[zero]
+    if zero_at is not None:
+        # the rotations are unitary: zero above the norm before them is zero
+        # above it after them
+        cols[zero_at][:-1] = 0
+    beta = 1.7
+    ref = _rotations_reference(cols, beta)
+    got = _givens_steps(cols, beta)
+    assert len(ref) == len(got) == m
+    eps = float(np.finfo(np.zeros((), dtype).real.dtype).eps)
+    scale = max(np.abs(c).max() for c in cols) * np.sqrt(m + 1)
+    for k, ((rcol, rg, rbd), (H, Q, g, bd, conv)) in enumerate(zip(ref, got)):
+        assert H.dtype == Q.dtype == g.dtype == dtype
+        assert (bd, conv, rbd) == (False, False, False)
+        tol = 8 * eps * np.sqrt(k + 2)
+        assert np.abs(H[:, k] - rcol).max() <= tol * scale, k
+        assert np.abs(g - rg).max() <= tol * beta, k
+        assert not H[k + 1:, k].any() and not H[:, k + 1:].any()
+        Q64 = Q.astype(np.complex128)
+        assert np.abs(Q64 @ Q64.conj().T - np.eye(m + 1)).max() <= tol
+        assert np.array_equal(Q[k + 2:], np.eye(m + 1, dtype=dtype)[k + 2:])
+        if k == zero_at:
+            assert H[k, k] == pytest.approx(abs(cols[k][-1])) and g[k] == 0
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+def test_a_breakdown_leaves_the_rotations_and_the_right_hand_side(dtype):
+    """``denom == 0`` at the fourth step: the flag, ``Q`` and so ``g`` to the
+    bit what the third step left; through the Arnoldi loop the step counter
+    stays (a nilpotent shift: the Krylov space closes after three steps)."""
+    m = 6
+    cols = _hessenberg_columns(m, dtype)
+    cols[3][:] = 0
+    ref = _rotations_reference(cols, 2.0)
+    got = _givens_steps(cols, 2.0)[:4]
+    assert [s[2] for s in ref] == [False, False, False, True]
+    (_H2, Q2, g2, bd2, _c2), (_H3, Q3, g3, bd3, _c3) = got[2], got[3]
+    assert (bd2, bd3) == (False, True)
+    assert np.array_equal(Q3, Q2) and np.array_equal(g3, g2)
+    assert np.abs(g3 - ref[3][1]).max() <= 1e-6 * 2.0
+    n, stop = 40, 3
+    phase = (0.6 + 0.8j) if np.issubdtype(dtype, np.complexfloating) else 1.0
+    A = sparse_tpu.csr_array(sp.diags([np.full(n - 1, phase, dtype)], [1],
+                                      format="csr"))
+    b = jnp.zeros(n, dtype).at[stop].set(2.0)
+    beta = jnp.linalg.norm(b)
+    _V, H, g, k, bd = linalg._gmres_arnoldi(
+        linalg.make_linear_operator(A).matvec, lambda v: v, b, beta,
+        jnp.asarray(1e-30, beta.dtype), m)
+    assert (int(k), bool(bd)) == (stop, True)
+    # the Hessenberg is the shift's: a one under each zero diagonal entry,
+    # every rotation a swap; the fourth column is empty
+    shift = [np.eye(j + 2, dtype=dtype)[j + 1] for j in range(stop)]
+    shift += [np.zeros(j + 2, dtype) for j in range(stop, m)]
+    ref = _rotations_reference(shift, 2.0)
+    assert [s[2] for s in ref] == [False] * stop + [True]
+    assert np.abs(np.asarray(g) - ref[stop][1]).max() <= 1e-6 * 2.0
+    assert abs(ref[stop][1][stop]) == 2.0
+    assert np.allclose(np.abs(np.asarray(H)[:stop, :stop]), np.eye(stop))
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["float32", "complex64"])
+def test_the_recurrences_residual_under_the_target_is_conv(dtype):
+    m, beta = 8, 3.0
+    cols = _hessenberg_columns(m, dtype)
+    history = [abs(g[k + 1]) for k, (_c, g, _b) in
+               enumerate(_rotations_reference(cols, beta))]
+    assert all(a > b for a, b in zip(history, history[1:]))
+    target = np.sqrt(history[4] * history[5])  # between two steps'
+    assert [s[4] for s in _givens_steps(cols, beta, target)] == \
+        [False] * 5 + [True] * (m - 5)

@@ -309,3 +309,52 @@ def test_the_public_function_runs_the_same_loop_and_no_jit_of_its_own():
         assert int(np.asarray(info.iters)[k]) == int(iters)
         np.testing.assert_allclose(np.asarray(X)[k], x, rtol=2e-5, atol=2e-6)
     assert bool(np.all(np.asarray(info.converged)))
+
+
+def test_a_done_lane_keeps_its_hessenberg_rotations_and_rhs_to_the_bit(
+        monkeypatch):
+    """A lane whose recurrence reaches its target at the fifth step, beside a
+    lane that ends with it and beside one that runs the cycle out: the same
+    program on the same shapes, so what the first lane holds may differ by
+    the steps ``j`` made after it was done and by nothing else. ``H``, ``g``,
+    ``kk`` and (returned in ``g``'s place by a patched ``_givens_rhs``) the
+    accumulated rotations ``Q`` are the same bits."""
+    n, m, stop = 200, 12, 5
+    rng = np.random.default_rng(31)
+    A = jnp.asarray((rng.uniform(-1, 1, (n, n)) / np.sqrt(n)
+                     + 2.0 * np.eye(n)).astype(np.float32))
+    r = jnp.asarray(rng.uniform(0.5, 1.5, (2, n)).astype(np.float32))
+    mv = lambda X: jnp.sum(A[None] * X[:, None, :], axis=-1)  # noqa: E731
+    ident = lambda X: X  # noqa: E731
+
+    def lanes(R, target):
+        beta = jnp.linalg.norm(R, axis=-1)
+        return krylov._gmres_arnoldi_lanes(
+            mv, ident, R, beta, jnp.asarray(target, jnp.float32), m)
+
+    # the first lane's residuals by step: g[k + 1] as each step leaves it
+    whole = lanes(r[:1], [0.0])
+    assert int(whole[3][0]) == m
+    y = np.abs(np.asarray(whole[2][0], np.float64))
+    history = np.sqrt(np.cumsum((y ** 2)[::-1])[::-1])[1:]  # |g[k+1]| then
+    assert all(a > b for a, b in zip(history, history[1:]))
+    target = float(np.sqrt(history[stop - 1] * history[stop - 2]))
+
+    for g_is_q in (False, True):
+        with monkeypatch.context() as mp:
+            if g_is_q:
+                mp.setattr(linalg, "_givens_rhs", lambda Q, beta: Q)
+            pair = lanes(jnp.stack([r[0], r[0]]), [target, target])
+            mixed = lanes(r, [target, 0.0])
+        assert [int(k) for k in pair[3]] == [stop, stop]
+        assert [int(k) for k in mixed[3]] == [stop, m]  # j ran on
+        assert not np.asarray(mixed[4]).any()
+        for got, kept in zip(mixed[1:3], pair[1:3]):  # H; g or Q
+            assert got.shape[1] == m + 1
+            assert np.array_equal(np.asarray(got[0]), np.asarray(kept[0]))
+            assert np.asarray(got[0]).any()
+        if g_is_q:
+            Q = np.asarray(mixed[2])
+            assert Q.shape == (2, m + 1, m + 1)
+            assert np.array_equal(Q[0, stop + 1:], np.eye(m + 1)[stop + 1:])
+            assert not np.array_equal(Q[1, stop + 1:], np.eye(m + 1)[stop + 1:])
