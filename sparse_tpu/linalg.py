@@ -1188,6 +1188,27 @@ def _operator_fields(A, M) -> dict:
     return fields
 
 
+def _shared_space(a_apply, m_apply):
+    """The order of the unknowns that a declared pair multiplies in, where
+    it is not the caller's: ``a_apply.space`` (``enter(v)`` and ``leave(v)``,
+    pure maps of a vector into that order and out of it, a permutation so
+    that norms and inner products are the caller's), if the preconditioner
+    multiplies in the same one or is the identity. Such an ``apply`` takes
+    and gives the caller's order when called, and ``apply.within()`` is the
+    same product on vectors already in the space. None for every operator
+    that declares no space (its ``apply`` is then traced as it is) and for
+    a pair of two different ones (each crosses on its own, every product)."""
+    space = getattr(a_apply, "space", None)
+    if space is None or (m_apply is not _identity_apply
+                         and getattr(m_apply, "space", None) != space):
+        return None
+    return space
+
+
+def _within(apply):
+    return apply if apply is _identity_apply else apply.within()
+
+
 def _pcg(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply, m_apply,
          conv_test_iters, tapped):
     """Whole-solve preconditioned CG over declared operators: A's operands,
@@ -1200,14 +1221,22 @@ def _pcg(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply, m_apply,
     ``b - A 0``, is ``b`` to the bit). Same recurrence and stopping rule as
     the closure loop (:func:`_cg_while`)."""
     _CG_PRECOND_TRACES.inc()
+    space = _shared_space(a_apply, m_apply)
+    if space is not None:
+        # the recurrence runs in the operators' own order of the unknowns:
+        # b, the start and the answer cross over once a solve, outside the
+        # loop (as :func:`_cg_general` does around a layout's space)
+        a_apply, m_apply = _within(a_apply), _within(m_apply)
+        b, x0 = space.enter(b), space.enter(x0)
     matvec = functools.partial(a_apply, a_operands)
     precond = functools.partial(m_apply, m_operands)
     r = b - matvec(x0)
     tol2 = jnp.asarray(tol, dtype=jnp.real(r).dtype) ** 2
     tap = functools.partial(_iter_tap, "cg", "device") if tapped else None
-    return _cg_while(
+    x, iters = _cg_while(
         matvec, precond, b, x0, r, tol2, maxiter, conv_test_iters, tap
     )
+    return (x if space is None else space.leave(x)), iters
 
 
 _pcg.__name__ = _pcg.__qualname__ = "pcg"

@@ -1265,3 +1265,80 @@ def test_gmg_pcg_mesh_program_left_to_the_partitioner_is_the_other_side(
     assert len(loop["collective-permute"]) > 2 * 14
     assert all("f32[" not in t for t in loop.get("all-gather", []))
 
+
+
+# ---------------------------------------------------------------------------
+# jit_pcg over HPCG's pair (PR 53: models/hpcg_grid.py) at the cell's size,
+# 256^3 and four levels: 27 stored planes a level in the colour-major order,
+# the 8-colour symmetric Gauss-Seidel cycle through kernels/hpcg_colour.py,
+# the solve's crossings into that order outside the loop
+# ---------------------------------------------------------------------------
+HPCG_SIDE, HPCG_LEVELS = 256, 4
+HPCG_COMPILE_SECONDS = 45.0  # 8 s here alone; with the colour a constant of
+# 105 fusions a cycle (hpcg_grid._row_sum, what the CPU runs) 77 s
+
+
+def test_hpcg_pcg_program_compiles_at_the_cells_size(one_chip, monkeypatch):
+    import time
+
+    from sparse_tpu import linalg
+    from sparse_tpu.models import hpcg_grid
+
+    # `hpcg_grid._rows` interprets the kernel off a TPU; this process's
+    # backend is the CPU and the program is compiled for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dims = (HPCG_SIDE,) * 3
+    planes = tuple(
+        _sds((8, 27) + tuple(d >> (k + 1) for d in dims), jnp.float32, one_chip)
+        for k in range(HPCG_LEVELS))
+    vec = _sds((HPCG_SIDE ** 3,), jnp.float32, one_chip)
+    t0 = time.perf_counter()
+    lowered = linalg._pcg_program.lower(
+        planes[0], planes, vec, vec, _sds((), jnp.float32, one_chip), 64,
+        a_apply=hpcg_grid._Product(dims, True),
+        m_apply=hpcg_grid._Cycle(dims, HPCG_LEVELS, True),
+        conv_test_iters=25, tapped=False)
+    c = lowered.compile()
+    seconds = time.perf_counter() - t0
+    print(f"jit_pcg over the HPCG pair at {HPCG_SIDE}^3: traced, lowered and "
+          f"compiled in {seconds:.1f} s")
+    assert seconds < HPCG_COMPILE_SECONDS, seconds
+    text = c.as_text()
+    assert "jit_pcg" in text and _device_bytes(c) < HBM_BYTES
+    ma = c.memory_analysis()
+    # the planes are arguments (the fine level's twice: A's and the cycle's
+    # parameter, one buffer in a run), with b and the start; the coarser
+    # levels' half-grids of 64, 32 and 16 lanes are padded to the tile's 128
+    held = 27 * 4 * sum((HPCG_SIDE >> k) ** 3 for k in range(HPCG_LEVELS))
+    fine = 27 * 4 * HPCG_SIDE ** 3
+    assert held + fine <= ma.argument_size_in_bytes < held + fine + 0.5e9
+    # the loop's temporaries are CG's vectors and a cycle's blocks: no
+    # array padded from a lane axis of 2 (4.3 GB a crossing, as a reshape
+    # to [.., nx/2, 2] was laid out)
+    assert ma.temp_size_in_bytes < 0.5e9
+    # what the design promises: nothing in the program gathers or scatters
+    assert not re.search(r" (gather|scatter)\(", text)
+    # a level's symmetric steps are a loop each around one kernel, its
+    # residual one more call of it, and A p one: 7 + 3 + 1 in the loop at
+    # four levels, and the start's residual outside it
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    assert len(names) == 2 * HPCG_LEVELS - 1 + HPCG_LEVELS - 1 + 2, names
+    for lvl in range(HPCG_LEVELS):
+        steps = [n for n in names
+                 if f"/while/body/hpcg.l{lvl}/hpcg.l{lvl}.symgs/while/body/" in n]
+        assert len(steps) == (2 if lvl < HPCG_LEVELS - 1 else 1), (lvl, names)
+        assert all("hpcg_colour_update" in n for n in steps)
+    for lvl in range(HPCG_LEVELS - 1):
+        (res,) = [n for n in names if f"/hpcg.l{lvl}/hpcg.l{lvl}.spmv/" in n]
+        assert "hpcg_colour_update" in res
+    products = [n for n in names if "/hpcg.spmv/" in n]
+    assert len(products) == 2 and all("hpcg_colour_product" in n for n in products)
+    assert sum("/while/body/" in n for n in products) == 1
+    # the loop reorders no vector of the fine level: its transposes are the
+    # re-colourings between a level's block 0 and the next level
+    moved = {n for n in re.findall(r'op_name="([^"]*)"', text)
+             if n.endswith("/transpose") and "/while/body/" in n}
+    assert moved and all(re.search(r"hpcg\.l\d\.transfer/transpose$", n)
+                         for n in moved), moved

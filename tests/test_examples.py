@@ -47,6 +47,17 @@ def test_gmg_example():
     assert float(m.group(2)) < 1e-5
 
 
+def test_hpcg_example():
+    # HPCG's problem through models/hpcg_grid.py: b = A 1, so the answer is 1
+    out = _run("hpcg.py", "-nx", "16", "-ny", "16", "-nz", "16", "-levels", "3",
+               "-maxiter", "20", "-sets", "1")
+    m = re.search(r"Iterations: (\d+)\s+residual: ([0-9.e+-]+)", out)
+    assert m and int(m.group(1)) == 20, out
+    assert float(m.group(2)) < 1e-10
+    assert float(re.search(r"Error: ([0-9.e+-]+)", out).group(1)) < 1e-9
+    assert re.search(r"GFLOP/s \(HPCG's count\): [0-9.]+", out)
+
+
 def test_gmg_example_generic_path():
     # --no-grid keeps the generic sparse-matrix hierarchy (GMG class,
     # SpGEMM Galerkin products) exercised end-to-end
