@@ -26,7 +26,6 @@ from (one trace+compile serves every same-bucket dispatch).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -338,7 +337,8 @@ def batched_bicgstab(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
 # GMRES — the library's Arnoldi cycle with a lane axis in front, restarts
 # inside the loop
 # ---------------------------------------------------------------------------
-def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
+def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int,
+                         orth_blocks=None):
     """The Arnoldi process of ``linalg._gmres_arnoldi`` for B lanes at once,
     from the lanes' (preconditioned) residuals ``R [B, n]`` of norms ``beta
     [B]``: the same step (``linalg._orth_against``, ``_givens_column``), the
@@ -355,11 +355,13 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
     basis and run every stage. One basis row is written a step and lane, in
     place and unmasked: a done lane's later rows take no part in its answer
     (their coefficients ``y`` are zero past ``kk``), and they stay finite
-    (normalised vectors, or zero after a breakdown).
+    (normalised vectors, or zero after a breakdown). ``orth_blocks`` are the
+    stages' blocks of the orthogonalisation's kernel (``linalg._orth_blocks``,
+    given by who builds the program), None for four contractions.
 
     ``(V, H, g, kk, breakdown)``: per lane what the library's gives."""
     from ..linalg import (_basis_flat, _basis_tiles, _givens_column,
-                          _givens_rhs, _orth_against, _orth_stages)
+                          _givens_rhs, _orth_stage_steps, _orth_stages)
 
     dt = R.dtype
     B, n = R.shape
@@ -373,8 +375,8 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
     Q = jnp.broadcast_to(jnp.eye(restart + 1, dtype=dt),
                          (B, restart + 1, restart + 1))
 
-    block, his = _orth_stages(restart)
-    stages = [partial(_orth_against, hi=hi, restart=restart) for hi in his]
+    block, _his = _orth_stages(restart)
+    stages = _orth_stage_steps(restart, orth_blocks)
     # the step's scalars are a lane's own; the column index is the bucket's
     givens = jax.vmap(_givens_column, in_axes=(0, 0, 0, 0, 0, None, 0))
 
@@ -418,7 +420,7 @@ def _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart: int):
     return V, H, g, kk, bd
 
 
-def _gmres_cycle_lanes(mv, Mv, X, b, target, restart: int):
+def _gmres_cycle_lanes(mv, Mv, X, b, target, restart: int, orth_blocks=None):
     """One restart cycle of ``linalg._gmres_cycle`` for B lanes: the lanes'
     residuals, the Arnoldi process from them (:func:`_gmres_arnoldi_lanes`),
     each lane's small triangular solve, ``X += y V``. ``(X', kk, beta,
@@ -438,7 +440,8 @@ def _gmres_cycle_lanes(mv, Mv, X, b, target, restart: int):
         R = Mv(R)
     with jax.named_scope("bucket.gmres.update"):
         beta = jnp.linalg.norm(R, axis=-1)
-    V, H, g, kk, bd = _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart)
+    V, H, g, kk, bd = _gmres_arnoldi_lanes(mv, Mv, R, beta, target, restart,
+                                           orth_blocks)
     with jax.named_scope("bucket.gmres.small"):
         y = jax.vmap(_hessenberg_solve)(H, g, kk)
     with jax.named_scope("bucket.gmres.update"):
@@ -446,7 +449,8 @@ def _gmres_cycle_lanes(mv, Mv, X, b, target, restart: int):
     return X, kk, beta, bd
 
 
-def _gmres_loop(matvec, b, X0, target, cycles, restart: int, Mvec=None):
+def _gmres_loop(matvec, b, X0, target, cycles, restart: int, Mvec=None,
+                orth_blocks=None):
     """Masked batched restarted GMRES, the whole solve (pure jnp, jit-safe):
     a ``lax.while_loop`` over the restart cycles of
     :func:`_gmres_cycle_lanes`, as ``linalg._gmres`` has them over its own.
@@ -457,7 +461,8 @@ def _gmres_loop(matvec, b, X0, target, cycles, restart: int, Mvec=None):
     checked against its true residual by the next pass, and goes on if that
     disagrees. A finished lane freezes: its ``X`` passes through every later
     cycle unchanged (its ``y`` is zero), its counts stop. The loop ends when
-    every lane is finished or the passes are spent.
+    every lane is finished or the passes are spent. ``orth_blocks`` goes to
+    :func:`_gmres_arnoldi_lanes`.
 
     Returns ``(X, iters, resid2, converged, cycles_run)``: per lane the
     Arnoldi steps counted as the library counts them (a breakdown's stage
@@ -475,7 +480,7 @@ def _gmres_loop(matvec, b, X0, target, cycles, restart: int, Mvec=None):
     def body(st):
         X, iters, beta_last, done, c, worked = st
         X, kk, beta, bd = _gmres_cycle_lanes(matvec, Mv, X, b, target,
-                                             restart)
+                                             restart, orth_blocks)
         if tap is not None:
             # cycle granularity: the entry residuals, squared to the health
             # monitor's resid2 convention

@@ -2709,7 +2709,7 @@ class SolveSession:
                 self.conv_test_iters,
                 gmres_inner=(
                     self._build_gmres_program(pattern, bkt, dt,
-                                              precond=precond)
+                                              precond=precond, sharded=True)
                     if solver == "gmres" else None
                 ),
                 m_factory=mfac,
@@ -2789,7 +2789,8 @@ class SolveSession:
         return run
 
     def _build_gmres_program(self, pattern, bkt, dt,
-                             precond: str = precond_mod.NONE):
+                             precond: str = precond_mod.NONE,
+                             sharded: bool = False):
         """The GMRES bucket program, of the kind ``cg`` and ``bicgstab``
         have: ONE ``jax.jit`` a (pattern, bucket, dtype, restart) whose
         arguments are the value stack, rhs, x0, tolerances and maxiter,
@@ -2809,7 +2810,15 @@ class SolveSession:
         order either way, the order a left preconditioner (``precond``) is
         built in. ``run.matvec`` names the form. It returns a fifth output,
         the passes in which some lane made a step, and ``run.event_fields``
-        makes the bucket's own ``batch.dispatch`` fields from it."""
+        makes the bucket's own ``batch.dispatch`` fields from it.
+
+        ``sharded``: the fleet's batch strategy runs this program over lanes
+        sharded on its mesh and GSPMD partitions it
+        (``fleet.build_batch_program``), which a Mosaic kernel cannot be:
+        the orthogonalisation then keeps its four contractions. On one
+        device the library's rule decides (``linalg._orth_blocks``)."""
+        from ..linalg import _orth_blocks, _orth_passes
+
         n = pattern.shape[0]
         restart = min(int(self.restart or min(20, n)), n)
         mfac = (
@@ -2817,6 +2826,7 @@ class SolveSession:
             else self.precond.factory(pattern, precond)
         )
         pack, product = pattern_matvec(pattern)
+        orth_blocks = None if sharded else _orth_blocks(restart, dt, n)
 
         @partial(jax.jit, donate_argnums=donate_argnums())
         def bucket_gmres(values, rhs, x0, tols, maxiter):
@@ -2833,7 +2843,7 @@ class SolveSession:
             target = jnp.maximum(tols.astype(rdt), 1e-30)
             cycles = jnp.maximum(-(-jnp.asarray(maxiter) // restart), 1)
             return krylov._gmres_loop(
-                fmv, rhs, x0, target, cycles, restart, Mvec
+                fmv, rhs, x0, target, cycles, restart, Mvec, orth_blocks
             )
 
         bucket_gmres.matvec = pack.form
@@ -2850,7 +2860,9 @@ class SolveSession:
             ``frozen_lane_pct``: the share of lane-steps a lane that was
             done spent waiting for its bucket's last (ROADMAP M6's cost);
             ``fetches``: the host reads a bucket's solve waits on, the
-            retire's readback alone (the program makes none)."""
+            retire's readback alone (the program makes none);
+            ``orth_passes``: 3 with the orthogonalisation's kernel in the
+            program, 4 without."""
             its = np.asarray(iters[:nb], dtype=np.int64)
             top, total = int(its.max(initial=0)), int(its.sum())
             return {
@@ -2860,6 +2872,7 @@ class SolveSession:
                 ) if nb and top else 0.0,
                 "cycles_max": int(cycles), "fetches": 1,
                 "basis_gb": basis_gb,
+                "orth_passes": _orth_passes(orth_blocks),
             }
 
         bucket_gmres.event_fields = event_fields

@@ -965,16 +965,17 @@ def _run_compiled_solve(solve_of, call, fields):
     return x, counted["iters"]
 
 
-def _compiled_solve(solve_of, A, M, b, x, stop, **static):
+def _compiled_solve(solve_of, A, M, b, x, stop, declare=None, **static):
     """The executable the solve of ``b`` over ``A`` and ``M`` runs
     (``jax.stages.Compiled``: its HLO text with every op's ``named_scope``,
     its memory analysis), or None where that solve takes another path. It is
     jit's own: after a solve of the same structure this traces and compiles
     nothing. For tools that read a device trace against the program (the
-    benchmark's per-level and per-scope shares)."""
+    benchmark's per-level and per-scope shares). ``declare`` is the solver's
+    own :func:`_declared_call`, where it has one."""
     A = make_linear_operator(A)
     M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
-    call = _declared_call(A, M, b, x, stop, 1, **static)
+    call = (declare or _declared_call)(A, M, b, x, stop, 1, **static)
     return call and solve_of.program.lower(*call[0], **call[1]).compile()
 
 
@@ -1585,12 +1586,13 @@ def _gmres_tap(iters, beta, inner, stop) -> None:
 
 
 def _gmres(a_operands, m_operands, b, x0, target, maxiter, *, a_apply,
-           m_apply, restart, tapped):
+           m_apply, restart, tapped, orth_blocks=None):
     """Whole-solve restarted GMRES over declared operators: A's operands,
     M's operands, ``b``, the start ``x0``, the residual ``target`` and the
     cycle count ``maxiter`` all arguments, only structure static (the two
     ``apply`` functions, the operands' treedefs and shapes, ``restart``,
-    whether it is tapped), so nothing an operator holds is a constant of
+    whether it is tapped, the orthogonalisation's blocks of
+    :func:`_orth_blocks`), so nothing an operator holds is a constant of
     the program. An outer ``lax.while_loop`` over the restart cycles of
     :func:`_gmres_cycle`, ended as the cycle path's host loop ends: by the
     cycle count, or by a cycle that finds the residual at the target on
@@ -1607,7 +1609,8 @@ def _gmres(a_operands, m_operands, b, x0, target, maxiter, *, a_apply,
 
     def body(state):
         x, cycles, iters, _stop = state
-        x, k, beta, bdown = _gmres_cycle(matvec, precond, x, b, target, restart)
+        x, k, beta, bdown = _gmres_cycle(
+            matvec, precond, x, b, target, restart, orth_blocks)
         stop = (k == 0) & ~bdown  # converged on entry
         iters = iters + k + bdown.astype(jnp.int32)
         if tapped:
@@ -1622,7 +1625,8 @@ def _gmres(a_operands, m_operands, b, x0, target, maxiter, *, a_apply,
 
 _gmres.__name__ = _gmres.__qualname__ = "gmres"
 _gmres_program = jax.jit(
-    _gmres, static_argnames=("a_apply", "m_apply", "restart", "tapped"),
+    _gmres, static_argnames=("a_apply", "m_apply", "restart", "tapped",
+                             "orth_blocks"),
 )
 
 
@@ -1631,12 +1635,23 @@ def _gmres_counts(counts, static):
     iters, cycles = (int(v) for v in _sync_fetch(counts))
     return {"cycles": cycles, "iters": iters, "fetches": HOST_SYNCS - syncs0,
             "orth_rows": _gmres_orth_rows(static["restart"], iters),
+            "orth_passes": _orth_passes(static["orth_blocks"]),
             "basis_write_rows": _BASIS_WRITE_ROWS}
 
 
 _GMRES = _CompiledSolve(
     _gmres_program, ("gmres.solve", "gmres.dispatch", "gmres.fetch"),
     _gmres_counts)
+
+
+def _gmres_call(A, M, b, x, stop, maxiter, *, restart):
+    """:func:`_declared_call` for ``jit_gmres``: with the orthogonalisation's
+    blocks by :func:`_orth_blocks`, read off what the program is handed."""
+    call = _declared_call(A, M, b, x, stop, maxiter, restart=restart)
+    if call is not None:
+        call[1]["orth_blocks"] = _orth_blocks(
+            restart, b.dtype, b.shape[0], call[0])
+    return call
 
 
 def _gmres_compiled(A, b, restart, M=None):
@@ -1648,6 +1663,7 @@ def _gmres_compiled(A, b, restart, M=None):
     b = b.astype(jnp.result_type(b.dtype, A.dtype))
     target = jnp.zeros((), jnp.finfo(b.dtype).dtype)  # shapes and types alone count
     return _compiled_solve(_GMRES, A, M, b, jnp.zeros_like(b), target,
+                           declare=_gmres_call,
                            restart=min(int(restart), b.shape[0]))
 
 
@@ -1707,7 +1723,7 @@ def gmres(
         # `restart`: one dispatch and one fetch a call. Nothing lazy is left
         # in them (`_matrix_form` builds the layout before the program is
         # called), so neither eager warm-up of the cycle path is needed
-        call = None if callback is not None else _declared_call(
+        call = None if callback is not None else _gmres_call(
             A, M, b, x, target, maxiter, restart=int(restart))
         declared = ((call[1]["a_apply"], call[0][0]) if call is not None
                     else _declared(A, b.dtype))
@@ -1732,10 +1748,11 @@ def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback, fields):
     with telemetry.span("gmres.solve", path="cycle", **fields) as solve:
         syncs0 = HOST_SYNCS
         try:
-            x, iters, cycles = _gmres_cycles(
+            x, iters, cycles, orth_blocks = _gmres_cycles(
                 A, M, b, x, target, restart, maxiter, callback)
             # the compiled cycle's Arnoldi process: staged, a row to a tile
             solve.annotate(orth_rows=_gmres_orth_rows(int(restart), iters),
+                           orth_passes=_orth_passes(orth_blocks),
                            basis_write_rows=_BASIS_WRITE_ROWS)
             path = "device"
         except (
@@ -1757,7 +1774,8 @@ def _gmres_cycle_path(A, M, b, x, target, restart, maxiter, callback, fields):
 def _gmres_cycles(A, M, b, x, target, restart, maxiter, callback):
     """The cycle path: one compiled restart cycle for this call
     (:func:`_make_gmres_cycle`), driven from the host with one fetch a
-    cycle. ``(x, iters, cycles that did work)``."""
+    cycle. ``(x, iters, cycles that did work, the program's blocks of
+    _orth_blocks)``."""
     # warm host-side format dispatch (e.g. csr_array._maybe_dia) with
     # one eager matvec so the traced cycle sees pure jnp paths
     r0 = b - A.matvec(x)
@@ -1769,8 +1787,10 @@ def _gmres_cycles(A, M, b, x, target, restart, maxiter, callback):
     # the whole solve — the host-sync-count test in
     # tests/test_precond.py pins that no M syncs land per cycle
     if not isinstance(M, IdentityOperator):
-        M.matvec(r0)
-    cycle = _make_gmres_cycle(A, M, restart, jnp.dtype(b.dtype))
+        r0 = M.matvec(r0)
+    # what a closure holds cannot be seen; where its product lives can
+    orth_blocks = _orth_blocks(int(restart), b.dtype, b.shape[0], (b, x, r0))
+    cycle = _make_gmres_cycle(A, M, restart, jnp.dtype(b.dtype), orth_blocks)
     total_iters = cycles = 0
     for _outer in range(maxiter):
         x, info = cycle(x, b, target)
@@ -1793,7 +1813,7 @@ def _gmres_cycles(A, M, b, x, target, restart, maxiter, callback):
             _gmres_cycle_event("device", total_iters, abs(beta), inner)
         if callback is not None:
             callback(x)
-    return x, total_iters, cycles
+    return x, total_iters, cycles, orth_blocks
 
 
 def _gmres_host_cycles(A, M, b, x, target, restart, maxiter, callback):
@@ -1875,7 +1895,8 @@ def _gmres_cycle_host(A, M, x, r, beta, restart, target):
     return x, k
 
 
-def _gmres_cycle(matvec, precond, x, b, target, restart: int):
+def _gmres_cycle(matvec, precond, x, b, target, restart: int,
+                 orth_blocks=None):
     """One fully device-resident restart cycle (VERDICT r2 #5), as traced
     values: the body of the compiled whole solve (:func:`_gmres`) and of the
     cycle path's per-call program (:func:`_make_gmres_cycle`). The residual
@@ -1902,7 +1923,8 @@ def _gmres_cycle(matvec, precond, x, b, target, restart: int):
         r = precond(r)
     with jax.named_scope("gmres.update"):
         beta = jnp.linalg.norm(r)
-    V, H, g, k, bdown = _gmres_arnoldi(matvec, precond, r, beta, target, restart)
+    V, H, g, k, bdown = _gmres_arnoldi(
+        matvec, precond, r, beta, target, restart, orth_blocks)
     with jax.named_scope("gmres.small"):
         y = _hessenberg_solve(H, g, k)
     with jax.named_scope("gmres.update"):
@@ -1928,12 +1950,17 @@ _BASIS_TILE = (8, 128)
 _BASIS_WRITE_ROWS = 1
 
 
+def _basis_rows(n: int) -> tuple:
+    """``(R, 128)``: the shape of a basis row that holds ``n`` elements."""
+    sub, lanes = _BASIS_TILE
+    return sub * -(-n // (sub * lanes)), lanes
+
+
 def _basis_tiles(v):
     """The flat vector ``v [..., n]`` as a basis row: padded with zeros to
     whole ``(8, 128)`` tiles, ``[..., R, 128]``."""
-    sub, lanes = _BASIS_TILE
     n = v.shape[-1]
-    rows = sub * -(-n // (sub * lanes))
+    rows, lanes = _basis_rows(n)
     pad = [(0, 0)] * (v.ndim - 1) + [(0, rows * lanes - n)]
     return jnp.pad(v, pad).reshape(*v.shape[:-1], rows, lanes)
 
@@ -1999,7 +2026,55 @@ def _gmres_orth_rows(restart: int, iters: int) -> float:
     return round((whole * sum(cycle) + sum(cycle[:last])) / iters, 3)
 
 
-def _orth_against(V, w, k, *, hi: int, restart: int):
+def _orth_platform() -> bool:
+    """The platform of the orthogonalisation's kernel: a TPU, and x64 off
+    (with it on the kernel's loop index is an int64 that Mosaic's verifier
+    refuses beside its int32 offsets), as ``csr._dia_platform``."""
+    return jax.default_backend() == "tpu" and not jax.config.jax_enable_x64
+
+
+def _orth_blocks(restart: int, dtype, n: int, placed=()):
+    """The rule of the kernel ``kernels.orth_pass.orth_update_project``, read
+    where a GMRES program is built (``jit_gmres``'s call, the cycle path's
+    cycle, the session's bucket program) and static in it: the rows of the
+    kernel's column block for each stage of :func:`_orth_stages`, or None
+    where every stage keeps its four ``jnp`` contractions: off the kernel's
+    platform (:func:`_orth_platform`), for another dtype than float32
+    (float64; complex, where the basis is conjugated), where a stage has no
+    block that fits the VMEM budget written beside the kernel, and where an
+    array of ``placed`` (a tree: what the program is handed) lives on more
+    than one device. GSPMD partitions such a program, and a Mosaic kernel it
+    cannot. Nothing sets it."""
+    if not (_orth_platform() and jnp.dtype(dtype) == jnp.float32):
+        return None
+    if any(_mesh_fields(a) for a in jax.tree_util.tree_leaves(placed)):
+        return None
+    from .kernels.orth_pass import block_rows
+
+    rows, _lanes = _basis_rows(n)
+    blocks = tuple(block_rows(hi, rows) for hi in _orth_stages(restart)[1])
+    return blocks if all(blocks) else None
+
+
+def _orth_passes(orth_blocks) -> int:
+    """Reads of the stage's rows of the basis one step of
+    :func:`_gmres_arnoldi` makes (the ``orth_passes`` field of the
+    ``gmres.solve`` span and of the GMRES bucket's ``batch.dispatch``): 3
+    in a program built with the kernel's blocks (:func:`_orth_blocks`), 4 in
+    one of four contractions."""
+    return 3 if orth_blocks else 4
+
+
+def _orth_stage_steps(restart: int, orth_blocks=None) -> list:
+    """The branches of a step's ``lax.switch``: :func:`_orth_against` for
+    each stage of :func:`_orth_stages`, with the stage's block of
+    ``orth_blocks`` (:func:`_orth_blocks`) or, with None, without."""
+    _block, his = _orth_stages(restart)
+    return [functools.partial(_orth_against, hi=hi, restart=restart, tr=tr)
+            for hi, tr in zip(his, orth_blocks or (None,) * len(his))]
+
+
+def _orth_against(V, w, k, *, hi: int, restart: int, tr=None):
     """Step ``k``'s classical Gram-Schmidt and one re-orthogonalisation pass
     of ``w [..., R, 128]`` against the rows ``V[..., :k + 1, :, :]``, as
     masked contractions over one stage's rows ``V[..., :hi, :, :]`` (a
@@ -2007,13 +2082,24 @@ def _orth_against(V, w, k, *, hi: int, restart: int):
     session's lanes, ``batch.krylov._gmres_arnoldi_lanes``: the leading axes
     are lanes, ``k`` is theirs in common). ``(h, w', ||w'||^2)``: the
     coefficients padded to the basis' ``restart + 1`` rows, what is left of
-    ``w``, and its sum of squares."""
+    ``w``, and its sum of squares.
+
+    Four contractions, four reads of the stage's rows; with the stage's
+    block rows ``tr`` (:func:`_orth_blocks`) the second and the third are
+    one kernel over one read of them (``kernels/orth_pass.py``), which takes
+    the basis whole and picks the stage's rows by its blocks."""
     rdt = jnp.zeros((), w.dtype).real.dtype
     Vs = V[..., :hi, :, :]
     mask = (jnp.arange(hi) <= k).astype(rdt)
     hcol = _basis_project(Vs, w) * mask
-    w = w - _basis_combine(hcol, Vs)
-    h2 = _basis_project(Vs, w) * mask
+    if tr is None:
+        w = w - _basis_combine(hcol, Vs)
+        h2 = _basis_project(Vs, w)
+    else:
+        from .kernels.orth_pass import orth_update_project
+
+        w, h2 = orth_update_project(V, w, hcol, hi=hi, tr=tr)
+    h2 = h2 * mask
     w = w - _basis_combine(h2, Vs)
     # ||w||: jnp.linalg.norm's own sum, its root among the scalars
     ww = jnp.sum(jnp.real(w * jnp.conj(w)), axis=(-2, -1))
@@ -2092,7 +2178,8 @@ def _hessenberg_solve(H, g, k):
     return jax.scipy.linalg.solve_triangular(Hs, gv, lower=False)
 
 
-def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
+def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int,
+                   orth_blocks=None):
     """The Arnoldi process of a restart cycle from the (preconditioned)
     residual ``r`` of norm ``beta``: at most ``restart`` steps of classical
     Gram-Schmidt with one re-orthogonalisation pass, the Givens recurrences
@@ -2110,7 +2197,9 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     ``V[:his[k // block]]`` of :func:`_orth_stages`, a static slice chosen
     by ``k`` on the device (``lax.switch``), under a mask within the last
     block. Rows past ``k`` are zero and their coefficients were masked to
-    zero, so leaving them out changes no term of any sum.
+    zero, so leaving them out changes no term of any sum. With
+    ``orth_blocks`` (:func:`_orth_blocks`, asked by who builds the program)
+    the second and the third contraction are one kernel over one read.
 
     The reference keeps its Hessenberg recurrences asynchronous via futures
     (linalg.py:670-795); here the [restart]^2 Givens/Hessenberg math runs
@@ -2130,9 +2219,8 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     H = jnp.zeros((restart + 1, restart), dtype=dt)
     Q = jnp.eye(restart + 1, dtype=dt)
 
-    block, his = _orth_stages(restart)
-    stages = [functools.partial(_orth_against, hi=hi, restart=restart)
-              for hi in his]
+    block, _his = _orth_stages(restart)
+    stages = _orth_stage_steps(restart, orth_blocks)
 
     def cond(st):
         _V, _H, _Q, k, done, _bd = st
@@ -2169,7 +2257,7 @@ def _gmres_arnoldi(matvec, precond, r, beta, target, restart: int):
     return V, H, g, k, bdown
 
 
-def _make_gmres_cycle(A, M, restart: int, dt):
+def _make_gmres_cycle(A, M, restart: int, dt, orth_blocks=None):
     """The cycle path's program: :func:`_gmres_cycle` jitted over the bound
     methods ``A.matvec`` and ``M.matvec``, for a closure on either side, a
     ``callback`` or an outer trace.
@@ -2187,7 +2275,8 @@ def _make_gmres_cycle(A, M, restart: int, dt):
 
     @jax.jit
     def cycle(x, b, target):
-        x, k, beta, bdown = _gmres_cycle(A.matvec, M.matvec, x, b, target, restart)
+        x, k, beta, bdown = _gmres_cycle(
+            A.matvec, M.matvec, x, b, target, restart, orth_blocks)
         info = jnp.stack(
             [k.astype(rdt), beta.astype(rdt), bdown.astype(rdt)]
         )

@@ -749,15 +749,20 @@ GMRES_BASIS_ROWS = 9928  # 8 * ceil(1,270,432 / 1024): a basis row is [9928, 128
 GMRES_SCOPES = ("gmres.spmv", "gmres.orth", "gmres.small", "gmres.update")
 
 
-def _gmres_compiled(one_chip, monkeypatch, restart=30, kernel=True):
+def _gmres_compiled(one_chip, monkeypatch, restart=30, kernel=True,
+                    orth_kernel=True):
     """``jit_gmres`` at the cell's box as the chip runs it since PR 50: the
     matrix's operand the packed rows of the layout ``dia`` at the plan the
     rule picks (``kernel=False``: the scipy-layout planes, the XLA form the
-    rule leaves everywhere else)."""
+    rule leaves everywhere else), and since PR 54 the orthogonalisation's
+    middle through ``orth_update_project``, with the blocks the rule gives
+    the call that builds the program (``orth_kernel=False``: none, as off a
+    TPU, and the four contractions stay)."""
     from sparse_tpu import csr, linalg
     from sparse_tpu.kernels.dia_spmv import DiaRows, dia_rows_plan
 
-    # `csr.form_matvec` interprets the kernel off a TPU; this process's
+    # `csr.form_matvec` and the orthogonalisation's kernel are interpreted
+    # off a TPU, and `linalg._orth_blocks` declines there; this process's
     # backend is the CPU and the program is compiled for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     a, b, c = GMRES_BOX
@@ -773,7 +778,9 @@ def _gmres_compiled(one_chip, monkeypatch, restart=30, kernel=True):
     return n, linalg._gmres_program.lower(
         operand, (), vec, vec, _sds((), jnp.float32, one_chip), 10,
         a_apply=linalg._FormApply("dia", (offsets, (n, n))),
-        m_apply=linalg._identity_apply, restart=restart, tapped=False).compile()
+        m_apply=linalg._identity_apply, restart=restart, tapped=False,
+        orth_blocks=(linalg._orth_blocks(restart, np.float32, n, (operand, vec))
+                     if orth_kernel else None)).compile()
 
 
 def _computations(text: str) -> dict:
@@ -799,6 +806,26 @@ def _arnoldi_body(computations: dict) -> str:
     (body,) = [c for c in computations.values()
                if re.search(r" conditional\([^\n]*gmres\.orth/", c)]
     return body
+
+
+def _stage_branches(body: str, computations: dict, scope: str) -> list:
+    """The branch computations of the one ``conditional`` in a loop body: the
+    orthogonalisation's stages, in order. The ``conditional`` itself stands
+    under the orthogonalisation's ``scope``: its own time is part of what
+    the cell's share of that scope reads."""
+    (names,) = re.findall(
+        r" conditional\([^\n]*branch_computations=\{([^}]*)\}[^\n]*%s/"
+        % re.escape(scope), body)
+    return [computations[b.strip().lstrip("%")] for b in names.split(",")]
+
+
+def _assert_float32_and_no_copy_of(text: str, basis: str):
+    """No copy of the basis is planned anywhere, and nothing is there for the
+    MXU's default bfloat16 pass to touch."""
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"\bcopy(-start)?\(", ln) and basis in ln]
+    assert "convolution" not in text and "bf16" not in text
+    assert not re.search(r"\bdot\(", text)
 
 
 def test_gmres_program_compiles_at_atmosmodd_size(one_chip, monkeypatch):
@@ -863,6 +890,8 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip, monkeypatch):
     # back to a row
     calls = [ln for ln in body.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1 and "gmres.spmv/" in calls[0], calls
+    # (the orthogonalisation's kernel is in the stages' branches: the test
+    # of the scopes below)
     flat = f"f32[{rows * 128}]"
     assert re.match(r"\s*%%?[\w.\-]+ = %s\S* custom-call\(" % re.escape(flat),
                     calls[0])
@@ -886,12 +915,14 @@ def test_gmres_program_compiles_at_atmosmodd_size(one_chip, monkeypatch):
                      through)
     assert " multiply(" in through and re.search(r"f32\[31\]\S* reduce\(",
                                                  through)
-    # the four contractions against the basis run in float32 on the vector
-    # unit: nothing for the MXU's default bfloat16 pass to touch
+    # the contractions against the basis, XLA's and the kernel's, run in
+    # float32 on the vector unit: nothing for the MXU's default bfloat16 pass
     assert "convolution" not in text and "bf16" not in text
     assert not re.search(r"\bdot\(", text)
     # the orthogonalisation's stages read static slices of the basis that
-    # fuse into their contractions (PR 43): no stage plans a copy of it
+    # fuse into their contractions (PR 43), and the kernel takes the basis
+    # whole and picks the stage's rows by its blocks (PR 54): no stage plans
+    # a copy of it
     assert not [ln for ln in text.splitlines()
                 if re.search(r"\bcopy(-start)?\(", ln) and basis in ln]
     # no constant of the program is larger than the (iters, cycles) pair:
@@ -905,7 +936,8 @@ def test_gmres_program_without_the_kernel_is_the_parents(one_chip, monkeypatch):
     """The other side of the rule (float64, a band too wide, a rectangular
     matrix on a TPU): the scipy-layout planes as the operand, the XLA form,
     whose planes x x is a ``[7, n]`` array written and read again."""
-    n, c = _gmres_compiled(one_chip, monkeypatch, kernel=False)
+    n, c = _gmres_compiled(one_chip, monkeypatch, kernel=False,
+                           orth_kernel=False)
     text = c.as_text()
     assert "jit_gmres" in text and _device_bytes(c) < HBM_BYTES
     assert "tpu_custom_call" not in text
@@ -914,28 +946,53 @@ def test_gmres_program_without_the_kernel_is_the_parents(one_chip, monkeypatch):
             if "gmres.spmv/" in ln and re.search(r"= f32\[7,%d\]" % n, ln)]
 
 
-def test_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
+def _stage_ops(branch: str, computations: dict, basis: str, scope: str):
+    """Of one stage's branch: the results of its contraction fusions over
+    the basis (``reduce_sum`` fusions under ``scope`` whose computation has
+    the whole basis as a parameter) with those computations, and the lines
+    of the orthogonalisation's kernel."""
+    sums = r'op_name="[^"]*%s/[^"]*reduce_sum"' % re.escape(scope)
+    orth = [(result, inner) for result, inner in (
+        (result, computations[_called(rest)])
+        for _name, result, rest in _fusions(branch) if re.search(sums, rest))
+        if basis in inner]
+    calls = [ln for ln in branch.splitlines()
+             if "tpu_custom_call" in ln and "orth_update_project" in ln]
+    return orth, calls
+
+
+@pytest.mark.parametrize("orth_kernel", [True, False],
+                         ids=["orth-kernel", "four-contractions"])
+def test_gmres_program_ops_carry_their_scope(one_chip, monkeypatch, orth_kernel):
     """What ``benchmark/reducers/op_scope_share.py`` reads the cell's
     per-scope shares from: in the Arnoldi loop's body, and in the branches
     of the orthogonalisation's ``conditional`` (one a stage: PR 43; eight
     stages of four rows since PR 47), every
     fusion that carries an ``op_name`` stands under exactly one of the four
     scopes, each scope has one, and the compiler's own fusions without an
-    ``op_name`` are few. Every stage has its four contractions, multiplies
-    and sums over the basis ``[31, R, 128]`` (PR 47) sliced to the stage's
-    rows of the major dimension inside the fusion."""
+    ``op_name`` are few. Every stage reads its rows of the basis ``[31, R,
+    128]`` (PR 47) three times (PR 54): two contractions, multiplies and
+    sums sliced to the stage's rows of the major dimension inside the fusion
+    (the first projection and the last update), and between them one call of
+    the kernel ``orth_update_project`` on the whole basis, under the same
+    scope. With the kernel's rule off the stage is the parent's four
+    contractions and no kernel, and ``orth_passes`` says which."""
     from sparse_tpu import linalg
 
-    n, c = _gmres_compiled(one_chip, monkeypatch)
+    n, c = _gmres_compiled(one_chip, monkeypatch, orth_kernel=orth_kernel)
+    # the field of the span, from the program's own static argument
+    blocks = linalg._orth_blocks(30, np.float32, n)
+    assert blocks == (1248, 768, 552, 432, 360, 304, 264, 240)
+    assert linalg._gmres_counts(np.zeros(2, np.int32), {
+        "restart": 30, "orth_blocks": blocks if orth_kernel else None,
+    })["orth_passes"] == (3 if orth_kernel else 4)
+    assert linalg._orth_blocks(30, np.float64, n) is None
     rows = GMRES_BASIS_ROWS
     text = c.as_text()
     computations = _computations(text)
     body = _arnoldi_body(computations)
     assert "gmres.update/dynamic_update_slice" in body
-    (branches,) = re.findall(
-        r" conditional\([^\n]*branch_computations=\{([^}]*)\}[^\n]*gmres\.orth/",
-        body)
-    branches = [computations[b.strip().lstrip("%")] for b in branches.split(",")]
+    branches = _stage_branches(body, computations, "gmres.orth")
     _block, his = linalg._orth_stages(30)
     assert his == (4, 8, 12, 16, 20, 24, 28, 31) and len(branches) == len(his)
 
@@ -947,7 +1004,7 @@ def test_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
             named[name] = m.group(1)
         else:
             unnamed.append(name)
-    assert len(named) + len(unnamed) >= 12 + 4 * len(his)
+    assert len(named) + len(unnamed) >= 12 + (2 if orth_kernel else 4) * len(his)
     for name, op_name in named.items():
         # (a fusion of two ops lists both names, under the one scope)
         under = {s for s in GMRES_SCOPES if f"/{s}/" in op_name}
@@ -955,24 +1012,36 @@ def test_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
     for scope in GMRES_SCOPES:
         assert any(f"/{scope}/" in v for v in named.values()), scope
     assert len(unnamed) <= 6, unnamed
-    # a stage's four contractions read the stage's rows of the basis and no
-    # others: two give the stage's coefficients, two give a vector
+    basis = f"f32[31,{rows},128]"
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 + (len(his) if orth_kernel else 0))  # the product's two
     for hi, branch in zip(his, branches):
-        orth = [(result, _called(rest))
-                for _name, result, rest in _fusions(branch)
-                if re.search(r'op_name="[^"]*gmres\.orth/[^"]*reduce_sum"', rest)
-                and f"f32[31,{rows},128]" in computations[_called(rest)]]
-        assert len(orth) == 4, (hi, orth)
+        # the stage's contractions read the stage's rows of the basis and no
+        # others: half give the stage's coefficients, half a vector
+        orth, calls = _stage_ops(branch, computations, basis, "gmres.orth")
+        each = 1 if orth_kernel else 2
+        assert len(orth) == 2 * each, (hi, orth)
         assert sorted(re.match(r"f32\[[\d,]+\]", r).group(0) for r, _ in orth) == \
-            sorted([f"f32[{hi}]"] * 2 + [f"f32[{rows},128]"] * 2)
-        for _result, fused in orth:
-            inner = computations[fused]
+            sorted([f"f32[{hi}]"] * each + [f"f32[{rows},128]"] * each)
+        for _result, inner in orth:
             # the whole basis is the fusion's parameter (handed on by
             # reference), the stage's rows its slice of it: whole tiles for
             # any count of rows
             assert re.search(r"= f32\[31,%d,128\]\S* parameter\(" % rows, inner)
             if hi < 31:
-                assert f"slice={{[0:{hi}], [0:{rows}], [0:128]}}" in inner, (hi, fused)
+                assert f"slice={{[0:{hi}], [0:{rows}], [0:128]}}" in inner, (hi, inner)
+        # the kernel: one call a stage, under the orthogonalisation's scope,
+        # the WHOLE basis its operand (no slice in front of it, so no copy),
+        # w1 and the partial sums of h2 a tile a row its results
+        assert len(calls) == (1 if orth_kernel else 0), (hi, calls)
+        for call in calls:
+            assert "/gmres.orth/" in call
+            assert re.search(
+                r"= \(f32\[%d,128\]\S*, f32\[%d,8,128\]\S*\) custom-call\("
+                % (rows, hi), call), call
+            assert ("operand_layout_constraints={f32[%d]{0}, %s{2,1,0}, "
+                    "f32[%d,128]{1,0}}" % (hi, basis, rows)) in call
+    _assert_float32_and_no_copy_of(text, basis)
 
 
 # ---------------------------------------------------------------------------
@@ -994,21 +1063,35 @@ BUCKET_GMRES_LANES = 4
 BUCKET_GMRES_SCOPES = tuple("bucket." + s for s in GMRES_SCOPES)
 
 
-def _bucket_gmres_compiled(one_chip, monkeypatch, restart=30):
+def _bucket_gmres_compiled(one_chip, monkeypatch, restart=30, orth_kernel=True,
+                           box=BUCKET_GMRES_BOX, lanes=BUCKET_GMRES_LANES):
+    """``jit_bucket_gmres`` as the chip runs it: the plane product XLA's, the
+    orthogonalisation's middle through ``orth_update_project`` since PR 54
+    (``orth_kernel=False``: the rule's platform says no, as off a TPU). The
+    program's ``orth_passes`` field says which."""
+    from sparse_tpu import linalg
     from sparse_tpu.batch import service
 
     from .utils.spd import operator_module
 
     monkeypatch.setattr(service, "donate_argnums", lambda: (0, 1, 2))
+    # the orthogonalisation's kernel is interpreted off a TPU and its rule
+    # declines there; this process's backend is the CPU and the program is
+    # compiled for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if not orth_kernel:
+        monkeypatch.setattr(linalg, "_orth_platform", lambda: False)
     d = operator_module("cfd_7pt").make(
-        {"box": BUCKET_GMRES_BOX, "restart": restart, "cycles": 1}, 1)
-    n, B = d["rows"], BUCKET_GMRES_LANES
+        {"box": box, "restart": restart, "cycles": 1}, 1)
+    n, B = d["rows"], lanes
     P = sp.csr_matrix((d["data"], d["indices"], d["indptr"]), shape=(n, n))
     ses = service.SolveSession("gmres", restart=restart, batch_max=B,
                                warm_start=False)
     pattern = ses.pattern_of(P)
     run = ses._build_program(pattern, B, np.dtype(np.float32))
     assert run.matvec == "planes"
+    assert run.event_fields(1, np.ones(1), 1)["orth_passes"] == (
+        3 if orth_kernel else 4)
     return n, run.lower(
         _sds((B, pattern.nnz), jnp.float32, one_chip),
         _sds((B, n), jnp.float32, one_chip),
@@ -1048,12 +1131,14 @@ def test_bucket_gmres_program_writes_one_row_a_lane_and_copies_no_basis(
     # the row's read for the product: one row a lane
     assert "dynamic_slice_sizes={%d,1,%d,128}" % (B, rows) in text
     # one conditional, eight stages, each with the static slice inside its
-    # contractions: no copy of the basis is planned anywhere
+    # contractions and the whole basis the operand of its kernel (PR 54): no
+    # copy of the basis is planned anywhere
     (branch_names,) = re.findall(
         r" conditional\([^\n]*branch_computations=\{([^}]*)\}", body)
     assert len(branch_names.split(",")) == 8
     assert not [ln for ln in text.splitlines()
                 if re.search(r"\bcopy(-start)?\(", ln) and basis in ln]
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
     # float32 on the vector unit: nothing for the MXU's bfloat16 pass
     assert "convolution" not in text and "bf16" not in text
     assert not re.search(r"\bdot\(", text)
@@ -1087,6 +1172,105 @@ def test_bucket_gmres_program_ops_carry_their_scope(one_chip, monkeypatch):
     assert named
     for rest in named:
         assert sum(f"/{s}/" in rest for s in BUCKET_GMRES_SCOPES) == 1, rest
+
+
+@pytest.mark.parametrize("orth_kernel", [True, False],
+                         ids=["orth-kernel", "four-contractions"])
+def test_bucket_gmres_program_at_the_cells_size_reads_a_stage_three_times(
+        one_chip, monkeypatch, orth_kernel):
+    """The served cell's own program, 32 lanes of the 148 x 148 x 58 box
+    (PR 54): in every stage's branch one call of ``orth_update_project``
+    under the orthogonalisation's scope, the lanes' WHOLE bases its operand,
+    and beside it exactly two contractions over them (the first projection,
+    the last update); no copy of the 5 GB of bases, no ``dot``, no
+    ``bf16``, and no more HBM than the parent's program plans (9.59 GB:
+    ``bucket_program_hbm_gb``). With the rule off: four contractions a
+    stage, no kernel, the field 4."""
+    from sparse_tpu import linalg
+
+    B = 32
+    n, c = _bucket_gmres_compiled(one_chip, monkeypatch, box=GMRES_BOX, lanes=B,
+                                  orth_kernel=orth_kernel)
+    assert n == ATMOSMODD[0]
+    text = c.as_text()
+    rows = GMRES_BASIS_ROWS
+    basis = f"f32[{B},31,{rows},128]"
+    assert basis + "{3,2,1,0:T(8,128)}" in text
+    # (what the compiler plans here: 9.430 GB with the kernel, 9.431 without)
+    assert 9.3e9 < _device_bytes(c) < 9.6e9
+    computations = _computations(text)
+    (body,) = [comp for comp in computations.values() if re.search(
+        r" conditional\([^\n]*bucket\.gmres\.orth/", comp)]
+    branches = _stage_branches(body, computations, "bucket.gmres.orth")
+    _block, his = linalg._orth_stages(30)
+    assert len(branches) == len(his) == 8
+    each = 1 if orth_kernel else 2
+    for hi, branch in zip(his, branches):
+        orth, calls = _stage_ops(branch, computations, basis, "bucket.gmres.orth")
+        assert sorted(re.match(r"f32\[[\d,]+\]", r).group(0) for r, _ in orth) == \
+            sorted([f"f32[{B},{hi}]"] * each + [f"f32[{B},{rows},128]"] * each)
+        assert len(calls) == (1 if orth_kernel else 0), (hi, calls)
+        for call in calls:
+            assert "/bucket.gmres.orth/" in call
+            assert re.search(
+                r"= \(f32\[%d,%d,128\]\S*, f32\[%d,%d,8,128\]\S*\) custom-call\("
+                % (B, rows, B, hi), call), call
+            assert basis + "{3,2,1,0}" in call
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        len(his) if orth_kernel else 0)
+    _assert_float32_and_no_copy_of(text, basis)
+
+
+def _fleet_bucket_gmres_lowered(chip, monkeypatch, sharded: bool):
+    """The fleet's GMRES bucket program for four described chips, float32:
+    what ``SolveSession("gmres", fleet=...)`` dispatches under the batch
+    strategy, its lanes sharded on the mesh's ``lanes`` axis and the rest
+    left to GSPMD (``fleet.build_batch_program``). ``sharded=False``: the
+    one-device program handed the same sharded lanes, the other side."""
+    from sparse_tpu import fleet
+    from sparse_tpu.batch import service
+
+    from .utils.spd import operator_module
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d = operator_module("cfd_7pt").make(
+        {"box": BUCKET_GMRES_BOX, "restart": 30, "cycles": 1}, 1)
+    n, B = d["rows"], 8
+    pat = sp.csr_matrix((d["data"], d["indices"], d["indptr"]), shape=(n, n))
+    mesh = Mesh(np.array(chip.devices[:4]), (fleet.FLEET_AXIS,))
+    ses = service.SolveSession("gmres", restart=30, batch_max=B,
+                               warm_start=False)
+    pattern = ses.pattern_of(pat)
+    plan = fleet.FleetPlan("batch", mesh, "v5e-2x2") if sharded else None
+    run = ses._build_program(pattern, B, np.dtype(np.float32), plan=plan)
+    lanes = NamedSharding(mesh, P(fleet.FLEET_AXIS))
+    # (the fleet's program puts its arguments on the mesh itself, a
+    # constraint under this jit; the one-device program takes them as given)
+    return run, jax.jit(run).lower(
+        _sds((B, pattern.nnz), jnp.float32, lanes),
+        _sds((B, n), jnp.float32, lanes),
+        _sds((B, n), jnp.float32, lanes),
+        _sds((B,), jnp.float32, lanes),
+        _sds((), jnp.int32, NamedSharding(mesh, P())))
+
+
+def test_fleet_bucket_gmres_program_compiles_on_four_chips(chip, monkeypatch):
+    """A Mosaic kernel cannot be partitioned by GSPMD, so the fleet's batch
+    strategy builds the bucket program without the orthogonalisation's
+    kernel: four contractions a stage, ``orth_passes`` 4, two lanes' bases a
+    device. The one-device program, with the kernel, is refused for sharded
+    lanes at its lowering: what a fleet dispatch on the chip would have met
+    (and degraded from) had the rule not known how the program is
+    partitioned."""
+    run, lowered = _fleet_bucket_gmres_lowered(chip, monkeypatch, sharded=True)
+    assert run.event_fields(1, np.ones(1), 1)["orth_passes"] == 4
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" not in text and "orth_update_project" not in text
+    rows = 8 * -(-30720 // 1024)
+    assert f"f32[2,31,{rows},128]" in text and f"f32[8,31,{rows},128]" not in text
+    assert "/bucket.gmres.orth/" in text and "all-reduce" in text
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        _fleet_bucket_gmres_lowered(chip, monkeypatch, sharded=False)
 
 
 def _gmg_pcg_compiled(one_chip, monkeypatch, fine_kernel: bool):
