@@ -21,6 +21,18 @@ jit-safe: :class:`~sparse_tpu.batch.service.SolveSession` closes them
 over a pattern's packed matvec inside ONE jitted program per batch
 bucket, which is where the compile-amortization of microbatching comes
 from (one trace+compile serves every same-bucket dispatch).
+
+Which public calls compile once: :func:`batched_bicgstab` over an operator
+and a preconditioner that declare what they hold (a
+:class:`~sparse_tpu.batch.operator.BatchedDIA`, the Jacobi factory's
+``Mvec``), in float32, runs ``jit_batched_bicgstab``, whose arguments are
+the planes, the reciprocal diagonal, ``b``, the start, the lanes' ``tol``
+and ``maxiter``, so that a later call of the same shapes, whatever the
+values, traces and compiles nothing. Every other call
+(:func:`batched_cg`, :func:`batched_gmres`, a callable, a dense stack, a
+``BatchedCSR``, a closure ``M``, float64, complex) runs its loop eagerly
+with the operator's arrays closed over, and so traces, lowers and compiles
+it again at every call.
 """
 
 from __future__ import annotations
@@ -31,9 +43,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import linalg as _linalg
 from .. import telemetry
 from ..resilience import faults as _faults
-from ..utils import asjnp
+from ..telemetry import _metrics
+from ..utils import asjnp, in_trace
 from .operator import BatchedOperator, as_batched_matvec
 
 
@@ -101,23 +115,42 @@ def _prep(A, b, x0, tol, maxiter):
     return mv, b, X0, tol, int(maxiter), B, n
 
 
-def _solve_event(solver: str, info: BatchedSolveInfo, n: int) -> None:
-    """One ``batch.solve`` event per completed batched solve. The per-lane
-    fetch only happens with telemetry on (documented sync cost)."""
+def _lane_fields(iters, converged) -> dict:
+    """What a solve's span and its ``batch.solve`` event say of the lanes'
+    fetched counts. ``frozen_lane_pct`` is the share of the loop's
+    lane-steps that a lane which had already stopped spent frozen under its
+    mask, waiting for the batch's last lane: 100 (1 - iters_sum / (B
+    iters_max))."""
+    B = int(iters.shape[0])
+    iters_max, iters_sum = int(iters.max(initial=0)), int(iters.sum())
+    return {
+        "iters_max": iters_max, "iters_sum": iters_sum,
+        "iters_mean": iters_sum / B if B else 0.0,
+        "frozen_lane_pct": round(
+            100.0 * (1.0 - iters_sum / (B * iters_max)), 3
+        ) if iters_max else 0.0,
+        "converged": int(np.count_nonzero(converged)),
+    }
+
+
+def _solve_event(solver: str, info: BatchedSolveInfo, n: int):
+    """One ``batch.solve`` event per completed batched solve; returns the
+    lanes' largest count (None with telemetry off). The lanes' counts come
+    to the host in ONE fetch, and only with telemetry on (documented sync
+    cost); a compiled solve's are on the host already."""
     if not telemetry.enabled():
-        return
-    iters = np.asarray(info.iters)
+        return None
+    iters, resid2, converged = (np.asarray(a) for a in jax.device_get(
+        (info.iters, info.resid2, info.converged)))
+    fields = _lane_fields(iters, converged)
     telemetry.record(
         "batch.solve", solver=solver, B=int(iters.shape[0]), n=int(n),
-        iters_max=int(iters.max(initial=0)),
-        iters_mean=float(iters.mean()) if iters.size else 0.0,
-        converged=int(np.asarray(info.converged).sum()),
+        **fields,
     )
     # final per-lane health sweep (NaN lanes flag even when the per-iter
     # taps were off, e.g. on TPU backends)
-    telemetry.health.end_batch(
-        solver, iters, np.asarray(info.resid2), np.asarray(info.converged)
-    )
+    telemetry.health.end_batch(solver, iters, resid2, converged)
+    return fields["iters_max"]
 
 
 def _make_lanes_tap(solver: str):
@@ -128,7 +161,7 @@ def _make_lanes_tap(solver: str):
     Feeds the health monitor's per-lane detectors; converged
     (frozen) lanes are masked by their tolerance inside ``observe_lanes``
     so a finished lane's bit-stable residual never reads as stagnation."""
-    if not telemetry.enabled() or jax.default_backend() != "cpu":
+    if not _linalg._iter_tapping():
         return None
 
     def tap(k, rn2, tol2):
@@ -218,6 +251,11 @@ def batched_cg(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
     ``b`` is ``(B, n)`` (``tol`` broadcasts per-lane). Returns
     ``(X, BatchedSolveInfo)``. Batch-of-1 matches :func:`sparse_tpu.
     linalg.cg` (same recurrences and conv-test points).
+
+    ``conv_test_iters``: the residual is tested every that many steps and
+    at ``maxiter - 1``. The default of 25 is the unbatched solvers' (and
+    what the session's buckets rely on); it is wrong for solves shorter
+    than 25 steps, which then all run 25: pass 1 for those.
     """
     mv, b, X0, tol, maxiter, _B, n = _prep(A, b, x0, tol, maxiter)
     Mvec = None if M is None else as_batched_matvec(M)
@@ -317,20 +355,142 @@ def batched_ir(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
                     **kwargs)
 
 
+_BICGSTAB_TRACES = _metrics.counter(
+    "batch.bicgstab.traces",
+    help="traces of the compiled batched BiCGStab over declared operators "
+    "(krylov._bicgstab_lanes, the program jit_batched_bicgstab): one per "
+    "program built, none for a call that reuses one",
+)
+
+
+def _bicgstab_lanes(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply,
+                    m_apply, conv_test_iters, tapped):
+    """Whole-solve masked BiCGStab over declared batched operators: A's
+    operands, M's operands, ``b``, the start, the lanes' ``tol`` and
+    ``maxiter`` all arguments, only structure static (the two ``apply``
+    functions, the operands' shapes, the test cadence, whether the loop is
+    tapped), so nothing an operator holds is a constant of the program. The
+    body is :func:`_bicgstab_loop` as the eager call runs it; the scopes
+    ``batch.spmv`` and ``batch.precond`` name the products and the
+    preconditioner's applies in a device trace, as ``bucket.dots`` names
+    the reductions. Returns ``(X, counts)``, ``counts [3, B]`` the lanes'
+    ``iters``, ``resid2`` and ``converged`` as ONE int32 array for the
+    solve's one fetch, the float32 residuals by their bits
+    (:func:`_lane_counts` reads them back). Integers, because a TPU flushes
+    float32 denormals to zero, which is what a small count's bits are: the
+    other way round every count came back 0 on the chip (PR 55)."""
+    _BICGSTAB_TRACES.inc()
+
+    def matvec(X):
+        with jax.named_scope("batch.spmv"):
+            return a_apply(a_operands, X)
+
+    def precond(R):
+        with jax.named_scope("batch.precond"):
+            return m_apply(m_operands, R)
+
+    X, iters, resid2, conv = _bicgstab_loop(
+        matvec, b, x0, tol, maxiter, conv_test_iters,
+        None if m_apply is None else precond)
+    return X, jnp.stack([
+        iters, jax.lax.bitcast_convert_type(resid2, jnp.int32),
+        conv.astype(jnp.int32)])
+
+
+_bicgstab_lanes.__name__ = _bicgstab_lanes.__qualname__ = "batched_bicgstab"
+_bicgstab_program = jax.jit(
+    _bicgstab_lanes,
+    static_argnames=("a_apply", "m_apply", "conv_test_iters", "tapped"),
+)
+
+
+def _lane_counts(counts, static):
+    """The compiled solve's one fetch and what its span says of it; the
+    lanes' arrays ride along as ``lanes`` for the caller
+    (``linalg._run_compiled_solve`` takes them off the fields)."""
+    syncs0 = _linalg.HOST_SYNCS
+    iters, resid2, converged = _linalg._sync_fetch(counts)
+    converged = converged.astype(bool)
+    return {**_lane_fields(iters, converged),
+            "fetches": _linalg.HOST_SYNCS - syncs0,
+            "lanes": (iters, resid2.view(np.float32), converged)}
+
+
+_BICGSTAB = _linalg._CompiledSolve(
+    _bicgstab_program,
+    ("batched_bicgstab.solve", "batched_bicgstab.dispatch",
+     "batched_bicgstab.fetch"),
+    _lane_counts)
+
+
+def _lanes_call(A, M, b, X0, tol, maxiter, conv_test_iters):
+    """``(args, static)`` with which ``jit_batched_bicgstab`` runs this
+    solve, or None where the eager loop takes it: the rule
+    ``linalg._declared_pair`` states for the unbatched solves (both sides
+    declare what they hold, no outer trace is open, no fault clause wraps
+    the product), for float32 lanes, which the packed counts are made for."""
+    if (in_trace() or b.dtype != jnp.float32
+            or getattr(A, "apply", None) is None
+            or not (M is None or getattr(M, "apply", None) is not None)
+            or (_faults.ACTIVE and _faults.targets("matvec"))):
+        return None
+    m_apply, m_operands = (None, ()) if M is None else (M.apply, M.operands)
+    return ((A.operands, m_operands, b, X0, tol, _linalg._i32(maxiter)),
+            dict(a_apply=A.apply, m_apply=m_apply,
+                 conv_test_iters=max(int(conv_test_iters), 1),
+                 tapped=_linalg._iter_tapping()))
+
+
+def _lanes_fields(A, M, b) -> dict:
+    """What the compiled solve's span says of its operands."""
+    B, n = b.shape
+    fields = {"B": int(B), "n": int(n),
+              "precond": "none" if M is None else getattr(
+                  M, "describe", {}).get("precond", "declared")}
+    offsets = getattr(A.apply, "offsets", None)
+    return fields if offsets is None else {**fields, "diags": len(offsets)}
+
+
 def batched_bicgstab(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
                      conv_test_iters=25):
-    """Batched BiCGStab; see :func:`batched_cg` for the lane contract.
-    ``M`` right-preconditions (applied to the search directions), so the
-    residual recurrence — and the stopping rule — stay those of the
-    unpreconditioned solver."""
-    mv, b, X0, tol, maxiter, _B, n = _prep(A, b, x0, tol, maxiter)
-    Mvec = None if M is None else as_batched_matvec(M)
-    X, iters, resid2, conv = _bicgstab_loop(
-        mv, b, X0, tol, maxiter, conv_test_iters, Mvec
-    )
-    info = BatchedSolveInfo(iters, resid2, conv)
-    _solve_event("bicgstab", info, n)
+    """Batched BiCGStab; see :func:`batched_cg` for the lane contract and
+    for ``conv_test_iters``' default. ``M`` right-preconditions (applied to
+    the search directions), so the residual recurrence — and the stopping
+    rule — stay those of the unpreconditioned solver.
+
+    Over an operator and a preconditioner that declare what they hold
+    (``BatchedDIA``; the Jacobi factory's ``Mvec``, or none), in float32,
+    the call is ONE compiled program, ``jit_batched_bicgstab``
+    (:func:`_bicgstab_lanes`), found again by the two ``apply`` functions
+    and the shapes: other values, another ``b``, ``x0``, ``tol`` or
+    ``maxiter`` trace and compile nothing. One dispatch and one fetch a
+    call; ``info``'s arrays are then on the host. Anything else runs the
+    loop eagerly, compiled anew at every call, and ``info`` stays on the
+    device."""
+    with _linalg._solver_call():
+        mv, b, X0, tol, maxiter, _B, n = _prep(A, b, x0, tol, maxiter)
+        call = _lanes_call(A, M, b, X0, tol, maxiter, conv_test_iters)
+        if call is not None:
+            X, lanes = _linalg._run_compiled_solve(
+                _BICGSTAB, call, _lanes_fields(A, M, b))
+        else:
+            Mvec = None if M is None else as_batched_matvec(M)
+            X, *lanes = _bicgstab_loop(
+                mv, b, X0, tol, maxiter, conv_test_iters, Mvec
+            )
+        info = BatchedSolveInfo(*lanes)
+        iters_max = _solve_event("bicgstab", info, n)
+        _linalg._solve_event("batched_bicgstab", n, iters_max, "device")
     return X, info
+
+
+def _bicgstab_compiled(A, b, M=None, conv_test_iters=25):
+    """The executable ``batched_bicgstab(A, b, M=M)`` runs (its scopes:
+    ``batch.spmv``, ``batch.precond``, ``bucket.dots``), or None where that
+    call takes the eager loop (``linalg._compiled_call``)."""
+    _mv, b, X0, tol, maxiter, _B, _n = _prep(A, b, None, 1e-8, 1)
+    return _linalg._compiled_call(
+        _BICGSTAB, _lanes_call(A, M, b, X0, tol, maxiter, conv_test_iters))
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,8 @@ through it, so the unbatched solver surface keeps working on a batch.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -401,10 +403,21 @@ def pattern_matvec(pattern: SparsityPattern):
 
 class BatchedOperator:
     """Abstract batched linear operator: ``matvec`` maps ``(B, n)`` ->
-    ``(B, m)``, one independent system per lane."""
+    ``(B, m)``, one independent system per lane.
+
+    An operator may DECLARE what its product reads, as
+    :class:`~sparse_tpu.linalg.LinearOperator` does: ``operands`` (a pytree
+    of arrays) apart from ``apply(operands, X)`` (pure, traceable, equal by
+    value for every operator of the same structure). A batched solver can
+    then hand the arrays to one compiled program as arguments
+    (:func:`~sparse_tpu.batch.krylov.batched_bicgstab`); an operator that
+    declares nothing (``apply`` None) reaches a solver as the closure
+    ``matvec``."""
 
     shape: tuple  # (B, m, n)
     dtype: np.dtype
+    apply = None
+    operands = None
 
     @property
     def batch(self) -> int:
@@ -591,13 +604,29 @@ class BatchedCSR(BatchedOperator):
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class _PlanesApply:
+    """``apply`` of a plane stack: ``operands = (planes [B, D, m],)``, the
+    product ``ops.dia_spmv.dia_planes_matvec`` over the static offsets;
+    equal by value for two stacks of the same offsets."""
+
+    offsets: tuple
+
+    def __call__(self, operands, X):
+        from ..ops.dia_spmv import dia_planes_matvec
+
+        return dia_planes_matvec(operands[0], self.offsets, X)
+
+
 class BatchedDIA(BatchedOperator):
     """Stacked diagonal planes ``(B, D, m)`` over shared offsets — the
     batched zero-gather SpMV for banded patterns (every PDE/mesh serving
     shape). ROW layout, as on the mesh and in the session's bucket
     program: ``data[b, k, i]`` holds ``A_b[i, i + o_k]`` (scipy's DIA
     indexes a plane by column; :meth:`lane` converts). One
-    ``ops.dia_spmv.dia_planes_matvec`` pass, no index loads at all."""
+    ``ops.dia_spmv.dia_planes_matvec`` pass, no index loads at all.
+    Declares what it holds: ``operands`` are the planes, ``apply`` the
+    product over the offsets (:class:`_PlanesApply`)."""
 
     def __init__(self, data, offsets, shape):
         data = asjnp(data)
@@ -614,11 +643,24 @@ class BatchedDIA(BatchedOperator):
         self.shape = (int(data.shape[0]), m, n)
         self.dtype = np.dtype(data.dtype)
 
+    @property
+    def apply(self):
+        return _PlanesApply(self.offsets)
+
+    @property
+    def operands(self):
+        return (self.data,)
+
     @classmethod
     def from_batched_csr(cls, bcsr: BatchedCSR, max_diags=None):
         pack = bcsr.pattern.dia_pack(max_diags=max_diags)
-        return cls(pack.pack_values(bcsr.values), pack.offsets,
-                   bcsr.pattern.shape)
+        # the value stack's one repack, on the device; live, the span waits
+        # for the planes so that the gather's time is in it
+        with telemetry.span("batch.values_pack", form="planes",
+                            B=bcsr.batch, diags=len(pack.offsets),
+                            n=bcsr.pattern.shape[0]) as sp:
+            data = sp.set_sync(pack.pack_values(bcsr.values))
+        return cls(data, pack.offsets, bcsr.pattern.shape)
 
     def lane(self, i: int):
         """Lane ``i`` as a ``dia_array`` (scipy convention: plane ``k``

@@ -939,7 +939,10 @@ class _CompiledSolve:
 
 def _run_compiled_solve(solve_of, call, fields):
     """The host's side of a compiled whole solve, ``call`` its ``(args,
-    static)``: ``(x, iters)``. One ``<solver>.solve`` span a call, with
+    static)``: ``(x, iters)``, or ``(X, the lanes' counts)`` for a solve of
+    many lanes (``solve_of.fields`` then gives them as ``lanes``, and no
+    ``iters``). One
+    ``<solver>.solve`` span a call, with
     ``path="device"`` and the caller's ``fields``; inside it
     ``<solver>.dispatch`` is the program's call until it returns
     (asynchronous: the host's part, and on a structure's first call the
@@ -956,13 +959,16 @@ def _run_compiled_solve(solve_of, call, fields):
         with telemetry.span(fetch, emit=False) as sp:
             counted = solve_of.fields(counts, static)
         fetch_s = sp.dur_s or 0.0
+        # a solve of many lanes (batch/krylov.py) hands back its lanes'
+        # fetched counts, which are no field of the span
+        lanes = counted.pop("lanes", None)
         solve.annotate(**counted, **_mesh_fields(x),
                        dispatch_s=round(dispatch_s, 9),
                        fetch_s=round(fetch_s, 9))
     _call_solved(solve, dispatch_s, fetch_s)
     if static["tapped"]:
         _effects_barrier()
-    return x, counted["iters"]
+    return x, counted["iters"] if lanes is None else lanes
 
 
 def _compiled_solve(solve_of, A, M, b, x, stop, declare=None, **static):
@@ -975,7 +981,13 @@ def _compiled_solve(solve_of, A, M, b, x, stop, declare=None, **static):
     own :func:`_declared_call`, where it has one."""
     A = make_linear_operator(A)
     M = IdentityOperator(A.shape, dtype=A.dtype) if M is None else make_linear_operator(M)
-    call = (declare or _declared_call)(A, M, b, x, stop, 1, **static)
+    return _compiled_call(
+        solve_of, (declare or _declared_call)(A, M, b, x, stop, 1, **static))
+
+
+def _compiled_call(solve_of, call):
+    """The executable that ``call`` (a solve's ``(args, static)``, or None)
+    runs."""
     return call and solve_of.program.lower(*call[0], **call[1]).compile()
 
 
@@ -1336,6 +1348,15 @@ def batched_gmres(A, b, **kwargs):
     from .batch.krylov import batched_gmres as _impl
 
     return _impl(A, b, **kwargs)
+
+
+def _batched_bicgstab_compiled(A, b, M=None, conv_test_iters=25):
+    """The compiled program ``batched_bicgstab(A, b, M=M)`` runs, or None
+    where that call takes the eager loop
+    (:func:`sparse_tpu.batch.krylov._bicgstab_compiled`)."""
+    from .batch.krylov import _bicgstab_compiled as _impl
+
+    return _impl(A, b, M, conv_test_iters)
 
 
 def batched_ir(A, b, **kwargs):

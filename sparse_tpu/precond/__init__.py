@@ -41,6 +41,7 @@ from .ilu import (  # noqa: F401
     ilu_factory,
 )
 from .jacobi import (  # noqa: F401
+    _scale,
     bjacobi_factory,
     block_map,
     diag_map,
@@ -154,8 +155,3 @@ def make_M(A, kind: str = "jacobi", solver: str = "cg",
         return Mvec(asjnp(x)[None, :])[0]
 
     return LinearOperator((n, n), matvec=mv, dtype=np.dtype(values.dtype))
-
-
-def _scale(operands, v):
-    """``apply`` of a declared diagonal preconditioner."""
-    return v * operands[0]

@@ -30,7 +30,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from .. import plan_cache
+from .. import plan_cache, telemetry
 from ..utils import commit_to_exec_device, host_scope
 
 
@@ -91,10 +91,20 @@ def diag_of(pattern, values):
     return jnp.where(has, d, jnp.ones((), dtype=values.dtype))
 
 
+def _scale(operands, v):
+    """``apply`` of a declared diagonal preconditioner: one lane's vector
+    or a lane stack times the reciprocal diagonal ``operands[0]``."""
+    return v * operands[0]
+
+
 def jacobi_factory(pattern, storage_dtype=None, acc_dtype=None):
     """Point-Jacobi numeric factory: ``factory(values, matvec) -> Mvec``
     with ``Mvec(R) = R / diag(A)`` per lane. The map build (host) runs
-    here, once per pattern; the returned factory is pure jnp.
+    here, once per pattern; the returned factory is pure jnp. ``Mvec``
+    declares what it holds, as ``linalg.LinearOperator`` does: ``Mvec.apply``
+    is :func:`_scale`, ``Mvec.operands`` the reciprocal diagonal ``(dinv
+    [B, n],)``, ``Mvec.describe`` names the kind, so that a batched solver
+    can hand the array to a compiled program as an argument.
 
     ``storage_dtype`` / ``acc_dtype`` (ISSUE 16): the reciprocal is
     computed at ``acc_dtype`` and STORED at ``storage_dtype`` — the
@@ -106,16 +116,23 @@ def jacobi_factory(pattern, storage_dtype=None, acc_dtype=None):
     adt = None if acc_dtype is None else jnp.dtype(acc_dtype)
 
     def factory(values, matvec=None):
-        d = diag_of(pattern, values)
-        if adt is not None:
-            d = d.astype(adt)
-        dinv = _safe_recip(d)
-        if sdt is not None:
-            dinv = dinv.astype(sdt)
+        # a no-op inside a bucket program's trace; live, the span waits
+        # for the diagonal so that the gather's time is in it
+        with telemetry.span("batch.values_pack", form="jacobi",
+                            n=pattern.shape[0]) as sp:
+            d = diag_of(pattern, values)
+            if adt is not None:
+                d = d.astype(adt)
+            dinv = _safe_recip(d)
+            if sdt is not None:
+                dinv = dinv.astype(sdt)
+            sp.set_sync(dinv)
 
         def Mvec(R):
             return R * dinv
 
+        Mvec.apply, Mvec.operands = _scale, (dinv,)
+        Mvec.describe = {"precond": "jacobi"}
         return Mvec
 
     return factory

@@ -1526,3 +1526,56 @@ def test_hpcg_pcg_program_compiles_at_the_cells_size(one_chip, monkeypatch):
              if n.endswith("/transpose") and "/while/body/" in n}
     assert moved and all(re.search(r"hpcg\.l\d\.transfer/transpose$", n)
                          for n in moved), moved
+
+
+# ---------------------------------------------------------------------------
+# jit_batched_bicgstab (PR 55: batch/krylov.py) at the cell's shapes: 32,768
+# lanes of XGC's collision system, 992 rows and nine planes a lane, Jacobi
+# ---------------------------------------------------------------------------
+XGC_LANES, XGC_ROWS = 32768, 992
+XGC_OFFSETS = (-33, -32, -31, -1, 0, 1, 31, 32, 33)
+
+
+def test_batched_bicgstab_program_compiles_at_the_cells_shapes(one_chip):
+    import time
+
+    from sparse_tpu.batch import krylov
+    from sparse_tpu.batch.operator import _PlanesApply
+    from sparse_tpu.precond.jacobi import _scale
+
+    B, n, D = XGC_LANES, XGC_ROWS, len(XGC_OFFSETS)
+    vec = _sds((B, n), jnp.float32, one_chip)
+    t0 = time.perf_counter()
+    c = krylov._bicgstab_program.lower(
+        (_sds((B, D, n), jnp.float32, one_chip),), (vec,), vec, vec,
+        _sds((B,), jnp.float32, one_chip), 200,
+        a_apply=_PlanesApply(XGC_OFFSETS), m_apply=_scale, conv_test_iters=1,
+        tapped=False).compile()
+    seconds = time.perf_counter() - t0
+    print(f"jit_batched_bicgstab at {B} lanes: traced, lowered and compiled "
+          f"in {seconds:.1f} s")
+    assert seconds < 30, seconds  # 2 s here alone
+    text = c.as_text()
+    assert "jit_batched_bicgstab" in text
+    ma = c.memory_analysis()
+    # the planes, the diagonal, b and the start are arguments (nothing an
+    # operator holds is a constant), unpadded: the compiler lays the lanes
+    # along the 128-wide axis of a tile and the 992 rows along the 8-wide one
+    held = 4 * B * n * (D + 3)
+    assert held <= ma.argument_size_in_bytes < held + 1e6
+    assert re.search(rf"f32\[{B},{D},{n}\]\{{0,2,1:T\(8,128\)\}} parameter\(0\)", text)
+    assert not re.search(rf"f32\[{B},{n}\]\{{1,0", text)
+    # the loop's carried vectors and a step's temporaries: a dozen vectors
+    assert ma.temp_size_in_bytes < 14 * 4 * B * n
+    assert _device_bytes(c) < 4e9 < HBM_BYTES
+    # one fetch: the lanes' counts leave as one [3, B] array beside X, of
+    # integers (the residuals by their bits): the chip flushes float32
+    # denormals to zero, which is what a small count's bits would be
+    assert re.search(rf"ROOT %\S+ = \(f32\[{B},{n}\]\S+ s32\[3,{B}\]\S+ tuple\(", text)
+    # the scopes the cell's shares read are on the loop's ops, and nothing
+    # in the program gathers
+    body = [ln for ln in text.splitlines() if "/while/body/" in ln]
+    for scope in ("/batch.spmv/", "/batch.precond/", "/bucket.dots/"):
+        assert any(scope in ln for ln in body), scope
+    assert not re.search(r" (gather|scatter)\(", text)
+    assert "tpu_custom_call" not in text

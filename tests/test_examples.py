@@ -58,6 +58,20 @@ def test_hpcg_example():
     assert re.search(r"GFLOP/s \(HPCG's count\): [0-9.]+", out)
 
 
+def test_xgc_collision_example():
+    # thousands of small same-pattern systems in one call (here 64): every
+    # lane converges, in two groups of iteration counts
+    out = _run("xgc_collision.py", "--precision", "f32", "-systems", "64",
+               "-seed", "7", "-calls", "2")
+    assert re.search(r"Systems: 64  converged: 64", out), out
+    m = re.search(r"ions \((\d+), ([0-9.]+), (\d+)\)  electrons "
+                  r"\((\d+), ([0-9.]+), (\d+)\)", out)
+    assert m and 3 * float(m.group(2)) <= float(m.group(5)), out
+    assert float(re.search(r"Largest relative residual: ([0-9.e+-]+)",
+                           out).group(1)) < 2e-5
+    assert float(re.search(r"Frozen lane-steps: ([0-9.]+) %", out).group(1)) > 30
+
+
 def test_gmg_example_generic_path():
     # --no-grid keeps the generic sparse-matrix hierarchy (GMG class,
     # SpGEMM Galerkin products) exercised end-to-end
