@@ -17,8 +17,10 @@ assembly hands it over) is repacked once into nine planes a lane
 one compiled program, ``jit_batched_bicgstab``, whose arguments are the planes,
 the diagonal, b, the start and the lanes' tolerances: the second call (the
 next Picard iteration: new values on the same pattern) traces and compiles
-nothing. A lane that has converged is frozen under its mask until the batch's
-last lane stops. The matrices here are made by the benchmark's generator
+nothing. A lane that has converged is frozen under its mask; a call of 7,937
+systems or more stops stepping the ones that are done (the program compacts
+its active lanes down a ladder of halving widths: docs/batching.md). The
+matrices here are made by the benchmark's generator
 (``benchmark/operators/xgc_collision.py``: A = I - dt C, C a finite-volume
 discretisation of div (D grad f + F f), strictly diagonally dominant), which
 stands in for XGC's own assembly.

@@ -28,7 +28,12 @@ and a preconditioner that declare what they hold (a
 ``Mvec``), in float32, runs ``jit_batched_bicgstab``, whose arguments are
 the planes, the reciprocal diagonal, ``b``, the start, the lanes' ``tol``
 and ``maxiter``, so that a later call of the same shapes, whatever the
-values, traces and compiles nothing. Every other call
+values, traces and compiles nothing. That program stops stepping lanes
+that are done (PR 56): a batch wide enough runs down a static ladder of
+halving widths (:func:`_bicgstab_ladder`), the lanes still active gathered
+into the next width between two stages and every lane's answer scattered
+back to its place, so that a lane waits frozen for the lanes of its stage
+and not of the batch (docs/batching.md). Every other call
 (:func:`batched_cg`, :func:`batched_gmres`, a callable, a dense stack, a
 ``BatchedCSR``, a closure ``M``, float64, complex) runs its loop eagerly
 with the operator's arrays closed over, and so traces, lowers and compiles
@@ -115,34 +120,43 @@ def _prep(A, b, x0, tol, maxiter):
     return mv, b, X0, tol, int(maxiter), B, n
 
 
-def _lane_fields(iters, converged) -> dict:
+def _lane_fields(iters, converged, stepped=None) -> dict:
     """What a solve's span and its ``batch.solve`` event say of the lanes'
-    fetched counts. ``frozen_lane_pct`` is the share of the loop's
-    lane-steps that a lane which had already stopped spent frozen under its
-    mask, waiting for the batch's last lane: 100 (1 - iters_sum / (B
-    iters_max))."""
+    fetched counts. ``stepped`` holds, a stage, the lane-steps the program
+    stepped there (the stage's width x its trips: the compiled
+    ``batched_bicgstab``'s ladder, :func:`_bicgstab_ladder`); None is one
+    loop at the batch's width, ``B iters_max``. ``stages`` counts the
+    stages that ran a step, ``lane_steps`` sums them, and
+    ``frozen_lane_pct`` is the share of the lane-steps the program stepped
+    that a lane which had already stopped spent frozen under its mask,
+    waiting for a lane of its stage: 100 (1 - iters_sum / lane_steps)."""
     B = int(iters.shape[0])
     iters_max, iters_sum = int(iters.max(initial=0)), int(iters.sum())
+    if stepped is None:
+        stepped = np.asarray([B * iters_max])
+    lane_steps = int(stepped.sum())
     return {
         "iters_max": iters_max, "iters_sum": iters_sum,
         "iters_mean": iters_sum / B if B else 0.0,
+        "stages": int(np.count_nonzero(stepped)), "lane_steps": lane_steps,
         "frozen_lane_pct": round(
-            100.0 * (1.0 - iters_sum / (B * iters_max)), 3
-        ) if iters_max else 0.0,
+            100.0 * (1.0 - iters_sum / lane_steps), 3
+        ) if lane_steps else 0.0,
         "converged": int(np.count_nonzero(converged)),
     }
 
 
-def _solve_event(solver: str, info: BatchedSolveInfo, n: int):
+def _solve_event(solver: str, info: BatchedSolveInfo, n: int, stepped=None):
     """One ``batch.solve`` event per completed batched solve; returns the
     lanes' largest count (None with telemetry off). The lanes' counts come
     to the host in ONE fetch, and only with telemetry on (documented sync
-    cost); a compiled solve's are on the host already."""
+    cost); a compiled solve's are on the host already, with what its stages
+    ``stepped`` (:func:`_lane_fields`)."""
     if not telemetry.enabled():
         return None
     iters, resid2, converged = (np.asarray(a) for a in jax.device_get(
         (info.iters, info.resid2, info.converged)))
-    fields = _lane_fields(iters, converged)
+    fields = _lane_fields(iters, converged, stepped)
     telemetry.record(
         "batch.solve", solver=solver, B=int(iters.shape[0]), n=int(n),
         **fields,
@@ -270,26 +284,26 @@ def batched_cg(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
 # ---------------------------------------------------------------------------
 # BiCGStab
 # ---------------------------------------------------------------------------
-def _bicgstab_loop(matvec, b, X0, tol, maxiter, conv_test_iters,
-                   Mvec=None, lane_reduce=None):
-    """Masked batched BiCGStab core — the recurrences of
-    ``linalg.bicgstab`` with per-lane scalars and frozen converged lanes.
-    ``lane_reduce`` is the sharded all-converged exit hook (see
-    :func:`_cg_loop`). ``Mvec`` right-preconditions the search
-    directions (``p_hat = M p``, ``s_hat = M s``) — ``None`` (the
-    default) traces byte-identically to the unpreconditioned loop."""
-    tol2 = tol.astype(jnp.real(b).dtype) ** 2
+def _bicgstab_start(matvec, b, X0):
+    """The masked BiCGStab's state before its first step, ``(X, R, P, V, rho,
+    alpha, omega, active, iters, k)``: every lane active, ``R = b - A X0``
+    (the shadow residual is this ``R``), one step counter ``k`` for the
+    batch."""
     B = b.shape[0]
-    cti = max(int(conv_test_iters), 1)
-    any_active = jnp.any if lane_reduce is None else lane_reduce
-    # sharded loops: no per-iteration host taps (see _cg_loop)
-    tap = None if lane_reduce is not None else _make_lanes_tap("bicgstab")
-    X = X0
-    R = b - matvec(X)
-    Rt = R
+    R = b - matvec(X0)
     Z = jnp.zeros_like(b)
     one = jnp.ones((B,), dtype=b.dtype)
     zero = jnp.zeros((B,), dtype=b.dtype)
+    return (X0, R, Z, Z, zero, one, one,
+            jnp.ones((B,), bool), jnp.zeros((B,), jnp.int32),
+            jnp.zeros((), jnp.int32))
+
+
+def _bicgstab_step(matvec, Mvec, Rt, tol2, maxiter, cti, tap):
+    """One masked step over the state of :func:`_bicgstab_start`, as a
+    ``while_loop`` body: ``Rt`` the lanes' shadow residuals and ``tol2``
+    their squared tolerances, at the width of the state the body is
+    given."""
 
     def body(st):
         X, R, P, V, rho, alpha, omega, active, iters, k = st
@@ -328,16 +342,158 @@ def _bicgstab_loop(matvec, b, X0, tol, maxiter, conv_test_iters,
         active = active & ~(tested & (rn2 < tol2))
         return X, R, P, V, rho, alpha, omega, active, iters, k
 
+    return body
+
+
+def _bicgstab_loop(matvec, b, X0, tol, maxiter, conv_test_iters,
+                   Mvec=None, lane_reduce=None):
+    """Masked batched BiCGStab core — the recurrences of
+    ``linalg.bicgstab`` with per-lane scalars and frozen converged lanes.
+    ``lane_reduce`` is the sharded all-converged exit hook (see
+    :func:`_cg_loop`). ``Mvec`` right-preconditions the search
+    directions (``p_hat = M p``, ``s_hat = M s``) — ``None`` (the
+    default) traces byte-identically to the unpreconditioned loop. One
+    ``while_loop`` at the batch's width over :func:`_bicgstab_start` and
+    :func:`_bicgstab_step`, which the compiled call's ladder of widths
+    (:func:`_bicgstab_ladder`) runs too."""
+    tol2 = tol.astype(jnp.real(b).dtype) ** 2
+    cti = max(int(conv_test_iters), 1)
+    any_active = jnp.any if lane_reduce is None else lane_reduce
+    # sharded loops: no per-iteration host taps (see _cg_loop)
+    tap = None if lane_reduce is not None else _make_lanes_tap("bicgstab")
+    st = _bicgstab_start(matvec, b, X0)
+    body = _bicgstab_step(matvec, Mvec, st[1], tol2, maxiter, cti, tap)
+
     def cond(st):
         active, k = st[7], st[9]
         return (k < maxiter) & any_active(active)
 
-    st = (X, R, Z, Z, zero, one, one,
-          jnp.ones((B,), bool), jnp.zeros((B,), jnp.int32),
-          jnp.zeros((), jnp.int32))
-    out = jax.lax.while_loop(cond, body, st)
-    X, R, active, iters = out[0], out[1], out[7], out[8]
+    return _bicgstab_answers(jax.lax.while_loop(cond, body, st))
+
+
+def _bicgstab_answers(st):
+    """``(X, iters, resid2, converged)`` of a state's lanes."""
+    X, R, active, iters = st[0], st[1], st[7], st[8]
     return X, iters, jnp.real(_bdot(R, R)), ~active
+
+
+# -- the compiled call's ladder of widths ------------------------------------
+# The narrowest stage, in lanes: below it a step is no longer bound by the
+# bytes of its arrays and another stage only adds to the program's text
+# (PERF.md, PR 56: read on the chip at 4096 and 1024). Widths are whole
+# tiles of 128 lanes.
+_LADDER_FLOOR = 4096
+_LANE_TILE = 128
+
+
+def _ladder(B: int) -> tuple:
+    """The static widths of the stages of a compiled solve of ``B`` lanes:
+    ``B``, then halves rounded up to whole tiles while they stay at or over
+    the floor. ``(B,)`` for a batch too narrow for a second stage."""
+    widths = [int(B)]
+    while True:
+        half = -(-widths[-1] // (2 * _LANE_TILE)) * _LANE_TILE
+        if half < _LADDER_FLOOR or half >= widths[-1]:
+            return tuple(widths)
+        widths.append(half)
+
+
+def _stage_widths(B: int, lane_operands, tapped: bool) -> tuple:
+    """The widths ``jit_batched_bicgstab`` steps ``B`` lanes at, from what
+    is static in it: one stage at ``B`` unless both operators say which of
+    their operands hold lanes and the loop is not tapped (the CPU's
+    per-step tap reports the full width)."""
+    return (int(B),) if lane_operands is None or tapped else _ladder(B)
+
+
+def _compact(st, Rt, tol2, place, held, narrow, width, batch):
+    """A stage's active lanes moved to the front of ``width`` slots, in the
+    order they had: every array with a lane axis (the state's vectors and
+    lane scalars, the shadow residuals, the tolerances, the operators'
+    lane-holding operands through ``narrow``) and ``place``, the lanes'
+    places in the caller's ``batch`` lanes. The slots past the active count
+    hold the stage's last lane, inactive, at places past the batch's end,
+    where the scatter of :func:`_bicgstab_ladder` drops them: they are never
+    counted, never written back and never hold a loop open."""
+    X, R, P, V, rho, alpha, omega, active, iters, k = st
+    (sel,) = jnp.nonzero(active, size=width, fill_value=active.shape[0] - 1)
+    slots = jnp.arange(width, dtype=place.dtype)
+    live = slots < jnp.count_nonzero(active)
+
+    def take(a):
+        return a.at[sel].get(mode="promise_in_bounds", indices_are_sorted=True)
+
+    st = (*(take(a) for a in (X, R, P, V, rho, alpha, omega)),
+          live, take(iters), k)
+    # unique and sorted, the dropped ones too
+    place = jnp.where(live, take(place), batch + slots)
+    return st, take(Rt), take(tol2), place, narrow(held, take)
+
+
+def _bicgstab_ladder(products, held, narrow, b, X0, tol, maxiter, cti,
+                     widths):
+    """The masked BiCGStab of :func:`_bicgstab_loop` down a static ladder
+    of ``widths`` (:func:`_ladder`), so that lanes which are done stop
+    being stepped. Stage j runs :func:`_bicgstab_step` on its ``widths[j]``
+    lanes until its active lanes FIT the next width (the last one until
+    none is active), then the active ones are compacted into the next
+    stage (:func:`_compact`, scope ``batch.compact``) and, at that stage's
+    end, their ``X``, ``iters``, ``resid2`` and ``converged`` scattered
+    back to their places in the full-width results (same scope). The step
+    counter ``k`` runs on through the stages, so the test cadence and
+    ``maxiter - 1`` fall where they fall in the one loop, and a lane
+    freezes bit-stable as there: every lane takes the steps it takes in
+    :func:`_bicgstab_loop`. A stage and its compaction sit under a
+    ``lax.cond`` on "a lane is still active", so a batch whose lanes stop
+    together runs stage 0 alone.
+
+    ``products(held)`` gives ``(matvec, Mvec)`` over the operators'
+    operands ``held``; ``narrow(held, take)`` gives ``held`` with ``take``
+    mapped over the operands that hold lanes. Returns ``(X, iters, resid2,
+    converged, trips)``, ``trips [len(widths)]`` each stage's steps."""
+    B, last = b.shape[0], len(widths) - 1
+    tol2 = tol.astype(jnp.real(b).dtype) ** 2
+
+    def stage(j, st, Rt, tol2, held):
+        fits = widths[j + 1] if j < last else 0
+        body = _bicgstab_step(*products(held), Rt, tol2, maxiter, cti, None)
+
+        def cond(st):
+            return (st[9] < maxiter) & (jnp.count_nonzero(st[7]) > fits)
+
+        return jax.lax.while_loop(cond, body, st)
+
+    def rest(j, st, Rt, tol2, held, place, out):
+        """Stages ``j + 1`` on, from stage ``j``'s last state: the results
+        and those stages' trips."""
+        if j == last:
+            return (*out, jnp.zeros((0,), jnp.int32))
+
+        def go():
+            with jax.named_scope("batch.compact"):
+                st1, Rt1, tol21, place1, held1 = _compact(
+                    st, Rt, tol2, place, held, narrow, widths[j + 1], B)
+            st1 = stage(j + 1, st1, Rt1, tol21, held1)
+            mine = _bicgstab_answers(st1)
+            with jax.named_scope("batch.compact"):
+                out1 = tuple(
+                    full.at[place1].set(a, mode="drop",
+                                        indices_are_sorted=True,
+                                        unique_indices=True)
+                    for full, a in zip(out, mine))
+            *out2, trips = rest(j + 1, st1, Rt1, tol21, held1, place1, out1)
+            return (*out2, jnp.concatenate([(st1[9] - st[9])[None], trips]))
+
+        return jax.lax.cond(
+            (st[9] < maxiter) & jnp.any(st[7]), go,
+            lambda: (*out, jnp.zeros((last - j,), jnp.int32)))
+
+    st = _bicgstab_start(products(held)[0], b, X0)
+    Rt = st[1]
+    st = stage(0, st, Rt, tol2, held)
+    *out, trips = rest(0, st, Rt, tol2, held, jnp.arange(B, dtype=jnp.int32),
+                       _bicgstab_answers(st))
+    return (*out, jnp.concatenate([st[9][None], trips]))
 
 
 def batched_ir(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
@@ -364,56 +520,93 @@ _BICGSTAB_TRACES = _metrics.counter(
 
 
 def _bicgstab_lanes(a_operands, m_operands, b, x0, tol, maxiter, *, a_apply,
-                    m_apply, conv_test_iters, tapped):
+                    m_apply, conv_test_iters, tapped, lane_operands=None):
     """Whole-solve masked BiCGStab over declared batched operators: A's
     operands, M's operands, ``b``, the start, the lanes' ``tol`` and
     ``maxiter`` all arguments, only structure static (the two ``apply``
-    functions, the operands' shapes, the test cadence, whether the loop is
-    tapped), so nothing an operator holds is a constant of the program. The
-    body is :func:`_bicgstab_loop` as the eager call runs it; the scopes
-    ``batch.spmv`` and ``batch.precond`` name the products and the
-    preconditioner's applies in a device trace, as ``bucket.dots`` names
-    the reductions. Returns ``(X, counts)``, ``counts [3, B]`` the lanes'
-    ``iters``, ``resid2`` and ``converged`` as ONE int32 array for the
-    solve's one fetch, the float32 residuals by their bits
-    (:func:`_lane_counts` reads them back). Integers, because a TPU flushes
-    float32 denormals to zero, which is what a small count's bits are: the
-    other way round every count came back 0 on the chip (PR 55)."""
+    functions, the operands' shapes and which of them hold lanes, the test
+    cadence, whether the loop is tapped), so nothing an operator holds is a
+    constant of the program. The step is :func:`_bicgstab_loop`'s as the
+    eager call runs it; the scopes ``batch.spmv`` and ``batch.precond``
+    name the products and the preconditioner's applies in a device trace, as
+    ``bucket.dots`` names the reductions.
+
+    Where both operators say which operands hold lanes (``lane_operands``:
+    A's flags and M's), the loop is not tapped and the batch is wide enough
+    (:func:`_stage_widths`), the solve runs down the ladder of
+    :func:`_bicgstab_ladder`, ``batch.compact`` the scope of its gathers
+    and scatters; else it is the one loop at the batch's width.
+
+    Returns ``(X, counts)``, ``counts [3, B]`` the lanes' ``iters``,
+    ``resid2`` and ``converged`` as ONE int32 array for the solve's one
+    fetch, the float32 residuals by their bits (:func:`_lane_counts` reads
+    them back); the ladder adds a fourth row, whose first entries are its
+    stages' trips. Integers, because a TPU flushes float32 denormals to
+    zero, which is what a small count's bits are: the other way round every
+    count came back 0 on the chip (PR 55)."""
     _BICGSTAB_TRACES.inc()
 
-    def matvec(X):
-        with jax.named_scope("batch.spmv"):
-            return a_apply(a_operands, X)
+    def products(held):
+        a_held, m_held = held
 
-    def precond(R):
-        with jax.named_scope("batch.precond"):
-            return m_apply(m_operands, R)
+        def matvec(X):
+            with jax.named_scope("batch.spmv"):
+                return a_apply(a_held, X)
 
-    X, iters, resid2, conv = _bicgstab_loop(
-        matvec, b, x0, tol, maxiter, conv_test_iters,
-        None if m_apply is None else precond)
+        def precond(R):
+            with jax.named_scope("batch.precond"):
+                return m_apply(m_held, R)
+
+        return matvec, None if m_apply is None else precond
+
+    def narrow(held, take):
+        return tuple(
+            tuple(jax.tree.map(take, op) if lanes else op
+                  for op, lanes in zip(ops, flags))
+            for ops, flags in zip(held, lane_operands))
+
+    B = b.shape[0]
+    widths = _stage_widths(B, lane_operands, tapped)
+    held = (a_operands, m_operands)
+    if len(widths) == 1:
+        matvec, precond = products(held)
+        X, iters, resid2, conv = _bicgstab_loop(
+            matvec, b, x0, tol, maxiter, conv_test_iters, precond)
+        rows = []
+    else:
+        X, iters, resid2, conv, trips = _bicgstab_ladder(
+            products, held, narrow, b, x0, tol, maxiter, conv_test_iters,
+            widths)
+        rows = [jnp.pad(trips, (0, B - len(widths)))]
     return X, jnp.stack([
         iters, jax.lax.bitcast_convert_type(resid2, jnp.int32),
-        conv.astype(jnp.int32)])
+        conv.astype(jnp.int32), *rows])
 
 
 _bicgstab_lanes.__name__ = _bicgstab_lanes.__qualname__ = "batched_bicgstab"
 _bicgstab_program = jax.jit(
     _bicgstab_lanes,
-    static_argnames=("a_apply", "m_apply", "conv_test_iters", "tapped"),
+    static_argnames=("a_apply", "m_apply", "conv_test_iters", "tapped",
+                     "lane_operands"),
 )
 
 
 def _lane_counts(counts, static):
     """The compiled solve's one fetch and what its span says of it; the
-    lanes' arrays ride along as ``lanes`` for the caller
-    (``linalg._run_compiled_solve`` takes them off the fields)."""
+    lanes' arrays and what each stage stepped (width x trips) ride along
+    as ``lanes`` for the caller (``linalg._run_compiled_solve`` takes them
+    off the fields)."""
     syncs0 = _linalg.HOST_SYNCS
-    iters, resid2, converged = _linalg._sync_fetch(counts)
+    iters, resid2, converged, *trips = _linalg._sync_fetch(counts)
     converged = converged.astype(bool)
-    return {**_lane_fields(iters, converged),
+    stepped = None
+    if trips:
+        widths = _stage_widths(iters.shape[0], static["lane_operands"],
+                               static["tapped"])
+        stepped = np.asarray(widths, np.int64) * trips[0][:len(widths)]
+    return {**_lane_fields(iters, converged, stepped),
             "fetches": _linalg.HOST_SYNCS - syncs0,
-            "lanes": (iters, resid2.view(np.float32), converged)}
+            "lanes": (iters, resid2.view(np.float32), converged, stepped)}
 
 
 _BICGSTAB = _linalg._CompiledSolve(
@@ -438,7 +631,24 @@ def _lanes_call(A, M, b, X0, tol, maxiter, conv_test_iters):
     return ((A.operands, m_operands, b, X0, tol, _linalg._i32(maxiter)),
             dict(a_apply=A.apply, m_apply=m_apply,
                  conv_test_iters=max(int(conv_test_iters), 1),
-                 tapped=_linalg._iter_tapping()))
+                 tapped=_linalg._iter_tapping(),
+                 lane_operands=_lane_operands(A, M)))
+
+
+def _lane_operands(A, M):
+    """Which operands of a declared pair hold lanes, ``(A's flags, M's
+    flags)`` with a flag an operand (``lane_operands`` beside ``apply`` and
+    ``operands``: True for an operand whose arrays have the lanes as their
+    leading axis, which the ladder's compaction gathers; False for one the
+    lanes share), or None where a side does not say: the solve is then one
+    loop at the batch's width."""
+    flags = []
+    for op in (A, M):
+        said = () if op is None else getattr(op, "lane_operands", None)
+        if said is None or len(said) != len(getattr(op, "operands", ())):
+            return None
+        flags.append(tuple(bool(x) for x in said))
+    return tuple(flags)
 
 
 def _lanes_fields(A, M, b) -> dict:
@@ -464,22 +674,27 @@ def batched_bicgstab(A, b, x0=None, tol=1e-08, maxiter=None, M=None,
     (:func:`_bicgstab_lanes`), found again by the two ``apply`` functions
     and the shapes: other values, another ``b``, ``x0``, ``tol`` or
     ``maxiter`` trace and compile nothing. One dispatch and one fetch a
-    call; ``info``'s arrays are then on the host. Anything else runs the
+    call; ``info``'s arrays are then on the host. Where both sides say
+    which of their operands hold lanes (``lane_operands``: both of those
+    do) and the batch is wide enough, the program compacts its active
+    lanes down a ladder of halving widths (:func:`_bicgstab_ladder`): every
+    lane's count and place are the one loop's. Anything else runs the
     loop eagerly, compiled anew at every call, and ``info`` stays on the
     device."""
     with _linalg._solver_call():
         mv, b, X0, tol, maxiter, _B, n = _prep(A, b, x0, tol, maxiter)
         call = _lanes_call(A, M, b, X0, tol, maxiter, conv_test_iters)
         if call is not None:
-            X, lanes = _linalg._run_compiled_solve(
+            X, (*lanes, stepped) = _linalg._run_compiled_solve(
                 _BICGSTAB, call, _lanes_fields(A, M, b))
         else:
             Mvec = None if M is None else as_batched_matvec(M)
             X, *lanes = _bicgstab_loop(
                 mv, b, X0, tol, maxiter, conv_test_iters, Mvec
             )
+            stepped = None
         info = BatchedSolveInfo(*lanes)
-        iters_max = _solve_event("bicgstab", info, n)
+        iters_max = _solve_event("bicgstab", info, n, stepped)
         _linalg._solve_event("batched_bicgstab", n, iters_max, "device")
     return X, info
 

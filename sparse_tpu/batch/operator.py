@@ -626,7 +626,8 @@ class BatchedDIA(BatchedOperator):
     indexes a plane by column; :meth:`lane` converts). One
     ``ops.dia_spmv.dia_planes_matvec`` pass, no index loads at all.
     Declares what it holds: ``operands`` are the planes, ``apply`` the
-    product over the offsets (:class:`_PlanesApply`)."""
+    product over the offsets (:class:`_PlanesApply`), ``lane_operands``
+    says that the planes' leading axis is the lanes'."""
 
     def __init__(self, data, offsets, shape):
         data = asjnp(data)
@@ -650,6 +651,10 @@ class BatchedDIA(BatchedOperator):
     @property
     def operands(self):
         return (self.data,)
+
+    # a lane's planes are its own: a solve that compacts its active lanes
+    # gathers them (krylov._lane_operands)
+    lane_operands = (True,)
 
     @classmethod
     def from_batched_csr(cls, bcsr: BatchedCSR, max_diags=None):

@@ -103,8 +103,11 @@ def jacobi_factory(pattern, storage_dtype=None, acc_dtype=None):
     here, once per pattern; the returned factory is pure jnp. ``Mvec``
     declares what it holds, as ``linalg.LinearOperator`` does: ``Mvec.apply``
     is :func:`_scale`, ``Mvec.operands`` the reciprocal diagonal ``(dinv
-    [B, n],)``, ``Mvec.describe`` names the kind, so that a batched solver
-    can hand the array to a compiled program as an argument.
+    [B, n],)``, ``Mvec.lane_operands`` says that its leading axis is the
+    lanes' (``precond.make_M``'s one-lane wrapper shares ``_scale`` over an
+    array the lanes would share, and says nothing), ``Mvec.describe`` names
+    the kind, so that a batched solver can hand the array to a compiled
+    program as an argument and gather it when it compacts its lanes.
 
     ``storage_dtype`` / ``acc_dtype`` (ISSUE 16): the reciprocal is
     computed at ``acc_dtype`` and STORED at ``storage_dtype`` — the
@@ -131,7 +134,7 @@ def jacobi_factory(pattern, storage_dtype=None, acc_dtype=None):
         def Mvec(R):
             return R * dinv
 
-        Mvec.apply, Mvec.operands = _scale, (dinv,)
+        Mvec.apply, Mvec.operands, Mvec.lane_operands = _scale, (dinv,), (True,)
         Mvec.describe = {"precond": "jacobi"}
         return Mvec
 

@@ -1537,6 +1537,8 @@ XGC_OFFSETS = (-33, -32, -31, -1, 0, 1, 31, 32, 33)
 
 
 def test_batched_bicgstab_program_compiles_at_the_cells_shapes(one_chip):
+    """The cell's program (PR 55), since PR 56 a ladder of four stages at
+    halving widths (``krylov._bicgstab_ladder``)."""
     import time
 
     from sparse_tpu.batch import krylov
@@ -1544,38 +1546,67 @@ def test_batched_bicgstab_program_compiles_at_the_cells_shapes(one_chip):
     from sparse_tpu.precond.jacobi import _scale
 
     B, n, D = XGC_LANES, XGC_ROWS, len(XGC_OFFSETS)
+    widths = krylov._ladder(B)
+    assert widths == (32768, 16384, 8192, 4096)
     vec = _sds((B, n), jnp.float32, one_chip)
     t0 = time.perf_counter()
     c = krylov._bicgstab_program.lower(
         (_sds((B, D, n), jnp.float32, one_chip),), (vec,), vec, vec,
         _sds((B,), jnp.float32, one_chip), 200,
         a_apply=_PlanesApply(XGC_OFFSETS), m_apply=_scale, conv_test_iters=1,
-        tapped=False).compile()
+        tapped=False, lane_operands=((True,), (True,))).compile()
     seconds = time.perf_counter() - t0
-    print(f"jit_batched_bicgstab at {B} lanes: traced, lowered and compiled "
-          f"in {seconds:.1f} s")
-    assert seconds < 30, seconds  # 2 s here alone
+    ma = c.memory_analysis()
+    print(f"jit_batched_bicgstab at {B} lanes, stages {widths}: traced, "
+          f"lowered and compiled in {seconds:.1f} s; {_device_bytes(c) / 1e9:.2f} "
+          f"GB (arguments {ma.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.2f})")
+    assert seconds < 30, seconds  # 6 s here alone (2 s with one stage)
     text = c.as_text()
     assert "jit_batched_bicgstab" in text
-    ma = c.memory_analysis()
     # the planes, the diagonal, b and the start are arguments (nothing an
-    # operator holds is a constant), unpadded: the compiler lays the lanes
+    # operator holds is a constant), unpadded: the runtime lays the lanes
     # along the 128-wide axis of a tile and the 992 rows along the 8-wide one
     held = 4 * B * n * (D + 3)
     assert held <= ma.argument_size_in_bytes < held + 1e6
     assert re.search(rf"f32\[{B},{D},{n}\]\{{0,2,1:T\(8,128\)\}} parameter\(0\)", text)
-    assert not re.search(rf"f32\[{B},{n}\]\{{1,0", text)
-    # the loop's carried vectors and a step's temporaries: a dozen vectors
-    assert ma.temp_size_in_bytes < 14 * 4 * B * n
-    assert _device_bytes(c) < 4e9 < HBM_BYTES
-    # one fetch: the lanes' counts leave as one [3, B] array beside X, of
-    # integers (the residuals by their bits): the chip flushes float32
-    # denormals to zero, which is what a small count's bits would be
-    assert re.search(rf"ROOT %\S+ = \(f32\[{B},{n}\]\S+ s32\[3,{B}\]\S+ tuple\(", text)
-    # the scopes the cell's shares read are on the loop's ops, and nothing
-    # in the program gathers
-    body = [ln for ln in text.splitlines() if "/while/body/" in ln]
-    for scope in ("/batch.spmv/", "/batch.precond/", "/bucket.dots/"):
-        assert any(scope in ln for ln in body), scope
-    assert not re.search(r" (gather|scatter)\(", text)
+    # the stages' gathered copies of the planes and of a dozen vectors, each
+    # stage's half the one before, and the lanes-major copies a compaction
+    # gathers from: under 7 GB with the arguments
+    assert _device_bytes(c) < 7e9 < HBM_BYTES
+    # four loops, one a stage. Stage 0 carries the arguments' layout, the
+    # lanes along the 128-wide axis; stages 1 to 3 come out lanes-major
+    # ({1,0}: a compaction gathers whole rows of a lanes-major copy and
+    # nothing ties the result to the arguments' layout). Read on the chip
+    # (PERF.md section 6, PR 56): a lanes-major step takes 0.360 us a lane
+    # where stage 0's takes 0.351, and a program told to keep every stage
+    # lanes-minor (a layout constraint on the gathered arrays and on each
+    # step's carried ones) pays that back in the copies around its gathers:
+    # 185.6 against 186.1 ms a call. So the compiler's layouts stand.
+    loops = re.findall(r" while\(.*?body=(%[\w.\-]+)", text)
+    carried = re.findall(r"= \((f32\[\d+,\d+\]\{[^}]*\}), .*? while\(", text)
+    assert len(loops) == len(carried) == len(widths)
+    assert sorted(carried, key=lambda t: -int(t[4:t.index(",")])) == [
+        f"f32[{B},{n}]{{0,1:T(8,128)}}"] + [
+        f"f32[{w},{n}]{{1,0:T(8,128)}}" for w in widths[1:]]
+    # and no copy inside a loop's body
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"\n(%[\w.\-]+) \([^\n]*\{\n(.*?)\n\}", text, re.S)}
+    for body in loops:
+        assert " copy(" not in bodies[body], body
+    # one fetch: the lanes' counts and the stages' trips leave as one [4, B]
+    # array beside X, of integers (the residuals by their bits): the chip
+    # flushes float32 denormals to zero, which is what a small count's bits
+    # would be
+    assert re.search(rf"ROOT %\S+ = \(f32\[{B},{n}\]\S+ s32\[4,{B}\]\S+ tuple\(", text)
+    # the scopes the cell's shares read are on every loop's ops
+    for w in widths:
+        body = [ln for ln in text.splitlines()
+                if "/while/body/" in ln and f"[{w}" in ln]
+        for scope in ("/batch.spmv/", "/batch.precond/", "/bucket.dots/"):
+            assert any(scope in ln for ln in body), (w, scope)
+    # the program gathers and scatters between the stages and nowhere else
+    moved = [ln for ln in text.splitlines()
+             if re.search(r" (gather|scatter)\(", ln)]
+    assert moved and all("/batch.compact/" in ln for ln in moved)
     assert "tpu_custom_call" not in text
